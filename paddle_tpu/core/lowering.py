@@ -661,9 +661,9 @@ def build_callable(program, fetch_names, read_names, written_names,
     donated to XLA so parameter updates alias their input buffers — the
     equivalent of the reference's in-place optimizer kernels + memory passes
     (details/inplace_op_pass.cc), for free via buffer donation. `donate=False`
-    opts out (the executor passes its policy: off through the host-relay
-    backend, where donated buffers round-trip host-side, and under
-    PADDLE_DONATE=0 for callers that keep stale references into the scope)."""
+    opts out (the executor passes its policy: off under PADDLE_DONATE=0 or
+    a per-call donate=False, for callers that keep stale references into
+    the scope)."""
     fn, ro_names, rw_names = build_fn(program, fetch_names, read_names,
                                       written_names, static_lods=static_lods,
                                       static_feed=static_feed,

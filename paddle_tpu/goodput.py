@@ -242,7 +242,7 @@ _sentinel_trace = [None]
 _ANALYSIS_KIND = {'run': 'run', 'bound': 'run', 'fused': 'fused',
                   'mesh': 'mesh'}
 
-# loss-bucket taxonomy: bucket -> monitor histograms whose SUM is the
+# loss-bucket table: bucket -> monitor histograms whose SUM is the
 # wall attributed to it (docs/observability.md "Goodput & MFU").
 # NOTE: 'queue' and 'retry_backoff' sum PER-REQUEST waits — N requests
 # queued concurrently contribute N overlapping seconds, so under

@@ -1,4 +1,4 @@
-"""BERT-base pretraining model (SURVEY §7 stage 8 / BASELINE.md north-star
+"""BERT-base pretraining model (SURVEY §7 stage 8 / BASELINE.json north-star
 "ERNIE / BERT-base pretraining"): bidirectional encoder with token +
 position + segment embeddings, masked-LM head (tied decoder over the
 token embedding) and next-sentence head — the reference exercises BERT

@@ -61,15 +61,6 @@ def _pick_block(ln, pref):
     return b if ln % b == 0 else ln
 
 
-def _compiler_params(pltpu, semantics):
-    cls = getattr(pltpu, 'CompilerParams', None) or \
-        getattr(pltpu, 'TPUCompilerParams')
-    try:
-        return cls(dimension_semantics=semantics)
-    except TypeError:       # field not supported on this version
-        return cls()
-
-
 # --------------------------------------------------------------------------
 # forward kernel
 # --------------------------------------------------------------------------
@@ -170,9 +161,10 @@ def _flash_fwd_pallas(q, k, v, scale, causal, interpret, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, dh), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name='flash_attention_fwd',
     )(*ins)
     return o, lse[:, 0]
 
@@ -311,9 +303,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, interpret,
         out_specs=[qspec],
         out_shape=[jax.ShapeDtypeStruct((bh, ln, dh), q.dtype)],
         scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name='flash_attention_bwd_dq',
     )(*ins)[0]
 
     # k-major grid: q blocks stream innermost
@@ -335,9 +328,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, interpret,
                    jax.ShapeDtypeStruct((bh, ln, dh), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, dh), jnp.float32),
                         pltpu.VMEM((bk, dh), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name='flash_attention_bwd_dkv',
     )(*ins2)
     return dq, dk, dv
 
@@ -436,6 +430,14 @@ def _flash_biased_bwd(scale, causal, impl, n_heads, res, ct):
 _flash_biased.defvjp(_flash_biased_fwd, _flash_biased_bwd)
 
 
+def flash_shapes_ok(ln):
+    """Tiling rule for the kernels: a 128-multiple tile divides L, or L is
+    short enough (<= 1024) to ride as one full-L VMEM tile. The
+    flash_attention op consults it through kernel_tier.dispatch; direct
+    callers of the functions below get the kernel they ask for."""
+    return ln % 128 == 0 or ln <= 1024
+
+
 def _resolve_impl(use_pallas):
     if use_pallas is None:
         return 'pallas' if jax.default_backend() == 'tpu' else 'ref'
@@ -462,10 +464,6 @@ def flash_attention(q, k, v, scale=None, causal=True, use_pallas=None,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     impl = _resolve_impl(use_pallas)
-    if impl == 'pallas' and q.shape[1] % 128 and q.shape[1] > 1024:
-        # no 128-multiple tile divides L: the kernel would need one full-L
-        # VMEM tile; the fused-by-XLA reference is the safer lowering
-        impl = 'ref'
     if key_padding_bias is not None:
         out = _flash_biased(q, k, v, key_padding_bias, float(scale),
                             bool(causal), impl, int(num_heads))
@@ -525,10 +523,6 @@ def flash_attention_spmd(q, k, v, mesh, scale=None, causal=True,
                               batch_axis=data_ax, head_axis=model_ax,
                               zigzag=zz)
     impl = _resolve_impl(use_pallas)
-    if impl == 'pallas' and ln % 128 and ln > 1024:
-        # same guard as flash_attention: no 128-multiple tile divides L,
-        # so the kernel would need one full-L VMEM tile per program
-        impl = 'ref'
     spec = P(data_ax, model_ax, None, None)
 
     if key_padding_bias is None:
@@ -581,26 +575,30 @@ def _flash_attention_op(ctx, op):
     scale = op.attr('scale', None)
     scale = None if scale is None or scale == 0.0 else float(scale)
     causal = op.attr('causal', True)
+    from . import kernel_tier
     from ..parallel.api import get_active_mesh
     mesh = get_active_mesh()
-    use_pallas = None
-    if jax.default_backend() != 'tpu':
-        # on CPU (virtual-mesh tests, dryrun) exercise the real kernels
-        # through the pallas interpreter under SPMD; plain jnp otherwise
-        use_pallas = 'interpret' if mesh is not None else False
-    if mesh is not None and mesh.size > 1:
-        if q.ndim != 4:
-            # 3-d [BH, L, dh]: no batch/head axes to shard_map over; the
-            # XLA auto-partitioner cannot split a pallas custom call, so
-            # lower the partitionable einsum reference instead
-            out = flash_attention(q, k, v, scale=scale, causal=causal,
-                                  use_pallas=False)
-        else:
-            out = flash_attention_spmd(
-                q, k, v, mesh, scale=scale, causal=causal,
-                use_pallas=use_pallas,
-                ring_zigzag=op.attr('ring_zigzag', False),
-                key_padding_bias=bias)
+    meshed = mesh is not None and mesh.size > 1
+    # the same tier knob as every other fused unit picks the lowering
+    # (pallas | interpret -> the kernels, anything else -> the einsum
+    # reference, counted as 'off': there is no distinct xla emission).
+    # Under a mesh the kernel needs batch/head axes to shard_map over
+    # (the XLA partitioner cannot split a pallas custom call), and a
+    # sharded sequence axis takes the ring path, which has no kernel.
+    ring = meshed and q.ndim == 4 and \
+        _mesh_axis(mesh, 'seq', q.shape[2]) is not None
+    pallas_ok = flash_shapes_ok(q.shape[-2]) and not ring and \
+        (q.ndim == 4 or not meshed)
+    impl = kernel_tier.dispatch(
+        'flash_attention', pallas_ok=pallas_ok, xla_ok=False, mesh=mesh,
+        count=getattr(ctx, 'sparse_mode', None) != 'scout')
+    use_pallas = {'pallas': True, 'interpret': 'interpret'}.get(impl, False)
+    if meshed and q.ndim == 4:
+        out = flash_attention_spmd(
+            q, k, v, mesh, scale=scale, causal=causal,
+            use_pallas=use_pallas,
+            ring_zigzag=op.attr('ring_zigzag', False),
+            key_padding_bias=bias)
     else:
         out = flash_attention(q, k, v, scale=scale, causal=causal,
                               use_pallas=use_pallas,

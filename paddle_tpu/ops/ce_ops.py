@@ -89,7 +89,6 @@ def _fwd_kernel(nj, ignore_index, *refs):
 def _fused_ce_fwd_pallas(logits, labels, ignore_index, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from .attention_ops import _compiler_params
     n, v = logits.shape
     bn = _pick_block(n, 256, 128)
     bv = _pick_block(v, 2048, 128)
@@ -107,9 +106,10 @@ def _fused_ce_fwd_pallas(logits, labels, ignore_index, interpret):
         scratch_shapes=[pltpu.VMEM((bn, 128), jnp.float32),
                         pltpu.VMEM((bn, 128), jnp.float32),
                         pltpu.VMEM((bn, 128), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, ("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name='softmax_ce_fwd',
     )(logits, lab2)
     return loss[0], lse[0]
 
@@ -136,7 +136,6 @@ def _bwd_kernel(ignore_index, x_ref, lab_ref, lse_ref, ct_ref, dx_ref):
 def _fused_ce_bwd_pallas(logits, labels, lse, ct, ignore_index, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from .attention_ops import _compiler_params
     n, v = logits.shape
     bn = _pick_block(n, 256, 128)
     bv = _pick_block(v, 2048, 128)
@@ -150,9 +149,10 @@ def _fused_ce_bwd_pallas(logits, labels, lse, ct, ignore_index, interpret):
                   pl.BlockSpec((1, bn), lambda i, j: (0, i))],
         out_specs=[pl.BlockSpec((bn, bv), lambda i, j: (i, j))],
         out_shape=[jax.ShapeDtypeStruct((n, v), logits.dtype)],
-        compiler_params=_compiler_params(
-            pltpu, ("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name='softmax_ce_bwd',
     )(logits, lab2, lse[None, :], ct.astype(jnp.float32)[None, :])[0]
 
 
@@ -268,9 +268,9 @@ def _sharded_vocab_ce(logits, labels, ignore_index, impl, vocab_axis):
     logits [n_loc, v_loc] local block, labels [n_loc] GLOBAL ids.
     Returns this shard's PARTIAL loss (partials psum to the true loss):
     an output the transpose treats as genuinely sharded — claiming a
-    replicated [n] loss instead makes shard_map's reverse rule average
-    the cotangent over the vocab axis (measured ct/axis_size on jax
-    0.4.37 with replication checking off), silently halving dlogits."""
+    replicated [n] loss instead makes shard_map's reverse rule (with
+    replication checking off) average the cotangent over the vocab
+    axis, silently scaling dlogits by 1/axis_size."""
     return _sharded_vocab_ce_fwd(logits, labels, ignore_index, impl,
                                  vocab_axis)[0]
 

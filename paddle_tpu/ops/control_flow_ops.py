@@ -221,7 +221,7 @@ def _while(ctx, op):
             # more times than it. Silent truncation would train on wrong
             # numbers — check the condition actually went false. (A
             # user-passed max_trip_count is an explicit contract and is not
-            # checked.) debug.callback needs host-callback support.
+            # checked.)
             def _check_exhausted(c, _bound=int(bound)):
                 if bool(np.any(np.asarray(c))):
                     raise RuntimeError(
@@ -230,12 +230,7 @@ def _while(ctx, op):
                         "true after %d iterations. Pass layers.While(cond, "
                         "max_trip_count=N) with the real bound." %
                         (_bound, _bound))
-            try:
-                supports_cb = jax.default_backend() in ('cpu', 'tpu', 'gpu')
-            except Exception:
-                supports_cb = False
-            if supports_cb:
-                jax.debug.callback(_check_exhausted, final[cond_name])
+            jax.debug.callback(_check_exhausted, final[cond_name])
     else:
         final = lax.while_loop(cond_fn, run_body, init)
     for n in carried:

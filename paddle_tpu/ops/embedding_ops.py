@@ -31,7 +31,7 @@ from ..core.registry import register_op
 
 def pallas_shapes_ok(w, n_ids):
     """Kernel tiling rule: features must fill whole lanes (the row DMA is
-    [1, D]); any id count works (grid is per-id)."""
+    one [1, 1, D] block); any id count works (grid is per-id)."""
     return w.ndim == 2 and w.shape[1] % 128 == 0 and n_ids >= 1 and \
         w.dtype == jnp.float32
 
@@ -69,23 +69,28 @@ def _gather_pallas(w, flat_ids, bias, interpret):
     has_bias = bias is not None
     # clamp like jnp.take's default TPU behavior (out-of-range ids clamp)
     ids32 = jnp.clip(flat_ids.astype(jnp.int32), 0, w.shape[0] - 1)
-    in_specs = [pl.BlockSpec((1, d), lambda i, ids: (ids[i], 0))]
-    ins = [w]
+    # rows ride as [V, 1, D]: Mosaic wants a block's last two dims to be
+    # (8k, 128k) or the array's own, and a one-row (1, D) block of a
+    # [V, D] table is neither — (1, 1, D) of [V, 1, D] is the array's own
+    row = pl.BlockSpec((1, 1, d), lambda i, ids: (ids[i], 0, 0))
+    in_specs = [row]
+    ins = [w.reshape(w.shape[0], 1, d)]
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, d), lambda i, ids: (0, 0)))
-        ins.append(bias.reshape(1, d))
+        in_specs.append(pl.BlockSpec((1, 1, d), lambda i, ids: (0, 0, 0)))
+        ins.append(bias.reshape(1, 1, d))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, d), lambda i, ids: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, d), lambda i, ids: (i, 0, 0)),
     )
     return pl.pallas_call(
         functools.partial(_gather_kernel, has_bias),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, d), w.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), w.dtype),
         interpret=interpret,
-    )(ids32, *ins)
+        name='embedding_gather',
+    )(ids32, *ins).reshape(n, d)
 
 
 def _gather_ref(w, flat_ids, bias):

@@ -49,7 +49,11 @@ def ffn_shapes_ok(n, d_in, d_ff, d_out):
     """Tiling rule for the pallas kernel: every matmul axis fills whole
     128-lane tiles, the row count tiles a power-of-two block, and both
     weight panels (+ one row block of every operand) fit VMEM together
-    (f32 budget ~12 MB of the ~16 MB/core)."""
+    (f32 budget ~12 MB of the 16 MB scoped limit). Mosaic keeps ONE copy
+    of a block whose index never changes — the weight panels — and
+    double-buffers the row blocks, which the doubled d_ff/d_out row
+    terms stand for; at d512 ff2048 (admitted) its own accounting reads
+    ~11 MB, at d640 ff2560 (refused here) 16.25 MB."""
     from .ce_ops import _pick_block
     if d_in % 128 or d_ff % 128 or d_out % 128:
         return False
@@ -75,6 +79,30 @@ def ffn_spmd_ok(mesh, n, d_in, d_ff, d_out):
 # pallas forward kernel: one row block through both matmuls per program
 # ---------------------------------------------------------------------------
 
+# Mosaic lowers neither lax.erf nor erfc (jax 0.9.0), and exact gelu needs
+# one: the kernel expands erf into the mul/add/div rational approximation
+# XLA itself uses for f32 (|err| < 4e-7 against math.erf).
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08,
+          -2.10102402082508e-06, -5.69250639462346e-05,
+          -7.34990630326855e-04, -2.95459980854025e-03,
+          -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04,
+          -1.68282697438203e-03, -7.37332916720468e-03,
+          -1.42647390514189e-02)
+
+
+def _erf_rational(x):
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    p = jnp.float32(_ERF_P[0])
+    for c in _ERF_P[1:]:
+        p = p * x2 + jnp.float32(c)
+    q = jnp.float32(_ERF_Q[0])
+    for c in _ERF_Q[1:]:
+        q = q * x2 + jnp.float32(c)
+    return x * p / q
+
+
 def _ffn_fwd_kernel(has_mask, *refs):
     if has_mask:
         (x_ref, w1_ref, b1_ref, w2_ref, b2_ref, mk_ref,
@@ -84,7 +112,9 @@ def _ffn_fwd_kernel(has_mask, *refs):
     x = x_ref[...]
     pre1 = jnp.dot(x, w1_ref[...],
                    preferred_element_type=jnp.float32) + b1_ref[...]
-    h = jax.nn.gelu(pre1, approximate=False).astype(x.dtype)
+    cdf = 0.5 * (1.0 + _erf_rational(
+        pre1 * np.float32(1.0 / np.sqrt(2.0))))
+    h = (pre1 * cdf).astype(x.dtype)
     y = jnp.dot(h, w2_ref[...],
                 preferred_element_type=jnp.float32) + b2_ref[...]
     y = y.astype(y_ref.dtype)
@@ -98,7 +128,6 @@ def _ffn_fwd_kernel(has_mask, *refs):
 def _ffn_fwd_pallas(x, w1, b1, w2, b2, mask, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from .attention_ops import _compiler_params
     from .ce_ops import _pick_block
     n, d_in = x.shape
     d_ff = w1.shape[1]
@@ -124,8 +153,10 @@ def _ffn_fwd_pallas(x, w1, b1, w2, b2, mask, interpret):
         out_specs=[row_out, row_ff],
         out_shape=[jax.ShapeDtypeStruct((n, d_out), x.dtype),
                    jax.ShapeDtypeStruct((n, d_ff), jnp.float32)],
-        compiler_params=_compiler_params(pltpu, ("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name='fused_ffn_tail_fwd',
     )(*args)
     return y, pre1
 

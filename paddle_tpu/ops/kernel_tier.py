@@ -18,7 +18,9 @@ per op; here one knob picks the lowering family for every fused unit):
                  ``use_pallas='interpret'``).
 
 Default (unset/auto): ``pallas`` on a TPU backend, ``off`` elsewhere — CPU
-test suites see legacy numerics unless they opt in.
+test suites see legacy numerics unless they opt in. Flash attention
+(ops/attention_ops.py) resolves through the same knob: ``pallas`` /
+``interpret`` run its kernels, ``off`` / ``xla`` the einsum reference.
 
 Dispatch is resolved at TRACE time (op lowerings consult it while the
 program compiles), so steady-state dispatch costs nothing per run; the
@@ -30,10 +32,9 @@ mesh), so bench counter deltas and obsreport show which tier actually
 ran (and when a shape forced a per-op fallback).
 
 Mesh-native fused units partition through :func:`partitioned_call` — the
-shard_map-over-mesh wrapper extracted from ops/attention_ops.py (riding
-parallel/ring_attention._shard_map), so every fused unit shards the way
-flash attention already does instead of falling back to the xla tier the
-moment a mesh is active.
+shard_map-over-mesh wrapper extracted from ops/attention_ops.py, so every
+fused unit shards the way flash attention already does instead of falling
+back to the xla tier the moment a mesh is active.
 """
 import os
 
@@ -117,15 +118,13 @@ def dispatch(op, pallas_ok=True, xla_ok=True, tier=None, count=True,
 def partitioned_call(fn, mesh, in_specs, out_specs):
     """shard_map ``fn`` over ``mesh`` with the given PartitionSpecs — one
     kernel invocation per shard, XLA stitching the shards back together.
-    Rides parallel/ring_attention._shard_map (manual-over-all-axes with
-    the jax-version fallbacks handled there); axes a spec does not name
-    see replicated data, so e.g. a data-only spec under
-    mesh(data=2, model=2) runs the same per-shard kernel on both model
-    rows. A pallas custom call cannot be auto-partitioned by the XLA
-    SPMD partitioner — this wrapper is what lets the fused tier survive
-    an active mesh at all."""
-    from ..parallel.ring_attention import _shard_map
-    return _shard_map(fn, mesh, in_specs, out_specs)
+    Manual over all mesh axes; axes a spec does not name see replicated
+    data, so e.g. a data-only spec under mesh(data=2, model=2) runs the
+    same per-shard kernel on both model rows. A pallas custom call
+    cannot be auto-partitioned by the XLA SPMD partitioner — this
+    wrapper is what lets the fused tier survive an active mesh at all."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def mesh_axis(mesh, name, dim_size):

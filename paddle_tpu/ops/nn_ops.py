@@ -436,12 +436,16 @@ def _layer_norm(ctx, op):
 # ---------------------------------------------------------------------------
 
 def ln_res_shapes_ok(n, d):
-    """Tiling rule: full rows fit one (bn, d) VMEM block (d fills whole
-    lanes, bounded so in+out+grad blocks stay well under VMEM), and the
-    row count tiles a power-of-two block."""
+    """Tiling rule: d fills whole lanes; the row count tiles a
+    power-of-two block bn whose (1, bn) row-statistics block Mosaic
+    accepts (whole 128-lane tiles, or all n rows at once); and the four
+    (bn, d) f32 row blocks either kernel streams, double-buffered, stay
+    under 12 MB of the 16 MB scoped VMEM limit (bn 128: d <= 3072 — at
+    d 4096 Mosaic's own accounting reads 16.03 MB and refuses)."""
     from .ce_ops import _pick_block
-    return d % 128 == 0 and d <= 8192 and \
-        _pick_block(n, 128, 8) is not None
+    bn = _pick_block(n, 128, 8)
+    return d % 128 == 0 and bn in (128, n) and \
+        8 * bn * d * 4 <= 12 * 1024 * 1024
 
 
 def ln_res_spmd_ok(mesh, n, d):
@@ -467,7 +471,6 @@ def _ln_res_fwd_kernel(eps, x_ref, r_ref, sc_ref, b_ref,
 def _ln_res_fwd_pallas(x, r, scale, bias, eps, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from .attention_ops import _compiler_params
     from .ce_ops import _pick_block
     n, d = x.shape
     bn = _pick_block(n, 128, 8)
@@ -483,8 +486,10 @@ def _ln_res_fwd_pallas(x, r, scale, bias, eps, interpret):
                    jax.ShapeDtypeStruct((n, d), x.dtype),
                    jax.ShapeDtypeStruct((1, n), jnp.float32),
                    jax.ShapeDtypeStruct((1, n), jnp.float32)],
-        compiler_params=_compiler_params(pltpu, ("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name='fused_ln_residual_fwd',
     )(x, r, scale.reshape(1, d), bias.reshape(1, d))
     return s, y, m[0], rs[0]
 
@@ -506,7 +511,6 @@ def _ln_res_bwd_kernel(s_ref, m_ref, rs_ref, sc_ref, dy_ref, ds_ref,
 def _ln_res_bwd_pallas(s, m, rs, scale, dy, ds, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from .attention_ops import _compiler_params
     from .ce_ops import _pick_block
     n, d = s.shape
     bn = _pick_block(n, 128, 8)
@@ -519,8 +523,10 @@ def _ln_res_bwd_pallas(s, m, rs, scale, dy, ds, interpret):
         in_specs=[row, stat, stat, vec, row, row],
         out_specs=[row],
         out_shape=[jax.ShapeDtypeStruct((n, d), s.dtype)],
-        compiler_params=_compiler_params(pltpu, ("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name='fused_ln_residual_bwd',
     )(s, m[None, :], rs[None, :], scale.reshape(1, d), dy, ds)[0]
 
 

@@ -224,7 +224,6 @@ def _fused_adam_flat(p, g, m1, m2, lr_t, b1, b2, eps, interpret):
     import jax
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from .attention_ops import _compiler_params
     L = p.shape[0]
     bn = 256
     row_bytes = bn * 128
@@ -244,8 +243,10 @@ def _fused_adam_flat(p, g, m1, m2, lr_t, b1, b2, eps, interpret):
                   spec, spec, spec, spec],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((R, 128), jnp.float32)] * 3,
-        compiler_params=_compiler_params(pltpu, ("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name='fused_adam',
     )(lrt2, shape2(p), shape2(g), shape2(m1), shape2(m2))
     return (po.reshape(-1)[:L], m1o.reshape(-1)[:L], m2o.reshape(-1)[:L])
 

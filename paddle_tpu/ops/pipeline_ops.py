@@ -130,28 +130,7 @@ def _gpipe_run(ctx, op):
         b0 = int(jnp.shape(act[0])[0])
         if b0 % n_micro == 0 and (b0 // n_micro) % mesh.shape['data'] == 0:
             batch_axis = 'data'
-    gated = False
-    if batch_axis is not None:
-        from ..parallel.ring_attention import shard_map_supports_axis_names
-        beyond = set(mesh.axis_names) - {'pipe', 'data'}
-        if beyond and not shard_map_supports_axis_names():
-            # manual-over-all fallback with axes OUTSIDE the manual set:
-            # cotangent psum semantics for those axes are jax-version-
-            # dependent — gate composition off (replicate: correct but
-            # duplicated compute) rather than risk silently wrong grads
-            _log_once(('gated', tuple(sorted(mesh.axis_names))),
-                      "gpipe_run: batch_axis composition DISABLED — this "
-                      "jax's shard_map lacks axis_names and the mesh has "
-                      "axes %s beyond {pipe, data}; the batch replicates "
-                      "over non-pipe axes (correct, duplicated compute). "
-                      "Upgrade jax for manual-over-subset shard_map."
-                      % sorted(beyond))
-            batch_axis = None
-            gated = True
-    # (skip when gated: the axis qualified — the cause was shard_map
-    # support, already diagnosed above; a second "name it 'data'" log
-    # would send the operator after the wrong fix)
-    if batch_axis is None and not gated and any(
+    if batch_axis is None and any(
             mesh.shape[a] > 1 for a in mesh.axis_names if a != 'pipe'):
         _log_once(('noengage', tuple(sorted(mesh.axis_names)), n_micro),
                   "gpipe_run: mesh %s has a >1 non-pipe axis but batch "

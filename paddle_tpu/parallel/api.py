@@ -70,13 +70,16 @@ class ShardingRules(object):
 
 
 class _MeshEntry(object):
-    __slots__ = ('fn', 'ro_names', 'rw_names', 'lod_out')
+    __slots__ = ('fn', 'ro_names', 'rw_names', 'lod_out', 'state_shardings')
 
-    def __init__(self, fn, ro_names, rw_names, lod_out=None):
+    def __init__(self, fn, ro_names, rw_names, lod_out, state_shardings):
         self.fn = fn
         self.ro_names = ro_names
         self.rw_names = rw_names
         self.lod_out = lod_out if lod_out is not None else {}
+        # {state name: NamedSharding}, resolved once per compile (the
+        # rules are regexes — not something to re-match every step)
+        self.state_shardings = state_shardings
 
 
 class MeshRunner(object):
@@ -146,14 +149,23 @@ class MeshRunner(object):
         fresh_compile = entry is None
         t_compile = time.perf_counter()
         if fresh_compile:
+            from ..executor import _wire_persistent_cache
+            _wire_persistent_cache()
             fn_, ro_, rw_, lod_out_ = self.compile(
                 {k: (v.shape, v.dtype) for k, v in feed.items()},
                 fetch_names, scope, feed_lods=static_lods)
-            entry = _MeshEntry(fn_, ro_, rw_, lod_out_)
+            entry = _MeshEntry(
+                fn_, ro_, rw_, lod_out_,
+                {n: self._sharding(self._rules.spec_for(n))
+                 for n in list(ro_) + list(rw_)})
             self._cache[key] = entry
         fn, ro_names, rw_names = entry.fn, entry.ro_names, entry.rw_names
         ro = {n: exe._state_value(scope, n, program) for n in ro_names}
         rw = {n: exe._state_value(scope, n, program) for n in rw_names}
+        if jax.process_count() == 1:
+            from .spmd import place_state
+            ro = place_state(scope, ro, entry.state_shardings)
+            rw = place_state(scope, rw, entry.state_shardings)
         self._run_counter += 1
         from ..executor import _run_key, _next_program_run
         key_arr = _run_key(program.random_seed, _next_program_run(program),

@@ -120,84 +120,16 @@ def _ring_inner(axis_name, scale, causal, q, k, v, q_pos):
     return out.astype(q.dtype)
 
 
-_axis_names_warned = [False]
-
-
-def shard_map_supports_axis_names():
-    """One-time signature probe: does this jax's shard_map accept the
-    axis_names parameter (manual-over-subset)? Callers composing a manual
-    axis with auto-partitioned axes (gpipe batch_axis) must gate that
-    composition off when this is False — under the manual-over-all
-    fallback the transpose/psum semantics for unmentioned axes are
-    jax-version-dependent and have produced silently wrong dp x pp grads
-    (ADVICE r5; ROADMAP open items)."""
-    if _axis_names_support[0] is None:
-        import inspect
-        try:
-            from jax import shard_map as sm
-        except ImportError:
-            from jax.experimental.shard_map import shard_map as sm
-        try:
-            params = inspect.signature(sm).parameters
-            _axis_names_support[0] = 'axis_names' in params
-        except (TypeError, ValueError):
-            # unsignaturable wrapper: assume NO support — callers use
-            # this probe to gate compositions that would be silently
-            # wrong under the manual-over-all fallback, so the safe
-            # answer is the pessimistic one (replicate, visibly)
-            _axis_names_support[0] = False
-    return _axis_names_support[0]
-
-
-_axis_names_support = [None]
-
-
-def _warn_axis_names_fallback(axis_names, mesh):
-    """Warn ONCE when a requested manual-axis subset is silently widened
-    to manual-over-all — only when it changes semantics (the mesh has
-    axes outside the requested subset)."""
-    extra = set(mesh.axis_names) - set(axis_names)
-    if _axis_names_warned[0] or not extra:
-        return
-    _axis_names_warned[0] = True
-    import warnings
-    warnings.warn(
-        "shard_map on this jax version lacks axis_names: requested manual "
-        "axes %s fall back to manual-over-ALL mesh axes (extra: %s). "
-        "Gradient correctness for values auto-partitioned over the extra "
-        "axes is jax-version-dependent under this fallback; batch_axis "
-        "composition is gated off where it would be silent (see "
-        "docs/parallelism.md)." % (sorted(axis_names), sorted(extra)),
-        stacklevel=3)
-
-
 def _shard_map(fn, mesh, in_specs, out_specs, axis_names=None):
     """axis_names: restrict MANUAL axes to this subset — the other mesh
     axes stay under the automatic SPMD partitioner, so e.g. gpipe over
     mesh(data=2, pipe=4) with axis_names={'pipe'} keeps the feed's
     'data' sharding (and the backward psum over 'data') instead of
-    replicating the whole batch per data replica. On jax versions whose
-    shard_map lacks the parameter this falls back to manual-over-all (the
-    previous behavior) and warns ONCE when that widens the manual set —
-    silent wrong grads become visible degradation (ADVICE r5)."""
-    try:
-        from jax import shard_map
-    except ImportError:          # older jax
-        from jax.experimental.shard_map import shard_map
-    if axis_names is not None:
-        try:
-            return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False,
-                             axis_names=frozenset(axis_names))
-        except TypeError:
-            _axis_names_support[0] = False
-            _warn_axis_names_fallback(axis_names, mesh)
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:            # older shard_map keyword
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    replicating the whole batch per data replica. None: manual over all
+    axes."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False,
+                         axis_names=frozenset(axis_names or ()))
 
 
 def ring_attention(q, k, v, mesh, axis_name='seq', scale=None, causal=True,

@@ -22,7 +22,7 @@ from ..core import lowering
 from ..framework import Variable
 from .mesh import data_mesh
 
-__all__ = ['DataParallelRunner']
+__all__ = ['DataParallelRunner', 'place_state']
 
 
 class _Entry(object):
@@ -38,6 +38,33 @@ class _Entry(object):
         self.feed_shardings = feed_shardings
         self.state_shardings = state_shardings
         self.lod_out = lod_out if lod_out is not None else {}
+
+
+def place_state(scope, state, shardings):
+    """Lay single-process `state` ({name: array}) out as `shardings`
+    ({name: NamedSharding}) before it is handed to the sharded jit, ONCE:
+    every moved array is rebound into the scope, so later runs (and
+    read-only state such as lr scalars or frozen weights, which new_state
+    never rebinds) find it in place. Two kinds of state arrive elsewhere:
+    uncommitted arrays the startup program left on the default device, and
+    arrays COMMITTED to another device set — restored by
+    checkpoint.load_checkpoint(mesh=...) onto a shrunken post-preemption
+    mesh, or left over from a larger one — which jit would refuse
+    (counted in spmd_state_migrated_total). jit could move the first kind
+    itself, but a step-1 input on one device and a step-2 input on the
+    mesh are two different lowerings to it: the same step compiled twice,
+    unseen by compile_cache_miss."""
+    out = {}
+    for n, v in state.items():
+        target = shardings[n]
+        if isinstance(v, jax.Array) and v.is_fully_addressable \
+                and not v.sharding.is_equivalent_to(target, v.ndim):
+            if getattr(v, '_committed', False):
+                monitor.inc('spmd_state_migrated_total')
+            v = jax.device_put(v, target)
+            scope.set(n, v)
+        out[n] = v
+    return out
 
 
 class DataParallelRunner(object):
@@ -191,6 +218,8 @@ class DataParallelRunner(object):
         if fresh_compile:
             monitor.inc('compile_cache_miss')
             t_compile = time.perf_counter()
+            from ..executor import _wire_persistent_cache
+            _wire_persistent_cache()
             entry = self._compile(feed, fetch_names,
                                   feed_lods=static_lods)
             self._cache[key] = entry
@@ -202,37 +231,8 @@ class DataParallelRunner(object):
         rw_state = {n: executor._state_value(scope, n, program)
                     for n in entry.rw_names}
         if nproc == 1:
-            # state committed to a DIFFERENT device set — e.g. restored
-            # by checkpoint.load_checkpoint(mesh=...) onto the shrunken
-            # post-preemption mesh while this runner was (re)built over
-            # it, or a leftover from a previous larger mesh — migrates
-            # onto this runner's sharding instead of failing jit's
-            # incompatible-devices check
-            mesh_devs = set(self._mesh.devices.flat)
-
-            def _conform(n, v):
-                # COMMITTED arrays only: uncommitted single-device state
-                # (fresh jnp.asarray uploads) is moved freely by jit
-                # itself — explicitly migrating those would re-transfer
-                # read-only state every run. A committed subset-of-mesh
-                # placement empirically dispatches fine on jax 0.4.37,
-                # but is migrated anyway: that tolerance is undocumented
-                # jit behavior, not a contract
-                if isinstance(v, jax.Array) and v.is_fully_addressable \
-                        and getattr(v, '_committed', False) \
-                        and set(v.sharding.device_set) != mesh_devs:
-                    monitor.inc('spmd_state_migrated_total')
-                    out = jax.device_put(v, entry.state_shardings[n])
-                    # rebind the migrated copy: written names are rebound
-                    # by new_state anyway, but READ-ONLY state (lr
-                    # scalars, frozen weights) would otherwise re-pay
-                    # this transfer on every run
-                    scope.set(n, out)
-                    return out
-                return v
-
-            ro_state = {n: _conform(n, v) for n, v in ro_state.items()}
-            rw_state = {n: _conform(n, v) for n, v in rw_state.items()}
+            ro_state = place_state(scope, ro_state, entry.state_shardings)
+            rw_state = place_state(scope, rw_state, entry.state_shardings)
         if nproc > 1:
             # assemble global arrays from per-process host-local data
             # (feeds: local batch shard; state: every process holds the

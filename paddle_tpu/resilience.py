@@ -9,7 +9,7 @@ compile failure, corrupted checkpoint, hung rendezvous, or NaN step.
 Four cooperating pieces:
 
 - **Fault injection** (``PADDLE_FAULT_SPEC``): raise controlled
-  ``InjectedFault`` errors at the compile / run / host-relay / collective /
+  ``InjectedFault`` errors at the compile / run / host_relay / collective /
   checkpoint-write / checkpoint-restore boundaries so every recovery path
   below is actually testable. Grammar (';'-separated clauses)::
 
@@ -27,7 +27,7 @@ Four cooperating pieces:
 - **Retry policy**: exponential backoff + full jitter + a wall-clock
   deadline, applied by the executor to transient compile/dispatch errors
   (RESOURCE_EXHAUSTED, UNAVAILABLE, connection resets — the TF-style
-  transient taxonomy) and by the distributed bootstrap to rendezvous.
+  transient classes) and by the distributed bootstrap to rendezvous.
   Knobs: ``PADDLE_RETRY_MAX_ATTEMPTS`` (default 4), ``PADDLE_RETRY_BASE_S``
   (0.05), ``PADDLE_RETRY_MAX_S`` (2.0), ``PADDLE_RETRY_DEADLINE_S`` (30).
 
@@ -251,12 +251,12 @@ class fault_spec(object):
 
 
 # ---------------------------------------------------------------------------
-# transient-error taxonomy + retry policy
+# transient-error classification + retry policy
 
 
 # substrings marking an error worth retrying: the XLA/gRPC status codes a
-# transient infrastructure failure surfaces as (TF's retry taxonomy), plus
-# socket-level connect noise from the relay/coordinator paths
+# transient infrastructure failure surfaces as (TF's retry classes), plus
+# socket-level connect noise from the coordinator paths
 _TRANSIENT_MARKERS = (
     'RESOURCE_EXHAUSTED', 'UNAVAILABLE', 'DEADLINE_EXCEEDED', 'ABORTED',
     'CANCELLED', 'connection reset', 'connection refused', 'broken pipe',

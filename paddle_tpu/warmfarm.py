@@ -1,8 +1,9 @@
 """Warmup farm: pre-compile a signature set once per process and share it.
 
 The compile-time tail is the serving fleet's cold-start tax (bert_base hit
-162 s in BENCH_r05, and the persistent on-disk cache is CPU-unsound —
-docs/executor_performance.md), so the lever is in-process AOT reuse:
+162 s in BENCH_r05). The persistent on-disk cache
+(docs/executor_performance.md) shortens it across processes; the lever
+inside one process is AOT reuse:
 ``Executor.precompile`` lowers + compiles an entry keyed by the SAME
 fingerprint cache ``run()`` uses, and this module keeps the process-wide
 ledger of which (program fingerprint, feed signature, fetch set, donate)

@@ -24,7 +24,6 @@ if '--xla_force_host_platform_device_count' not in flags:
         % _local).strip()
 
 import jax
-jax.config.update('jax_platforms', 'cpu')
 
 import numpy as np
 

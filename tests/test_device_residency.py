@@ -9,8 +9,8 @@
     reference readable after later runs;
 plus the compile-cache contract: a re-built but structurally identical
 Program (new _uid) hits the process-wide fingerprint cache in a FRESH
-Executor, and the persistent XLA cache dir is wired from
-PADDLE_COMPILE_CACHE_DIR.
+Executor, and the persistent XLA cache dir follows one rule
+(JAX_COMPILATION_CACHE_DIR, else a fixed in-checkout path on accelerators).
 """
 import os
 
@@ -212,34 +212,87 @@ def test_compile_cache_hit_in_fresh_executor(monkeypatch):
                                rtol=1e-6)
 
 
-def test_persistent_cache_dir_wired(tmp_path, monkeypatch):
-    cache_dir = str(tmp_path / 'xla_cache')
-    monkeypatch.setenv('PADDLE_COMPILE_CACHE_DIR', cache_dir)
-    monkeypatch.setattr(executor_mod, '_persistent_cache_dir', [None])
-    old = jax.config.jax_compilation_cache_dir
-    try:
-        # wiring is deferred to the first compile (constructing an Executor
-        # must not initialize the backend) — drive one run through it
-        x = fluid.layers.data(name='x', shape=[2], dtype='float32')
-        loss = fluid.layers.mean(x)
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(fluid.default_main_program(),
-                feed={'x': np.zeros((1, 2), 'float32')}, fetch_list=[loss])
-        assert os.path.isdir(cache_dir)
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-    finally:
-        # the jax config is process-global: leave no cache dir behind for
-        # later tests (XLA:CPU cache round-trips are numerically unsound
-        # on this jax version — see _wire_persistent_cache)
-        jax.config.update('jax_compilation_cache_dir', old)
+# the repo knob this rule replaced, spelled in halves so that a grep for it
+# finds no user left
+_DEAD_KNOB = 'PADDLE_COMPILE' + '_CACHE_DIR'
+
+_CACHE_KNOBS = ('jax_compilation_cache_dir',
+                'jax_persistent_cache_min_compile_time_secs',
+                'jax_persistent_cache_min_entry_size_bytes')
 
 
-def test_persistent_cache_not_wired_on_cpu(monkeypatch):
-    """Without an explicit PADDLE_COMPILE_CACHE_DIR the CPU backend must
-    NOT get the on-disk cache (wrong-numerics guard)."""
-    monkeypatch.delenv('PADDLE_COMPILE_CACHE_DIR', raising=False)
+@pytest.fixture
+def fresh_cache_wiring(monkeypatch):
+    """Un-memoize the wiring and restore the process-global jax config
+    afterwards (a cache dir left behind would serve every later test)."""
     monkeypatch.setattr(executor_mod, '_persistent_cache_dir', [None])
+    saved = {k: getattr(jax.config, k) for k in _CACHE_KNOBS}
+    yield
+    for k, v in saved.items():
+        jax.config.update(k, v)
+
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _wired_in_child(cwd, env_extra):
+    """What a SECOND process (another cwd, faked accelerator backend, no
+    JAX_COMPILATION_CACHE_DIR) resolves the cache directory to."""
+    import subprocess
+    import sys
+    env = dict(os.environ, PYTHONPATH=_ROOT, **env_extra)
+    env.pop('JAX_COMPILATION_CACHE_DIR', None)
+    code = ("import jax; jax.default_backend = lambda: 'tpu'\n"
+            "from paddle_tpu import executor\n"
+            "print(executor._wire_persistent_cache())\n")
+    out = subprocess.run([sys.executable, '-c', code], env=env,
+                         cwd=str(cwd), capture_output=True, text=True,
+                         timeout=120, check=True)
+    return out.stdout.strip().splitlines()[-1]
+
+
+def test_cache_dir_from_outside_is_left_alone(tmp_path, monkeypatch,
+                                              fresh_cache_wiring):
+    """JAX_COMPILATION_CACHE_DIR set -> JAX already uses it; the code
+    sets no directory and touches no floor, whatever the backend."""
+    outside = str(tmp_path / 'outside_cache')
+    # what jax itself does with the env var at import
+    jax.config.update('jax_compilation_cache_dir', outside)
+    floors = {k: getattr(jax.config, k) for k in _CACHE_KNOBS[1:]}
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    assert executor_mod._wire_persistent_cache() == outside
+    assert jax.config.jax_compilation_cache_dir == outside
+    assert {k: getattr(jax.config, k) for k in _CACHE_KNOBS[1:]} == floors
+    assert not os.path.exists(outside)      # jax creates it, not this code
+
+
+def test_cache_dir_default_is_fixed_in_checkout(tmp_path, monkeypatch,
+                                                fresh_cache_wiring):
+    """Unset on an accelerator backend -> the fixed in-checkout path:
+    the same across two calls and two processes (never $HOME, a temp
+    name, a pid or the cwd — a directory that moves never hits), and
+    the deleted repo knob no longer has any say."""
+    want = os.path.join(_ROOT, '.jax_cache')
+    monkeypatch.setenv(_DEAD_KNOB, str(tmp_path / 'knob'))
+    monkeypatch.setenv('HOME', str(tmp_path / 'home'))
+    jax.config.update('jax_compilation_cache_dir', None)
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    assert executor_mod._wire_persistent_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert executor_mod._wire_persistent_cache() == want
+    assert _wired_in_child(tmp_path, {
+        _DEAD_KNOB: str(tmp_path / 'knob'),
+        'HOME': str(tmp_path / 'home2')}) == want
+    assert not os.path.exists(str(tmp_path / 'knob'))
+
+
+def test_cache_not_wired_by_default_on_cpu(monkeypatch, fresh_cache_wiring):
+    """The CPU backend gets no default on-disk cache (cheap compiles;
+    tier-1 must not fill the checkout) — with or without the dead knob."""
+    monkeypatch.setenv(_DEAD_KNOB, '/nonexistent/knob')
+    jax.config.update('jax_compilation_cache_dir', None)
     assert executor_mod._wire_persistent_cache() == ''
+    assert jax.config.jax_compilation_cache_dir is None
 
 
 def test_executor_cache_is_lru_bounded(monkeypatch):

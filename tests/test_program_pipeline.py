@@ -221,12 +221,9 @@ def test_pipeline_composes_with_data_parallel():
     pipeline over its batch shard, grads psum over 'data' — the
     trajectory must still equal the serial run exactly.
 
-    @slow (ISSUE 11 budget shave, ~18 s): this is the DETERMINISTIC
-    pre-existing tier-1 failure (jit x manual-over-all shard_map
-    divergence, jax 0.4.37 — ROADMAP triage). The bug stays pinned in
-    tier-1 by the minimized strict xfail
-    test_gpipe_2axis_mesh_lowering_jit_matches_serial (~2 s) below;
-    burning 18 s re-demonstrating it every run bought nothing."""
+    @slow (ISSUE 11 budget shave, ~18 s): the minimized
+    test_gpipe_2axis_mesh_lowering_jit_matches_serial (~2 s) below keeps
+    the same jit x shard_map composition in tier-1."""
     from jax.sharding import PartitionSpec as P
     from paddle_tpu.parallel import make_mesh, MeshRunner
 
@@ -321,27 +318,18 @@ def _lowered_gpipe_fn(num_stages=4, hid=8, n_layer=4, seed=31):
 
 
 def test_gpipe_2axis_mesh_lowering_eager_is_exact():
-    """Control for the xfail below: the SAME lowered gpipe_run under the
-    SAME mesh(data=2, pipe=4), called eagerly (no surrounding jit), is
-    exact — the bug lives in the jit-of-manual-over-all-shard_map
-    interaction, not in the pipeline schedule itself."""
+    """Control for the jit test below: the SAME lowered gpipe_run under
+    the SAME mesh(data=2, pipe=4), called eagerly (no surrounding jit),
+    is exact."""
     call, ref = _lowered_gpipe_fn()
     got = call(lambda fn: fn)
     np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-7)
 
 
-@pytest.mark.xfail(
-    strict=True,
-    reason="gpipe-under-2-axis-mesh FORWARD divergence (ROADMAP open "
-           "item): jax.jit of a program whose gpipe_run lowers through "
-           "the manual-over-ALL shard_map fallback (jax 0.4.37, "
-           "check_rep=False) under a mesh carrying an unused-by-manual "
-           "'data' axis computes a wrong forward (~3.5x relerr on this "
-           "4-layer fc stack; eager call of the SAME fn is exact — see "
-           "the control test above). Deterministic; fix likely needs "
-           "manual-over-subset shard_map (jax upgrade) or replicating "
-           "the gpipe operands explicitly before entry.")
 def test_gpipe_2axis_mesh_lowering_jit_matches_serial():
+    """jax.jit of a program whose gpipe_run lowers through shard_map
+    (manual over {'pipe', 'data'}) under mesh(data=2, pipe=4) computes
+    the serial forward."""
     call, ref = _lowered_gpipe_fn()
     got = call(jax.jit)
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)

@@ -116,45 +116,3 @@ def test_zigzag_permutation_properties():
     half = 64 // 8
     shard0 = perm[:16]
     assert set(shard0.tolist()) == set(range(0, 8)) | set(range(56, 64))
-
-
-def test_shard_map_axis_names_fallback_warns_once():
-    """ADVICE r5: when axis_names is requested but this jax's shard_map
-    lacks it AND the fallback widens the manual set (mesh axes beyond the
-    request), a warning fires — ONCE — so silent wrong-grad territory is
-    visible. When the request already covers the mesh, no warning."""
-    import importlib
-    import warnings
-    # the package re-exports the ring_attention FUNCTION under the same
-    # name, so plain `import ... as ra` binds the function, not the module
-    ra = importlib.import_module('paddle_tpu.parallel.ring_attention')
-    from paddle_tpu.parallel import make_mesh
-    from jax.sharding import PartitionSpec as P
-
-    supported = ra.shard_map_supports_axis_names()
-    mesh = make_mesh([('data', 2), ('pipe', 4)])
-
-    # request covers the whole mesh: no semantic change, never warns
-    prev = ra._axis_names_warned[0]
-    ra._axis_names_warned[0] = False
-    try:
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter('always')
-            ra._shard_map(lambda x: x, mesh, (P(),), P(),
-                          axis_names={'data', 'pipe'})
-        assert not [x for x in w if 'axis_names' in str(x.message)]
-
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter('always')
-            ra._shard_map(lambda x: x, mesh, (P(),), P(),
-                          axis_names={'pipe'})
-            ra._shard_map(lambda x: x, mesh, (P(),), P(),
-                          axis_names={'pipe'})
-        hits = [x for x in w if 'axis_names' in str(x.message)]
-        if supported:
-            assert not hits
-        else:
-            assert len(hits) == 1        # once, not per call
-            assert 'manual-over-ALL' in str(hits[0].message)
-    finally:
-        ra._axis_names_warned[0] = prev

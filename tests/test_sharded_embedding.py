@@ -7,7 +7,6 @@ import os
 import tempfile
 
 import numpy as np
-import pytest
 import jax
 import jax.numpy as jnp
 
@@ -113,17 +112,12 @@ def test_distributed_embedding_big_vocab_compiles():
     assert l1 < l0          # sgd applied through the sharded scatter
 
 
-@pytest.mark.xfail(
-    strict=True,
-    reason="jax 0.4.37 XLA SPMD partitioner: scatter-add whose indices/"
-           "updates CONCAT batch-sharded vectors into a dim-0-sharded "
-           "operand misplaces shard-0 updates at stride-N rows and drops "
-           "the rest. core/lowering.py works around it by pinning the "
-           "concatenated SelectedRows rows/values replicated; when a jax "
-           "upgrade makes this test XPASS, the pin can be dropped.")
 def test_sharded_scatter_concat_partitioner():
-    """Minimized raw-jax repro of the bug behind the (formerly failing)
-    sharded-embedding trajectory divergence — no paddle_tpu machinery."""
+    """Raw-jax check, no paddle_tpu machinery: a scatter-add whose
+    indices/updates CONCAT batch-sharded vectors into a dim-0-sharded
+    operand partitions correctly (an older XLA SPMD partitioner misplaced
+    shard-0 updates; core/lowering.py still pins the concatenated
+    SelectedRows rows/values replicated — ROADMAP D1 drops that pin)."""
     from jax import lax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     vocab, dim, slots, batch = 64, 8, 4, 8

@@ -1,9 +1,9 @@
-"""Slope-timed (relay-constant-free) step rates for the conv bench rows.
+"""Slope-timed (per-call-constant-free) step rates for the conv bench rows.
 
 NOTE: the build recipe (model + AMP-decorated Momentum + staged feeds)
 mirrors bench.py _bench_image_model; if the bench measurement contract
 changes, update both or the slope numbers stop describing the same
-configuration the BASELINE.md tables compare against."""
+configuration the bench rows describe."""
 import json
 import os
 import sys

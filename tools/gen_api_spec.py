@@ -27,10 +27,8 @@ def _spec_of(fn):
 
 def iter_api():
     import jax
-    try:
-        jax.config.update('jax_platforms', 'cpu')
-    except Exception:
-        pass
+    # the spec needs no device: never take a chip for it
+    jax.config.update('jax_platforms', 'cpu')
     import paddle_tpu as fluid
 
     modules = [

@@ -276,7 +276,9 @@ def main():
             '--xla_force_host_platform_device_count' not in \
             os.environ.get('XLA_FLAGS', ''):
         # CLI convenience: a virtual multi-device CPU host (must happen
-        # before jax initializes; harmless when a real accelerator wins)
+        # before jax initializes). The flag only shapes the CPU backend:
+        # on a TPU host set JAX_PLATFORMS=cpu too, or this process takes
+        # the chip
         os.environ['XLA_FLAGS'] = (
             os.environ.get('XLA_FLAGS', '') +
             ' --xla_force_host_platform_device_count=%d'

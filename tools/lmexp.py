@@ -1,5 +1,5 @@
 """Flagship-LM fused-window experiments: donation off + window-size sweep
-(slope timing cancels the relay constant)."""
+(slope timing cancels the per-call constant)."""
 import json
 import os
 import sys

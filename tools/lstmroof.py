@@ -36,7 +36,7 @@ import numpy as np
 
 def _slope(fn, s1=20, s2=80, reps=3):
     # iteration counts must be large enough that (s2-s1)*per_iter >> the
-    # relay's ~0.5-1.5 s fetch jitter, or the slope measures noise
+    # fetch jitter, or the slope measures noise
     fn(s1)
     fn(s2)
     best = float('inf')

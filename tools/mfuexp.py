@@ -1,7 +1,7 @@
 """MFU frontier experiments (VERDICT r4 #4): lm_large and BERT variants,
-slope-timed ((t(S2)-t(S1))/(S2-S1)) so the relay constant cancels, plus a
+slope-timed ((t(S2)-t(S1))/(S2-S1)) so the per-call constant cancels, plus a
 pure-JAX probe of each model's exact GEMM mix that yields its
-shape-limited ceiling for the written BASELINE.md argument.
+shape-limited ceiling (ROADMAP S3 cites the per-GEMM rates it found).
 
 Usage:
   python tools/mfuexp.py gemm          # model-shape matmul rooflines

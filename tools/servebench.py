@@ -84,7 +84,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # Committed serving-row baseline (BENCH_r08, the PR 14-sentinel era
 # box): engine/sequential speedup 1.77. The r06/r07 0.84-0.85x readings
 # were TRIAGED as sequential-BASELINE drift, not an engine regression:
-# sequential_rps swings 3.7x across cpu_fallback rounds on identical
+# sequential_rps swings 3.7x across CPU-only rounds on identical
 # code (720 r08 / 1712 r07 / 1886 r06 / 2673 standalone 2026-08) while
 # the engine re-measures >= 1.6x standalone on the same tree, and the
 # ratio IMPROVES under both external CPU load (4.3x) and in-process GIL
