@@ -18,6 +18,7 @@ op's index, so replaying a segment inside the vjp closure sees identical
 randomness (dropout masks match between forward env and grad closure).
 """
 import contextlib
+import re
 import threading
 
 import numpy as np
@@ -649,7 +650,16 @@ def build_fn(program, fetch_names, read_names, written_names,
         new_state = {n: env[n] for n in written_names if n in env}
         return fetches, new_state
 
+    name_after(fn, program)
     return fn, ro_names, rw_names
+
+
+def name_after(fn, program, suffix=''):
+    """Name `fn` after the program it runs: jax.jit calls the XLA module
+    jit_<fn.__name__>, so a device trace's 'XLA Modules' line and every
+    op path read jit_lm_train, jit_lm_decode_step ... and not jit_fn."""
+    fn.__name__ = fn.__qualname__ = re.sub(
+        r'[^0-9A-Za-z_.-]', '_', program.name) + suffix
 
 
 def build_callable(program, fetch_names, read_names, written_names,

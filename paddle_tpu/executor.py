@@ -438,6 +438,20 @@ class scope_guard(object):
         _scope_stack.pop()
 
 
+def _run_phase(name):
+    """Phase `name` of Executor.run (monitor.phase): its self time into
+    executor_run_phase_seconds_total{phase=name}, and a
+    'paddle_tpu:run.<name>' span in a profiler session. prepare: feed
+    preparation, fingerprint and feed signature, cache lookup, state
+    gather, the run key, the flight recorder's step note; dispatch: the
+    compiled call; commit: the goodput hook, scope.update, LoD
+    propagation; fetch: materialising the fetches, the wait for the
+    device; compile: lowering and the first call of a new signature;
+    segmented: a PADDLE_SEGMENT_HOST_OPS run."""
+    return monitor.phase('run.' + name, 'executor_run_phase_seconds_total',
+                         {'phase': name})
+
+
 class _CompiledEntry(object):
     # holds a strong ref to the program so id(program) cache keys can never
     # alias a garbage-collected program's address
@@ -1126,212 +1140,249 @@ class Executor(object):
         monitor.observe('stage_seconds', stage_s)
         return fut
 
+    @staticmethod
+    def _host_splittable(program):
+        """PADDLE_SEGMENT_HOST_OPS=1: whether the program's host ops can
+        be split out into segments of their own. Memoized per program
+        version: the common (host-op-free) training step must not rescan
+        the op list every call."""
+        cached = getattr(program, '_host_split_cache', None)
+        if cached is None or cached[0] != program._version:
+            main_ops = program.global_block().ops
+            host_pos = [i for i, op in enumerate(main_ops)
+                        if op.type in _HOST_SEGMENT_OPS]
+            bwd_pos = [i for i, op in enumerate(main_ops)
+                       if op.type == 'backward']
+            # a host op inside a differentiated forward span cannot
+            # be split out (it would cut the jax.vjp closure) — those
+            # keep the callback path (py_func backward_func is itself
+            # a callback)
+            splittable = bool(host_pos) and (
+                not bwd_pos or min(host_pos) > max(bwd_pos))
+            cached = (program._version, splittable)
+            program._host_split_cache = cached
+        return cached[1]
+
+    def _build_entry(self, program, feed, fetch_names, static_lods,
+                     static_feed, donate):
+        """Lower the program for this signature (the jitted function
+        compiles inside its first call)."""
+        def _build():
+            resilience.maybe_fault('compile')
+            read, written = lowering.analyze_state(program, fetch_names)
+            # only require state read before being written this run
+            needed = self._read_before_write(program, read, written,
+                                             set(feed), fetch_names)
+            lod_out = {}
+            fn, ro_names, rw_names = lowering.build_callable(
+                program, fetch_names, needed, written,
+                static_lods=static_lods, static_feed=static_feed,
+                lod_out=lod_out, donate=donate)
+            return _CompiledEntry(fn, fetch_names, ro_names, rw_names,
+                                  written, program, lod_out)
+        try:
+            return _build()
+        except Exception as e:      # noqa: BLE001 — classified inside
+            return resilience.retry_after(e, _build, site='compile')
+
     def _run_impl(self, program, feed, fetch_list, scope, return_numpy,
                   use_program_cache, donate_override=None, _sync_out=None):
+        """One run, in phases (_run_phase): prepare, then dispatch (or
+        compile, on a signature's first run), commit, fetch."""
         if scope is None:
             scope = global_scope()
-        feed, fetch_names, static_feed, static_lods = \
-            self._prepare_run_inputs(program, feed, scope, fetch_list)
+        with _run_phase('prepare'):
+            feed, fetch_names, static_feed, static_lods = \
+                self._prepare_run_inputs(program, feed, scope, fetch_list)
 
-        if os.environ.get('PADDLE_SEGMENT_HOST_OPS') == '1':
-            # memoized per program version: the common (host-op-free)
-            # training step must not rescan the op list every call
-            cached = getattr(program, '_host_split_cache', None)
-            if cached is None or cached[0] != program._version:
-                main_ops = program.global_block().ops
-                host_pos = [i for i, op in enumerate(main_ops)
-                            if op.type in _HOST_SEGMENT_OPS]
-                bwd_pos = [i for i, op in enumerate(main_ops)
-                           if op.type == 'backward']
-                # a host op inside a differentiated forward span cannot
-                # be split out (it would cut the jax.vjp closure) — those
-                # keep the callback path (py_func backward_func is itself
-                # a callback)
-                splittable = bool(host_pos) and (
-                    not bwd_pos or min(host_pos) > max(bwd_pos))
-                cached = (program._version, splittable)
-                program._host_split_cache = cached
-            if cached[1]:
-                return self._run_segmented(
-                    program, feed, fetch_names, scope, return_numpy,
-                    static_lods, static_feed, donate_override)
+            if os.environ.get('PADDLE_SEGMENT_HOST_OPS') == '1' \
+                    and self._host_splittable(program):
+                with _run_phase('segmented'):
+                    return self._run_segmented(
+                        program, feed, fetch_names, scope, return_numpy,
+                        static_lods, static_feed, donate_override)
 
-        if donate_override is None and analysis.nan_localization_enabled():
+            if donate_override is None \
+                    and analysis.nan_localization_enabled():
+                from . import flags as _flags
+                if _flags.get_flags('check_nan_inf'):
+                    # the opt-in provenance replay re-runs this step
+                    # against the PRE-run state, so its buffers must
+                    # survive the call
+                    donate_override = False
+            donate = _donation_enabled(override=donate_override)
+            key = (program._fingerprint(),
+                   self._feed_signature(feed, static_lods, static_feed),
+                   tuple(fetch_names), donate)
+            entry = self._cache_get(key) if use_program_cache else None
+            fresh_compile = entry is None
+            if fresh_compile:
+                monitor.inc('compile_cache_miss' if use_program_cache
+                            else 'compile_cache_bypass')
+                t_compile = time.perf_counter()
+                # wired at first compile, not Executor construction:
+                # building an executor must stay free of backend
+                # initialization (io-only executors, launcher parents
+                # that must not claim the chip)
+                _wire_persistent_cache()
+                with _run_phase('compile'):
+                    entry = self._build_entry(program, feed, fetch_names,
+                                              static_lods, static_feed,
+                                              donate)
+                if use_program_cache:
+                    self._cache_put(key, entry)
+            else:
+                monitor.inc('compile_cache_hit')
+
+            ro_state, rw_state = {}, {}
+            for n in entry.ro_names:
+                ro_state[n] = self._state_value(scope, n, program)
+            for n in entry.rw_names:
+                rw_state[n] = self._state_value(scope, n, program,
+                                                cache=False)
+
+            self._run_counter += 1
+            key_arr = _run_key(program.random_seed,
+                               _next_program_run(program),
+                               self._run_counter)
+            # the step's PRNG key, kept for debug replays (TrainingGuard's
+            # NaN-provenance pass must reproduce the failed step's
+            # randomness)
+            program._last_run_key = key_arr
+            blackbox.note_step(program)
+        if fresh_compile:
+            with _run_phase('compile'):
+                # jax.jit is lazy: the XLA compile happens inside the
+                # FIRST call, so honest compile wall time spans lowering +
+                # that call. A transient XLA failure here
+                # (RESOURCE_EXHAUSTED) retries under the 'compile' site
+                # policy.
+                def _first_call():
+                    with monitor.span('compile'):
+                        return entry.fn(feed, ro_state, rw_state, key_arr)
+                try:
+                    fetches, new_state = _first_call()
+                except Exception as e:  # noqa: BLE001 — classified inside
+                    fetches, new_state = resilience.retry_after(
+                        e, _first_call, site='compile', state=rw_state)
+                monitor.observe('compile_seconds',
+                                time.perf_counter() - t_compile)
+                goodput.note_compile(key[0],
+                                     time.perf_counter() - t_compile)
+                # register the executable for XLA cost/memory analytics
+                # (lazy: mined when snapshot/explain/costreport first looks)
+                analysis.record_compiled(entry.fn, program,
+                                         (feed, ro_state, rw_state, key_arr),
+                                         kind='run', donate=donate)
+        else:
+            with _run_phase('dispatch'):
+                # steady-state dispatch: the success path pays one
+                # fault-site check and a try frame; retry machinery engages
+                # only after an exception actually escaped (and never with
+                # consumed donated buffers — resilience._buffers_alive
+                # guards the re-invoke)
+                def _dispatch():
+                    resilience.maybe_fault('run')
+                    return entry.fn(feed, ro_state, rw_state, key_arr)
+                t_disp = time.perf_counter()
+                try:
+                    fetches, new_state = _dispatch()
+                except Exception as e:  # noqa: BLE001 — classified inside
+                    fetches, new_state = resilience.retry_after(
+                        e, _dispatch, site='run', state=rw_state)
+                    t_disp = time.perf_counter()    # exclude retry backoff
+                t_staged = time.perf_counter()
+        with _run_phase('commit'):
+            if not fresh_compile:
+                # goodput accounting: fresh compiles land in the 'compile'
+                # loss bucket instead, keeping execute baselines clean
+                goodput.note_dispatch(key[0], 'run', t_disp, t_staged,
+                                      leaf=_goodput_leaf(new_state, fetches))
+            if os.environ.get('PADDLE_OPTEST_COLLECT_DIR'):
+                # TPU second-place validation (reference op_test.py:304
+                # check_output_with_place / the mkldnn-suite reuse pattern):
+                # record executed (program, feed, state, key, CPU fetches)
+                # cases for tools/tpu_optest.py to replay on the real chip
+                from .core.optest_collect import record_case
+                record_case(program, feed, static_lods, ro_state, rw_state,
+                            key_arr, fetch_names, fetches)
+            # rebind the scope BEFORE the nan-check can raise: with
+            # donation on, the pre-run rw buffers are already consumed, so
+            # bailing out here would leave the scope pointing at deleted
+            # arrays — a NaN state is at least readable/checkpointable for
+            # debugging
+            scope.update(new_state)
+            if _sync_out is not None and new_state:
+                # one state leaf as the async completion token: fetch-less
+                # steps still give StepFuture.wait something device-side to
+                # block on (the single device stream orders everything else
+                # behind it)
+                _sync_out.append(next(iter(new_state.values())))
             from . import flags as _flags
             if _flags.get_flags('check_nan_inf'):
-                # the opt-in provenance replay re-runs this step against
-                # the PRE-run state, so its buffers must survive the call
-                donate_override = False
-        donate = _donation_enabled(override=donate_override)
-        key = (program._fingerprint(),
-               self._feed_signature(feed, static_lods, static_feed),
-               tuple(fetch_names), donate)
-        entry = self._cache_get(key) if use_program_cache else None
-        fresh_compile = entry is None
-        if fresh_compile:
-            monitor.inc('compile_cache_miss' if use_program_cache
-                        else 'compile_cache_bypass')
-            t_compile = time.perf_counter()
-            # wired at first compile, not Executor construction: building an
-            # executor must stay free of backend initialization (io-only
-            # executors, launcher parents that must not claim the chip)
-            _wire_persistent_cache()
-
-            def _build():
-                resilience.maybe_fault('compile')
-                read, written = lowering.analyze_state(program, fetch_names)
-                # only require state read before being written this run
-                needed = self._read_before_write(program, read, written,
-                                                 set(feed), fetch_names)
-                lod_out = {}
-                fn, ro_names, rw_names = lowering.build_callable(
-                    program, fetch_names, needed, written,
-                    static_lods=static_lods, static_feed=static_feed,
-                    lod_out=lod_out, donate=donate)
-                return _CompiledEntry(fn, fetch_names, ro_names, rw_names,
-                                      written, program, lod_out)
-            try:
-                entry = _build()
-            except Exception as e:      # noqa: BLE001 — classified inside
-                entry = resilience.retry_after(e, _build, site='compile')
-            if use_program_cache:
-                self._cache_put(key, entry)
-        else:
-            monitor.inc('compile_cache_hit')
-
-        ro_state, rw_state = {}, {}
-        for n in entry.ro_names:
-            ro_state[n] = self._state_value(scope, n, program)
-        for n in entry.rw_names:
-            rw_state[n] = self._state_value(scope, n, program, cache=False)
-
-        self._run_counter += 1
-        key_arr = _run_key(program.random_seed, _next_program_run(program),
-                           self._run_counter)
-        # the step's PRNG key, kept for debug replays (TrainingGuard's
-        # NaN-provenance pass must reproduce the failed step's randomness)
-        program._last_run_key = key_arr
-        blackbox.note_step(program)
-        if fresh_compile:
-            # jax.jit is lazy: the XLA compile happens inside the FIRST
-            # call, so honest compile wall time spans lowering + that call.
-            # A transient XLA failure here (RESOURCE_EXHAUSTED) retries
-            # under the 'compile' site policy.
-            def _first_call():
-                with monitor.span('compile'):
-                    return entry.fn(feed, ro_state, rw_state, key_arr)
-            try:
-                fetches, new_state = _first_call()
-            except Exception as e:      # noqa: BLE001 — classified inside
-                fetches, new_state = resilience.retry_after(
-                    e, _first_call, site='compile', state=rw_state)
-            monitor.observe('compile_seconds',
-                            time.perf_counter() - t_compile)
-            goodput.note_compile(key[0], time.perf_counter() - t_compile)
-            # register the executable for XLA cost/memory analytics
-            # (lazy: mined when snapshot/explain/costreport first looks)
-            analysis.record_compiled(entry.fn, program,
-                                     (feed, ro_state, rw_state, key_arr),
-                                     kind='run', donate=donate)
-        else:
-            # steady-state dispatch: the success path pays one fault-site
-            # check and a try frame; retry machinery engages only after an
-            # exception actually escaped (and never with consumed donated
-            # buffers — resilience._buffers_alive guards the re-invoke)
-            def _dispatch():
-                resilience.maybe_fault('run')
-                return entry.fn(feed, ro_state, rw_state, key_arr)
-            t_disp = time.perf_counter()
-            try:
-                fetches, new_state = _dispatch()
-            except Exception as e:      # noqa: BLE001 — classified inside
-                fetches, new_state = resilience.retry_after(
-                    e, _dispatch, site='run', state=rw_state)
-                t_disp = time.perf_counter()    # exclude retry backoff
-            # goodput accounting: fresh compiles land in the 'compile'
-            # loss bucket instead, keeping execute baselines clean
-            goodput.note_dispatch(key[0], 'run', t_disp,
-                                  time.perf_counter(),
-                                  leaf=_goodput_leaf(new_state, fetches))
-        if os.environ.get('PADDLE_OPTEST_COLLECT_DIR'):
-            # TPU second-place validation (reference op_test.py:304
-            # check_output_with_place / the mkldnn-suite reuse pattern):
-            # record executed (program, feed, state, key, CPU fetches)
-            # cases for tools/tpu_optest.py to replay on the real chip
-            from .core.optest_collect import record_case
-            record_case(program, feed, static_lods, ro_state, rw_state,
-                        key_arr, fetch_names, fetches)
-        # rebind the scope BEFORE the nan-check can raise: with donation on,
-        # the pre-run rw buffers are already consumed, so bailing out here
-        # would leave the scope pointing at deleted arrays — a NaN state is
-        # at least readable/checkpointable for debugging
-        scope.update(new_state)
-        if _sync_out is not None and new_state:
-            # one state leaf as the async completion token: fetch-less
-            # steps still give StepFuture.wait something device-side to
-            # block on (the single device stream orders everything else
-            # behind it)
-            _sync_out.append(next(iter(new_state.values())))
-        from . import flags as _flags
-        if _flags.get_flags('check_nan_inf'):
-            try:
-                _check_nan_inf(new_state,
-                               dict(zip(entry.fetch_names, fetches)))
-            except RuntimeError as e:
-                # PADDLE_NAN_LOCALIZE=1: replay the step op-by-op against
-                # the still-alive pre-run state and name the first op
-                # that produced a non-finite value (no-op when disabled)
-                info = analysis.localize_nonfinite(
-                    program, feed, ro_state, rw_state, key_arr,
-                    static_lods, static_feed)
-                if info is not None:
-                    err = RuntimeError('%s; %s' % (
-                        e, analysis.format_localization(info)))
-                    # carried for TrainingGuard: the guard must reuse
-                    # this localization, not pay a second replay (and
-                    # double-count nonfinite_localized_total)
-                    err.nonfinite_localization = info
-                    raise err from None
-                raise
-        if _flags.get_flags('benchmark'):
-            # block on the new state too: timing only fetches under-measures
-            # steps whose outputs are all state writes (pure-train steps
-            # fetching just a scalar loss, or nothing at all). The synced
-            # wait lands in the executor_sync_seconds histogram — the
-            # device-completion tail FLAGS_benchmark exists to expose
-            t_sync = time.perf_counter()
-            jax.block_until_ready((fetches, new_state))
-            monitor.observe('executor_sync_seconds',
-                            time.perf_counter() - t_sync)
-        # checkpoint_notify (ops/dist_ops.py): the reference RPCs the
-        # checkpoint dir to pservers each execution; here the executor is
-        # the checkpoint writer, so save persistables after the run
-        for cn_dir in entry.notify_dirs:
-            from .io import save_persistables
-            with scope_guard(scope):
-                save_persistables(self, cn_dir, main_program=program)
-        # propagate LoD of written persistables into the scope, and of
-        # fetches into the returned tensors
-        for n in entry.written:
-            lod = entry.lod_out.get(n)
-            if lod:
-                scope._lods[n] = lod
-            else:
-                scope._lods.pop(n, None)
-        from .core.selected_rows import SelectedRows
-        fetches = [f.to_dense() if isinstance(f, SelectedRows) else f
-                   for f in fetches]  # fetched sparse grads densify, like
-        # the reference's fetch of a SelectedRows var materializing a tensor
+                try:
+                    _check_nan_inf(new_state,
+                                   dict(zip(entry.fetch_names, fetches)))
+                except RuntimeError as e:
+                    # PADDLE_NAN_LOCALIZE=1: replay the step op-by-op
+                    # against the still-alive pre-run state and name the
+                    # first op that produced a non-finite value (no-op when
+                    # disabled)
+                    info = analysis.localize_nonfinite(
+                        program, feed, ro_state, rw_state, key_arr,
+                        static_lods, static_feed)
+                    if info is not None:
+                        err = RuntimeError('%s; %s' % (
+                            e, analysis.format_localization(info)))
+                        # carried for TrainingGuard: the guard must reuse
+                        # this localization, not pay a second replay (and
+                        # double-count nonfinite_localized_total)
+                        err.nonfinite_localization = info
+                        raise err from None
+                    raise
+            if _flags.get_flags('benchmark'):
+                # block on the new state too: timing only fetches
+                # under-measures steps whose outputs are all state writes
+                # (pure-train steps fetching just a scalar loss, or nothing
+                # at all). The synced wait lands in the
+                # executor_sync_seconds histogram — the device-completion
+                # tail FLAGS_benchmark exists to expose
+                t_sync = time.perf_counter()
+                with _run_phase('fetch'):
+                    jax.block_until_ready((fetches, new_state))
+                monitor.observe('executor_sync_seconds',
+                                time.perf_counter() - t_sync)
+            # checkpoint_notify (ops/dist_ops.py): the reference RPCs the
+            # checkpoint dir to pservers each execution; here the executor
+            # is the checkpoint writer, so save persistables after the run
+            for cn_dir in entry.notify_dirs:
+                from .io import save_persistables
+                with scope_guard(scope):
+                    save_persistables(self, cn_dir, main_program=program)
+            # propagate LoD of written persistables into the scope, and of
+            # fetches into the returned tensors
+            for n in entry.written:
+                lod = entry.lod_out.get(n)
+                if lod:
+                    scope._lods[n] = lod
+                else:
+                    scope._lods.pop(n, None)
+            from .core.selected_rows import SelectedRows
+            # fetched sparse grads densify, like the reference's fetch of
+            # a SelectedRows var materializing a tensor
+            fetches = [f.to_dense() if isinstance(f, SelectedRows) else f
+                       for f in fetches]
         if return_numpy:
-            out = [
-                _fetched(f, entry.lod_out[n])
-                if entry.lod_out.get(n) else np.asarray(f)
-                for n, f in zip(entry.fetch_names, fetches)
-            ]
-            if out:
-                monitor.inc('fetch_host_bytes',
-                            sum(int(getattr(f, 'nbytes', 0)) for f in out))
-            return out
+            with _run_phase('fetch'):
+                out = [
+                    _fetched(f, entry.lod_out[n])
+                    if entry.lod_out.get(n) else np.asarray(f)
+                    for n, f in zip(entry.fetch_names, fetches)
+                ]
+                if out:
+                    monitor.inc('fetch_host_bytes', sum(
+                        int(getattr(f, 'nbytes', 0)) for f in out))
+                return out
         # return_numpy=False keeps fetches device-resident (no host sync);
         # only lod-carrying results are wrapped, since the LoD metadata is
         # the point of asking for them. Under async dispatch the wrap is
@@ -1787,6 +1838,7 @@ class Executor(object):
             # Donation default ON (see _donation_enabled): parameter updates
             # alias their input buffers instead of doubling peak HBM;
             # PADDLE_FUSED_DONATE / PADDLE_DONATE override.
+            lowering.name_after(fused, program, '_fused')
             jitted = jax.jit(fused, donate_argnums=(2,) if donate else ())
             entry = _CompiledEntry(jitted, fetch_names, ro_names, rw_names,
                                    written, program, {})

@@ -362,9 +362,16 @@ class Program(object):
     (reference framework.py:1877). clone()/prune() support transpilers,
     inference export, and test fixtures, exactly like the reference."""
 
-    def __init__(self):
+    DEFAULT_NAME = 'program'
+
+    def __init__(self, name=None):
         self.blocks = [Block(self, 0)]
         self.current_block_idx = 0
+        # what the compiled XLA module is called (jit_<name>): the name a
+        # device trace shows the program's operations under. Not part of
+        # _fingerprint(): two programs that differ in name alone share a
+        # compiled entry
+        self.name = name or self.DEFAULT_NAME
         self.random_seed = 0
         self._version = 0          # bumped on any mutation; keys compile cache
         # process-unique id for compile-cache keys: unlike id(self), never

@@ -12,6 +12,7 @@ from .. import layers
 # decode steps gather rows of the SAME sinusoid table the
 # add_position_encoding op applies during prefill — sharing the builder
 # keeps a token's embedding bit-identical on both paths (re-exported)
+from ..framework import default_main_program
 from ..ops.tensor_ops import position_encoding_table  # noqa: F401
 from ..param_attr import ParamAttr
 
@@ -171,10 +172,19 @@ def transformer_block(x, cfg, prefix, mask_var=None, is_test=False,
     return layers.elementwise_add(x, ff2)
 
 
+def _name_program(name):
+    """Name the program being built, unless whoever made it already has:
+    its compiled XLA module is then jit_<name> in a device trace."""
+    program = default_main_program()
+    if program.name == program.DEFAULT_NAME:
+        program.name = name
+
+
 def build_lm(cfg=None, is_test=False):
     """Causal LM: feeds {'tokens', 'labels'} of shape (B, L) int64; returns
     (tokens, labels, logits, avg_loss)."""
     cfg = cfg or LMConfig()
+    _name_program('lm_eval' if is_test else 'lm_train')
     tokens = layers.data(name='tokens', shape=[cfg.seq_len], dtype='int64')
     labels = layers.data(name='labels', shape=[cfg.seq_len], dtype='int64')
 
@@ -414,6 +424,7 @@ def build_lm_decode_step(cfg, slots, max_len, block_size=None,
     'gen_btab' [slots, max_len // block_size] int64 per-slot block
     tables. Returns {'tokens', 'pos', 'logits', 'next_tokens',
     'k_cache', 'v_cache'} — fetch 'next_tokens' ([slots] int64)."""
+    _name_program('lm_decode_step')
     paged = block_size is not None
     d, h = cfg.d_model, cfg.n_head
     dh = d // h
@@ -503,6 +514,7 @@ def build_lm_drafter(cfg, slots, max_len, spec_k, num_blocks, block_size):
     ``jnp.argmax`` the sample op's temperature-0 branch takes): a draft
     is a PROPOSAL, the target's verify step decides every emitted
     token, so draft sampling would only lower the accept rate."""
+    _name_program('lm_drafter')
     d, h = cfg.d_model, cfg.n_head
     dh = d // h
     mb = max_len // block_size
@@ -601,6 +613,7 @@ def build_lm_verify(cfg, slots, width, max_len, num_blocks, block_size):
     int64. Returns {'tokens', 'pos', 'block_table', 'vmask', 'logits'
     ([slots * width, vocab], row-major), 'verify_tokens'
     ([slots * width] int64, row-major), 'k_cache', 'v_cache'}."""
+    _name_program('lm_verify')
     d, h = cfg.d_model, cfg.n_head
     dh = d // h
     W = int(width)
@@ -672,6 +685,7 @@ def build_lm_prefill(cfg, prompt_len, slots, max_len):
     causal-masked out of the answer and overwritten by later decode
     steps). Returns {'prompt', 'slot', 'length', 'logits', 'first_token',
     'k_cache', 'v_cache'} — fetch 'first_token' ([1] int64)."""
+    _name_program('lm_prefill')
     if prompt_len > max_len:
         raise ValueError(
             "prompt bucket %d exceeds the KV cache width max_len=%d"
@@ -776,6 +790,7 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     write to the trash block), and the `SAMPLE_FEEDS` quad [1, 1].
     Returns {'prompt', 'positions', 'block_table', 'length', 'logits',
     'first_token', 'k_cache', 'v_cache'}."""
+    _name_program('lm_prefill_paged')
     d, h = cfg.d_model, cfg.n_head
     dh = d // h
     T = int(prompt_len)

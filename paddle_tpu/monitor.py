@@ -21,6 +21,11 @@ Spans: ``monitor.span(name)`` records into a bounded ring buffer
 — no session to start — so ``profiler.export_chrome_tracing`` can emit the
 executor's compile/run spans even when no explicit profiler session is
 active. The ring bound makes always-on safe for long-lived processes.
+While a jax profiler session is live every span is also a
+``jax.profiler.TraceAnnotation`` 'paddle_tpu:<name>', on the clock of the
+device trace. ``monitor.phase(name, counter, labels)`` is the hot paths'
+variant: self time into a seconds counter (what benchmark metrics read)
+plus the same annotation, and nothing on the ring.
 
 Label cardinality is capped per metric name (``PADDLE_MONITOR_MAX_SERIES``,
 default 64): overflowing label sets collapse into the reserved series
@@ -37,6 +42,7 @@ import itertools
 import json
 import math
 import os
+import sys
 import threading
 import time
 
@@ -200,14 +206,20 @@ def _capped_key(series, key):
     return _OVERFLOW_KEY
 
 
+def _inc_key(name, key, value):
+    with _lock:
+        series = _counters.get(name)
+        if series is None:
+            series = _counters[name] = {}
+        if key not in series:
+            key = _capped_key(series, key)
+        series[key] = series.get(key, 0.0) + value
+
+
 def inc(name, value=1.0, labels=None):
     """Add `value` (default 1) to counter `name`; labels: optional dict."""
-    key = _labels_key(labels)
-    value = float(value)    # numpy scalars must not poison JSON export
-    with _lock:
-        series = _counters.setdefault(name, {})
-        key = _capped_key(series, key)
-        series[key] = series.get(key, 0.0) + value
+    # float(): numpy scalars must not poison JSON export
+    _inc_key(name, _labels_key(labels), float(value))
 
 
 # Gauges whose value changes are ALSO recorded into the span ring as
@@ -297,6 +309,35 @@ if hasattr(os, 'register_at_fork'):
     os.register_at_fork(after_in_child=_refresh_pid)
 
 
+# what every span and phase of this repo is called in a profiler trace:
+# 'paddle_tpu:<name>'
+ANNOTATION_PREFIX = 'paddle_tpu:'
+
+# jax.profiler.TraceAnnotation, resolved on first use in a process that
+# already has jax: importing this module must not import jax (launcher
+# parents stay off it), and a process without jax cannot hold a profiler
+# session that an annotation could land in.
+_TraceAnnotation = None
+
+
+def _annotate(name):
+    """An entered TraceAnnotation `name` — a host event on the clock of
+    the profiler's device trace — while a profiler session is live, else
+    None (one is_enabled() read, ~0.1 us). The caller exits it."""
+    global _TraceAnnotation
+    ta = _TraceAnnotation
+    if ta is None:
+        profiler = getattr(sys.modules.get('jax'), 'profiler', None)
+        if profiler is None:
+            return None
+        ta = _TraceAnnotation = profiler.TraceAnnotation
+    if not ta.is_enabled():
+        return None
+    a = ta(name)
+    a.__enter__()
+    return a
+
+
 class _Span(object):
     """Plain __enter__/__exit__ object, not @contextmanager: the generator
     protocol costs ~2-3 us per span on the hot path for nothing. Each
@@ -307,9 +348,13 @@ class _Span(object):
     When a SAMPLED trace is bound to this thread (trace.activate), the
     span records trace_id/span_id/parent_id and becomes the parent of
     spans nested inside it — the causality export_chrome_tracing turns
-    into flow events. The no-trace fast path pays one thread-local read."""
+    into flow events. The no-trace fast path pays one thread-local read.
 
-    __slots__ = ('name', 'ts', 't0', '_tctx', '_sid')
+    While a profiler session is live the span is also a TraceAnnotation
+    'paddle_tpu:<name>', so it lands in the device trace, on the
+    profiler's clock (docs/observability.md "Reading a device trace")."""
+
+    __slots__ = ('name', 'ts', 't0', '_tctx', '_sid', '_ta')
 
     def __init__(self, name):
         self.name = name
@@ -332,11 +377,14 @@ class _Span(object):
             _trace_ctx[tid] = (ctx[0], self._sid)   # nested spans chain
         else:
             self._tctx = None
+        self._ta = _annotate(ANNOTATION_PREFIX + self.name)
         self.ts = time.time() * 1e6
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        if self._ta is not None:
+            self._ta.__exit__(None, None, None)
         tid = threading.get_ident()
         rec = {'name': self.name, 'ts': self.ts,
                'dur': (time.perf_counter() - self.t0) * 1e6,
@@ -418,6 +466,66 @@ def timed_span(name, histogram):
     `histogram`. Not exported via __all__ — an instrumentation-internal
     helper, not a stable public surface."""
     return _TimedSpan(name, histogram)
+
+
+_open_phase = {}        # thread id -> innermost open _Phase
+
+
+class _Phase(object):
+    """One phase of a hot path, written to both places a reader has: on
+    exit its SELF time (its duration less what phases nested inside it
+    took, so the phases of one thread add up to its wall time) is added to
+    a seconds counter, which is always on and is what per-layer benchmark
+    metrics read; while open it is a TraceAnnotation 'paddle_tpu:<name>'
+    in a live profiler session, where phases nest as they are. Nothing
+    goes to the span ring: a decode loop's phases would churn it in under
+    a minute. Single-use, like _Span."""
+
+    __slots__ = ('known', 't0', 'nested_s', '_outer', '_ta')
+
+    def __init__(self, known):
+        self.known = known     # the phase's entry in _phase_series
+
+    def __enter__(self):
+        tid = threading.get_ident()
+        self._outer = _open_phase.get(tid)
+        _open_phase[tid] = self
+        self.nested_s = 0.0
+        self._ta = _annotate(self.known[3])
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dur_s = time.perf_counter() - self.t0
+        if self._ta is not None:
+            self._ta.__exit__(None, None, None)
+        outer = self._outer
+        if outer is None:
+            del _open_phase[threading.get_ident()]
+        else:
+            _open_phase[threading.get_ident()] = outer
+            outer.nested_s += dur_s
+        _inc_key(self.known[0], self.known[2], dur_s - self.nested_s)
+        return False
+
+
+# phase name -> (counter, labels, series key, annotation name)
+_phase_series = {}
+
+
+def phase(name, counter, labels=None):
+    """Phase `name` of a hot path: seconds of self time into
+    `counter{labels}` always, a 'paddle_tpu:<name>' TraceAnnotation while
+    a profiler session is live. Like timed_span, an instrumentation
+    helper and not part of __all__."""
+    # a phase opens thousands of times a second with the same arguments:
+    # its series key and annotation name are made once per name
+    known = _phase_series.get(name)
+    if known is None or known[0] != counter or known[1] != labels:
+        known = _phase_series[name] = (
+            counter, dict(labels) if labels else None,
+            _labels_key(labels), ANNOTATION_PREFIX + name)
+    return _Phase(known)
 
 
 def spans():

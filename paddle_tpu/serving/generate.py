@@ -132,6 +132,29 @@ __all__ = ['GenerateConfig', 'GenerateEngine', 'GenerateRequest',
 
 _DONE = object()
 
+def _loop_phase(name):
+    """Phase `name` of the decode loop thread (monitor.phase): its self
+    time into generate_loop_seconds_total{phase=name}, and a
+    'paddle_tpu:generate.<name>' span in a profiler session. The phases
+    and what the device does meanwhile: docs/observability.md."""
+    return monitor.phase('generate.' + name, 'generate_loop_seconds_total',
+                         {'phase': name})
+
+
+def _loop_sums():
+    """The loop thread's counters as stats()['loop']: seconds by phase,
+    the wall seconds of its passes, and the queue wait of the requests
+    admitted. Process-wide, like the counters they are read from."""
+    flat = monitor.counters()
+    prefix = 'generate_loop_seconds_total{phase='
+    return {
+        'phase_s': {k[len(prefix):-1]: v for k, v in flat.items()
+                    if k.startswith(prefix)},
+        'wall_s': flat.get('generate_loop_wall_seconds_total', 0.0),
+        'queue_wait_s': flat.get('generate_queue_wait_seconds_total', 0.0),
+        'admitted': flat.get('generate_admit_total', 0),
+    }
+
 
 def _sampling_stream(sample_seed):
     """One request's private sampling PRNG: a pinned seed replays the
@@ -541,7 +564,11 @@ class GenerateEngine(object):
                     num_blocks=c.num_blocks)
         self._prefill = {}
         for b in c.prompt_buckets:
-            main, start = Program(), Program()
+            # a bucket's prefill is a program of its own: its name says
+            # which, so a device trace tells the buckets apart
+            main = Program('lm_prefill%s_b%d'
+                           % ('_paged' if c.paged else '', b))
+            start = Program()
             main.random_seed = c.seed
             with program_guard(main, start):
                 with unique_name.guard():
@@ -577,7 +604,8 @@ class GenerateEngine(object):
                 # a distinct draft prefills for real; the target-copy
                 # fast path block-copies instead and never runs these
                 for b in c.prompt_buckets:
-                    main, start = Program(), Program()
+                    main, start = Program('lm_draft_prefill_paged_b%d'
+                                          % b), Program()
                     main.random_seed = c.seed
                     with program_guard(main, start):
                         with unique_name.guard():
@@ -1106,29 +1134,46 @@ class GenerateEngine(object):
     # ------------------------------------------------------------------
     # decode loop
     def _loop(self):
+        """The decode loop. Every stretch of a pass is a phase
+        (_loop_phase): its self time goes to
+        generate_loop_seconds_total{phase=...} and, in a profiler
+        session, it is a 'paddle_tpu:generate.<phase>' span on the device
+        trace's clock, so a device idle gap has a name."""
         poll = self.config.idle_poll_s
+        done = None     # the last completed step: (outputs, slots, t0)
+        t_pass = time.perf_counter()
         while not self._stop_evt.is_set():
-            self._evict_expired()
-            self._admit()
+            # the wall time of the pass just ended, beside the phases'
+            # self times: what no phase covers is then itself a number
+            now = time.perf_counter()
+            monitor.inc('generate_loop_wall_seconds_total', now - t_pass)
+            t_pass = now
+            with _loop_phase('admit'):
+                self._evict_expired()
+                self._admit()
             if not any(s is not None for s in self._slots):
-                if self._pending_admit is not None:
-                    # parked for blocks with nothing resident: _admit()
-                    # retries it at the top of every loop pass (it can
-                    # only be reachable transiently — with no residents
-                    # the prefix cache is fully evictable)
-                    time.sleep(poll)
-                    continue
-                # idle: block briefly for new work instead of spinning
-                batch, expired = self.queue.take_batch(1, 0.0,
-                                                       poll_s=poll)
-                self._fail_expired(expired)
-                if batch:
-                    self._admit_one(batch[0])
-                monitor.set_gauge('generate_queue_depth',
-                                  self.queue.depth())
+                with _loop_phase('idle'):
+                    if self._pending_admit is not None:
+                        # parked for blocks with nothing resident:
+                        # _admit() retries it at the top of every loop
+                        # pass (it can only be reachable transiently —
+                        # with no residents the prefix cache is fully
+                        # evictable)
+                        time.sleep(poll)
+                        continue
+                    # idle: block briefly for new work instead of spinning
+                    batch, expired = self.queue.take_batch(1, 0.0,
+                                                           poll_s=poll)
+                    self._fail_expired(expired)
+                    if batch:
+                        with _loop_phase('admit'):
+                            self._admit_one(batch[0])
+                    monitor.set_gauge('generate_queue_depth',
+                                      self.queue.depth())
                 continue
             if self._spec_ready():
-                self._spec_round()
+                with _loop_phase('spec_round'):
+                    self._spec_round()
                 continue
             if self.config.speculative:
                 # a sampled resident pins the whole batch on plain
@@ -1143,13 +1188,25 @@ class GenerateEngine(object):
                 # Eviction stays OUT of this window — releasing a slot
                 # the in-flight step's snapshot references would let a
                 # new tenant double-book it before completion lands.
-                t_adm = time.perf_counter()
-                self._admit()
-                # admission time is observed as prefill_seconds already;
-                # exclude it so decode_step_seconds stays a per-token
-                # signal instead of double-counting the overlap window
-                self._step_complete(pending,
-                                    exclude_s=time.perf_counter() - t_adm)
+                with _loop_phase('admit_overlapped'):
+                    # the LAST step's fetched outputs are freed here,
+                    # behind the device's work. Freed between two steps
+                    # instead (0.3-0.5 ms, and the client threads take
+                    # the GIL there) a finished client's next request
+                    # is admitted at the top of the loop, not in this
+                    # window: another schedule (PERF.md, PR 25)
+                    done = None
+                    t_adm = time.perf_counter()
+                    self._admit()
+                    # admission time is observed as prefill_seconds
+                    # already; exclude it so decode_step_seconds stays a
+                    # per-token signal instead of double-counting the
+                    # overlap window
+                    exclude_s = time.perf_counter() - t_adm
+                self._step_complete(pending, exclude_s=exclude_s)
+                done, pending = pending, None   # noqa: F841 — held, above
+        monitor.inc('generate_loop_wall_seconds_total',
+                    time.perf_counter() - t_pass)      # the last pass
         # shutdown: a resident generation must not leave its caller
         # blocked forever
         for i, st in enumerate(self._slots):
@@ -1252,6 +1309,8 @@ class GenerateEngine(object):
         # queue wait as a histogram (the goodput 'queue' loss bucket
         # reads its sum) + the queue-SLO burn sentinel feed
         monitor.observe('generate_queue_seconds', qs)
+        monitor.inc('generate_queue_wait_seconds_total', qs)
+        monitor.inc('generate_admit_total')
         goodput.note_queue_wait(qs)
         if req.trace is not None:
             # queue stage closes at admission; the span rides the
@@ -1263,38 +1322,39 @@ class GenerateEngine(object):
         t0 = time.perf_counter()
         pf_wall = time.time() * 1e6
         dblocks, dtable = None, None
-        try:
-            first = self._run_prefill(
-                slot, req.prompt,
-                (req.temperature, req.top_k, req.top_p, req._draw_u()),
-                table=table, ctx_len=ctx_len)
-            if c.speculative:
-                # the draft tracks the request in its OWN pool: full
-                # prompt (no prefix cache — draft K/V are model-specific
-                # throwaways), chunked exactly like the target's. With
-                # draft == target the prompt rows are block-copied from
-                # the target pool instead of recomputed.
-                dblocks = self._draft_alloc.alloc(
-                    -(-req.prompt.size // c.block_size))
-                if dblocks is None:     # unreachable by pool sizing
-                    raise RuntimeError("draft KV pool exhausted")
-                dtable = self._slot_table(dblocks)
-                if self._draft_copies_target:
-                    self._draft_cache_sync(dblocks, blocks)
-                else:
-                    self._run_prefill(slot, req.prompt, table=dtable,
-                                      ctx_len=0,
-                                      bound=self._draft_prefill_bound)
-        except Exception as e:  # noqa: BLE001 — delivered per-request
-            self._free.append(slot)
-            if blocks:
-                self._deref_blocks(blocks)
-            if dblocks:
-                self._draft_alloc.deref_many(dblocks)
-            monitor.inc('generate_request_total',
-                        labels={'outcome': 'error'})
-            req.fail(e)
-            return True
+        with _loop_phase('prefill'):
+            try:
+                first = self._run_prefill(
+                    slot, req.prompt,
+                    (req.temperature, req.top_k, req.top_p, req._draw_u()),
+                    table=table, ctx_len=ctx_len)
+                if c.speculative:
+                    # the draft tracks the request in its OWN pool: full
+                    # prompt (no prefix cache — draft K/V are
+                    # model-specific throwaways), chunked exactly like the
+                    # target's. With draft == target the prompt rows are
+                    # block-copied from the target pool, not recomputed.
+                    dblocks = self._draft_alloc.alloc(
+                        -(-req.prompt.size // c.block_size))
+                    if dblocks is None:     # unreachable by pool sizing
+                        raise RuntimeError("draft KV pool exhausted")
+                    dtable = self._slot_table(dblocks)
+                    if self._draft_copies_target:
+                        self._draft_cache_sync(dblocks, blocks)
+                    else:
+                        self._run_prefill(slot, req.prompt, table=dtable,
+                                          ctx_len=0,
+                                          bound=self._draft_prefill_bound)
+            except Exception as e:  # noqa: BLE001 — delivered per-request
+                self._free.append(slot)
+                if blocks:
+                    self._deref_blocks(blocks)
+                if dblocks:
+                    self._draft_alloc.deref_many(dblocks)
+                monitor.inc('generate_request_total',
+                            labels={'outcome': 'error'})
+                req.fail(e)
+                return True
         if c.paged and self._prefix is not None:
             # publish this prompt's FULL blocks (immutable once
             # prefilled: decode writes land strictly past the prompt)
@@ -1596,9 +1656,10 @@ class GenerateEngine(object):
             self._fail_step(active, e)
             return
         # overlap: admit queued prompts while the verify computes
-        t_adm = time.perf_counter()
-        self._admit()
-        adm_s = time.perf_counter() - t_adm
+        with _loop_phase('admit_overlapped'):
+            t_adm = time.perf_counter()
+            self._admit()
+            adm_s = time.perf_counter() - t_adm
         try:
             verdict = np.asarray(out[0]).reshape(S, W)
         except Exception as e:  # noqa: BLE001 — delivered per-request
@@ -1688,40 +1749,42 @@ class GenerateEngine(object):
         WITHOUT materializing its next-token fetch — JAX's async
         dispatch returns as soon as the step is staged, so the caller
         can do host work (admission) while the device computes."""
-        c = self.config
-        if c.paged:
-            self._grow_blocks()
-        S = c.slots
-        toks = np.zeros((S, 1), 'int64')
-        pos = np.zeros((S, 1), 'int64')
-        sample = self._sample_feed(S)
-        btab = np.zeros((S, self._max_blocks), 'int64') if c.paged \
-            else None
-        active = []
-        for i, st in enumerate(self._slots):
-            if st is None:
-                continue
-            toks[i], pos[i] = st.last, st.pos
-            r = st.req
-            sample['gen_temp'][i] = r.temperature
-            sample['gen_topk'][i] = r.top_k
-            sample['gen_topp'][i] = r.top_p
-            sample['gen_u'][i] = r._draw_u()
+        with _loop_phase('feed'):
+            c = self.config
+            if c.paged:
+                self._grow_blocks()
+            S = c.slots
+            toks = np.zeros((S, 1), 'int64')
+            pos = np.zeros((S, 1), 'int64')
+            sample = self._sample_feed(S)
+            btab = np.zeros((S, self._max_blocks), 'int64') if c.paged \
+                else None
+            active = []
+            for i, st in enumerate(self._slots):
+                if st is None:
+                    continue
+                toks[i], pos[i] = st.last, st.pos
+                r = st.req
+                sample['gen_temp'][i] = r.temperature
+                sample['gen_topk'][i] = r.top_k
+                sample['gen_topp'][i] = r.top_p
+                sample['gen_u'][i] = r._draw_u()
+                if btab is not None:
+                    btab[i] = st.table
+                active.append((i, st))
+            if not active:
+                return None
+            feed = {'gen_tokens': toks, 'gen_pos': pos}
             if btab is not None:
-                btab[i] = st.table
-            active.append((i, st))
-        if not active:
-            return None
-        feed = {'gen_tokens': toks, 'gen_pos': pos}
-        if btab is not None:
-            feed['gen_btab'] = btab
-        feed.update(sample)
-        t0 = time.perf_counter()
-        try:
-            out = self._step_bound(feed, return_numpy=False)
-        except Exception as e:  # noqa: BLE001 — delivered per-request
-            self._fail_step(active, e)
-            return None
+                feed['gen_btab'] = btab
+            feed.update(sample)
+        with _loop_phase('dispatch'):
+            t0 = time.perf_counter()
+            try:
+                out = self._step_bound(feed, return_numpy=False)
+            except Exception as e:  # noqa: BLE001 — delivered per-request
+                self._fail_step(active, e)
+                return None
         return (out, active, t0)
 
     def _fail_step(self, active, e):
@@ -1744,12 +1807,20 @@ class GenerateEngine(object):
         try:
             # materialization = device completion; an async runtime
             # failure surfaces here and fails the step's residents
-            nxt = np.asarray(out[0]).reshape(-1)
+            with _loop_phase('wait'):
+                nxt = np.asarray(out[0]).reshape(-1)
+                monitor.observe(
+                    'decode_step_seconds',
+                    max(0.0, time.perf_counter() - t0 - exclude_s))
         except Exception as e:  # noqa: BLE001 — delivered per-request
             self._fail_step(active, e)
             return
-        monitor.observe('decode_step_seconds',
-                        max(0.0, time.perf_counter() - t0 - exclude_s))
+        with _loop_phase('deliver'):
+            self._deliver(active, nxt)
+
+    def _deliver(self, active, nxt):
+        """The host's share of a completed step: per-slot bookkeeping,
+        the tokens out to their streams, finished slots released."""
         now = time.perf_counter()
         n = len(active)
         self._decode_steps += 1
@@ -1846,7 +1917,8 @@ class GenerateEngine(object):
         """Decode-loop statistics since construction. Paged engines add
         the block-level capacity accounting under 'blocks' — physical
         pool state, the peak footprint, and the prefix-cache entry
-        count (the monitor mirrors it as kv_blocks_in_use/free)."""
+        count (the monitor mirrors it as kv_blocks_in_use/free). 'loop'
+        is where the loop thread's time went, by phase (_loop_sums)."""
         steps = self._decode_steps
         out = {
             'slots': self.config.slots,
@@ -1882,6 +1954,7 @@ class GenerateEngine(object):
                 if prop else 0.0,
                 'draft_blocks_in_use': self._draft_alloc.in_use(),
             }
+        out['loop'] = _loop_sums()
         out['goodput'] = goodput.stats(fps=self._goodput_fp_set())
         return out
 

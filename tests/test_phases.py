@@ -1,0 +1,307 @@
+"""monitor.phase and what is built on it: the decode loop's and
+Executor.run's phase counters add up to the wall time they split, spans
+and phases land in a profiler trace as `paddle_tpu:<name>`, compiled
+programs carry their program's name, and a phase costs little with no
+profiler session."""
+import gc
+import glob
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import monitor
+from paddle_tpu.core import lowering
+from paddle_tpu.models.transformer import LMConfig, build_lm_decode_step
+from paddle_tpu.serving import GenerateConfig, GenerateEngine
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LOOP = 'generate_loop_seconds_total'
+RUN = 'executor_run_phase_seconds_total'
+
+
+def _engine():
+    # wide enough that a decode step takes the CPU a few milliseconds:
+    # the microseconds between two phases are then well under the 5 %
+    return GenerateEngine(GenerateConfig(
+        model=LMConfig(vocab_size=64, seq_len=32, d_model=256, n_head=4,
+                       n_layer=4, d_ff=1024, dropout=0.0, attn_dropout=0.0,
+                       use_flash_attention=False),
+        slots=4, max_len=48, prompt_buckets=[8, 16], eos_id=None, seed=0))
+
+
+def _prompt(n, seed):
+    return np.random.RandomState(seed).randint(2, 64, size=n) \
+        .astype('int64')
+
+
+def _phases(delta, counter):
+    prefix = counter + '{phase='
+    return {k[len(prefix):-1]: v for k, v in delta.items()
+            if k.startswith(prefix)}
+
+
+def _hist_sum(name):
+    return monitor.snapshot()['histograms'].get(name, {}).get('sum', 0.0)
+
+
+# ---------------------------------------------------------------------------
+# the primitive
+
+
+def test_a_nested_phase_is_taken_out_of_its_parents_self_time():
+    before = monitor.counters()
+    with monitor.phase('t.outer', 't_phase_seconds_total', {'phase': 'o'}):
+        time.sleep(0.02)
+        with monitor.phase('t.inner', 't_phase_seconds_total',
+                           {'phase': 'i'}):
+            time.sleep(0.03)
+    got = _phases(monitor.counter_delta(before), 't_phase_seconds_total')
+    assert got['i'] == pytest.approx(0.03, abs=0.008)
+    assert got['o'] == pytest.approx(0.02, abs=0.008)
+    assert 't.outer' not in [s['name'] for s in monitor.spans()]
+
+
+def test_a_phase_counts_also_when_its_body_raises():
+    before = monitor.counters()
+    with pytest.raises(KeyError):
+        with monitor.phase('t.raises', 't_raises_seconds_total'):
+            time.sleep(0.005)
+            raise KeyError('x')
+    assert monitor.counter_delta(before)['t_raises_seconds_total'] >= 0.005
+    assert not monitor._open_phase      # nothing left open on this thread
+
+
+def test_monitor_alone_imports_no_jax():
+    """`import paddle_tpu` brings jax in through the package's own
+    __init__; the module itself must not, spans and phases included, so a
+    parent that loads it alone stays off jax."""
+    code = (
+        "import importlib.util, sys\n"
+        "spec = importlib.util.spec_from_file_location('mon', %r)\n"
+        "m = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(m)\n"
+        "with m.span('s'):\n"
+        "    with m.phase('p', 'p_seconds_total', {'phase': 'p'}):\n"
+        "        pass\n"
+        "assert m.counters()['p_seconds_total{phase=p}'] >= 0\n"
+        "assert 'jax' not in sys.modules, 'monitor imported jax'\n"
+        % os.path.join(REPO, 'paddle_tpu', 'monitor.py'))
+    done = subprocess.run([sys.executable, '-c', code], timeout=120,
+                          capture_output=True, text=True)
+    assert done.returncode == 0, done.stderr
+
+
+# ---------------------------------------------------------------------------
+# the decode loop
+
+
+def test_loop_phases_add_up_to_the_loops_wall_time():
+    eng = _engine()
+    eng.warmup()
+    work = [(_prompt(4, 1), 9), (_prompt(7, 2), 14), (_prompt(12, 3), 6),
+            (_prompt(16, 4), 11), (_prompt(5, 5), 8), (_prompt(9, 6), 13)]
+    before = monitor.counters()
+    prefill0 = _hist_sum('prefill_seconds')
+    with eng:
+        reqs = [eng.submit(p, max_new_tokens=n) for p, n in work]
+        for r, (_p, n) in zip(reqs, work):
+            assert len(r.result(timeout=60)) == n
+    delta = monitor.counter_delta(before)
+    phases = _phases(delta, LOOP)
+    wall = delta['generate_loop_wall_seconds_total']
+    assert {'admit', 'prefill', 'feed', 'dispatch', 'admit_overlapped',
+            'wait', 'deliver', 'idle'} <= set(phases)
+    # self times: nothing counts twice, and little is left uncovered
+    assert sum(phases.values()) <= wall * 1.001
+    assert sum(phases.values()) >= wall * 0.95
+    # `admit` holds admissions without their prefills: those are `prefill`,
+    # the stretch prefill_seconds times
+    prefill_s = _hist_sum('prefill_seconds') - prefill0
+    assert phases['prefill'] == pytest.approx(prefill_s, rel=0.2)
+    assert phases['admit'] + phases['admit_overlapped'] < wall - prefill_s
+    assert delta['generate_admit_total'] == len(work)
+    assert delta['generate_queue_wait_seconds_total'] > 0
+    loop = eng.stats()['loop']
+    flat = monitor.counters()
+    assert loop['admitted'] == flat['generate_admit_total']
+    assert loop['wall_s'] == flat['generate_loop_wall_seconds_total']
+    assert loop['phase_s']['feed'] == flat[LOOP + '{phase=feed}']
+
+
+# ---------------------------------------------------------------------------
+# Executor.run
+
+
+def _mlp():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name='x', shape=[256], dtype='float32')
+        h = x
+        for _ in range(4):
+            h = fluid.layers.fc(h, size=256, act='relu')
+        loss = fluid.layers.mean(h)
+        fluid.optimizer.SGD(0.01).minimize(loss)
+    return main, startup, loss
+
+
+def test_run_phases_add_up_to_executor_run_seconds():
+    main, startup, loss = _mlp()
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    feed = {'x': np.ones((64, 256), 'float32')}
+    exe.run(main, feed=feed, fetch_list=[loss], scope=scope)    # compiles
+    before, run0 = monitor.counters(), _hist_sum('executor_run_seconds')
+    for _ in range(50):
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    delta = monitor.counter_delta(before)
+    phases = _phases(delta, RUN)
+    assert set(phases) == {'prepare', 'dispatch', 'commit', 'fetch'}
+    assert delta['executor_run_total'] == 50
+    run_s = _hist_sum('executor_run_seconds') - run0
+    assert sum(phases.values()) == pytest.approx(run_s, rel=0.10)
+
+
+def test_a_first_run_is_the_compile_phase():
+    main, startup, loss = _mlp()
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    before = monitor.counters()
+    exe.run(main, feed={'x': np.ones((3, 256), 'float32')},
+            fetch_list=[loss], scope=scope)
+    phases = _phases(monitor.counter_delta(before), RUN)
+    assert 'dispatch' not in phases
+    assert phases['compile'] > phases['prepare']
+
+
+# ---------------------------------------------------------------------------
+# names
+
+
+def test_a_program_lowers_to_a_module_of_its_name():
+    import jax
+
+    def build(name=None):
+        prog = fluid.Program(name)
+        with fluid.program_guard(prog, fluid.Program()):
+            with fluid.unique_name.guard():
+                x = fluid.layers.data(name='x', shape=[4], dtype='float32')
+                y = fluid.layers.scale(x, scale=2.0)
+        return prog, y
+
+    named, y = build('lm_decode_step')
+    plain, _ = build()
+    assert plain.name == 'program'
+    assert named._fingerprint() == plain._fingerprint()
+    assert named.clone().name == 'lm_decode_step'
+    fn, _ro, _rw = lowering.build_callable(named, [y.name], [], [])
+    text = fn.lower({'x': np.ones((2, 4), 'float32')}, {}, {},
+                    jax.random.PRNGKey(0)).as_text()
+    assert 'module @jit_lm_decode_step ' in text
+
+
+def test_builders_and_the_engine_name_their_programs():
+    prog = fluid.Program()
+    with fluid.program_guard(prog, fluid.Program()):
+        with fluid.unique_name.guard():
+            build_lm_decode_step(LMConfig(
+                vocab_size=64, seq_len=32, d_model=32, n_head=2, n_layer=1,
+                d_ff=64, dropout=0.0), 2, 16)
+    assert prog.name == 'lm_decode_step'
+    eng = _engine()
+    assert eng._step_prog.name == 'lm_decode_step'
+    assert {b: p.name for b, (p, _v) in eng._prefill.items()} == \
+        {8: 'lm_prefill_b8', 16: 'lm_prefill_b16'}
+
+
+# ---------------------------------------------------------------------------
+# the profiler's trace
+
+
+def test_spans_and_phases_land_in_the_profiler_trace(tmp_path):
+    import jax
+    main, startup, loss = _mlp()
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    feed = {'x': np.ones((8, 256), 'float32')}
+    exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    eng = _engine()
+    eng.warmup()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+        with eng:
+            assert len(eng.submit(_prompt(5, 1), max_new_tokens=4)
+                       .result(timeout=60)) == 4
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / 'plugins' / 'profile' / '*'
+                          / '*.xplane.pb'))
+    names = {e.name
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             if plane.name.startswith('/host:')
+             for line in plane.lines for e in line.events
+             if e.name.startswith(monitor.ANNOTATION_PREFIX)}
+    assert {'paddle_tpu:run', 'paddle_tpu:run.prepare',
+            'paddle_tpu:run.dispatch', 'paddle_tpu:run.commit',
+            'paddle_tpu:run.fetch', 'paddle_tpu:generate.admit',
+            'paddle_tpu:generate.prefill', 'paddle_tpu:generate.feed',
+            'paddle_tpu:generate.dispatch', 'paddle_tpu:generate.wait',
+            'paddle_tpu:generate.deliver'} <= names
+
+
+# ---------------------------------------------------------------------------
+# cost with no profiler session
+
+
+def _best_call_us(hook, n=6000, rounds=5):
+    """Min of per-call timings over interleaved rounds, gc off: the
+    method of the repo's other overhead guards (a preempted timeslice
+    poisons a block average, but only one call)."""
+    pc, best = time.perf_counter, float('inf')
+    gc.disable()
+    try:
+        for _ in range(rounds):
+            for _ in range(n):
+                t0 = pc()
+                hook()
+                dt = pc() - t0
+                if dt < best:
+                    best = dt
+    finally:
+        gc.enable()
+    return best * 1e6
+
+
+def test_phase_overhead_within_the_step_and_run_budgets():
+    """What the phases add with no profiler session: at most 30 us a
+    decode step (its seven phases and the pass's wall counter) and 10 us
+    an Executor.run (its four)."""
+    import jax
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    from paddle_tpu.executor import _run_phase
+    from paddle_tpu.serving.generate import _loop_phase
+
+    def step():
+        for name in ('admit', 'feed', 'dispatch', 'admit_overlapped',
+                     'wait', 'deliver'):
+            with _loop_phase(name):
+                pass
+        monitor.inc('generate_loop_wall_seconds_total', 0.0)
+
+    def run():
+        for name in ('prepare', 'dispatch', 'commit', 'fetch'):
+            with _run_phase(name):
+                pass
+
+    spans_before = monitor.span_seq()
+    step_us, run_us = _best_call_us(step), _best_call_us(run)
+    assert step_us <= 30.0, 'phases of a decode step: %.1f us' % step_us
+    assert run_us <= 10.0, 'phases of an Executor.run: %.1f us' % run_us
+    assert monitor.span_seq() == spans_before   # nothing went to the ring

@@ -32,13 +32,14 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # monitor.inc('name'...) / monitor.observe('name'...) /
 # monitor.set_gauge('name'...), first argument a string literal —
 # possibly on the next line after the open paren. timed_span's SECOND
-# argument is the histogram series it observes into; executor.py's
-# _count() is a thin monitor.inc wrapper (the donation ledger).
+# argument is the histogram series it observes into, phase's the seconds
+# counter it adds to; executor.py's _count() is a thin monitor.inc
+# wrapper (the donation ledger).
 _CALL_RE = re.compile(
     r"monitor\.(inc|observe|set_gauge)\(\s*'([A-Za-z0-9_.]+)'", re.S)
 _SPAN_RE = re.compile(
-    r"monitor\.timed_span\(\s*'[A-Za-z0-9_.:]+',\s*'([A-Za-z0-9_.]+)'",
-    re.S)
+    r"monitor\.(?:timed_span|phase)\(\s*(?:'[A-Za-z0-9_.:]+'(?:\s*\+\s*\w+)?"
+    r"|\w+),\s*'([A-Za-z0-9_.]+)'", re.S)
 _HELPER_RE = re.compile(r"\b_count\(\s*'([A-Za-z0-9_.]+)'", re.S)
 
 # any quoted token with a series suffix, wherever it appears — the
