@@ -78,7 +78,7 @@ class SmokeConfig(object):
             'softmax_with_cross_entropy': 'pallas', 'fused_adam': 'pallas'}
         self.serve_tiers = {
             'lookup_table': 'pallas', 'fused_ln_residual': 'pallas',
-            'fused_ffn_tail': 'xla'}
+            'fused_ffn_tail': 'xla', 'kv_decode_attention_paged': 'pallas'}
         # Mosaic kernel names the compiled train step must contain for the
         # units declared pallas (the `name=` of their pallas_call)
         self.mosaic_kernels = {

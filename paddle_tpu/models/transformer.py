@@ -269,8 +269,13 @@ def build_lm(cfg=None, is_test=False):
 #
 # PAGED mode (PR 12): pass block_size/num_blocks to build_lm_decode_step
 # (or use build_lm_prefill_paged) and the cache becomes
-# [num_blocks, layers, heads, block_size, head_dim], addressed through
-# runtime-fed per-slot block tables (ops/kv_cache_ops.py paged variants).
+# [num_blocks, layers, block_size, heads * head_dim] — a page (one block
+# of one layer) is one contiguous [block_size, d_model] run of HBM —
+# addressed through runtime-fed per-slot block tables (ops/kv_cache_ops.py
+# paged variants). The decode step's `kv_decode_attention_paged` reads
+# each slot's live pages in place from that pool (a Pallas kernel on the
+# chip, ops/paged_decode_attention.py); the prefill and verify programs
+# still gather a table row's pages into a dense K/V first.
 # The table is an ordinary feed, so the program count and every compiled
 # signature stay fixed — serving/generate.py's allocator decides the
 # physical layout per request at admission time.
@@ -306,8 +311,7 @@ def _declare_kv_caches(block, cfg, slots, max_len):
 
 
 def _declare_paged_kv_caches(block, cfg, num_blocks, block_size):
-    dh = cfg.d_model // cfg.n_head
-    shape = (num_blocks, cfg.n_layer, cfg.n_head, block_size, dh)
+    shape = (num_blocks, cfg.n_layer, block_size, cfg.d_model)
     kc = block.create_var(name=KV_CACHE_K, shape=shape, dtype='float32',
                           persistable=True, stop_gradient=True)
     vc = block.create_var(name=KV_CACHE_V, shape=shape, dtype='float32',

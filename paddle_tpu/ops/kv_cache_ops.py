@@ -31,9 +31,18 @@ which is what makes admitting/evicting requests at token boundaries safe
 while other slots are mid-sequence.
 
 PAGED variants (PR 12) break the contiguous row-span reservation: the
-physical cache is ``[num_blocks, layers, heads, block_size, head_dim]``
+physical cache is ``[num_blocks, layers, block_size, heads * head_dim]``
 and every slot addresses it through a runtime-fed BLOCK TABLE — logical
 position ``p`` lives at ``(table[p // block_size], p % block_size)``.
+A PAGE (one block of one layer) is ``[block_size, heads * head_dim]``:
+rows are tokens, lanes are (head, feature). The minor dimension has to
+be a multiple of 128: then the layout XLA gives the pool on a TPU is the
+row-major one and a page is ONE contiguous run of HBM (64 KB at
+16 x 16 x 64 float32). A pool whose minor dimension is ``head_dim`` = 64
+is NOT laid out as declared — XLA:TPU will not pad 64 lanes to 128 and
+makes ``num_blocks`` the minor dimension instead, so a page is 16 K
+single elements a pool-stride apart and every page read or write is an
+element gather (measured on the v5e, PERF.md PR 26).
 The table is an ordinary feed, so ONE compiled program serves any
 allocation pattern (the fixed-signature / zero-recompile contract is
 untouched); HBM is committed block-by-block as sequences actually grow,
@@ -45,6 +54,15 @@ pad-row writes land there, so an idle slot's garbage computation can
 never scribble over a live block. Masking keeps the exact-zero parity
 contract of the contiguous ops: a masked (stale / trash / other-tenant)
 position contributes ``0 * garbage = 0`` bit-exactly.
+
+``kv_decode_attention_paged`` — the op every decode step spends its
+time in — has three lowerings behind ``kernel_tier.dispatch``: ``off``
+gathers each slot's whole table row into a dense ``[S, H, MB*bs, dh]``
+K and V and runs the contiguous op's einsums on them (the tests'
+reference); ``xla`` does the same without the transpose; ``pallas`` /
+``interpret`` is the kernel of ops/paged_decode_attention.py, which
+reads each slot's LIVE pages in place from the pool — no pool slice, no
+table-wide gather, no dense copy.
 
 ``kv_prefix_attention`` is what makes prefix sharing pay: a prefill
 whose leading ``P`` positions are already cached computes only the
@@ -160,6 +178,20 @@ def _block_of(table, pos, block_size):
     return table[idx].astype(jnp.int32), (pos % block_size).astype(jnp.int32)
 
 
+def _gather_pages(cache, layer, tables, n_head):
+    """The pages `tables` names ([..., MB] block ids) of one layer, as
+    ``[..., MB*bs, H, dh]`` in logical position order."""
+    g = cache[:, layer][tables]                 # [..., MB, bs, H*dh]
+    lead = tables.shape[:-1]
+    return g.reshape(lead + (-1, n_head, g.shape[-1] // n_head))
+
+
+def _gather_heads(cache, layer, tables, n_head):
+    """`_gather_pages` with heads ahead of positions: the dense
+    ``[..., H, MB*bs, dh]`` K or V of the contiguous ops."""
+    return jnp.moveaxis(_gather_pages(cache, layer, tables, n_head), -2, -3)
+
+
 @register_op('kv_cache_prefill_paged', share_lod=False)
 def _kv_cache_prefill_paged(ctx, op):
     """Cache[table[(P+t)//bs], layer, :, (P+t)%bs, :] = New[0, :, t, :] for
@@ -167,7 +199,7 @@ def _kv_cache_prefill_paged(ctx, op):
     REDIRECTED to the trash block (a contiguous prefill could park pad
     rows in its own reserved span — a paged slot owns no span, so pad
     garbage must never land in a real block)."""
-    cache = ctx.in1(op, 'Cache')                # [NB, Ln, H, bs, dh]
+    cache = ctx.in1(op, 'Cache')                # [NB, Ln, bs, H*dh]
     new = ctx.in1(op, 'New')                    # [1, H, T, dh]
     table = ctx.in1(op, 'BlockTable').reshape(-1).astype(jnp.int32)
     pos = ctx.in1(op, 'Positions').reshape(-1).astype(jnp.int32)  # [T]
@@ -175,11 +207,12 @@ def _kv_cache_prefill_paged(ctx, op):
     layer = int(op.attr('layer'))
     bs = int(op.attr('block_size'))
     rows = jnp.transpose(new[0], (1, 0, 2)).astype(cache.dtype)  # [T,H,dh]
+    rows = rows.reshape(rows.shape[0], -1)                       # [T,H*dh]
     blk, off = _block_of(table, pos, bs)
     real = jnp.arange(rows.shape[0]) < length[0]
     blk = jnp.where(real, blk, 0)
     off = jnp.where(real, off, 0)
-    out = cache.at[blk, layer, :, off, :].set(rows)
+    out = cache.at[blk, layer, off, :].set(rows)
     ctx.out(op, 'Out', out)
 
 
@@ -192,7 +225,7 @@ def _kv_cache_update_paged(ctx, op):
     redirects invalid rows to the trash block explicitly — the drafter's
     unrolled steps use it for positions at or past ``max_len``, where
     the clipped table lookup would otherwise target a LIVE block."""
-    cache = ctx.in1(op, 'Cache')                # [NB, Ln, H, bs, dh]
+    cache = ctx.in1(op, 'Cache')                # [NB, Ln, bs, H*dh]
     new = ctx.in1(op, 'New')                    # [S, H, dh]
     tables = ctx.in1(op, 'BlockTables').astype(jnp.int32)  # [S, MB]
     pos = ctx.in1(op, 'Positions').reshape(-1).astype(jnp.int32)
@@ -206,7 +239,8 @@ def _kv_cache_update_paged(ctx, op):
         keep = valid.reshape(-1) != 0
         blk = jnp.where(keep, blk, 0)
         off = jnp.where(keep, off, 0)
-    out = cache.at[blk, layer, :, off, :].set(new.astype(cache.dtype))
+    rows = new.reshape(new.shape[0], -1).astype(cache.dtype)  # [S, H*dh]
+    out = cache.at[blk, layer, off, :].set(rows)
     ctx.out(op, 'Out', out)
 
 
@@ -219,7 +253,7 @@ def _kv_cache_update_span_paged(ctx, op):
     are redirected to the trash block: a speculative row may later be
     rolled back, but it must never be able to touch a live block the
     slot doesn't own."""
-    cache = ctx.in1(op, 'Cache')                # [NB, Ln, H, bs, dh]
+    cache = ctx.in1(op, 'Cache')                # [NB, Ln, bs, H*dh]
     new = ctx.in1(op, 'New')                    # [S, H, W, dh]
     tables = ctx.in1(op, 'BlockTables').astype(jnp.int32)  # [S, MB]
     pos = ctx.in1(op, 'Positions').astype(jnp.int32)       # [S, W]
@@ -234,8 +268,8 @@ def _kv_cache_update_span_paged(ctx, op):
     off = jnp.where(keep, off, 0)
     rows = jnp.transpose(new, (0, 2, 1, 3)).astype(cache.dtype)  # [S,W,H,dh]
     S, W = pos.shape
-    out = cache.at[blk.reshape(-1), layer, :, off.reshape(-1), :].set(
-        rows.reshape(S * W, rows.shape[2], rows.shape[3]))
+    out = cache.at[blk.reshape(-1), layer, off.reshape(-1), :].set(
+        rows.reshape(S * W, -1))
     ctx.out(op, 'Out', out)
 
 
@@ -251,23 +285,16 @@ def _kv_verify_attention_paged(ctx, op):
     speculative acceptance; masked (stale / trash / rolled-back) rows
     contribute exact 0."""
     q = ctx.in1(op, 'Q')                        # [S, H, W, dh]
-    kc = ctx.in1(op, 'KCache')                  # [NB, Ln, H, bs, dh]
+    kc = ctx.in1(op, 'KCache')                  # [NB, Ln, bs, H*dh]
     vc = ctx.in1(op, 'VCache')
     tables = ctx.in1(op, 'BlockTables').astype(jnp.int32)  # [S, MB]
     pos = ctx.in1(op, 'Positions')              # [S, W]
     layer = int(op.attr('layer'))
     scale = op.attr('scale', 1.0)
     bs = int(op.attr('block_size'))
-    S, MB = tables.shape
-    H, dh = kc.shape[2], kc.shape[4]
-
-    def gather(c):
-        # [S, MB, H, bs, dh] -> [S, H, MB*bs, dh] (logical position order)
-        g = c[:, layer][tables]
-        return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(S, H, MB * bs, dh)
-
-    k = gather(kc)
-    v = gather(vc)
+    MB = tables.shape[1]
+    k = _gather_heads(kc, layer, tables, q.shape[1])   # [S, H, MB*bs, dh]
+    v = _gather_heads(vc, layer, tables, q.shape[1])
     scores = jnp.einsum('shtd,shmd->shtm', q, k,
                         preferred_element_type=jnp.float32) * scale
     m = jnp.arange(MB * bs)[None, None, None, :] <= \
@@ -281,37 +308,52 @@ def _kv_verify_attention_paged(ctx, op):
 
 @register_op('kv_decode_attention_paged', share_lod=False)
 def _kv_decode_attention_paged(ctx, op):
-    """One-query attention per slot over its BLOCK-TABLE-gathered K/V,
+    """One-query attention per slot over the pages its BLOCK TABLE names,
     masked to each slot's positions 0..Positions[s] exactly as the
-    contiguous op: the gathered logical layout is table order x in-block
-    offset, so the mask arithmetic is identical and masked (stale /
-    trash / shared-beyond-prefix) rows contribute exact 0."""
+    contiguous op: masked (stale / trash / shared-beyond-prefix) rows
+    contribute exact 0. ``pallas`` / ``interpret``: the kernel of
+    ops/paged_decode_attention.py, which reads pages 0..Positions[s]//bs
+    of each slot in place. ``xla``: the table-wide gather, einsums on
+    the gathered ``[S, MB*bs, H, dh]``. ``off``: that gather moved to the
+    contiguous op's ``[S, H, MB*bs, dh]`` and its einsums, letter for
+    letter. A >1-device mesh has no kernel here (the pool is not
+    sharded): it takes ``xla``."""
+    from . import kernel_tier, paged_decode_attention as pda
+    from ..parallel.api import get_active_mesh
     q = ctx.in1(op, 'Q')                        # [S, H, dh]
-    kc = ctx.in1(op, 'KCache')                  # [NB, Ln, H, bs, dh]
+    kc = ctx.in1(op, 'KCache')                  # [NB, Ln, bs, H*dh]
     vc = ctx.in1(op, 'VCache')
     tables = ctx.in1(op, 'BlockTables').astype(jnp.int32)  # [S, MB]
     pos = ctx.in1(op, 'Positions').reshape(-1)  # [S]
     layer = int(op.attr('layer'))
     scale = op.attr('scale', 1.0)
     bs = int(op.attr('block_size'))
-    S, MB = tables.shape
-    H, dh = kc.shape[2], kc.shape[4]
-
-    def gather(c):
-        # [S, MB, H, bs, dh] -> [S, H, MB*bs, dh] (logical position order)
-        g = c[:, layer][tables]
-        return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(S, H, MB * bs, dh)
-
-    k = gather(kc)
-    v = gather(vc)
-    scores = jnp.einsum('shd,shmd->shm', q, k,
+    MB = tables.shape[1]
+    H, dh = q.shape[1], q.shape[2]
+    mesh = get_active_mesh()
+    meshed = mesh is not None and mesh.size > 1
+    impl = kernel_tier.dispatch(
+        'kv_decode_attention_paged',
+        pallas_ok=pda.shapes_ok(H, dh, bs) and not meshed, mesh=mesh)
+    if impl in ('pallas', 'interpret'):
+        ctx.out(op, 'Out', pda.paged_decode_attention(
+            q, kc, vc, tables, pos, jnp.int32(layer), scale=float(scale),
+            interpret=impl == 'interpret'))
+        return
+    # xla keeps the gathered [S, M, H, dh]; off moves it to the contiguous
+    # op's [S, H, M, dh]
+    gather, qk, wv = \
+        (_gather_pages, 'shd,smhd->shm', 'shm,smhd->shd') if impl == 'xla' \
+        else (_gather_heads, 'shd,shmd->shm', 'shm,shmd->shd')
+    k = gather(kc, layer, tables, H)
+    v = gather(vc, layer, tables, H)
+    scores = jnp.einsum(qk, q, k,
                         preferred_element_type=jnp.float32) * scale
     m = jnp.arange(MB * bs)[None, None, :] <= pos[:, None, None]
     scores = jnp.where(m, scores, _NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     w = jnp.where(m, w, 0.0)
-    ctx.out(op, 'Out',
-            jnp.einsum('shm,shmd->shd', w.astype(v.dtype), v))
+    ctx.out(op, 'Out', jnp.einsum(wv, w.astype(v.dtype), v))
 
 
 @register_op('kv_prefix_attention', share_lod=False)
@@ -324,7 +366,7 @@ def _kv_prefix_attention(ctx, op):
     (Positions starting at 0) this is exactly the causal prefill
     attention, computed from the cache instead of a local K/V copy."""
     q = ctx.in1(op, 'Q')                        # [1, H, T, dh]
-    kc = ctx.in1(op, 'KCache')                  # [NB, Ln, H, bs, dh]
+    kc = ctx.in1(op, 'KCache')                  # [NB, Ln, bs, H*dh]
     vc = ctx.in1(op, 'VCache')
     table = ctx.in1(op, 'BlockTable').reshape(-1).astype(jnp.int32)
     pos = ctx.in1(op, 'Positions').reshape(-1)  # [T] global query positions
@@ -332,15 +374,8 @@ def _kv_prefix_attention(ctx, op):
     scale = op.attr('scale', 1.0)
     bs = int(op.attr('block_size'))
     MB = table.shape[0]
-    H, dh = kc.shape[2], kc.shape[4]
-
-    def gather(c):
-        # [MB, H, bs, dh] -> [H, MB*bs, dh]
-        return jnp.transpose(c[:, layer][table],
-                             (1, 0, 2, 3)).reshape(H, MB * bs, dh)
-
-    k = gather(kc)
-    v = gather(vc)
+    k = _gather_heads(kc, layer, table, q.shape[1])    # [H, MB*bs, dh]
+    v = _gather_heads(vc, layer, table, q.shape[1])
     scores = jnp.einsum('htd,hmd->htm', q[0], k,
                         preferred_element_type=jnp.float32) * scale
     m = jnp.arange(MB * bs)[None, :] <= pos[:, None]       # [T, M]

@@ -38,7 +38,7 @@ serving).
 
 PAGED mode (PR 12, ``GenerateConfig(paged=True)``) replaces the
 per-slot ``max_len`` row reservation with a BLOCK pool: the cache is
-``[num_blocks, layers, heads, block_size, head_dim]`` and each slot
+``[num_blocks, layers, block_size, heads * head_dim]`` and each slot
 addresses it through a runtime-fed block table, so HBM is committed as
 sequences actually grow — admission is a blocks-available decision
 (serving/kv_blocks.py), eviction returns blocks, and a pool that runs
@@ -154,6 +154,16 @@ def _loop_sums():
         'queue_wait_s': flat.get('generate_queue_wait_seconds_total', 0.0),
         'admitted': flat.get('generate_admit_total', 0),
     }
+
+
+def _live_page_share():
+    """Of the table pages the active slots' decode steps spanned, the
+    share at or below the slots' positions — what the paged attention
+    kernel reads. Process-wide, like `_loop_sums`."""
+    flat = monitor.counters()
+    table = flat.get('kv_decode_pages_table_total', 0)
+    return round(flat.get('kv_decode_pages_live_total', 0) / float(table),
+                 4) if table else 0.0
 
 
 def _sampling_stream(sample_seed):
@@ -648,20 +658,19 @@ class GenerateEngine(object):
         by two live engines stays unsupported)."""
         import jax.numpy as jnp
         cfg, c = self.config.model, self.config
-        dh = cfg.d_model // cfg.n_head
         if c.paged:
-            shape = (c.num_blocks, cfg.n_layer, cfg.n_head,
-                     c.block_size, dh)
+            shape = (c.num_blocks, cfg.n_layer, c.block_size, cfg.d_model)
         else:
-            shape = (c.slots, cfg.n_layer, cfg.n_head, c.max_len, dh)
+            shape = (c.slots, cfg.n_layer, cfg.n_head, c.max_len,
+                     cfg.d_model // cfg.n_head)
         have = self.scope.get(KV_CACHE_K)
         if have is None or tuple(have.shape) != shape:
             self.scope.set(KV_CACHE_K, jnp.zeros(shape, 'float32'))
             self.scope.set(KV_CACHE_V, jnp.zeros(shape, 'float32'))
         if c.speculative:
             dcfg = self._draft_cfg
-            dshape = (self._draft_nb, dcfg.n_layer, dcfg.n_head,
-                      c.block_size, dcfg.d_model // dcfg.n_head)
+            dshape = (self._draft_nb, dcfg.n_layer, c.block_size,
+                      dcfg.d_model)
             dhave = self._draft_scope.get(KV_CACHE_K)
             if dhave is None or tuple(dhave.shape) != dshape:
                 self._draft_scope.set(KV_CACHE_K,
@@ -1760,6 +1769,7 @@ class GenerateEngine(object):
             btab = np.zeros((S, self._max_blocks), 'int64') if c.paged \
                 else None
             active = []
+            live_pages = 0
             for i, st in enumerate(self._slots):
                 if st is None:
                     continue
@@ -1771,9 +1781,16 @@ class GenerateEngine(object):
                 sample['gen_u'][i] = r._draw_u()
                 if btab is not None:
                     btab[i] = st.table
+                    live_pages += st.pos // c.block_size + 1
                 active.append((i, st))
             if not active:
                 return None
+            if btab is not None:
+                # what the step's paged attention reads of what its
+                # tables span (stats()['blocks']['decode_live_page_share'])
+                monitor.inc('kv_decode_pages_live_total', live_pages)
+                monitor.inc('kv_decode_pages_table_total',
+                            len(active) * self._max_blocks)
             feed = {'gen_tokens': toks, 'gen_pos': pos}
             if btab is not None:
                 feed['gen_btab'] = btab
@@ -1917,7 +1934,8 @@ class GenerateEngine(object):
         """Decode-loop statistics since construction. Paged engines add
         the block-level capacity accounting under 'blocks' — physical
         pool state, the peak footprint, and the prefix-cache entry
-        count (the monitor mirrors it as kv_blocks_in_use/free). 'loop'
+        count (the monitor mirrors it as kv_blocks_in_use/free), and
+        the share of table pages the decode steps read. 'loop'
         is where the loop thread's time went, by phase (_loop_sums)."""
         steps = self._decode_steps
         out = {
@@ -1940,6 +1958,7 @@ class GenerateEngine(object):
                 'peak_in_use': self._blocks_peak,
                 'prefix_entries': len(self._prefix)
                 if self._prefix is not None else 0,
+                'decode_live_page_share': _live_page_share(),
             }
         if self.config.speculative:
             prop = self._spec_proposed

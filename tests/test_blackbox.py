@@ -22,7 +22,10 @@ from paddle_tpu import blackbox, goodput, monitor, resilience
 def bb(tmp_path, monkeypatch):
     """Recorder ON into a private root, unlimited rate, tiny retry
     backoffs; drained and reset on the way out so no other test sees a
-    half-written queue."""
+    half-written queue. The goodput window starts clean too: its
+    sentinels are process-wide, and a steady state left by an earlier
+    file of the same worker would read this test's first compiles as a
+    recompile storm."""
     d = str(tmp_path / 'bb')
     monkeypatch.setenv('PADDLE_BLACKBOX', '1')
     monkeypatch.setenv('PADDLE_BLACKBOX_DIR', d)
@@ -30,6 +33,7 @@ def bb(tmp_path, monkeypatch):
     monkeypatch.setenv('PADDLE_RETRY_BASE_S', '0.001')
     monkeypatch.setenv('PADDLE_RETRY_MAX_S', '0.01')
     blackbox.reset()
+    goodput.reset()
     yield d
     blackbox.flush(10.0)
     blackbox.reset()

@@ -23,7 +23,7 @@ def _toy():
     cfg.batch = 32                # 512 rows: 128 per shard under data=4
     cfg.slots = 8                 # the decode step's rows: one 8-row block
     cfg.max_len = 32
-    cfg.block_size = 4
+    cfg.block_size = 8            # whole (8, 128) tiles: the paged kernel's rule
     cfg.prompt_buckets = [8, 16]
     cfg.max_new_tokens = 4
     cfg.prompt_lens = [3, 8, 5, 12, 16, 7]

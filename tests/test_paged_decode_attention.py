@@ -1,0 +1,235 @@
+"""kv_decode_attention_paged (ops/kv_cache_ops.py) across its tiers: the
+Pallas kernel of ops/paged_decode_attention.py, through the interpreter,
+against the `off` tier's table-wide gather — on the op alone at a toy, a
+355M-shaped and a 1.3B-shaped case, bitwise slot independence, and a toy
+paged engine whose greedy tokens must not depend on the tier.
+
+The op is lowered directly (a stand-in ctx/op pair around the registered
+lowering): the tiers differ only inside it, and a program around it would
+test the executor again.
+"""
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from paddle_tpu import monitor
+from paddle_tpu.core.registry import get_op
+from paddle_tpu.models.transformer import LMConfig
+from paddle_tpu.ops import paged_decode_attention as pda
+from paddle_tpu.serving import GenerateConfig, GenerateEngine
+
+
+class _Op(object):
+    def __init__(self, **attrs):
+        self.attrs = attrs
+
+    def attr(self, name, default=None):
+        return self.attrs.get(name, default)
+
+
+class _Ctx(object):
+    def __init__(self, **ins):
+        self.ins, self.outs = ins, {}
+
+    def in1(self, op, slot):
+        return self.ins.get(slot)
+
+    def out(self, op, slot, value):
+        self.outs[slot] = value
+
+
+def _attend(tier, monkeypatch, q, kc, vc, tables, pos, layer, bs,
+            lands_on=None):
+    """The op under `tier`; its one dispatch must land on `lands_on`
+    (the tier itself unless a shape makes it fall)."""
+    monkeypatch.setenv('PADDLE_FUSED_TIER', tier)
+    before = monitor.counters()
+    ctx = _Ctx(Q=jnp.asarray(q), KCache=jnp.asarray(kc),
+               VCache=jnp.asarray(vc), BlockTables=jnp.asarray(tables),
+               Positions=jnp.asarray(pos)[:, None])
+    op = _Op(layer=layer, scale=q.shape[-1] ** -0.5, block_size=bs)
+    get_op('kv_decode_attention_paged').lower(ctx, op)
+    moved = monitor.counter_delta(before)
+    assert moved == {'fused_kernel_dispatch_total{impl=%s,mesh=1,'
+                     'op=kv_decode_attention_paged}'
+                     % (lands_on or tier): 1}, moved
+    return np.asarray(ctx.outs['Out'])
+
+
+def _pools(rng, nb, ln, bs, hd):
+    return (rng.randn(nb, ln, bs, hd).astype('float32'),
+            rng.randn(nb, ln, bs, hd).astype('float32'))
+
+
+# (S, H, bs, dh, MB): toy; fairseq-dense 355M's heads, block and table at
+# fewer slots; 1.3B's. Pools of 40 blocks, 2 layers.
+SHAPES = [(6, 2, 8, 64, 4), (6, 16, 16, 64, 48), (6, 32, 16, 64, 66)]
+
+
+@pytest.mark.parametrize('S,H,bs,dh,MB', SHAPES,
+                         ids=['toy', 'fd355m', 'fd1.3b'])
+def test_interpret_tier_matches_off_tier(monkeypatch, S, H, bs, dh, MB):
+    assert pda.shapes_ok(H, dh, bs)
+    rng = np.random.RandomState(S * H + MB)
+    nb, ln, layer = 40, 2, 1
+    kc, vc = _pools(rng, nb, ln, bs, H * dh)
+    q = rng.randn(S, H, dh).astype('float32')
+    tables = rng.randint(1, nb, size=(S, MB)).astype('int32')
+    # slot 0 at position 0; 1 on a page's last row; 2 on the next page's
+    # first row; 3 fills the table; 4 is idle (all-zero row -> the trash
+    # block, position 0); 5 shares slot 3's leading pages
+    pos = np.array([0, bs - 1, bs, MB * bs - 1, 0, 2 * bs + 3], 'int32')
+    tables[4] = 0
+    tables[5, :2] = tables[3, :2]
+    args = (q, kc, vc, tables, pos, layer, bs)
+    off = _attend('off', monkeypatch, *args)
+    got = _attend('interpret', monkeypatch, *args)
+    np.testing.assert_allclose(got, off, rtol=2e-5, atol=2e-6)
+    if (S, H, bs, dh, MB) == SHAPES[0]:
+        np.testing.assert_allclose(_attend('xla', monkeypatch, *args), off,
+                                   rtol=2e-5, atol=2e-6)
+
+
+def test_shapes_the_kernel_refuses_fall_to_xla(monkeypatch):
+    # a head's 24 lanes would straddle vregs; 4-row pages are no tile
+    assert not pda.shapes_ok(2, 24, 8) and not pda.shapes_ok(2, 64, 4)
+    rng = np.random.RandomState(0)
+    kc, vc = _pools(rng, 6, 1, 4, 2 * 16)
+    q = rng.randn(2, 2, 16).astype('float32')
+    tables = np.array([[1, 2], [3, 0]], 'int32')
+    pos = np.array([5, 2], 'int32')
+    args = (q, kc, vc, tables, pos, 0, 4)
+    np.testing.assert_allclose(
+        _attend('interpret', monkeypatch, *args, lands_on='xla'),
+        _attend('off', monkeypatch, *args), rtol=2e-5, atol=2e-6)
+
+
+def test_a_slot_is_bitwise_independent_of_its_neighbours(monkeypatch):
+    S, H, bs, dh, MB = 5, 4, 16, 64, 6
+    rng = np.random.RandomState(3)
+    nb = 30
+    kc, vc = _pools(rng, nb, 2, bs, H * dh)
+    q = rng.randn(S, H, dh).astype('float32')
+    tables = rng.randint(1, nb, size=(S, MB)).astype('int32')
+    pos = np.array([7, 40, 2 * bs + 5, 95, 16], 'int32')
+    full = _attend('interpret', monkeypatch, q, kc, vc, tables, pos, 0, bs)
+
+    # slot 2 alone, in another row of the batch, its unused table entries
+    # on other blocks, and every block it does not read filled with
+    # garbage: not a bit of its output may move
+    used = tables[2, :3]
+    kc2, vc2 = kc.copy(), vc.copy()
+    spare = np.setdiff1d(np.arange(nb), used)
+    kc2[spare] = 1e30
+    vc2[spare] = -1e30
+    q1 = np.zeros_like(q[:2])
+    q1[1] = q[2]
+    t1 = np.zeros((2, MB), 'int32')
+    t1[1, :3] = used
+    t1[1, 3:] = spare[:MB - 3]
+    p1 = np.array([0, pos[2]], 'int32')
+    alone = _attend('interpret', monkeypatch, q1, kc2, vc2, t1, p1, 0, bs)
+    np.testing.assert_array_equal(alone[1], full[2])
+
+
+def _toy_engine():
+    model = LMConfig(vocab_size=64, seq_len=32, d_model=128, n_head=2,
+                     n_layer=2, d_ff=64, dropout=0.0, attn_dropout=0.0,
+                     use_flash_attention=False)
+    return GenerateEngine(GenerateConfig(
+        model=model, slots=4, max_len=48, prompt_buckets=[16],
+        eos_id=None, seed=0, paged=True, block_size=8))
+
+
+def _serve(eng, prompts, n_new):
+    eng.warmup()
+    warm = monitor.counters()
+    reqs = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, n_new)]
+    eng._admit()
+    while any(r.finish_reason is None and r._error is None for r in reqs):
+        eng._step()
+        eng._admit()
+    moved = monitor.counter_delta(warm)
+    return [list(r.result(timeout=5)) for r in reqs], moved
+
+
+def test_engine_tokens_do_not_depend_on_the_tier(monkeypatch):
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(2, 64, size=n).astype('int64')
+               for n in (5, 16, 9, 12, 3, 8)]
+    n_new = [12, 20, 7, 30, 16, 9]
+    out = {}
+    for tier in ('off', 'interpret'):
+        monkeypatch.setenv('PADDLE_FUSED_TIER', tier)
+        before = monitor.counters()
+        eng = _toy_engine()
+        out[tier], moved = _serve(eng, prompts, n_new)
+        assert not any(k.startswith('compile_cache_miss') for k in moved), \
+            moved
+        built = monitor.counter_delta(before)
+        # one decision per layer of the decode program, all on the tier
+        assert built['fused_kernel_dispatch_total{impl=%s,mesh=1,'
+                     'op=kv_decode_attention_paged}' % tier] == 2
+        # the feed phase's page counters: 6 pages a table row, and of them
+        # what lies at or below each slot's position
+        live = moved['kv_decode_pages_live_total']
+        table = moved['kv_decode_pages_table_total']
+        assert table % 6 == 0 and 0 < live < table
+        share = eng.stats()['blocks']['decode_live_page_share']
+        assert 0.0 < share < 1.0
+    assert out['interpret'] == out['off']
+    assert [len(t) for t in out['off']] == n_new
+
+
+# ---------------------------------------------------------------------------
+# Mosaic, without a chip: the TPU compiler against a described v5e. The
+# topology is described inside a fixture, never at import (one process at a
+# time may load libtpu: under xdist only this file's worker does).
+
+@pytest.fixture(scope='module')
+def one_chip():
+    import os
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    for k, v in (('TPU_ACCELERATOR_TYPE', 'v5litepod-4'),
+                 ('TPU_WORKER_HOSTNAMES', 'localhost'),
+                 ('TPU_SKIP_MDS_QUERY', '1')):
+        os.environ.setdefault(k, v)
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to ask
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize('S,H,MB,NB', [(32, 16, 48, 1024), (4, 32, 66, 265)],
+                         ids=['fd355m-serve-chat', 'fd1.3b-serve-doc'])
+def test_mosaic_accepts_the_kernel_at_the_cells_shapes(one_chip, S, H, MB,
+                                                       NB):
+    import jax
+    bs, dh, ln, layer = 16, 64, 24, 3
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(kc, vc, q, new, tables, pos):
+        # the layer's row scatter into the donated pool, then the kernel's
+        # read of it: a pool the custom call could not take in place would
+        # show as a pool-sized temp
+        blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
+        kc = kc.at[blk, layer, pos % bs, :].set(new)
+        return kc, pda.paged_decode_attention(q, kc, vc, tables, pos, layer,
+                                              scale=dh ** -0.5)
+
+    pool = sds((NB, ln, bs, H * dh))
+    c = jax.jit(step, donate_argnums=0).lower(
+        pool, pool, sds((S, H, dh)), sds((S, H * dh)),
+        sds((S, MB), jnp.int32), sds((S,), jnp.int32)).compile()
+    text = c.as_text()
+    assert 'tpu_custom_call' in text and 'paged_decode_attention' in text
+    # row-major pool: a page is one contiguous run
+    assert 'f32[%d,%d,%d,%d]{3,2,1,0:T(8,128)}' % (NB, ln, bs, H * dh) in text
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -321,7 +321,7 @@ def test_draft_cache_resync_after_fallback_burst():
     for p in range(pos_before):
         tb, db = st_g.blocks[p // BS], st_g.dblocks[p // BS]
         np.testing.assert_array_equal(
-            kt[tb, :, :, p % BS, :], kd[db, :, :, p % BS, :],
+            kt[tb, :, p % BS, :], kd[db, :, p % BS, :],
             err_msg='draft cache stale at position %d' % p)
     _drive(spec, g)     # speculation continues on the synced cache
     delta = monitor.counter_delta(before)
