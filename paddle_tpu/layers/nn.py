@@ -14,7 +14,8 @@ __all__ = [
     'square_error_cost', 'softmax_with_cross_entropy',
     'sigmoid_cross_entropy_with_logits', 'conv2d', 'conv3d',
     'conv2d_transpose', 'pool2d', 'pool3d', 'batch_norm', 'layer_norm',
-    'fused_layer_norm_residual', 'fused_ffn_tail',
+    'fused_layer_norm_residual', 'fused_ffn_tail', 'rms_norm',
+    'rotary_embedding', 'moe_ffn',
     'group_norm', 'data_norm', 'l2_normalize', 'matmul', 'mul', 'topk',
     'reshape', 'squeeze', 'unsqueeze', 'flatten', 'transpose', 'split',
     'reduce_sum', 'reduce_mean', 'reduce_max', 'reduce_min', 'reduce_prod',
@@ -572,6 +573,85 @@ def fused_ffn_tail(input, inner_size, size, num_flatten_dims=1,
                'seed': seed if seed is not None else 0,
                'dropout_implementation': 'upscale_in_train'})
     return out
+
+
+def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
+             name=None):
+    """RMSNorm over the trailing dimensions from ``begin_norm_axis``:
+    ``x * rsqrt(mean(x^2) + epsilon) * w``, computed in float32
+    (ops/moe_ops.py). The weight starts at 1."""
+    helper = LayerHelper('rms_norm', param_attr=param_attr, name=name)
+    dtype = input.dtype
+    w = helper.create_parameter(
+        attr=helper.param_attr,
+        shape=[_prod(input.shape[begin_norm_axis:])], dtype=dtype,
+        default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(dtype,
+                                                    shape=input.shape)
+    helper.append_op(type='rms_norm',
+                     inputs={'X': [input], 'Scale': [w]},
+                     outputs={'Out': [out]},
+                     attrs={'epsilon': epsilon,
+                            'begin_norm_axis': begin_norm_axis})
+    return out
+
+
+def rotary_embedding(input, positions, theta=10000.0, name=None):
+    """Rotary position embedding of ``input [..., H, dh]`` by the int64
+    ``positions`` (one per leading row of ``input``), ``rotate_half``
+    convention, ``inv_freq = theta^(-2i/dh)`` (ops/moe_ops.py)."""
+    helper = LayerHelper('rotary_embedding', name=name)
+    out = helper.create_variable_for_type_inference(input.dtype,
+                                                    shape=input.shape)
+    helper.append_op(type='rotary_embedding',
+                     inputs={'X': [input], 'Positions': [positions]},
+                     outputs={'Out': [out]}, attrs={'theta': float(theta)})
+    return out
+
+
+def moe_ffn(input, n_experts, expert_width, top_k, norm_topk_prob=False,
+            length=None, valid=None, router_param_attr=None,
+            gate_param_attr=None, up_param_attr=None, down_param_attr=None,
+            name=None):
+    """Dropless top-k mixture-of-experts FFN over the rows of ``input
+    [N, d]`` (ops/moe_ops.py): a float32 softmax router over all
+    ``n_experts``, the ``top_k`` largest (renormalised only with
+    ``norm_topk_prob``), SiLU-gated experts of width ``expert_width``
+    through a grouped matmul. Returns ``(out [N, d], topk_idx [N, top_k]
+    int32, expert_load [n_experts] int32)``; ``length`` (real rows of a
+    padded bucket) and ``valid`` (zero = an idle row) only leave rows out
+    of ``expert_load``."""
+    helper = LayerHelper('moe_ffn', name=name)
+    dtype = input.dtype
+    n, d = input.shape[0], input.shape[-1]
+    init = Normal(0.0, 0.02)
+
+    def param(attr, shape):
+        return helper.create_parameter(attr=attr or ParamAttr(),
+                                       shape=shape, dtype=dtype,
+                                       default_initializer=init)
+    router = param(router_param_attr, [d, n_experts])
+    gate = param(gate_param_attr, [n_experts, d, expert_width])
+    up = param(up_param_attr, [n_experts, d, expert_width])
+    down = param(down_param_attr, [n_experts, expert_width, d])
+    out = helper.create_variable_for_type_inference(dtype,
+                                                    shape=input.shape)
+    idx = helper.create_variable_for_type_inference('int32',
+                                                    shape=(n, top_k))
+    load = helper.create_variable_for_type_inference('int32',
+                                                     shape=(n_experts,))
+    inputs = {'X': [input], 'RouterW': [router], 'GateW': [gate],
+              'UpW': [up], 'DownW': [down]}
+    if length is not None:
+        inputs['Length'] = [length]
+    if valid is not None:
+        inputs['Valid'] = [valid]
+    helper.append_op(type='moe_ffn', inputs=inputs,
+                     outputs={'Out': [out], 'TopkIdx': [idx],
+                              'ExpertLoad': [load]},
+                     attrs={'top_k': int(top_k),
+                            'norm_topk_prob': bool(norm_topk_prob)})
+    return out, idx, load
 
 
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,

@@ -23,9 +23,34 @@ __all__ = ['multi_head_attention', 'transformer_block', 'build_lm',
 
 
 class LMConfig(object):
+    """The decoder block, as fields. The defaults are the block this repo
+    trains and serves everywhere (pre-LayerNorm, sinusoid positions added
+    to the embedding, heads of d_model / n_head, biases, a GELU FFN of
+    width d_ff). The other values are served by the PAGED decode step and
+    the paged prefill only (build_lm_decode_step with a block size,
+    build_lm_prefill_paged); every other builder refuses them by name
+    (`_require_classic_block`):
+
+    - ``norm='rms_norm'`` (``rms_eps``): RMSNorm, no bias, for every norm;
+    - ``position='rope'`` (``rope_theta``): nothing is added to the
+      embedding; q and k are rotated by the fed positions and K is cached
+      rotated;
+    - ``head_dim``: a head size other than d_model / n_head;
+    - ``qk_norm``: the block's norm over the WHOLE projected q and k
+      (before the split into heads, OLMoE's way);
+    - ``bias=False``: no bias on any projection;
+    - ``ffn='moe'``: a dropless top-``experts_per_token``-of-``n_experts``
+      FFN of SiLU-gated experts of width ``expert_width``
+      (``layers.moe_ffn``), weights renormalised over the chosen experts
+      only with ``norm_topk_prob``."""
+
     def __init__(self, vocab_size=32000, seq_len=512, d_model=512,
                  n_head=8, n_layer=6, d_ff=2048, dropout=0.1,
-                 attn_dropout=None, use_flash_attention=True):
+                 attn_dropout=None, use_flash_attention=True,
+                 norm='layer_norm', rms_eps=1e-5, position='sinusoid',
+                 rope_theta=10000.0, head_dim=None, qk_norm=False,
+                 bias=True, ffn='gelu', n_experts=0, experts_per_token=0,
+                 expert_width=0, norm_topk_prob=False):
         self.vocab_size = vocab_size
         self.seq_len = seq_len
         self.d_model = d_model
@@ -40,6 +65,52 @@ class LMConfig(object):
         self.use_flash_attention = use_flash_attention
         # balanced causal layout when the sequence axis is ring-sharded
         self.ring_zigzag = False
+        for field, value, known in (
+                ('norm', norm, ('layer_norm', 'rms_norm')),
+                ('position', position, ('sinusoid', 'rope')),
+                ('ffn', ffn, ('gelu', 'moe'))):
+            if value not in known:
+                raise ValueError('LMConfig.%s=%r: expected one of %r'
+                                 % (field, value, known))
+        self.norm = norm
+        self.rms_eps = rms_eps
+        self.position = position
+        self.rope_theta = rope_theta
+        self.head_dim = head_dim or d_model // n_head
+        self.qk_norm = qk_norm
+        self.bias = bias
+        self.ffn = ffn
+        self.n_experts = n_experts
+        self.experts_per_token = experts_per_token
+        self.expert_width = expert_width
+        self.norm_topk_prob = norm_topk_prob
+        if ffn == 'moe' and not 0 < experts_per_token <= n_experts:
+            raise ValueError('LMConfig.ffn=%r needs 0 < experts_per_token '
+                             '<= n_experts, got %r of %r'
+                             % (ffn, experts_per_token, n_experts))
+
+    @property
+    def kv_width(self):
+        """Lanes of one cached K (or V) row: heads x head size."""
+        return self.n_head * self.head_dim
+
+
+_CLASSIC_BLOCK = (('norm', 'layer_norm'), ('position', 'sinusoid'),
+                  ('qk_norm', False), ('bias', True), ('ffn', 'gelu'))
+
+
+def _require_classic_block(cfg, who):
+    """`who` writes the classic block out by hand: refuse a configuration
+    it cannot express, naming the field."""
+    classic = _CLASSIC_BLOCK + (('head_dim', cfg.d_model // cfg.n_head),)
+    for field, value in classic:
+        if getattr(cfg, field) != value:
+            raise ValueError(
+                "%s cannot express LMConfig.%s=%r (it builds %r): only the "
+                "paged decode step and the paged prefill "
+                "(build_lm_decode_step with a block size, "
+                "build_lm_prefill_paged) build that block"
+                % (who, field, getattr(cfg, field), value))
 
 
 def multi_head_attention(x, cfg, prefix, mask_var=None, is_test=False,
@@ -123,6 +194,111 @@ def _entry_ln(x, residual, bna, name):
         bias_attr=ParamAttr(name=name + '.b'))
 
 
+def _norm(cfg, x, residual, bna, name):
+    """The block's norm at a residual-stream read point, of whichever
+    kind `cfg.norm` says: (normed, resolved_stream), the pending
+    ``residual`` (or None) added first. LayerNorm: `_entry_ln`."""
+    if cfg.norm == 'layer_norm':
+        return _entry_ln(x, residual, bna, name)
+    if residual is not None:
+        x = layers.elementwise_add(x, residual)
+    return layers.rms_norm(x, begin_norm_axis=bna, epsilon=cfg.rms_eps,
+                           param_attr=ParamAttr(name=name + '.w')), x
+
+
+def _bias(cfg, name):
+    return ParamAttr(name=name) if cfg.bias else False
+
+
+def _heads_of(cfg, flat, p, which, pos, T):
+    """One of q / k / v from its flat projection ([S, H*dh] decode rows,
+    [1, T, H*dh] in a prefill): the optional whole-width q/k-norm, the
+    split into heads, the optional rotation by the fed positions; laid
+    out as the cache ops want it ([S, H, dh]; [1, H, T, dh])."""
+    h, dh = cfg.n_head, cfg.head_dim
+    rows = T is None
+    if cfg.qk_norm and which != 'v':
+        flat = layers.rms_norm(
+            flat, begin_norm_axis=1 if rows else 2, epsilon=cfg.rms_eps,
+            param_attr=ParamAttr(name='%s.attn.%s_norm.w' % (p, which)))
+    x = layers.reshape(flat, shape=[-1, h, dh] if rows else [0, T, h, dh])
+    if cfg.position == 'rope' and which != 'v':
+        x = layers.rotary_embedding(x, pos, theta=cfg.rope_theta)
+    return x if rows else layers.transpose(x, perm=[0, 2, 1, 3])
+
+
+def _qkv(cfg, ln1, p, pos, T=None):
+    """The block's q, k, v from its normed input: the fused projection,
+    then each prepared for the cache ops. ``T`` None: decode rows
+    ``[S, d]`` -> three ``[S, H, dh]``; else one prompt ``[1, T, d]`` ->
+    three ``[1, H, T, dh]``. K comes back as it is CACHED: after k-norm
+    and rotation."""
+    h, dh = cfg.n_head, cfg.head_dim
+    qkv = layers.fc(ln1, size=3 * h * dh,
+                    num_flatten_dims=1 if T is None else 2,
+                    param_attr=ParamAttr(name=p + '.attn.qkv.w'),
+                    bias_attr=_bias(cfg, p + '.attn.qkv.b'))
+    if not cfg.qk_norm and cfg.position == 'sinusoid':
+        if T is None:
+            return _qkv_split_step(qkv, cfg)
+        qkv = layers.reshape(qkv, shape=[0, T, 3, h, dh])
+        qkv = layers.transpose(qkv, perm=[2, 0, 3, 1, 4])    # (3,1,H,T,dh)
+        return [layers.squeeze(layers.slice(qkv, axes=[0], starts=[i],
+                                            ends=[i + 1]), axes=[0])
+                for i in range(3)]
+    axis = 1 if T is None else 2
+    return [_heads_of(cfg, layers.slice(qkv, axes=[axis],
+                                        starts=[i * h * dh],
+                                        ends=[(i + 1) * h * dh]),
+                      p, which, pos, T)
+            for i, which in enumerate('qkv')]
+
+
+def _ffn(cfg, ln2, p, num_flatten_dims, length=None, valid=None):
+    """The block's FFN on its normed input: (delta, routing). GELU: the
+    fused tail, routing None. Experts: `layers.moe_ffn` over the rows,
+    routing = (the ``[rows, experts_per_token]`` experts chosen, the
+    ``[n_experts]`` int32 rows routed to each expert; ``length`` /
+    ``valid`` say which rows are a request's and count)."""
+    if cfg.ffn == 'gelu':
+        # decode is inference-only: prob 0 / is_test keeps the op on the
+        # RNG-free bind fast path (no per-step key derivation)
+        return _ffn_tail(ln2, cfg, p, num_flatten_dims), None
+    shape = ln2.shape
+    rows = ln2 if num_flatten_dims == 1 \
+        else layers.reshape(ln2, shape=[-1, cfg.d_model])
+    out, idx, load = layers.moe_ffn(
+        rows, cfg.n_experts, cfg.expert_width, cfg.experts_per_token,
+        norm_topk_prob=cfg.norm_topk_prob, length=length, valid=valid,
+        router_param_attr=ParamAttr(name=p + '.moe.router.w'),
+        gate_param_attr=ParamAttr(name=p + '.moe.gate.w'),
+        up_param_attr=ParamAttr(name=p + '.moe.up.w'),
+        down_param_attr=ParamAttr(name=p + '.moe.down.w'))
+    if num_flatten_dims != 1:
+        out = layers.reshape(out, shape=[-1] + list(shape[1:]))
+    return out, (idx, load)
+
+
+def _lm_head(cfg, x):
+    return layers.fc(x, size=cfg.vocab_size,
+                     param_attr=ParamAttr(name='lm_head.w'),
+                     bias_attr=False)
+
+
+def _expert_outputs(out, tokens, routing):
+    """What a decode program of an expert model adds to its outputs:
+    'tokens_and_load', the tokens and the per-layer expert loads
+    (``[n_layer * n_experts]``) as ONE int64 vector — one device-to-host
+    transfer, as without experts (serving/generate.py splits it) — and
+    'topk_idx', each layer's chosen experts, for the checks."""
+    if routing:
+        load = layers.cast(layers.concat([r[1] for r in routing], axis=0),
+                           'int64')
+        out['tokens_and_load'] = layers.concat([tokens, load], axis=0)
+        out['topk_idx'] = [r[0] for r in routing]
+    return out
+
+
 def _ffn_tail(ln2, cfg, prefix, num_flatten_dims, dropout_prob=0.0,
               is_test=True):
     """The block's FFN tail — fc(d_ff, gelu) -> fc(d_model) -> dropout —
@@ -184,6 +360,7 @@ def build_lm(cfg=None, is_test=False):
     """Causal LM: feeds {'tokens', 'labels'} of shape (B, L) int64; returns
     (tokens, labels, logits, avg_loss)."""
     cfg = cfg or LMConfig()
+    _require_classic_block(cfg, 'build_lm')
     _name_program('lm_eval' if is_test else 'lm_train')
     tokens = layers.data(name='tokens', shape=[cfg.seq_len], dtype='int64')
     labels = layers.data(name='labels', shape=[cfg.seq_len], dtype='int64')
@@ -311,7 +488,7 @@ def _declare_kv_caches(block, cfg, slots, max_len):
 
 
 def _declare_paged_kv_caches(block, cfg, num_blocks, block_size):
-    shape = (num_blocks, cfg.n_layer, block_size, cfg.d_model)
+    shape = (num_blocks, cfg.n_layer, block_size, cfg.kv_width)
     kc = block.create_var(name=KV_CACHE_K, shape=shape, dtype='float32',
                           persistable=True, stop_gradient=True)
     vc = block.create_var(name=KV_CACHE_V, shape=shape, dtype='float32',
@@ -369,52 +546,48 @@ def _qkv_split_step(qkv, cfg):
     return parts
 
 
-def _decode_tower(cfg, x, cache_write, attend, tag='', head=True):
+def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
+                  pos=None, valid=None, routing=None):
     """One decode-position transformer tower over per-slot row state
-    ``x`` ([S, d]: token embedding + position encoding). The cache
-    write and cached attention are delegated to closures so the SAME
-    structural body serves the plain decode step, each of the drafter's
-    unrolled steps, and any future cached-decode flavor — per-position
-    numerics can never drift between them. Returns logits [S, V].
+    ``x`` ([S, d]: token embedding, + position encoding where positions
+    are added). The cache write and cached attention are delegated to
+    closures so the SAME structural body serves the plain decode step,
+    each of the drafter's unrolled steps, and any future cached-decode
+    flavor — per-position numerics can never drift between them. Norm,
+    q/k preparation and FFN are `cfg`'s (`_norm`, `_qkv`, `_ffn`).
+    Returns logits [S, V].
 
     ``tag`` disambiguates intermediate var names when the tower is
     instantiated more than once in one program (the drafter's unroll).
-    ``head=False`` skips the final LayerNorm + LM head and returns
+    ``head=False`` skips the final norm + LM head and returns
     None — the drafter's trailing write-only step needs every layer's
-    K/V deposited but no logits."""
-    d, h = cfg.d_model, cfg.n_head
-    dh = d // h
+    K/V deposited but no logits. ``pos`` ([S, 1], rotary positions),
+    ``valid`` ([S, 1], zero = idle slot) and ``routing`` (a list that
+    takes each layer's `_ffn` routing) serve the blocks that need them."""
     delta = None             # previous layer's deferred FFN output
     for i in range(cfg.n_layer):
         p = 'layer_%d' % i
-        ln1, x = _entry_ln(x, delta, 1, p + '.ln1')
-        qkv = layers.fc(ln1, size=3 * d,
-                        param_attr=ParamAttr(name=p + '.attn.qkv.w'),
-                        bias_attr=ParamAttr(name=p + '.attn.qkv.b'))
-        q, k, v = _qkv_split_step(qkv, cfg)                  # [S, H, dh]
+        ln1, x = _norm(cfg, x, delta, 1, p + '.ln1')
+        q, k, v = _qkv(cfg, ln1, p, pos)                     # [S, H, dh]
         cache_write(k, v, i)
         if not head and i == cfg.n_layer - 1:
             # write-only tower, last layer: nothing consumes x past
             # this K/V deposit — attention/proj/ffn are dead compute
             return None
         ctx = attend(q, i, p + tag)
-        attn = layers.fc(layers.reshape(ctx, shape=[-1, d]), size=d,
+        attn = layers.fc(layers.reshape(ctx, shape=[-1, cfg.kv_width]),
+                         size=cfg.d_model,
                          param_attr=ParamAttr(name=p + '.attn.proj.w'),
-                         bias_attr=ParamAttr(name=p + '.attn.proj.b'))
-        ln2, x = layers.fused_layer_norm_residual(
-            x, attn, begin_norm_axis=1,
-            param_attr=ParamAttr(name=p + '.ln2.w'),
-            bias_attr=ParamAttr(name=p + '.ln2.b'))
-        # decode is inference-only: prob 0 / is_test keeps the op on the
-        # RNG-free bind fast path (no per-step key derivation)
-        delta = _ffn_tail(ln2, cfg, p, 1)
+                         bias_attr=_bias(cfg, p + '.attn.proj.b'))
+        ln2, x = _norm(cfg, x, attn, 1, p + '.ln2')
+        delta, routed = _ffn(cfg, ln2, p, 1, valid=valid)
+        if routed is not None:
+            routing.append(routed)
 
     if not head:
         return None
-    x, _ = _entry_ln(x, delta, 1, 'final_ln')
-    return layers.fc(x, size=cfg.vocab_size,
-                     param_attr=ParamAttr(name='lm_head.w'),
-                     bias_attr=False)                        # [S, V]
+    x, _ = _norm(cfg, x, delta, 1, 'final_ln')
+    return _lm_head(cfg, x)                                  # [S, V]
 
 
 def build_lm_decode_step(cfg, slots, max_len, block_size=None,
@@ -427,11 +600,16 @@ def build_lm_decode_step(cfg, slots, max_len, block_size=None,
     host uniform; all-zero = bitwise greedy), and — paged mode —
     'gen_btab' [slots, max_len // block_size] int64 per-slot block
     tables. Returns {'tokens', 'pos', 'logits', 'next_tokens',
-    'k_cache', 'v_cache'} — fetch 'next_tokens' ([slots] int64)."""
+    'k_cache', 'v_cache'} — fetch 'next_tokens' ([slots] int64). With
+    experts (`cfg.ffn == 'moe'`, paged only) also 'tokens_and_load' —
+    next_tokens and the [n_layer * n_experts] expert loads of the live
+    slots' rows in one int64 vector: fetch that INSTEAD — and 'topk_idx'
+    (`_expert_outputs`)."""
     _name_program('lm_decode_step')
     paged = block_size is not None
-    d, h = cfg.d_model, cfg.n_head
-    dh = d // h
+    if not paged:
+        _require_classic_block(cfg, 'build_lm_decode_step(contiguous)')
+    d, h, dh = cfg.d_model, cfg.n_head, cfg.head_dim
     tokens = layers.data(name='gen_tokens', shape=[1], dtype='int64')
     pos = layers.data(name='gen_pos', shape=[1], dtype='int64')
     sample_vars = _sampling_inputs()
@@ -447,8 +625,9 @@ def build_lm_decode_step(cfg, slots, max_len, block_size=None,
     x = layers.embedding(
         tokens, size=[cfg.vocab_size, d], dtype='float32',
         param_attr=ParamAttr(name='tok_emb.w'))              # [S, d]
-    pe = layers.assign(position_encoding_table(max_len, d))
-    x = layers.elementwise_add(x, layers.gather(pe, pos))
+    if cfg.position == 'sinusoid':
+        pe = layers.assign(position_encoding_table(max_len, d))
+        x = layers.elementwise_add(x, layers.gather(pe, pos))
 
     def cache_write(k, v, layer):
         for cache, new in ((kc, k), (vc, v)):
@@ -481,11 +660,19 @@ def build_lm_decode_step(cfg, slots, max_len, block_size=None,
             attrs=attn_attrs)
         return ctx
 
-    logits = _decode_tower(cfg, x, cache_write, attend)      # [S, V]
+    # an idle slot's table row is all zero and a live slot's first page is
+    # never block 0 (the trash block): the expert loads count live rows
+    valid = layers.slice(btab, axes=[1], starts=[0], ends=[1]) \
+        if cfg.ffn == 'moe' else None
+    routing = []
+    logits = _decode_tower(cfg, x, cache_write, attend, pos=pos,
+                           valid=valid, routing=routing)     # [S, V]
     next_tokens = _append_sample_op(block, logits, sample_vars,
                                     'gen_next_tokens')       # [S]
-    return {'tokens': tokens, 'pos': pos, 'logits': logits,
-            'next_tokens': next_tokens, 'k_cache': kc, 'v_cache': vc}
+    return _expert_outputs(
+        {'tokens': tokens, 'pos': pos, 'logits': logits,
+         'next_tokens': next_tokens, 'k_cache': kc, 'v_cache': vc},
+        next_tokens, routing)
 
 
 def build_lm_drafter(cfg, slots, max_len, spec_k, num_blocks, block_size):
@@ -519,6 +706,7 @@ def build_lm_drafter(cfg, slots, max_len, spec_k, num_blocks, block_size):
     is a PROPOSAL, the target's verify step decides every emitted
     token, so draft sampling would only lower the accept rate."""
     _name_program('lm_drafter')
+    _require_classic_block(cfg, 'build_lm_drafter')
     d, h = cfg.d_model, cfg.n_head
     dh = d // h
     mb = max_len // block_size
@@ -618,6 +806,7 @@ def build_lm_verify(cfg, slots, width, max_len, num_blocks, block_size):
     ([slots * width, vocab], row-major), 'verify_tokens'
     ([slots * width] int64, row-major), 'k_cache', 'v_cache'}."""
     _name_program('lm_verify')
+    _require_classic_block(cfg, 'build_lm_verify')
     d, h = cfg.d_model, cfg.n_head
     dh = d // h
     W = int(width)
@@ -690,6 +879,7 @@ def build_lm_prefill(cfg, prompt_len, slots, max_len):
     steps). Returns {'prompt', 'slot', 'length', 'logits', 'first_token',
     'k_cache', 'v_cache'} — fetch 'first_token' ([1] int64)."""
     _name_program('lm_prefill')
+    _require_classic_block(cfg, 'build_lm_prefill(contiguous)')
     if prompt_len > max_len:
         raise ValueError(
             "prompt bucket %d exceeds the KV cache width max_len=%d"
@@ -793,10 +983,12 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     block table), 'gen_len' [1, 1] int64 (REAL suffix length; pad rows
     write to the trash block), and the `SAMPLE_FEEDS` quad [1, 1].
     Returns {'prompt', 'positions', 'block_table', 'length', 'logits',
-    'first_token', 'k_cache', 'v_cache'}."""
+    'first_token', 'k_cache', 'v_cache'}, and with experts
+    'tokens_and_load' (first_token and the [n_layer * n_experts] expert
+    loads of the REAL suffix rows, one int64 vector: fetch it instead)
+    and 'topk_idx' (`_expert_outputs`)."""
     _name_program('lm_prefill_paged')
-    d, h = cfg.d_model, cfg.n_head
-    dh = d // h
+    d, h, dh = cfg.d_model, cfg.n_head, cfg.head_dim
     T = int(prompt_len)
     prompt = layers.data(name='gen_prompt', shape=[-1, T], dtype='int64')
     pos = layers.data(name='gen_pos', shape=[-1, T], dtype='int64')
@@ -806,15 +998,17 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     block = prompt.block
     kc, vc = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
 
-    emb = layers.embedding(
+    x = layers.embedding(
         prompt, size=[cfg.vocab_size, d], dtype='float32',
         param_attr=ParamAttr(name='tok_emb.w'))              # [1, T, d]
-    # decode-parity positioning: gather the SAME sinusoid table rows the
-    # contiguous prefill's add_position_encoding applies at offset 0
-    pe = layers.assign(position_encoding_table(
-        max_blocks * block_size, d))
-    pe_rows = layers.reshape(layers.gather(pe, pos), shape=[-1, T, d])
-    x = layers.elementwise_add(emb, pe_rows)
+    if cfg.position == 'sinusoid':
+        # decode-parity positioning: gather the SAME sinusoid table rows
+        # the contiguous prefill's add_position_encoding applies at
+        # offset 0
+        pe = layers.assign(position_encoding_table(
+            max_blocks * block_size, d))
+        pe_rows = layers.reshape(layers.gather(pe, pos), shape=[-1, T, d])
+        x = layers.elementwise_add(x, pe_rows)
 
     def cache_write(cache, new, layer):
         block.append_op(
@@ -826,20 +1020,11 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
         return cache
 
     delta = None
+    routing = []
     for i in range(cfg.n_layer):
         p = 'layer_%d' % i
-        ln1, x = _entry_ln(x, delta, 2, p + '.ln1')
-        qkv = layers.fc(ln1, size=3 * d, num_flatten_dims=2,
-                        param_attr=ParamAttr(name=p + '.attn.qkv.w'),
-                        bias_attr=ParamAttr(name=p + '.attn.qkv.b'))
-        qkv = layers.reshape(qkv, shape=[0, T, 3, h, dh])
-        qkv = layers.transpose(qkv, perm=[2, 0, 3, 1, 4])    # (3,1,H,T,dh)
-        q = layers.squeeze(layers.slice(qkv, axes=[0], starts=[0],
-                                        ends=[1]), axes=[0])
-        k = layers.squeeze(layers.slice(qkv, axes=[0], starts=[1],
-                                        ends=[2]), axes=[0])
-        v = layers.squeeze(layers.slice(qkv, axes=[0], starts=[2],
-                                        ends=[3]), axes=[0])
+        ln1, x = _norm(cfg, x, delta, 2, p + '.ln1')
+        q, k, v = _qkv(cfg, ln1, p, pos, T)                  # [1,H,T,dh]
         kc = cache_write(kc, k, i)
         vc = cache_write(vc, v, i)
         ctx = block.create_var(name=p + '.prefix_attn_out',
@@ -852,25 +1037,23 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
             attrs={'layer': i, 'scale': dh ** -0.5,
                    'block_size': int(block_size)})
         ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
-        ctx = layers.reshape(ctx, shape=[0, T, d])
+        ctx = layers.reshape(ctx, shape=[0, T, cfg.kv_width])
         attn = layers.fc(ctx, size=d, num_flatten_dims=2,
                          param_attr=ParamAttr(name=p + '.attn.proj.w'),
-                         bias_attr=ParamAttr(name=p + '.attn.proj.b'))
-        ln2, x = layers.fused_layer_norm_residual(
-            x, attn, begin_norm_axis=2,
-            param_attr=ParamAttr(name=p + '.ln2.w'),
-            bias_attr=ParamAttr(name=p + '.ln2.b'))
-        delta = _ffn_tail(ln2, cfg, p, 2)
+                         bias_attr=_bias(cfg, p + '.attn.proj.b'))
+        ln2, x = _norm(cfg, x, attn, 2, p + '.ln2')
+        delta, routed = _ffn(cfg, ln2, p, 2, length=length)
+        if routed is not None:
+            routing.append(routed)
 
-    x, _ = _entry_ln(x, delta, 2, 'final_ln')
+    x, _ = _norm(cfg, x, delta, 2, 'final_ln')
     x_flat = layers.reshape(x, shape=[-1, d])                # [T, d]
     one = layers.fill_constant(shape=[1], dtype='int64', value=1)
     last = layers.gather(x_flat, layers.elementwise_sub(length, one))
-    logits = layers.fc(last, size=cfg.vocab_size,
-                       param_attr=ParamAttr(name='lm_head.w'),
-                       bias_attr=False)                      # [1, V]
+    logits = _lm_head(cfg, last)                             # [1, V]
     first_token = _append_sample_op(block, logits, sample_vars,
                                     'gen_first_token')       # [1]
-    return {'prompt': prompt, 'positions': pos, 'block_table': btab,
-            'length': length, 'logits': logits,
-            'first_token': first_token, 'k_cache': kc, 'v_cache': vc}
+    return _expert_outputs(
+        {'prompt': prompt, 'positions': pos, 'block_table': btab,
+         'length': length, 'logits': logits, 'first_token': first_token,
+         'k_cache': kc, 'v_cache': vc}, first_token, routing)

@@ -624,6 +624,29 @@ class GenerateEngine(object):
                                 self._max_blocks)
                     self._draft_prefill[b] = (main, v)
 
+    @staticmethod
+    def _token_fetch(v, tokens):
+        """The one var a decode program's dispatch fetches: its tokens,
+        or with experts the tokens and the expert loads in one vector
+        (`_split_load` takes them apart)."""
+        return v.get('tokens_and_load', v[tokens])
+
+    def _split_load(self, out, n_tokens):
+        """The fetched vector as tokens; the expert loads behind them
+        (a model with experts) go into the moe_* counters: per layer-step
+        the assignments, the experts touched and the busiest expert's
+        rows — four scalar adds, no label (docs/observability.md)."""
+        flat = np.asarray(out).reshape(-1)
+        if flat.size > n_tokens:
+            load = flat[n_tokens:].reshape(self.config.model.n_layer, -1)
+            monitor.inc('moe_layer_steps_total', load.shape[0])
+            monitor.inc('moe_assignments_total', int(load.sum()))
+            monitor.inc('moe_experts_touched_total',
+                        int(np.count_nonzero(load)))
+            monitor.inc('moe_max_expert_rows_total',
+                        int(load.max(axis=1).sum()))
+        return flat[:n_tokens]
+
     def _init_state(self):
         import jax.numpy as jnp
         cfg, c = self.config.model, self.config
@@ -659,10 +682,10 @@ class GenerateEngine(object):
         import jax.numpy as jnp
         cfg, c = self.config.model, self.config
         if c.paged:
-            shape = (c.num_blocks, cfg.n_layer, c.block_size, cfg.d_model)
+            shape = (c.num_blocks, cfg.n_layer, c.block_size, cfg.kv_width)
         else:
             shape = (c.slots, cfg.n_layer, cfg.n_head, c.max_len,
-                     cfg.d_model // cfg.n_head)
+                     cfg.head_dim)
         have = self.scope.get(KV_CACHE_K)
         if have is None or tuple(have.shape) != shape:
             self.scope.set(KV_CACHE_K, jnp.zeros(shape, 'float32'))
@@ -670,7 +693,7 @@ class GenerateEngine(object):
         if c.speculative:
             dcfg = self._draft_cfg
             dshape = (self._draft_nb, dcfg.n_layer, c.block_size,
-                      dcfg.d_model)
+                      dcfg.kv_width)
             dhave = self._draft_scope.get(KV_CACHE_K)
             if dhave is None or tuple(dhave.shape) != dshape:
                 self._draft_scope.set(KV_CACHE_K,
@@ -805,12 +828,12 @@ class GenerateEngine(object):
                 else:
                     feed['gen_slot'] = np.zeros((1, 1), 'int64')
                 feed.update(self._sample_feed(1))
+                fetch = [self._token_fetch(v, 'first_token')]
                 key, already = farm.track(self.executor, prog, feed,
-                                          fetch_list=[v['first_token']],
+                                          fetch_list=fetch,
                                           scope=self.scope)
                 self._prefill_bound[b] = self.executor.bind(
-                    prog, feed, fetch_list=[v['first_token']],
-                    scope=self.scope)
+                    prog, feed, fetch_list=fetch, scope=self.scope)
                 if already:
                     reused += 1
                 else:
@@ -821,14 +844,12 @@ class GenerateEngine(object):
                 feed['gen_btab'] = np.zeros((S, self._max_blocks),
                                             'int64')
             feed.update(self._sample_feed(S))
+            fetch = [self._token_fetch(self._step_vars, 'next_tokens')]
             key, already = farm.track(
-                self.executor, self._step_prog, feed,
-                fetch_list=[self._step_vars['next_tokens']],
+                self.executor, self._step_prog, feed, fetch_list=fetch,
                 scope=self.scope)
             self._step_bound = self.executor.bind(
-                self._step_prog, feed,
-                fetch_list=[self._step_vars['next_tokens']],
-                scope=self.scope)
+                self._step_prog, feed, fetch_list=fetch, scope=self.scope)
             if already:
                 reused += 1
             else:
@@ -1132,7 +1153,7 @@ class GenerateEngine(object):
                 sf['gen_topp'][0], sf['gen_u'][0] = sample[2], draw_u()
                 feed.update(sf)
                 out = self._step_bound(feed)
-                last = int(np.asarray(out[0]).reshape(-1)[0])
+                last = int(self._split_load(out[0], S)[0])
                 tokens.append(last)
                 pos += 1
             return tokens
@@ -1426,7 +1447,8 @@ class GenerateEngine(object):
                     'gen_btab': table[None],
                     'gen_len': np.array([[wide]], 'int64')}
             feed.update(self._sample_feed(1))
-            bound[wide](feed)       # K/V deposited; token output unused
+            # K/V deposited; token output unused
+            self._split_load(bound[wide](feed)[0], 1)
             off += wide
         b = bucketize(suffix.size, c.prompt_buckets)
         padded = np.full((1, b), c.pad_id, 'int64')
@@ -1437,8 +1459,7 @@ class GenerateEngine(object):
                 'gen_btab': table[None],
                 'gen_len': np.array([[suffix.size]], 'int64')}
         feed.update(self._sample_feed(1, *sample))
-        out = bound[b](feed)
-        return int(np.asarray(out[0]).reshape(-1)[0])
+        return int(self._split_load(bound[b](feed)[0], 1)[0])
 
     def _step(self):
         """One decode step, dispatch + completion back to back (the
@@ -1825,7 +1846,7 @@ class GenerateEngine(object):
             # materialization = device completion; an async runtime
             # failure surfaces here and fails the step's residents
             with _loop_phase('wait'):
-                nxt = np.asarray(out[0]).reshape(-1)
+                fetched = np.asarray(out[0])
                 monitor.observe(
                     'decode_step_seconds',
                     max(0.0, time.perf_counter() - t0 - exclude_s))
@@ -1833,7 +1854,8 @@ class GenerateEngine(object):
             self._fail_step(active, e)
             return
         with _loop_phase('deliver'):
-            self._deliver(active, nxt)
+            self._deliver(active, self._split_load(fetched,
+                                                   self.config.slots))
 
     def _deliver(self, active, nxt):
         """The host's share of a completed step: per-slot bookkeeping,

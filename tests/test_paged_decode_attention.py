@@ -63,12 +63,15 @@ def _pools(rng, nb, ln, bs, hd):
 
 
 # (S, H, bs, dh, MB): toy; fairseq-dense 355M's heads, block and table at
-# fewer slots; 1.3B's. Pools of 40 blocks, 2 layers.
-SHAPES = [(6, 2, 8, 64, 4), (6, 16, 16, 64, 48), (6, 32, 16, 64, 66)]
+# fewer slots; 1.3B's; OLMoE's 16 heads of 128 (a head takes a whole vreg:
+# the kernel's `heads is None` branch) at a shorter table. Pools of 40
+# blocks, 2 layers.
+SHAPES = [(6, 2, 8, 64, 4), (6, 16, 16, 64, 48), (6, 32, 16, 64, 66),
+          (6, 16, 16, 128, 12)]
 
 
 @pytest.mark.parametrize('S,H,bs,dh,MB', SHAPES,
-                         ids=['toy', 'fd355m', 'fd1.3b'])
+                         ids=['toy', 'fd355m', 'fd1.3b', 'olmoe-head128'])
 def test_interpret_tier_matches_off_tier(monkeypatch, S, H, bs, dh, MB):
     assert pda.shapes_ok(H, dh, bs)
     rng = np.random.RandomState(S * H + MB)
@@ -205,12 +208,14 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize('S,H,MB,NB', [(32, 16, 48, 1024), (4, 32, 66, 265)],
-                         ids=['fd355m-serve-chat', 'fd1.3b-serve-doc'])
+@pytest.mark.parametrize('S,H,MB,NB,dh,ln', [
+    (32, 16, 48, 1024, 64, 24), (4, 32, 66, 265, 64, 24),
+    (16, 16, 80, 1280, 128, 6)],
+    ids=['fd355m-serve-chat', 'fd1.3b-serve-doc', 'olmoe-serve-chat16'])
 def test_mosaic_accepts_the_kernel_at_the_cells_shapes(one_chip, S, H, MB,
-                                                       NB):
+                                                       NB, dh, ln):
     import jax
-    bs, dh, ln, layer = 16, 64, 24, 3
+    bs, layer = 16, 3
 
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
