@@ -459,9 +459,14 @@ def build_lm(cfg=None, is_test=False):
 #
 # Both decode-step flavors (and both prefills, for the FIRST token) end
 # in the `sample_next_token` op: per-slot temperature / top-k / top-p
-# feeds plus a host-fed uniform drive sampling; temperature 0 rows take
-# the bitwise argmax branch, so greedy engines are bit-identical to the
-# pre-sampling programs' outputs.
+# feeds plus a host-fed uniform drive sampling; temperature 0 rows return
+# the bitwise argmax, so greedy engines are bit-identical to the
+# pre-sampling programs' outputs. The op decides on the device from its
+# temperature feed (one program, no engine option): a step whose rows
+# are all greedy computes the argmax and nothing else; a step with a
+# sampled row sorts the vocabulary once for every row (the sort carries
+# the values, nothing gathers [rows, vocab]) and its greedy rows are
+# still the argmax.
 #
 # SPECULATIVE decoding (PR 13) adds two paged-only program shapes:
 # - build_lm_drafter: spec_k greedy decode steps UNROLLED in-program

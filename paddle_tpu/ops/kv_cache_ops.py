@@ -75,8 +75,14 @@ prefill compute entirely.
 over the step logits, driven by a HOST-FED per-slot uniform (the
 engine owns one PRNG stream per request), so the op is deterministic,
 ``needs_rng``-free (bind's single-PRNGKey fast path still applies), and
-``temperature == 0`` rows take the bitwise argmax branch — greedy stays
-the bitwise default.
+``temperature == 0`` rows return the bitwise argmax — greedy stays the
+bitwise default. It is the op every decode step and every prefill ends
+in, so it adapts to what it is fed, on the device: a step with no
+sampled row takes ``argmax`` and nothing else (a ``lax.cond`` on
+``any(Temp > 0)``), and a step with one sorts the vocabulary once and
+lets that sort carry the values — neither gathers ``[S, V]`` (that
+gather was 15.5 ms of a 22.4 ms decode step at 32 x 50264 on the v5e,
+PERF.md PR 26 / PR 29).
 
 SPECULATIVE-DECODE ops (PR 13) widen the per-slot decode step from one
 token to a window of ``W = spec_k + 1`` tokens so a target model can
@@ -389,38 +395,57 @@ def _kv_prefix_attention(ctx, op):
 @register_op('sample_next_token', share_lod=False)
 def _sample_next_token(ctx, op):
     """Per-row temperature / top-k / top-p sampling driven by a host-fed
-    uniform U[s] in [0, 1): sort the temperature-scaled distribution
-    descending, intersect the top-k and top-p (nucleus) keep sets,
-    renormalize, inverse-CDF sample with U. Rows with Temp <= 0 return
-    the bitwise argmax (the greedy default); TopK <= 0 disables top-k,
-    TopP <= 0 or >= 1 disables nucleus. Deterministic given U — the
-    engine owns one host PRNG stream per request, so co-resident slots
-    sample independently and a (seed, prompt) pair replays exactly."""
+    uniform U[s] in [0, 1): sort the distribution descending, intersect
+    the top-k and top-p (nucleus) keep sets, renormalize, inverse-CDF
+    sample with U. Rows with Temp <= 0 return the bitwise argmax (the
+    greedy default); TopK <= 0 disables top-k, TopP <= 0 or >= 1
+    disables nucleus. Deterministic given U — the engine owns one host
+    PRNG stream per request, so co-resident slots sample independently
+    and a (seed, prompt) pair replays exactly.
+
+    The op branches ON THE DEVICE on what it can see in its input
+    (``lax.cond`` on ``any(Temp > 0)``; the host does not choose and
+    there is one program): a step whose rows are all greedy takes the
+    argmax and nothing else — no sort, no softmax, no pass over the
+    vocabulary but the one. A step with at least one sampled row runs
+    the sampled branch for every row and its greedy rows still take the
+    argmax through the final ``where``. Neither branch gathers ``[S, V]``:
+    the one sort carries the negated logits along with their indices,
+    so the sorted distribution is its first result (negation is exact)
+    and the only gather left reads the drawn token out of the order."""
     logits = ctx.in1(op, 'Logits').astype(jnp.float32)     # [S, V]
     temp = ctx.in1(op, 'Temp').reshape(-1)                 # [S]
     topk = ctx.in1(op, 'TopK').reshape(-1).astype(jnp.int32)
     topp = ctx.in1(op, 'TopP').reshape(-1)
     u = ctx.in1(op, 'U').reshape(-1)
     V = logits.shape[1]
-    greedy = jnp.argmax(logits, axis=1).astype(jnp.int64)
-    t = jnp.where(temp > 0, temp, 1.0)[:, None]
-    order = jnp.argsort(-logits, axis=1)                   # stable: ties
-    sorted_logits = jnp.take_along_axis(logits / t, order, axis=1)
-    probs = jax.nn.softmax(sorted_logits, axis=1)
-    ranks = jnp.arange(V)[None, :]
-    k_eff = jnp.where(topk > 0, topk, V)[:, None]
-    p_on = (topp > 0) & (topp < 1.0)
-    p_eff = jnp.where(p_on, topp, 1.0)[:, None]
-    cum = jnp.cumsum(probs, axis=1)
-    # nucleus keeps the smallest head with mass >= p (the first token
-    # always survives); top-k keeps ranks < k; the sets intersect
-    keep = (ranks < k_eff) & ((cum - probs < p_eff) | (ranks == 0))
-    masked = jnp.where(keep, probs, 0.0)
-    mcum = jnp.cumsum(masked, axis=1)
-    total = mcum[:, -1:]
-    # smallest kept index with cumulative mass > u * total
-    j = jnp.sum(mcum <= u[:, None] * total, axis=1)
-    j = jnp.minimum(j, jnp.sum(keep, axis=1) - 1)
-    sampled = jnp.take_along_axis(order, j[:, None], axis=1)[:, 0]
-    out = jnp.where(temp > 0, sampled.astype(jnp.int64), greedy)
-    ctx.out(op, 'Out', out)
+
+    def greedy_step():
+        return jnp.argmax(logits, axis=1).astype(jnp.int64)
+
+    def sampled_step():
+        t = jnp.where(temp > 0, temp, 1.0)[:, None]
+        # stable, so ties keep the vocabulary's order: jnp.argsort(-logits)
+        # is this very sort with its first result thrown away
+        neg_sorted, order = lax.sort_key_val(
+            -logits, lax.broadcasted_iota(jnp.int32, logits.shape, 1),
+            dimension=1, is_stable=True)
+        probs = jax.nn.softmax(-neg_sorted / t, axis=1)
+        ranks = jnp.arange(V)[None, :]
+        k_eff = jnp.where(topk > 0, topk, V)[:, None]
+        p_on = (topp > 0) & (topp < 1.0)
+        p_eff = jnp.where(p_on, topp, 1.0)[:, None]
+        cum = jnp.cumsum(probs, axis=1)
+        # nucleus keeps the smallest head with mass >= p (the first token
+        # always survives); top-k keeps ranks < k; the sets intersect
+        keep = (ranks < k_eff) & ((cum - probs < p_eff) | (ranks == 0))
+        masked = jnp.where(keep, probs, 0.0)
+        mcum = jnp.cumsum(masked, axis=1)
+        total = mcum[:, -1:]
+        # smallest kept index with cumulative mass > u * total
+        j = jnp.sum(mcum <= u[:, None] * total, axis=1)
+        j = jnp.minimum(j, jnp.sum(keep, axis=1) - 1)
+        sampled = jnp.take_along_axis(order, j[:, None], axis=1)[:, 0]
+        return jnp.where(temp > 0, sampled.astype(jnp.int64), greedy_step())
+
+    ctx.out(op, 'Out', lax.cond(jnp.any(temp > 0), sampled_step, greedy_step))

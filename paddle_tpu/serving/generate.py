@@ -538,6 +538,7 @@ class GenerateEngine(object):
         self._lock = threading.Lock()
         self._metrics_server = None
         self._decode_steps = 0
+        self._sampled_steps = 0
         self._decode_tokens = 0
         self._occ_sum = 0.0
         self._occ_peak = 0.0
@@ -1806,6 +1807,13 @@ class GenerateEngine(object):
                 active.append((i, st))
             if not active:
                 return None
+            if (sample['gen_temp'] > 0).any():
+                # the very condition sample_next_token branches on: one
+                # sampled row puts the whole step on its sampled branch
+                # (the sort and three passes over [slots, vocab]); an
+                # all-greedy step is the argmax
+                self._sampled_steps += 1
+                monitor.inc('generate_sampled_steps_total')
             if btab is not None:
                 # what the step's paged attention reads of what its
                 # tables span (stats()['blocks']['decode_live_page_share'])
@@ -1966,6 +1974,7 @@ class GenerateEngine(object):
             'peak_active': self._active_peak,
             'queue_depth': self.queue.depth(),
             'decode_steps': steps,
+            'sampled_steps': self._sampled_steps,
             'decode_tokens': self._decode_tokens,
             'peak_slot_occupancy': round(self._occ_peak, 4),
             'mean_slot_occupancy': round(self._occ_sum / steps, 4)
