@@ -100,7 +100,7 @@ def _check(cond, msg):
 
 
 def _build_train_program(cfg):
-    """The bench.py / examples/train_lm.py recipe. unique_name.guard +
+    """The examples/train_lm.py recipe. unique_name.guard +
     fixed seeds: two builds give identical names, init and dropout keys,
     so Phase C replays Phase A's trajectory."""
     import paddle_tpu as fluid
@@ -287,7 +287,7 @@ def phase_serve(cfg, lm, scope):
 
     c0 = monitor.counters()
     eng = GenerateEngine(GenerateConfig(
-        model=lm, slots=cfg.slots, max_len=cfg.max_len, paged=True,
+        model=lm, slots=cfg.slots, max_len=cfg.max_len,
         block_size=cfg.block_size, prompt_buckets=cfg.prompt_buckets,
         max_new_tokens=cfg.max_new_tokens), scope=scope)
     warm = eng.warmup()

@@ -17,7 +17,7 @@ from ..ops.tensor_ops import position_encoding_table  # noqa: F401
 from ..param_attr import ParamAttr
 
 __all__ = ['multi_head_attention', 'transformer_block', 'build_lm',
-           'LMConfig', 'position_encoding_table', 'build_lm_prefill',
+           'LMConfig', 'position_encoding_table',
            'build_lm_decode_step', 'build_lm_prefill_paged',
            'build_lm_drafter', 'build_lm_verify']
 
@@ -26,10 +26,9 @@ class LMConfig(object):
     """The decoder block, as fields. The defaults are the block this repo
     trains and serves everywhere (pre-LayerNorm, sinusoid positions added
     to the embedding, heads of d_model / n_head, biases, a GELU FFN of
-    width d_ff). The other values are served by the PAGED decode step and
-    the paged prefill only (build_lm_decode_step with a block size,
-    build_lm_prefill_paged); every other builder refuses them by name
-    (`_require_classic_block`):
+    width d_ff). The other values are served by the decode step and the
+    prefill only (build_lm_decode_step, build_lm_prefill_paged); every
+    other builder refuses them by name (`_require_classic_block`):
 
     - ``norm='rms_norm'`` (``rms_eps``): RMSNorm, no bias, for every norm;
     - ``position='rope'`` (``rope_theta``): nothing is added to the
@@ -107,8 +106,7 @@ def _require_classic_block(cfg, who):
         if getattr(cfg, field) != value:
             raise ValueError(
                 "%s cannot express LMConfig.%s=%r (it builds %r): only the "
-                "paged decode step and the paged prefill "
-                "(build_lm_decode_step with a block size, "
+                "decode step and the prefill (build_lm_decode_step, "
                 "build_lm_prefill_paged) build that block"
                 % (who, field, getattr(cfg, field), value))
 
@@ -425,50 +423,45 @@ def build_lm(cfg=None, is_test=False):
 # ---------------------------------------------------------------------------
 # Generative decode programs (serving/generate.py)
 #
-# Two program shapes drive autoregressive generation against a persistent
-# device-resident KV cache ([slots, layers, heads, max_len, head_dim]
-# persistable buffers shared BY NAME with every program in the engine's
-# scope — like params, the cache is ordinary executor state, so donation
-# updates it in place):
+# Two program shapes drive autoregressive generation against ONE persistent
+# device-resident KV cache: a pool of fixed-size blocks,
+# [num_blocks, layers, block_size, heads * head_dim] persistable buffers
+# shared BY NAME with every program in the engine's scope — like params,
+# the cache is ordinary executor state, so donation updates it in place.
+# A page (one block of one layer) is one contiguous [block_size, d_model]
+# run of HBM, addressed through runtime-fed per-slot block tables
+# (ops/kv_cache_ops.py). The table is an ordinary feed, so the program
+# count and every compiled signature stay fixed — serving/generate.py's
+# allocator decides the physical layout per request at admission time.
 #
-# - build_lm_prefill: one compiled signature per prompt bucket. Runs the
-#   full causal forward of ONE prompt (padded to the bucket), deposits its
-#   K/V rows into the request's cache slot, and emits the first generated
-#   token (argmax at the last REAL position).
+# - build_lm_prefill_paged: one compiled signature per prompt bucket. Runs
+#   the causal forward of ONE prompt suffix (padded to the bucket) against
+#   whatever prefix the slot's table already holds, deposits its K/V rows
+#   into the slot's blocks, and emits the first generated token (the
+#   sampled token at the last REAL position).
 # - build_lm_decode_step: ONE compiled signature per engine. Advances every
 #   slot one token: deposits each slot's new K/V at its own position and
-#   attends against its cached history. All ops are slot-row-independent,
-#   so requests admitted/evicted at token boundaries never perturb their
-#   neighbors' numerics (the parity contract tests/test_generate.py pins).
+#   attends against its cached history (`kv_decode_attention_paged` reads
+#   each slot's live pages in place from the pool — a Pallas kernel on the
+#   chip, ops/paged_decode_attention.py; the prefill and verify programs
+#   still gather a table row's pages into a dense K/V first). All ops are
+#   slot-row-independent, so requests admitted/evicted at token boundaries
+#   never perturb their neighbors' numerics (the parity contract
+#   tests/test_generate.py pins).
 #
 # Parameter names match build_lm exactly — a scope trained (or loaded) for
 # the LM serves decode without any renaming.
 #
-# PAGED mode (PR 12): pass block_size/num_blocks to build_lm_decode_step
-# (or use build_lm_prefill_paged) and the cache becomes
-# [num_blocks, layers, block_size, heads * head_dim] — a page (one block
-# of one layer) is one contiguous [block_size, d_model] run of HBM —
-# addressed through runtime-fed per-slot block tables (ops/kv_cache_ops.py
-# paged variants). The decode step's `kv_decode_attention_paged` reads
-# each slot's live pages in place from that pool (a Pallas kernel on the
-# chip, ops/paged_decode_attention.py); the prefill and verify programs
-# still gather a table row's pages into a dense K/V first.
-# The table is an ordinary feed, so the program count and every compiled
-# signature stay fixed — serving/generate.py's allocator decides the
-# physical layout per request at admission time.
+# The decode step (and the prefill, for the FIRST token) ends in the
+# `sample_next_token` op: per-slot temperature / top-k / top-p feeds plus
+# a host-fed uniform drive sampling; temperature 0 rows return the bitwise
+# argmax. The op decides on the device from its temperature feed (one
+# program, no engine option): a step whose rows are all greedy computes
+# the argmax and nothing else; a step with a sampled row sorts the
+# vocabulary once for every row (the sort carries the values, nothing
+# gathers [rows, vocab]) and its greedy rows are still the argmax.
 #
-# Both decode-step flavors (and both prefills, for the FIRST token) end
-# in the `sample_next_token` op: per-slot temperature / top-k / top-p
-# feeds plus a host-fed uniform drive sampling; temperature 0 rows return
-# the bitwise argmax, so greedy engines are bit-identical to the
-# pre-sampling programs' outputs. The op decides on the device from its
-# temperature feed (one program, no engine option): a step whose rows
-# are all greedy computes the argmax and nothing else; a step with a
-# sampled row sorts the vocabulary once for every row (the sort carries
-# the values, nothing gathers [rows, vocab]) and its greedy rows are
-# still the argmax.
-#
-# SPECULATIVE decoding (PR 13) adds two paged-only program shapes:
+# SPECULATIVE decoding (PR 13) adds two program shapes:
 # - build_lm_drafter: spec_k greedy decode steps UNROLLED in-program
 #   (each one the same `_decode_tower` as the decode step), the draft
 #   model's K proposals in one dispatch.
@@ -480,16 +473,6 @@ def build_lm(cfg=None, is_test=False):
 
 KV_CACHE_K = 'gen_kv_k'
 KV_CACHE_V = 'gen_kv_v'
-
-
-def _declare_kv_caches(block, cfg, slots, max_len):
-    dh = cfg.d_model // cfg.n_head
-    shape = (slots, cfg.n_layer, cfg.n_head, max_len, dh)
-    kc = block.create_var(name=KV_CACHE_K, shape=shape, dtype='float32',
-                          persistable=True, stop_gradient=True)
-    vc = block.create_var(name=KV_CACHE_V, shape=shape, dtype='float32',
-                          persistable=True, stop_gradient=True)
-    return kc, vc
 
 
 def _declare_paged_kv_caches(block, cfg, num_blocks, block_size):
@@ -523,19 +506,6 @@ def _append_sample_op(block, logits, sample_vars, out_name):
                 'TopP': [topp], 'U': [u]},
         outputs={'Out': [out]})
     return out
-
-
-def _cache_write(block, op_type, cache, new, index_var, layer):
-    """Append a cache-write op whose output IS the cache var (read-modify-
-    write persistable state: the executor returns it as new state and
-    donation aliases the update in place)."""
-    index_slot = 'Slot' if op_type == 'kv_cache_prefill' else 'Positions'
-    block.append_op(
-        type=op_type,
-        inputs={'Cache': [cache], 'New': [new], index_slot: [index_var]},
-        outputs={'Out': [cache]},
-        attrs={'layer': int(layer)})
-    return cache
 
 
 def _qkv_split_step(qkv, cfg):
@@ -595,37 +565,29 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
     return _lm_head(cfg, x)                                  # [S, V]
 
 
-def build_lm_decode_step(cfg, slots, max_len, block_size=None,
-                         num_blocks=None):
+def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
     """Single-token decode step over ALL cache slots.
 
     Feeds: 'gen_tokens' [slots, 1] int64 (each slot's last token),
     'gen_pos' [slots, 1] int64 (the position each slot writes this step),
     the `SAMPLE_FEEDS` quad [slots, 1] (temperature / top-k / top-p /
-    host uniform; all-zero = bitwise greedy), and — paged mode —
-    'gen_btab' [slots, max_len // block_size] int64 per-slot block
-    tables. Returns {'tokens', 'pos', 'logits', 'next_tokens',
-    'k_cache', 'v_cache'} — fetch 'next_tokens' ([slots] int64). With
-    experts (`cfg.ffn == 'moe'`, paged only) also 'tokens_and_load' —
-    next_tokens and the [n_layer * n_experts] expert loads of the live
-    slots' rows in one int64 vector: fetch that INSTEAD — and 'topk_idx'
+    host uniform; all-zero = bitwise greedy), and 'gen_btab'
+    [slots, max_len // block_size] int64 per-slot block tables. Returns
+    {'tokens', 'pos', 'logits', 'next_tokens', 'k_cache', 'v_cache'} —
+    fetch 'next_tokens' ([slots] int64). With experts
+    (`cfg.ffn == 'moe'`) also 'tokens_and_load' — next_tokens and the
+    [n_layer * n_experts] expert loads of the live slots' rows in one
+    int64 vector: fetch that INSTEAD — and 'topk_idx'
     (`_expert_outputs`)."""
     _name_program('lm_decode_step')
-    paged = block_size is not None
-    if not paged:
-        _require_classic_block(cfg, 'build_lm_decode_step(contiguous)')
     d, h, dh = cfg.d_model, cfg.n_head, cfg.head_dim
     tokens = layers.data(name='gen_tokens', shape=[1], dtype='int64')
     pos = layers.data(name='gen_pos', shape=[1], dtype='int64')
     sample_vars = _sampling_inputs()
     block = tokens.block
-    if paged:
-        mb = max_len // block_size
-        btab = layers.data(name='gen_btab', shape=[mb], dtype='int64')
-        kc, vc = _declare_paged_kv_caches(block, cfg, num_blocks,
-                                          block_size)
-    else:
-        kc, vc = _declare_kv_caches(block, cfg, slots, max_len)
+    mb = max_len // block_size
+    btab = layers.data(name='gen_btab', shape=[mb], dtype='int64')
+    kc, vc = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
 
     x = layers.embedding(
         tokens, size=[cfg.vocab_size, d], dtype='float32',
@@ -636,10 +598,6 @@ def build_lm_decode_step(cfg, slots, max_len, block_size=None,
 
     def cache_write(k, v, layer):
         for cache, new in ((kc, k), (vc, v)):
-            if not paged:
-                _cache_write(block, 'kv_cache_update', cache, new,
-                             pos, layer)
-                continue
             block.append_op(
                 type='kv_cache_update_paged',
                 inputs={'Cache': [cache], 'New': [new],
@@ -651,18 +609,13 @@ def build_lm_decode_step(cfg, slots, max_len, block_size=None,
     def attend(q, layer, name):
         ctx = block.create_var(name=name + '.kv_ctx',
                                shape=(-1, h, dh), dtype='float32')
-        attn_inputs = {'Q': [q], 'KCache': [kc], 'VCache': [vc],
-                       'Positions': [pos]}
-        attn_attrs = {'layer': layer, 'scale': dh ** -0.5}
-        if paged:
-            attn_inputs['BlockTables'] = [btab]
-            attn_attrs['block_size'] = int(block_size)
         block.append_op(
-            type='kv_decode_attention_paged' if paged
-            else 'kv_decode_attention',
-            inputs=attn_inputs,
+            type='kv_decode_attention_paged',
+            inputs={'Q': [q], 'KCache': [kc], 'VCache': [vc],
+                    'Positions': [pos], 'BlockTables': [btab]},
             outputs={'Out': [ctx]},
-            attrs=attn_attrs)
+            attrs={'layer': layer, 'scale': dh ** -0.5,
+                   'block_size': int(block_size)})
         return ctx
 
     # an idle slot's table row is all zero and a live slot's first page is
@@ -874,101 +827,6 @@ def build_lm_verify(cfg, slots, width, max_len, num_blocks, block_size):
             'k_cache': kc, 'v_cache': vc}
 
 
-def build_lm_prefill(cfg, prompt_len, slots, max_len):
-    """Prefill ONE prompt (padded to `prompt_len`, a bucket cell) into one
-    cache slot and emit the first generated token.
-
-    Feeds: 'gen_prompt' [1, prompt_len] int64, 'gen_slot' [1, 1] int64,
-    'gen_len' [1, 1] int64 (real prompt length; pad rows beyond it are
-    causal-masked out of the answer and overwritten by later decode
-    steps). Returns {'prompt', 'slot', 'length', 'logits', 'first_token',
-    'k_cache', 'v_cache'} — fetch 'first_token' ([1] int64)."""
-    _name_program('lm_prefill')
-    _require_classic_block(cfg, 'build_lm_prefill(contiguous)')
-    if prompt_len > max_len:
-        raise ValueError(
-            "prompt bucket %d exceeds the KV cache width max_len=%d"
-            % (prompt_len, max_len))
-    d, h = cfg.d_model, cfg.n_head
-    dh = d // h
-    T = int(prompt_len)
-    prompt = layers.data(name='gen_prompt', shape=[-1, T], dtype='int64')
-    slot = layers.data(name='gen_slot', shape=[1], dtype='int64')
-    length = layers.data(name='gen_len', shape=[1], dtype='int64')
-    sample_vars = _sampling_inputs()
-    block = prompt.block
-    kc, vc = _declare_kv_caches(block, cfg, slots, max_len)
-
-    emb = layers.embedding(
-        prompt, size=[cfg.vocab_size, d], dtype='float32',
-        param_attr=ParamAttr(name='tok_emb.w'))              # [1, T, d]
-    x = layers.add_position_encoding(emb, alpha=1.0, beta=1.0)
-
-    use_flash = bool(getattr(cfg, 'use_flash_attention', False))
-    mask_var = None
-    if not use_flash:
-        causal_mask = np.triu(np.full((T, T), -1e9, dtype='float32'), k=1)
-        mask_var = layers.assign(causal_mask)
-
-    delta = None
-    for i in range(cfg.n_layer):
-        p = 'layer_%d' % i
-        ln1, x = _entry_ln(x, delta, 2, p + '.ln1')
-        qkv = layers.fc(ln1, size=3 * d, num_flatten_dims=2,
-                        param_attr=ParamAttr(name=p + '.attn.qkv.w'),
-                        bias_attr=ParamAttr(name=p + '.attn.qkv.b'))
-        qkv = layers.reshape(qkv, shape=[0, T, 3, h, dh])
-        qkv = layers.transpose(qkv, perm=[2, 0, 3, 1, 4])    # (3,1,H,T,dh)
-        q = layers.squeeze(layers.slice(qkv, axes=[0], starts=[0],
-                                        ends=[1]), axes=[0])
-        k = layers.squeeze(layers.slice(qkv, axes=[0], starts=[1],
-                                        ends=[2]), axes=[0])
-        v = layers.squeeze(layers.slice(qkv, axes=[0], starts=[2],
-                                        ends=[3]), axes=[0])
-        kc = _cache_write(block, 'kv_cache_prefill', kc, k, slot, i)
-        vc = _cache_write(block, 'kv_cache_prefill', vc, v, slot, i)
-        if use_flash:
-            ctx = block.create_var(name=p + '.prefill_flash_out',
-                                   shape=(-1, h, T, dh), dtype='float32')
-            block.append_op(
-                type='flash_attention',
-                inputs={'Q': [q], 'K': [k], 'V': [v]},
-                outputs={'Out': [ctx]},
-                attrs={'scale': dh ** -0.5, 'causal': True,
-                       'ring_zigzag': False})
-        else:
-            logits_a = layers.matmul(q, k, transpose_y=True,
-                                     alpha=dh ** -0.5)
-            logits_a = layers.elementwise_add(logits_a, mask_var)
-            weights = layers.softmax(logits_a)
-            ctx = layers.matmul(weights, v)                  # (1,H,T,dh)
-        ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
-        ctx = layers.reshape(ctx, shape=[0, T, d])
-        attn = layers.fc(ctx, size=d, num_flatten_dims=2,
-                         param_attr=ParamAttr(name=p + '.attn.proj.w'),
-                         bias_attr=ParamAttr(name=p + '.attn.proj.b'))
-        ln2, x = layers.fused_layer_norm_residual(
-            x, attn, begin_norm_axis=2,
-            param_attr=ParamAttr(name=p + '.ln2.w'),
-            bias_attr=ParamAttr(name=p + '.ln2.b'))
-        delta = _ffn_tail(ln2, cfg, p, 2)
-
-    x, _ = _entry_ln(x, delta, 2, 'final_ln')
-    # only the last REAL row feeds the LM head: one [1, d] x [d, V] matmul
-    # instead of projecting all T rows to vocab
-    x_flat = layers.reshape(x, shape=[-1, d])                # [T, d]
-    one = layers.fill_constant(shape=[1], dtype='int64', value=1)
-    last = layers.gather(x_flat, layers.elementwise_sub(length, one))
-    logits = layers.fc(last, size=cfg.vocab_size,
-                       param_attr=ParamAttr(name='lm_head.w'),
-                       bias_attr=False)                      # [1, V]
-    first_token = _append_sample_op(block, logits, sample_vars,
-                                    'gen_first_token')       # [1]
-    return {'prompt': prompt, 'slot': slot, 'length': length,
-            'logits': logits, 'first_token': first_token,
-            'k_cache': kc, 'v_cache': vc}
-
-
 def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
                            max_blocks):
     """Prefill one prompt SUFFIX (padded to the `prompt_len` bucket) into
@@ -1008,8 +866,7 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
         param_attr=ParamAttr(name='tok_emb.w'))              # [1, T, d]
     if cfg.position == 'sinusoid':
         # decode-parity positioning: gather the SAME sinusoid table rows
-        # the contiguous prefill's add_position_encoding applies at
-        # offset 0
+        # the decode step gathers, at the suffix's global positions
         pe = layers.assign(position_encoding_table(
             max_blocks * block_size, d))
         pe_rows = layers.reshape(layers.gather(pe, pos), shape=[-1, T, d])

@@ -1,39 +1,34 @@
 """Device-resident KV-cache ops for the generative decode engine.
 
 The serving-side decode path (serving/generate.py) keeps one pair of
-persistable cache buffers per engine, laid out
+persistable cache buffers per engine — a pool of fixed-size blocks, laid
+out
 
-    [slots, layers, heads, max_len, head_dim]
+    [num_blocks, layers, block_size, heads * head_dim]
 
 and compiles exactly TWO program shapes per engine: a per-prompt-bucket
 prefill and a single-token decode step. The cache vars are read-AND-written
 persistables, so the executor's donation path (PR 1) aliases each step's
 updated cache onto the previous buffer — the whole multi-hundred-MB cache
-never doubles in HBM and never crosses the host. Three ops make that
-expressible in program IR:
+never doubles in HBM and never crosses the host.
 
-- ``kv_cache_prefill``: write a whole prompt's K (or V) rows
-  ``[1, H, T, dh]`` into one slot's cache at positions ``0:T`` (the slot id
-  is a runtime feed — one compiled prefill serves every slot).
-- ``kv_cache_update``: the decode-step write — every slot deposits its new
-  token's K (or V) row ``[S, H, dh]`` at its OWN position (a ``[S]`` feed),
-  one scatter for the whole in-flight batch.
-- ``kv_decode_attention``: one-query attention of every slot against its
-  cached keys/values, masked at each slot's current length. Positions past
-  a slot's write head carry stale garbage from earlier tenants of the slot;
-  the mask zeroes their weights EXACTLY (post-softmax ``where``), so a
-  slot's output is bit-identical whatever previously occupied the cache —
-  the property the continuous batcher's parity contract
-  (tests/test_generate.py) rests on.
+Every slot addresses the pool through a runtime-fed BLOCK TABLE — logical
+position ``p`` lives at ``(table[p // block_size], p % block_size)``:
+``kv_cache_prefill_paged`` writes a prompt suffix's K (or V) rows,
+``kv_cache_update_paged`` is the decode-step write (every slot deposits
+its new token's row at its OWN position, one scatter for the whole
+in-flight batch) and ``kv_decode_attention_paged`` the one-query
+attention of every slot against its cached keys/values, masked at each
+slot's current length. Positions past a slot's write head carry stale
+garbage from earlier tenants of the block; the mask zeroes their weights
+EXACTLY (post-softmax ``where``): a masked (stale / trash / other-tenant)
+position contributes ``0 * garbage = 0`` bit-exactly, so a slot's output
+is bit-identical whatever previously occupied the cache — the property
+the continuous batcher's parity contract (tests/test_generate.py) rests
+on. All ops are slot-row-independent: no op mixes data across the slot
+axis, which is what makes admitting/evicting requests at token boundaries
+safe while other slots are mid-sequence.
 
-All three are slot-row-independent: no op mixes data across the slot axis,
-which is what makes admitting/evicting requests at token boundaries safe
-while other slots are mid-sequence.
-
-PAGED variants (PR 12) break the contiguous row-span reservation: the
-physical cache is ``[num_blocks, layers, block_size, heads * head_dim]``
-and every slot addresses it through a runtime-fed BLOCK TABLE — logical
-position ``p`` lives at ``(table[p // block_size], p % block_size)``.
 A PAGE (one block of one layer) is ``[block_size, heads * head_dim]``:
 rows are tokens, lanes are (head, feature). The minor dimension has to
 be a multiple of 128: then the layout XLA gives the pool on a TPU is the
@@ -51,14 +46,12 @@ entries at the SAME physical blocks (serving/kv_blocks.py refcounts
 them, copy-on-write on the first divergent write). Physical block 0 is
 reserved as the TRASH block: table filler entries and redirected
 pad-row writes land there, so an idle slot's garbage computation can
-never scribble over a live block. Masking keeps the exact-zero parity
-contract of the contiguous ops: a masked (stale / trash / other-tenant)
-position contributes ``0 * garbage = 0`` bit-exactly.
+never scribble over a live block.
 
 ``kv_decode_attention_paged`` — the op every decode step spends its
 time in — has three lowerings behind ``kernel_tier.dispatch``: ``off``
 gathers each slot's whole table row into a dense ``[S, H, MB*bs, dh]``
-K and V and runs the contiguous op's einsums on them (the tests'
+K and V and runs plain einsums on them (the tests'
 reference); ``xla`` does the same without the transpose; ``pallas`` /
 ``interpret`` is the kernel of ops/paged_decode_attention.py, which
 reads each slot's LIVE pages in place from the pool — no pool slice, no
@@ -120,57 +113,6 @@ from ..core.registry import register_op
 _NEG_INF = -1e30
 
 
-@register_op('kv_cache_prefill', share_lod=False)
-def _kv_cache_prefill(ctx, op):
-    """Cache[slot, layer, :, 0:T, :] = New[0]  (T = prompt bucket)."""
-    cache = ctx.in1(op, 'Cache')                # [S, Ln, H, M, dh]
-    new = ctx.in1(op, 'New')                    # [1, H, T, dh]
-    slot = ctx.in1(op, 'Slot').reshape(-1).astype(jnp.int32)
-    layer = int(op.attr('layer'))
-    upd = new[:, None].astype(cache.dtype)      # [1, 1, H, T, dh]
-    zero = jnp.int32(0)
-    out = lax.dynamic_update_slice(
-        cache, upd, (slot[0], jnp.int32(layer), zero, zero, zero))
-    ctx.out(op, 'Out', out)
-
-
-@register_op('kv_cache_update', share_lod=False)
-def _kv_cache_update(ctx, op):
-    """Cache[s, layer, :, Positions[s], :] = New[s] for every slot s."""
-    cache = ctx.in1(op, 'Cache')                # [S, Ln, H, M, dh]
-    new = ctx.in1(op, 'New')                    # [S, H, dh]
-    pos = ctx.in1(op, 'Positions').reshape(-1).astype(jnp.int32)
-    layer = int(op.attr('layer'))
-    s = jnp.arange(cache.shape[0])
-    out = cache.at[s, layer, :, pos, :].set(new.astype(cache.dtype))
-    ctx.out(op, 'Out', out)
-
-
-@register_op('kv_decode_attention', share_lod=False)
-def _kv_decode_attention(ctx, op):
-    """One-query attention per slot over its cached K/V, masked to each
-    slot's positions 0..Positions[s] (inclusive: the step's own token was
-    just deposited at Positions[s] by kv_cache_update)."""
-    q = ctx.in1(op, 'Q')                        # [S, H, dh]
-    kc = ctx.in1(op, 'KCache')                  # [S, Ln, H, M, dh]
-    vc = ctx.in1(op, 'VCache')
-    pos = ctx.in1(op, 'Positions').reshape(-1)  # [S]
-    layer = int(op.attr('layer'))
-    scale = op.attr('scale', 1.0)
-    k = kc[:, layer]                            # [S, H, M, dh]
-    v = vc[:, layer]
-    scores = jnp.einsum('shd,shmd->shm', q, k,
-                        preferred_element_type=jnp.float32) * scale
-    m = jnp.arange(k.shape[2])[None, None, :] <= pos[:, None, None]
-    scores = jnp.where(m, scores, _NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)
-    # exact zero for masked positions: stale cache rows must contribute
-    # 0 * garbage = 0 bit-exactly, not exp(-1e30 - max) * garbage
-    w = jnp.where(m, w, 0.0)
-    ctx.out(op, 'Out',
-            jnp.einsum('shm,shmd->shd', w.astype(v.dtype), v))
-
-
 # ---------------------------------------------------------------------------
 # paged (block-table) variants
 
@@ -194,7 +136,7 @@ def _gather_pages(cache, layer, tables, n_head):
 
 def _gather_heads(cache, layer, tables, n_head):
     """`_gather_pages` with heads ahead of positions: the dense
-    ``[..., H, MB*bs, dh]`` K or V of the contiguous ops."""
+    ``[..., H, MB*bs, dh]`` K or V of a slot."""
     return jnp.moveaxis(_gather_pages(cache, layer, tables, n_head), -2, -3)
 
 
@@ -202,9 +144,8 @@ def _gather_heads(cache, layer, tables, n_head):
 def _kv_cache_prefill_paged(ctx, op):
     """Cache[table[(P+t)//bs], layer, :, (P+t)%bs, :] = New[0, :, t, :] for
     suffix rows t < Length; rows at or past the real suffix length are
-    REDIRECTED to the trash block (a contiguous prefill could park pad
-    rows in its own reserved span — a paged slot owns no span, so pad
-    garbage must never land in a real block)."""
+    REDIRECTED to the trash block (a slot owns no span of its own, so
+    pad garbage must never land in a real block)."""
     cache = ctx.in1(op, 'Cache')                # [NB, Ln, bs, H*dh]
     new = ctx.in1(op, 'New')                    # [1, H, T, dh]
     table = ctx.in1(op, 'BlockTable').reshape(-1).astype(jnp.int32)
@@ -315,14 +256,15 @@ def _kv_verify_attention_paged(ctx, op):
 @register_op('kv_decode_attention_paged', share_lod=False)
 def _kv_decode_attention_paged(ctx, op):
     """One-query attention per slot over the pages its BLOCK TABLE names,
-    masked to each slot's positions 0..Positions[s] exactly as the
-    contiguous op: masked (stale / trash / shared-beyond-prefix) rows
-    contribute exact 0. ``pallas`` / ``interpret``: the kernel of
+    masked to each slot's positions 0..Positions[s] (inclusive: the
+    step's own token was just deposited there by kv_cache_update_paged):
+    masked (stale / trash / shared-beyond-prefix) rows contribute exact
+    0. ``pallas`` / ``interpret``: the kernel of
     ops/paged_decode_attention.py, which reads pages 0..Positions[s]//bs
     of each slot in place. ``xla``: the table-wide gather, einsums on
-    the gathered ``[S, MB*bs, H, dh]``. ``off``: that gather moved to the
-    contiguous op's ``[S, H, MB*bs, dh]`` and its einsums, letter for
-    letter. A >1-device mesh has no kernel here (the pool is not
+    the gathered ``[S, MB*bs, H, dh]``. ``off``: that gather moved to
+    ``[S, H, MB*bs, dh]``, heads ahead of positions (the tests'
+    reference). A >1-device mesh has no kernel here (the pool is not
     sharded): it takes ``xla``."""
     from . import kernel_tier, paged_decode_attention as pda
     from ..parallel.api import get_active_mesh
@@ -346,8 +288,8 @@ def _kv_decode_attention_paged(ctx, op):
             q, kc, vc, tables, pos, jnp.int32(layer), scale=float(scale),
             interpret=impl == 'interpret'))
         return
-    # xla keeps the gathered [S, M, H, dh]; off moves it to the contiguous
-    # op's [S, H, M, dh]
+    # xla keeps the gathered [S, M, H, dh]; off moves it to
+    # [S, H, M, dh]
     gather, qk, wv = \
         (_gather_pages, 'shd,smhd->shm', 'shm,smhd->shd') if impl == 'xla' \
         else (_gather_heads, 'shd,shmd->shm', 'shm,shmd->shd')

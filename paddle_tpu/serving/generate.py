@@ -7,11 +7,12 @@ autoregressive model is bounded by the slowest sentence in each batch.
 This module is the decode-native path:
 
 - **Persistent device-resident KV cache.** One pair of persistable
-  ``[slots, layers, heads, max_len, head_dim]`` buffers
-  (models/transformer.py ``KV_CACHE_K``/``KV_CACHE_V``) lives in the
-  engine's scope like any other executor state: the decode step reads AND
-  writes them, so the PR 1 donation path aliases each step's update in
-  place — the cache never doubles in HBM and never crosses the host.
+  ``[num_blocks, layers, block_size, heads * head_dim]`` buffers
+  (models/transformer.py ``KV_CACHE_K``/``KV_CACHE_V``) — a pool of
+  fixed-size blocks — lives in the engine's scope like any other executor
+  state: the decode step reads AND writes them, so the PR 1 donation path
+  aliases each step's update in place — the cache never doubles in HBM
+  and never crosses the host.
 - **Two compiled signatures, fixed forever.** A per-prompt-bucket
   ``prefill`` (prompt lengths pad onto ``prompt_buckets``, the
   reader/bucketing ladder idiom) and ONE single-token ``decode step``
@@ -36,27 +37,25 @@ site exactly as `Executor.run` (a transient fault retries inside the
 step; an exhausted retry fails the RESIDENT requests and the engine keeps
 serving).
 
-PAGED mode (PR 12, ``GenerateConfig(paged=True)``) replaces the
-per-slot ``max_len`` row reservation with a BLOCK pool: the cache is
-``[num_blocks, layers, block_size, heads * head_dim]`` and each slot
-addresses it through a runtime-fed block table, so HBM is committed as
-sequences actually grow — admission is a blocks-available decision
-(serving/kv_blocks.py), eviction returns blocks, and a pool that runs
-dry finishes the starved request with ``finish_reason='cache_full'``.
-On top of the allocator rides PREFIX SHARING: prompts are chain-hashed
-per full block, a hit maps the request's leading table entries onto the
-blocks already holding that prefix (refcounted; copy-on-write when the
-whole prompt lands on shared blocks), and the prefill buckets by
-SUFFIX length — shared-prefix traffic skips both the duplicate storage
-and the shared prefill compute. Both modes sample: per-request
-temperature / top-k / top-p with an independent host PRNG stream per
-request (``sample_seed`` replays exactly); temperature 0 stays the
-bitwise greedy default, and the program count is unchanged —
-``len(prompt_buckets) + 1`` fixed signatures, zero recompiles after
-warmup under any mixed paged traffic.
+THE BLOCK POOL (PR 12). No slot reserves ``max_len`` rows: each slot
+addresses the pool through a runtime-fed block table, so HBM is
+committed as sequences actually grow — admission is a blocks-available
+decision (serving/kv_blocks.py), eviction returns blocks, and a pool
+that runs dry finishes the starved request with
+``finish_reason='cache_full'``. On top of the allocator rides PREFIX
+SHARING: prompts are chain-hashed per full block, a hit maps the
+request's leading table entries onto the blocks already holding that
+prefix (refcounted; copy-on-write when the whole prompt lands on shared
+blocks), and the prefill buckets by SUFFIX length — shared-prefix
+traffic skips both the duplicate storage and the shared prefill
+compute. Requests sample: per-request temperature / top-k / top-p with
+an independent host PRNG stream per request (``sample_seed`` replays
+exactly); temperature 0 stays the bitwise greedy default, and the
+program count is unchanged — ``len(prompt_buckets) + 1`` fixed
+signatures, zero recompiles after warmup under any mixed traffic.
 
-SPECULATIVE DECODING (PR 13, ``GenerateConfig(speculative=True)``,
-paged engines only) breaks the one-token-per-dispatch decode ceiling:
+SPECULATIVE DECODING (PR 13, ``GenerateConfig(speculative=True)``)
+breaks the one-token-per-dispatch decode ceiling:
 a DRAFT model (``draft_model``; default = the target config, so a
 seed-built engine drafts with the target's own weights — the
 100%-accept reference; an int8-converted or distilled small model is
@@ -69,7 +68,7 @@ mismatch falls back to the target's own token — since every emitted
 token IS the target's argmax given the previously emitted tokens,
 greedy output is **bitwise identical** to non-speculative decode,
 speculation only changes how many tokens land per dispatch (up to
-``spec_k + 1``). Rejected rows roll back through the PAGED block
+``spec_k + 1``). Rejected rows roll back through the block
 table: their positions sit past the accepted write head (masked to
 exact zero by every later attention), and tail blocks holding no
 accepted position return to the allocator — no cache bytes are copied
@@ -79,22 +78,22 @@ draft state never alias. Sampled requests co-resident on a speculative
 engine fall the whole batch back to plain steps for those rounds
 (``spec_fallback_total``) — speculation accelerates greedy traffic.
 
-CHUNKED PREFILL (same PR, paged engines): prompts longer than the
-widest bucket no longer reject at submit() — the prefill runs in
-bucket-sized chunks, each chunk attending the cached prefix through
-``kv_prefix_attention`` exactly like a shared-prefix suffix, so
-admission now reaches ``max_len - 1`` tokens with ZERO new compiled
-signatures and the continuation is bit-exact vs a single-shot prefill
-through a wider bucket.
+CHUNKED PREFILL (same PR): prompts longer than the widest bucket do
+not reject at submit() — the prefill runs in bucket-sized chunks, each
+chunk attending the cached prefix through ``kv_prefix_attention``
+exactly like a shared-prefix suffix, so admission reaches
+``max_len - 1`` tokens with ZERO new compiled signatures and the
+continuation is bit-exact vs a single-shot prefill through a wider
+bucket.
 
 Monitor series: ``decode_tokens_total``, ``kv_slot_occupancy``,
 ``decode_step_seconds``, ``prefill_seconds``,
 ``generate_request_total{outcome=ok|error|shed|deadline|rejected|stopped}``,
 ``generate_queue_depth``, ``generate_step_error_total``,
-``generate_warmup_total``; paged mode adds the block-level capacity
-accounting ``kv_blocks_in_use`` / ``kv_blocks_free`` gauges (these
-replace slot occupancy as the saturation signal — slots no longer bound
-memory) and the ``kv_block_cow_total``,
+``generate_warmup_total``; the block-level capacity accounting
+``kv_blocks_in_use`` / ``kv_blocks_free`` gauges (these, not slot
+occupancy, are the saturation signal — slots do not bound memory) and
+the ``kv_block_cow_total``,
 ``kv_prefix_hit_total{outcome=hit|miss}`` and
 ``kv_prefix_tokens_saved_total`` counters. Speculative engines add
 ``spec_propose_total`` / ``spec_accept_total`` /
@@ -119,7 +118,7 @@ from .. import unique_name
 from ..executor import Executor, Scope, scope_guard
 from ..framework import Program, TPUPlace, program_guard
 from ..models.transformer import (KV_CACHE_K, KV_CACHE_V, LMConfig,
-                                  build_lm_decode_step, build_lm_prefill,
+                                  build_lm_decode_step,
                                   build_lm_prefill_paged)
 from ..reader.bucketing import bucketize
 from .kv_blocks import BlockAllocator, PrefixCache, chain_hashes
@@ -206,8 +205,9 @@ class GenerateConfig(object):
 
     - model: an `LMConfig` (decode programs share parameter names with
       `build_lm`, so a scope trained for the LM serves directly).
-    - slots: KV-cache width — the max number of in-flight sequences.
-    - max_len: cache length per slot; prompt + generated tokens beyond it
+    - slots: the max number of in-flight sequences.
+    - max_len: the longest sequence a slot's block table can address
+      (a multiple of block_size); prompt + generated tokens beyond it
       end the request with finish_reason='cache_full'.
     - prompt_buckets: ascending prompt-length ladder; one prefill program
       compiles per bucket. Default: powers of two from 16 up to max_len/2.
@@ -218,18 +218,20 @@ class GenerateConfig(object):
       identical weights — the parity-test contract).
     - metrics_port: as ServingConfig.metrics_port (None falls back to
       PADDLE_METRICS_PORT; the endpoint rides start()/stop()).
-    - paged / block_size / num_blocks / prefix_sharing: paged-KV mode.
+    - block_size / num_blocks / prefix_sharing: the KV block pool.
       `num_blocks` is the PHYSICAL pool size (block 0 is the reserved
       trash block, so `num_blocks - 1` blocks are allocatable); the
-      default matches the contiguous cache's HBM exactly
-      (slots * max_len / block_size), which is how the >= 2x-concurrency
-      contract is stated. `prompt_buckets` bucket the prefill SUFFIX in
-      paged mode — with prefix sharing, a request's prefill cost is its
+      default, slots * max_len / block_size, is the HBM of `max_len`
+      rows reserved for every slot. `prompt_buckets` bucket the prefill
+      SUFFIX — with prefix sharing, a request's prefill cost is its
       un-cached suffix, not its prompt.
+    - paged: accepted and unused — the block pool is the only cache.
+      True is the only value; False raises (the contiguous cache it
+      selected is gone). The benchmark's serve driver still passes it.
     - temperature / top_k / top_p: engine-wide sampling defaults applied
       when submit() passes none. 0 / 0 / 0 = bitwise greedy.
-    - speculative / spec_k / draft_model: speculative decoding (paged
-      engines only). A draft LM proposes `spec_k` greedy tokens per
+    - speculative / spec_k / draft_model: speculative decoding. A
+      draft LM proposes `spec_k` greedy tokens per
       decode round in one dispatch and the target verifies all of them
       in one `spec_k + 1`-wide batched step — greedy output stays
       bitwise identical to non-speculative decode, up to spec_k + 1
@@ -245,7 +247,7 @@ class GenerateConfig(object):
                  prompt_buckets=None, eos_id=None, max_new_tokens=64,
                  pad_id=0, queue_cap=256, default_deadline_s=60.0,
                  seed=0, metrics_port=None, idle_poll_s=0.02,
-                 paged=False, block_size=16, num_blocks=None,
+                 paged=True, block_size=16, num_blocks=None,
                  prefix_sharing=True, temperature=0.0, top_k=0,
                  top_p=0.0, speculative=False, spec_k=4,
                  draft_model=None):
@@ -256,26 +258,25 @@ class GenerateConfig(object):
             raise ValueError("slots must be >= 1")
         if self.max_len < 2:
             raise ValueError("max_len must be >= 2")
-        self.paged = bool(paged)
+        if not paged:
+            raise ValueError(
+                "paged=False: the contiguous KV cache is gone — the block "
+                "pool is the only cache engine (drop the argument)")
         self.block_size = int(block_size)
-        self.prefix_sharing = bool(prefix_sharing) and self.paged
-        if self.paged:
-            if self.block_size < 1:
-                raise ValueError("block_size must be >= 1")
-            if self.max_len % self.block_size:
-                raise ValueError(
-                    "paged mode needs max_len (%d) divisible by "
-                    "block_size (%d) — the block table is "
-                    "max_len/block_size entries wide"
-                    % (self.max_len, self.block_size))
-            if num_blocks is None:
-                num_blocks = self.slots * self.max_len // self.block_size
-            self.num_blocks = int(num_blocks)
-            if self.num_blocks < 2:
-                raise ValueError("num_blocks must be >= 2 (block 0 is "
-                                 "the reserved trash block)")
-        else:
-            self.num_blocks = None
+        self.prefix_sharing = bool(prefix_sharing)
+        if self.block_size < 1:
+            raise ValueError("block_size must be >= 1")
+        if self.max_len % self.block_size:
+            raise ValueError(
+                "max_len (%d) must be divisible by block_size (%d) — the "
+                "block table is max_len/block_size entries wide"
+                % (self.max_len, self.block_size))
+        if num_blocks is None:
+            num_blocks = self.slots * self.max_len // self.block_size
+        self.num_blocks = int(num_blocks)
+        if self.num_blocks < 2:
+            raise ValueError("num_blocks must be >= 2 (block 0 is "
+                             "the reserved trash block)")
         self.temperature = float(temperature)
         self.top_k = int(top_k)
         self.top_p = float(top_p)
@@ -283,11 +284,6 @@ class GenerateConfig(object):
         self.spec_k = int(spec_k)
         self.draft_model = draft_model
         if self.speculative:
-            if not self.paged:
-                raise ValueError(
-                    "speculative decoding rides the paged KV engine "
-                    "(rollback is block-table truncation) — pass "
-                    "paged=True")
             if self.spec_k < 1:
                 raise ValueError("spec_k must be >= 1")
             if draft_model is not None and \
@@ -423,7 +419,7 @@ class _Slot(object):
     __slots__ = ('req', 'pos', 'generated', 'last', 'last_t', 'wall0',
                  'blocks', 'table', 'dblocks', 'dtable', 'draft_stale')
 
-    def __init__(self, req, pos, last, blocks=None, table=None,
+    def __init__(self, req, pos, last, blocks, table,
                  dblocks=None, dtable=None):
         self.req = req
         self.pos = pos          # cache position the NEXT step writes
@@ -431,8 +427,8 @@ class _Slot(object):
         self.last = last        # last generated token (next step's input)
         self.last_t = time.perf_counter()   # previous token's completion
         self.wall0 = time.time() * 1e6      # decode-phase start (us)
-        self.blocks = blocks    # paged: physical block ids, table order
-        self.table = table      # paged: np [max_blocks] int64, filler 0
+        self.blocks = blocks    # physical block ids, table order
+        self.table = table      # np [max_blocks] int64, filler 0
         self.dblocks = dblocks  # speculative: DRAFT-pool block ids
         self.dtable = dtable    # speculative: draft block table
         # plain (fallback) steps write K/V into the TARGET cache only —
@@ -457,7 +453,7 @@ class GenerateEngine(object):
     build_lm); otherwise the engine initializes fresh parameters from
     ``config.seed``.
 
-    ``block_allocator=`` (paged mode) injects a shared pool instead of
+    ``block_allocator=`` injects a shared pool instead of
     the engine-private default — the multi-tenant residency path: a
     `ModelFleet` sizes ONE ``BlockAllocator`` to the real HBM budget
     and hands each co-resident engine a `QuotaBlockAllocator` view, so
@@ -473,31 +469,22 @@ class GenerateEngine(object):
         self.scope = scope if scope is not None else Scope()
         self.executor = Executor(TPUPlace(0))
         c = self.config
-        if block_allocator is not None and not c.paged:
-            raise ValueError(
-                "block_allocator= injection is a paged-mode feature "
-                "(the contiguous cache reserves slots * max_len rows "
-                "up front) — pass paged=True")
-        if c.paged:
-            if block_allocator is not None:
-                if block_allocator.block_size != c.block_size:
-                    raise ValueError(
-                        "injected allocator block_size %d != config "
-                        "block_size %d — the paged kernels address the "
-                        "cache through the table at the allocator's "
-                        "granularity" % (block_allocator.block_size,
-                                         c.block_size))
-                self._alloc = block_allocator
-            else:
-                self._alloc = BlockAllocator(c.num_blocks, c.block_size)
-            self._prefix = PrefixCache(self._alloc) \
-                if c.prefix_sharing else None
-            self._max_blocks = c.max_len // c.block_size
-            self._cow_jit = None
-            self._dcopy_jit = None
+        if block_allocator is not None:
+            if block_allocator.block_size != c.block_size:
+                raise ValueError(
+                    "injected allocator block_size %d != config "
+                    "block_size %d — the paged kernels address the "
+                    "cache through the table at the allocator's "
+                    "granularity" % (block_allocator.block_size,
+                                     c.block_size))
+            self._alloc = block_allocator
         else:
-            self._alloc = None
-            self._prefix = None
+            self._alloc = BlockAllocator(c.num_blocks, c.block_size)
+        self._prefix = PrefixCache(self._alloc) \
+            if c.prefix_sharing else None
+        self._max_blocks = c.max_len // c.block_size
+        self._cow_jit = None
+        self._dcopy_jit = None
         if c.speculative:
             self._draft_cfg = c.draft_model or c.model
             # +1 over the all-slots-at-max_len footprint (the trash
@@ -557,8 +544,7 @@ class GenerateEngine(object):
         self._goodput_fp_set()
         monitor.set_gauge('kv_slot_occupancy', 0.0)
         monitor.set_gauge('generate_queue_depth', 0.0)
-        if c.paged:
-            self._set_block_gauges()
+        self._set_block_gauges()
 
     # ------------------------------------------------------------------
     # build + state
@@ -570,25 +556,20 @@ class GenerateEngine(object):
         with program_guard(self._step_prog, self._startup):
             with unique_name.guard():
                 self._step_vars = build_lm_decode_step(
-                    cfg, c.slots, c.max_len,
-                    block_size=c.block_size if c.paged else None,
+                    cfg, c.slots, c.max_len, block_size=c.block_size,
                     num_blocks=c.num_blocks)
         self._prefill = {}
         for b in c.prompt_buckets:
             # a bucket's prefill is a program of its own: its name says
             # which, so a device trace tells the buckets apart
-            main = Program('lm_prefill%s_b%d'
-                           % ('_paged' if c.paged else '', b))
+            main = Program('lm_prefill_paged_b%d' % b)
             start = Program()
             main.random_seed = c.seed
             with program_guard(main, start):
                 with unique_name.guard():
-                    if c.paged:
-                        v = build_lm_prefill_paged(
-                            cfg, b, c.num_blocks, c.block_size,
-                            self._max_blocks)
-                    else:
-                        v = build_lm_prefill(cfg, b, c.slots, c.max_len)
+                    v = build_lm_prefill_paged(
+                        cfg, b, c.num_blocks, c.block_size,
+                        self._max_blocks)
             self._prefill[b] = (main, v)
         if c.speculative:
             from ..models.transformer import (build_lm_drafter,
@@ -673,20 +654,16 @@ class GenerateEngine(object):
     def _ensure_cache(self):
         """Make the scope's gen_kv_k/v buffers match THIS engine's
         geometry. A provided scope may carry another engine's cache
-        under the same names — contiguous vs paged, or a different
-        slots/max_len/pool shape; the cache holds no trained state, so
-        re-zeroing is always safe, while reusing a mismatched buffer
-        would feed the compiled programs garbage shapes. Re-checked at
-        warmup()/start()/generate_once() so engines sharing one trained
-        scope SEQUENTIALLY each reclaim it (concurrent use of one scope
-        by two live engines stays unsupported)."""
+        under the same names with a different pool shape; the cache
+        holds no trained state, so re-zeroing is always safe, while
+        reusing a mismatched buffer would feed the compiled programs
+        garbage shapes. Re-checked at warmup()/start()/generate_once()
+        so engines sharing one trained scope SEQUENTIALLY each reclaim
+        it (concurrent use of one scope by two live engines stays
+        unsupported)."""
         import jax.numpy as jnp
         cfg, c = self.config.model, self.config
-        if c.paged:
-            shape = (c.num_blocks, cfg.n_layer, c.block_size, cfg.kv_width)
-        else:
-            shape = (c.slots, cfg.n_layer, cfg.n_head, c.max_len,
-                     cfg.head_dim)
+        shape = (c.num_blocks, cfg.n_layer, c.block_size, cfg.kv_width)
         have = self.scope.get(KV_CACHE_K)
         if have is None or tuple(have.shape) != shape:
             self.scope.set(KV_CACHE_K, jnp.zeros(shape, 'float32'))
@@ -703,7 +680,7 @@ class GenerateEngine(object):
                                       jnp.zeros(dshape, 'float32'))
 
     # ------------------------------------------------------------------
-    # paged helpers
+    # feed + block helpers
     @staticmethod
     def _sample_feed(n, temp=0.0, topk=0, topp=0.0, u=0.0):
         return {'gen_temp': np.full((n, 1), temp, 'float32'),
@@ -777,7 +754,7 @@ class GenerateEngine(object):
         self._set_block_gauges()
 
     def _release_blocks(self, st):
-        self._deref_blocks(st.blocks or [])
+        self._deref_blocks(st.blocks)
         st.blocks = []
         if st.dblocks:
             self._draft_alloc.deref_many(st.dblocks)
@@ -814,20 +791,16 @@ class GenerateEngine(object):
         before = monitor.counters()
         S = self.config.slots
         reused = 0
-        paged = self.config.paged
         with monitor.span('generate.warmup'):
             for b, (prog, v) in sorted(self._prefill.items()):
+                # an all-zero block table points every write at the
+                # reserved trash block — warmup never touches a row a
+                # live request could own
                 feed = {'gen_prompt': np.zeros((1, b), 'int64'),
-                        'gen_len': np.ones((1, 1), 'int64')}
-                if paged:
-                    # an all-zero block table points every write at the
-                    # reserved trash block — warmup never touches a row
-                    # a live request could own
-                    feed['gen_pos'] = np.zeros((1, b), 'int64')
-                    feed['gen_btab'] = np.zeros((1, self._max_blocks),
-                                                'int64')
-                else:
-                    feed['gen_slot'] = np.zeros((1, 1), 'int64')
+                        'gen_len': np.ones((1, 1), 'int64'),
+                        'gen_pos': np.zeros((1, b), 'int64'),
+                        'gen_btab': np.zeros((1, self._max_blocks),
+                                             'int64')}
                 feed.update(self._sample_feed(1))
                 fetch = [self._token_fetch(v, 'first_token')]
                 key, already = farm.track(self.executor, prog, feed,
@@ -840,10 +813,8 @@ class GenerateEngine(object):
                 else:
                     farm.commit(key)
             feed = {'gen_tokens': np.zeros((S, 1), 'int64'),
-                    'gen_pos': np.zeros((S, 1), 'int64')}
-            if paged:
-                feed['gen_btab'] = np.zeros((S, self._max_blocks),
-                                            'int64')
+                    'gen_pos': np.zeros((S, 1), 'int64'),
+                    'gen_btab': np.zeros((S, self._max_blocks), 'int64')}
             feed.update(self._sample_feed(S))
             fetch = [self._token_fetch(self._step_vars, 'next_tokens')]
             key, already = farm.track(
@@ -857,15 +828,14 @@ class GenerateEngine(object):
                 farm.commit(key)
             if self.config.speculative:
                 reused += self._warm_spec(farm)
-            if paged:
-                # compile the copy-on-write block copy now (0 -> 0 is a
-                # trash-block no-op) so steady traffic stays at zero
-                # compiles even when the first COW lands mid-stream
-                self._cow_copy(0, 0)
-                if self.config.speculative and self._draft_copies_target:
-                    # ... and the draft-pool prompt-block copy (same
-                    # trash-block no-op) for the draft==target fast path
-                    self._draft_cache_sync([0], [0])
+            # compile the copy-on-write block copy now (0 -> 0 is a
+            # trash-block no-op) so steady traffic stays at zero
+            # compiles even when the first COW lands mid-stream
+            self._cow_copy(0, 0)
+            if self.config.speculative and self._draft_copies_target:
+                # ... and the draft-pool prompt-block copy (same
+                # trash-block no-op) for the draft==target fast path
+                self._draft_cache_sync([0], [0])
         delta = monitor.counter_delta(before)
         compiles = sum(v for k, v in delta.items()
                        if k.startswith('compile_cache_miss'))
@@ -1006,7 +976,7 @@ class GenerateEngine(object):
                sample_seed=None):
         """Enqueue one prompt (1-D int token ids); returns the
         `GenerateRequest` stream/future. Raises ValueError synchronously
-        for prompts the ladder cannot serve and `LoadShedError` when the
+        for prompts the cache cannot hold and `LoadShedError` when the
         bounded queue is full.
 
         temperature/top_k/top_p default to the engine-wide
@@ -1016,24 +986,17 @@ class GenerateEngine(object):
         co-resident; None draws a fresh unpredictable stream."""
         prompt = np.asarray(prompt, dtype='int64').reshape(-1)
         buckets = self.config.prompt_buckets
-        if self.config.paged:
-            # chunked prefill lifts admission past the bucket ladder:
-            # an over-wide prompt prefills in bucket-sized chunks, each
-            # attending the cached prefix — only the cache length bounds
-            # it (one row must remain for the first decode write)
-            limit = self.config.max_len - 1
-            limit_why = "max_len - 1 (chunked-prefill admission bound)"
-        else:
-            limit = buckets[-1]
-            limit_why = "largest prompt bucket — trim the prompt, " \
-                "widen prompt_buckets, or use paged=True (chunked " \
-                "prefill admits up to max_len - 1)"
+        # chunked prefill lifts admission past the bucket ladder: an
+        # over-wide prompt prefills in bucket-sized chunks, each
+        # attending the cached prefix — only the cache length bounds it
+        # (one row must remain for the first decode write)
+        limit = self.config.max_len - 1
         if prompt.size < 1 or prompt.size > limit:
             monitor.inc('generate_request_total',
                         labels={'outcome': 'rejected'})
             raise ValueError(
-                "prompt length %d outside [1, %d] (%s)"
-                % (prompt.size, limit, limit_why))
+                "prompt length %d outside [1, %d] (max_len - 1: the "
+                "chunked-prefill admission bound)" % (prompt.size, limit))
         if max_new_tokens is None:
             max_new_tokens = self.config.max_new_tokens
         if int(max_new_tokens) < 1:
@@ -1090,9 +1053,9 @@ class GenerateEngine(object):
         against, and a zero-thread debug path. Greedy by default;
         sampling args mirror submit() (a pinned `sample_seed` replays
         the exact submit() sampling stream). Only valid while the engine
-        is NOT started (it shares the loop's cache slots). Paged engines
-        allocate the reference's blocks from the live pool (bypassing
-        the prefix cache) and return every block before returning."""
+        is NOT started (it shares the loop's cache slots). The
+        reference's blocks come from the live pool (bypassing the prefix
+        cache) and every one is returned before returning."""
         if self._started:
             raise RuntimeError(
                 "generate_once drives the decode programs inline and "
@@ -1116,25 +1079,21 @@ class GenerateEngine(object):
             return float(rng[0].random())
 
         sample = (temperature, int(top_k), float(top_p))
-        blocks, table = None, None
-        if c.paged:
-            bs = c.block_size
-            blocks = self._alloc_blocks(-(-prompt.size // bs))
-            if blocks is None:
-                raise RuntimeError(
-                    "paged KV pool cannot hold a %d-token prompt right "
-                    "now (%d blocks free of %d)"
-                    % (prompt.size, self._alloc.available(),
-                       self._alloc.capacity))
-            table = self._slot_table(blocks)
+        blocks = self._alloc_blocks(-(-prompt.size // c.block_size))
+        if blocks is None:
+            raise RuntimeError(
+                "paged KV pool cannot hold a %d-token prompt right "
+                "now (%d blocks free of %d)"
+                % (prompt.size, self._alloc.available(),
+                   self._alloc.capacity))
+        table = self._slot_table(blocks)
         try:
-            first = self._run_prefill(0, prompt,
-                                      sample + (draw_u(),),
-                                      table=table, ctx_len=0)
+            first = self._run_prefill(prompt, table,
+                                      sample + (draw_u(),))
             tokens, last, pos = [first], first, prompt.size
             while (len(tokens) < max_new_tokens and pos < c.max_len and
                    (c.eos_id is None or last != c.eos_id)):
-                if c.paged and pos // c.block_size >= len(blocks):
+                if pos // c.block_size >= len(blocks):
                     grown = self._alloc_blocks(1)
                     if grown is None:     # pool dry: cache_full semantics
                         break
@@ -1144,11 +1103,10 @@ class GenerateEngine(object):
                 toks = np.zeros((S, 1), 'int64')
                 posf = np.zeros((S, 1), 'int64')
                 toks[0], posf[0] = last, pos
-                feed = {'gen_tokens': toks, 'gen_pos': posf}
-                if c.paged:
-                    btab = np.zeros((S, self._max_blocks), 'int64')
-                    btab[0] = table
-                    feed['gen_btab'] = btab
+                btab = np.zeros((S, self._max_blocks), 'int64')
+                btab[0] = table
+                feed = {'gen_tokens': toks, 'gen_pos': posf,
+                        'gen_btab': btab}
                 sf = self._sample_feed(S)
                 sf['gen_temp'][0], sf['gen_topk'][0] = sample[0], sample[1]
                 sf['gen_topp'][0], sf['gen_u'][0] = sample[2], draw_u()
@@ -1159,8 +1117,7 @@ class GenerateEngine(object):
                 pos += 1
             return tokens
         finally:
-            if blocks:
-                self._deref_blocks(blocks)
+            self._deref_blocks(blocks)
 
     # ------------------------------------------------------------------
     # decode loop
@@ -1315,26 +1272,24 @@ class GenerateEngine(object):
         return shared[:n_keep] + new_ids, ctx_len, hashes
 
     def _admit_one(self, req):
-        """Admit one popped request. Returns False when a paged engine
-        must wait for blocks (the request parks in _pending_admit and is
-        retried every token boundary); True when the request was
-        consumed — admitted, finished, or failed."""
+        """Admit one popped request. Returns False when it must wait for
+        blocks (the request parks in _pending_admit and is retried every
+        token boundary); True when the request was consumed — admitted,
+        finished, or failed."""
         c = self.config
-        blocks, table, ctx_len, hashes = None, None, 0, []
-        if c.paged:
-            if -(-req.prompt.size // c.block_size) > self._alloc.capacity:
-                # no eviction can ever fit this prompt: structured
-                # cache_full, zero tokens, nothing leaked
-                monitor.inc('generate_request_total',
-                            labels={'outcome': 'ok'})
-                req._finish('cache_full')
-                return True
-            plan = self._paged_plan(req)
-            if plan is None:
-                self._pending_admit = req
-                return False
-            blocks, ctx_len, hashes = plan
-            table = self._slot_table(blocks)
+        if -(-req.prompt.size // c.block_size) > self._alloc.capacity:
+            # no eviction can ever fit this prompt: structured
+            # cache_full, zero tokens, nothing leaked
+            monitor.inc('generate_request_total',
+                        labels={'outcome': 'ok'})
+            req._finish('cache_full')
+            return True
+        plan = self._paged_plan(req)
+        if plan is None:
+            self._pending_admit = req
+            return False
+        blocks, ctx_len, hashes = plan
+        table = self._slot_table(blocks)
         slot = self._free.pop()
         qs = max(0.0, time.monotonic() - req.enqueue_t)
         # queue wait as a histogram (the goodput 'queue' loss bucket
@@ -1356,9 +1311,9 @@ class GenerateEngine(object):
         with _loop_phase('prefill'):
             try:
                 first = self._run_prefill(
-                    slot, req.prompt,
+                    req.prompt, table,
                     (req.temperature, req.top_k, req.top_p, req._draw_u()),
-                    table=table, ctx_len=ctx_len)
+                    ctx_len=ctx_len)
                 if c.speculative:
                     # the draft tracks the request in its OWN pool: full
                     # prompt (no prefix cache — draft K/V are
@@ -1373,20 +1328,18 @@ class GenerateEngine(object):
                     if self._draft_copies_target:
                         self._draft_cache_sync(dblocks, blocks)
                     else:
-                        self._run_prefill(slot, req.prompt, table=dtable,
-                                          ctx_len=0,
+                        self._run_prefill(req.prompt, dtable,
                                           bound=self._draft_prefill_bound)
             except Exception as e:  # noqa: BLE001 — delivered per-request
                 self._free.append(slot)
-                if blocks:
-                    self._deref_blocks(blocks)
+                self._deref_blocks(blocks)
                 if dblocks:
                     self._draft_alloc.deref_many(dblocks)
                 monitor.inc('generate_request_total',
                             labels={'outcome': 'error'})
                 req.fail(e)
                 return True
-        if c.paged and self._prefix is not None:
+        if self._prefix is not None:
             # publish this prompt's FULL blocks (immutable once
             # prefilled: decode writes land strictly past the prompt)
             for i, h in enumerate(hashes):
@@ -1405,8 +1358,7 @@ class GenerateEngine(object):
                    dblocks=dblocks, dtable=dtable)
         reason = self._finish_reason(st)
         if reason:
-            if c.paged:
-                self._release_blocks(st)
+            self._release_blocks(st)
             self._free.append(slot)
             monitor.inc('generate_request_total',
                         labels={'outcome': 'ok'})
@@ -1416,20 +1368,10 @@ class GenerateEngine(object):
         self._set_occupancy()
         return True
 
-    def _run_prefill(self, slot, prompt, sample=(0.0, 0, 0.0, 0.0),
-                     table=None, ctx_len=0, bound=None):
+    def _run_prefill(self, prompt, table, sample=(0.0, 0, 0.0, 0.0),
+                     ctx_len=0, bound=None):
         c = self.config
-        if table is None:
-            b = bucketize(prompt.size, c.prompt_buckets)
-            padded = np.full((1, b), c.pad_id, 'int64')
-            padded[0, :prompt.size] = prompt
-            feed = {'gen_prompt': padded,
-                    'gen_slot': np.array([[slot]], 'int64'),
-                    'gen_len': np.array([[prompt.size]], 'int64')}
-            feed.update(self._sample_feed(1, *sample))
-            out = self._prefill_bound[b](feed)
-            return int(np.asarray(out[0]).reshape(-1)[0])
-        # paged: only the UN-CACHED suffix is computed; it buckets by
+        # only the UN-CACHED suffix is computed; it buckets by
         # suffix length — the prefill-compute saving of a prefix hit.
         # A suffix wider than the widest bucket runs CHUNKED: each
         # widest-bucket chunk deposits its K/V and attends the cached
@@ -1490,7 +1432,7 @@ class GenerateEngine(object):
             all(s.req.temperature <= 0.0 for s in active)
 
     def _grow_blocks(self):
-        """Paged pre-step pass: any resident whose next write position
+        """Pre-step pass: any resident whose next write position
         crosses into an unallocated block gets one more block; a dry
         pool (even after prefix-cache eviction) finishes the starved
         request with 'cache_full' and returns its blocks — neighbors
@@ -1782,14 +1724,12 @@ class GenerateEngine(object):
         can do host work (admission) while the device computes."""
         with _loop_phase('feed'):
             c = self.config
-            if c.paged:
-                self._grow_blocks()
+            self._grow_blocks()
             S = c.slots
             toks = np.zeros((S, 1), 'int64')
             pos = np.zeros((S, 1), 'int64')
             sample = self._sample_feed(S)
-            btab = np.zeros((S, self._max_blocks), 'int64') if c.paged \
-                else None
+            btab = np.zeros((S, self._max_blocks), 'int64')
             active = []
             live_pages = 0
             for i, st in enumerate(self._slots):
@@ -1801,9 +1741,8 @@ class GenerateEngine(object):
                 sample['gen_topk'][i] = r.top_k
                 sample['gen_topp'][i] = r.top_p
                 sample['gen_u'][i] = r._draw_u()
-                if btab is not None:
-                    btab[i] = st.table
-                    live_pages += st.pos // c.block_size + 1
+                btab[i] = st.table
+                live_pages += st.pos // c.block_size + 1
                 active.append((i, st))
             if not active:
                 return None
@@ -1814,15 +1753,12 @@ class GenerateEngine(object):
                 # all-greedy step is the argmax
                 self._sampled_steps += 1
                 monitor.inc('generate_sampled_steps_total')
-            if btab is not None:
-                # what the step's paged attention reads of what its
-                # tables span (stats()['blocks']['decode_live_page_share'])
-                monitor.inc('kv_decode_pages_live_total', live_pages)
-                monitor.inc('kv_decode_pages_table_total',
-                            len(active) * self._max_blocks)
-            feed = {'gen_tokens': toks, 'gen_pos': pos}
-            if btab is not None:
-                feed['gen_btab'] = btab
+            # what the step's paged attention reads of what its tables
+            # span (stats()['blocks']['decode_live_page_share'])
+            monitor.inc('kv_decode_pages_live_total', live_pages)
+            monitor.inc('kv_decode_pages_table_total',
+                        len(active) * self._max_blocks)
+            feed = {'gen_tokens': toks, 'gen_pos': pos, 'gen_btab': btab}
             feed.update(sample)
         with _loop_phase('dispatch'):
             t0 = time.perf_counter()
@@ -1947,7 +1883,7 @@ class GenerateEngine(object):
 
     def _release(self, i):
         st = self._slots[i]
-        if st is not None and self.config.paged:
+        if st is not None:
             self._release_blocks(st)
         self._slots[i] = None
         self._free.append(i)
@@ -1961,12 +1897,12 @@ class GenerateEngine(object):
 
     # ------------------------------------------------------------------
     def stats(self):
-        """Decode-loop statistics since construction. Paged engines add
-        the block-level capacity accounting under 'blocks' — physical
-        pool state, the peak footprint, and the prefix-cache entry
-        count (the monitor mirrors it as kv_blocks_in_use/free), and
-        the share of table pages the decode steps read. 'loop'
-        is where the loop thread's time went, by phase (_loop_sums)."""
+        """Decode-loop statistics since construction. 'blocks' is the
+        block-level capacity accounting — physical pool state, the
+        peak footprint, and the prefix-cache entry count (the monitor
+        mirrors it as kv_blocks_in_use/free), and the share of table
+        pages the decode steps read. 'loop' is where the loop thread's
+        time went, by phase (_loop_sums)."""
         steps = self._decode_steps
         out = {
             'slots': self.config.slots,
@@ -1980,17 +1916,16 @@ class GenerateEngine(object):
             'mean_slot_occupancy': round(self._occ_sum / steps, 4)
             if steps else 0.0,
         }
-        if self.config.paged:
-            out['blocks'] = {
-                'block_size': self.config.block_size,
-                'capacity': self._alloc.capacity,
-                'in_use': self._alloc.in_use(),
-                'free': self._alloc.available(),
-                'peak_in_use': self._blocks_peak,
-                'prefix_entries': len(self._prefix)
-                if self._prefix is not None else 0,
-                'decode_live_page_share': _live_page_share(),
-            }
+        out['blocks'] = {
+            'block_size': self.config.block_size,
+            'capacity': self._alloc.capacity,
+            'in_use': self._alloc.in_use(),
+            'free': self._alloc.available(),
+            'peak_in_use': self._blocks_peak,
+            'prefix_entries': len(self._prefix)
+            if self._prefix is not None else 0,
+            'decode_live_page_share': _live_page_share(),
+        }
         if self.config.speculative:
             prop = self._spec_proposed
             out['spec'] = {

@@ -480,7 +480,6 @@ def _paged_engine(view, **kw):
     kw.setdefault('prompt_buckets', [8, 16])
     kw.setdefault('eos_id', None)
     kw.setdefault('seed', 0)
-    kw.setdefault('paged', True)
     kw.setdefault('block_size', 8)
     return GenerateEngine(GenerateConfig(**kw), block_allocator=view)
 

@@ -51,7 +51,7 @@ def _prompt(n, seed=0):
 def test_greedy_parity_engine_vs_sequential_exact():
     """Continuous-batched decode must equal the sequential step-by-step
     reference EXACTLY per request — co-resident slots never perturb each
-    other's numerics (the kv_decode_attention masking contract)."""
+    other's numerics (the kv_decode_attention_paged masking contract)."""
     eng = GenerateEngine(_cfg())
     work = [(_prompt(4, 1), 9), (_prompt(7, 2), 14), (_prompt(12, 3), 6),
             (_prompt(16, 4), 11), (_prompt(5, 5), 8), (_prompt(9, 6), 13)]
@@ -155,7 +155,7 @@ def test_reject_and_shed_semantics():
     eng = GenerateEngine(_cfg(queue_cap=2))
     before = monitor.counters()
     with pytest.raises(ValueError, match='prompt length'):
-        eng.submit(_prompt(BUCKETS[-1] + 1))     # over the widest bucket
+        eng.submit(_prompt(MAX_LEN))     # over max_len - 1, the chunked bound
     with pytest.raises(ValueError, match='max_new_tokens'):
         eng.submit(_prompt(4), max_new_tokens=0)
     eng.submit(_prompt(4))

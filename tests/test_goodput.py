@@ -150,9 +150,8 @@ def test_fused_bound_mesh_kinds_account():
 
 def test_live_mfu_agrees_with_offline_window():
     """The live flops rate over the accounted window agrees with the
-    offline formula (registry flops / measured wall) — the same
-    cross-check bench.py's flagship goodput block records, with a CI
-    margin for box noise."""
+    offline formula (registry flops / measured wall), with a CI margin
+    for box noise."""
     exe, scope = fluid.Executor(), fluid.Scope()
     main, startup, out = _fc_program()
     feed = _warm(exe, scope, main, startup, out)

@@ -142,7 +142,7 @@ def _toy_engine():
                      use_flash_attention=False)
     return GenerateEngine(GenerateConfig(
         model=model, slots=4, max_len=48, prompt_buckets=[16],
-        eos_id=None, seed=0, paged=True, block_size=8))
+        eos_id=None, seed=0, block_size=8))
 
 
 def _serve(eng, prompts, n_new):

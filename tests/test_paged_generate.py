@@ -1,12 +1,12 @@
-"""Paged KV cache (serving/generate.py paged mode + serving/kv_blocks.py
-+ the ops/kv_cache_ops.py paged variants): exact greedy parity vs the
-contiguous cache, block-allocator admission/growth/exhaustion semantics,
+"""The KV block pool (serving/generate.py + serving/kv_blocks.py + the
+ops/kv_cache_ops.py block-table ops): exact greedy parity vs a forward
+that has NO cache, block-allocator admission/growth/exhaustion semantics,
 prefix sharing with physical block reuse and copy-on-write isolation,
 per-request sampling streams, and the zero-recompile contract under
-mixed paged traffic.
+mixed traffic.
 
-Engines here share ONE tiny-LM shape family (and the contiguous shapes
-of test_generate.py), so the process-wide fingerprint compile cache
+Engines here share ONE tiny-LM shape family (test_generate.py's, at
+block size 8), so the process-wide fingerprint compile cache
 keeps per-test warmups at milliseconds after the first test pays the
 XLA compiles. Several tests drive the engine INLINE (submit + _admit +
 _step, loop thread never started) — that makes allocator state,
@@ -18,8 +18,9 @@ test_generate.py's).
 import numpy as np
 import pytest
 
+import paddle_tpu as fluid
 from paddle_tpu import monitor
-from paddle_tpu.models.transformer import LMConfig
+from paddle_tpu.models.transformer import LMConfig, build_lm
 from paddle_tpu.serving import GenerateConfig, GenerateEngine
 from paddle_tpu.serving.kv_blocks import (BlockAllocator, PrefixCache,
                                           chain_hashes)
@@ -28,12 +29,12 @@ BUCKETS = [8, 16]
 MAX_LEN = 48
 SLOTS = 4
 BS = 8                        # block size
-NUM_BLOCKS = SLOTS * MAX_LEN // BS          # 24 physical = contiguous HBM
+NUM_BLOCKS = SLOTS * MAX_LEN // BS          # 24 physical: MAX_LEN rows a slot
 USABLE = NUM_BLOCKS - 1                     # block 0 is the trash block
 
 
-def _model():
-    return LMConfig(vocab_size=64, seq_len=32, d_model=32, n_head=2,
+def _model(seq_len=32):
+    return LMConfig(vocab_size=64, seq_len=seq_len, d_model=32, n_head=2,
                     n_layer=2, d_ff=64, dropout=0.0, attn_dropout=0.0,
                     use_flash_attention=False)
 
@@ -45,14 +46,8 @@ def _paged_cfg(**kw):
     kw.setdefault('prompt_buckets', list(BUCKETS))
     kw.setdefault('eos_id', None)
     kw.setdefault('seed', 0)
-    kw.setdefault('paged', True)
     kw.setdefault('block_size', BS)
     return GenerateConfig(**kw)
-
-
-def _contig_cfg(**kw):
-    kw['paged'] = False
-    return _paged_cfg(**kw)
 
 
 def _prompt(n, seed=0):
@@ -113,27 +108,59 @@ def test_block_allocator_and_prefix_cache_unit():
 # parity + recompiles
 
 
-def test_greedy_parity_paged_vs_contiguous_exact():
-    """Block-table decode must equal the contiguous row-span cache
-    EXACTLY, token for token, on mixed prompt/output lengths — the
-    paged gather/scatter + trash-block masking is bit-transparent."""
-    contig = GenerateEngine(_contig_cfg())
-    paged = GenerateEngine(_paged_cfg())
-    work = [(_prompt(4, 1), 9), (_prompt(7, 2), 14), (_prompt(12, 3), 6),
-            (_prompt(16, 4), 11), (_prompt(5, 5), 8), (_prompt(9, 6), 13)]
-    refs = [contig.generate_once(p, max_new_tokens=n) for p, n in work]
-    solo = [paged.generate_once(p, max_new_tokens=n) for p, n in work]
-    assert solo == refs
-    with paged:
-        reqs = [paged.submit(p, max_new_tokens=n) for p, n in work]
+def _cache_free_greedy(eng, prompt, n_new):
+    """The reference with no cache in it: `build_lm(is_test=True)` — the
+    training forward, none of the decode programs' ops — re-run on the
+    growing sequence, argmax at its last real row. One program at
+    MAX_LEN rows serves every length: the sequence is zero-padded behind
+    and the causal mask keeps the padding out of every real row."""
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        with fluid.unique_name.guard():
+            _t, _l, logits, _loss = build_lm(_model(MAX_LEN), is_test=True)
+    ids = [int(t) for t in prompt]
+    for _ in range(n_new):
+        arr = np.zeros((1, MAX_LEN), 'int64')
+        arr[0, :len(ids)] = ids
+        out = eng.executor.run(main, feed={'tokens': arr, 'labels': arr},
+                               fetch_list=[logits], scope=eng.scope)
+        ids.append(int(np.asarray(out[0])[0, len(ids) - 1].argmax()))
+    return ids[len(prompt):]
+
+
+# (prompt length, new tokens): the prompt ends inside a block; on a block
+# edge of both sizes; and is wider than the widest bucket (16), so its
+# prefill runs in three chunks. Each generation crosses a block edge.
+PARITY_CASES = {'mid_block': (5, 14), 'block_edge': (16, 18),
+                'chunked': (37, 10)}
+
+
+@pytest.mark.parametrize('block_size', [8, 16])
+@pytest.mark.parametrize('case', sorted(PARITY_CASES))
+def test_greedy_tokens_equal_the_cache_free_forward(case, block_size):
+    """Prefill + block-table decode must give the tokens of a forward
+    that keeps no cache EXACTLY — through generate_once and with
+    neighbours in the other slots: the page gather/scatter, the
+    trash-block masking and the chunked prefill are bit-transparent to
+    the argmax."""
+    eng = GenerateEngine(_paged_cfg(block_size=block_size))
+    plen, n_new = PARITY_CASES[case]
+    work = [(_prompt(7, 2), 14), (_prompt(plen, 70 + plen), n_new),
+            (_prompt(12, 3), 6), (_prompt(9, 6), 13)]
+    refs = [_cache_free_greedy(eng, p, n) for p, n in work]
+    assert eng.generate_once(*work[1]) == refs[1]
+    with eng:
+        reqs = [eng.submit(p, max_new_tokens=n) for p, n in work]
         outs = [r.result(60) for r in reqs]
-        live = paged.stats()['blocks']
+        live = eng.stats()['blocks']
         # finished requests returned their blocks; only the prefix
-        # cache's references remain until stop() drops them
-        assert live['in_use'] == live['prefix_entries'] > 0
+        # cache's references (every prompt's full blocks) remain until
+        # stop() drops them
+        assert live['in_use'] == live['prefix_entries'] == \
+            sum(p.size // block_size for p, _ in work)
     assert outs == refs
-    assert paged.stats()['active'] == 0
-    assert paged.stats()['blocks']['in_use'] == 0   # stop() drops cache
+    assert eng.stats()['active'] == 0
+    assert eng.stats()['blocks']['in_use'] == 0     # stop() drops cache
 
 
 def test_mixed_paged_traffic_zero_recompiles_after_warmup():
@@ -267,13 +294,13 @@ def test_allocator_exhaustion_cache_full_and_blocks_returned():
 
 
 def test_paged_serves_2x_concurrent_sequences_at_same_hbm():
-    """THE capacity contract: at the contiguous cache's exact HBM
-    budget (NUM_BLOCKS * BS = SLOTS * MAX_LEN rows), the paged engine
-    holds >= 2x the contiguous slot count in flight simultaneously,
-    because short sequences commit one block instead of a max_len
-    row-span — with exact greedy parity throughout."""
-    contiguous_slots_at_budget = NUM_BLOCKS * BS // MAX_LEN   # = SLOTS
-    assert contiguous_slots_at_budget == SLOTS
+    """THE capacity contract: at the HBM budget of a MAX_LEN row-span
+    for each of SLOTS sequences (NUM_BLOCKS * BS = SLOTS * MAX_LEN
+    rows), the pool holds >= 2x that many sequences in flight
+    simultaneously, because short sequences commit one block instead of
+    a max_len row-span — with exact greedy parity throughout."""
+    row_span_slots_at_budget = NUM_BLOCKS * BS // MAX_LEN   # = SLOTS
+    assert row_span_slots_at_budget == SLOTS
     eng = GenerateEngine(_paged_cfg(slots=4 * SLOTS))
     eng.warmup()
     work = [(_prompt(3 + i % 3, seed=60 + i), 3) for i in range(16)]
@@ -285,7 +312,7 @@ def test_paged_serves_2x_concurrent_sequences_at_same_hbm():
     assert stats['blocks']['in_use'] <= USABLE
     _drive(eng, *reqs)
     assert [r.result(5) for r in reqs] == refs
-    assert eng.stats()['peak_active'] >= 2 * contiguous_slots_at_budget
+    assert eng.stats()['peak_active'] >= 2 * row_span_slots_at_budget
     eng.stop()
 
 

@@ -210,12 +210,12 @@ def test_builders_and_the_engine_name_their_programs():
         with fluid.unique_name.guard():
             build_lm_decode_step(LMConfig(
                 vocab_size=64, seq_len=32, d_model=32, n_head=2, n_layer=1,
-                d_ff=64, dropout=0.0), 2, 16)
+                d_ff=64, dropout=0.0), 2, 16, block_size=8, num_blocks=4)
     assert prog.name == 'lm_decode_step'
     eng = _engine()
     assert eng._step_prog.name == 'lm_decode_step'
     assert {b: p.name for b, (p, _v) in eng._prefill.items()} == \
-        {8: 'lm_prefill_b8', 16: 'lm_prefill_b16'}
+        {8: 'lm_prefill_paged_b8', 16: 'lm_prefill_paged_b16'}
 
 
 # ---------------------------------------------------------------------------

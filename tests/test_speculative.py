@@ -55,7 +55,6 @@ def _cfg(**kw):
     kw.setdefault('prompt_buckets', list(BUCKETS))
     kw.setdefault('eos_id', None)
     kw.setdefault('seed', 0)
-    kw.setdefault('paged', True)
     kw.setdefault('block_size', BS)
     return GenerateConfig(**kw)
 
@@ -87,8 +86,6 @@ def _drive(eng, *reqs):
 
 
 def test_config_validation():
-    with pytest.raises(ValueError):
-        GenerateConfig(model=_model(), speculative=True, paged=False)
     with pytest.raises(ValueError):
         _spec_cfg(spec_k=0)
     with pytest.raises(ValueError):
@@ -205,8 +202,7 @@ def test_spec_eos_inside_window():
 def test_chunked_prefill_bitexact_vs_single_shot():
     """A prompt longer than the widest bucket is admitted via chunked
     prefill and its continuation matches the single-shot (wide-bucket)
-    reference bit-exactly, through generate_once AND the engine loop.
-    Non-paged engines keep the old rejection."""
+    reference bit-exactly, through generate_once AND the engine loop."""
     p = _prompt(40, 9)              # widest chunked bucket is 16
     wide = GenerateEngine(_cfg(prompt_buckets=[40]))
     ref = wide.generate_once(p, max_new_tokens=8)
@@ -215,13 +211,9 @@ def test_chunked_prefill_bitexact_vs_single_shot():
     with chunk:
         r = chunk.submit(p, max_new_tokens=8)
         assert list(r.result(60)) == ref
-    # admission bound is now max_len - 1 ...
+    # the admission bound is max_len - 1
     with pytest.raises(ValueError):
         chunk.submit(_prompt(MAX_LEN, 10))
-    # ... but only for paged engines; contiguous keeps the ladder bound
-    contig = GenerateEngine(_cfg(paged=False))
-    with pytest.raises(ValueError):
-        contig.submit(_prompt(BUCKETS[-1] + 1, 11))
 
 
 def test_chunked_prefill_composes_with_speculation_and_sharing():

@@ -1,9 +1,8 @@
 """Slope-timed (per-call-constant-free) step rates for the conv bench rows.
 
 NOTE: the build recipe (model + AMP-decorated Momentum + staged feeds)
-mirrors bench.py _bench_image_model; if the bench measurement contract
-changes, update both or the slope numbers stop describing the same
-configuration the bench rows describe."""
+mirrored `_bench_image_model` of the second benchmark (`bench.py`,
+deleted at PR 30): the slope numbers describe that configuration."""
 import json
 import os
 import sys

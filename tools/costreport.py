@@ -10,8 +10,8 @@ each, and prints a side-by-side report plus the contrib
 Usage:
     python tools/costreport.py [--batch 64] [--hidden 64] [--json]
 
-Importable: ``measure_costreport(batch=...)`` returns the dict bench.py
-embeds as its `costreport` row (flops / peak_bytes columns per program).
+Importable: ``measure_costreport(batch=...)`` returns the dict (flops /
+peak_bytes columns per program).
 """
 import argparse
 import json

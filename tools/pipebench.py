@@ -86,8 +86,7 @@ def _build(dim, hidden):
 
 def measure_pipeline(rounds=3, n_batches=24, batch=64, dim=192,
                      hidden=1024, io_wait_s=0.01):
-    """Returns the async_pipeline bench row (importable; bench.py uses
-    it for the smoke path)."""
+    """Returns the async_pipeline bench row (importable)."""
     import numpy as np
     import paddle_tpu as fluid
     from paddle_tpu import monitor

@@ -57,7 +57,7 @@ def _build_ctr(hidden=400):
 
 
 def measure_ctr_ps(rounds=3, n_batches=12, batch=512, num_shards=2):
-    """Returns the ctr_ps bench row (importable; bench.py uses it)."""
+    """Returns the ctr_ps bench row (importable)."""
     import numpy as np
     import paddle_tpu as fluid
     from paddle_tpu import monitor, ps

@@ -38,8 +38,7 @@ def _build():
 
 def measure_run_overhead(rounds=300):
     """Returns {'run_overhead_us', 'first_compile_s', 'cache_hit_compile_s',
-    'rounds'}; importable (bench.py reuses it for its per-run-overhead
-    row)."""
+    'rounds'}; importable."""
     import jax
     import paddle_tpu as fluid
 

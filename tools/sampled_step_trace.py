@@ -97,7 +97,7 @@ def main(argv=None):
         scope.set(name, value)
     eng = GenerateEngine(GenerateConfig(
         model=model.lm_config(m, max_len, False), slots=slots,
-        max_len=max_len, paged=bool(e['paged']),
+        max_len=max_len,
         block_size=int(e['block_size']), num_blocks=int(e['num_blocks']),
         prompt_buckets=list(e['prompt_buckets']), prefix_sharing=False,
         queue_cap=4096, default_deadline_s=300.0, seed=PROGRAM_SEED),

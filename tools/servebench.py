@@ -27,11 +27,10 @@ docs/serving.md) makes measurable promises about:
   arrival gaps are NOT used — tokens buffered in the stream queue drain
   in ~0 time, which used to report a nonsense sub-microsecond p50
   against a tens-of-ms p99 (BENCH_r06).
-- paged columns (same row): the identical workload through a PAGED
-  engine holding the SAME KV HBM budget (num_blocks * block_size ==
-  contiguous slots * max_len) but 2x the slots — block utilization,
-  prefix-share hit rate, peak concurrent sequences, and greedy parity
-  vs the contiguous engine's outputs.
+- block-pool columns (same row, under `paged`): block utilization,
+  prefix-share hit rate, copy-on-write count and peak concurrent
+  sequences of that run. Greedy parity is held against the re-traced
+  baseline, which has no cache.
 - shared-prefix win (`measure_shared_prefix`, `--shared-prefix`): N
   clients sending ONE system prompt + tiny unique suffixes through the
   paged engine with prefix sharing on vs off. Reports physical-sharing
@@ -68,8 +67,9 @@ Usage: python tools/servebench.py [rounds] (prints one JSON line);
        python tools/servebench.py --fleet [requests_per_client]
 importable `measure_serving()` / `measure_generate()` /
 `measure_shared_prefix()` / `measure_speculative()` / `measure_fleet()`
-(bench.py's 'serving', 'generate', 'generate_speculative' and
-'serving_fleet' rows reuse them).
+(the four `@slow` tests of tests/test_generate.py,
+test_paged_generate.py, test_speculative.py and test_fleet.py reuse
+them).
 """
 import json
 import os
@@ -500,34 +500,16 @@ def measure_generate(rounds=3, sentences=24, slots=8, clients=6):
             eng.stop()
         return best, miss, outs, token_ms, errors[0]
 
+    before_run = monitor.counters()
     eng_best, miss_delta, outs, token_ms, errors = \
         run_engine_rounds(engine)
-
-    # --- paged engine, SAME KV HBM budget, 2x the slots ---------------
-    # contiguous reserves slots*max_len rows; the paged pool holds the
-    # same rows as blocks, so admission is bounded by actual usage —
-    # the 2x-concurrency / block-utilization columns of the bench row
-    paged_cfg = GenerateConfig(
-        model=base, slots=2 * slots, max_len=cfg.max_len,
-        prompt_buckets=[8, 16, 32], eos_id=None, max_new_tokens=64,
-        seed=0, queue_cap=sentences + clients, paged=True,
-        block_size=16,
-        num_blocks=slots * cfg.max_len // 16)
-    paged_engine = GenerateEngine(paged_cfg)
-    paged_warm = paged_engine.warmup()
-    before_paged = monitor.counters()
-    paged_best, paged_miss, paged_outs, paged_token_ms, paged_errors = \
-        run_engine_rounds(paged_engine)
-    paged_delta = monitor.counter_delta(before_paged)
-    paged_stats = paged_engine.stats()
-    paged_parity = sum(1 for r, o in zip(outs, paged_outs) if o == r)
+    run_delta = monitor.counter_delta(before_run)
 
     stats = engine.stats()
     lat = sorted(token_ms)
-    plat = sorted(paged_token_ms)
     parity = sum(1 for r, o in zip(refs, outs) if o == r)
-    hits = paged_delta.get('kv_prefix_hit_total{outcome=hit}', 0)
-    misses = paged_delta.get('kv_prefix_hit_total{outcome=miss}', 0)
+    hits = run_delta.get('kv_prefix_hit_total{outcome=hit}', 0)
+    misses = run_delta.get('kv_prefix_hit_total{outcome=miss}', 0)
     return {
         'sentences': sentences,
         'tokens_generated': total_new,
@@ -547,27 +529,15 @@ def measure_generate(rounds=3, sentences=24, slots=8, clients=6):
         'errors': errors,
         'warmup': warm,
         'paged': {
-            'block_size': paged_cfg.block_size,
-            'hbm_budget_rows': slots * cfg.max_len,
-            'engine_sentences_per_sec': round(sentences / paged_best, 2),
-            'engine_tokens_per_sec': round(total_new / paged_best, 1),
-            'ms_per_token_p50': round(_quantile(plat, 0.5) or 0, 3),
-            'ms_per_token_p99': round(_quantile(plat, 0.99) or 0, 3),
-            'vs_contiguous': round(eng_best / paged_best, 2),
-            'concurrent_seqs_at_fixed_hbm': {
-                'contiguous': slots,
-                'paged_peak': paged_stats['peak_active']},
+            'block_size': cfg.block_size,
+            'hbm_budget_rows': cfg.num_blocks * cfg.block_size,
+            'concurrent_seqs_peak': stats['peak_active'],
             'block_utilization_peak': round(
-                paged_stats['blocks']['peak_in_use']
-                / float(paged_stats['blocks']['capacity']), 3),
+                stats['blocks']['peak_in_use']
+                / float(stats['blocks']['capacity']), 3),
             'prefix_hit_rate': round(hits / float(hits + misses), 3)
             if hits + misses else 0.0,
-            'cow_total': int(paged_delta.get('kv_block_cow_total', 0)),
-            'recompiles_after_warmup': int(paged_miss),
-            'greedy_parity_vs_contiguous': '%d/%d' % (paged_parity,
-                                                      sentences),
-            'errors': paged_errors,
-            'warmup': paged_warm,
+            'cow_total': int(run_delta.get('kv_block_cow_total', 0)),
         },
         'rounds': rounds,
         'config': 'lm v%d d%d h%d L%d slots%d maxlen%d' % (
@@ -600,7 +570,7 @@ def measure_shared_prefix(clients=8, system_len=48, suffix_len=8,
         cfg = GenerateConfig(
             model=base, slots=8, max_len=96,
             prompt_buckets=[8, 16, 32, 64], eos_id=None, seed=0,
-            queue_cap=clients + 1, paged=True, block_size=block_size,
+            queue_cap=clients + 1, block_size=block_size,
             prefix_sharing=sharing)
         eng = GenerateEngine(cfg)
         eng.warmup()
@@ -696,7 +666,7 @@ def measure_speculative(rounds=4, sentences=8, slots=8, spec_k=6,
     total = sum(n for _, n in work)
     kw = dict(model=base, slots=slots, max_len=96,
               prompt_buckets=[8, 16, 32], eos_id=None, max_new_tokens=64,
-              seed=0, queue_cap=sentences + 2, paged=True, block_size=16)
+              seed=0, queue_cap=sentences + 2, block_size=16)
     draft = LMConfig(**dict(dict(vocab_size=base.vocab_size,
                                  seq_len=base.seq_len), **draft_config)) \
         if draft_config else None
@@ -738,7 +708,7 @@ def measure_speculative(rounds=4, sentences=8, slots=8, spec_k=6,
     long_p = rng.randint(2, 256, size=56).astype('int64')   # > bucket 32
     wide = GenerateEngine(GenerateConfig(
         model=base, slots=slots, max_len=96, prompt_buckets=[64],
-        eos_id=None, seed=0, paged=True, block_size=16))
+        eos_id=None, seed=0, block_size=16))
     ref = wide.generate_once(long_p, max_new_tokens=16)
     chunk = GenerateEngine(GenerateConfig(**kw))
     chunk.warmup()
