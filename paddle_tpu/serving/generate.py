@@ -86,6 +86,12 @@ exactly like a shared-prefix suffix, so admission reaches
 continuation is bit-exact vs a single-shot prefill through a wider
 bucket.
 
+THE LOOP IS A PIPELINE ONE STEP DEEP (PR 31): with step k dispatched and
+its tokens not fetched, step k + 1 is dispatched on them as they are on
+the device, and step k is fetched, delivered and booked while k + 1
+computes (`GenerateEngine._loop`; ``generate_overlapped_steps_total``,
+``generate_discarded_rows_total``).
+
 Monitor series: ``decode_tokens_total``, ``kv_slot_occupancy``,
 ``decode_step_seconds``, ``prefill_seconds``,
 ``generate_request_total{outcome=ok|error|shed|deadline|rejected|stopped}``,
@@ -417,7 +423,8 @@ class GenerateRequest(Request):
 
 class _Slot(object):
     __slots__ = ('req', 'pos', 'generated', 'last', 'last_t', 'wall0',
-                 'blocks', 'table', 'dblocks', 'dtable', 'draft_stale')
+                 'blocks', 'table', 'dblocks', 'dtable', 'draft_stale',
+                 'ahead')
 
     def __init__(self, req, pos, last, blocks, table,
                  dblocks=None, dtable=None):
@@ -425,6 +432,11 @@ class _Slot(object):
         self.pos = pos          # cache position the NEXT step writes
         self.generated = 1      # prefill already emitted the first token
         self.last = last        # last generated token (next step's input)
+        # pos, generated and last are as of the last step DELIVERED;
+        # `ahead` counts the steps dispatched for this slot since (0 or
+        # 1): the next dispatch writes pos + ahead, on a token that is
+        # still on the device when ahead is 1
+        self.ahead = 0
         self.last_t = time.perf_counter()   # previous token's completion
         self.wall0 = time.time() * 1e6      # decode-phase start (us)
         self.blocks = blocks    # physical block ids, table order
@@ -434,6 +446,28 @@ class _Slot(object):
         # plain (fallback) steps write K/V into the TARGET cache only —
         # the draft cache misses those rows until a spec round resyncs
         self.draft_stale = False
+
+
+class _Flight(object):
+    """A decode step between its dispatch and the fetch of its tokens."""
+    __slots__ = ('out', 'active', 't0', 'overlapped')
+
+    def __init__(self, out, active, t0, overlapped):
+        self.out = out          # device fetches; None once a failure
+        #                         took the step with it
+        self.active = active    # [(slot index, _Slot)] as dispatched
+        self.t0 = t0
+        # dispatched while its predecessor was still unfetched
+        self.overlapped = overlapped
+
+    def fetch(self):
+        """The step's fetched vector on the host: blocks until the device
+        is done with the step, and raises what an async failure left."""
+        return np.asarray(self.out[0])
+
+    def ready(self):
+        """Is the device done with the step? Never blocks."""
+        return self.out[0].is_ready()
 
 
 class GenerateEngine(object):
@@ -485,6 +519,7 @@ class GenerateEngine(object):
         self._max_blocks = c.max_len // c.block_size
         self._cow_jit = None
         self._dcopy_jit = None
+        self._carry_jit = None
         if c.speculative:
             self._draft_cfg = c.draft_model or c.model
             # +1 over the all-slots-at-max_len footprint (the trash
@@ -524,8 +559,16 @@ class GenerateEngine(object):
         self._stop_evt = threading.Event()
         self._lock = threading.Lock()
         self._metrics_server = None
+        # decode steps dispatched and not fetched yet, oldest first: two
+        # at most, while the loop's pipeline is full
+        self._flights = []
+        self._fetched_t = 0.0   # when the last step's fetch came back
+        # since then: what admissions waited for their prefills alone
+        self._prefill_alone_s = 0.0
         self._decode_steps = 0
         self._sampled_steps = 0
+        self._overlapped_steps = 0
+        self._discarded_rows = 0
         self._decode_tokens = 0
         self._occ_sum = 0.0
         self._occ_peak = 0.0
@@ -732,6 +775,24 @@ class GenerateEngine(object):
             self._draft_scope.set(name,
                                   self._dcopy_jit(dst, src, d_ids, s_ids))
 
+    def _carry_tokens(self, prev, carry, toks):
+        """The next step's 'gen_tokens' with a step in flight: row i takes
+        the token step `prev` (its device fetch, the tokens leading it)
+        made for it where `carry[i]`, the host's `toks[i]` elsewhere — a
+        row admitted since, whose first token came from its prefill, or
+        an empty one. One tiny jitted select, compiled at warmup: the
+        carried token never crosses to the host and back."""
+        import jax
+        if self._carry_jit is None:
+            import jax.numpy as jnp
+            S = self.config.slots
+
+            def _select(prev, carry, toks):
+                mine = prev.reshape(-1)[:S].reshape(S, 1)
+                return jnp.where(carry, mine.astype(toks.dtype), toks)
+            self._carry_jit = jax.jit(_select)
+        return self._carry_jit(prev, carry, toks)
+
     def _set_block_gauges(self):
         used = self._alloc.in_use()
         self._blocks_peak = max(self._blocks_peak, used)
@@ -826,6 +887,17 @@ class GenerateEngine(object):
                 reused += 1
             else:
                 farm.commit(key)
+            # the pipelined loop feeds the step its predecessor's tokens
+            # as they are on the device (int32 there, with x64 off, where
+            # the numpy feed is int64): compile the select and run the
+            # step on its output now. It is the executable just bound and
+            # no second one (tests/test_decode_pipeline.py counts jax's
+            # own compiles); all-zero tables keep the writes in the trash
+            # block.
+            out = self._step_bound(feed, return_numpy=False)
+            self._step_bound(dict(feed, gen_tokens=self._carry_tokens(
+                out[0], np.zeros((S, 1), bool), feed['gen_tokens'])),
+                return_numpy=False)
             if self.config.speculative:
                 reused += self._warm_spec(farm)
             # compile the copy-on-write block copy now (0 -> 0 is a
@@ -1122,13 +1194,39 @@ class GenerateEngine(object):
     # ------------------------------------------------------------------
     # decode loop
     def _loop(self):
-        """The decode loop. Every stretch of a pass is a phase
-        (_loop_phase): its self time goes to
-        generate_loop_seconds_total{phase=...} and, in a profiler
+        """The decode loop: a pipeline one step deep. With step k
+        dispatched and not yet fetched, a pass dispatches step k + 1 — a
+        carried row's input token taken from step k's output ON THE
+        DEVICE, its position pos + 1, its table grown on the host — and
+        only then admits, fetches step k, delivers and books it, while
+        k + 1 computes: the token's round trip to the host, `wait`,
+        `deliver`, `feed` and `dispatch` all run behind a busy device.
+        Nothing in flight (the start, after an idle) or a speculative
+        round due: the pass is the serial one, admit, then dispatch.
+        Only an `eos` finish depends on a token's value: such a row is
+        in step k + 1 already and `_deliver` drops its result there;
+        every other finish is foreseen and the row left out
+        (`_grow_blocks`).
+
+        Where a pass admits is what the loop observes after the
+        dispatch (below): step k done already — the host paces the loop
+        — it admits at once, between the dispatch and the fetch, where
+        admission sat before the pipeline (a client whose request just
+        ended has had the time of a dispatch to send its next; admitted
+        after the delivery instead it comes a pass later and in larger
+        groups: on the chip chat's p95 token gap + 22 %); step k still
+        computing — the device paces the loop — it fetches and delivers
+        k first (admitted before, the prefill waits for two steps:
+        OLMoE's p95 token gap + 30 %). PERF.md, PR 31. The probe goes
+        when a prefill's first token stays on the device as well: one
+        fixed order then serves both regimes (ROADMAP S8).
+
+        Every stretch of a pass is a phase (_loop_phase): its self time
+        goes to generate_loop_seconds_total{phase=...} and, in a profiler
         session, it is a 'paddle_tpu:generate.<phase>' span on the device
         trace's clock, so a device idle gap has a name."""
         poll = self.config.idle_poll_s
-        done = None     # the last completed step: (outputs, slots, t0)
+        done = None     # the last fetched step, its outputs held (below)
         t_pass = time.perf_counter()
         while not self._stop_evt.is_set():
             # the wall time of the pass just ended, beside the phases'
@@ -1136,9 +1234,44 @@ class GenerateEngine(object):
             now = time.perf_counter()
             monitor.inc('generate_loop_wall_seconds_total', now - t_pass)
             t_pass = now
+            flight = self._flights[0] if self._flights else None
+            nxt = None
+            if flight is not None and not self._spec_ready():
+                nxt = self._fallback_dispatch(prev=flight)
+                # the step BEFORE `flight` gives up its fetched outputs
+                # here, behind the device's work. Freed between two
+                # steps instead (0.3-0.5 ms, and the client threads take
+                # the GIL there) a finished client's next request is
+                # admitted a pass later: another schedule (PERF.md, PR
+                # 25)
+                done = None
+            # a prefill goes out with ONE unfinished step ahead of it on
+            # the device, as before the pipeline. Step k done already
+            # (the host paces the loop): admit now, behind k + 1. Still
+            # computing (the device paces it): fetch and deliver it
+            # first, then admit behind k + 1 — admitted now, the prefill
+            # would wait for two steps, and every stream with it
+            fetch_first = nxt is not None and not flight.ready()
+            if fetch_first:
+                self._step_complete(flight, nxt)
             with _loop_phase('admit'):
+                # an evicted slot may be in the snapshot of a step in
+                # flight, and have a new tenant before that step lands:
+                # `_deliver` books a row only for the tenant it was
+                # dispatched for
                 self._evict_expired()
-                self._admit()
+                if nxt is None:
+                    self._admit()
+            if nxt is not None:
+                # admit queued prompts (queue pops + prefill staging)
+                # while the steps in flight compute
+                with _loop_phase('admit_overlapped'):
+                    self._admit()
+            if flight is not None:
+                if not fetch_first:
+                    self._step_complete(flight, nxt)
+                done = flight   # noqa: F841 — held, above
+                continue
             if not any(s is not None for s in self._slots):
                 with _loop_phase('idle'):
                     if self._pending_admit is not None:
@@ -1163,36 +1296,11 @@ class GenerateEngine(object):
                 with _loop_phase('spec_round'):
                     self._spec_round()
                 continue
-            if self.config.speculative:
-                # a sampled resident pins the whole batch on plain
-                # steps this round — speculation accelerates greedy
-                # traffic (acceptance is an argmax identity)
-                monitor.inc('spec_fallback_total')
-                self._spec_fallbacks += 1
-            pending = self._step_dispatch()
-            if pending is not None:
-                # overlap: admit queued prompts (queue pops + prefill
-                # staging) while the dispatched step computes on device.
-                # Eviction stays OUT of this window — releasing a slot
-                # the in-flight step's snapshot references would let a
-                # new tenant double-book it before completion lands.
-                with _loop_phase('admit_overlapped'):
-                    # the LAST step's fetched outputs are freed here,
-                    # behind the device's work. Freed between two steps
-                    # instead (0.3-0.5 ms, and the client threads take
-                    # the GIL there) a finished client's next request
-                    # is admitted at the top of the loop, not in this
-                    # window: another schedule (PERF.md, PR 25)
-                    done = None
-                    t_adm = time.perf_counter()
-                    self._admit()
-                    # admission time is observed as prefill_seconds
-                    # already; exclude it so decode_step_seconds stays a
-                    # per-token signal instead of double-counting the
-                    # overlap window
-                    exclude_s = time.perf_counter() - t_adm
-                self._step_complete(pending, exclude_s=exclude_s)
-                done, pending = pending, None   # noqa: F841 — held, above
+            self._fallback_dispatch()
+        while self._flights:
+            # stopping: the steps in flight land first, so nothing on
+            # the device still runs on this engine's state
+            self._step_complete(self._flights[0])
         monitor.inc('generate_loop_wall_seconds_total',
                     time.perf_counter() - t_pass)      # the last pass
         # shutdown: a resident generation must not leave its caller
@@ -1391,7 +1499,7 @@ class GenerateEngine(object):
                     'gen_len': np.array([[wide]], 'int64')}
             feed.update(self._sample_feed(1))
             # K/V deposited; token output unused
-            self._split_load(bound[wide](feed)[0], 1)
+            self._prefill_call(bound[wide], feed)
             off += wide
         b = bucketize(suffix.size, c.prompt_buckets)
         padded = np.full((1, b), c.pad_id, 'int64')
@@ -1402,7 +1510,26 @@ class GenerateEngine(object):
                 'gen_btab': table[None],
                 'gen_len': np.array([[suffix.size]], 'int64')}
         feed.update(self._sample_feed(1, *sample))
-        return int(self._split_load(bound[b](feed)[0], 1)[0])
+        return int(self._prefill_call(bound[b], feed)[0])
+
+    def _prefill_call(self, bound, feed):
+        """One prefill dispatch and the fetch of its token, behind the
+        decode steps in flight as the device runs them. The loop thread
+        sees those complete on the way: what it waits from there on is
+        the prefill's time alone and none of a step's (`_observe_step`)."""
+        out = bound(feed, return_numpy=False)
+        seen = None
+        if self._flights:
+            for flight in self._flights:
+                try:
+                    flight.out[0].block_until_ready()
+                except Exception:   # noqa: BLE001 — raised at its fetch
+                    pass
+            seen = time.perf_counter()
+        tokens = self._split_load(out[0], 1)
+        if seen is not None:
+            self._prefill_alone_s += time.perf_counter() - seen
+        return tokens
 
     def _step(self):
         """One decode step, dispatch + completion back to back (the
@@ -1436,16 +1563,29 @@ class GenerateEngine(object):
         crosses into an unallocated block gets one more block; a dry
         pool (even after prefix-cache eviction) finishes the starved
         request with 'cache_full' and returns its blocks — neighbors
-        keep decoding."""
-        bs = self.config.block_size
+        keep decoding. Returns the slots this step leaves out: those
+        with a step in flight (`ahead`) that the host can see ending at
+        its delivery — `length`, `max_len` — so that no row is computed
+        for nothing, and those the dry pool starves while their token of
+        the step in flight is still to come (the next pass decides)."""
+        c = self.config
+        bs = c.block_size
+        held = set()
         for i, st in enumerate(self._slots):
             if st is None:
                 continue
-            bi = st.pos // bs
-            if bi < len(st.blocks):
+            at = st.pos + st.ahead
+            if st.ahead and (at >= c.max_len or st.generated + st.ahead
+                             >= st.req.max_new_tokens):
+                held.add(i)
+                continue
+            if at // bs < len(st.blocks):
                 continue
             grown = self._alloc_blocks(1)
             if grown is None:
+                if st.ahead:
+                    held.add(i)
+                    continue
                 self._release(i)
                 monitor.inc('generate_request_total',
                             labels={'outcome': 'ok'})
@@ -1454,6 +1594,7 @@ class GenerateEngine(object):
             st.table[len(st.blocks)] = grown[0]
             st.blocks.append(grown[0])
         self._set_occupancy()
+        return held
 
     # ------------------------------------------------------------------
     # speculative decode
@@ -1717,32 +1858,57 @@ class GenerateEngine(object):
         self._set_block_gauges()
         self._set_occupancy()
 
-    def _step_dispatch(self):
+    def _fallback_dispatch(self, prev=None):
+        """The loop's plain step. On a speculative engine it is a
+        fallback — a sampled resident pins the whole batch on plain steps
+        (speculation accelerates greedy traffic: acceptance is an argmax
+        identity) — and is counted as one; such steps pipeline like any
+        other, and the round that follows the last of them starts with
+        nothing in flight."""
+        if self.config.speculative:
+            monitor.inc('spec_fallback_total')
+            self._spec_fallbacks += 1
+        return self._step_dispatch(prev)
+
+    def _step_dispatch(self, prev=None):
         """Snapshot the resident slots and dispatch one decode step
         WITHOUT materializing its next-token fetch — JAX's async
         dispatch returns as soon as the step is staged, so the caller
-        can do host work (admission) while the device computes."""
+        can do host work while the device computes. With `prev`, the
+        step dispatched before this one and not fetched yet, a row of
+        `prev` takes its input token from `prev`'s output on the device
+        (`_carry_tokens`) and writes one position further; every other
+        row is fed from the host as without. Returns the step's
+        `_Flight`, or None with nothing to step or after a failure."""
         with _loop_phase('feed'):
             c = self.config
-            self._grow_blocks()
+            held = self._grow_blocks()
             S = c.slots
             toks = np.zeros((S, 1), 'int64')
+            carry = np.zeros((S, 1), bool)
             pos = np.zeros((S, 1), 'int64')
             sample = self._sample_feed(S)
             btab = np.zeros((S, self._max_blocks), 'int64')
             active = []
             live_pages = 0
             for i, st in enumerate(self._slots):
-                if st is None:
+                if st is None or i in held:
                     continue
-                toks[i], pos[i] = st.last, st.pos
+                if st.ahead:
+                    carry[i] = True
+                else:
+                    toks[i] = st.last
+                at = st.pos + st.ahead
+                pos[i] = at
                 r = st.req
                 sample['gen_temp'][i] = r.temperature
                 sample['gen_topk'][i] = r.top_k
                 sample['gen_topp'][i] = r.top_p
+                # drawn a step ahead of the token it follows; a row
+                # whose result is dropped belongs to a request that ended
                 sample['gen_u'][i] = r._draw_u()
                 btab[i] = st.table
-                live_pages += st.pos // c.block_size + 1
+                live_pages += at // c.block_size + 1
                 active.append((i, st))
             if not active:
                 return None
@@ -1758,63 +1924,128 @@ class GenerateEngine(object):
             monitor.inc('kv_decode_pages_live_total', live_pages)
             monitor.inc('kv_decode_pages_table_total',
                         len(active) * self._max_blocks)
-            feed = {'gen_tokens': toks, 'gen_pos': pos, 'gen_btab': btab}
+            feed = {'gen_pos': pos, 'gen_btab': btab}
             feed.update(sample)
         with _loop_phase('dispatch'):
             t0 = time.perf_counter()
             try:
+                feed['gen_tokens'] = self._carry_tokens(
+                    prev.out[0], carry, toks) if carry.any() else toks
                 out = self._step_bound(feed, return_numpy=False)
+                # the device-to-host copy starts now and is latency
+                # behind the next step, not a wait after this one
+                out[0].copy_to_host_async()
             except Exception as e:  # noqa: BLE001 — delivered per-request
-                self._fail_step(active, e)
+                self._fail_step(active, e, prev)
                 return None
-        return (out, active, t0)
+        for _i, st in active:
+            st.ahead += 1
+        flight = _Flight(out, active, t0, prev is not None)
+        self._flights.append(flight)
+        return flight
 
-    def _fail_step(self, active, e):
-        # an exhausted retry (or permanent fault) fails the RESIDENT
-        # requests; the loop and the engine live on — the decode
-        # analog of the PR 4 "pool never dies" contract
+    def _fail_step(self, active, e, *flights):
+        """An exhausted retry (or permanent fault) fails the RESIDENT
+        requests; the loop and the engine live on — the decode analog of
+        the PR 4 "pool never dies" contract. The steps in flight go with
+        it, their residents failed once each: the cache is threaded
+        through every step, so what a later one computed on a failed
+        one's state is nobody's token."""
         monitor.inc('generate_step_error_total')
+        active = list(active)
+        for f in flights:
+            if f is not None:
+                active += f.active
+                f.out = None
+                if f in self._flights:
+                    self._flights.remove(f)
         blackbox.record('generate_step_error', error=e,
                         program=getattr(self._step_bound, '_program', None),
                         residents=len(active))
         for i, st in active:
+            if self._slots[i] is not st:
+                continue    # failed a line above, or gone before
             self._release(i)
             monitor.inc('generate_request_total',
                         labels={'outcome': 'error'})
             st.req.fail(e)
         self._set_occupancy()
 
-    def _step_complete(self, pending, exclude_s=0.0):
-        out, active, t0 = pending
+    def _step_complete(self, flight, nxt=None):
+        """Fetch step `flight`'s tokens and deliver them; `nxt` is the
+        step dispatched behind it. A step that fails here takes `nxt`
+        with it: the residents of both are failed, neither delivers."""
+        if flight.out is None:
+            return      # went with the failed dispatch of the step behind
         try:
             # materialization = device completion; an async runtime
-            # failure surfaces here and fails the step's residents
+            # failure surfaces here and fails the residents
             with _loop_phase('wait'):
-                fetched = np.asarray(out[0])
-                monitor.observe(
-                    'decode_step_seconds',
-                    max(0.0, time.perf_counter() - t0 - exclude_s))
+                fetched = flight.fetch()
+                self._observe_step(flight)
         except Exception as e:  # noqa: BLE001 — delivered per-request
-            self._fail_step(active, e)
+            self._fail_step([], e, flight, nxt)
             return
+        self._flights.remove(flight)
         with _loop_phase('deliver'):
-            self._deliver(active, self._split_load(fetched,
-                                                   self.config.slots))
+            self._deliver(flight.active,
+                          self._split_load(fetched, self.config.slots))
+        with _loop_phase('yield'):
+            # The threads that consume the tokens run NOW: with the
+            # pipeline full this thread no longer blocks on the device,
+            # and a consumer would otherwise get the GIL a switch
+            # interval later, in the middle of the next dispatch — a
+            # client whose request just ended then sends its next one
+            # too late for the coming admission (on the chip: chat's
+            # ttft_p95_ms + 14 % without this line, PERF.md PR 31). The
+            # work is theirs either way; this only says when, and the
+            # phase keeps their turn out of `deliver`, the loop's own.
+            time.sleep(0)
 
-    def _deliver(self, active, nxt):
-        """The host's share of a completed step: per-slot bookkeeping,
-        the tokens out to their streams, finished slots released."""
+    def _observe_step(self, flight):
+        """decode_step_seconds, one observation a decode step. A step
+        that was in flight together with the one before it is timed from
+        that one's fetch to its own: from its own dispatch it would read
+        two periods, round a fetch that is already there nothing. Of an
+        admission in between, what it waited for its prefill ALONE — the
+        steps in flight seen complete (`_prefill_call`) — is taken out:
+        that is prefill_seconds, and the time the steps computed behind
+        the admission stays in. A step with no predecessor in flight is
+        timed from its dispatch. In a device-bound loop the mean is the
+        device's step, in a host-bound one the loop's period."""
         now = time.perf_counter()
-        n = len(active)
+        since = self._fetched_t if flight.overlapped else flight.t0
+        monitor.observe('decode_step_seconds',
+                        max(0.0, now - since - self._prefill_alone_s))
+        self._fetched_t, self._prefill_alone_s = now, 0.0
+        if flight.overlapped:
+            # booked with the observation it is a share of, so that a
+            # window's two deltas count the same steps
+            self._overlapped_steps += 1
+            monitor.inc('generate_overlapped_steps_total')
+
+    def _deliver(self, active, tokens):
+        """The host's share of a completed step: per-slot bookkeeping,
+        the tokens out to their streams, finished slots released. A row
+        is booked only for the tenant it was dispatched for: one that
+        finished by `eos` (or was evicted) with this step in flight
+        already is gone, its row's result dropped and counted."""
+        now = time.perf_counter()
+        live = [(i, st) for i, st in active if self._slots[i] is st]
+        n = len(live)
+        if n < len(active):
+            self._discarded_rows += len(active) - n
+            monitor.inc('generate_discarded_rows_total', len(active) - n)
         self._decode_steps += 1
         self._decode_tokens += n
         self._occ_sum += n / float(self.config.slots)
         monitor.inc('decode_tokens_total', n)
         speculative = self.config.speculative
-        for i, st in active:
+        for i, st in live:
+            st.ahead -= 1
             st.pos += 1
             st.generated += 1
-            st.last = int(nxt[i])
+            st.last = int(tokens[i])
             if speculative:
                 # this plain step wrote position pos-1 into the TARGET
                 # cache only; the draft cache now has a hole there
@@ -1882,6 +2113,14 @@ class GenerateEngine(object):
                 % (now - r.enqueue_t)))
 
     def _release(self, i):
+        # The step in flight may hold this slot's row still (a finish by
+        # `eos`, an eviction): it then writes one stale K/V row into a
+        # block handed back here — one past the prompt, so never a block
+        # the prefix cache shares. The device runs programs in dispatch
+        # order and the cache is threaded through them as donated state,
+        # so whatever a later tenant of the block writes lands after, and
+        # rows past a tenant's own write head are masked in every
+        # attention: the stale row is never read.
         st = self._slots[i]
         if st is not None:
             self._release_blocks(st)
@@ -1911,6 +2150,8 @@ class GenerateEngine(object):
             'queue_depth': self.queue.depth(),
             'decode_steps': steps,
             'sampled_steps': self._sampled_steps,
+            'overlapped_steps': self._overlapped_steps,
+            'discarded_rows': self._discarded_rows,
             'decode_tokens': self._decode_tokens,
             'peak_slot_occupancy': round(self._occ_peak, 4),
             'mean_slot_occupancy': round(self._occ_sum / steps, 4)

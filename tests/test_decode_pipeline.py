@@ -1,0 +1,397 @@
+"""The decode loop's pipeline (serving/generate.py `_loop`): with step k
+dispatched and not fetched, step k + 1 goes out on step k's tokens as they
+are on the device, and step k is fetched and delivered behind it. Held
+here: the order and that a carried token never becomes numpy; the one
+finish the host cannot foresee (`eos`) with a step in flight, and the
+slot's and the blocks' next tenant; streams bitwise `generate_once`'s
+through every other finish; a failed step; a speculative engine's
+fallback steps; and that the device-fed step is the executable
+`warmup()` bound, not a second compile.
+
+Engines share test_paged_generate.py's tiny-LM shape family, so the
+process-wide compile cache keeps warmups at milliseconds.
+"""
+import threading
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from paddle_tpu import monitor, resilience
+from paddle_tpu.models.transformer import LMConfig
+from paddle_tpu.serving import GenerateConfig, GenerateEngine
+from paddle_tpu.serving import generate as generate_mod
+from paddle_tpu.serving.batcher import DeadlineExceededError
+
+MAX_LEN = 48
+BS = 8
+
+
+def _cfg(**kw):
+    kw.setdefault('model', LMConfig(
+        vocab_size=64, seq_len=32, d_model=32, n_head=2, n_layer=2,
+        d_ff=64, dropout=0.0, attn_dropout=0.0, use_flash_attention=False))
+    kw.setdefault('slots', 4)
+    kw.setdefault('max_len', MAX_LEN)
+    kw.setdefault('prompt_buckets', [8, 16])
+    kw.setdefault('eos_id', None)
+    kw.setdefault('seed', 0)
+    kw.setdefault('block_size', BS)
+    return GenerateConfig(**kw)
+
+
+def _prompt(n, seed=0):
+    return np.random.RandomState(seed).randint(2, 64, size=n) \
+        .astype('int64')
+
+
+def _record(eng, log, slow_s=0.0):
+    """Wrap the bound decode step and `_deliver`: `log` gets ('dispatch',
+    the type of the fed gen_tokens) and ('deliver', rows) in the order the
+    loop thread reaches them."""
+    step, deliver = eng._step_bound, eng._deliver
+
+    def bound(feed, **kw):
+        log.append(('dispatch', type(feed['gen_tokens'])))
+        if slow_s:
+            time.sleep(slow_s)
+        return step(feed, **kw)
+
+    def delivered(active, tokens):
+        log.append(('deliver', len(active)))
+        return deliver(active, tokens)
+    eng._step_bound, eng._deliver = bound, delivered
+
+
+# ---------------------------------------------------------------------------
+# (a) the order, and where the token lives
+
+
+def test_next_step_goes_out_before_the_last_is_fetched_on_device_tokens():
+    eng = GenerateEngine(_cfg())
+    p, n = _prompt(6, seed=1), 12
+    ref = eng.generate_once(p, max_new_tokens=n)
+    log = []
+    _record(eng, log)
+    before = monitor.counters()
+    with eng:
+        assert list(eng.submit(p, max_new_tokens=n).result(60)) == ref
+    kinds = [k for k, _ in log]
+    # n - 1 decode steps: the first has no predecessor, every later one is
+    # dispatched with its predecessor unfetched — two dispatches lead,
+    # then a delivery and a dispatch alternate, and two deliveries trail
+    # (the host foresees `length` and leaves the row out of a step n)
+    assert kinds == ['dispatch'] * 2 + ['deliver', 'dispatch'] * (n - 3) \
+        + ['deliver'] * 2
+    fed = [t for k, t in log if k == 'dispatch']
+    assert fed[0] is np.ndarray                 # the prefill's token
+    assert all(issubclass(t, jax.Array) for t in fed[1:])   # never numpy
+    delta = monitor.counter_delta(before)
+    assert delta['generate_overlapped_steps_total'] == n - 2
+    assert 'generate_discarded_rows_total' not in delta
+    st = eng.stats()
+    assert st['overlapped_steps'] == n - 2 and st['discarded_rows'] == 0
+    assert st['decode_steps'] == n - 1
+
+
+def test_the_device_fed_step_is_the_executable_warmup_bound():
+    """jax's own compiles, counted: none after warmup(), although the
+    loop feeds the step a device int32 where warmup's numpy feed is
+    int64; and the paddle-level compile cache stays quiet too."""
+    eng = GenerateEngine(_cfg())
+    eng.warmup()
+    compiles = []
+
+    def listener(event, _secs, **_kw):
+        if event == '/jax/core/compile/backend_compile_duration':
+            compiles.append(event)
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    before = monitor.counters()
+    try:
+        with eng:
+            reqs = [eng.submit(_prompt(5 + i, seed=20 + i),
+                               max_new_tokens=6 + i,
+                               temperature=0.7 * (i % 2), sample_seed=i)
+                    for i in range(6)]
+            assert [len(r.result(60)) for r in reqs] == list(range(6, 12))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert compiles == []
+    delta = monitor.counter_delta(before)
+    assert not any(k.startswith('compile_cache_miss') for k in delta), delta
+    assert delta['generate_overlapped_steps_total'] > 0
+
+
+@pytest.mark.parametrize('step_done,order', [
+    (True, ['dispatch', 'admit', 'deliver']),
+    (False, ['dispatch', 'deliver', 'admit'])],
+    ids=['host-paced', 'device-paced'])
+def test_where_a_pass_admits_is_what_the_loop_observes(monkeypatch,
+                                                       step_done, order):
+    """The one fork in a pass, pinned on each side: step k done when the
+    dispatch of k + 1 returns (the host paces the loop) — admit, then
+    fetch and deliver k; still computing (the device paces it) — fetch
+    and deliver k, then admit. A prefill so goes out with one unfinished
+    step ahead of it either way. The tokens are generate_once's on both
+    sides: the fork moves when the host looks, not what is computed."""
+    monkeypatch.setattr(generate_mod._Flight, 'ready',
+                        lambda self: step_done)
+    eng = GenerateEngine(_cfg())
+    work = [(_prompt(6, seed=71), 12), (_prompt(9, seed=72), 8),
+            (_prompt(4, seed=73), 10)]
+    ref = [eng.generate_once(p, max_new_tokens=n) for p, n in work]
+    log = []
+    _record(eng, log)
+    admit = eng._admit
+
+    def admitted():
+        log.append(('admit', None))
+        return admit()
+    eng._admit = admitted
+    with eng:
+        reqs = [eng.submit(p, max_new_tokens=n) for p, n in work]
+        assert [list(r.result(60)) for r in reqs] == ref
+    kinds = [k for k, _ in log]
+    at = [i for i, k in enumerate(kinds) if k == 'dispatch']
+    # a pass with a step in flight that dispatched another: from its
+    # dispatch to the next pass's
+    full = [kinds[a:b] for a, b in zip(at, at[1:]) if b - a > 1]
+    assert len(full) >= 8 and all(p == order for p in full), full
+
+
+# ---------------------------------------------------------------------------
+# (b) `eos` with a step in flight
+
+
+@pytest.mark.parametrize('prefix_sharing', [True, False],
+                         ids=['prefix-cache', 'no-prefix-cache'])
+def test_eos_with_a_step_in_flight_and_the_slots_next_tenant(prefix_sharing):
+    """The row that ends by `eos` is in the step in flight already: its
+    stream ends AT the eos, the row's result is dropped, and the next
+    tenant of the slot — one slot, a pool of four blocks, so of the same
+    blocks too — serves exactly generate_once's tokens."""
+    kw = dict(slots=1, num_blocks=5, prefix_sharing=prefix_sharing)
+    pa = _prompt(10, seed=7)
+    probe = GenerateEngine(_cfg(**kw))
+    ref_a = probe.generate_once(pa, max_new_tokens=24)
+    eos = ref_a[3]      # a token the model does emit, mid-sequence
+    cut_a = ref_a[:ref_a.index(eos) + 1]
+    for seed in range(8, 40):   # a tenant that decodes before any eos
+        pb = _prompt(13, seed=seed)
+        ref_b = probe.generate_once(pb, max_new_tokens=15)
+        if eos not in ref_b[:4]:
+            break
+    cut_b = ref_b[:ref_b.index(eos) + 1] if eos in ref_b else ref_b
+
+    eng = GenerateEngine(_cfg(eos_id=eos, **kw))
+    eng.warmup()
+    start = eng.stats()['blocks']['in_use']
+    owned = {}
+    step = eng._step_bound
+
+    def bound(feed, **kw):
+        for st in eng._slots:
+            if st is not None:
+                owned.setdefault(id(st.req), set()).update(st.blocks)
+        return step(feed, **kw)
+    eng._step_bound = bound
+    before = monitor.counters()
+    with eng:
+        a = eng.submit(pa, max_new_tokens=24)
+        b = eng.submit(pb, max_new_tokens=15)
+        got_a, got_b = list(a.result(60)), list(b.result(60))
+    assert got_a == cut_a and a.finish_reason == 'eos'
+    assert got_b == cut_b
+    assert len(got_a) > 2 and len(got_b) > 2    # both did decode
+    delta = monitor.counter_delta(before)
+    assert delta['generate_discarded_rows_total'] >= 1
+    assert eng.stats()['discarded_rows'] >= 1
+    assert owned[id(a)] & owned[id(b)], (owned, 'no block changed hands')
+    assert eng.stats()['blocks']['in_use'] == start == 0
+
+
+# ---------------------------------------------------------------------------
+# (c) every other finish, with a step in flight; streams bitwise
+
+
+@pytest.mark.parametrize('pool', ['roomy', 'dry'])
+def test_streams_are_generate_onces_through_every_finish(pool):
+    """Greedy and pinned-seed sampled rows under concurrency, with a
+    step in flight at every finish: `length`; `cache_full` by max_len
+    (roomy) or by a dry pool (dry: five residents that would grow to 18
+    blocks on a pool of 11); a deadline eviction. Whatever a request got
+    is generate_once's tokens, bitwise, from the first on."""
+    eng = GenerateEngine(_cfg(
+        slots=5, **(dict(num_blocks=12) if pool == 'dry' else {})))
+    work = {
+        'greedy': dict(prompt=_prompt(6, seed=31), max_new_tokens=14),
+        'sampled': dict(prompt=_prompt(9, seed=32), max_new_tokens=17,
+                        temperature=0.8, top_k=8, sample_seed=11),
+        'nucleus': dict(prompt=_prompt(4, seed=33), max_new_tokens=9,
+                        temperature=1.1, top_p=0.9, sample_seed=5),
+        'long': dict(prompt=_prompt(10, seed=34), max_new_tokens=200),
+    }
+    ref = {k: eng.generate_once(**w) for k, w in work.items()}
+    assert len(ref['long']) == MAX_LEN - 10 + 1
+    log = []
+    _record(eng, log, slow_s=0.01)
+    before = monitor.counters()
+    with eng:
+        doomed = eng.submit(_prompt(5, seed=35), max_new_tokens=40,
+                            deadline_s=0.3)
+        reqs = {k: eng.submit(deadline_s=60.0, **w)
+                for k, w in work.items()}
+        got_doomed = []
+        with pytest.raises(DeadlineExceededError):
+            for tok in doomed.stream(timeout=30.0):
+                got_doomed.append(tok)
+        got = {k: list(r.result(60)) for k, r in reqs.items()}
+        after = eng.generate(_prompt(5, seed=35), max_new_tokens=40,
+                             deadline_s=60.0)
+    if pool == 'roomy':
+        for k in ('greedy', 'sampled', 'nucleus'):
+            assert reqs[k].finish_reason == 'length', k
+        assert got == ref               # 'long' ended by max_len
+    else:
+        # whom the dry pool starves is the allocator's to say: what a
+        # request got before is its reference's tokens all the same
+        for k in work:
+            assert 1 <= len(got[k]) <= len(ref[k]), k
+            assert got[k] == ref[k][:len(got[k])], k
+            assert reqs[k].finish_reason == (
+                'length' if len(got[k]) == work[k]['max_new_tokens']
+                else 'cache_full'), k
+        assert any(len(got[k]) < len(ref[k]) for k in work)
+    assert reqs['long'].finish_reason == 'cache_full'
+    assert 0 < len(got_doomed) < 40
+    assert list(after)[:len(got_doomed)] == got_doomed  # a prefix, bitwise
+    delta = monitor.counter_delta(before)
+    assert delta['generate_request_total{outcome=deadline}'] == 1
+    assert delta['generate_request_total{outcome=ok}'] == 5
+    assert delta['generate_overlapped_steps_total'] > 0
+    # the evicted row was in the step in flight: dropped, not booked
+    assert delta.get('generate_discarded_rows_total', 0) >= 1
+    st = eng.stats()
+    assert st['active'] == 0 and st['blocks']['in_use'] == 0
+    steps = monitor.snapshot()['histograms']['decode_step_seconds']
+    assert steps['count'] >= st['decode_steps']     # process-wide >= ours
+
+
+# ---------------------------------------------------------------------------
+# (d) a step that fails
+
+
+def _fail_fetch_once(monkeypatch, at):
+    calls = []
+    fetch = generate_mod._Flight.fetch
+
+    def failing(self):
+        calls.append(1)
+        if len(calls) == at:
+            raise RuntimeError('async failure at the fetch')
+        return fetch(self)
+    monkeypatch.setattr(generate_mod._Flight, 'fetch', failing)
+
+
+@pytest.mark.parametrize('where', ['dispatch', 'fetch'])
+def test_a_failed_step_takes_both_steps_residents_once(monkeypatch, where):
+    """A step fails at its dispatch (the `run` fault site, retries
+    exhausted) or at its fetch (an async failure) with another step in
+    flight: every resident of the two steps gets the error once, after
+    the tokens it streamed; the loop lives and serves the next request."""
+    monkeypatch.setenv('PADDLE_RETRY_MAX_ATTEMPTS', '2')
+    monkeypatch.setenv('PADDLE_RETRY_BASE_S', '0.01')
+    eng = GenerateEngine(_cfg())
+    eng.warmup()
+    ref = eng.generate_once(_prompt(5, seed=43), max_new_tokens=4)
+    log = []
+    _record(eng, log, slow_s=0.005)
+    before = monitor.counters()
+    error = resilience.InjectedFault if where == 'dispatch' \
+        else RuntimeError
+    with eng:
+        reqs = [eng.submit(_prompt(5 + i, seed=40 + i), max_new_tokens=40,
+                           deadline_s=60.0) for i in range(3)]
+        streams = [r.stream(timeout=30.0) for r in reqs]
+        got = [[next(s), next(s)] for s in streams]     # all resident
+        if where == 'dispatch':
+            resilience.install_fault('run', mode='always')
+        else:
+            _fail_fetch_once(monkeypatch, at=1)
+        try:
+            for s, g in zip(streams, got):
+                with pytest.raises(error):
+                    for tok in s:
+                        g.append(tok)
+        finally:
+            resilience.clear_faults()
+        assert all(len(g) < 40 for g in got)
+        out = eng.generate(_prompt(5, seed=43), max_new_tokens=4,
+                           deadline_s=60.0)
+        assert list(out) == ref
+    delta = monitor.counter_delta(before)
+    assert delta['generate_step_error_total'] == 1
+    assert delta['generate_request_total{outcome=error}'] == 3  # once each
+    assert delta['generate_request_total{outcome=ok}'] == 1
+    assert eng.stats()['active'] == 0
+    assert eng.stats()['blocks']['in_use'] == 0
+
+
+# ---------------------------------------------------------------------------
+# a speculative engine: rounds serial, fallback steps pipelined
+
+
+def test_speculative_fallback_steps_pipeline_and_rounds_stay_serial():
+    """A sampled rider pins a speculative engine on plain steps: those
+    pipeline like any other engine's (the counter moves, tokens are
+    generate_once's); the rounds before and after run with nothing in
+    flight, and accept every proposal of a draft that IS the target."""
+    eng = GenerateEngine(_cfg(speculative=True, spec_k=3))
+    pg, ps = _prompt(6, seed=51), _prompt(9, seed=52)
+    ref_g = eng.generate_once(pg, max_new_tokens=20)
+    ref_s = eng.generate_once(ps, max_new_tokens=7, temperature=0.8,
+                              top_k=8, sample_seed=3)
+    rounds_in_flight = []
+    spec_round = eng._spec_round
+
+    def watched():
+        rounds_in_flight.append(list(eng._flights))
+        return spec_round()
+    eng._spec_round = watched
+    with eng:
+        rs = eng.submit(ps, max_new_tokens=7, temperature=0.8, top_k=8,
+                        sample_seed=3)
+        rg = eng.submit(pg, max_new_tokens=20)
+        assert list(rs.result(60)) == ref_s
+        assert list(rg.result(60)) == ref_g
+    st = eng.stats()
+    assert st['overlapped_steps'] > 0
+    assert st['spec']['fallback_rounds'] > 0 and st['spec']['rounds'] > 0
+    assert rounds_in_flight and not any(rounds_in_flight)
+
+
+# ---------------------------------------------------------------------------
+# stop() with a step in flight
+
+
+def test_stop_lands_the_step_in_flight():
+    eng = GenerateEngine(_cfg())
+    eng.warmup()
+    log = []
+    _record(eng, log, slow_s=0.01)
+    eng.start()
+    req = eng.submit(_prompt(6, seed=61), max_new_tokens=40,
+                     deadline_s=60.0)
+    stream = req.stream(timeout=30.0)
+    got = [next(stream), next(stream), next(stream)]
+    eng.stop()
+    with pytest.raises(generate_mod.EngineStoppedError):
+        for tok in stream:
+            got.append(tok)
+    assert eng._flights == []
+    # every step dispatched was fetched and delivered before the thread
+    # ended: nothing on the device outlives the engine's loop
+    kinds = [k for k, _ in log]
+    assert kinds.count('dispatch') == kinds.count('deliver')
+    assert not any(t.name == 'paddle-generate' for t in threading.enumerate())

@@ -115,7 +115,7 @@ def test_loop_phases_add_up_to_the_loops_wall_time():
     phases = _phases(delta, LOOP)
     wall = delta['generate_loop_wall_seconds_total']
     assert {'admit', 'prefill', 'feed', 'dispatch', 'admit_overlapped',
-            'wait', 'deliver', 'idle'} <= set(phases)
+            'wait', 'deliver', 'yield', 'idle'} <= set(phases)
     # self times: nothing counts twice, and little is left uncovered
     assert sum(phases.values()) <= wall * 1.001
     assert sum(phases.values()) >= wall * 0.95
@@ -131,6 +131,46 @@ def test_loop_phases_add_up_to_the_loops_wall_time():
     assert loop['admitted'] == flat['generate_admit_total']
     assert loop['wall_s'] == flat['generate_loop_wall_seconds_total']
     assert loop['phase_s']['feed'] == flat[LOOP + '{phase=feed}']
+
+
+def test_decode_step_seconds_is_one_observation_a_step_inside_the_wall():
+    """With the pipeline full a step's observation runs from the fetch
+    before it to its own: one a decode step, so the count is the step
+    count `decode_sampled_step_share` and `decode_overlapped_step_share`
+    divide by; their sum stays inside the loop's wall time (no stretch
+    is counted twice); and the mean is not below what the bound call
+    itself takes — an observation round a fetch that is already there
+    would read nothing, and `decode_hbm_share` over it more than the
+    device can do."""
+    eng = _engine()
+    eng.warmup()
+    S = eng.config.slots
+    feed = {'gen_tokens': np.zeros((S, 1), 'int64'),
+            'gen_pos': np.zeros((S, 1), 'int64'),
+            'gen_btab': np.zeros((S, eng._max_blocks), 'int64')}
+    feed.update(eng._sample_feed(S))
+    call_s = []
+    for _ in range(8):      # all-zero tables: the trash block
+        t0 = time.perf_counter()
+        out = eng._step_bound(feed, return_numpy=False)
+        call_s.append(time.perf_counter() - t0)     # the call, not the step
+        out[0].block_until_ready()
+    before = monitor.counters()
+    hist0 = monitor.snapshot()['histograms'].get('decode_step_seconds', {})
+    with eng:
+        reqs = [eng.submit(_prompt(5 + i, i), max_new_tokens=24)
+                for i in range(S)]      # every slot resident throughout
+        assert [len(r.result(timeout=60)) for r in reqs] == [24] * S
+    delta = monitor.counter_delta(before)
+    hist1 = monitor.snapshot()['histograms']['decode_step_seconds']
+    count = hist1['count'] - hist0.get('count', 0)
+    total = hist1['sum'] - hist0.get('sum', 0.0)
+    st = eng.stats()
+    assert count == st['decode_steps'] == 23
+    assert st['overlapped_steps'] == delta['generate_overlapped_steps_total']
+    assert st['overlapped_steps'] >= 21         # all but the start's
+    assert total <= delta['generate_loop_wall_seconds_total']
+    assert total / count >= min(call_s)
 
 
 # ---------------------------------------------------------------------------
@@ -281,7 +321,7 @@ def _best_call_us(hook, n=6000, rounds=5):
 
 def test_phase_overhead_within_the_step_and_run_budgets():
     """What the phases add with no profiler session: at most 30 us a
-    decode step (its seven phases and the pass's wall counter) and 10 us
+    decode step (its phases and the pass's wall counter) and 10 us
     an Executor.run (its four)."""
     import jax
     assert not jax.profiler.TraceAnnotation.is_enabled()
@@ -290,7 +330,7 @@ def test_phase_overhead_within_the_step_and_run_budgets():
 
     def step():
         for name in ('admit', 'feed', 'dispatch', 'admit_overlapped',
-                     'wait', 'deliver'):
+                     'wait', 'deliver', 'yield'):
             with _loop_phase(name):
                 pass
         monitor.inc('generate_loop_wall_seconds_total', 0.0)
