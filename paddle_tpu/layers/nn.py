@@ -15,7 +15,8 @@ __all__ = [
     'sigmoid_cross_entropy_with_logits', 'conv2d', 'conv3d',
     'conv2d_transpose', 'pool2d', 'pool3d', 'batch_norm', 'layer_norm',
     'fused_layer_norm_residual', 'fused_ffn_tail', 'rms_norm',
-    'rotary_embedding', 'moe_ffn',
+    'rotary_embedding', 'moe_ffn', 'mla_decode_attention',
+    'mla_prefix_attention',
     'group_norm', 'data_norm', 'l2_normalize', 'matmul', 'mul', 'topk',
     'reshape', 'squeeze', 'unsqueeze', 'flatten', 'transpose', 'split',
     'reduce_sum', 'reduce_mean', 'reduce_max', 'reduce_min', 'reduce_prod',
@@ -596,23 +597,29 @@ def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
     return out
 
 
-def rotary_embedding(input, positions, theta=10000.0, name=None):
+def rotary_embedding(input, positions, theta=10000.0, interleave=False,
+                     name=None):
     """Rotary position embedding of ``input [..., H, dh]`` by the int64
     ``positions`` (one per leading row of ``input``), ``rotate_half``
-    convention, ``inv_freq = theta^(-2i/dh)`` (ops/moe_ops.py)."""
+    convention, or with ``interleave`` the pairs ``(2i, 2i + 1)``;
+    ``inv_freq = theta^(-2i/dh)`` (ops/moe_ops.py)."""
     helper = LayerHelper('rotary_embedding', name=name)
     out = helper.create_variable_for_type_inference(input.dtype,
                                                     shape=input.shape)
+    attrs = {'theta': float(theta)}
+    if interleave:
+        attrs['interleave'] = True
     helper.append_op(type='rotary_embedding',
                      inputs={'X': [input], 'Positions': [positions]},
-                     outputs={'Out': [out]}, attrs={'theta': float(theta)})
+                     outputs={'Out': [out]}, attrs=attrs)
     return out
 
 
 def moe_ffn(input, n_experts, expert_width, top_k, norm_topk_prob=False,
             length=None, valid=None, router_param_attr=None,
             gate_param_attr=None, up_param_attr=None, down_param_attr=None,
-            name=None):
+            score='softmax', select_bias_attr=None, routed_scale=1.0,
+            experts_held=None, name=None):
     """Dropless top-k mixture-of-experts FFN over the rows of ``input
     [N, d]`` (ops/moe_ops.py): a float32 softmax router over all
     ``n_experts``, the ``top_k`` largest (renormalised only with
@@ -620,7 +627,15 @@ def moe_ffn(input, n_experts, expert_width, top_k, norm_topk_prob=False,
     through a grouped matmul. Returns ``(out [N, d], topk_idx [N, top_k]
     int32, expert_load [n_experts] int32)``; ``length`` (real rows of a
     padded bucket) and ``valid`` (zero = an idle row) only leave rows out
-    of ``expert_load``."""
+    of ``expert_load``.
+
+    ``score='sigmoid'``: sigmoid scores, chosen by score +
+    ``select_bias_attr``'s parameter, weighted by the score and
+    ``routed_scale``. ``experts_held = (first, count)``: the layer
+    holds that share of the ``n_experts`` the router scores, ``out`` is
+    the part of the sum these experts give and ``expert_load`` is
+    ``[count + 1]``, the last entry the assignments that went
+    elsewhere."""
     helper = LayerHelper('moe_ffn', name=name)
     dtype = input.dtype
     n, d = input.shape[0], input.shape[-1]
@@ -630,28 +645,86 @@ def moe_ffn(input, n_experts, expert_width, top_k, norm_topk_prob=False,
         return helper.create_parameter(attr=attr or ParamAttr(),
                                        shape=shape, dtype=dtype,
                                        default_initializer=init)
+    first, held = experts_held or (0, n_experts)
+    if not 0 <= first <= first + held <= n_experts or not held:
+        raise ValueError('moe_ffn: experts_held=%r is no share of %d '
+                         'experts' % (experts_held, n_experts))
+    share = held < n_experts
     router = param(router_param_attr, [d, n_experts])
-    gate = param(gate_param_attr, [n_experts, d, expert_width])
-    up = param(up_param_attr, [n_experts, d, expert_width])
-    down = param(down_param_attr, [n_experts, expert_width, d])
+    gate = param(gate_param_attr, [held, d, expert_width])
+    up = param(up_param_attr, [held, d, expert_width])
+    down = param(down_param_attr, [held, expert_width, d])
     out = helper.create_variable_for_type_inference(dtype,
                                                     shape=input.shape)
     idx = helper.create_variable_for_type_inference('int32',
                                                     shape=(n, top_k))
-    load = helper.create_variable_for_type_inference('int32',
-                                                     shape=(n_experts,))
+    load = helper.create_variable_for_type_inference(
+        'int32', shape=(held + 1 if share else held,))
     inputs = {'X': [input], 'RouterW': [router], 'GateW': [gate],
               'UpW': [up], 'DownW': [down]}
     if length is not None:
         inputs['Length'] = [length]
     if valid is not None:
         inputs['Valid'] = [valid]
+    attrs = {'top_k': int(top_k), 'norm_topk_prob': bool(norm_topk_prob)}
+    if score != 'softmax':
+        attrs.update(score=score, routed_scale=float(routed_scale))
+        inputs['SelectBias'] = [helper.create_parameter(
+            attr=select_bias_attr or ParamAttr(), shape=[n_experts],
+            dtype=dtype, default_initializer=Normal(0.0, 0.01))]
+    if share:
+        attrs['first_expert'] = int(first)
     helper.append_op(type='moe_ffn', inputs=inputs,
                      outputs={'Out': [out], 'TopkIdx': [idx],
-                              'ExpertLoad': [load]},
-                     attrs={'top_k': int(top_k),
-                            'norm_topk_prob': bool(norm_topk_prob)})
+                              'ExpertLoad': [load]}, attrs=attrs)
     return out, idx, load
+
+
+def _mla_attention(op_type, table_slot, q, cache, positions, tables, layer,
+                   scale, kv_rank, rope_dim, v_dim, up_k_attr, up_v_attr):
+    helper = LayerHelper(op_type)
+    n_head, nope = q.shape[-2], q.shape[-1] - rope_dim
+    init = Normal(0.0, 0.02)
+    up_k = helper.create_parameter(attr=up_k_attr, dtype=q.dtype,
+                                   shape=[n_head, nope, kv_rank],
+                                   default_initializer=init)
+    up_v = helper.create_parameter(attr=up_v_attr, dtype=q.dtype,
+                                   shape=[n_head, kv_rank, v_dim],
+                                   default_initializer=init)
+    out = helper.create_variable_for_type_inference(
+        q.dtype, shape=tuple(q.shape[:-1]) + (v_dim,))
+    helper.append_op(
+        type=op_type,
+        inputs={'Q': [q], 'Cache': [cache], 'UpK': [up_k], 'UpV': [up_v],
+                'Positions': [positions], table_slot: [tables]},
+        outputs={'Out': [out]},
+        attrs={'layer': int(layer), 'scale': float(scale)})
+    return out
+
+
+def mla_decode_attention(q, cache, positions, block_tables, layer, scale,
+                         kv_rank, rope_dim, v_dim, up_k_attr=None,
+                         up_v_attr=None):
+    """Absorbed latent attention of every slot's one query against the
+    latent rows its block table names (ops/mla_ops.py). ``q [S, H, nope +
+    rope_dim]``, ``cache`` the latent pool; the parameters are the two
+    halves of the latent up-projection, ``W_uk [H, nope, kv_rank]`` and
+    ``W_uv [H, kv_rank, v_dim]``. Returns ``[S, H, v_dim]``."""
+    return _mla_attention('mla_decode_attention_paged', 'BlockTables', q,
+                          cache, positions, block_tables, layer, scale,
+                          kv_rank, rope_dim, v_dim, up_k_attr, up_v_attr)
+
+
+def mla_prefix_attention(q, cache, positions, block_table, layer, scale,
+                         kv_rank, rope_dim, v_dim, up_k_attr=None,
+                         up_v_attr=None):
+    """Expanded causal latent attention of one prompt suffix ``q [1, T, H,
+    nope + rope_dim]`` against the slot's cached rows (ops/mla_ops.py),
+    with `mla_decode_attention`'s parameters. Returns ``[1, T, H,
+    v_dim]``."""
+    return _mla_attention('mla_prefix_attention', 'BlockTable', q, cache,
+                          positions, block_table, layer, scale, kv_rank,
+                          rope_dim, v_dim, up_k_attr, up_v_attr)
 
 
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,

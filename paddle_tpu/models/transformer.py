@@ -17,7 +17,7 @@ from ..ops.tensor_ops import position_encoding_table  # noqa: F401
 from ..param_attr import ParamAttr
 
 __all__ = ['multi_head_attention', 'transformer_block', 'build_lm',
-           'LMConfig', 'position_encoding_table',
+           'LMConfig', 'position_encoding_table', 'kv_cache_names',
            'build_lm_decode_step', 'build_lm_prefill_paged',
            'build_lm_drafter', 'build_lm_verify']
 
@@ -41,7 +41,26 @@ class LMConfig(object):
     - ``ffn='moe'``: a dropless top-``experts_per_token``-of-``n_experts``
       FFN of SiLU-gated experts of width ``expert_width``
       (``layers.moe_ffn``), weights renormalised over the chosen experts
-      only with ``norm_topk_prob``."""
+      only with ``norm_topk_prob``. ``moe_score='sigmoid'``: sigmoid
+      scores, a bias parameter added to them for the choice alone,
+      ``routed_scale`` on the weights (DeepSeek-V3's router).
+      ``n_shared_experts``: that many more experts of the same width
+      every row goes through, beside the routed ones.
+      ``experts_held = (first, count)``: the chip's SHARE of the experts
+      under expert parallelism — the router scores all ``n_experts``, the
+      layer holds and computes ``count`` of them from ``first`` on, and
+      what the others would add is left out (ops/moe_ops.py).
+      ``n_dense_layers``: that many leading layers take a dense
+      SiLU-gated FFN instead, ``(silu(x W_g) * (x W_u)) W_d`` of width
+      ``d_ff``;
+    - ``attention='mla'``: latent attention (DeepSeek-V2/V3). q through a
+      rank-``q_lora_rank`` bottleneck with its norm, heads of
+      ``qk_nope_dim + qk_rope_dim``; K and V through ONE normed latent of
+      ``kv_lora_rank`` and ONE rotary key of ``qk_rope_dim`` shared by all
+      heads, which is all that is cached (``kv_width``; no V pool); values
+      of ``v_head_dim`` a head. ``rope_interleave``: rotate the pairs
+      ``(2i, 2i + 1)``. The prefill attends in the expanded form, the
+      decode step in the absorbed one (ops/mla_ops.py)."""
 
     def __init__(self, vocab_size=32000, seq_len=512, d_model=512,
                  n_head=8, n_layer=6, d_ff=2048, dropout=0.1,
@@ -49,7 +68,12 @@ class LMConfig(object):
                  norm='layer_norm', rms_eps=1e-5, position='sinusoid',
                  rope_theta=10000.0, head_dim=None, qk_norm=False,
                  bias=True, ffn='gelu', n_experts=0, experts_per_token=0,
-                 expert_width=0, norm_topk_prob=False):
+                 expert_width=0, norm_topk_prob=False, moe_score='softmax',
+                 routed_scale=1.0, n_shared_experts=0, experts_held=None,
+                 n_dense_layers=0,
+                 attention='mha', q_lora_rank=0, kv_lora_rank=0,
+                 qk_nope_dim=0, qk_rope_dim=0, v_head_dim=0,
+                 rope_interleave=False):
         self.vocab_size = vocab_size
         self.seq_len = seq_len
         self.d_model = d_model
@@ -67,7 +91,9 @@ class LMConfig(object):
         for field, value, known in (
                 ('norm', norm, ('layer_norm', 'rms_norm')),
                 ('position', position, ('sinusoid', 'rope')),
-                ('ffn', ffn, ('gelu', 'moe'))):
+                ('ffn', ffn, ('gelu', 'moe')),
+                ('moe_score', moe_score, ('softmax', 'sigmoid')),
+                ('attention', attention, ('mha', 'mla'))):
             if value not in known:
                 raise ValueError('LMConfig.%s=%r: expected one of %r'
                                  % (field, value, known))
@@ -87,15 +113,53 @@ class LMConfig(object):
             raise ValueError('LMConfig.ffn=%r needs 0 < experts_per_token '
                              '<= n_experts, got %r of %r'
                              % (ffn, experts_per_token, n_experts))
+        self.moe_score = moe_score
+        self.routed_scale = routed_scale
+        self.n_shared_experts = n_shared_experts
+        self.experts_held = tuple(experts_held or (0, n_experts))
+        self.n_dense_layers = n_dense_layers if ffn == 'moe' else 0
+        self.attention = attention
+        self.q_lora_rank = q_lora_rank
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_dim = qk_nope_dim
+        self.qk_rope_dim = qk_rope_dim
+        self.v_head_dim = v_head_dim
+        self.rope_interleave = rope_interleave
+        if attention == 'mla' and not (
+                position == 'rope' and q_lora_rank and kv_lora_rank
+                and qk_nope_dim and qk_rope_dim and v_head_dim):
+            raise ValueError("LMConfig.attention='mla' needs position="
+                             "'rope' and its five sizes (q_lora_rank, "
+                             "kv_lora_rank, qk_nope_dim, qk_rope_dim, "
+                             "v_head_dim)")
 
     @property
     def kv_width(self):
-        """Lanes of one cached K (or V) row: heads x head size."""
+        """Lanes of one cached row. Heads x head size of K (and of V, in
+        its own pool); with latent attention the ONE row of a token,
+        ``kv_lora_rank + qk_rope_dim`` numbers filled up with zeros to
+        whole 128-lane tiles — the TPU stores the pool in such tiles
+        whatever is declared, and the decode kernel copies whole ones
+        (ops/mla_paged_decode_attention.py)."""
+        if self.attention == 'mla':
+            return -(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128
         return self.n_head * self.head_dim
+
+    @property
+    def attn_width(self):
+        """Lanes of one token's attention output, all heads."""
+        return self.n_head * (self.v_head_dim if self.attention == 'mla'
+                              else self.head_dim)
+
+    @property
+    def n_moe_layers(self):
+        return self.n_layer - self.n_dense_layers if self.ffn == 'moe' \
+            else 0
 
 
 _CLASSIC_BLOCK = (('norm', 'layer_norm'), ('position', 'sinusoid'),
-                  ('qk_norm', False), ('bias', True), ('ffn', 'gelu'))
+                  ('qk_norm', False), ('bias', True), ('ffn', 'gelu'),
+                  ('attention', 'mha'))
 
 
 def _require_classic_block(cfg, who):
@@ -231,6 +295,8 @@ def _qkv(cfg, ln1, p, pos, T=None):
     ``[S, d]`` -> three ``[S, H, dh]``; else one prompt ``[1, T, d]`` ->
     three ``[1, H, T, dh]``. K comes back as it is CACHED: after k-norm
     and rotation."""
+    if cfg.attention == 'mla':
+        return _mla_qkv(cfg, ln1, p, pos, T)
     h, dh = cfg.n_head, cfg.head_dim
     qkv = layers.fc(ln1, size=3 * h * dh,
                     num_flatten_dims=1 if T is None else 2,
@@ -252,26 +318,117 @@ def _qkv(cfg, ln1, p, pos, T=None):
             for i, which in enumerate('qkv')]
 
 
-def _ffn(cfg, ln2, p, num_flatten_dims, length=None, valid=None):
+def _mla_qkv(cfg, ln1, p, pos, T=None):
+    """Latent attention's `_qkv`: (q, the row to cache, None). q ``[S, H,
+    nope + rope]`` (``[1, T, H, nope + rope]`` in a prefill), its rotary
+    part rotated. The row, laid out as the cache ops take a K of ONE head
+    (``[S, 1, kv_width]``; ``[1, 1, T, kv_width]``): the normed latent,
+    the ONE rotated rotary key of all heads, zeros up to `kv_width`.
+    There is no V to cache."""
+    h, nope, rope = cfg.n_head, cfg.qk_nope_dim, cfg.qk_rope_dim
+    rank, nfd = cfg.kv_lora_rank, 1 if T is None else 2
+    lead = [-1] if T is None else [0, T]
+
+    def proj(x, size, name):
+        return layers.fc(x, size=size, num_flatten_dims=nfd,
+                         param_attr=ParamAttr(name='%s.attn.%s.w'
+                                              % (p, name)),
+                         bias_attr=False)
+
+    def norm(x, name):
+        return layers.rms_norm(
+            x, begin_norm_axis=nfd, epsilon=cfg.rms_eps,
+            param_attr=ParamAttr(name='%s.attn.%s_norm.w' % (p, name)))
+
+    def rotated(x):
+        return layers.rotary_embedding(x, pos, theta=cfg.rope_theta,
+                                       interleave=cfg.rope_interleave)
+
+    def cut(x, axis, start, end):
+        return layers.slice(x, axes=[axis], starts=[start], ends=[end])
+
+    q = layers.reshape(proj(norm(proj(ln1, cfg.q_lora_rank, 'q_a'), 'q_a'),
+                            h * (nope + rope), 'q_b'),
+                       shape=lead + [h, nope + rope])
+    q = layers.concat([cut(q, nfd + 1, 0, nope),
+                       rotated(cut(q, nfd + 1, nope, nope + rope))],
+                      axis=nfd + 1)
+    kv = proj(ln1, rank + rope, 'kv_a')
+    k_rope = layers.reshape(
+        rotated(layers.reshape(cut(kv, nfd, rank, rank + rope),
+                               shape=lead + [1, rope])),
+        shape=lead + [rope])
+    row = layers.pad(
+        layers.concat([norm(cut(kv, nfd, 0, rank), 'kv_a'), k_rope],
+                      axis=nfd),
+        paddings=[0, 0] * nfd + [0, cfg.kv_width - rank - rope])
+    return q, layers.reshape(
+        row, shape=[-1, 1, cfg.kv_width] if T is None
+        else [0, 1, T, cfg.kv_width]), None
+
+
+def _mla_attend(cfg, attention, q, cache, pos, tables, layer):
+    """`attention` (layers.mla_decode_attention / mla_prefix_attention) of
+    `q` against the latent pool, with the layer's up-projection."""
+    p = 'layer_%d' % layer
+    return attention(
+        q, cache, pos, tables, layer,
+        (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5, cfg.kv_lora_rank,
+        cfg.qk_rope_dim, cfg.v_head_dim,
+        up_k_attr=ParamAttr(name=p + '.attn.kv_b_k.w'),
+        up_v_attr=ParamAttr(name=p + '.attn.kv_b_v.w'))
+
+
+def _gated_ffn(x, width, d_model, name, num_flatten_dims):
+    """``(silu(x W_g) * (x W_u)) W_d``, no bias: a dense SiLU-gated FFN
+    (and an expert that every row goes through)."""
+    def proj(x, size, which):
+        return layers.fc(x, size=size, num_flatten_dims=num_flatten_dims,
+                         param_attr=ParamAttr(name='%s.%s.w'
+                                              % (name, which)),
+                         bias_attr=False)
+    return proj(layers.elementwise_mul(layers.swish(proj(x, width, 'gate')),
+                                       proj(x, width, 'up')),
+                d_model, 'down')
+
+
+def _ffn(cfg, ln2, p, num_flatten_dims, length=None, valid=None, layer=0):
     """The block's FFN on its normed input: (delta, routing). GELU: the
-    fused tail, routing None. Experts: `layers.moe_ffn` over the rows,
-    routing = (the ``[rows, experts_per_token]`` experts chosen, the
-    ``[n_experts]`` int32 rows routed to each expert; ``length`` /
+    fused tail, routing None. The ``n_dense_layers`` leading layers of an
+    expert model: `_gated_ffn`, routing None. Experts: `layers.moe_ffn`
+    over the rows, routing = (the ``[rows, experts_per_token]`` experts
+    chosen, the int32 rows routed to each expert held here; ``length`` /
     ``valid`` say which rows are a request's and count)."""
     if cfg.ffn == 'gelu':
         # decode is inference-only: prob 0 / is_test keeps the op on the
         # RNG-free bind fast path (no per-step key derivation)
         return _ffn_tail(ln2, cfg, p, num_flatten_dims), None
+    if layer < cfg.n_dense_layers:
+        return _gated_ffn(ln2, cfg.d_ff, cfg.d_model, p + '.ffn',
+                          num_flatten_dims), None
     shape = ln2.shape
     rows = ln2 if num_flatten_dims == 1 \
         else layers.reshape(ln2, shape=[-1, cfg.d_model])
+    # only what departs from the softmax router over experts all held
+    # here is said: that block's op is the one it always was
+    router = {}
+    if cfg.moe_score != 'softmax':
+        router.update(score=cfg.moe_score, routed_scale=cfg.routed_scale,
+                      select_bias_attr=ParamAttr(
+                          name=p + '.moe.router.bias'))
+    if cfg.experts_held != (0, cfg.n_experts):
+        router['experts_held'] = cfg.experts_held
     out, idx, load = layers.moe_ffn(
         rows, cfg.n_experts, cfg.expert_width, cfg.experts_per_token,
         norm_topk_prob=cfg.norm_topk_prob, length=length, valid=valid,
         router_param_attr=ParamAttr(name=p + '.moe.router.w'),
         gate_param_attr=ParamAttr(name=p + '.moe.gate.w'),
         up_param_attr=ParamAttr(name=p + '.moe.up.w'),
-        down_param_attr=ParamAttr(name=p + '.moe.down.w'))
+        down_param_attr=ParamAttr(name=p + '.moe.down.w'), **router)
+    if cfg.n_shared_experts:
+        out = layers.elementwise_add(out, _gated_ffn(
+            rows, cfg.n_shared_experts * cfg.expert_width, cfg.d_model,
+            p + '.moe.shared', 1))
     if num_flatten_dims != 1:
         out = layers.reshape(out, shape=[-1] + list(shape[1:]))
     return out, (idx, load)
@@ -475,13 +632,21 @@ KV_CACHE_K = 'gen_kv_k'
 KV_CACHE_V = 'gen_kv_v'
 
 
+def kv_cache_names(cfg):
+    """The pools a model's programs declare: K and V apart, or with latent
+    attention the ONE pool of latent rows (under K's name)."""
+    return (KV_CACHE_K,) if cfg.attention == 'mla' \
+        else (KV_CACHE_K, KV_CACHE_V)
+
+
 def _declare_paged_kv_caches(block, cfg, num_blocks, block_size):
+    """(K pool, V pool) of `kv_cache_names`; None for a pool the model
+    does not have."""
     shape = (num_blocks, cfg.n_layer, block_size, cfg.kv_width)
-    kc = block.create_var(name=KV_CACHE_K, shape=shape, dtype='float32',
-                          persistable=True, stop_gradient=True)
-    vc = block.create_var(name=KV_CACHE_V, shape=shape, dtype='float32',
-                          persistable=True, stop_gradient=True)
-    return kc, vc
+    pools = [block.create_var(name=name, shape=shape, dtype='float32',
+                              persistable=True, stop_gradient=True)
+             for name in kv_cache_names(cfg)]
+    return (pools + [None])[:2]
 
 
 SAMPLE_FEEDS = ('gen_temp', 'gen_topk', 'gen_topp', 'gen_u')
@@ -550,12 +715,12 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
             # this K/V deposit — attention/proj/ffn are dead compute
             return None
         ctx = attend(q, i, p + tag)
-        attn = layers.fc(layers.reshape(ctx, shape=[-1, cfg.kv_width]),
+        attn = layers.fc(layers.reshape(ctx, shape=[-1, cfg.attn_width]),
                          size=cfg.d_model,
                          param_attr=ParamAttr(name=p + '.attn.proj.w'),
                          bias_attr=_bias(cfg, p + '.attn.proj.b'))
         ln2, x = _norm(cfg, x, attn, 1, p + '.ln2')
-        delta, routed = _ffn(cfg, ln2, p, 1, valid=valid)
+        delta, routed = _ffn(cfg, ln2, p, 1, valid=valid, layer=i)
         if routed is not None:
             routing.append(routed)
 
@@ -598,6 +763,8 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
 
     def cache_write(k, v, layer):
         for cache, new in ((kc, k), (vc, v)):
+            if cache is None:       # latent attention: no V pool
+                continue
             block.append_op(
                 type='kv_cache_update_paged',
                 inputs={'Cache': [cache], 'New': [new],
@@ -607,6 +774,9 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
                        'block_size': int(block_size)})
 
     def attend(q, layer, name):
+        if cfg.attention == 'mla':
+            return _mla_attend(cfg, layers.mla_decode_attention, q, kc,
+                               pos, btab, layer)
         ctx = block.create_var(name=name + '.kv_ctx',
                                shape=(-1, h, dh), dtype='float32')
         block.append_op(
@@ -888,23 +1058,27 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
         ln1, x = _norm(cfg, x, delta, 2, p + '.ln1')
         q, k, v = _qkv(cfg, ln1, p, pos, T)                  # [1,H,T,dh]
         kc = cache_write(kc, k, i)
-        vc = cache_write(vc, v, i)
-        ctx = block.create_var(name=p + '.prefix_attn_out',
-                               shape=(-1, h, T, dh), dtype='float32')
-        block.append_op(
-            type='kv_prefix_attention',
-            inputs={'Q': [q], 'KCache': [kc], 'VCache': [vc],
-                    'Positions': [pos], 'BlockTable': [btab]},
-            outputs={'Out': [ctx]},
-            attrs={'layer': i, 'scale': dh ** -0.5,
-                   'block_size': int(block_size)})
-        ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
-        ctx = layers.reshape(ctx, shape=[0, T, cfg.kv_width])
+        if cfg.attention == 'mla':
+            ctx = _mla_attend(cfg, layers.mla_prefix_attention, q, kc, pos,
+                              btab, i)                       # [1,T,H,v]
+        else:
+            vc = cache_write(vc, v, i)
+            ctx = block.create_var(name=p + '.prefix_attn_out',
+                                   shape=(-1, h, T, dh), dtype='float32')
+            block.append_op(
+                type='kv_prefix_attention',
+                inputs={'Q': [q], 'KCache': [kc], 'VCache': [vc],
+                        'Positions': [pos], 'BlockTable': [btab]},
+                outputs={'Out': [ctx]},
+                attrs={'layer': i, 'scale': dh ** -0.5,
+                       'block_size': int(block_size)})
+            ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
+        ctx = layers.reshape(ctx, shape=[0, T, cfg.attn_width])
         attn = layers.fc(ctx, size=d, num_flatten_dims=2,
                          param_attr=ParamAttr(name=p + '.attn.proj.w'),
                          bias_attr=_bias(cfg, p + '.attn.proj.b'))
         ln2, x = _norm(cfg, x, attn, 2, p + '.ln2')
-        delta, routed = _ffn(cfg, ln2, p, 2, length=length)
+        delta, routed = _ffn(cfg, ln2, p, 2, length=length, layer=i)
         if routed is not None:
             routing.append(routed)
 

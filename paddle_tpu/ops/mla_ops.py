@@ -1,0 +1,142 @@
+"""Latent attention (MLA, the DeepSeek-V2/V3 layer) against the paged
+cache: two ops for a block whose cached state is ONE row a token a layer,
+shared by all heads (models/transformer.py, LMConfig(attention='mla')).
+
+The cached row is ``[c_kv | k_r]``: the normed latent (``kv_lora_rank``
+lanes) and the rotated shared rotary key (``qk_rope_dim`` lanes), ``W``
+lanes in all. It lies in the K pool of ops/kv_cache_ops.py, written by the
+same ``kv_cache_update_paged`` / ``kv_cache_prefill_paged`` (a row is a
+"head" of width ``W``); there is NO V pool. With ``W_uk [H, nope, R]`` and
+``W_uv [H, R, v]`` the two halves of the published up-projection
+(``kv_b_proj``: ``k_nope = c_kv W_uk^T``, ``v = c_kv W_uv`` per head):
+
+    score(h, t) = (q_nope_h . k_nope_h,t + q_r_h . k_r,t) * scale
+    out_h       = sum_t softmax(score)(h, t) v_h,t
+
+Two forms of it, one result:
+
+- ``mla_prefix_attention`` — EXPANDED, the prefill: the table's rows are
+  gathered, ``k_nope`` and ``v`` rebuilt from their latents for every
+  head, and the suffix's queries attend them causally (queries in chunks,
+  so the scores of a 2048-row bucket against a 2816-row table never stand
+  whole).
+- ``mla_decode_attention_paged`` — ABSORBED, the decode step: ``q' =
+  [q_nope W_uk | q_r]`` (``W`` lanes a head) scores against the cached
+  rows as they lie, ``o_latent = sum p c_kv`` (``R`` lanes a head), then
+  ``out = o_latent W_uv``. ``pallas`` / ``interpret``: the kernel of
+  ops/mla_paged_decode_attention.py on the pool in place; ``off`` /
+  ``xla``: the gather formulation below, the CPU path and the kernel's
+  parity oracle.
+
+A masked (stale / trash / other-tenant) position has weight exactly 0, as
+in every cache op: a slot's output is bit-identical whatever else the
+pool holds.
+"""
+import jax
+import jax.numpy as jnp
+
+from ..core.registry import register_op
+
+_NEG_INF = -1e30
+# query rows of a prefill that attend at once (the scores are
+# [H, rows, table positions] float32)
+_QUERY_CHUNK = 256
+
+
+def absorbed_decode_reference(q, pool, tables, pos, layer, scale, v_width):
+    """The gather formulation of the absorbed decode: q ``[S, H, W]``,
+    pool ``[NB, Ln, bs, W]``, tables ``[S, MB]``, pos ``[S]`` ->
+    ``[S, H, v_width]``."""
+    rows = pool[:, layer][tables]                   # [S, MB, bs, W]
+    rows = rows.reshape(rows.shape[0], -1, rows.shape[-1])   # [S, M, W]
+    scores = jnp.einsum('shw,smw->shm', q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    m = jnp.arange(rows.shape[1])[None, None, :] <= pos[:, None, None]
+    scores = jnp.where(m, scores, _NEG_INF)
+    w = jax.nn.softmax(scores, axis=-1)
+    w = jnp.where(m, w, 0.0)
+    return jnp.einsum('shm,smv->shv', w.astype(rows.dtype),
+                      rows[..., :v_width])
+
+
+@register_op('mla_decode_attention_paged', share_lod=False)
+def _mla_decode_attention_paged(ctx, op):
+    """Absorbed one-query latent attention per slot over the pages its
+    block table names, masked to positions 0..Positions[s] (the step's own
+    row was just deposited there). Q ``[S, H, nope + rope]`` (the rotary
+    part rotated), Out ``[S, H, v]``."""
+    from . import kernel_tier, mla_paged_decode_attention as kern
+    from ..parallel.api import get_active_mesh
+    q = ctx.in1(op, 'Q')                        # [S, H, nope + rope]
+    pool = ctx.in1(op, 'Cache')                 # [NB, Ln, bs, W]
+    w_uk = ctx.in1(op, 'UpK')                   # [H, nope, R]
+    w_uv = ctx.in1(op, 'UpV')                   # [H, R, v]
+    tables = ctx.in1(op, 'BlockTables').astype(jnp.int32)   # [S, MB]
+    pos = ctx.in1(op, 'Positions').reshape(-1).astype(jnp.int32)
+    layer = int(op.attr('layer'))
+    scale = float(op.attr('scale', 1.0))
+    nope, rank = w_uk.shape[1], w_uk.shape[2]
+    S, H, bs = q.shape[0], q.shape[1], pool.shape[2]
+    # [S, H, W], lane for lane the cached row: the latent, the rotary
+    # key, zeros up to whole lane tiles
+    fill = pool.shape[3] - rank - (q.shape[2] - nope)
+    absorbed = jnp.concatenate(
+        [jnp.einsum('shn,hnr->shr', q[..., :nope], w_uk), q[..., nope:],
+         jnp.zeros((S, H, fill), q.dtype)], axis=-1)
+    mesh = get_active_mesh()
+    meshed = mesh is not None and mesh.size > 1
+    impl = kernel_tier.dispatch(
+        'mla_decode_attention_paged', mesh=mesh,
+        pallas_ok=kern.shapes_ok(H, pool.shape[3], rank, bs)
+        and not meshed)
+    if impl in ('pallas', 'interpret'):
+        latent = kern.mla_paged_decode_attention(
+            absorbed, pool, tables, pos, jnp.int32(layer), scale=scale,
+            v_width=rank, interpret=impl == 'interpret')
+    else:
+        latent = absorbed_decode_reference(absorbed, pool, tables, pos,
+                                           layer, scale, rank)
+    ctx.out(op, 'Out', jnp.einsum('shr,hrv->shv', latent, w_uv))
+
+
+@register_op('mla_prefix_attention', share_lod=False)
+def _mla_prefix_attention(ctx, op):
+    """Expanded causal latent attention of one slot's prefill SUFFIX
+    against its block-table cache: query row t sits at global position
+    Positions[t] and attends every cached position <= Positions[t] — the
+    shared prefix plus the suffix rows just deposited. Q ``[1, T, H, nope
+    + rope]``, Out ``[1, T, H, v]``."""
+    q = ctx.in1(op, 'Q')[0]                     # [T, H, nope + rope]
+    pool = ctx.in1(op, 'Cache')                 # [NB, Ln, bs, W]
+    w_uk = ctx.in1(op, 'UpK')                   # [H, nope, R]
+    w_uv = ctx.in1(op, 'UpV')                   # [H, R, v]
+    table = ctx.in1(op, 'BlockTable').reshape(-1).astype(jnp.int32)
+    pos = ctx.in1(op, 'Positions').reshape(-1).astype(jnp.int32)   # [T]
+    layer = int(op.attr('layer'))
+    scale = float(op.attr('scale', 1.0))
+    nope, rank = w_uk.shape[1], w_uk.shape[2]
+    rows = pool[:, layer][table].reshape(-1, pool.shape[3])   # [M, W]
+    # behind the rotary key the row is zeros up to whole lane tiles
+    latent = rows[:, :rank]
+    k_rope = rows[:, rank:rank + q.shape[-1] - nope]
+    k_nope = jnp.einsum('mr,hnr->hmn', latent, w_uk)          # [H, M, nope]
+    value = jnp.einsum('mr,hrv->hmv', latent, w_uv)           # [H, M, v]
+    key_at = jnp.arange(rows.shape[0])
+
+    def attend(args):
+        qc, pc = args                           # [C, H, nope + rope], [C]
+        scores = (jnp.einsum('thn,hmn->htm', qc[..., :nope], k_nope,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum('thr,mr->htm', qc[..., nope:], k_rope,
+                               preferred_element_type=jnp.float32)) * scale
+        m = (key_at[None, :] <= pc[:, None])[None]            # [1, C, M]
+        scores = jnp.where(m, scores, _NEG_INF)
+        w = jax.nn.softmax(scores, axis=-1)
+        w = jnp.where(m, w, 0.0)
+        return jnp.einsum('htm,hmv->thv', w.astype(value.dtype), value)
+
+    T = q.shape[0]
+    chunk = _QUERY_CHUNK if T % _QUERY_CHUNK == 0 else T
+    out = jax.lax.map(attend, (q.reshape((T // chunk, chunk) + q.shape[1:]),
+                               pos.reshape(T // chunk, chunk)))
+    ctx.out(op, 'Out', out.reshape((1, T) + out.shape[2:]))

@@ -6,7 +6,11 @@ to the Program path (OLMoE: models/transformer.py, LMConfig(norm=
   dimensions from ``begin_norm_axis``, computed in float32.
 - ``rotary_embedding``: rotate every head of ``X [..., H, dh]`` by the
   angle ``Positions * theta^(-2i/dh)``, ``rotate_half`` convention (the
-  two halves of a head are dims ``[0, dh/2)`` and ``[dh/2, dh)``).
+  two halves of a head are dims ``[0, dh/2)`` and ``[dh/2, dh)``), or with
+  ``interleave`` the pairs ``(2i, 2i + 1)``, each rotated where it lies
+  (DeepSeek-V3's ``rope_interleave``; HF moves the pairs apart first and
+  then rotates halves: q and k are permuted alike, the scores are the
+  same).
   Positions are the ones the decode and prefill programs already feed
   (``gen_pos``), one per leading row of ``X``, so a prefix-shared suffix
   rotates by its GLOBAL positions.
@@ -26,6 +30,22 @@ to the Program path (OLMoE: models/transformer.py, LMConfig(norm=
   prefill of T rows computes ``T * top_k`` expert rows, not ``T * E``
   (read on the chip and in the compiled program's FLOPs: PERF.md, PR 28).
   On the CPU it lowers to masked dense matmuls — the tests' toy widths.
+
+  The DeepSeek-V3 router (``score='sigmoid'``): ``s = sigmoid(x @
+  RouterW)``, the top_k largest of ``s + SelectBias`` (the bias chooses
+  only), weights ``s_e`` of the chosen, divided by ``sum + 1e-20`` with
+  ``norm_topk_prob``, times ``routed_scale``.
+
+  A SHARE of the experts (``experts_held = (first, count)``, the chip's
+  share under expert parallelism): the router still scores ALL
+  ``n_experts`` and every row still chooses ``top_k`` of them; GateW, UpW
+  and DownW hold the ``count`` experts from ``first`` on, and ``Out`` is
+  the part of the sum these give. An assignment to an expert held
+  elsewhere sorts behind every group of the grouped matmul, which never
+  visits it: it is neither computed nor read, and nothing stands in for
+  the chip that holds it. ``ExpertLoad`` then has ``count + 1`` entries,
+  the last the assignments that went elsewhere. With every expert held
+  the op is bit for bit what it was.
 
   ``ExpertLoad [E]`` counts the rows routed to each expert. Rows that
   are not a request's are left out of the COUNT (they are still
@@ -54,8 +74,10 @@ def _rms_norm(ctx, op):
     ctx.out(op, 'Out', y.astype(x.dtype))
 
 
-def rotate(x, positions, theta):
-    """`x [..., H, dh]` rotated by `positions` (one per leading row)."""
+def rotate(x, positions, theta, interleave=False):
+    """`x [..., H, dh]` rotated by `positions` (one per leading row): the
+    pair of dims (i, i + dh/2), or with `interleave` (2i, 2i + 1), by the
+    angle `position * theta^(-2i/dh)`."""
     dh = x.shape[-1]
     half = dh // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dh)
@@ -64,6 +86,10 @@ def rotate(x, positions, theta):
     cos = jnp.cos(angle)[..., None, :]
     sin = jnp.sin(angle)[..., None, :]
     xf = x.astype(jnp.float32)
+    if interleave:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     x1, x2 = xf[..., :half], xf[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
@@ -75,33 +101,75 @@ def _rotary_embedding(ctx, op):
     pos = ctx.in1(op, 'Positions')              # one per leading row
     if x.shape[-1] % 2:
         raise ValueError('rotary_embedding: odd head size %d' % x.shape[-1])
-    ctx.out(op, 'Out', rotate(x, pos, float(op.attr('theta', 10000.0))))
+    ctx.out(op, 'Out', rotate(x, pos, float(op.attr('theta', 10000.0)),
+                              bool(op.attr('interleave', False))))
 
 
-def route(x, router_w, top_k, norm_topk_prob):
-    """(weights [N, k] float32, experts [N, k] int32): softmax over ALL
-    experts in float32, then the top_k largest."""
+def route(x, router_w, top_k, norm_topk_prob, score='softmax',
+          select_bias=None, routed_scale=1.0):
+    """(weights [N, k] float32, experts [N, k] int32): the scores of ALL
+    experts in float32, then the top_k largest. `score='sigmoid'`: the
+    choice is by score + `select_bias`, the weights are the scores alone,
+    normalised over the chosen with `norm_topk_prob` and scaled."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if score == 'softmax':
+        w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        if norm_topk_prob:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return w, idx.astype(jnp.int32)
+    s = jax.nn.sigmoid(logits)
+    idx = lax.top_k(s + select_bias.astype(jnp.float32)[None, :], top_k)[1]
+    w = jnp.take_along_axis(s, idx, axis=1)
     if norm_topk_prob:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return w, idx.astype(jnp.int32)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * routed_scale, idx.astype(jnp.int32)
 
 
-def grouped_ffn(x, w, idx, gate_w, up_w, down_w):
+def grouped_ffn(x, w, idx, gate_w, up_w, down_w, first=None, routed=None):
     """sum_j w[n, j] * FFN_{idx[n, j]}(x[n]) through three ragged_dots
-    over the assignments sorted by expert."""
+    over the assignments sorted by expert. `first` (not None): the
+    weights are those of experts first .. first + E - 1 of `routed`, and
+    the sum runs over the assignments to these alone."""
     n, k = idx.shape
     n_experts = gate_w.shape[0]
     flat = idx.reshape(-1)
+    if first is not None:
+        # an expert held elsewhere: behind every group, in none
+        flat = flat - first
+        flat = jnp.where((flat >= 0) & (flat < n_experts), flat, n_experts)
     order = jnp.argsort(flat)                   # stable: by expert, row
     sizes = jnp.sum(flat[:, None] == jnp.arange(n_experts)[None, :],
                     axis=0, dtype=jnp.int32)    # [E]
-    xs = x[order // k]                          # [N*k, d]
-    h = jax.nn.silu(lax.ragged_dot(xs, gate_w, sizes)) \
-        * lax.ragged_dot(xs, up_w, sizes)
-    y = lax.ragged_dot(h, down_w, sizes)        # [N*k, d], sorted
+
+    def experts(rows):
+        """The first `rows` sorted assignments through the experts:
+        [rows, d], sorted."""
+        xs = x[(order if rows == n * k else order[:rows]) // k]
+        h = jax.nn.silu(lax.ragged_dot(xs, gate_w, sizes)) \
+            * lax.ragged_dot(xs, up_w, sizes)
+        return lax.ragged_dot(h, down_w, sizes)
+
+    if first is None:
+        y = experts(n * k)
+    else:
+        # The grouped matmul's time goes with the rows it is GIVEN, in a
+        # group or not (on the v5e a tile of 128 rows in no group costs
+        # what one in a group does, PERF.md PR 32), and a share of the
+        # experts gets its share of the assignments: the matmuls take
+        # one and a half times the expected number of rows, in whole
+        # tiles, and ALL rows in the step where more are held than that
+        # (the device decides, on what it sees: dropless either way).
+        held = jnp.sum(sizes)
+        cap = -(-3 * n * k * n_experts // (2 * routed * 128)) * 128
+
+        def some():
+            return jnp.pad(experts(cap), ((0, n * k - cap), (0, 0)))
+        y = experts(n * k) if cap >= n * k \
+            else lax.cond(held <= cap, some, lambda: experts(n * k))
+        # what the grouped matmul leaves in the rows of no group is not a
+        # result: drop it before the weights see it
+        y = jnp.where((jnp.arange(n * k) < held)[:, None], y, 0.0)
     y = y[jnp.argsort(order)].reshape(n, k, -1)
     return jnp.einsum('nk,nkd->nd', w.astype(y.dtype), y)
 
@@ -116,17 +184,29 @@ def _moe_ffn(ctx, op):
     length = ctx.in1(op, 'Length')              # optional: real rows
     valid = ctx.in1(op, 'Valid')                # optional [N]/[N, 1]
     top_k = int(op.attr('top_k'))
-    w, idx = route(x, router_w, top_k, bool(op.attr('norm_topk_prob',
-                                                    False)))
-    out = grouped_ffn(x, w, idx, gate_w, up_w, down_w)
+    w, idx = route(x, router_w, top_k,
+                   bool(op.attr('norm_topk_prob', False)),
+                   op.attr('score', 'softmax'), ctx.in1(op, 'SelectBias'),
+                   float(op.attr('routed_scale', 1.0)))
+    held = gate_w.shape[0]
+    # the experts held here: all of them, or `held` from `first` on
+    first = None if held == router_w.shape[1] \
+        else int(op.attr('first_expert', 0))
+    out = grouped_ffn(x, w, idx, gate_w, up_w, down_w, first,
+                      router_w.shape[1])
     counted = jnp.ones((x.shape[0],), bool)
     if length is not None:
         counted &= jnp.arange(x.shape[0]) < \
             length.reshape(-1)[0].astype(jnp.int32)
     if valid is not None:
         counted &= valid.reshape(-1) != 0
-    hit = (idx[:, :, None] == jnp.arange(gate_w.shape[0])[None, None, :]) \
+    local = idx if first is None else idx - first
+    hit = (local[:, :, None] == jnp.arange(held)[None, None, :]) \
         & counted[:, None, None]
     ctx.out(op, 'Out', out.astype(x.dtype))
     ctx.out(op, 'TopkIdx', idx)
-    ctx.out(op, 'ExpertLoad', jnp.sum(hit, axis=(0, 1), dtype=jnp.int32))
+    load = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
+    if first is not None:
+        elsewhere = top_k * jnp.sum(counted, dtype=jnp.int32) - jnp.sum(load)
+        load = jnp.concatenate([load, elsewhere[None]])
+    ctx.out(op, 'ExpertLoad', load)

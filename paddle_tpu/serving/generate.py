@@ -123,9 +123,9 @@ from .. import trace as trace_mod
 from .. import unique_name
 from ..executor import Executor, Scope, scope_guard
 from ..framework import Program, TPUPlace, program_guard
-from ..models.transformer import (KV_CACHE_K, KV_CACHE_V, LMConfig,
+from ..models.transformer import (KV_CACHE_K, LMConfig,
                                   build_lm_decode_step,
-                                  build_lm_prefill_paged)
+                                  build_lm_prefill_paged, kv_cache_names)
 from ..reader.bucketing import bucketize
 from .kv_blocks import BlockAllocator, PrefixCache, chain_hashes
 from .batcher import (DeadlineExceededError, EngineStoppedError,
@@ -663,13 +663,18 @@ class GenerateEngine(object):
         rows — four scalar adds, no label (docs/observability.md)."""
         flat = np.asarray(out).reshape(-1)
         if flat.size > n_tokens:
-            load = flat[n_tokens:].reshape(self.config.model.n_layer, -1)
+            cfg = self.config.model
+            load = flat[n_tokens:].reshape(cfg.n_moe_layers, -1)
+            # the experts held here; a layer that holds a share only
+            # counts the assignments to the others in one last column
+            held = load[:, :cfg.experts_held[1]]
             monitor.inc('moe_layer_steps_total', load.shape[0])
             monitor.inc('moe_assignments_total', int(load.sum()))
+            monitor.inc('moe_held_assignments_total', int(held.sum()))
             monitor.inc('moe_experts_touched_total',
-                        int(np.count_nonzero(load)))
+                        int(np.count_nonzero(held)))
             monitor.inc('moe_max_expert_rows_total',
-                        int(load.max(axis=1).sum()))
+                        int(held.max(axis=1).sum()))
         return flat[:n_tokens]
 
     def _init_state(self):
@@ -686,7 +691,7 @@ class GenerateEngine(object):
                 # immutable — zero-copy); the caches are NOT copied,
                 # _ensure_cache gives the draft scope its own pool
                 for name in self.scope.names():
-                    if name not in (KV_CACHE_K, KV_CACHE_V):
+                    if name not in kv_cache_names(cfg):
                         self._draft_scope.set(name, self.scope.get(name))
             else:
                 with scope_guard(self._draft_scope):
@@ -709,18 +714,16 @@ class GenerateEngine(object):
         shape = (c.num_blocks, cfg.n_layer, c.block_size, cfg.kv_width)
         have = self.scope.get(KV_CACHE_K)
         if have is None or tuple(have.shape) != shape:
-            self.scope.set(KV_CACHE_K, jnp.zeros(shape, 'float32'))
-            self.scope.set(KV_CACHE_V, jnp.zeros(shape, 'float32'))
+            for name in kv_cache_names(cfg):
+                self.scope.set(name, jnp.zeros(shape, 'float32'))
         if c.speculative:
             dcfg = self._draft_cfg
             dshape = (self._draft_nb, dcfg.n_layer, c.block_size,
                       dcfg.kv_width)
             dhave = self._draft_scope.get(KV_CACHE_K)
             if dhave is None or tuple(dhave.shape) != dshape:
-                self._draft_scope.set(KV_CACHE_K,
-                                      jnp.zeros(dshape, 'float32'))
-                self._draft_scope.set(KV_CACHE_V,
-                                      jnp.zeros(dshape, 'float32'))
+                for name in kv_cache_names(dcfg):
+                    self._draft_scope.set(name, jnp.zeros(dshape, 'float32'))
 
     # ------------------------------------------------------------------
     # feed + block helpers
@@ -745,7 +748,7 @@ class GenerateEngine(object):
             self._cow_jit = jax.jit(_copy)
         s = np.asarray(src, 'int32')
         d = np.asarray(dst, 'int32')
-        for name in (KV_CACHE_K, KV_CACHE_V):
+        for name in kv_cache_names(self.config.model):
             self.scope.set(name, self._cow_jit(
                 self.executor._state_value(self.scope, name,
                                            self._step_prog, cache=False),
@@ -767,7 +770,7 @@ class GenerateEngine(object):
         s_ids = np.zeros((self._max_blocks,), 'int32')
         d_ids[:len(dblocks)] = dblocks
         s_ids[:len(blocks)] = blocks
-        for name in (KV_CACHE_K, KV_CACHE_V):
+        for name in kv_cache_names(self.config.model):
             dst = self.executor._state_value(
                 self._draft_scope, name, self._drafter_prog, cache=False)
             src = self.executor._state_value(
@@ -1890,7 +1893,7 @@ class GenerateEngine(object):
             sample = self._sample_feed(S)
             btab = np.zeros((S, self._max_blocks), 'int64')
             active = []
-            live_pages = 0
+            live_pages = live_tokens = 0
             for i, st in enumerate(self._slots):
                 if st is None or i in held:
                     continue
@@ -1909,6 +1912,7 @@ class GenerateEngine(object):
                 sample['gen_u'][i] = r._draw_u()
                 btab[i] = st.table
                 live_pages += at // c.block_size + 1
+                live_tokens += at + 1
                 active.append((i, st))
             if not active:
                 return None
@@ -1924,6 +1928,10 @@ class GenerateEngine(object):
             monitor.inc('kv_decode_pages_live_total', live_pages)
             monitor.inc('kv_decode_pages_table_total',
                         len(active) * self._max_blocks)
+            if c.model.attention == 'mla':
+                # the latent rows the step's attention has to read
+                monitor.inc('kv_latent_tokens_read_total',
+                            live_tokens * c.model.n_layer)
             feed = {'gen_pos': pos, 'gen_btab': btab}
             feed.update(sample)
         with _loop_phase('dispatch'):

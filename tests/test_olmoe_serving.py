@@ -196,23 +196,12 @@ def _drive(eng, prompts, n_new, late=None):
     return [list(reqs[i].result(timeout=5)) for i in range(len(prompts))]
 
 
-@pytest.fixture(scope='module')
-def served():
-    """Five requests of different lengths through a 4-slot paged engine
-    (block 8): prompts that end inside a block, on a block's last row and
+def serve_five(eng, vocab):
+    """Five requests of different lengths through a warmed 4-slot paged
+    engine (block 8, buckets 16 and 32), every dispatch's feed and logits
+    logged: prompts that end inside a block, on a block's last row and
     past the 16 bucket; outputs that cross block boundaries; request 3
     admitted while the others decode, request 4 after a slot frees."""
-    cfg = toy_config()
-    eng = GenerateEngine(GenerateConfig(
-        model=cfg, slots=4, max_len=64, prompt_buckets=[16, 32],
-        eos_id=None, seed=3, block_size=8))
-    # the startup program's N(0, 0.02) experts add little to the residual
-    # stream at this width: four times larger each (64 times the FFN's
-    # output), a wrong choice of expert moves the logits
-    for name in eng.scope.names():
-        if '.moe.' in name and 'router' not in name:
-            eng.scope.set(name, eng.scope.get(name) * 4.0)
-    eng.warmup()
     log = []
 
     def tapped(bound_with_logits, kind):
@@ -241,13 +230,30 @@ def served():
         fetch_list=[eng._step_vars['tokens_and_load'],
                     eng._step_vars['logits']]), 'step')
     rng = np.random.RandomState(7)
-    prompts = [rng.randint(2, 97, size=n).astype('int64')
+    prompts = [rng.randint(2, vocab, size=n).astype('int64')
                for n in (5, 16, 23, 8, 11)]
     n_new = [14, 9, 20, 12, 6]
     before = monitor.counters()
     tokens = _drive(eng, prompts, n_new, late=3)
     return dict(eng=eng, log=log, prompts=prompts, n_new=n_new,
                 tokens=tokens, moved=monitor.counter_delta(before))
+
+
+@pytest.fixture(scope='module')
+def served():
+    """`serve_five` on the toy OLMoE block."""
+    cfg = toy_config()
+    eng = GenerateEngine(GenerateConfig(
+        model=cfg, slots=4, max_len=64, prompt_buckets=[16, 32],
+        eos_id=None, seed=3, block_size=8))
+    # the startup program's N(0, 0.02) experts add little to the residual
+    # stream at this width: four times larger each (64 times the FFN's
+    # output), a wrong choice of expert moves the logits
+    for name in eng.scope.names():
+        if '.moe.' in name and 'router' not in name:
+            eng.scope.set(name, eng.scope.get(name) * 4.0)
+    eng.warmup()
+    return serve_five(eng, 97)
 
 
 # Largest difference of a logit, relative to its row's (max - mean). Both
@@ -406,6 +412,61 @@ def test_todays_configuration_builds_the_parent_commits_program(program):
     }[program]
     got = json.loads(json.dumps(_program_listing(build)))
     assert got == want
+
+
+LISTED = {
+    'fairseq-dense': dict(vocab_size=97, seq_len=32, d_model=32, n_head=4,
+                          n_layer=2, d_ff=64, dropout=0.0),
+    'olmoe': dict(vocab_size=97, seq_len=32, d_model=64, n_head=4,
+                  n_layer=2, d_ff=32, dropout=0.0, norm='rms_norm',
+                  position='rope', head_dim=16, qk_norm=True, bias=False,
+                  ffn='moe', n_experts=8, experts_per_token=2,
+                  expert_width=32),
+}
+
+
+def _plain(value):
+    try:
+        json.dumps(value)
+    except TypeError:
+        return repr(type(value))
+    return value
+
+
+@pytest.mark.parametrize('program', ['decode_step', 'prefill_paged'])
+@pytest.mark.parametrize('config', sorted(LISTED))
+def test_the_benchmarks_configurations_build_the_pr31_commits_programs(
+        config, program):
+    """The same, with every op's ATTRIBUTES too and for the OLMoE block as
+    well, against a listing recorded from commit 44db736 (PR 31), the
+    parent of the PR that brought latent attention, the gated FFN and the
+    held share of the experts: the blocks the benchmark already had build
+    the programs they built."""
+    with open(os.path.join(HERE, 'fixtures',
+                           'lm_programs_parent_pr31.json')) as f:
+        want = json.load(f)[config][program]
+    cfg = LMConfig(**LISTED[config])
+    build = {
+        'decode_step': lambda: T.build_lm_decode_step(
+            cfg, 4, 32, block_size=8, num_blocks=9),
+        'prefill_paged': lambda: T.build_lm_prefill_paged(cfg, 16, 9, 8, 4),
+    }[program]
+    main, start = Program(), Program()
+    with program_guard(main, start):
+        with unique_name.guard():
+            build()
+    block = main.global_block()
+    got = {
+        'ops': [[op.type,
+                 {k: list(v) for k, v in sorted(op.inputs.items())},
+                 {k: list(v) for k, v in sorted(op.outputs.items())},
+                 {k: _plain(v) for k, v in sorted(op.attrs.items())}]
+                for op in block.ops],
+        'params': [[p.name, list(p.shape)] for p in block.all_parameters()],
+        'startup': [[op.type, sorted(n for vs in op.outputs.values()
+                                     for n in vs)]
+                    for op in start.global_block().ops]}
+    assert json.loads(json.dumps(got)) == want
 
 
 def test_an_engine_without_experts_fetches_the_tokens_alone():

@@ -238,3 +238,38 @@ def test_mosaic_accepts_the_kernel_at_the_cells_shapes(one_chip, S, H, MB,
     # row-major pool: a page is one contiguous run
     assert 'f32[%d,%d,%d,%d]{3,2,1,0:T(8,128)}' % (NB, ln, bs, H * dh) in text
     assert c.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_mosaic_accepts_the_latent_kernel_at_its_cells_shapes(one_chip):
+    """ops/mla_paged_decode_attention.py at joyai-serve-longchat64's size:
+    64 slots x 32 absorbed queries of 640 lanes (576 numbers in whole
+    tiles) against ONE pool of latent rows, the values its first 512
+    lanes. A pool declared 576 wide is stored 640 wide by the TPU anyway,
+    and Mosaic refuses a page of it (a slice of the minor dimension has to
+    be whole 128-lane tiles): LMConfig.kv_width says 640."""
+    import jax
+    from paddle_tpu.ops import mla_paged_decode_attention as mla
+    S, H, W, V, MB, NB, ln, bs, layer = 64, 32, 640, 512, 176, 8192, 7, 16, 3
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(pool, q, new, tables, pos):
+        blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
+        pool = pool.at[blk, layer, pos % bs, :].set(new)
+        return pool, mla.mla_paged_decode_attention(
+            q, pool, tables, pos, layer, scale=192 ** -0.5, v_width=V)
+
+    assert mla.shapes_ok(H, W, V, bs)
+    c = jax.jit(step, donate_argnums=0).lower(
+        sds((NB, ln, bs, W)), sds((S, H, W)), sds((S, W)),
+        sds((S, MB), jnp.int32), sds((S,), jnp.int32)).compile()
+    text = c.as_text()
+    assert 'tpu_custom_call' in text
+    assert 'mla_paged_decode_attention' in text
+    assert 'f32[%d,%d,%d,%d]{3,2,1,0:T(8,128)}' % (NB, ln, bs, W) in text
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 20
+    with pytest.raises(Exception, match='aligned to tiling'):
+        jax.jit(step, donate_argnums=0).lower(
+            sds((NB, ln, bs, 576)), sds((S, H, 576)), sds((S, 576)),
+            sds((S, MB), jnp.int32), sds((S,), jnp.int32)).compile()
