@@ -275,35 +275,16 @@ def _lookup_table(ctx, op):
         from .dist_ops import table_sharding_constraint
         w = table_sharding_constraint(w)
 
-    from . import kernel_tier
-    from .embedding_ops import pallas_shapes_ok, spmd_gather_ok
-    from ..parallel.api import get_active_mesh, get_active_param_spec
-    mesh = get_active_mesh()
-    if mesh is not None and mesh.size > 1:
-        # mesh-native: the kernel runs per shard (ids over 'data') via
-        # kernel_tier.partitioned_call inside embedding_gather. A SHARDED
-        # table — the is_distributed vocab pin above or a param rule —
-        # keeps the XLA gather the SPMD partitioner splits into
-        # shard-local masked gathers + psum (the dist_ops pipeline).
-        spec_fn = get_active_param_spec()
-        w_spec = spec_fn(op.input('W')[0]) if spec_fn else None
-        ok = not op.attr('is_distributed', False) and \
-            spmd_gather_ok(mesh, w, int(flat.shape[0]), w_spec)
-    else:
-        ok = pallas_shapes_ok(w, int(flat.shape[0]))
-    impl = kernel_tier.dispatch(
-        'lookup_table', pallas_ok=ok,
-        xla_ok=False,   # no distinct xla tier: the gather IS one HLO
-        mesh=mesh,
-        count=getattr(ctx, 'sparse_mode', None) != 'scout')
-    out = lookup_gather(ctx, op, w, flat, impl=impl)
+    from .embedding_ops import count_dispatch
+    count_dispatch(ctx, 'lookup_table')
+    out = lookup_gather(ctx, op, w, flat)
     ctx.out(op, 'Out', embedding_epilogue(out, flat, ids, w, padding_idx))
 
 
-def lookup_gather(ctx, op, w, flat, bias=None, impl='off'):
+def lookup_gather(ctx, op, w, flat, bias=None):
     """Shared lookup_table / fused_embedding_gather gather body: routes
     the is_sparse scout/apply mechanism (core/lowering.py sparse grads)
-    around whichever gather impl the kernel tier picked."""
+    around the gather."""
     from .embedding_ops import embedding_gather
     w_name = op.input('W')[0]
     sparse = w_name in getattr(ctx, 'sparse_tables', ())
@@ -313,18 +294,15 @@ def lookup_gather(ctx, op, w, flat, bias=None, impl='off'):
     if sparse and mode == 'apply':
         k = ctx.sparse_counter[0]
         ctx.sparse_counter[0] += 1
-        # bias adds OUTSIDE the differentiable=False kernel: the table is
-        # stop_gradient'd but a trainable Bias is not, and jax cannot
-        # transpose through a raw pallas_call — the add after the gather
-        # keeps the bias on plain-jnp AD while the dummy carries the
-        # table's sparse grad
-        out = embedding_gather(lax.stop_gradient(w), flat,
-                               impl=impl, differentiable=False) \
+        # the table is stop_gradient'd and the dummy carries its sparse
+        # grad; a trainable Bias is not held out, so it adds after the
+        # dummy, on plain-jnp AD
+        out = embedding_gather(lax.stop_gradient(w), flat) \
             + ctx.env['@sparse%d' % k]
         if bias is not None:
             out = out + bias.reshape(1, -1)
     else:
-        out = embedding_gather(w, flat, bias=bias, impl=impl)
+        out = embedding_gather(w, flat, bias=bias)
     return out
 
 

@@ -470,6 +470,20 @@ class _Flight(object):
         return self.out[0].is_ready()
 
 
+def block_copy_fn(backend):
+    """The jitted copy of one block of a cache pool onto another
+    (copy-on-write; warmup compiles it). The pool is DONATED wherever the
+    backend donates: without that the call holds a second pool for as
+    long as it runs — in warmup() it was every serve cell's memory peak
+    (2.35 GB over a 12.76 GB state in the largest, PERF.md PR 33). The
+    CPU ignores donation with a warning, so none is asked of it."""
+    import jax
+
+    def _copy(cache, s, d):
+        return cache.at[d].set(cache[s])
+    return jax.jit(_copy, donate_argnums=() if backend == 'cpu' else (0,))
+
+
 class GenerateEngine(object):
     """In-process continuous-batching decode engine. ::
 
@@ -741,11 +755,7 @@ class GenerateEngine(object):
         traced scalars), donation aliases the pool in place."""
         import jax
         if self._cow_jit is None:
-            def _copy(cache, s, d):
-                return cache.at[d].set(cache[s])
-            # no donate: CPU ignores it with a warning, and COW is rare
-            # enough that a transient copy of the pool is acceptable
-            self._cow_jit = jax.jit(_copy)
+            self._cow_jit = block_copy_fn(jax.default_backend())
         s = np.asarray(src, 'int32')
         d = np.asarray(dst, 'int32')
         for name in kv_cache_names(self.config.model):

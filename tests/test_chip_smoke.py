@@ -30,11 +30,13 @@ def _toy():
     cfg.shared_prefix = 8
     cfg.shared_tails = [2, 6]
     cfg.platform = 'cpu'
-    interp = {op: 'interpret' for op in cfg.train_tiers}
+    def interp(tiers):            # the lookup is `off` at every tier
+        return {op: 'off' if op == 'lookup_table' else 'interpret'
+                for op in tiers}
     # AMP stands the FFN kernel down in training; the f32 serving
     # programs run it (these panels fit its VMEM predicate)
-    cfg.train_tiers = dict(interp, fused_ffn_tail='xla')
-    cfg.serve_tiers = {op: 'interpret' for op in cfg.serve_tiers}
+    cfg.train_tiers = dict(interp(cfg.train_tiers), fused_ffn_tail='xla')
+    cfg.serve_tiers = interp(cfg.serve_tiers)
     cfg.mosaic_kernels = {}       # no Mosaic in interpret mode
     return cfg
 

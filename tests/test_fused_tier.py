@@ -78,32 +78,84 @@ class TestFusedCrossEntropy(object):
 
 
 class TestFusedEmbeddingGather(object):
+    """The lookup has ONE lowering since PR 33 (XLA's gather of the table
+    as it is stored, ops/embedding_ops.py): what the Pallas unit's cases
+    held — the rows, the bias, the scatter-add gradient, the sparse table
+    beside a trainable bias, the program-level op — is held against it,
+    at every tier name."""
+
     def test_gather_bias_grad_bitwise(self):
         from paddle_tpu.ops.embedding_ops import embedding_gather
         rng = np.random.RandomState(1)
-        w = jnp.asarray(rng.randn(64, 128).astype('float32'))
-        ids = jnp.asarray(rng.randint(0, 64, 37).astype('int32'))
-        bias = jnp.asarray(rng.randn(128).astype('float32'))
+        w = rng.randn(64, 128).astype('float32')
+        ids = rng.randint(0, 64, 37).astype('int32')
+        ids[5] = ids[9]                                  # a row twice
+        bias = rng.randn(128).astype('float32')
+        got = embedding_gather(jnp.asarray(w), jnp.asarray(ids),
+                               jnp.asarray(bias))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.take(w, ids, axis=0) + bias)
+        np.testing.assert_array_equal(
+            np.asarray(embedding_gather(jnp.asarray(w), jnp.asarray(ids))),
+            np.take(w, ids, axis=0))
+        # d/dw of sum(c * rows) is c scatter-ADDED at the ids, d/dbias
+        # its column sums
+        c = rng.randn(37, 128).astype('float32')
+        gw, gb = jax.grad(
+            lambda wv, bv: jnp.sum(embedding_gather(wv, jnp.asarray(ids), bv)
+                                   * c), argnums=(0, 1))(
+            jnp.asarray(w), jnp.asarray(bias))
+        want = np.zeros_like(w)
+        np.add.at(want, ids, c)
+        np.testing.assert_allclose(np.asarray(gw), want, rtol=1e-6,
+                                   atol=1e-6)
+        assert np.abs(np.asarray(gw)[np.setdiff1d(np.arange(64), ids)]
+                      ).max() == 0.0
+        np.testing.assert_allclose(np.asarray(gb), c.sum(0), rtol=1e-5,
+                                   atol=1e-5)
 
-        def loss(impl):
-            return lambda wv, bv: jnp.sum(
-                embedding_gather(wv, ids, bv, impl=impl) ** 2)
+    def test_out_of_range_ids_read_the_nearest_row(self):
+        """What the TPU's gather does and the Pallas unit did: no NaN
+        fill, no wrap-around; the gradient lands on the row read."""
+        from paddle_tpu.ops.embedding_ops import embedding_gather
+        w = jnp.arange(16 * 128, dtype=jnp.float32).reshape(16, 128)
+        ids = jnp.asarray([-3, 0, 15, 16, 10 ** 6], jnp.int32)
+        got = np.asarray(embedding_gather(w, ids))
+        np.testing.assert_array_equal(
+            got, np.asarray(w)[[0, 0, 15, 15, 15]])
+        gw = jax.grad(lambda wv: jnp.sum(embedding_gather(wv, ids)))(w)
+        np.testing.assert_array_equal(
+            np.asarray(gw)[:, 0], [2.0] + [0.0] * 14 + [3.0])
 
-        ref = embedding_gather(w, ids, bias, impl='off')
-        got = embedding_gather(w, ids, bias, impl='interpret')
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-        gw_r, gb_r = jax.grad(loss('off'), argnums=(0, 1))(w, bias)
-        gw_g, gb_g = jax.grad(loss('interpret'), argnums=(0, 1))(w, bias)
-        np.testing.assert_array_equal(np.asarray(gw_g), np.asarray(gw_r))
-        np.testing.assert_array_equal(np.asarray(gb_g), np.asarray(gb_r))
+    @pytest.mark.parametrize('tier', ['off', 'xla', 'interpret'])
+    def test_lookup_table_is_one_lowering_at_every_tier(self, tier_env,
+                                                        tier):
+        """padding_idx rows zero, a trailing-1 ids shape folds, and the
+        dispatch counter reads the surviving implementation (`off`: the
+        unfused gather) whatever tier is asked for."""
+        from test_detection_ops import _run_single_op
+        rng = np.random.RandomState(3)
+        w = rng.randn(32, 128).astype('float32')
+        ids = rng.randint(0, 32, (3, 7, 1)).astype('int64')
+        ids[0, :3, 0] = 4
+        tier_env(tier)
+        before = monitor.counters()
+        out, = _run_single_op('lookup_table', {'W': w, 'Ids': ids},
+                              {'Out': ['lt_out']}, {'padding_idx': 4})
+        want = w[ids[..., 0]] * (ids != 4)
+        np.testing.assert_array_equal(out, want)
+        assert out.shape == (3, 7, 128)
+        d = monitor.counter_delta(before)
+        assert {k: v for k, v in d.items() if 'op=lookup_table' in k} == {
+            'fused_kernel_dispatch_total{impl=off,mesh=1,op=lookup_table}':
+            1}, d
 
     def test_sparse_table_with_trainable_bias_trains(self, tier_env):
         """fused_embedding_gather on an is_sparse table WITH a trainable
-        Bias under the interpret tier: the table grad rides the sparse
-        scout/dummy path while the bias adds OUTSIDE the (non-
-        differentiable) kernel — the backward must trace (review finding:
-        jax cannot transpose through a raw pallas_call) and both the
-        table rows and the bias must move."""
+        Bias (under the interpret tier, as when a kernel stood here): the
+        table grad rides the sparse scout/dummy path while the bias adds
+        after the stop_gradient'd gather, on plain AD — both the table
+        rows and the bias must move."""
         tier_env('interpret')
         main, startup = fluid.Program(), fluid.Program()
         main.random_seed = startup.random_seed = 9

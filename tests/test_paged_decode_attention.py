@@ -273,3 +273,99 @@ def test_mosaic_accepts_the_latent_kernel_at_its_cells_shapes(one_chip):
         jax.jit(step, donate_argnums=0).lower(
             sds((NB, ln, bs, 576)), sds((S, H, 576)), sds((S, 576)),
             sds((S, MB), jnp.int32), sds((S,), jnp.int32)).compile()
+
+
+def _compile_serving_program(build, fetch, rows, one_chip):
+    """A serving program (`build()` -> its vars) lowered by
+    core.lowering.build_fn and compiled for the described chip at `rows`
+    rows a feed, the cache pools donated: shapes in, nothing executed."""
+    import jax
+    from paddle_tpu import unique_name
+    from paddle_tpu.core.lowering import build_fn
+    from paddle_tpu.framework import Program, program_guard
+    from paddle_tpu.models.transformer import KV_CACHE_K, KV_CACHE_V
+    main = Program()
+    with program_guard(main, Program()), unique_name.guard():
+        v = build()
+    block = main.global_block()
+
+    def sds(var):
+        dt = jnp.dtype(str(var.dtype))
+        return jax.ShapeDtypeStruct(
+            tuple(rows if s < 0 else s for s in var.shape),
+            jnp.int32 if dt == jnp.int64 else dt, sharding=one_chip)
+    state = [x.name for x in block.vars.values() if x.persistable]
+    fn, ro, rw = build_fn(main, [v[fetch].name], state,
+                          [KV_CACHE_K, KV_CACHE_V])
+    written = {n for op in block.ops for ns in op.outputs.values()
+               for n in ns}
+    feeds = {n: x for n, x in block.vars.items()
+             if n.startswith('gen_') and not x.persistable
+             and n not in written}
+    return jax.jit(fn, donate_argnums=2).lower(
+        {n: sds(x) for n, x in feeds.items()},
+        {n: sds(block.var(n)) for n in ro},
+        {n: sds(block.var(n)) for n in rw},
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)).compile()
+
+
+@pytest.mark.parametrize('program', ['decode_step', 'prefill_b64'])
+def test_no_serving_program_re_lays_the_embedding_table(one_chip,
+                                                        monkeypatch,
+                                                        program):
+    """The lookup reads its rows from `tok_emb.w` [V, D] as it is stored.
+    Until PR 33 a Pallas gather took the table as `w.reshape(V, 1, D)`,
+    which on the TPU is a copy of the whole table ((8, 128) tiles to
+    (1, 128) tiles) in EVERY dispatch: 134 MB of temporaries here, 1.06 GB
+    in the largest cell. No instruction but a parameter may have the
+    table's element count (shapes [D, V] are the untied head's, inside
+    its matmul's fusion), and the program's temporaries stay far under the
+    table's bytes."""
+    import re
+    from paddle_tpu.models import transformer as T
+    monkeypatch.setenv('PADDLE_FUSED_TIER', 'pallas')
+    V, D, slots, bs, max_len, NB = 32768, 1024, 8, 16, 128, 64
+    cfg = LMConfig(vocab_size=V, seq_len=max_len, d_model=D, n_head=16,
+                   n_layer=2, d_ff=2048, dropout=0.0, attn_dropout=0.0)
+    before = monitor.counters()
+    if program == 'decode_step':
+        c = _compile_serving_program(
+            lambda: T.build_lm_decode_step(cfg, slots, max_len,
+                                           block_size=bs, num_blocks=NB),
+            'next_tokens', slots, one_chip)
+    else:
+        c = _compile_serving_program(
+            lambda: T.build_lm_prefill_paged(cfg, 64, NB, bs,
+                                             max_len // bs),
+            'first_token', 1, one_chip)
+    # the lookup counts the one lowering it has, whatever the tier
+    assert monitor.counter_delta(before)[
+        'fused_kernel_dispatch_total{impl=off,mesh=1,op=lookup_table}'] == 1
+    text = c.as_text()
+    assert 'f32[%d,%d]{1,0:T(8,128)}' % (V, D) in text       # in place
+    table_sized = []
+    for m in re.finditer(r'^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* '
+                         r'([\w\-]+)\(', text, re.M):
+        dims = [int(d) for d in m.group(1).split(',')]
+        if int(np.prod(dims)) == V * D and dims != [D, V] \
+                and m.group(2) != 'parameter':
+            table_sized.append(m.group(0).strip())
+    assert not table_sized, table_sized
+    assert c.memory_analysis().temp_size_in_bytes < V * D * 4 // 4
+
+
+def test_the_block_copy_takes_the_pool_in_place(one_chip):
+    """serving/generate.py `block_copy_fn`: the copy-on-write block copy
+    that warmup() compiles donates the pool on the TPU. Undonated it held
+    a second pool while it ran, and that was every serve cell's memory
+    peak (JoyAI: 15.20 GB over a 12.76 GB state; PERF.md, PR 33)."""
+    import jax
+    from paddle_tpu.serving.generate import block_copy_fn
+    pool = jax.ShapeDtypeStruct((1024, 6, 16, 2048), jnp.float32,
+                                sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ma = block_copy_fn('tpu').lower(pool, idx, idx).compile() \
+        .memory_analysis()
+    nbytes = 1024 * 6 * 16 * 2048 * 4
+    assert ma.alias_size_in_bytes == nbytes
+    assert ma.temp_size_in_bytes < nbytes // 4

@@ -1,9 +1,9 @@
 """Per-op fused-vs-unfused microbench for the kernel tier.
 
-For each fused unit (softmax_ce / fused_adam / embedding_gather /
-layernorm_residual / ffn_tail / ln_sites — the last two are the PR 16
-FFN-tail epilogue and the block-entry/final-LN residual-threading
-sites) this builds a small program that isolates the op,
+For each fused unit (softmax_ce / fused_adam / layernorm_residual /
+ffn_tail / ln_sites — the last two are the PR 16 FFN-tail epilogue and
+the block-entry/final-LN residual-threading sites) this builds a small
+program that isolates the op,
 compiles it under each requested PADDLE_FUSED_TIER, and reports
 steady-state wall time (best-of-rounds minima over k dispatches — the
 box-noise protocol from BASELINE notes) next to the XLA cost-analysis
@@ -17,6 +17,12 @@ fused-vs-unfused numbers exist for the sharded case too (the
 ``fused_kernel_dispatch_total{...,mesh=n}`` counter rows prove which
 impl actually ran). Needs >= N local devices; as a CLI this file forces
 an 8-device virtual CPU host when no accelerator is attached.
+
+The `embedding_gather` case is not a tier comparison: the lookup has
+one lowering (XLA's gather, ops/embedding_ops.py). It times that gather
+and the in-place DMA kernel it was chosen over (`gather_in_place`, kept
+here only) alone, at the benchmark cells' tables and row counts with
+`--size bench`: ms a call and GB/s, a candidate a column.
 
 Usage: python tools/kernbench.py [--tiers off,xla,interpret]
        [--cases softmax_ce,fused_adam,embedding_gather,
@@ -76,18 +82,127 @@ def _build_fused_adam(size):
     return main, startup, feed, loss
 
 
-def _build_embedding_gather(size):
+def gather_in_place(w, flat_ids, interpret=False, ring=16):
+    """The lookup's OTHER candidate (PERF.md, PR 33), kept here to be
+    measured against XLA's gather, which `ops/embedding_ops.py` runs: a
+    Pallas kernel that leaves the table an HBM ref and fetches each row
+    by its own DMA, `ring` in flight. Mosaic refuses a one-row slice of
+    an (8, 128)-tiled ref ("Slice shape along dimension 0 must be
+    aligned to tiling (8)"), so a DMA takes the row's whole 8-row tile
+    group into VMEM and the kernel copies the one row out: 8 times the
+    bytes. V % 8 == 0, D % 128 == 0, float32."""
+    import jax
+    import jax.numpy as jnp
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    n, d = flat_ids.shape[0], w.shape[1]
+    rows = 8 if n % 8 == 0 else n           # an output block
+    ring = min(ring, n)
+
+    def kernel(ids_ref, w_hbm, o_ref, buf, sems):
+        g = pl.program_id(0)
+
+        def fetch(i):
+            group = pl.multiple_of(ids_ref[i] // 8 * 8, 8)
+            return pltpu.make_async_copy(
+                w_hbm.at[pl.ds(group, 8), :], buf.at[i % ring],
+                sems.at[i % ring])
+
+        @pl.when(g == 0)
+        def _():
+            for i in range(ring):
+                fetch(i).start()
+
+        for j in range(rows):
+            i = g * rows + j
+            fetch(i).wait()
+            o_ref[pl.ds(j, 1), :] = buf[i % ring,
+                                        pl.ds(ids_ref[i] % 8, 1), :]
+
+            @pl.when(i + ring < n)
+            def _():
+                fetch(i + ring).start()
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n // rows,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((rows, d), lambda g, ids: (g, 0)),
+            scratch_shapes=[pltpu.VMEM((ring, 8, d), w.dtype),
+                            pltpu.SemaphoreType.DMA((ring,))]),
+        out_shape=jax.ShapeDtypeStruct((n, d), w.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name='embedding_gather_in_place',
+    )(jnp.clip(flat_ids.astype(jnp.int32), 0, w.shape[0] - 1), w)
+
+
+# the cells' `tok_emb.w` (float32) and the rows a dispatch looks up: a
+# decode step's 4 / 16 / 32 / 64 slots, a prefill bucket, a train step
+GATHER_TABLES = {
+    'small': {'toy': (1024, 128)},
+    'bench': {'fd355m chat, train-2k': (50264, 1024),
+              'fd1.3b doc, train-4chip': (50264, 2048),
+              'olmoe': (50304, 2048),
+              'joyai': (129280, 2048)}}
+GATHER_ROWS = {'small': (4, 64), 'bench': (4, 32, 64, 2048, 8192)}
+
+
+def gather_candidates():
+    import functools
+    import jax
+    from paddle_tpu.ops.embedding_ops import embedding_gather
+    return {'xla_gather': embedding_gather,
+            'dma_in_place': functools.partial(
+                gather_in_place,
+                interpret=jax.default_backend() != 'tpu')}
+
+
+def measure_embedding_gather(size, rounds, k, candidates=None):
+    """The lookup alone, each candidate at each of the cells' tables and
+    row counts: ms a call and GB/s of rows moved (read + written), best
+    of `rounds` runs of ONE program that makes `k` dependent calls (ids
+    shifted by the loop index, and by a bit of the call before, so
+    neither the host's dispatch nor a hoisted gather is what is timed).
+    Every candidate's rows are checked against numpy.take."""
     import numpy as np
-    import paddle_tpu as fluid
-    v, d, n = (1024, 128, 512) if size == 'small' else (100000, 256, 8192)
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        ids = fluid.layers.data(name='ei', shape=[1], dtype='int64')
-        emb = fluid.layers.embedding(ids, size=[v, d])
-        out = fluid.layers.reduce_sum(emb)
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    candidates = candidates or gather_candidates()
+    out = {}
     rng = np.random.RandomState(0)
-    feed = {'ei': rng.randint(0, v, (n, 1)).astype('int64')}
-    return main, startup, feed, out
+    for label, (v, d) in GATHER_TABLES[size].items():
+        w = jax.random.normal(jax.random.PRNGKey(0), (v, d), jnp.float32)
+        for n in GATHER_ROWS[size]:
+            ids = jnp.asarray(rng.randint(0, v, n).astype('int32'))
+            want = np.take(np.asarray(w), np.asarray(ids), axis=0)
+            row = out.setdefault('%s [%d, %d]' % (label, v, d), {}) \
+                .setdefault('rows=%d' % n, {})
+            for name, fn in candidates.items():
+                try:
+                    def calls(w, ids, fn=fn):
+                        def body(i, acc):
+                            moved = (acc[0, 0] != acc[0, 0]).astype(ids.dtype)
+                            return fn(w, (ids + i + moved) % v)
+                        return lax.fori_loop(0, k, body, fn(w, ids))
+                    np.testing.assert_array_equal(
+                        np.asarray(jax.jit(fn)(w, ids)), want)
+                    loop = jax.jit(calls)
+                    loop(w, ids).block_until_ready()
+                    best = float('inf')
+                    for _ in range(rounds):
+                        t0 = time.perf_counter()
+                        loop(w, ids).block_until_ready()
+                        best = min(best, (time.perf_counter() - t0) / (k + 1))
+                    row[name] = {'ms': round(best * 1e3, 4),
+                                 'gb_per_s': round(2 * n * d * 4 / best / 1e9,
+                                                   2)}
+                except Exception as e:      # noqa: BLE001 — advisory tool
+                    row[name] = {'error': '%s: %s' % (
+                        type(e).__name__, str(e)[:200])}
+    return out
 
 
 def _build_layernorm_residual(size):
@@ -157,11 +272,12 @@ def _build_ln_sites(size):
 _CASES = {
     'softmax_ce': _build_softmax_ce,
     'fused_adam': _build_fused_adam,
-    'embedding_gather': _build_embedding_gather,
     'layernorm_residual': _build_layernorm_residual,
     'ffn_tail': _build_ffn_tail,
     'ln_sites': _build_ln_sites,
 }
+# every case by name: the tier comparisons and the lookup's candidates
+_CASE_NAMES = list(_CASES) + ['embedding_gather']
 
 
 def _measure(build, tier, rounds, k, size, mesh_n=1):
@@ -233,10 +349,13 @@ def measure_kernbench(cases=None, tiers=None, rounds=5, k=10,
     ``mesh=N`` runs every case through a mesh(data=N) MeshRunner so the
     partitioned fused kernels are what gets timed)."""
     from paddle_tpu import monitor
-    cases = list(cases or _CASES)
+    cases = list(cases or _CASE_NAMES)
     tiers = list(tiers or ['off', 'xla', 'interpret'])
     out = {}
     for case in cases:
+        if case == 'embedding_gather':      # candidates, not tiers
+            out[case] = measure_embedding_gather(size, rounds, k)
+            continue
         out[case] = {}
         for tier in tiers:
             before = monitor.counters()
@@ -263,7 +382,7 @@ def measure_kernbench(cases=None, tiers=None, rounds=5, k=10,
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument('--cases', default=','.join(_CASES))
+    ap.add_argument('--cases', default=','.join(_CASE_NAMES))
     ap.add_argument('--tiers', default='off,xla,interpret')
     ap.add_argument('--rounds', type=int, default=5)
     ap.add_argument('--k', type=int, default=10)
