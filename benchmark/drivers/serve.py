@@ -107,16 +107,21 @@ def run(ctx):
                                    name='bench-sampler', daemon=True)
         sampler.start()
 
-    before = monitor.counters()
+    # The loop books a step's histogram observation, then its counters:
+    # with the histograms read first here and last at the close, every
+    # step the counters' interval holds is in the histograms' too, so a
+    # count of steps over their number cannot pass 1 by the snapshots'
+    # order (one booking split by the opening snapshots still can).
     h0 = {n: _hist(monitor, n)
           for n in ('decode_step_seconds', 'prefill_seconds')}
+    before = monitor.counters()
     s0 = eng.stats()
     t0 = ctx.open_window()
     time.sleep(ctx.seconds)
     t1 = t0 + ctx.seconds
     s1 = eng.stats()
-    h1 = {n: _hist(monitor, n) for n in h0}
     delta = monitor.counter_delta(before)
+    h1 = {n: _hist(monitor, n) for n in h0}
     load.stop()
     if ctx.trace:
         stop_sampler.set()

@@ -5,13 +5,16 @@ never left the device: generate_overlapped_steps_total / the count of
 decode_step_seconds, both as they moved over the window, in percent. A
 closed loop that keeps its slots resident should read near 100; every
 idle spell and every failed step starts the pipeline anew with one step
-that has no predecessor. The serve driver snapshots the counters a moment
-before the histograms when the window opens and after them when it
-closes, so a step or two more can stand in the numerator: a short window
-may read 100.1. A counter that did not move is not among
-facts['counters']; that the program counts at all is read from the
-engine's stats ('overlapped_steps'). A program from before the pipeline
-reads nothing. Moves serve_tokens_per_s."""
+that has no predecessor. The loop books a step's observation, then its
+counter, and the serve driver reads the histograms outside the counters
+at both ends of the window, so the counter's steps are among the
+histogram's but for ONE whose two bookings the opening snapshots split:
+that one is cut here. A count that passes the steps by more is no skew
+of snapshots but a fault in the counting, and is returned as it reads.
+A counter that did not move is not among facts['counters']; that the
+program counts at all is read from the engine's stats
+('overlapped_steps'). A program from before the pipeline reads nothing.
+Moves serve_tokens_per_s."""
 
 
 def read(facts):
@@ -19,5 +22,8 @@ def read(facts):
                                                     (0, 0))
     if not steps or 'overlapped_steps' not in facts.get('engine_stats', {}):
         return None
-    return 100.0 * facts.get('counters', {}).get(
-        'generate_overlapped_steps_total', 0) / steps
+    overlapped = facts.get('counters', {}).get(
+        'generate_overlapped_steps_total', 0)
+    if overlapped == steps + 1:
+        overlapped = steps
+    return 100.0 * overlapped / steps

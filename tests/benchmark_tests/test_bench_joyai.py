@@ -1,7 +1,7 @@
 """The JoyAI-LLM-Flash configuration's benchmark files (ISSUE 32): a toy cell
 with the new builder through run.py end to end on the CPU (its own toy
-manifest, which lists the three new per-layer metrics: BENCHMARK.json
-cannot yet, PERF.md section 7), the manifest's entry and the published
+manifest; BENCHMARK.json lists the three per-layer metrics it brought
+since PR 34), the manifest's entry and the published
 file against the catalog's row, flops_joyai's formulae against a count of
 param_shapes, the three new readers on made-up facts, and the comparison
 script's main() at toy width."""
@@ -15,7 +15,7 @@ from benchmark import flops_joyai
 from benchmark.models import joyai
 
 from test_bench_olmoe import _last_json, _load, run_on_cpu   # noqa: F401
-from test_bench_run import MANIFEST, check_config_entry
+from test_bench_run import MANIFEST, by_name, check_config_entry
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -72,19 +72,21 @@ def test_traced_line(run_on_cpu, capsys):                  # noqa: F811
 
 # ---- the manifest and the published file 
 
-def test_config_entry_admits_the_new_entry():
-    conf, = [c for c in MANIFEST['configs']
-             if c['name'] == 'joyai-llm-flash-ep4']
-    assert MANIFEST['configs'][-1] is conf            # appended, not put in
-    check_config_entry(conf, MANIFEST)
+def check_joyai_entry(manifest):
+    """The configuration, its cell and the metrics that list the cell,
+    each found by name: where they stand in their lists is
+    test_bench_manifest.py's to hold (appended, never put in)."""
+    conf = by_name(manifest['configs'], 'joyai-llm-flash-ep4')
+    check_config_entry(conf, manifest)
     assert conf['reduced'] == ['num_hidden_layers', 'n_routed_experts']
-    cell = MANIFEST['workloads'][-1]
+    cell = by_name(manifest['workloads'], CELL)
     assert cell == dict(cell, name=CELL, config=conf['name'],
                         traffic='longchat64-closed', chips=1)
     # listed under every serve metric whose reader asks nothing of the
-    # configuration, and under no metric that reads OLMoE's key names
-    listed = {x['name'] for x in MANIFEST['end_to_end']
-              + MANIFEST['per_layer'] if CELL in x.get('workloads', ())}
+    # configuration and under the three that read its own keys and
+    # counters, and under no metric that reads OLMoE's key names
+    listed = {x['name'] for x in manifest['end_to_end']
+              + manifest['per_layer'] if CELL in x.get('workloads', ())}
     assert listed == {
         'serve_tokens_per_s', 'itl_p95_ms', 'decode_step_ms',
         'decode_hbm_share', 'decode_host_gap_ms', 'decode_host_gap_ms.admit',
@@ -92,9 +94,13 @@ def test_config_entry_admits_the_new_entry():
         'decode_host_gap_ms.deliver', 'server_loop_unaccounted_share',
         'device_idle_share.serve', 'peak_hbm_gb.serve',
         'ttft_p95_unbounded_ms', 'ttft_mean_unbounded_ms',
-        'decode_sampled_step_share'}
-    assert all(x['workloads'][-1] == CELL for x in MANIFEST['end_to_end']
-               + MANIFEST['per_layer'] if CELL in x.get('workloads', ()))
+        'decode_sampled_step_share', 'decode_overlapped_step_share',
+        'mla_decode_attention_hbm_share', 'moe_held_assignment_share',
+        'moe_held_ffn_hbm_share'}
+
+
+def test_config_entry_admits_the_new_entry():
+    check_joyai_entry(MANIFEST)
 
 
 def test_the_published_file_keeps_every_number_of_the_catalogs_row():

@@ -1,6 +1,7 @@
 """The OLMoE configuration's benchmark files (ISSUE 28): a toy OLMoE cell
 and a toy open-loop cell through run.py end to end on the CPU (their own
-toy manifest; the platform check is overridden HERE), flops_moe's formulae
+toy manifest; test_bench_run.py's fixture overrides the platform check),
+flops_moe's formulae
 against a count of param_shapes, the readers of the three new per-layer
 metrics on made-up facts, the comparison script's main() at toy width, and
 the sizing of the cell against the device-less v5e."""
@@ -14,6 +15,8 @@ import pytest
 from benchmark import flops_moe
 from benchmark.models import olmoe
 
+from test_bench_run import _last_json, run_on_cpu          # noqa: F401
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 TOY_MANIFEST = os.path.join(HERE, 'fixtures', 'BENCHMARK.toy.olmoe.json')
@@ -26,27 +29,6 @@ def _load(path, name):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
-
-
-@pytest.fixture()
-def run_on_cpu(monkeypatch):
-    import jax
-    from benchmark import flops, reduce_trace
-    run = _load(os.path.join(ROOT, 'benchmark', 'run.py'),
-                'bench_run_under_test_olmoe')
-    monkeypatch.setattr(
-        run, 'require_devices',
-        lambda chips: (jax.devices(), flops.peaks_for('TPU v5 lite')))
-    monkeypatch.setattr(
-        reduce_trace, 'is_ops_line',
-        lambda plane, line: plane == '/host:CPU'
-        and line.startswith('tf_XLAPjRtCpuClient'))
-    return run
-
-
-def _last_json(capsys):
-    lines = [ln for ln in capsys.readouterr().out.splitlines() if ln.strip()]
-    return json.loads(lines[-1]), lines
 
 
 E2E = {'serve_tokens_per_s', 'itl_p95_ms', 'setup_s'}

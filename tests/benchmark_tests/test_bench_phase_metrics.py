@@ -3,14 +3,13 @@
 `facts`, its value and nothing where there is nothing to divide by or to
 read, as on a program from before the phases; and run.py's traced line at
 toy width on the CPU printing every one of them."""
-import json
 import os
 
 import pytest
 
 from benchmark import phase_counters
-from test_bench_run import (ROOT, _last_json, _load_run,  # noqa: F401
-                            run_on_cpu)
+from test_bench_run import (MANIFEST, ROOT, _last_json,  # noqa: F401
+                            _load_run, run_on_cpu)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TOY_MANIFEST = os.path.join(HERE, 'fixtures', 'BENCHMARK.toy.phases.json')
@@ -102,15 +101,17 @@ def test_phase_seconds_sums_the_phases_asked_for():
     assert phase_counters.per_ms(0.5, 0) is None
 
 
-def test_every_phase_metric_of_the_manifest_has_a_case():
-    with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
-        manifest = json.load(f)
+def check_phase_metrics(manifest):
     assert {m['name'] for m in manifest['per_layer']} >= set(CASES)
     for m in manifest['per_layer']:
         if m['name'] in CASES:
             assert m['source'] == 'program_counter'
             assert m['layer'] == ('trainer API' if CASES[m['name']][0]
                                   is TRAIN else 'server')
+
+
+def test_every_phase_metric_of_the_manifest_has_a_case():
+    check_phase_metrics(MANIFEST)
 
 
 @pytest.mark.parametrize('workload,metrics', [

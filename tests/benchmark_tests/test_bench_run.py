@@ -27,13 +27,27 @@ def _load_run():
     return mod
 
 
+def trace_into(monkeypatch, run, tmp_path):
+    """A traced run of this test writes its trace under the test's own
+    directory: several test files trace the same toy cell, the driver runs
+    the files in parallel, and run.py's `.bench_trace/<workload>` would be
+    one directory for all of them, removed by whichever ends first."""
+    init = run.Context.__init__
+
+    def __init__(self, args, *rest):
+        init(self, args, *rest)
+        self.trace_dir = str(tmp_path / 'bench_trace' / args.workload)
+    monkeypatch.setattr(run.Context, '__init__', __init__)
+
+
 @pytest.fixture()
-def run_on_cpu(monkeypatch):
+def run_on_cpu(monkeypatch, tmp_path):
     """run.py with its platform check and its choice of trace line
     overridden for the CPU backend — in the test, not in the program."""
     import jax
     from benchmark import flops, reduce_trace
     run = _load_run()
+    trace_into(monkeypatch, run, tmp_path)
     monkeypatch.setattr(
         run, 'require_devices',
         lambda chips: (jax.devices(), flops.peaks_for('TPU v5 lite')))
@@ -76,7 +90,7 @@ def test_end_to_end_line(run_on_cpu, capsys, workload, metrics):
                    'pallas_time_share', 'device_idle_share.train'}),
     ('toy-serve', {'decode_step_ms', 'prefill_ms.ttft', 'decode_hbm_share',
                    'device_idle_share.serve'})])
-def test_traced_line(run_on_cpu, capsys, workload, metrics):
+def test_traced_line(run_on_cpu, capsys, tmp_path, workload, metrics):
     rc = run_on_cpu.main(['--workload', workload, '--seed', '7',
                           '--seconds', '0.7', '--trace', '1'],
                          manifest_path=TOY_MANIFEST)
@@ -91,7 +105,7 @@ def test_traced_line(run_on_cpu, capsys, workload, metrics):
     assert set(out['breakdown']) == {'device_ops', 'idle_gaps'}
     assert 0 < len(out['breakdown']['device_ops']) <= 10
     assert len(out['breakdown']['idle_gaps']) <= 10
-    assert not os.path.exists(os.path.join(ROOT, '.bench_trace', workload))
+    assert not (tmp_path / 'bench_trace' / workload).exists()   # removed
 
 
 # ---- train_mesh: four forced host devices -----------------------------
@@ -197,51 +211,81 @@ def test_an_unknown_workload_is_refused(capsys):
 
 # ---- the manifest -------------------------------------------------------
 
+# Every check of the manifest takes the manifest as an argument and finds
+# its entries by `name` (`by_name`), never by position: a later PR appends
+# configurations, cells and metrics, and test_bench_manifest.py runs all
+# of these checks on a copy with such entries appended.
+
 with open(os.path.join(ROOT, 'BENCHMARK.json')) as _f:
     MANIFEST = json.load(_f)
 METRICS = MANIFEST['end_to_end'] + MANIFEST['per_layer']
 
 
-def test_manifest_keys_and_limits():
-    assert set(MANIFEST) == {'command', 'paths', 'run_seconds', 'configs',
+def by_name(entries, name):
+    """The one entry of a manifest list that has this `name`."""
+    entry, = [x for x in entries if x['name'] == name]
+    return entry
+
+
+def check_manifest_keys_and_limits(manifest):
+    assert set(manifest) == {'command', 'paths', 'run_seconds', 'configs',
                              'workloads', 'end_to_end', 'per_layer'}
-    assert 1 <= MANIFEST['run_seconds'] <= 51
+    assert 1 <= manifest['run_seconds'] <= 51
     assert all(PATH.match(p) and os.path.isdir(os.path.join(ROOT, p))
-               for p in MANIFEST['paths'])
-    assert os.path.getsize(os.path.join(ROOT, 'BENCHMARK.json')) < 64 * 1024
-    four = [w for w in MANIFEST['workloads'] if w['chips'] == 4]
-    assert len(four) <= max(1, len(MANIFEST['workloads']) // 4)
-    names = [x['name'] for x in METRICS]
+               for p in manifest['paths'])
+    assert len(json.dumps(manifest)) < 64 * 1024
+    assert 1 <= len(manifest['configs']) <= 24
+    assert 1 <= len(manifest['workloads']) <= 24
+    assert 1 <= len(manifest['end_to_end']) <= 16
+    assert 1 <= len(manifest['per_layer']) <= 128
+    four = [w for w in manifest['workloads'] if w['chips'] == 4]
+    assert len(four) <= max(1, len(manifest['workloads']) // 4)
+    for section in ('configs', 'workloads'):
+        names = [x['name'] for x in manifest[section]]
+        assert len(names) == len(set(names))
+    pairs = [(w['config'], w['traffic']) for w in manifest['workloads']]
+    assert len(pairs) == len(set(pairs))
+    names = [x['name'] for x in manifest['end_to_end']
+             + manifest['per_layer']]
     assert len(names) == len(set(names))
-    assert 'setup_s' in [x['name'] for x in MANIFEST['end_to_end']]
-    for x in MANIFEST['end_to_end']:
+    assert 'setup_s' in [x['name'] for x in manifest['end_to_end']]
+    for x in manifest['end_to_end']:
         assert set(x) - {'workloads'} == {'name', 'unit', 'better', 'bound',
                                           'source'}
         assert 0.01 <= x['bound'] <= 0.1
         assert x['source'] in ('host_clock', 'device_trace')
 
 
-@pytest.mark.parametrize('cell', MANIFEST['workloads'],
-                         ids=lambda c: c['name'])
-def test_cell_resolves_to_files(cell):
+def test_manifest_keys_and_limits():
+    check_manifest_keys_and_limits(MANIFEST)
+    assert os.path.getsize(os.path.join(ROOT, 'BENCHMARK.json')) < 64 * 1024
+
+
+def check_cell_resolves_to_files(cell, manifest):
     run = _load_run()
     assert set(cell) == {'name', 'config', 'traffic', 'chips', 'why'}
     assert NAME.match(cell['name']) and NAME.match(cell['traffic'])
     assert cell['chips'] in (1, 4) and 1 <= len(cell['why']) <= 200
-    _cell, config, traffic = run.load_cell(MANIFEST, cell['name'])
-    assert os.path.isfile(run.find_file(MANIFEST, 'drivers',
+    _cell, config, traffic = run.load_cell(manifest, cell['name'])
+    assert os.path.isfile(run.find_file(manifest, 'drivers',
                                         traffic['kind'] + '.py'))
-    assert os.path.isfile(run.find_file(MANIFEST, 'models',
+    assert os.path.isfile(run.find_file(manifest, 'models',
                                         config['builder'] + '.py'))
-    e2e = run.metrics_of(MANIFEST, 'end_to_end', cell['name'])
+    e2e = run.metrics_of(manifest, 'end_to_end', cell['name'])
     assert {'setup_s'} < {x['name'] for x in e2e}
-    layer = run.metrics_of(MANIFEST, 'per_layer', cell['name'])
+    layer = run.metrics_of(manifest, 'per_layer', cell['name'])
     assert layer
     for x in layer:
         assert x['moves'] in {y['name'] for y in e2e}
-        reader = run.load_module(run.find_file(MANIFEST, 'layer_metrics',
+        reader = run.load_module(run.find_file(manifest, 'layer_metrics',
                                                x['name'] + '.py'))
         assert callable(reader.read)
+
+
+@pytest.mark.parametrize('cell', MANIFEST['workloads'],
+                         ids=lambda c: c['name'])
+def test_cell_resolves_to_files(cell):
+    check_cell_resolves_to_files(cell, MANIFEST)
 
 
 def check_config_entry(conf, manifest):
@@ -333,17 +377,22 @@ def test_config_entry_refuses(tmp_path, conf_changes, file_changes):
         check_config_entry(conf, manifest)
 
 
-@pytest.mark.parametrize('metric', METRICS, ids=lambda m: m['name'])
-def test_metric_names_and_units(metric):
+def check_metric_names_and_units(metric, manifest):
     assert NAME.match(metric['name']) and UNIT.match(metric['unit'])
     assert metric['better'] in ('lower', 'higher')
     assert metric['source'] in SOURCES
-    cells = {w['name'] for w in MANIFEST['workloads']}
-    assert set(metric.get('workloads', cells)) <= cells
+    cells = [w['name'] for w in manifest['workloads']]
+    listed = metric.get('workloads', cells)
+    assert set(listed) <= set(cells) and len(listed) == len(set(listed))
     if 'layer' in metric:
         assert set(metric) - {'workloads'} == {'name', 'unit', 'better',
                                                'source', 'layer', 'moves'}
         assert '\n' not in metric['layer'] and len(metric['layer']) <= 200
+
+
+@pytest.mark.parametrize('metric', METRICS, ids=lambda m: m['name'])
+def test_metric_names_and_units(metric):
+    check_metric_names_and_units(metric, MANIFEST)
 
 
 def test_size_train_mesh_compiles_the_toy_step_for_the_v5e_without_a_chip():

@@ -4,13 +4,12 @@ share, and nothing where there is no decode step or where the program
 does not count (the parent of the PR that brought the counter); the
 manifest lists it for the serve cells; and run.py's traced line at toy
 width on the CPU prints it as 0, the toy traffic being greedy."""
-import json
 import os
 
 import pytest
 
-from test_bench_run import (ROOT, _last_json, _load_run,  # noqa: F401
-                            run_on_cpu)
+from test_bench_run import (MANIFEST, ROOT, _last_json,  # noqa: F401
+                            _load_run, by_name, run_on_cpu)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TOY_MANIFEST = os.path.join(HERE, 'fixtures', 'BENCHMARK.toy.sampled.json')
@@ -50,16 +49,18 @@ def test_reader(facts, value):
     assert value is None or isinstance(got, float)
 
 
-def test_manifest_entry():
-    with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
-        manifest = json.load(f)
-    entry = manifest['per_layer'][-1]
-    serve = [m for m in manifest['end_to_end']
-             if m['name'] == 'itl_p95_ms'][0]['workloads']
-    assert entry == {
+def check_manifest_entry(manifest):
+    """The whole entry, found by name, listed for every cell that reports
+    the metric it moves."""
+    serve = by_name(manifest['end_to_end'], 'itl_p95_ms')['workloads']
+    assert by_name(manifest['per_layer'], NAME) == {
         'name': NAME, 'unit': '%', 'better': 'lower',
         'source': 'program_counter', 'layer': 'model step',
         'moves': 'itl_p95_ms', 'workloads': serve}
+
+
+def test_manifest_entry():
+    check_manifest_entry(MANIFEST)
 
 
 def test_traced_line_reads_zero_on_greedy_traffic(run_on_cpu,  # noqa: F811

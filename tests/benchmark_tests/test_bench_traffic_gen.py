@@ -176,7 +176,9 @@ def test_closed_loop_and_the_window():
     load.join(10)
     w = tg.window_stats(load.records, t0, t1)
     assert w['attempted'] > 5 and w['failed'] == 0
-    assert abs(w['tokens'] - 3 * w['attempted']) <= 6   # edges of the window
+    # a request astride an edge of the window is not `attempted`, its tokens
+    # inside count: 2 clients x 2 edges x at most 3 tokens
+    assert abs(w['tokens'] - 3 * w['attempted']) <= 12
     assert len(w['itl_s']) >= 2 * w['attempted']
     assert len(w['ttft_s']) >= w['attempted']
     assert tg.percentile([1, 2, 3, 4], 50) == 2
