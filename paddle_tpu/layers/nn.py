@@ -16,7 +16,7 @@ __all__ = [
     'conv2d_transpose', 'pool2d', 'pool3d', 'batch_norm', 'layer_norm',
     'fused_layer_norm_residual', 'fused_ffn_tail', 'rms_norm',
     'rotary_embedding', 'moe_ffn', 'mla_decode_attention',
-    'mla_prefix_attention',
+    'mla_prefix_attention', 'short_conv_decode', 'short_conv_prefill',
     'group_norm', 'data_norm', 'l2_normalize', 'matmul', 'mul', 'topk',
     'reshape', 'squeeze', 'unsqueeze', 'flatten', 'transpose', 'split',
     'reduce_sum', 'reduce_mean', 'reduce_max', 'reduce_min', 'reduce_prod',
@@ -619,7 +619,7 @@ def moe_ffn(input, n_experts, expert_width, top_k, norm_topk_prob=False,
             length=None, valid=None, router_param_attr=None,
             gate_param_attr=None, up_param_attr=None, down_param_attr=None,
             score='softmax', select_bias_attr=None, routed_scale=1.0,
-            experts_held=None, name=None):
+            experts_held=None, router_eps=None, name=None):
     """Dropless top-k mixture-of-experts FFN over the rows of ``input
     [N, d]`` (ops/moe_ops.py): a float32 softmax router over all
     ``n_experts``, the ``top_k`` largest (renormalised only with
@@ -631,7 +631,9 @@ def moe_ffn(input, n_experts, expert_width, top_k, norm_topk_prob=False,
 
     ``score='sigmoid'``: sigmoid scores, chosen by score +
     ``select_bias_attr``'s parameter, weighted by the score and
-    ``routed_scale``. ``experts_held = (first, count)``: the layer
+    ``routed_scale``; with ``norm_topk_prob`` the chosen scores are
+    divided by their sum + ``router_eps`` (None: the op's 1e-20).
+    ``experts_held = (first, count)``: the layer
     holds that share of the ``n_experts`` the router scores, ``out`` is
     the part of the sum these experts give and ``expert_load`` is
     ``[count + 1]``, the last entry the assignments that went
@@ -674,6 +676,8 @@ def moe_ffn(input, n_experts, expert_width, top_k, norm_topk_prob=False,
             dtype=dtype, default_initializer=Normal(0.0, 0.01))]
     if share:
         attrs['first_expert'] = int(first)
+    if router_eps is not None:
+        attrs['router_eps'] = float(router_eps)
     helper.append_op(type='moe_ffn', inputs=inputs,
                      outputs={'Out': [out], 'TopkIdx': [idx],
                               'ExpertLoad': [load]}, attrs=attrs)
@@ -725,6 +729,48 @@ def mla_prefix_attention(q, cache, positions, block_table, layer, scale,
     return _mla_attention('mla_prefix_attention', 'BlockTable', q, cache,
                           positions, block_table, layer, scale, kv_rank,
                           rope_dim, v_dim, up_k_attr, up_v_attr)
+
+
+def _short_conv(op_type, table_slot, g, cache, positions, tables, layer,
+                block_size, kernel, param_attr, length=None):
+    helper = LayerHelper(op_type)
+    w = helper.create_parameter(attr=param_attr or ParamAttr(),
+                                shape=[g.shape[-1], int(kernel)],
+                                dtype=g.dtype,
+                                default_initializer=Normal(0.0, 0.3))
+    out = helper.create_variable_for_type_inference(g.dtype, shape=g.shape)
+    inputs = {'X': [g], 'Weight': [w], 'Cache': [cache],
+              'Positions': [positions], table_slot: [tables]}
+    if length is not None:
+        inputs['Length'] = [length]
+    helper.append_op(type=op_type, inputs=inputs,
+                     outputs={'Out': [out], 'CacheOut': [cache]},
+                     attrs={'layer': int(layer),
+                            'block_size': int(block_size)})
+    return out
+
+
+def short_conv_decode(g, cache, positions, block_tables, layer, block_size,
+                      kernel=3, param_attr=None):
+    """The causal depthwise convolution (``kernel`` taps, weight ``[d,
+    kernel]``, no bias) of every slot's one new row ``g [S, d]`` behind the
+    ``kernel - 1`` rows its block table's pool entry holds, and that
+    entry's update (ops/short_conv_ops.py). ``cache`` is the tails' pool,
+    read and written in place. Returns ``[S, d]``."""
+    return _short_conv('short_conv_decode_paged', 'BlockTables', g, cache,
+                       positions, block_tables, layer, block_size, kernel,
+                       param_attr)
+
+
+def short_conv_prefill(g, cache, positions, block_table, length, layer,
+                       block_size, kernel=3, param_attr=None):
+    """`short_conv_decode` for one prompt suffix ``g [1, T, d]`` that
+    starts at ``positions[0]``: resumes behind the entry of the block
+    before it and writes the entry of every block its ``length`` real rows
+    touch (ops/short_conv_ops.py). Returns ``[1, T, d]``."""
+    return _short_conv('short_conv_prefill_paged', 'BlockTable', g, cache,
+                       positions, block_table, layer, block_size, kernel,
+                       param_attr, length=length)
 
 
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,

@@ -36,7 +36,24 @@ class LMConfig(object):
       rotated;
     - ``head_dim``: a head size other than d_model / n_head;
     - ``qk_norm``: the block's norm over the WHOLE projected q and k
-      (before the split into heads, OLMoE's way);
+      (before the split into heads, OLMoE's way); ``qk_norm='head'``: over
+      each head's ``head_dim`` numbers alone, one weight ``[head_dim]``
+      for q and one for k shared by the heads (LFM2's way);
+    - ``n_kv_head``: fewer K/V heads than query heads (grouped-query
+      attention): query head ``h`` reads K/V head ``h // (n_head //
+      n_kv_head)``, and the pools hold ``n_kv_head`` heads;
+    - ``layer_types``: one of ``'attention'`` | ``'conv'`` a layer. A
+      ``'conv'`` layer's mixer is LFM2's gated short convolution
+      (``[B | C | u] = z W_in``; ``y = (C * conv(B * u)) W_out``, a
+      causal depthwise convolution of ``conv_kernel`` taps, no bias); it
+      caches no key and no value but the last ``conv_kernel - 1`` rows
+      of ``B * u``, in a pool of its own under the K/V pools' block ids
+      (ops/short_conv_ops.py). The K/V pools hold the attention layers
+      only;
+    - ``tie_embeddings``: the head contracts against ``tok_emb.w`` where
+      it lies; there is no ``lm_head.w``;
+    - ``router_eps``: what the sigmoid router adds to the sum it
+      divides the chosen weights by;
     - ``bias=False``: no bias on any projection;
     - ``ffn='moe'``: a dropless top-``experts_per_token``-of-``n_experts``
       FFN of SiLU-gated experts of width ``expert_width``
@@ -73,7 +90,8 @@ class LMConfig(object):
                  n_dense_layers=0,
                  attention='mha', q_lora_rank=0, kv_lora_rank=0,
                  qk_nope_dim=0, qk_rope_dim=0, v_head_dim=0,
-                 rope_interleave=False):
+                 rope_interleave=False, layer_types=None, conv_kernel=3,
+                 n_kv_head=None, tie_embeddings=False, router_eps=1e-20):
         self.vocab_size = vocab_size
         self.seq_len = seq_len
         self.d_model = d_model
@@ -93,7 +111,8 @@ class LMConfig(object):
                 ('position', position, ('sinusoid', 'rope')),
                 ('ffn', ffn, ('gelu', 'moe')),
                 ('moe_score', moe_score, ('softmax', 'sigmoid')),
-                ('attention', attention, ('mha', 'mla'))):
+                ('attention', attention, ('mha', 'mla')),
+                ('qk_norm', qk_norm, (False, True, 'head'))):
             if value not in known:
                 raise ValueError('LMConfig.%s=%r: expected one of %r'
                                  % (field, value, known))
@@ -125,6 +144,23 @@ class LMConfig(object):
         self.qk_rope_dim = qk_rope_dim
         self.v_head_dim = v_head_dim
         self.rope_interleave = rope_interleave
+        self.n_kv_head = n_kv_head or n_head
+        self.layer_types = tuple(layer_types or ('attention',) * n_layer)
+        self.conv_kernel = conv_kernel
+        self.tie_embeddings = tie_embeddings
+        self.router_eps = router_eps
+        if len(self.layer_types) != n_layer or \
+                set(self.layer_types) - {'attention', 'conv'}:
+            raise ValueError("LMConfig.layer_types=%r: expected %d of "
+                             "'attention' | 'conv'"
+                             % (self.layer_types, n_layer))
+        if n_head % self.n_kv_head:
+            raise ValueError('LMConfig.n_kv_head=%r does not divide '
+                             'n_head=%r' % (self.n_kv_head, n_head))
+        if attention == 'mla' and (self.n_kv_head != n_head
+                                   or self.n_conv_layers):
+            raise ValueError("LMConfig.attention='mla' is built with "
+                             "neither n_kv_head nor 'conv' layer_types")
         if attention == 'mla' and not (
                 position == 'rope' and q_lora_rank and kv_lora_rank
                 and qk_nope_dim and qk_rope_dim and v_head_dim):
@@ -143,7 +179,20 @@ class LMConfig(object):
         (ops/mla_paged_decode_attention.py)."""
         if self.attention == 'mla':
             return -(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128
-        return self.n_head * self.head_dim
+        return self.n_kv_head * self.head_dim
+
+    @property
+    def n_conv_layers(self):
+        return self.layer_types.count('conv')
+
+    @property
+    def n_attn_layers(self):
+        return self.n_layer - self.n_conv_layers
+
+    def layer_ordinal(self, layer):
+        """`layer`'s place among the layers of its kind: the `layer`
+        attribute of its cache ops (a pool holds one kind only)."""
+        return self.layer_types[:layer].count(self.layer_types[layer])
 
     @property
     def attn_width(self):
@@ -165,7 +214,11 @@ _CLASSIC_BLOCK = (('norm', 'layer_norm'), ('position', 'sinusoid'),
 def _require_classic_block(cfg, who):
     """`who` writes the classic block out by hand: refuse a configuration
     it cannot express, naming the field."""
-    classic = _CLASSIC_BLOCK + (('head_dim', cfg.d_model // cfg.n_head),)
+    classic = _CLASSIC_BLOCK + (
+        ('head_dim', cfg.d_model // cfg.n_head),
+        ('n_kv_head', cfg.n_head),
+        ('layer_types', ('attention',) * cfg.n_layer),
+        ('tie_embeddings', False))
     for field, value in classic:
         if getattr(cfg, field) != value:
             raise ValueError(
@@ -274,16 +327,23 @@ def _bias(cfg, name):
 
 def _heads_of(cfg, flat, p, which, pos, T):
     """One of q / k / v from its flat projection ([S, H*dh] decode rows,
-    [1, T, H*dh] in a prefill): the optional whole-width q/k-norm, the
-    split into heads, the optional rotation by the fed positions; laid
+    [1, T, H*dh] in a prefill; H the K/V heads for k and v): the optional
+    q/k-norm (over the whole width before the split into heads, or over
+    each head after it), the optional rotation by the fed positions; laid
     out as the cache ops want it ([S, H, dh]; [1, H, T, dh])."""
-    h, dh = cfg.n_head, cfg.head_dim
+    h = cfg.n_head if which == 'q' else cfg.n_kv_head
+    dh = cfg.head_dim
     rows = T is None
-    if cfg.qk_norm and which != 'v':
+    normed = cfg.qk_norm and which != 'v'
+    norm_attr = ParamAttr(name='%s.attn.%s_norm.w' % (p, which))
+    if normed and cfg.qk_norm != 'head':
         flat = layers.rms_norm(
             flat, begin_norm_axis=1 if rows else 2, epsilon=cfg.rms_eps,
-            param_attr=ParamAttr(name='%s.attn.%s_norm.w' % (p, which)))
+            param_attr=norm_attr)
     x = layers.reshape(flat, shape=[-1, h, dh] if rows else [0, T, h, dh])
+    if normed and cfg.qk_norm == 'head':
+        x = layers.rms_norm(x, begin_norm_axis=2 if rows else 3,
+                            epsilon=cfg.rms_eps, param_attr=norm_attr)
     if cfg.position == 'rope' and which != 'v':
         x = layers.rotary_embedding(x, pos, theta=cfg.rope_theta)
     return x if rows else layers.transpose(x, perm=[0, 2, 1, 3])
@@ -294,15 +354,17 @@ def _qkv(cfg, ln1, p, pos, T=None):
     then each prepared for the cache ops. ``T`` None: decode rows
     ``[S, d]`` -> three ``[S, H, dh]``; else one prompt ``[1, T, d]`` ->
     three ``[1, H, T, dh]``. K comes back as it is CACHED: after k-norm
-    and rotation."""
+    and rotation, on its ``n_kv_head`` heads (as V is)."""
     if cfg.attention == 'mla':
         return _mla_qkv(cfg, ln1, p, pos, T)
     h, dh = cfg.n_head, cfg.head_dim
-    qkv = layers.fc(ln1, size=3 * h * dh,
+    ends = [w * dh for w in (h, h + cfg.n_kv_head, h + 2 * cfg.n_kv_head)]
+    qkv = layers.fc(ln1, size=ends[-1],
                     num_flatten_dims=1 if T is None else 2,
                     param_attr=ParamAttr(name=p + '.attn.qkv.w'),
                     bias_attr=_bias(cfg, p + '.attn.qkv.b'))
-    if not cfg.qk_norm and cfg.position == 'sinusoid':
+    if not cfg.qk_norm and cfg.position == 'sinusoid' \
+            and cfg.n_kv_head == h:
         if T is None:
             return _qkv_split_step(qkv, cfg)
         qkv = layers.reshape(qkv, shape=[0, T, 3, h, dh])
@@ -311,11 +373,10 @@ def _qkv(cfg, ln1, p, pos, T=None):
                                             ends=[i + 1]), axes=[0])
                 for i in range(3)]
     axis = 1 if T is None else 2
-    return [_heads_of(cfg, layers.slice(qkv, axes=[axis],
-                                        starts=[i * h * dh],
-                                        ends=[(i + 1) * h * dh]),
+    return [_heads_of(cfg, layers.slice(qkv, axes=[axis], starts=[start],
+                                        ends=[end]),
                       p, which, pos, T)
-            for i, which in enumerate('qkv')]
+            for which, start, end in zip('qkv', [0] + ends, ends)]
 
 
 def _mla_qkv(cfg, ln1, p, pos, T=None):
@@ -379,6 +440,28 @@ def _mla_attend(cfg, attention, q, cache, pos, tables, layer):
         up_v_attr=ParamAttr(name=p + '.attn.kv_b_v.w'))
 
 
+def _conv_mixer(cfg, ln1, p, nth, conv, num_flatten_dims):
+    """LFM2's gated short convolution on the normed input: ``[B | C | u] =
+    z W_in``, ``y = (C * conv(B * u)) W_out``, no bias. ``conv(g,
+    weight_attr, nth)`` is the program's cache op (layers.short_conv_decode
+    / short_conv_prefill) on the ``nth`` convolution layer's tails: the
+    causal depthwise convolution of ``B * u`` behind the rows the pool
+    holds, and the pool's update."""
+    d = cfg.d_model
+
+    def proj(x, size, which):
+        return layers.fc(x, size=size, num_flatten_dims=num_flatten_dims,
+                         param_attr=ParamAttr(name='%s.conv.%s.w'
+                                              % (p, which)),
+                         bias_attr=False)
+    bcu = proj(ln1, 3 * d, 'in')
+    b, c, u = [layers.slice(bcu, axes=[num_flatten_dims], starts=[i * d],
+                            ends=[(i + 1) * d]) for i in range(3)]
+    mixed = conv(layers.elementwise_mul(b, u),
+                 ParamAttr(name=p + '.conv.w'), nth)
+    return proj(layers.elementwise_mul(c, mixed), d, 'out')
+
+
 def _gated_ffn(x, width, d_model, name, num_flatten_dims):
     """``(silu(x W_g) * (x W_u)) W_d``, no bias: a dense SiLU-gated FFN
     (and an expert that every row goes through)."""
@@ -418,6 +501,8 @@ def _ffn(cfg, ln2, p, num_flatten_dims, length=None, valid=None, layer=0):
                           name=p + '.moe.router.bias'))
     if cfg.experts_held != (0, cfg.n_experts):
         router['experts_held'] = cfg.experts_held
+    if cfg.router_eps != 1e-20:
+        router['router_eps'] = cfg.router_eps
     out, idx, load = layers.moe_ffn(
         rows, cfg.n_experts, cfg.expert_width, cfg.experts_per_token,
         norm_topk_prob=cfg.norm_topk_prob, length=length, valid=valid,
@@ -435,6 +520,10 @@ def _ffn(cfg, ln2, p, num_flatten_dims, length=None, valid=None, layer=0):
 
 
 def _lm_head(cfg, x):
+    if cfg.tie_embeddings:
+        # against the table where it lies, [V, d]: no transposed copy
+        table = default_main_program().global_block().var('tok_emb.w')
+        return layers.matmul(x, table, transpose_y=True)
     return layers.fc(x, size=cfg.vocab_size,
                      param_attr=ParamAttr(name='lm_head.w'),
                      bias_attr=False)
@@ -630,23 +719,40 @@ def build_lm(cfg=None, is_test=False):
 
 KV_CACHE_K = 'gen_kv_k'
 KV_CACHE_V = 'gen_kv_v'
+CONV_CACHE = 'gen_conv_tail'
 
 
 def kv_cache_names(cfg):
-    """The pools a model's programs declare: K and V apart, or with latent
-    attention the ONE pool of latent rows (under K's name)."""
-    return (KV_CACHE_K,) if cfg.attention == 'mla' \
+    """The pools a model's programs declare, all indexed by the same block
+    ids: K and V apart, or with latent attention the ONE pool of latent
+    rows (under K's name); with convolution layers the pool of their
+    tails as well."""
+    names = (KV_CACHE_K,) if cfg.attention == 'mla' \
         else (KV_CACHE_K, KV_CACHE_V)
+    return names + (CONV_CACHE,) if cfg.n_conv_layers else names
+
+
+def kv_cache_shapes(cfg, num_blocks, block_size):
+    """name -> shape of every pool of `kv_cache_names`. K/V: the
+    ATTENTION layers' pages, ``[num_blocks, n_attn_layers, block_size,
+    kv_width]``; the tails: a block's ``conv_kernel - 1`` rows a
+    convolution layer, ``[num_blocks, n_conv_layers, conv_kernel - 1,
+    d_model]``."""
+    kv = (num_blocks, cfg.n_attn_layers, block_size, cfg.kv_width)
+    tail = (num_blocks, cfg.n_conv_layers, cfg.conv_kernel - 1, cfg.d_model)
+    return {name: tail if name == CONV_CACHE else kv
+            for name in kv_cache_names(cfg)}
 
 
 def _declare_paged_kv_caches(block, cfg, num_blocks, block_size):
-    """(K pool, V pool) of `kv_cache_names`; None for a pool the model
-    does not have."""
-    shape = (num_blocks, cfg.n_layer, block_size, cfg.kv_width)
-    pools = [block.create_var(name=name, shape=shape, dtype='float32',
-                              persistable=True, stop_gradient=True)
-             for name in kv_cache_names(cfg)]
-    return (pools + [None])[:2]
+    """(K pool, V pool, tail pool) of `kv_cache_names`; None for a pool
+    the model does not have."""
+    pools = {name: block.create_var(name=name, shape=shape, dtype='float32',
+                                    persistable=True, stop_gradient=True)
+             for name, shape in kv_cache_shapes(cfg, num_blocks,
+                                                block_size).items()}
+    return [pools.get(name) for name in (KV_CACHE_K, KV_CACHE_V,
+                                         CONV_CACHE)]
 
 
 SAMPLE_FEEDS = ('gen_temp', 'gen_topk', 'gen_topp', 'gen_u')
@@ -687,7 +793,7 @@ def _qkv_split_step(qkv, cfg):
 
 
 def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
-                  pos=None, valid=None, routing=None):
+                  pos=None, valid=None, routing=None, conv=None):
     """One decode-position transformer tower over per-slot row state
     ``x`` ([S, d]: token embedding, + position encoding where positions
     are added). The cache write and cached attention are delegated to
@@ -703,22 +809,30 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
     None — the drafter's trailing write-only step needs every layer's
     K/V deposited but no logits. ``pos`` ([S, 1], rotary positions),
     ``valid`` ([S, 1], zero = idle slot) and ``routing`` (a list that
-    takes each layer's `_ffn` routing) serve the blocks that need them."""
+    takes each layer's `_ffn` routing) serve the blocks that need them;
+    ``conv(g, weight_attr, layer)`` is a convolution layer's cache op.
+    The cache closures get a layer's ORDINAL among the layers of its
+    kind (`LMConfig.layer_ordinal`): a pool holds one kind."""
     delta = None             # previous layer's deferred FFN output
     for i in range(cfg.n_layer):
         p = 'layer_%d' % i
+        nth = cfg.layer_ordinal(i)
         ln1, x = _norm(cfg, x, delta, 1, p + '.ln1')
-        q, k, v = _qkv(cfg, ln1, p, pos)                     # [S, H, dh]
-        cache_write(k, v, i)
-        if not head and i == cfg.n_layer - 1:
-            # write-only tower, last layer: nothing consumes x past
-            # this K/V deposit — attention/proj/ffn are dead compute
-            return None
-        ctx = attend(q, i, p + tag)
-        attn = layers.fc(layers.reshape(ctx, shape=[-1, cfg.attn_width]),
-                         size=cfg.d_model,
-                         param_attr=ParamAttr(name=p + '.attn.proj.w'),
-                         bias_attr=_bias(cfg, p + '.attn.proj.b'))
+        if cfg.layer_types[i] == 'conv':
+            attn = _conv_mixer(cfg, ln1, p, nth, conv, 1)
+        else:
+            q, k, v = _qkv(cfg, ln1, p, pos)                 # [S, H, dh]
+            cache_write(k, v, nth)
+            if not head and i == cfg.n_layer - 1:
+                # write-only tower, last layer: nothing consumes x past
+                # this K/V deposit — attention/proj/ffn are dead compute
+                return None
+            ctx = attend(q, nth, p + tag)
+            attn = layers.fc(
+                layers.reshape(ctx, shape=[-1, cfg.attn_width]),
+                size=cfg.d_model,
+                param_attr=ParamAttr(name=p + '.attn.proj.w'),
+                bias_attr=_bias(cfg, p + '.attn.proj.b'))
         ln2, x = _norm(cfg, x, attn, 1, p + '.ln2')
         delta, routed = _ffn(cfg, ln2, p, 1, valid=valid, layer=i)
         if routed is not None:
@@ -752,7 +866,8 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
     block = tokens.block
     mb = max_len // block_size
     btab = layers.data(name='gen_btab', shape=[mb], dtype='int64')
-    kc, vc = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
+    kc, vc, tails = _declare_paged_kv_caches(block, cfg, num_blocks,
+                                             block_size)
 
     x = layers.embedding(
         tokens, size=[cfg.vocab_size, d], dtype='float32',
@@ -760,6 +875,11 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
     if cfg.position == 'sinusoid':
         pe = layers.assign(position_encoding_table(max_len, d))
         x = layers.elementwise_add(x, layers.gather(pe, pos))
+
+    def conv(g, weight_attr, layer):
+        return layers.short_conv_decode(
+            g, tails, pos, btab, layer, block_size, cfg.conv_kernel,
+            param_attr=weight_attr)
 
     def cache_write(k, v, layer):
         for cache, new in ((kc, k), (vc, v)):
@@ -794,7 +914,8 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
         if cfg.ffn == 'moe' else None
     routing = []
     logits = _decode_tower(cfg, x, cache_write, attend, pos=pos,
-                           valid=valid, routing=routing)     # [S, V]
+                           valid=valid, routing=routing,
+                           conv=conv)                        # [S, V]
     next_tokens = _append_sample_op(block, logits, sample_vars,
                                     'gen_next_tokens')       # [S]
     return _expert_outputs(
@@ -844,7 +965,7 @@ def build_lm_drafter(cfg, slots, max_len, spec_k, num_blocks, block_size):
     vmask = layers.data(name='gen_vmask', shape=[spec_k + 1],
                         dtype='int64')
     block = tokens.block
-    kc, vc = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
+    kc, vc, _ = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
     pe = layers.assign(position_encoding_table(max_len, d))
 
     drafts = []
@@ -947,7 +1068,7 @@ def build_lm_verify(cfg, slots, width, max_len, num_blocks, block_size):
     btab = layers.data(name='gen_btab', shape=[mb], dtype='int64')
     vmask = layers.data(name='gen_vmask', shape=[W], dtype='int64')
     block = tokens.block
-    kc, vc = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
+    kc, vc, _ = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
 
     flat = layers.reshape(tokens, shape=[-1])                # [S*W]
     x = layers.embedding(
@@ -1029,7 +1150,8 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     length = layers.data(name='gen_len', shape=[1], dtype='int64')
     sample_vars = _sampling_inputs()
     block = prompt.block
-    kc, vc = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
+    kc, vc, tails = _declare_paged_kv_caches(block, cfg, num_blocks,
+                                             block_size)
 
     x = layers.embedding(
         prompt, size=[cfg.vocab_size, d], dtype='float32',
@@ -1051,18 +1173,22 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
             attrs={'layer': int(layer), 'block_size': int(block_size)})
         return cache
 
-    delta = None
-    routing = []
-    for i in range(cfg.n_layer):
-        p = 'layer_%d' % i
-        ln1, x = _norm(cfg, x, delta, 2, p + '.ln1')
+    def conv(g, weight_attr, layer):
+        return layers.short_conv_prefill(
+            g, tails, pos, btab, length, layer, block_size, cfg.conv_kernel,
+            param_attr=weight_attr)
+
+    def attention(ln1, p, nth):
+        """An attention layer's mixer: q, k, v, the cache writes, the
+        suffix's attention against the slot's pages, the projection."""
+        nonlocal kc, vc
         q, k, v = _qkv(cfg, ln1, p, pos, T)                  # [1,H,T,dh]
-        kc = cache_write(kc, k, i)
+        kc = cache_write(kc, k, nth)
         if cfg.attention == 'mla':
             ctx = _mla_attend(cfg, layers.mla_prefix_attention, q, kc, pos,
-                              btab, i)                       # [1,T,H,v]
+                              btab, nth)                     # [1,T,H,v]
         else:
-            vc = cache_write(vc, v, i)
+            vc = cache_write(vc, v, nth)
             ctx = block.create_var(name=p + '.prefix_attn_out',
                                    shape=(-1, h, T, dh), dtype='float32')
             block.append_op(
@@ -1070,13 +1196,24 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
                 inputs={'Q': [q], 'KCache': [kc], 'VCache': [vc],
                         'Positions': [pos], 'BlockTable': [btab]},
                 outputs={'Out': [ctx]},
-                attrs={'layer': i, 'scale': dh ** -0.5,
+                attrs={'layer': nth, 'scale': dh ** -0.5,
                        'block_size': int(block_size)})
             ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
         ctx = layers.reshape(ctx, shape=[0, T, cfg.attn_width])
-        attn = layers.fc(ctx, size=d, num_flatten_dims=2,
+        return layers.fc(ctx, size=d, num_flatten_dims=2,
                          param_attr=ParamAttr(name=p + '.attn.proj.w'),
                          bias_attr=_bias(cfg, p + '.attn.proj.b'))
+
+    delta = None
+    routing = []
+    for i in range(cfg.n_layer):
+        p = 'layer_%d' % i
+        nth = cfg.layer_ordinal(i)
+        ln1, x = _norm(cfg, x, delta, 2, p + '.ln1')
+        if cfg.layer_types[i] == 'conv':
+            attn = _conv_mixer(cfg, ln1, p, nth, conv, 2)
+        else:
+            attn = attention(ln1, p, nth)
         ln2, x = _norm(cfg, x, attn, 2, p + '.ln2')
         delta, routed = _ffn(cfg, ln2, p, 2, length=length, layer=i)
         if routed is not None:

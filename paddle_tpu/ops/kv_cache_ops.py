@@ -264,12 +264,16 @@ def _kv_decode_attention_paged(ctx, op):
     of each slot in place. ``xla``: the table-wide gather, einsums on
     the gathered ``[S, MB*bs, H, dh]``. ``off``: that gather moved to
     ``[S, H, MB*bs, dh]``, heads ahead of positions (the tests'
-    reference). A >1-device mesh has no kernel here (the pool is not
+    reference). Pools of fewer K/V heads than Q has (grouped queries):
+    query head h reads K/V head ``h // (H // Hkv)``; the kernel copies a
+    page once for its heads' queries, and ``xla`` / ``off`` are ONE gather
+    formulation over the ``Hkv`` gathered heads, none repeated. A
+    >1-device mesh has no kernel here (the pool is not
     sharded): it takes ``xla``."""
     from . import kernel_tier, paged_decode_attention as pda
     from ..parallel.api import get_active_mesh
     q = ctx.in1(op, 'Q')                        # [S, H, dh]
-    kc = ctx.in1(op, 'KCache')                  # [NB, Ln, bs, H*dh]
+    kc = ctx.in1(op, 'KCache')                  # [NB, Ln, bs, Hkv*dh]
     vc = ctx.in1(op, 'VCache')
     tables = ctx.in1(op, 'BlockTables').astype(jnp.int32)  # [S, MB]
     pos = ctx.in1(op, 'Positions').reshape(-1)  # [S]
@@ -278,15 +282,30 @@ def _kv_decode_attention_paged(ctx, op):
     bs = int(op.attr('block_size'))
     MB = tables.shape[1]
     H, dh = q.shape[1], q.shape[2]
+    Hkv = kc.shape[3] // dh
     mesh = get_active_mesh()
     meshed = mesh is not None and mesh.size > 1
     impl = kernel_tier.dispatch(
         'kv_decode_attention_paged',
-        pallas_ok=pda.shapes_ok(H, dh, bs) and not meshed, mesh=mesh)
+        pallas_ok=pda.shapes_ok(H, dh, bs, Hkv) and not meshed, mesh=mesh)
     if impl in ('pallas', 'interpret'):
         ctx.out(op, 'Out', pda.paged_decode_attention(
             q, kc, vc, tables, pos, jnp.int32(layer), scale=float(scale),
             interpret=impl == 'interpret'))
+        return
+    m = jnp.arange(MB * bs)[None, None, :] <= pos[:, None, None]
+    if Hkv != H:
+        # grouped queries: the H // Hkv query heads of a K/V head against
+        # its gathered pages, which are not repeated
+        k = _gather_pages(kc, layer, tables, Hkv)           # [S, M, Hkv, dh]
+        v = _gather_pages(vc, layer, tables, Hkv)
+        qg = q.reshape(q.shape[0], Hkv, H // Hkv, dh)
+        scores = jnp.einsum('skgd,smkd->skgm', qg, k,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(m[:, None], scores, _NEG_INF)
+        w = jnp.where(m[:, None], jax.nn.softmax(scores, axis=-1), 0.0)
+        ctx.out(op, 'Out', jnp.einsum('skgm,smkd->skgd', w.astype(v.dtype),
+                                      v).reshape(q.shape))
         return
     # xla keeps the gathered [S, M, H, dh]; off moves it to
     # [S, H, M, dh]
@@ -297,7 +316,6 @@ def _kv_decode_attention_paged(ctx, op):
     v = gather(vc, layer, tables, H)
     scores = jnp.einsum(qk, q, k,
                         preferred_element_type=jnp.float32) * scale
-    m = jnp.arange(MB * bs)[None, None, :] <= pos[:, None, None]
     scores = jnp.where(m, scores, _NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     w = jnp.where(m, w, 0.0)
@@ -314,7 +332,7 @@ def _kv_prefix_attention(ctx, op):
     (Positions starting at 0) this is exactly the causal prefill
     attention, computed from the cache instead of a local K/V copy."""
     q = ctx.in1(op, 'Q')                        # [1, H, T, dh]
-    kc = ctx.in1(op, 'KCache')                  # [NB, Ln, bs, H*dh]
+    kc = ctx.in1(op, 'KCache')                  # [NB, Ln, bs, Hkv*dh]
     vc = ctx.in1(op, 'VCache')
     table = ctx.in1(op, 'BlockTable').reshape(-1).astype(jnp.int32)
     pos = ctx.in1(op, 'Positions').reshape(-1)  # [T] global query positions
@@ -322,16 +340,25 @@ def _kv_prefix_attention(ctx, op):
     scale = op.attr('scale', 1.0)
     bs = int(op.attr('block_size'))
     MB = table.shape[0]
-    k = _gather_heads(kc, layer, table, q.shape[1])    # [H, MB*bs, dh]
-    v = _gather_heads(vc, layer, table, q.shape[1])
-    scores = jnp.einsum('htd,hmd->htm', q[0], k,
-                        preferred_element_type=jnp.float32) * scale
+    H, T, dh = q.shape[1:]
+    Hkv = kc.shape[3] // dh
+    k = _gather_heads(kc, layer, table, Hkv)           # [Hkv, MB*bs, dh]
+    v = _gather_heads(vc, layer, table, Hkv)
     m = jnp.arange(MB * bs)[None, :] <= pos[:, None]       # [T, M]
-    scores = jnp.where(m[None], scores, _NEG_INF)
+    if Hkv != H:
+        # grouped queries: a K/V head's H // Hkv query heads are rows of
+        # ONE matmul against it; the gathered keys are not repeated
+        qg = q[0].reshape(Hkv, (H // Hkv) * T, dh)
+        mg = jnp.tile(m, (H // Hkv, 1))[None]
+    else:
+        qg, mg = q[0], m[None]
+    scores = jnp.einsum('htd,hmd->htm', qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(mg, scores, _NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
-    w = jnp.where(m[None], w, 0.0)
+    w = jnp.where(mg, w, 0.0)
     out = jnp.einsum('htm,hmd->htd', w.astype(v.dtype), v)
-    ctx.out(op, 'Out', out[None])               # [1, H, T, dh]
+    ctx.out(op, 'Out', out.reshape(1, H, T, dh))           # [1, H, T, dh]
 
 
 @register_op('sample_next_token', share_lod=False)

@@ -33,8 +33,8 @@ to the Program path (OLMoE: models/transformer.py, LMConfig(norm=
 
   The DeepSeek-V3 router (``score='sigmoid'``): ``s = sigmoid(x @
   RouterW)``, the top_k largest of ``s + SelectBias`` (the bias chooses
-  only), weights ``s_e`` of the chosen, divided by ``sum + 1e-20`` with
-  ``norm_topk_prob``, times ``routed_scale``.
+  only), weights ``s_e`` of the chosen, divided by ``sum + router_eps``
+  (1e-20; LFM2 says 1e-6) with ``norm_topk_prob``, times ``routed_scale``.
 
   A SHARE of the experts (``experts_held = (first, count)``, the chip's
   share under expert parallelism): the router still scores ALL
@@ -106,7 +106,7 @@ def _rotary_embedding(ctx, op):
 
 
 def route(x, router_w, top_k, norm_topk_prob, score='softmax',
-          select_bias=None, routed_scale=1.0):
+          select_bias=None, routed_scale=1.0, eps=1e-20):
     """(weights [N, k] float32, experts [N, k] int32): the scores of ALL
     experts in float32, then the top_k largest. `score='sigmoid'`: the
     choice is by score + `select_bias`, the weights are the scores alone,
@@ -122,7 +122,7 @@ def route(x, router_w, top_k, norm_topk_prob, score='softmax',
     idx = lax.top_k(s + select_bias.astype(jnp.float32)[None, :], top_k)[1]
     w = jnp.take_along_axis(s, idx, axis=1)
     if norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return w * routed_scale, idx.astype(jnp.int32)
 
 
@@ -187,7 +187,8 @@ def _moe_ffn(ctx, op):
     w, idx = route(x, router_w, top_k,
                    bool(op.attr('norm_topk_prob', False)),
                    op.attr('score', 'softmax'), ctx.in1(op, 'SelectBias'),
-                   float(op.attr('routed_scale', 1.0)))
+                   float(op.attr('routed_scale', 1.0)),
+                   float(op.attr('router_eps', 1e-20)))
     held = gate_w.shape[0]
     # the experts held here: all of them, or `held` from `first` on
     first = None if held == router_w.shape[1] \

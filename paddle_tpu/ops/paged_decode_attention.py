@@ -29,6 +29,14 @@ LAST page — get score ``-1e30``, weight exactly 0, and leave max, sum and
 accumulator as they were: the exact-zero contract of the gather
 formulation.
 
+Grouped queries (``n_kv_head < n_head``): the pool holds the K/V heads
+only, a page is ``[bs, n_kv_head * dh]``, and query head ``h`` reads K/V
+head ``h // G`` (``G = n_head // n_kv_head``). A page is still copied
+ONCE; the ``G`` queries of each of its heads are laid over its lanes one
+after the other (query ``g`` of every K/V head at a time, a lane layout
+like the page's own), each with its own ``bs`` streams. With ``G == 1``
+this is the kernel it was, operation for operation.
+
 Slot independence is bitwise: the pages a slot visits, and the sequence
 of operations on them, depend on its own position, table row and query
 only. Which ring buffer a page lands in depends on the neighbours; the
@@ -47,11 +55,14 @@ _RING_BYTES = 2 << 20
 _RING_MAX = 32
 
 
-def shapes_ok(n_head, head_dim, block_size):
-    """The kernel's tiling rule: pages are whole (8, 128) tiles and a
-    head's lanes never straddle a vreg."""
-    return (n_head * head_dim) % _LANES == 0 and \
-        _LANES % head_dim == 0 and block_size % 8 == 0
+def shapes_ok(n_head, head_dim, block_size, n_kv_head=None):
+    """The kernel's tiling rule: pages (of the K/V heads) are whole
+    (8, 128) tiles, a head's lanes never straddle a vreg, and the query
+    heads divide evenly over the K/V heads."""
+    n_kv_head = n_kv_head or n_head
+    return (n_kv_head * head_dim) % _LANES == 0 and \
+        _LANES % head_dim == 0 and block_size % 8 == 0 and \
+        n_head % n_kv_head == 0
 
 
 def ring_depth(n_head, head_dim, block_size):
@@ -78,7 +89,7 @@ def _kernel(tables_ref, pos_ref, layer_ref,          # scalar prefetch
             q_ref, k_hbm, v_hbm,                     # inputs
             o_ref,                                   # output
             k_buf, v_buf, sems, qb, m_scr, l_scr, acc_scr, cur,
-            *, scale, head_dim, block_size, max_blocks, slots, ring):
+            *, scale, head_dim, block_size, max_blocks, slots, ring, group):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     s = pl.program_id(0)
@@ -120,7 +131,10 @@ def _kernel(tables_ref, pos_ref, layer_ref,          # scalar prefetch
         cur[3] = 0
         lax.fori_loop(0, ring, lambda i, c: (issue(), c)[1], 0)
 
-    qb[...] = jnp.broadcast_to(q_ref[0] * scale, qb.shape)
+    # query g of every K/V head over the page's lanes, in rows g*bs..
+    for g in range(group):
+        qb[pl.ds(g * bs, bs), :] = jnp.broadcast_to(
+            q_ref[0, pl.ds(g, 1), :] * scale, (bs, hd))
     m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
     l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
     acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
@@ -143,19 +157,20 @@ def _kernel(tables_ref, pos_ref, layer_ref,          # scalar prefetch
         # serves both (traced and lowered once)
         live = p * bs + row <= jnp.where(p == n - 1, pos,
                                          jnp.iinfo(jnp.int32).max)
-        for c0 in range(0, hd, _LANES):
-            sl = pl.ds(c0, _LANES)
-            sc = _segment_sum(k_buf[r, :, sl] * qb[:, sl], heads)
+        for g, c0 in [(g, c0) for g in range(group)
+                      for c0 in range(0, hd, _LANES)]:
+            rs, sl = pl.ds(g * bs, bs), pl.ds(c0, _LANES)
+            sc = _segment_sum(k_buf[r, :, sl] * qb[rs, sl], heads)
             sc = jnp.where(live, sc, _NEG_INF)
-            m_prev = m_scr[:, sl]
+            m_prev = m_scr[rs, sl]
             m_new = jnp.maximum(m_prev, sc)
             alpha = jnp.exp(m_prev - m_new)
             # a row past the position: weight exactly 0, whatever the
             # page holds there
             w = jnp.where(live, jnp.exp(sc - m_new), 0.0)
-            l_scr[:, sl] = alpha * l_scr[:, sl] + w
-            acc_scr[:, sl] = alpha * acc_scr[:, sl] + w * v_buf[r, :, sl]
-            m_scr[:, sl] = m_new
+            l_scr[rs, sl] = alpha * l_scr[rs, sl] + w
+            acc_scr[rs, sl] = alpha * acc_scr[rs, sl] + w * v_buf[r, :, sl]
+            m_scr[rs, sl] = m_new
         cur[0] = cur[0] + 1
         issue()
         return carry
@@ -163,21 +178,23 @@ def _kernel(tables_ref, pos_ref, layer_ref,          # scalar prefetch
     lax.fori_loop(0, n, page, 0)
 
     # merge the bs streams of every head
-    for c0 in range(0, hd, _LANES):
-        sl = pl.ds(c0, _LANES)
-        m = m_scr[:, sl]
+    for g, c0 in [(g, c0) for g in range(group)
+                  for c0 in range(0, hd, _LANES)]:
+        rs, sl = pl.ds(g * bs, bs), pl.ds(c0, _LANES)
+        m = m_scr[rs, sl]
         w = jnp.exp(m - jnp.max(m, axis=0, keepdims=True))
-        den = jnp.sum(l_scr[:, sl] * w, axis=0, keepdims=True)
-        num = jnp.sum(acc_scr[:, sl] * w, axis=0, keepdims=True)
-        o_ref[0, :, sl] = (num / den).astype(o_ref.dtype)
+        den = jnp.sum(l_scr[rs, sl] * w, axis=0, keepdims=True)
+        num = jnp.sum(acc_scr[rs, sl] * w, axis=0, keepdims=True)
+        o_ref[0, pl.ds(g, 1), sl] = (num / den).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=('scale', 'interpret'))
 def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
                            scale, interpret=False):
-    """q ``[S, H, dh]``; pools ``[NB, Ln, bs, H*dh]``; tables ``[S, MB]``
-    and pos ``[S]`` int32; layer an int32 scalar. Returns ``[S, H, dh]``:
-    softmax(q . K[0..pos]) V[0..pos] per slot and head.
+    """q ``[S, H, dh]``; pools ``[NB, Ln, bs, Hkv*dh]``; tables ``[S,
+    MB]`` and pos ``[S]`` int32; layer an int32 scalar. Returns ``[S, H,
+    dh]``: softmax(q . K[0..pos]) V[0..pos] per slot and head, query head
+    h against K/V head ``h // (H // Hkv)``.
 
     Jitted, with `layer` an operand: the layers of a decode program call
     ONE traced function, so the kernel is traced and lowered to Mosaic
@@ -188,13 +205,17 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
     S, H, dh = q.shape
     bs, hd = k_pool.shape[2], k_pool.shape[3]
     MB = tables.shape[1]
-    ring = ring_depth(H, dh, bs)
+    Hkv = hd // dh
+    G = H // Hkv
+    ring = ring_depth(Hkv, dh, bs)
     kernel = functools.partial(
         _kernel, scale=scale, head_dim=dh, block_size=bs,
-        max_blocks=MB, slots=S, ring=ring)
-    row = pl.BlockSpec((1, 1, hd), lambda s, *_: (s, 0, 0))
+        max_blocks=MB, slots=S, ring=ring, group=G)
+    # rows of one slot: query g of every K/V head, laid out as a page's
+    # lanes are (K/V head, feature)
+    row = pl.BlockSpec((1, G, hd), lambda s, *_: (s, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
-    state = pltpu.VMEM((bs, hd), jnp.float32)
+    state = pltpu.VMEM((G * bs, hd), jnp.float32)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -208,12 +229,13 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
                 pltpu.SemaphoreType.DMA((2, ring)),
                 state, state, state, state,
                 pltpu.SMEM((4,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((S, 1, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, G, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name='paged_decode_attention',
     )(tables.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1),
-      q.reshape(S, 1, hd), k_pool, v_pool)
-    return out.reshape(S, H, dh)
+      jnp.swapaxes(q.reshape(S, Hkv, G, dh), 1, 2).reshape(S, G, hd),
+      k_pool, v_pool)
+    return jnp.swapaxes(out.reshape(S, G, Hkv, dh), 1, 2).reshape(S, H, dh)

@@ -8,8 +8,11 @@ This module is the decode-native path:
 
 - **Persistent device-resident KV cache.** One pair of persistable
   ``[num_blocks, layers, block_size, heads * head_dim]`` buffers
-  (models/transformer.py ``KV_CACHE_K``/``KV_CACHE_V``) — a pool of
-  fixed-size blocks — lives in the engine's scope like any other executor
+  (models/transformer.py ``KV_CACHE_K``/``KV_CACHE_V``; the attention
+  layers and the K/V heads only, where a model says so, and beside them
+  the convolution layers' tails under the same block ids:
+  ``kv_cache_shapes``) — a pool of fixed-size blocks — lives in the
+  engine's scope like any other executor
   state: the decode step reads AND writes them, so the PR 1 donation path
   aliases each step's update in place — the cache never doubles in HBM
   and never crosses the host.
@@ -123,9 +126,9 @@ from .. import trace as trace_mod
 from .. import unique_name
 from ..executor import Executor, Scope, scope_guard
 from ..framework import Program, TPUPlace, program_guard
-from ..models.transformer import (KV_CACHE_K, LMConfig,
-                                  build_lm_decode_step,
-                                  build_lm_prefill_paged, kv_cache_names)
+from ..models.transformer import (LMConfig, build_lm_decode_step,
+                                  build_lm_prefill_paged, kv_cache_names,
+                                  kv_cache_shapes)
 from ..reader.bucketing import bucketize
 from .kv_blocks import BlockAllocator, PrefixCache, chain_hashes
 from .batcher import (DeadlineExceededError, EngineStoppedError,
@@ -534,6 +537,12 @@ class GenerateEngine(object):
         self._cow_jit = None
         self._dcopy_jit = None
         self._carry_jit = None
+        if c.speculative and c.model.n_conv_layers:
+            raise ValueError(
+                "speculative=True with LMConfig.layer_types=%r: a rejected "
+                "draft rewinds positions, and a convolution layer's tail "
+                "in the block pool cannot be rewound (it holds the last "
+                "rows written, not every row)" % (c.model.layer_types,))
         if c.speculative:
             self._draft_cfg = c.draft_model or c.model
             # +1 over the all-slots-at-max_len footprint (the trash
@@ -714,8 +723,9 @@ class GenerateEngine(object):
         self._ensure_cache()
 
     def _ensure_cache(self):
-        """Make the scope's gen_kv_k/v buffers match THIS engine's
-        geometry. A provided scope may carry another engine's cache
+        """Make the scope's pools (`kv_cache_names`: K, V, the
+        convolution tails) match THIS engine's geometry. A provided
+        scope may carry another engine's cache
         under the same names with a different pool shape; the cache
         holds no trained state, so re-zeroing is always safe, while
         reusing a mismatched buffer would feed the compiled programs
@@ -724,20 +734,17 @@ class GenerateEngine(object):
         it (concurrent use of one scope by two live engines stays
         unsupported)."""
         import jax.numpy as jnp
-        cfg, c = self.config.model, self.config
-        shape = (c.num_blocks, cfg.n_layer, c.block_size, cfg.kv_width)
-        have = self.scope.get(KV_CACHE_K)
-        if have is None or tuple(have.shape) != shape:
-            for name in kv_cache_names(cfg):
-                self.scope.set(name, jnp.zeros(shape, 'float32'))
+        c = self.config
+        pools = [(self.scope, kv_cache_shapes(c.model, c.num_blocks,
+                                              c.block_size))]
         if c.speculative:
-            dcfg = self._draft_cfg
-            dshape = (self._draft_nb, dcfg.n_layer, c.block_size,
-                      dcfg.kv_width)
-            dhave = self._draft_scope.get(KV_CACHE_K)
-            if dhave is None or tuple(dhave.shape) != dshape:
-                for name in kv_cache_names(dcfg):
-                    self._draft_scope.set(name, jnp.zeros(dshape, 'float32'))
+            pools.append((self._draft_scope, kv_cache_shapes(
+                self._draft_cfg, self._draft_nb, c.block_size)))
+        for scope, shapes in pools:
+            for name, shape in shapes.items():
+                have = scope.get(name)
+                if have is None or tuple(have.shape) != shape:
+                    scope.set(name, jnp.zeros(shape, 'float32'))
 
     # ------------------------------------------------------------------
     # feed + block helpers
@@ -1355,7 +1362,9 @@ class GenerateEngine(object):
         fresh blocks for the rest, and a copy-on-write duplicate of the
         final shared block when the ENTIRE prompt landed on shared
         blocks (its last position must be recomputed, a divergent
-        write). Returns None when the pool cannot satisfy the request
+        write; a model with convolution layers recomputes that whole
+        block into a fresh one instead). Returns None when the pool
+        cannot satisfy the request
         right now (nothing referenced, nothing allocated)."""
         c = self.config
         bs = c.block_size
@@ -1365,8 +1374,14 @@ class GenerateEngine(object):
         if self._prefix is not None:
             hashes = chain_hashes(req.prompt, bs)
             shared = self._prefix.match(hashes)
-        cow = bool(shared) and len(shared) * bs >= L
-        n_keep = len(shared) - (1 if cow else 0)
+        whole = bool(shared) and len(shared) * bs >= L
+        n_keep = len(shared) - (1 if whole else 0)
+        # a wholly shared prompt: copy its last block and recompute the
+        # final row. A model with convolution layers RECOMPUTES that block
+        # into a fresh one instead: a copied entry of the tails' pool
+        # holds g of the block's last rows, and recomputing the last
+        # position alone would need the rows before them
+        cow = whole and not c.model.n_conv_layers
         ctx_len = min(n_keep * bs + (bs if cow else 0), L - 1)
         # pin every matched block (incl. the COW source) BEFORE touching
         # the allocator: under pool pressure _alloc_blocks evicts
@@ -1385,6 +1400,7 @@ class GenerateEngine(object):
             self._cow_copy(shared[-1], new_ids[0])
             self._alloc.deref(shared[-1])   # pinned only for the copy
             monitor.inc('kv_block_cow_total')
+        monitor.inc('prefill_prompt_tokens_total', L)
         if self._prefix is not None:
             monitor.inc('kv_prefix_hit_total', labels={
                 'outcome': 'hit' if ctx_len > 0 else 'miss'})
@@ -1503,6 +1519,11 @@ class GenerateEngine(object):
         wide = c.prompt_buckets[-1]
         off = int(ctx_len)
         suffix = prompt[off:]
+        if c.model.n_conv_layers:
+            # every dispatch that starts past position 0 — a hit's suffix,
+            # a later chunk — resumes from a tail the pool holds
+            monitor.inc('conv_tail_resumes_total',
+                        (off > 0) + (suffix.size - 1) // wide)
         while suffix.size > wide:
             chunk, suffix = suffix[:wide], suffix[wide:]
             pos = np.clip(off + np.arange(wide), 0, c.max_len - 1)
@@ -1942,6 +1963,10 @@ class GenerateEngine(object):
                 # the latent rows the step's attention has to read
                 monitor.inc('kv_latent_tokens_read_total',
                             live_tokens * c.model.n_layer)
+            else:
+                # the per-head K and V rows: the attention layers' alone
+                monitor.inc('kv_tokens_read_total',
+                            live_tokens * c.model.n_attn_layers)
             feed = {'gen_pos': pos, 'gen_btab': btab}
             feed.update(sample)
         with _loop_phase('dispatch'):

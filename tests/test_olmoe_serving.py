@@ -196,12 +196,10 @@ def _drive(eng, prompts, n_new, late=None):
     return [list(reqs[i].result(timeout=5)) for i in range(len(prompts))]
 
 
-def serve_five(eng, vocab):
-    """Five requests of different lengths through a warmed 4-slot paged
-    engine (block 8, buckets 16 and 32), every dispatch's feed and logits
-    logged: prompts that end inside a block, on a block's last row and
-    past the 16 bucket; outputs that cross block boundaries; request 3
-    admitted while the others decode, request 4 after a slot frees."""
+def tap_logits(eng):
+    """Rebind a warmed engine's programs with their logits fetched beside
+    the tokens; every dispatch's (kind, feed, logits) goes to the list
+    returned."""
     log = []
 
     def tapped(bound_with_logits, kind):
@@ -211,7 +209,7 @@ def serve_five(eng, vocab):
                         np.asarray(out[1])))
             return out
         return call
-    S, mb = 4, eng._max_blocks
+    S, mb = eng.config.slots, eng._max_blocks
     for b, (prog, v) in eng._prefill.items():
         feed = {'gen_prompt': np.zeros((1, b), 'int64'),
                 'gen_pos': np.zeros((1, b), 'int64'),
@@ -229,6 +227,16 @@ def serve_five(eng, vocab):
         eng._step_prog, feed, scope=eng.scope,
         fetch_list=[eng._step_vars['tokens_and_load'],
                     eng._step_vars['logits']]), 'step')
+    return log
+
+
+def serve_five(eng, vocab):
+    """Five requests of different lengths through a warmed 4-slot paged
+    engine (block 8, buckets 16 and 32), every dispatch's feed and logits
+    logged: prompts that end inside a block, on a block's last row and
+    past the 16 bucket; outputs that cross block boundaries; request 3
+    admitted while the others decode, request 4 after a slot frees."""
+    log = tap_logits(eng)
     rng = np.random.RandomState(7)
     prompts = [rng.randint(2, vocab, size=n).astype('int64')
                for n in (5, 16, 23, 8, 11)]
@@ -423,6 +431,23 @@ LISTED = {
                   ffn='moe', n_experts=8, experts_per_token=2,
                   expert_width=32),
 }
+# where a block's listing was recorded: the two the benchmark had at PR 31
+# (lm_programs_parent_pr31.json, from commit 44db736, the parent of the PR
+# that brought latent attention, the gated FFN and the held share of the
+# experts) build at PR 34 what they built then; JoyAI's toy
+# (benchmark_tests/configs/toy-joyai.json) is recorded from commit 56ed6e0
+# (PR 34), the parent of the PR that brought layer kinds, K/V-head counts
+# and the tied head
+RECORDED = {'fairseq-dense': 'pr31', 'olmoe': 'pr31', 'joyai': 'pr34'}
+
+
+def listed_config(config):
+    if config in LISTED:
+        return LMConfig(**LISTED[config])
+    from benchmark.models import joyai
+    with open(os.path.join(HERE, 'benchmark_tests', 'configs',
+                           'toy-joyai.json')) as f:
+        return joyai.lm_config(json.load(f), 32, False)
 
 
 def _plain(value):
@@ -433,19 +458,10 @@ def _plain(value):
     return value
 
 
-@pytest.mark.parametrize('program', ['decode_step', 'prefill_paged'])
-@pytest.mark.parametrize('config', sorted(LISTED))
-def test_the_benchmarks_configurations_build_the_pr31_commits_programs(
-        config, program):
-    """The same, with every op's ATTRIBUTES too and for the OLMoE block as
-    well, against a listing recorded from commit 44db736 (PR 31), the
-    parent of the PR that brought latent attention, the gated FFN and the
-    held share of the experts: the blocks the benchmark already had build
-    the programs they built."""
-    with open(os.path.join(HERE, 'fixtures',
-                           'lm_programs_parent_pr31.json')) as f:
-        want = json.load(f)[config][program]
-    cfg = LMConfig(**LISTED[config])
+def program_listing(cfg, program):
+    """A decode or prefill program of `cfg` at the listings' toy engine:
+    every op with its inputs, outputs and ATTRIBUTES, the parameters, the
+    startup program, in order."""
     build = {
         'decode_step': lambda: T.build_lm_decode_step(
             cfg, 4, 32, block_size=8, num_blocks=9),
@@ -466,7 +482,26 @@ def test_the_benchmarks_configurations_build_the_pr31_commits_programs(
         'startup': [[op.type, sorted(n for vs in op.outputs.values()
                                      for n in vs)]
                     for op in start.global_block().ops]}
-    assert json.loads(json.dumps(got)) == want
+    return json.loads(json.dumps(got))
+
+
+def parent_listing(config, program):
+    with open(os.path.join(HERE, 'fixtures', 'lm_programs_parent_%s.json'
+                           % RECORDED[config])) as f:
+        return json.load(f)[config][program]
+
+
+@pytest.mark.parametrize('program', ['decode_step', 'prefill_paged'])
+@pytest.mark.parametrize('config', sorted(RECORDED))
+def test_the_benchmarks_configurations_build_the_pr31_commits_programs(
+        config, program):
+    """The same, with every op's ATTRIBUTES too, for every block the
+    benchmark serves — fairseq-dense, OLMoE and (since PR 35) JoyAI —
+    against listings recorded at the parent of the PR that touched the
+    builders last (`RECORDED`): the blocks the benchmark already had
+    build the programs they built."""
+    assert program_listing(listed_config(config), program) == \
+        parent_listing(config, program)
 
 
 def test_an_engine_without_experts_fetches_the_tokens_alone():
@@ -484,7 +519,7 @@ def test_an_engine_without_experts_fetches_the_tokens_alone():
                 if k.startswith('moe_')]
 
 
-# ---- 5. the refusals ---------------------------------------------------------
+# ---- 5. the refusals --------------------------------------------------------
 
 REFUSERS = {
     'build_lm': lambda cfg: T.build_lm(cfg, is_test=True),

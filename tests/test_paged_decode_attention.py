@@ -240,6 +240,37 @@ def test_mosaic_accepts_the_kernel_at_the_cells_shapes(one_chip, S, H, MB,
     assert c.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+def test_mosaic_accepts_the_grouped_query_kernel_at_its_cells_shapes(
+        one_chip):
+    """lfm2-serve-agent64: 64 slots, 32 query heads on 8 K/V heads of 64,
+    pages of 32 rows x 512 lanes, tables of 160 entries (the kernel's
+    largest so far), a pool of 4 096 blocks over the 2 attention layers.
+    A page is copied once for the four queries of each of its heads."""
+    import jax
+    S, H, Hkv, dh, MB, NB, ln, bs, layer = 64, 32, 8, 64, 160, 4096, 2, 32, 1
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(kc, vc, q, new, tables, pos):
+        blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
+        kc = kc.at[blk, layer, pos % bs, :].set(new)
+        return kc, pda.paged_decode_attention(q, kc, vc, tables, pos, layer,
+                                              scale=dh ** -0.5)
+
+    assert pda.shapes_ok(H, dh, bs, Hkv)
+    assert not pda.shapes_ok(H, dh, bs, 5) and not pda.shapes_ok(2, dh, bs, 1)
+    pool = sds((NB, ln, bs, Hkv * dh))
+    c = jax.jit(step, donate_argnums=0).lower(
+        pool, pool, sds((S, H, dh)), sds((S, Hkv * dh)),
+        sds((S, MB), jnp.int32), sds((S,), jnp.int32)).compile()
+    text = c.as_text()
+    assert 'tpu_custom_call' in text and 'paged_decode_attention' in text
+    assert 'f32[%d,%d,%d,%d]{3,2,1,0:T(8,128)}' % (NB, ln, bs, Hkv * dh) \
+        in text
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def test_mosaic_accepts_the_latent_kernel_at_its_cells_shapes(one_chip):
     """ops/mla_paged_decode_attention.py at joyai-serve-longchat64's size:
     64 slots x 32 absorbed queries of 640 lanes (576 numbers in whole
@@ -283,7 +314,8 @@ def _compile_serving_program(build, fetch, rows, one_chip):
     from paddle_tpu import unique_name
     from paddle_tpu.core.lowering import build_fn
     from paddle_tpu.framework import Program, program_guard
-    from paddle_tpu.models.transformer import KV_CACHE_K, KV_CACHE_V
+    from paddle_tpu.models.transformer import (CONV_CACHE, KV_CACHE_K,
+                                               KV_CACHE_V)
     main = Program()
     with program_guard(main, Program()), unique_name.guard():
         v = build()
@@ -296,7 +328,7 @@ def _compile_serving_program(build, fetch, rows, one_chip):
             jnp.int32 if dt == jnp.int64 else dt, sharding=one_chip)
     state = [x.name for x in block.vars.values() if x.persistable]
     fn, ro, rw = build_fn(main, [v[fetch].name], state,
-                          [KV_CACHE_K, KV_CACHE_V])
+                          [KV_CACHE_K, KV_CACHE_V, CONV_CACHE])
     written = {n for op in block.ops for ns in op.outputs.values()
                for n in ns}
     feeds = {n: x for n, x in block.vars.items()
@@ -352,6 +384,45 @@ def test_no_serving_program_re_lays_the_embedding_table(one_chip,
             table_sized.append(m.group(0).strip())
     assert not table_sized, table_sized
     assert c.memory_analysis().temp_size_in_bytes < V * D * 4 // 4
+
+
+@pytest.mark.parametrize('program', ['decode_step', 'prefill_b64'])
+def test_a_tied_head_and_the_tails_pool_cost_no_copy(one_chip, monkeypatch,
+                                                     program):
+    """An LFM2-shaped block (a convolution layer, grouped queries, the head
+    tied to the table): the head contracts against `tok_emb.w` [V, D]
+    where it lies — no transposed copy, no `lm_head.w` —, the tails' pool
+    is updated in place beside the K/V pools (all three aliased), and the
+    decode step's attention is the kernel."""
+    from paddle_tpu.models import transformer as T
+    monkeypatch.setenv('PADDLE_FUSED_TIER', 'pallas')
+    V, D, slots, bs, max_len, NB = 32768, 1024, 8, 16, 128, 64
+    cfg = LMConfig(vocab_size=V, seq_len=max_len, d_model=D, n_head=16,
+                   n_kv_head=4, n_layer=2, layer_types=['conv', 'attention'],
+                   d_ff=2048, dropout=0.0, attn_dropout=0.0, norm='rms_norm',
+                   position='rope', qk_norm='head', bias=False,
+                   tie_embeddings=True, ffn='moe', n_dense_layers=2,
+                   n_experts=4, experts_per_token=2, expert_width=64)
+    if program == 'decode_step':
+        c = _compile_serving_program(
+            lambda: T.build_lm_decode_step(cfg, slots, max_len,
+                                           block_size=bs, num_blocks=NB),
+            'next_tokens', slots, one_chip)
+    else:
+        c = _compile_serving_program(
+            lambda: T.build_lm_prefill_paged(cfg, 64, NB, bs,
+                                             max_len // bs),
+            'first_token', 1, one_chip)
+    text = c.as_text()
+    assert 'f32[%d,%d]{1,0:T(8,128)}' % (V, D) in text        # in place
+    assert 'f32[%d,%d]' % (D, V) not in text                 # no [D, V]
+    ma = c.memory_analysis()
+    assert ma.temp_size_in_bytes < V * D * 4 // 4
+    pools = 2 * NB * 1 * bs * 4 * 64 * 4 + NB * 1 * 2 * D * 4
+    assert ma.alias_size_in_bytes == pools
+    if program == 'decode_step':
+        assert 'tpu_custom_call' in text
+        assert 'paged_decode_attention' in text
 
 
 def test_the_block_copy_takes_the_pool_in_place(one_chip):
