@@ -266,11 +266,14 @@ def _kv_decode_attention_paged(ctx, op):
     ``[S, H, MB*bs, dh]``, heads ahead of positions (the tests'
     reference). Pools of fewer K/V heads than Q has (grouped queries):
     query head h reads K/V head ``h // (H // Hkv)``; the kernel copies a
-    page once for its heads' queries, and ``xla`` / ``off`` are ONE gather
+    page once for its heads' queries and takes its MXU body (counted in
+    ``paged_decode_attention_form_total{form=mxu|vpu}``, once a call site
+    lowered to the kernel), and ``xla`` / ``off`` are ONE gather
     formulation over the ``Hkv`` gathered heads, none repeated. A
     >1-device mesh has no kernel here (the pool is not
     sharded): it takes ``xla``."""
     from . import kernel_tier, paged_decode_attention as pda
+    from .. import monitor
     from ..parallel.api import get_active_mesh
     q = ctx.in1(op, 'Q')                        # [S, H, dh]
     kc = ctx.in1(op, 'KCache')                  # [NB, Ln, bs, Hkv*dh]
@@ -289,6 +292,10 @@ def _kv_decode_attention_paged(ctx, op):
         'kv_decode_attention_paged',
         pallas_ok=pda.shapes_ok(H, dh, bs, Hkv) and not meshed, mesh=mesh)
     if impl in ('pallas', 'interpret'):
+        # which of the kernel's two bodies this call site lowered to: the
+        # head counts decide, at trace time
+        monitor.inc('paged_decode_attention_form_total',
+                    labels={'form': pda.form(H, Hkv)})
         ctx.out(op, 'Out', pda.paged_decode_attention(
             q, kc, vc, tables, pos, jnp.int32(layer), scale=float(scale),
             interpret=impl == 'interpret'))

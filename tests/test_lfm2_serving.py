@@ -32,7 +32,7 @@ from benchmark.reference import lfm2_control, lfm2_reference as ref
 from benchmark.reference.olmoe_control import logit_gap
 
 from test_olmoe_serving import LISTED, lower, serve_five, tap_logits
-from test_paged_decode_attention import _attend, _pools
+from test_paged_decode_attention import _attend, _pools, _serving_program
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 with open(os.path.join(HERE, 'benchmark_tests', 'configs',
@@ -174,6 +174,55 @@ def test_the_grouped_query_kernel_matches_the_gather(monkeypatch, H, Hkv):
     np.testing.assert_allclose(
         _attend('off', monkeypatch, q, wide[0], wide[1], tables, pos, layer,
                 bs), off, rtol=2e-5, atol=2e-6)
+
+
+def _trace_decode_step(cfg, slots=4):
+    """`build_lm_decode_step(cfg)` traced at its shapes (`jax.eval_shape`:
+    every op's lowering runs and counts, nothing executes)."""
+    import jax
+    fn, args = _serving_program(
+        lambda: T.build_lm_decode_step(cfg, slots, 64, block_size=8,
+                                       num_blocks=16),
+        'next_tokens', slots)
+    jax.eval_shape(fn, *args)
+
+
+@pytest.mark.parametrize('model,form,layers', [
+    ('lfm2', 'mxu', 2), ('fairseq-dense', 'vpu', 3)])
+def test_the_decode_program_counts_the_kernels_body_once_a_layer(
+        monkeypatch, model, form, layers):
+    """`paged_decode_attention_form_total{form}`: + 1 for each attention
+    layer lowered to the kernel, `mxu` where the head counts make it take
+    grouped queries (an LFM2-shaped block: 8 query heads on 2 K/V heads of
+    64, conv conv attn conv attn conv) and `vpu` for the fairseq-dense
+    block; the choice is the shapes', no field of the configuration names
+    it. The toy widths above (heads of 8) fall to `xla` and count none."""
+    monkeypatch.setenv('PADDLE_FUSED_TIER', 'interpret')
+    common = dict(vocab_size=64, seq_len=64, dropout=0.0, attn_dropout=0.0)
+    if model == 'lfm2':
+        cfg = LMConfig(d_model=512, n_head=8, n_kv_head=2, n_layer=6,
+                       layer_types=['conv', 'conv', 'attention', 'conv',
+                                    'attention', 'conv'],
+                       d_ff=64, norm='rms_norm', position='rope',
+                       qk_norm='head', bias=False, tie_embeddings=True,
+                       ffn='moe', n_dense_layers=2, n_experts=4,
+                       experts_per_token=2, expert_width=32, **common)
+    else:
+        cfg = LMConfig(d_model=128, n_head=2, n_layer=3, d_ff=64,
+                       use_flash_attention=False, **common)
+    before = monitor.counters()
+    _trace_decode_step(cfg)
+    moved = monitor.counter_delta(before)
+    assert {k: n for k, n in moved.items()
+            if k.startswith('paged_decode_attention_form_total')} == \
+        {'paged_decode_attention_form_total{form=%s}' % form: layers}, moved
+    assert moved['fused_kernel_dispatch_total{impl=interpret,mesh=1,'
+                 'op=kv_decode_attention_paged}'] == layers
+    before = monitor.counters()
+    _trace_decode_step(lfm2.lm_config(TOY, 64, False))
+    moved = monitor.counter_delta(before)
+    assert not any(k.startswith('paged_decode_attention_form_total')
+                   for k in moved), moved
 
 
 def test_a_prefix_attention_of_grouped_queries_repeats_no_key():

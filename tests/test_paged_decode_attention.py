@@ -1,8 +1,11 @@
 """kv_decode_attention_paged (ops/kv_cache_ops.py) across its tiers: the
 Pallas kernel of ops/paged_decode_attention.py, through the interpreter,
 against the `off` tier's table-wide gather — on the op alone at a toy, a
-355M-shaped and a 1.3B-shaped case, bitwise slot independence, and a toy
-paged engine whose greedy tokens must not depend on the tier.
+355M-shaped and a 1.3B-shaped case and, for the grouped-query (MXU) body,
+at 2, 4 and 8 queries a K/V head; bitwise slot independence; dead rows
+that hold NaN; the one-query (VPU) body bit for bit what it was before
+the MXU body came (a recorded fixture); and a toy paged engine whose
+greedy tokens must not depend on the tier.
 
 The op is lowered directly (a stand-in ctx/op pair around the registered
 lowering): the tiers differ only inside it, and a program around it would
@@ -51,9 +54,15 @@ def _attend(tier, monkeypatch, q, kc, vc, tables, pos, layer, bs,
     op = _Op(layer=layer, scale=q.shape[-1] ** -0.5, block_size=bs)
     get_op('kv_decode_attention_paged').lower(ctx, op)
     moved = monitor.counter_delta(before)
-    assert moved == {'fused_kernel_dispatch_total{impl=%s,mesh=1,'
-                     'op=kv_decode_attention_paged}'
-                     % (lands_on or tier): 1}, moved
+    landed = lands_on or tier
+    want = {'fused_kernel_dispatch_total{impl=%s,mesh=1,'
+            'op=kv_decode_attention_paged}' % landed: 1}
+    if landed in ('pallas', 'interpret'):
+        # the kernel's body, chosen by the head counts alone
+        Hkv = kc.shape[3] // q.shape[2]
+        want['paged_decode_attention_form_total{form=%s}'
+             % ('mxu' if q.shape[1] > Hkv else 'vpu')] = 1
+    assert moved == want, moved
     return np.asarray(ctx.outs['Out'])
 
 
@@ -62,34 +71,47 @@ def _pools(rng, nb, ln, bs, hd):
             rng.randn(nb, ln, bs, hd).astype('float32'))
 
 
-# (S, H, bs, dh, MB): toy; fairseq-dense 355M's heads, block and table at
-# fewer slots; 1.3B's; OLMoE's 16 heads of 128 (a head takes a whole vreg:
-# the kernel's `heads is None` branch) at a shorter table. Pools of 40
-# blocks, 2 layers.
-SHAPES = [(6, 2, 8, 64, 4), (6, 16, 16, 64, 48), (6, 32, 16, 64, 66),
-          (6, 16, 16, 128, 12)]
+# (S, H, Hkv, bs, dh, MB): toy; fairseq-dense 355M's heads, block and table
+# at fewer slots; 1.3B's; OLMoE's 16 heads of 128 (a head takes a whole
+# vreg: the kernel's `heads is None` branch) at a shorter table; then
+# grouped queries, the MXU body: LFM2's 32 on 8 heads of 64 in pages of 32
+# (a table of 20 = 640 keys, a window of 512 and a short one), 2 queries a
+# K/V head, and 8 with heads of 128. Pools of 40 blocks, 2 layers.
+SHAPES = [(6, 2, 2, 8, 64, 4), (6, 16, 16, 16, 64, 48),
+          (6, 32, 32, 16, 64, 66), (6, 16, 16, 16, 128, 12),
+          (8, 32, 8, 32, 64, 20), (8, 16, 8, 16, 64, 40),
+          (8, 16, 2, 16, 128, 36)]
 
 
-@pytest.mark.parametrize('S,H,bs,dh,MB', SHAPES,
-                         ids=['toy', 'fd355m', 'fd1.3b', 'olmoe-head128'])
-def test_interpret_tier_matches_off_tier(monkeypatch, S, H, bs, dh, MB):
-    assert pda.shapes_ok(H, dh, bs)
+@pytest.mark.parametrize('S,H,Hkv,bs,dh,MB', SHAPES,
+                         ids=['toy', 'fd355m', 'fd1.3b', 'olmoe-head128',
+                              'lfm2-g4', 'g2', 'g8-head128'])
+def test_interpret_tier_matches_off_tier(monkeypatch, S, H, Hkv, bs, dh, MB):
+    """One tolerance for both bodies: the MXU body's two products are
+    committed at `Precision.HIGHEST` (float32 operands whole, as the VPU
+    body multiplies them; the interpreter's are float32 too), so what is
+    left against the gather is summation order, here as there."""
+    assert pda.shapes_ok(H, dh, bs, Hkv)
+    assert pda.form(H, Hkv) == ('mxu' if H > Hkv else 'vpu')
     rng = np.random.RandomState(S * H + MB)
     nb, ln, layer = 40, 2, 1
-    kc, vc = _pools(rng, nb, ln, bs, H * dh)
+    kc, vc = _pools(rng, nb, ln, bs, Hkv * dh)
     q = rng.randn(S, H, dh).astype('float32')
     tables = rng.randint(1, nb, size=(S, MB)).astype('int32')
     # slot 0 at position 0; 1 on a page's last row; 2 on the next page's
     # first row; 3 fills the table; 4 is idle (all-zero row -> the trash
-    # block, position 0); 5 shares slot 3's leading pages
-    pos = np.array([0, bs - 1, bs, MB * bs - 1, 0, 2 * bs + 3], 'int32')
+    # block, position 0); 5 shares slot 3's leading pages; grouped
+    # queries: 6 on a window's last key, 7 on the next window's first
+    pos = np.array([0, bs - 1, bs, MB * bs - 1, 0, 2 * bs + 3,
+                    pda._WINDOW_KEYS - 1, pda._WINDOW_KEYS][:S], 'int32')
+    assert pos.max() < MB * bs
     tables[4] = 0
     tables[5, :2] = tables[3, :2]
     args = (q, kc, vc, tables, pos, layer, bs)
     off = _attend('off', monkeypatch, *args)
     got = _attend('interpret', monkeypatch, *args)
     np.testing.assert_allclose(got, off, rtol=2e-5, atol=2e-6)
-    if (S, H, bs, dh, MB) == SHAPES[0]:
+    if (S, H, Hkv, bs, dh, MB) == SHAPES[0]:
         np.testing.assert_allclose(_attend('xla', monkeypatch, *args), off,
                                    rtol=2e-5, atol=2e-6)
 
@@ -97,6 +119,11 @@ def test_interpret_tier_matches_off_tier(monkeypatch, S, H, bs, dh, MB):
 def test_shapes_the_kernel_refuses_fall_to_xla(monkeypatch):
     # a head's 24 lanes would straddle vregs; 4-row pages are no tile
     assert not pda.shapes_ok(2, 24, 8) and not pda.shapes_ok(2, 64, 4)
+    # grouped queries: 12 query rows fill no whole sublanes, pages of 24
+    # rows no window, and two windows of 16 K/V heads of 128 no ring
+    assert pda.shapes_ok(32, 64, 32, 8) and not pda.shapes_ok(12, 64, 16, 4)
+    assert not pda.shapes_ok(32, 64, 24, 8)
+    assert pda.shapes_ok(64, 128, 16, 8) and not pda.shapes_ok(64, 128, 16, 16)
     rng = np.random.RandomState(0)
     kc, vc = _pools(rng, 6, 1, 4, 2 * 16)
     q = rng.randn(2, 2, 16).astype('float32')
@@ -108,20 +135,26 @@ def test_shapes_the_kernel_refuses_fall_to_xla(monkeypatch):
         _attend('off', monkeypatch, *args), rtol=2e-5, atol=2e-6)
 
 
-def test_a_slot_is_bitwise_independent_of_its_neighbours(monkeypatch):
-    S, H, bs, dh, MB = 5, 4, 16, 64, 6
+@pytest.mark.parametrize('H,Hkv,bs,MB,pos2', [
+    (4, 4, 16, 6, 2 * 16 + 5), (32, 8, 32, 20, 17 * 32 + 5)],
+    ids=['one-query', 'grouped-g4-two-windows'])
+def test_a_slot_is_bitwise_independent_of_its_neighbours(monkeypatch, H, Hkv,
+                                                         bs, MB, pos2):
+    S, dh = 5, 64
     rng = np.random.RandomState(3)
     nb = 30
-    kc, vc = _pools(rng, nb, 2, bs, H * dh)
+    kc, vc = _pools(rng, nb, 2, bs, Hkv * dh)
     q = rng.randn(S, H, dh).astype('float32')
     tables = rng.randint(1, nb, size=(S, MB)).astype('int32')
-    pos = np.array([7, 40, 2 * bs + 5, 95, 16], 'int32')
+    tables[2] = 1 + rng.permutation(nb - 1)[:MB]        # no page twice
+    pos = np.array([7, 40, pos2, 95, 16], 'int32')
     full = _attend('interpret', monkeypatch, q, kc, vc, tables, pos, 0, bs)
 
     # slot 2 alone, in another row of the batch, its unused table entries
     # on other blocks, and every block it does not read filled with
     # garbage: not a bit of its output may move
-    used = tables[2, :3]
+    n_used = pos2 // bs + 1
+    used = tables[2, :n_used]
     kc2, vc2 = kc.copy(), vc.copy()
     spare = np.setdiff1d(np.arange(nb), used)
     kc2[spare] = 1e30
@@ -129,11 +162,79 @@ def test_a_slot_is_bitwise_independent_of_its_neighbours(monkeypatch):
     q1 = np.zeros_like(q[:2])
     q1[1] = q[2]
     t1 = np.zeros((2, MB), 'int32')
-    t1[1, :3] = used
-    t1[1, 3:] = spare[:MB - 3]
+    t1[1, :n_used] = used
+    t1[1, n_used:] = spare[:MB - n_used]
     p1 = np.array([0, pos[2]], 'int32')
     alone = _attend('interpret', monkeypatch, q1, kc2, vc2, t1, p1, 0, bs)
     np.testing.assert_array_equal(alone[1], full[2])
+
+
+@pytest.mark.parametrize('H,Hkv,bs,planted', [
+    (4, 4, 16, 'k'), (32, 8, 32, 'k-and-v')],
+    ids=['one-query-k', 'grouped-g4-k-and-v'])
+def test_a_row_past_the_position_has_weight_exactly_zero(monkeypatch, H, Hkv,
+                                                         bs, planted):
+    """NaN in the rows past the position of a slot's last page: the output
+    is, bit for bit, what it is with zeros there. The MXU body zeroes the
+    dead V rows in its ring before the product (0 x NaN would be NaN);
+    the VPU body multiplies a weight of exactly 0 by the row, as it always
+    did, so only its keys may hold anything."""
+    S, dh, MB, nb = 3, 64, 4, 16
+    rng = np.random.RandomState(11)
+    kc, vc = _pools(rng, nb, 2, bs, Hkv * dh)
+    q = rng.randn(S, H, dh).astype('float32')
+    tables = (1 + np.arange(S * MB).reshape(S, MB) % (nb - 1)).astype('int32')
+    pos = np.array([5, bs + 3, 3 * bs + bs // 2], 'int32')
+    clean, dirty = [], []
+    for fill, into in ((0.0, clean), (np.nan, dirty)):
+        k2, v2 = kc.copy(), vc.copy()
+        for s_ in range(S):
+            last = tables[s_, pos[s_] // bs]
+            k2[last, :, pos[s_] % bs + 1:] = fill
+            if planted == 'k-and-v':
+                v2[last, :, pos[s_] % bs + 1:] = fill
+        into.append(_attend('interpret', monkeypatch, q, k2, v2, tables, pos,
+                            1, bs))
+    assert np.isfinite(dirty[0]).all()
+    np.testing.assert_array_equal(dirty[0], clean[0])
+
+
+# (S, H, bs, dh, MB, seed): heads of 64 (two a vreg) and of 128 (the
+# `heads is None` branch)
+G1_FIXTURE = [(5, 4, 16, 64, 6, 36), (4, 2, 8, 128, 5, 37)]
+
+
+def _g1_fixture_inputs(S, H, bs, dh, MB, seed):
+    rng = np.random.RandomState(seed)
+    nb = 24
+    kc, vc = _pools(rng, nb, 2, bs, H * dh)
+    q = rng.randn(S, H, dh).astype('float32')
+    tables = rng.randint(1, nb, size=(S, MB)).astype('int32')
+    pos = rng.randint(0, MB * bs, size=S).astype('int32')
+    pos[0], pos[-1] = 0, MB * bs - 1
+    return q, kc, vc, tables, pos
+
+
+@pytest.mark.parametrize('case', range(len(G1_FIXTURE)),
+                         ids=['head64', 'head128'])
+def test_the_one_query_body_is_bit_for_bit_the_parents(monkeypatch, case):
+    """`G == 1` keeps the body it had, operation for operation:
+    fixtures/paged_decode_attention_g1_pr35.json holds the outputs of PR
+    35's kernel (interpreted, on these seeded inputs) as float32 bytes."""
+    import json
+    import os
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           'fixtures',
+                           'paged_decode_attention_g1_pr35.json')) as f:
+        rec = json.load(f)['cases'][case]
+    shape = G1_FIXTURE[case]
+    assert rec['shape'] == list(shape)
+    q, kc, vc, tables, pos = _g1_fixture_inputs(*shape)
+    got = _attend('interpret', monkeypatch, q, kc, vc, tables, pos, 1,
+                  shape[2])
+    want = np.frombuffer(bytes.fromhex(rec['out_f32_hex']),
+                         '<f4').reshape(got.shape)
+    np.testing.assert_array_equal(got, want)
 
 
 def _toy_engine():
@@ -245,7 +346,9 @@ def test_mosaic_accepts_the_grouped_query_kernel_at_its_cells_shapes(
     """lfm2-serve-agent64: 64 slots, 32 query heads on 8 K/V heads of 64,
     pages of 32 rows x 512 lanes, tables of 160 entries (the kernel's
     largest so far), a pool of 4 096 blocks over the 2 attention layers.
-    A page is copied once for the four queries of each of its heads."""
+    A page is copied once for the four queries of each of its heads, and
+    the body is the MXU's: a block-diagonal [32, 512] query matrix against
+    windows of 16 pages (PR 36)."""
     import jax
     S, H, Hkv, dh, MB, NB, ln, bs, layer = 64, 32, 8, 64, 160, 4096, 2, 32, 1
 
@@ -258,7 +361,7 @@ def test_mosaic_accepts_the_grouped_query_kernel_at_its_cells_shapes(
         return kc, pda.paged_decode_attention(q, kc, vc, tables, pos, layer,
                                               scale=dh ** -0.5)
 
-    assert pda.shapes_ok(H, dh, bs, Hkv)
+    assert pda.shapes_ok(H, dh, bs, Hkv) and pda.form(H, Hkv) == 'mxu'
     assert not pda.shapes_ok(H, dh, bs, 5) and not pda.shapes_ok(2, dh, bs, 1)
     pool = sds((NB, ln, bs, Hkv * dh))
     c = jax.jit(step, donate_argnums=0).lower(
@@ -306,10 +409,10 @@ def test_mosaic_accepts_the_latent_kernel_at_its_cells_shapes(one_chip):
             sds((S, MB), jnp.int32), sds((S,), jnp.int32)).compile()
 
 
-def _compile_serving_program(build, fetch, rows, one_chip):
+def _serving_program(build, fetch, rows, sharding=None):
     """A serving program (`build()` -> its vars) lowered by
-    core.lowering.build_fn and compiled for the described chip at `rows`
-    rows a feed, the cache pools donated: shapes in, nothing executed."""
+    core.lowering.build_fn: the raw function and its arguments as shapes,
+    `rows` rows a feed, the cache pools the written state."""
     import jax
     from paddle_tpu import unique_name
     from paddle_tpu.core.lowering import build_fn
@@ -325,7 +428,7 @@ def _compile_serving_program(build, fetch, rows, one_chip):
         dt = jnp.dtype(str(var.dtype))
         return jax.ShapeDtypeStruct(
             tuple(rows if s < 0 else s for s in var.shape),
-            jnp.int32 if dt == jnp.int64 else dt, sharding=one_chip)
+            jnp.int32 if dt == jnp.int64 else dt, sharding=sharding)
     state = [x.name for x in block.vars.values() if x.persistable]
     fn, ro, rw = build_fn(main, [v[fetch].name], state,
                           [KV_CACHE_K, KV_CACHE_V, CONV_CACHE])
@@ -334,11 +437,18 @@ def _compile_serving_program(build, fetch, rows, one_chip):
     feeds = {n: x for n, x in block.vars.items()
              if n.startswith('gen_') and not x.persistable
              and n not in written}
-    return jax.jit(fn, donate_argnums=2).lower(
-        {n: sds(x) for n, x in feeds.items()},
-        {n: sds(block.var(n)) for n in ro},
-        {n: sds(block.var(n)) for n in rw},
-        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)).compile()
+    return fn, ({n: sds(x) for n, x in feeds.items()},
+                {n: sds(block.var(n)) for n in ro},
+                {n: sds(block.var(n)) for n in rw},
+                jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding))
+
+
+def _compile_serving_program(build, fetch, rows, one_chip):
+    """`_serving_program` compiled for the described chip, the cache pools
+    donated: shapes in, nothing executed."""
+    import jax
+    fn, args = _serving_program(build, fetch, rows, one_chip)
+    return jax.jit(fn, donate_argnums=2).lower(*args).compile()
 
 
 @pytest.mark.parametrize('program', ['decode_step', 'prefill_b64'])
