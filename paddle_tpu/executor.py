@@ -544,7 +544,6 @@ class BoundProgram(object):
     def __call__(self, feed, return_numpy=True):
         entry = self._entry
         scope = self._scope
-        monitor.inc('executor_bound_run_total')
         ro_state, rw_state = {}, {}
         exe = self._exe
         # _state_value, not a bare scope.get: it raises the clear
@@ -694,8 +693,8 @@ class StepFuture(object):
     def result(self, return_numpy=True):
         """The step's fetch list. Blocks until complete; raises the
         step's error if it failed. ``return_numpy=True`` materializes
-        host-side (counted into ``fetch_host_bytes``, like ``run``);
-        ``return_numpy=False`` returns the device arrays."""
+        host-side, like ``run``; ``return_numpy=False`` returns the
+        device arrays."""
         self.wait()
         if self._error is not None:
             raise self._error
@@ -706,20 +705,8 @@ class StepFuture(object):
             return [_fetched(f.arr, f.lod) if isinstance(f, _DeferredFetch)
                     else f for f in self._outs]
         t_sync = time.perf_counter()
-        out, host_bytes = [], 0
-        for f in self._outs:
-            if isinstance(f, _DeferredFetch):
-                a = _fetched(f.arr, f.lod)
-                host_bytes += int(a.nbytes)
-                out.append(a)
-            elif isinstance(f, np.ndarray):
-                out.append(f)
-            else:
-                a = np.asarray(f)
-                host_bytes += int(a.nbytes)
-                out.append(a)
-        if host_bytes:
-            monitor.inc('fetch_host_bytes', host_bytes)
+        out = [_fetched(f.arr, f.lod) if isinstance(f, _DeferredFetch)
+               else np.asarray(f) for f in self._outs]
         if self._sync_s is None:
             self._sync_s = time.perf_counter() - t_sync
         return out
@@ -1374,15 +1361,11 @@ class Executor(object):
                        for f in fetches]
         if return_numpy:
             with _run_phase('fetch'):
-                out = [
+                return [
                     _fetched(f, entry.lod_out[n])
                     if entry.lod_out.get(n) else np.asarray(f)
                     for n, f in zip(entry.fetch_names, fetches)
                 ]
-                if out:
-                    monitor.inc('fetch_host_bytes', sum(
-                        int(getattr(f, 'nbytes', 0)) for f in out))
-                return out
         # return_numpy=False keeps fetches device-resident (no host sync);
         # only lod-carrying results are wrapped, since the LoD metadata is
         # the point of asking for them. Under async dispatch the wrap is
@@ -1900,11 +1883,7 @@ class Executor(object):
             with scope_guard(scope):
                 save_persistables(self, cn_dir, main_program=program)
         if return_numpy:
-            out = [np.asarray(f) for f in fetches]
-            if out:
-                monitor.inc('fetch_host_bytes',
-                            sum(int(f.nbytes) for f in out))
-            return out
+            return [np.asarray(f) for f in fetches]
         return list(fetches)
 
     # ------------------------------------------------------------------

@@ -479,9 +479,10 @@ class _Phase(object):
     metrics read; while open it is a TraceAnnotation 'paddle_tpu:<name>'
     in a live profiler session, where phases nest as they are. Nothing
     goes to the span ring: a decode loop's phases would churn it in under
-    a minute. Single-use, like _Span."""
+    a minute. Single-use, like _Span; `dur_s`, set on exit, is its whole
+    duration for a caller that books it elsewhere too."""
 
-    __slots__ = ('known', 't0', 'nested_s', '_outer', '_ta')
+    __slots__ = ('known', 't0', 'nested_s', 'dur_s', '_outer', '_ta')
 
     def __init__(self, known):
         self.known = known     # the phase's entry in _phase_series
@@ -496,7 +497,7 @@ class _Phase(object):
         return self
 
     def __exit__(self, *exc):
-        dur_s = time.perf_counter() - self.t0
+        dur_s = self.dur_s = time.perf_counter() - self.t0
         if self._ta is not None:
             self._ta.__exit__(None, None, None)
         outer = self._outer

@@ -555,15 +555,11 @@ class ServingEngine(object):
         # batch-level fetches (no padded leading dim) are shared whole by
         # every request in the batch: materialize them host-side ONCE
         # here, not once per request in _slice_result
-        shared_bytes = 0
         for i, o in enumerate(outs):
             if not (getattr(o, 'ndim', 0) and
                     getattr(o, 'shape', (None,))[0] == padded_rows) \
                     and not isinstance(o, np.ndarray):
                 outs[i] = np.asarray(o)
-                shared_bytes += int(outs[i].nbytes)
-        if shared_bytes:
-            monitor.inc('fetch_host_bytes', shared_bytes)
         off = 0
         for r in batch:
             # per-request delivery is individually guarded: one request
@@ -627,7 +623,6 @@ class ServingEngine(object):
         own rows materialized — previously every request pulled the whole
         padded batch host-side per fetch."""
         out = []
-        host_bytes = 0
         for o in outs:
             a = o
             if getattr(a, 'ndim', 0) and a.shape[0] == padded_rows:
@@ -645,12 +640,7 @@ class ServingEngine(object):
                 # loop, once per batch) — only this request's own sliced
                 # rows cross here
                 a = np.asarray(a)
-                host_bytes += int(a.nbytes)
             out.append(a)
-        if host_bytes:
-            # the executor no longer counts these (return_numpy=False on
-            # the batched run); the engine counts what actually crossed
-            monitor.inc('fetch_host_bytes', host_bytes)
         return out
 
 

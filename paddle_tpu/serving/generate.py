@@ -139,6 +139,9 @@ __all__ = ['GenerateConfig', 'GenerateEngine', 'GenerateRequest',
            'GenerateResult']
 
 _DONE = object()
+# generate_token_gap*_total{held=...}: a delivered token gap inside which
+# another request's admission completed, or none did (`_deliver`)
+_HELD, _PLAIN = {'held': 'admission'}, {'held': 'none'}
 
 def _loop_phase(name):
     """Phase `name` of the decode loop thread (monitor.phase): its self
@@ -195,7 +198,9 @@ class GenerateResult(list):
     - ``timing``: the request's latency budget — ``queue_s``,
       ``prefill_s``, ``decode_step_s`` (sum over steps), ``total_s``,
       ``tokens``, ``step_s_mean`` / ``step_s_p99`` (per-token decode
-      gaps), and the ``trace_id`` joining it to the trace log
+      gaps), ``admission_wait_s`` / ``admissions_waited`` (of those
+      gaps, the seconds and the number that held another request's
+      admission), and the ``trace_id`` joining it to the trace log
       (docs/observability.md).
     """
 
@@ -337,7 +342,8 @@ class GenerateRequest(Request):
 
     __slots__ = ('prompt', 'max_new_tokens', 'tokens', 'finish_reason',
                  'step_s', '_stream_q', 'temperature', 'top_k', 'top_p',
-                 'sample_seed', '_rng', 'spec_proposed', 'spec_accepted')
+                 'sample_seed', '_rng', 'spec_proposed', 'spec_accepted',
+                 'admission_wait_s', 'admissions_waited')
 
     def __init__(self, prompt, seq_len, bucket, deadline, max_new_tokens,
                  temperature=0.0, top_k=0, top_p=0.0, sample_seed=None):
@@ -356,6 +362,10 @@ class GenerateRequest(Request):
         self._rng = None
         self.spec_proposed = 0  # draft tokens proposed for this request
         self.spec_accepted = 0  # ... that became emitted tokens
+        # of its token gaps, those that held ANOTHER request's admission:
+        # their seconds and their number (`GenerateEngine._deliver`)
+        self.admission_wait_s = 0.0
+        self.admissions_waited = 0
 
     def _draw_u(self):
         """Next uniform of this request's OWN sampling stream: one host
@@ -385,6 +395,8 @@ class GenerateRequest(Request):
                 srt = sorted(self.step_s)
                 t['step_s_mean'] = sum(srt) / len(srt)
                 t['step_s_p99'] = srt[monitor._rank_idx(0.99, len(srt))]
+            t['admission_wait_s'] = self.admission_wait_s
+            t['admissions_waited'] = self.admissions_waited
             if self.spec_proposed:
                 t['spec_proposed'] = self.spec_proposed
                 t['spec_accepted'] = self.spec_accepted
@@ -427,9 +439,9 @@ class GenerateRequest(Request):
 class _Slot(object):
     __slots__ = ('req', 'pos', 'generated', 'last', 'last_t', 'wall0',
                  'blocks', 'table', 'dblocks', 'dtable', 'draft_stale',
-                 'ahead')
+                 'ahead', 'admit_seq')
 
-    def __init__(self, req, pos, last, blocks, table,
+    def __init__(self, req, pos, last, blocks, table, admit_seq,
                  dblocks=None, dtable=None):
         self.req = req
         self.pos = pos          # cache position the NEXT step writes
@@ -441,6 +453,9 @@ class _Slot(object):
         # still on the device when ahead is 1
         self.ahead = 0
         self.last_t = time.perf_counter()   # previous token's completion
+        # the engine's count of admissions at that token, its own
+        # included: moved by the next token, the gap held an admission
+        self.admit_seq = admit_seq
         self.wall0 = time.time() * 1e6      # decode-phase start (us)
         self.blocks = blocks    # physical block ids, table order
         self.table = table      # np [max_blocks] int64, filler 0
@@ -588,6 +603,7 @@ class GenerateEngine(object):
         self._fetched_t = 0.0   # when the last step's fetch came back
         # since then: what admissions waited for their prefills alone
         self._prefill_alone_s = 0.0
+        self._admit_seq = 0     # admissions completed (`_Slot.admit_seq`)
         self._decode_steps = 0
         self._sampled_steps = 0
         self._overlapped_steps = 0
@@ -1490,8 +1506,9 @@ class GenerateEngine(object):
         monitor.inc('decode_tokens_total')
         self._decode_tokens += 1
         req._emit(first)
+        self._admit_seq += 1
         st = _Slot(req, pos=req.prompt.size, last=first,
-                   blocks=blocks, table=table,
+                   blocks=blocks, table=table, admit_seq=self._admit_seq,
                    dblocks=dblocks, dtable=dtable)
         reason = self._finish_reason(st)
         if reason:
@@ -1548,21 +1565,25 @@ class GenerateEngine(object):
 
     def _prefill_call(self, bound, feed):
         """One prefill dispatch and the fetch of its token, behind the
-        decode steps in flight as the device runs them. The loop thread
-        sees those complete on the way: what it waits from there on is
-        the prefill's time alone and none of a step's (`_observe_step`)."""
-        out = bound(feed, return_numpy=False)
-        seen = None
-        if self._flights:
-            for flight in self._flights:
-                try:
-                    flight.out[0].block_until_ready()
-                except Exception:   # noqa: BLE001 — raised at its fetch
-                    pass
-            seen = time.perf_counter()
-        tokens = self._split_load(out[0], 1)
-        if seen is not None:
-            self._prefill_alone_s += time.perf_counter() - seen
+        decode steps in flight as the device runs them: three phases
+        inside `prefill`. `prefill.dispatch` is the host's bound call,
+        `prefill.drain` the wait for the steps in flight, and
+        `prefill.fetch`, from there to the token on the host, is the
+        prefill's time alone and none of a step's (`_observe_step`)."""
+        with _loop_phase('prefill.dispatch'):
+            out = bound(feed, return_numpy=False)
+        behind = bool(self._flights)
+        if behind:
+            with _loop_phase('prefill.drain'):
+                for flight in self._flights:
+                    try:
+                        flight.out[0].block_until_ready()
+                    except Exception:   # noqa: BLE001 — raised at its fetch
+                        pass
+        with _loop_phase('prefill.fetch') as alone:
+            tokens = self._split_load(out[0], 1)
+        if behind:
+            self._prefill_alone_s += alone.dur_s
         return tokens
 
     def _step(self):
@@ -1848,6 +1869,9 @@ class GenerateEngine(object):
             self._spec_truncate(st)
             dt = max(0.0, now - st.last_t)
             st.last_t = now
+            # a round's gaps are in no generate_token_gap* series; the
+            # slot's count stays that of its last token
+            st.admit_seq = self._admit_seq
             if r.trace is not None:
                 # draft/verify are SUB-stages of the decode wall: the
                 # residual host time stays in decode_step so the stage
@@ -2084,6 +2108,11 @@ class GenerateEngine(object):
         self._occ_sum += n / float(self.config.slots)
         monitor.inc('decode_tokens_total', n)
         speculative = self.config.speculative
+        # every token gap by whether an admission completed inside it:
+        # the engine's count moved since the row's last token
+        seq = self._admit_seq
+        held_s = plain_s = 0.0
+        held_n = 0
         for i, st in live:
             st.ahead -= 1
             st.pos += 1
@@ -2098,6 +2127,14 @@ class GenerateEngine(object):
             # prefill + decode sums to its end-to-end latency
             dt = max(0.0, now - st.last_t)
             st.last_t = now
+            if st.admit_seq != seq:
+                st.admit_seq = seq
+                held_s += dt
+                held_n += 1
+                st.req.admission_wait_s += dt
+                st.req.admissions_waited += 1
+            else:
+                plain_s += dt
             if st.req.trace is not None:
                 st.req.trace.add_stage('decode_step', dt)
                 st.req.step_s.append(dt)
@@ -2113,6 +2150,12 @@ class GenerateEngine(object):
                                         sum(st.req.step_s) * 1e6,
                                         trace=st.req.trace)
                 st.req._finish(reason)
+        if held_n:
+            monitor.inc('generate_token_gap_seconds_total', held_s, _HELD)
+            monitor.inc('generate_token_gaps_total', held_n, _HELD)
+        if n > held_n:
+            monitor.inc('generate_token_gap_seconds_total', plain_s, _PLAIN)
+            monitor.inc('generate_token_gaps_total', n - held_n, _PLAIN)
         self._set_occupancy()
 
     def _finish_reason(self, st):

@@ -68,6 +68,33 @@ def test_the_split_follows_the_innermost_span_through_a_gap():
         rep['idle_s'] - rep['short_gaps_s'])
 
 
+def test_under_a_span_idle_and_busy_by_module():
+    rep = gapreport.report(TRACE, min_gap_ns=15)
+    # prepare [18,32): train's ops to 20 and from 30, the gap between;
+    # fetch [45,55) is all gap; run's own [32,45) [55,78): train to 40,
+    # program [60,70); before and after the run: train to 18, idle from 78
+    assert rep['under'] == {
+        'run.prepare': {'open_s': pytest.approx(14e-9),
+                        'idle_s': pytest.approx(10e-9),
+                        'busy': {'jit_lm_train': pytest.approx(4e-9)}},
+        'run.fetch': {'open_s': pytest.approx(10e-9),
+                      'idle_s': pytest.approx(10e-9), 'busy': {}},
+        'run': {'open_s': pytest.approx(36e-9),
+                'idle_s': pytest.approx(18e-9),
+                'busy': {'jit_lm_train': pytest.approx(8e-9),
+                         'jit_program': pytest.approx(10e-9)}},
+        'none': {'open_s': pytest.approx(40e-9),
+                 'idle_s': pytest.approx(22e-9),
+                 'busy': {'jit_lm_train': pytest.approx(18e-9)}}}
+    # the whole window, once: short gaps too
+    assert sum(r['open_s'] for r in rep['under'].values()) \
+        == pytest.approx(rep['window_s'])
+    assert sum(r['idle_s'] for r in rep['under'].values()) \
+        == pytest.approx(rep['idle_s'])
+    assert sum(sum(r['busy'].values()) for r in rep['under'].values()) \
+        == pytest.approx(rep['busy_s'])
+
+
 def test_timeline_is_the_open_span_that_started_last():
     segs = gapreport.timeline([('outer', 0, 100), ('a', 10, 20),
                                ('b', 40, 5), ('late', 120, 10)])
@@ -84,7 +111,8 @@ def test_timeline_is_the_open_span_that_started_last():
 def test_render_names_every_row():
     text = gapreport.render(gapreport.report(TRACE, min_gap_ns=5), 5e-6)
     for word in ('run.prepare', 'run.fetch', 'none', 'jit_lm_train',
-                 'at their middle: 50.0 %'):
+                 'at their middle: 50.0 %', 'under each span',
+                 'jit_program 0.0000, jit_lm_train 0.0000'):
         assert word in text
 
 
@@ -117,3 +145,9 @@ def test_a_trace_recorded_on_the_v5e():
                            'jit_lm_prefill_paged_b768': 1}
     assert rep['busy']['jit_lm_prefill_paged_b768'] == \
         pytest.approx(0.0619, abs=1e-4)
+    # the prefill ran under the loop's `prefill` phase and nowhere else
+    # (a trace from before the phases nested in it)
+    assert rep['under']['generate.prefill']['busy'][
+        'jit_lm_prefill_paged_b768'] == pytest.approx(0.0619, abs=1e-4)
+    assert sum(r['open_s'] for r in rep['under'].values()) \
+        == pytest.approx(rep['window_s'])

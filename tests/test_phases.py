@@ -2,7 +2,9 @@
 Executor.run's phase counters add up to the wall time they split, spans
 and phases land in a profiler trace as `paddle_tpu:<name>`, compiled
 programs carry their program's name, and a phase costs little with no
-profiler session."""
+profiler session. Since PR 37 also the admission taken apart: the three
+phases nested in `prefill`, and every delivered token gap booked by
+whether another request's admission completed inside it."""
 import gc
 import glob
 import os
@@ -18,9 +20,11 @@ from paddle_tpu import monitor
 from paddle_tpu.core import lowering
 from paddle_tpu.models.transformer import LMConfig, build_lm_decode_step
 from paddle_tpu.serving import GenerateConfig, GenerateEngine
+from paddle_tpu.serving.generate import _Flight
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOOP = 'generate_loop_seconds_total'
+ADMISSION = ('prefill', 'prefill.dispatch', 'prefill.drain', 'prefill.fetch')
 RUN = 'executor_run_phase_seconds_total'
 
 
@@ -114,15 +118,20 @@ def test_loop_phases_add_up_to_the_loops_wall_time():
     delta = monitor.counter_delta(before)
     phases = _phases(delta, LOOP)
     wall = delta['generate_loop_wall_seconds_total']
-    assert {'admit', 'prefill', 'feed', 'dispatch', 'admit_overlapped',
+    assert {'admit', 'prefill', 'prefill.dispatch', 'prefill.drain',
+            'prefill.fetch', 'feed', 'dispatch', 'admit_overlapped',
             'wait', 'deliver', 'yield', 'idle'} <= set(phases)
     # self times: nothing counts twice, and little is left uncovered
     assert sum(phases.values()) <= wall * 1.001
     assert sum(phases.values()) >= wall * 0.95
-    # `admit` holds admissions without their prefills: those are `prefill`,
-    # the stretch prefill_seconds times
+    # `admit` holds admissions without their prefills: those are `prefill`
+    # and the three phases nested in it, the stretch prefill_seconds times
     prefill_s = _hist_sum('prefill_seconds') - prefill0
-    assert phases['prefill'] == pytest.approx(prefill_s, rel=0.2)
+    parts = [phases[p] for p in ADMISSION]
+    assert sum(parts) == pytest.approx(prefill_s, rel=0.2)
+    # the bound call, the wait for the steps in flight and the prefill
+    # alone are nearly all of it: `prefill` keeps only the feed's making
+    assert min(parts) > 0 and phases['prefill'] < 0.5 * sum(parts)
     assert phases['admit'] + phases['admit_overlapped'] < wall - prefill_s
     assert delta['generate_admit_total'] == len(work)
     assert delta['generate_queue_wait_seconds_total'] > 0
@@ -131,6 +140,161 @@ def test_loop_phases_add_up_to_the_loops_wall_time():
     assert loop['admitted'] == flat['generate_admit_total']
     assert loop['wall_s'] == flat['generate_loop_wall_seconds_total']
     assert loop['phase_s']['feed'] == flat[LOOP + '{phase=feed}']
+
+
+class _StepInFlight(object):
+    """A decode step's first output, done `seconds` after it is asked."""
+
+    def __init__(self, seconds):
+        self.seconds = seconds
+
+    def block_until_ready(self):
+        time.sleep(self.seconds)
+
+
+def test_prefill_drain_is_the_wait_for_the_steps_in_flight():
+    """Nothing in flight: the bound call and the fetch, and the drain's
+    counter does not move. A step in flight: the drain is the wait for
+    it, and what `decode_step_seconds` leaves out is the fetch phase's
+    duration, the prefill alone."""
+    eng = _engine()
+    eng.warmup()
+    prompt = _prompt(5, 1)
+    table = eng._slot_table(eng._alloc_blocks(
+        -(-prompt.size // eng.config.block_size)))
+    before = monitor.counters()
+    first = eng._run_prefill(prompt, table)
+    alone = _phases(monitor.counter_delta(before), LOOP)
+    assert set(alone) == {'prefill.dispatch', 'prefill.fetch'}
+    assert min(alone.values()) > 0 and eng._prefill_alone_s == 0.0
+    eng._flights = [_Flight([_StepInFlight(0.03)], [], 0.0, False)]
+    before = monitor.counters()
+    assert eng._run_prefill(prompt, table) == first
+    behind = _phases(monitor.counter_delta(before), LOOP)
+    eng._flights = []
+    assert set(behind) == set(ADMISSION) - {'prefill'}
+    assert behind['prefill.drain'] == pytest.approx(0.03, abs=0.01)
+    assert eng._prefill_alone_s == pytest.approx(behind['prefill.fetch'],
+                                                 rel=1e-6)
+
+
+def test_a_chunked_prefill_books_every_chunk():
+    """A prompt wider than the widest bucket prefills in chunks, each
+    through `_prefill_call`: the three dispatches of a 40-token prompt
+    (16 + 16 + 8) are all inside the two phases."""
+    eng = _engine()
+    eng.warmup()
+    calls = []
+    bound = {b: (lambda feed, return_numpy, _b=b, _f=f:
+                 calls.append(_b) or _f(feed, return_numpy=return_numpy))
+             for b, f in eng._prefill_bound.items()}
+    prompt = _prompt(40, 2)
+    table = eng._slot_table(eng._alloc_blocks(
+        -(-prompt.size // eng.config.block_size)))
+    before = monitor.counters()
+    eng._run_prefill(prompt, table, bound=bound)
+    got = _phases(monitor.counter_delta(before), LOOP)
+    assert calls == [16, 16, 8]
+    assert set(got) == {'prefill.dispatch', 'prefill.fetch'}
+
+
+def _drive(eng, *reqs):
+    """The loop's pass inline, no thread: a step, then admission."""
+    while any(r.finish_reason is None for r in reqs):
+        eng._step()
+        eng._admit()
+
+
+def _gaps(delta):
+    return {held: (delta.get('generate_token_gaps_total{held=%s}' % held, 0),
+                   delta.get('generate_token_gap_seconds_total{held=%s}'
+                             % held, 0.0))
+            for held in ('admission', 'none')}
+
+
+def test_a_gap_that_held_an_admission_is_booked_apart():
+    """A is resident; B is admitted between A's second and third token.
+    Exactly that gap of A's is `held=admission`; B's own prefill is
+    before B's first gap, which is plain like every other; the request's
+    timing says the same."""
+    eng = _engine()
+    eng.warmup()
+    a = eng.submit(_prompt(6, 1), max_new_tokens=6)
+    eng._admit()
+    before = monitor.counters()
+    eng._step()                                 # A's second token
+    assert _gaps(monitor.counter_delta(before))['admission'] == (0, 0.0)
+    b = eng.submit(_prompt(9, 2), max_new_tokens=4)
+    eng._admit()                                # B's prefill
+    mid = monitor.counters()
+    eng._step()                                 # A's third, B's second
+    one = _gaps(monitor.counter_delta(mid))
+    assert one['admission'][0] == 1 and one['none'][0] == 1
+    assert one['admission'][1] > one['none'][1] > 0     # A's held B's prefill
+    _drive(eng, a, b)
+    got = _gaps(monitor.counter_delta(before))
+    assert len(a.tokens) == 6 and len(b.tokens) == 4
+    # every token a decode step delivered closed one gap
+    assert got['admission'][0] == 1
+    assert got['none'][0] == (6 - 1) + (4 - 1) - 1
+    ta, tb = a.result().timing, b.result().timing
+    assert ta['admissions_waited'] == 1 and tb['admissions_waited'] == 0
+    assert ta['admission_wait_s'] == pytest.approx(got['admission'][1])
+    assert tb['admission_wait_s'] == 0.0
+    assert ta['admission_wait_s'] < ta['decode_step_s']
+
+
+def test_two_admissions_in_one_gap_are_one_gap_and_a_lone_row_has_none():
+    eng = _engine()
+    eng.warmup()
+    before = monitor.counters()
+    a = eng.submit(_prompt(5, 3), max_new_tokens=4)
+    eng._admit()
+    eng._step()
+    b = eng.submit(_prompt(7, 4), max_new_tokens=3)
+    c = eng.submit(_prompt(8, 5), max_new_tokens=3)
+    eng._admit()            # both, one after the other
+    _drive(eng, a, b, c)
+    got = _gaps(monitor.counter_delta(before))
+    # A's third token waited for B and C: one gap. B's first gap held
+    # C's admission, C's own held none
+    assert got['admission'][0] == 2
+    assert a.result().timing['admissions_waited'] == 1
+    assert b.result().timing['admissions_waited'] == 1
+    assert c.result().timing['admissions_waited'] == 0
+    assert got['admission'][0] + got['none'][0] == 3 + 2 + 2
+    lone = eng.submit(_prompt(4, 6), max_new_tokens=5)
+    mid = monitor.counters()
+    eng._admit()
+    _drive(eng, lone)
+    alone = _gaps(monitor.counter_delta(mid))
+    assert alone['admission'] == (0, 0.0) and alone['none'][0] == 4
+
+
+def test_the_loops_gap_counts_add_up_to_its_decode_tokens():
+    """Under the loop thread, whatever the schedule: the gaps counted are
+    the tokens decode steps delivered (every token but a request's
+    first), and the seconds booked per request add up to the counter."""
+    eng = _engine()
+    eng.warmup()
+    work = [(_prompt(4 + i, 10 + i), 5 + i % 4) for i in range(7)]
+    before = monitor.counters()
+    with eng:
+        reqs = [eng.submit(p, max_new_tokens=n) for p, n in work]
+        outs = [r.result(timeout=60) for r in reqs]
+    delta = monitor.counter_delta(before)
+    got = _gaps(delta)
+    assert got['admission'][0] + got['none'][0] \
+        == delta['decode_tokens_total'] - delta['generate_admit_total'] \
+        == sum(n - 1 for _p, n in work)
+    # 7 requests through 4 slots: some admission found a neighbour
+    assert got['admission'][0] >= 1
+    assert sum(o.timing['admissions_waited'] for o in outs) \
+        == got['admission'][0]
+    assert sum(o.timing['admission_wait_s'] for o in outs) \
+        == pytest.approx(got['admission'][1])
+    assert sum(o.timing['decode_step_s'] for o in outs) \
+        == pytest.approx(got['admission'][1] + got['none'][1], rel=1e-3)
 
 
 def test_decode_step_seconds_is_one_observation_a_step_inside_the_wall():
@@ -291,7 +455,9 @@ def test_spans_and_phases_land_in_the_profiler_trace(tmp_path):
     assert {'paddle_tpu:run', 'paddle_tpu:run.prepare',
             'paddle_tpu:run.dispatch', 'paddle_tpu:run.commit',
             'paddle_tpu:run.fetch', 'paddle_tpu:generate.admit',
-            'paddle_tpu:generate.prefill', 'paddle_tpu:generate.feed',
+            'paddle_tpu:generate.prefill',
+            'paddle_tpu:generate.prefill.dispatch',
+            'paddle_tpu:generate.prefill.fetch', 'paddle_tpu:generate.feed',
             'paddle_tpu:generate.dispatch', 'paddle_tpu:generate.wait',
             'paddle_tpu:generate.deliver'} <= names
 

@@ -15,7 +15,13 @@ over the traced window (benchmark.reduce_trace.traced_window):
   own (between two operations of one program) and are summed apart;
 - device BUSY time by XLA module: the `XLA Modules` line of each device
   plane, named after the program (`jit_lm_decode_step`,
-  `jit_lm_prefill_paged_b512`, `jit_lm_train` ...).
+  `jit_lm_prefill_paged_b512`, `jit_lm_train` ...);
+- what the device did UNDER each span, by the innermost span at every
+  instant of the window: the seconds it was open, the device's idle
+  seconds inside it (every gap, the short ones too) and its busy seconds
+  by module — `generate.prefill.drain` should hold the decode step only,
+  `generate.prefill.fetch` the prefill's bucket and then the idle tail
+  that is the fetch's latency.
 
 docs/observability.md "Reading a device trace".
 """
@@ -108,17 +114,23 @@ def report(trace, min_gap_ns=1000000):
     'labelled_share' of it under a span by the first reading. Beside
     them 'window_s', 'busy_s', 'idle_s', 'short_gaps_s' (the gaps below
     min_gap_ns), 'busy' {module: seconds} and 'runs' {module: its runs
-    that touch the window, over all devices}. Seconds are per device,
-    averaged over the devices in the trace."""
+    that touch the window, over all devices}; 'under' {span: {'open_s',
+    'idle_s', 'busy': {module: seconds}}}, the whole window by the
+    innermost span at every instant. Seconds are per device, averaged
+    over the devices in the trace."""
     t0, t1 = rt.traced_window(trace)
     segments = timeline(trace['spans'])
     starts = [a for a, _b, _n in segments]
     n = len(trace['devices'])
     idle, parts, short_ns, busy_ns = {}, {}, 0, 0
+    open_ns = split(segments, starts, t0, t1)   # one clock for all devices
+    idle_under, busy_under = {}, {}
     for _plane, events in sorted(trace['devices'].items()):
         evs = rt.clip(events, t0, t1)
         busy_ns += rt.busy_ns(evs)
         for s, d in rt.idle_gaps(evs, t0, t1):
+            for name, ns in split(segments, starts, s, s + d).items():
+                idle_under[name] = idle_under.get(name, 0) + ns
             if d < min_gap_ns:
                 short_ns += d
                 continue
@@ -138,7 +150,11 @@ def report(trace, min_gap_ns=1000000):
         for name, s, d in rt.clip(events, t0, t1):
             i, inside = bisect.bisect_right(ends, s), 0
             while i < len(ops) and ops[i][0] < s + d:
-                inside += min(ops[i][1], s + d) - max(ops[i][0], s)
+                a, b = max(ops[i][0], s), min(ops[i][1], s + d)
+                inside += b - a
+                for span, ns in split(segments, starts, a, b).items():
+                    busy = busy_under.setdefault(span, {})
+                    busy[name] = busy.get(name, 0) + ns
                 i += 1
             modules[name] = modules.get(name, 0) + inside
             runs[name] = runs.get(name, 0) + 1
@@ -154,6 +170,11 @@ def report(trace, min_gap_ns=1000000):
         if long_ns else None,
         'busy': {k: v / n / 1e9 for k, v in modules.items()},
         'runs': runs,
+        'under': {k: {'open_s': ns / 1e9,
+                      'idle_s': idle_under.get(k, 0) / n / 1e9,
+                      'busy': {m: b / n / 1e9
+                               for m, b in busy_under.get(k, {}).items()}}
+                  for k, ns in open_ns.items()},
     }
 
 
@@ -185,6 +206,15 @@ def render(rep, min_gap_ms):
         out.append('%-40s %10.4f %7.1f%% %6d'
                    % (name, sec, 100.0 * sec / rep['busy_s'],
                       rep['runs'][name]))
+    out += ['', 'under each span, innermost at every instant: seconds open, '
+            'the device idle, busy by module',
+            '%-28s %10s %10s  %s' % ('span', 'open', 'idle', 'busy')]
+    for name, row in sorted(rep['under'].items(),
+                            key=lambda kv: -kv[1]['open_s']):
+        out.append('%-28s %10.4f %10.4f  %s' % (
+            name, row['open_s'], row['idle_s'], ', '.join(
+                '%s %.4f' % kv for kv in sorted(row['busy'].items(),
+                                                key=lambda kv: -kv[1]))))
     return '\n'.join(out)
 
 
