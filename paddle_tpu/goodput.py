@@ -458,9 +458,14 @@ def _drain(block=True):
     a multi-second in-flight step — the completer thread picks up the
     remainder. Records are in dispatch order and one stream executes
     them in order, so stopping at the first unready leaf keeps the
-    serial attribution exact."""
+    serial attribution exact. A blocking drain takes the records queued
+    when it begins and no more: a serving loop that always has a step
+    in flight queues the next record while this one is waited for, and
+    a drain that went on until the queue was empty would not return
+    while such a loop runs (the completer thread takes what comes
+    after)."""
     with _drain_lock:
-        while True:
+        for _ in range(len(_q)):
             try:
                 rec = _q.popleft()
             except IndexError:

@@ -480,7 +480,9 @@ class _Phase(object):
     in a live profiler session, where phases nest as they are. Nothing
     goes to the span ring: a decode loop's phases would churn it in under
     a minute. Single-use, like _Span; `dur_s`, set on exit, is its whole
-    duration for a caller that books it elsewhere too."""
+    duration for a caller that books it elsewhere too. With no counter
+    the phase nests and annotates all the same and adds nothing: its
+    caller books `dur_s - nested_s`, later."""
 
     __slots__ = ('known', 't0', 'nested_s', 'dur_s', '_outer', '_ta')
 
@@ -506,7 +508,8 @@ class _Phase(object):
         else:
             _open_phase[threading.get_ident()] = outer
             outer.nested_s += dur_s
-        _inc_key(self.known[0], self.known[2], dur_s - self.nested_s)
+        if self.known[0] is not None:
+            _inc_key(self.known[0], self.known[2], dur_s - self.nested_s)
         return False
 
 
@@ -516,9 +519,10 @@ _phase_series = {}
 
 def phase(name, counter, labels=None):
     """Phase `name` of a hot path: seconds of self time into
-    `counter{labels}` always, a 'paddle_tpu:<name>' TraceAnnotation while
-    a profiler session is live. Like timed_span, an instrumentation
-    helper and not part of __all__."""
+    `counter{labels}` always (`counter` None: left to the caller), a
+    'paddle_tpu:<name>' TraceAnnotation while a profiler session is live.
+    Like timed_span, an instrumentation helper and not part of
+    __all__."""
     # a phase opens thousands of times a second with the same arguments:
     # its series key and annotation name are made once per name
     known = _phase_series.get(name)

@@ -93,7 +93,11 @@ THE LOOP IS A PIPELINE ONE STEP DEEP (PR 31): with step k dispatched and
 its tokens not fetched, step k + 1 is dispatched on them as they are on
 the device, and step k is fetched, delivered and booked while k + 1
 computes (`GenerateEngine._loop`; ``generate_overlapped_steps_total``,
-``generate_discarded_rows_total``).
+``generate_discarded_rows_total``). AN ADMISSION BLOCKS FOR NOTHING (PR
+38): it dispatches the prefill and returns; the first token stays on the
+device, the row joins the next step on it, and the loop picks it up
+before that step's own fetch (`_pickup`;
+``generate_first_token_carried_total``).
 
 Monitor series: ``decode_tokens_total``, ``kv_slot_occupancy``,
 ``decode_step_seconds``, ``prefill_seconds``,
@@ -143,13 +147,23 @@ _DONE = object()
 # another request's admission completed, or none did (`_deliver`)
 _HELD, _PLAIN = {'held': 'admission'}, {'held': 'none'}
 
-def _loop_phase(name):
+def _loop_phase(name, counted=True):
     """Phase `name` of the decode loop thread (monitor.phase): its self
     time into generate_loop_seconds_total{phase=name}, and a
     'paddle_tpu:generate.<name>' span in a profiler session. The phases
-    and what the device does meanwhile: docs/observability.md."""
-    return monitor.phase('generate.' + name, 'generate_loop_seconds_total',
-                         {'phase': name})
+    and what the device does meanwhile: docs/observability.md. Not
+    `counted`, it is the span and nothing else: an admission's two phases,
+    which `_pickup` books with the rest of the admission."""
+    return monitor.phase('generate.' + name, 'generate_loop_seconds_total'
+                         if counted else None, {'phase': name})
+
+
+def _book_admission(self_s, dispatch_s):
+    """An admission's phases `prefill` and `prefill.dispatch`, their
+    seconds kept since (`_loop_phase(..., counted=False)`)."""
+    monitor.inc('generate_loop_seconds_total', self_s, {'phase': 'prefill'})
+    monitor.inc('generate_loop_seconds_total', dispatch_s,
+                {'phase': 'prefill.dispatch'})
 
 
 def _loop_sums():
@@ -439,14 +453,18 @@ class GenerateRequest(Request):
 class _Slot(object):
     __slots__ = ('req', 'pos', 'generated', 'last', 'last_t', 'wall0',
                  'blocks', 'table', 'dblocks', 'dtable', 'draft_stale',
-                 'ahead', 'admit_seq')
+                 'ahead', 'admit_seq', 'first')
 
-    def __init__(self, req, pos, last, blocks, table, admit_seq,
-                 dblocks=None, dtable=None):
+    def __init__(self, req, pos, blocks, table, dblocks=None, dtable=None):
         self.req = req
         self.pos = pos          # cache position the NEXT step writes
-        self.generated = 1      # prefill already emitted the first token
-        self.last = last        # last generated token (next step's input)
+        self.generated = 1      # the prefill made the first token
+        self.last = None        # last generated token (next step's input)
+        # the prefill's `_First` until the loop picks the first token up
+        # (`_pickup`): `last` is on the device only, nothing is emitted
+        # yet, and a step takes the row's token from the engine's buffer
+        # of first tokens (`_carry_tokens`)
+        self.first = None
         # pos, generated and last are as of the last step DELIVERED;
         # `ahead` counts the steps dispatched for this slot since (0 or
         # 1): the next dispatch writes pos + ahead, on a token that is
@@ -455,7 +473,7 @@ class _Slot(object):
         self.last_t = time.perf_counter()   # previous token's completion
         # the engine's count of admissions at that token, its own
         # included: moved by the next token, the gap held an admission
-        self.admit_seq = admit_seq
+        self.admit_seq = None
         self.wall0 = time.time() * 1e6      # decode-phase start (us)
         self.blocks = blocks    # physical block ids, table order
         self.table = table      # np [max_blocks] int64, filler 0
@@ -468,24 +486,40 @@ class _Slot(object):
 
 class _Flight(object):
     """A decode step between its dispatch and the fetch of its tokens."""
-    __slots__ = ('out', 'active', 't0', 'overlapped')
+    __slots__ = ('out', 'active', 't0', 'overlapped', 'firsts')
 
-    def __init__(self, out, active, t0, overlapped):
+    def __init__(self, out, active, t0, overlapped, firsts):
         self.out = out          # device fetches; None once a failure
         #                         took the step with it
         self.active = active    # [(slot index, _Slot)] as dispatched
         self.t0 = t0
         # dispatched while its predecessor was still unfetched
         self.overlapped = overlapped
+        # the admissions since the step dispatched before this one: their
+        # first tokens are picked up before this step's own fetch
+        self.firsts = firsts
 
     def fetch(self):
         """The step's fetched vector on the host: blocks until the device
         is done with the step, and raises what an async failure left."""
         return np.asarray(self.out[0])
 
-    def ready(self):
-        """Is the device done with the step? Never blocks."""
-        return self.out[0].is_ready()
+
+class _First(object):
+    """An admission between its prefill's dispatch and the pick-up of
+    its first token (`GenerateEngine._pickup`)."""
+    __slots__ = ('slot', 'st', 'out', 't0', 'wall0', 'self_s', 'dispatch_s')
+
+    def __init__(self, slot, st, out, t0, wall0, self_s, dispatch_s):
+        self.slot = slot
+        self.st = st
+        self.out = out          # the prefill's device fetch
+        self.t0 = t0            # the admission's start
+        self.wall0 = wall0      # ... on the wall clock, us (the span)
+        # what the admission took the loop: the `prefill` phase's self
+        # time and the bound calls in it, booked at the pick-up
+        self.self_s = self_s
+        self.dispatch_s = dispatch_s
 
 
 def block_copy_fn(backend):
@@ -552,6 +586,12 @@ class GenerateEngine(object):
         self._cow_jit = None
         self._dcopy_jit = None
         self._carry_jit = None
+        self._put_jit = None
+        # the prefills' first tokens as they are on the device, a row a
+        # slot, and a step's output to stand for "no step in flight"
+        # (warmup makes both)
+        self._first_buf = None
+        self._no_prev = None
         if c.speculative and c.model.n_conv_layers:
             raise ValueError(
                 "speculative=True with LMConfig.layer_types=%r: a rejected "
@@ -600,10 +640,15 @@ class GenerateEngine(object):
         # decode steps dispatched and not fetched yet, oldest first: two
         # at most, while the loop's pipeline is full
         self._flights = []
+        # admissions whose first token is still on the device and that no
+        # step in flight has taken over (`_Flight.firsts`), oldest first
+        self._firsts = []
         self._fetched_t = 0.0   # when the last step's fetch came back
-        # since then: what admissions waited for their prefills alone
-        self._prefill_alone_s = 0.0
-        self._admit_seq = 0     # admissions completed (`_Slot.admit_seq`)
+        # ... and the last first token picked up behind steps in flight:
+        # up to there the device's time was a prefill's, no step's
+        self._picked_t = 0.0
+        self._admit_seq = 0     # first tokens picked up (`_Slot.admit_seq`)
+        self._first_carried = 0
         self._decode_steps = 0
         self._sampled_steps = 0
         self._overlapped_steps = 0
@@ -811,23 +856,42 @@ class GenerateEngine(object):
             self._draft_scope.set(name,
                                   self._dcopy_jit(dst, src, d_ids, s_ids))
 
-    def _carry_tokens(self, prev, carry, toks):
-        """The next step's 'gen_tokens' with a step in flight: row i takes
-        the token step `prev` (its device fetch, the tokens leading it)
-        made for it where `carry[i]`, the host's `toks[i]` elsewhere — a
-        row admitted since, whose first token came from its prefill, or
-        an empty one. One tiny jitted select, compiled at warmup: the
-        carried token never crosses to the host and back."""
+    def _carry_tokens(self, prev, src, toks):
+        """The next step's 'gen_tokens' where a row's token is on the
+        device: row i takes the token step `prev` (its device fetch, the
+        tokens leading it; None with no step in flight) made for it where
+        `src[i]` is 1, its prefill's first token (`_put_first`) where 2,
+        the host's `toks[i]` elsewhere — an empty row, or one whose last
+        token was delivered. One tiny jitted select, compiled at warmup:
+        neither token crosses to the host and back."""
         import jax
         if self._carry_jit is None:
             import jax.numpy as jnp
             S = self.config.slots
 
-            def _select(prev, carry, toks):
+            def carry_tokens(prev, first, src, toks):
                 mine = prev.reshape(-1)[:S].reshape(S, 1)
-                return jnp.where(carry, mine.astype(toks.dtype), toks)
-            self._carry_jit = jax.jit(_select)
-        return self._carry_jit(prev, carry, toks)
+                return jnp.where(src == 1, mine.astype(toks.dtype),
+                                 jnp.where(src == 2, first.astype(toks.dtype),
+                                           toks))
+            self._carry_jit = jax.jit(carry_tokens)
+        return self._carry_jit(self._no_prev if prev is None else prev,
+                               self._first_buf, src, toks)
+
+    def _put_first(self, slot, out):
+        """A prefill's first token (its device fetch, the token leading
+        it) into row `slot` of the buffer the next step's select reads:
+        one jitted update, compiled at warmup, behind the prefill on the
+        device."""
+        import jax
+        if self._put_jit is None:
+            # its module's name is what tools/gapreport.py counts
+            def first_token_put(buf, out, slot):
+                return buf.at[slot, 0].set(
+                    out.reshape(-1)[0].astype(buf.dtype))
+            self._put_jit = jax.jit(first_token_put)
+        self._first_buf = self._put_jit(self._first_buf, out,
+                                        np.asarray(slot, 'int32'))
 
     def _set_block_gauges(self):
         used = self._alloc.in_use()
@@ -893,18 +957,18 @@ class GenerateEngine(object):
                 # an all-zero block table points every write at the
                 # reserved trash block — warmup never touches a row a
                 # live request could own
-                feed = {'gen_prompt': np.zeros((1, b), 'int64'),
-                        'gen_len': np.ones((1, 1), 'int64'),
-                        'gen_pos': np.zeros((1, b), 'int64'),
-                        'gen_btab': np.zeros((1, self._max_blocks),
-                                             'int64')}
-                feed.update(self._sample_feed(1))
+                pfeed = {'gen_prompt': np.zeros((1, b), 'int64'),
+                         'gen_len': np.ones((1, 1), 'int64'),
+                         'gen_pos': np.zeros((1, b), 'int64'),
+                         'gen_btab': np.zeros((1, self._max_blocks),
+                                              'int64')}
+                pfeed.update(self._sample_feed(1))
                 fetch = [self._token_fetch(v, 'first_token')]
-                key, already = farm.track(self.executor, prog, feed,
+                key, already = farm.track(self.executor, prog, pfeed,
                                           fetch_list=fetch,
                                           scope=self.scope)
                 self._prefill_bound[b] = self.executor.bind(
-                    prog, feed, fetch_list=fetch, scope=self.scope)
+                    prog, pfeed, fetch_list=fetch, scope=self.scope)
                 if already:
                     reused += 1
                 else:
@@ -930,9 +994,18 @@ class GenerateEngine(object):
             # no second one (tests/test_decode_pipeline.py counts jax's
             # own compiles); all-zero tables keep the writes in the trash
             # block.
+            # ... and the select's other source, the buffer the prefills'
+            # first tokens are written into (`_put_first`: the widest
+            # bucket's output here, every bucket's has its shape).
+            import jax.numpy as jnp
             out = self._step_bound(feed, return_numpy=False)
+            self._no_prev = out[0]
+            self._first_buf = jnp.zeros((S, 1), out[0].dtype)
+            self._put_first(0, self._prefill_bound[
+                self.config.prompt_buckets[-1]](pfeed,
+                                                return_numpy=False)[0])
             self._step_bound(dict(feed, gen_tokens=self._carry_tokens(
-                out[0], np.zeros((S, 1), bool), feed['gen_tokens'])),
+                out[0], np.zeros((S, 1), 'int8'), feed['gen_tokens'])),
                 return_numpy=False)
             if self.config.speculative:
                 reused += self._warm_spec(farm)
@@ -1196,8 +1269,8 @@ class GenerateEngine(object):
                    self._alloc.capacity))
         table = self._slot_table(blocks)
         try:
-            first = self._run_prefill(prompt, table,
-                                      sample + (draw_u(),))
+            first = int(self._split_load(self._run_prefill(
+                prompt, table, sample + (draw_u(),)), 1)[0])
             tokens, last, pos = [first], first, prompt.size
             while (len(tokens) < max_new_tokens and pos < c.max_len and
                    (c.eos_id is None or last != c.eos_id)):
@@ -1234,28 +1307,29 @@ class GenerateEngine(object):
         dispatched and not yet fetched, a pass dispatches step k + 1 — a
         carried row's input token taken from step k's output ON THE
         DEVICE, its position pos + 1, its table grown on the host — and
-        only then admits, fetches step k, delivers and books it, while
-        k + 1 computes: the token's round trip to the host, `wait`,
-        `deliver`, `feed` and `dispatch` all run behind a busy device.
-        Nothing in flight (the start, after an idle) or a speculative
-        round due: the pass is the serial one, admit, then dispatch.
-        Only an `eos` finish depends on a token's value: such a row is
-        in step k + 1 already and `_deliver` drops its result there;
-        every other finish is foreseen and the row left out
-        (`_grow_blocks`).
+        only then fetches step k, delivers and books it, while k + 1
+        computes: the token's round trip to the host, `wait`, `deliver`,
+        `feed` and `dispatch` all run behind a busy device. Nothing in
+        flight (the start, after an idle) or a speculative round due:
+        the pass is the serial one, admit, then dispatch. Only an `eos`
+        finish depends on a token's value: such a row is in step k + 1
+        already and `_deliver` drops its result there; every other
+        finish is foreseen and the row left out (`_grow_blocks`).
 
-        Where a pass admits is what the loop observes after the
-        dispatch (below): step k done already — the host paces the loop
-        — it admits at once, between the dispatch and the fetch, where
-        admission sat before the pipeline (a client whose request just
-        ended has had the time of a dispatch to send its next; admitted
-        after the delivery instead it comes a pass later and in larger
-        groups: on the chip chat's p95 token gap + 22 %); step k still
-        computing — the device paces the loop — it fetches and delivers
-        k first (admitted before, the prefill waits for two steps:
-        OLMoE's p95 token gap + 30 %). PERF.md, PR 31. The probe goes
-        when a prefill's first token stays on the device as well: one
-        fixed order then serves both regimes (ROADMAP S8).
+        A pass admits last, in one fixed order: fetch and deliver step
+        k, the consumers' turn (`yield`), then `_admit`. Behind a step
+        in flight an admission is its prefill's bound call and nothing
+        more (`_admit_one`): the prefill queues behind step k + 1, the
+        row joins step k + 2 — the next pass's dispatch, a moment later
+        — on its first token as the prefill leaves it on the device, and
+        the loop picks that token up before step k + 2's own fetch
+        (`_pickup`), with the prefill long done or nearly so. Wherever
+        the admission sat in the pass the device would run the same
+        programs in the same order; placed last it catches the client
+        whose request the delivery just ended, in the same pass (placed
+        between the dispatch and the fetch: chat's `ttft_p95_ms` + 27 %,
+        OLMoE's first-token p95 + 35 %, both cells' p95 token gap level.
+        PERF.md, PR 38).
 
         Every stretch of a pass is a phase (_loop_phase): its self time
         goes to generate_loop_seconds_total{phase=...} and, in a profiler
@@ -1281,15 +1355,9 @@ class GenerateEngine(object):
                 # admitted a pass later: another schedule (PERF.md, PR
                 # 25)
                 done = None
-            # a prefill goes out with ONE unfinished step ahead of it on
-            # the device, as before the pipeline. Step k done already
-            # (the host paces the loop): admit now, behind k + 1. Still
-            # computing (the device paces it): fetch and deliver it
-            # first, then admit behind k + 1 — admitted now, the prefill
-            # would wait for two steps, and every stream with it
-            fetch_first = nxt is not None and not flight.ready()
-            if fetch_first:
+            if flight is not None:
                 self._step_complete(flight, nxt)
+                done = flight   # noqa: F841 — held, above
             with _loop_phase('admit'):
                 # an evicted slot may be in the snapshot of a step in
                 # flight, and have a new tenant before that step lands:
@@ -1299,14 +1367,12 @@ class GenerateEngine(object):
                 if nxt is None:
                     self._admit()
             if nxt is not None:
-                # admit queued prompts (queue pops + prefill staging)
-                # while the steps in flight compute
+                # queue pops and the prefills' bound calls, behind the
+                # step just dispatched: a prefill queues on the device
+                # and the loop goes on
                 with _loop_phase('admit_overlapped'):
                     self._admit()
             if flight is not None:
-                if not fetch_first:
-                    self._step_complete(flight, nxt)
-                done = flight   # noqa: F841 — held, above
                 continue
             if not any(s is not None for s in self._slots):
                 with _loop_phase('idle'):
@@ -1337,6 +1403,8 @@ class GenerateEngine(object):
             # stopping: the steps in flight land first, so nothing on
             # the device still runs on this engine's state
             self._step_complete(self._flights[0])
+        firsts, self._firsts = self._firsts, []
+        self._pickup(firsts)    # ... and the prefills no step took over
         monitor.inc('generate_loop_wall_seconds_total',
                     time.perf_counter() - t_pass)      # the last pass
         # shutdown: a resident generation must not leave its caller
@@ -1461,9 +1529,14 @@ class GenerateEngine(object):
         t0 = time.perf_counter()
         pf_wall = time.time() * 1e6
         dblocks, dtable = None, None
-        with _loop_phase('prefill'):
+        # behind a step in flight the admission ends with its dispatch;
+        # with nothing to wait behind (the start, after an idle spell,
+        # the inline paths) the pass is the serial one, the token now.
+        # So for a speculative engine: a round reads `last` on the host
+        defer = bool(self._flights) and not c.speculative
+        with _loop_phase('prefill', counted=False) as own:
             try:
-                first = self._run_prefill(
+                out = self._run_prefill(
                     req.prompt, table,
                     (req.temperature, req.top_k, req.top_p, req._draw_u()),
                     ctx_len=ctx_len)
@@ -1483,6 +1556,8 @@ class GenerateEngine(object):
                     else:
                         self._run_prefill(req.prompt, dtable,
                                           bound=self._draft_prefill_bound)
+                if defer:
+                    self._put_first(slot, out)
             except Exception as e:  # noqa: BLE001 — delivered per-request
                 self._free.append(slot)
                 self._deref_blocks(blocks)
@@ -1491,36 +1566,104 @@ class GenerateEngine(object):
                 monitor.inc('generate_request_total',
                             labels={'outcome': 'error'})
                 req.fail(e)
-                return True
+                out = None
+        # the bound calls are the phases nested in it
+        self_s, dispatch_s = own.dur_s - own.nested_s, own.nested_s
+        if out is None:
+            _book_admission(self_s, dispatch_s)
+            return True
         if self._prefix is not None:
             # publish this prompt's FULL blocks (immutable once
-            # prefilled: decode writes land strictly past the prompt)
+            # prefilled: decode writes land strictly past the prompt).
+            # The prefill may still be running: whatever reads the
+            # blocks is a program dispatched after it
             for i, h in enumerate(hashes):
                 self._prefix.register(h, i, blocks[i])
-        pf_s = time.perf_counter() - t0
-        monitor.observe('prefill_seconds', pf_s)
-        if req.trace is not None:
-            req.trace.add_stage('prefill', pf_s)
-            monitor.record_span('request.prefill', pf_wall, pf_s * 1e6,
-                                trace=req.trace)
-        monitor.inc('decode_tokens_total')
-        self._decode_tokens += 1
-        req._emit(first)
-        self._admit_seq += 1
-        st = _Slot(req, pos=req.prompt.size, last=first,
-                   blocks=blocks, table=table, admit_seq=self._admit_seq,
+        st = _Slot(req, pos=req.prompt.size, blocks=blocks, table=table,
                    dblocks=dblocks, dtable=dtable)
-        reason = self._finish_reason(st)
-        if reason:
-            self._release_blocks(st)
-            self._free.append(slot)
-            monitor.inc('generate_request_total',
-                        labels={'outcome': 'ok'})
-            req._finish(reason)
+        st.first = _First(slot, st, out, t0, pf_wall, self_s, dispatch_s)
+        self._slots[slot] = st
+        if defer:
+            # the row joins the next step on its token as it is on the
+            # device, and the loop picks the token up before that step's
+            # fetch (`_step_complete`)
+            self._firsts.append(st.first)
         else:
-            self._slots[slot] = st
+            self._pickup([st.first])
         self._set_occupancy()
         return True
+
+    def _pickup(self, firsts):
+        """The first tokens of `firsts` on the host, oldest first, each
+        booked and emitted as the admission's end. Phase `prefill.fetch`
+        is the time the loop BLOCKS for one (behind steps in flight the
+        next step's time starts where the last of them lands:
+        `_observe_step`). `prefill_seconds` is
+        the loop's own time for the admission — that wait and what it
+        took up to the dispatch, the phases `prefill` and
+        `prefill.dispatch`, booked here with it: the whole stretch to
+        the token with nothing in flight, its two ends behind a step in
+        flight — where the request's `prefill` stage runs from the
+        admission's start to here, across the passes in between. The
+        engine's count of admissions moves, so the token gap that this
+        wait is part of reads as one that held an admission
+        (`_deliver`). A row evicted meanwhile (a deadline) is skipped,
+        its token never fetched. A prefill that failed surfaces here:
+        its request is failed, and with it every step and every
+        admission dispatched since, once each — the cache is threaded
+        through all of them (`_fail_step`)."""
+        for n, f in enumerate(firsts):
+            st, r = f.st, f.st.req
+            # the loop's phase counters and prefill_seconds move for an
+            # admission at ONE moment, this one: a window's two deltas
+            # hold the same admissions
+            _book_admission(f.self_s, f.dispatch_s)
+            if self._slots[f.slot] is not st:
+                continue
+            try:
+                with _loop_phase('prefill.fetch') as alone:
+                    st.last = int(self._split_load(f.out, 1)[0])
+            except Exception as e:  # noqa: BLE001 — delivered per-request
+                later = firsts[n:] + self._firsts + \
+                    [g for fl in self._flights for g in fl.firsts]
+                for g in later[1:]:
+                    _book_admission(g.self_s, g.dispatch_s)
+                self._firsts = []
+                for fl in self._flights:
+                    fl.firsts = []
+                if self._prefix is not None:
+                    # it may hold blocks the failed prefill never wrote
+                    self._prefix.drop_all()
+                self._fail_step([(g.slot, g.st) for g in later], e,
+                                *self._flights)
+                return
+            st.first = None
+            now = time.perf_counter()
+            if self._flights:
+                self._picked_t = now
+            pf_s = now - f.t0
+            monitor.observe('prefill_seconds',
+                            f.self_s + f.dispatch_s + alone.dur_s)
+            if r.trace is not None:
+                r.trace.add_stage('prefill', pf_s)
+                monitor.record_span('request.prefill', f.wall0, pf_s * 1e6,
+                                    trace=r.trace)
+            monitor.inc('decode_tokens_total')
+            self._decode_tokens += 1
+            r._emit(st.last)
+            self._admit_seq += 1
+            st.admit_seq = self._admit_seq
+            st.last_t = now
+            st.wall0 = time.time() * 1e6
+            reason = self._finish_reason(st)
+            if reason:
+                # by `eos` the row may be in a step in flight already:
+                # `_deliver` drops its result there
+                self._release(f.slot)
+                monitor.inc('generate_request_total',
+                            labels={'outcome': 'ok'})
+                r._finish(reason)
+        self._set_occupancy()
 
     def _run_prefill(self, prompt, table, sample=(0.0, 0, 0.0, 0.0),
                      ctx_len=0, bound=None):
@@ -1549,7 +1692,7 @@ class GenerateEngine(object):
                     'gen_btab': table[None],
                     'gen_len': np.array([[wide]], 'int64')}
             feed.update(self._sample_feed(1))
-            # K/V deposited; token output unused
+            # K/V deposited; the token output is never fetched
             self._prefill_call(bound[wide], feed)
             off += wide
         b = bucketize(suffix.size, c.prompt_buckets)
@@ -1561,30 +1704,20 @@ class GenerateEngine(object):
                 'gen_btab': table[None],
                 'gen_len': np.array([[suffix.size]], 'int64')}
         feed.update(self._sample_feed(1, *sample))
-        return int(self._prefill_call(bound[b], feed)[0])
+        out = self._prefill_call(bound[b], feed)
+        # the copy to the host starts behind the prefill: by the pick-up
+        # it is latency behind a busy device
+        out.copy_to_host_async()
+        return out
 
     def _prefill_call(self, bound, feed):
-        """One prefill dispatch and the fetch of its token, behind the
-        decode steps in flight as the device runs them: three phases
-        inside `prefill`. `prefill.dispatch` is the host's bound call,
-        `prefill.drain` the wait for the steps in flight, and
-        `prefill.fetch`, from there to the token on the host, is the
-        prefill's time alone and none of a step's (`_observe_step`)."""
-        with _loop_phase('prefill.dispatch'):
-            out = bound(feed, return_numpy=False)
-        behind = bool(self._flights)
-        if behind:
-            with _loop_phase('prefill.drain'):
-                for flight in self._flights:
-                    try:
-                        flight.out[0].block_until_ready()
-                    except Exception:   # noqa: BLE001 — raised at its fetch
-                        pass
-        with _loop_phase('prefill.fetch') as alone:
-            tokens = self._split_load(out[0], 1)
-        if behind:
-            self._prefill_alone_s += alone.dur_s
-        return tokens
+        """One prefill dispatch, phase `prefill.dispatch` inside
+        `prefill` (`_admit_one` keeps their seconds for `_pickup`): the
+        host's bound call and nothing more. The program queues behind
+        the steps in flight on the device; its output stays there
+        (`_run_prefill`, `_pickup`)."""
+        with _loop_phase('prefill.dispatch', counted=False):
+            return bound(feed, return_numpy=False)[0]
 
     def _step(self):
         """One decode step, dispatch + completion back to back (the
@@ -1619,10 +1752,11 @@ class GenerateEngine(object):
         pool (even after prefix-cache eviction) finishes the starved
         request with 'cache_full' and returns its blocks — neighbors
         keep decoding. Returns the slots this step leaves out: those
-        with a step in flight (`ahead`) that the host can see ending at
-        its delivery — `length`, `max_len` — so that no row is computed
-        for nothing, and those the dry pool starves while their token of
-        the step in flight is still to come (the next pass decides)."""
+        with a token still to come — of a step in flight (`ahead`), or
+        the first, of the prefill (`first`) — that the host can see
+        ending at its delivery — `length`, `max_len` — so that no row is
+        computed for nothing, and those the dry pool starves while that
+        token is still to come (the next pass decides)."""
         c = self.config
         bs = c.block_size
         held = set()
@@ -1630,15 +1764,16 @@ class GenerateEngine(object):
             if st is None:
                 continue
             at = st.pos + st.ahead
-            if st.ahead and (at >= c.max_len or st.generated + st.ahead
-                             >= st.req.max_new_tokens):
+            unseen = st.ahead or st.first is not None
+            if unseen and (at >= c.max_len or st.generated + st.ahead
+                           >= st.req.max_new_tokens):
                 held.add(i)
                 continue
             if at // bs < len(st.blocks):
                 continue
             grown = self._alloc_blocks(1)
             if grown is None:
-                if st.ahead:
+                if unseen:
                     held.add(i)
                     continue
                 self._release(i)
@@ -1935,25 +2070,32 @@ class GenerateEngine(object):
         can do host work while the device computes. With `prev`, the
         step dispatched before this one and not fetched yet, a row of
         `prev` takes its input token from `prev`'s output on the device
-        (`_carry_tokens`) and writes one position further; every other
-        row is fed from the host as without. Returns the step's
-        `_Flight`, or None with nothing to step or after a failure."""
+        (`_carry_tokens`) and writes one position further; a row
+        admitted since takes its prefill's first token, on the device as
+        well (`generate_first_token_carried_total`); every other row is
+        fed from the host as without. Returns the step's `_Flight`, or
+        None with nothing to step or after a failure."""
         with _loop_phase('feed'):
             c = self.config
             held = self._grow_blocks()
             S = c.slots
             toks = np.zeros((S, 1), 'int64')
-            carry = np.zeros((S, 1), bool)
+            # where a row's token is: 0 the host, 1 `prev`'s output, 2
+            # the first tokens' buffer
+            src = np.zeros((S, 1), 'int8')
             pos = np.zeros((S, 1), 'int64')
             sample = self._sample_feed(S)
             btab = np.zeros((S, self._max_blocks), 'int64')
             active = []
-            live_pages = live_tokens = 0
+            live_pages = live_tokens = carried = 0
             for i, st in enumerate(self._slots):
                 if st is None or i in held:
                     continue
                 if st.ahead:
-                    carry[i] = True
+                    src[i] = 1
+                elif st.first is not None:
+                    src[i] = 2
+                    carried += 1
                 else:
                     toks[i] = st.last
                 at = st.pos + st.ahead
@@ -1969,7 +2111,12 @@ class GenerateEngine(object):
                 live_pages += at // c.block_size + 1
                 live_tokens += at + 1
                 active.append((i, st))
+            # the admissions since the last dispatch: this step's to pick
+            # up, whether or not their rows are in it
+            firsts, self._firsts = self._firsts, []
             if not active:
+                # no step to wait behind: now
+                self._pickup(firsts)
                 return None
             if (sample['gen_temp'] > 0).any():
                 # the very condition sample_next_token branches on: one
@@ -1997,17 +2144,23 @@ class GenerateEngine(object):
             t0 = time.perf_counter()
             try:
                 feed['gen_tokens'] = self._carry_tokens(
-                    prev.out[0], carry, toks) if carry.any() else toks
+                    None if prev is None else prev.out[0], src,
+                    toks) if src.any() else toks
                 out = self._step_bound(feed, return_numpy=False)
                 # the device-to-host copy starts now and is latency
                 # behind the next step, not a wait after this one
                 out[0].copy_to_host_async()
             except Exception as e:  # noqa: BLE001 — delivered per-request
                 self._fail_step(active, e, prev)
+                # those the failure left (a row this step held back)
+                self._pickup(firsts)
                 return None
+        if carried:
+            self._first_carried += carried
+            monitor.inc('generate_first_token_carried_total', carried)
         for _i, st in active:
             st.ahead += 1
-        flight = _Flight(out, active, t0, prev is not None)
+        flight = _Flight(out, active, t0, prev is not None, firsts)
         self._flights.append(flight)
         return flight
 
@@ -2042,8 +2195,12 @@ class GenerateEngine(object):
         """Fetch step `flight`'s tokens and deliver them; `nxt` is the
         step dispatched behind it. A step that fails here takes `nxt`
         with it: the residents of both are failed, neither delivers."""
+        firsts, flight.firsts = flight.firsts, []
+        # a stream sees its first token before its second: the first
+        # tokens this step was dispatched on, picked up before its own
+        self._pickup(firsts)
         if flight.out is None:
-            return      # went with the failed dispatch of the step behind
+            return      # went with a failed dispatch or a failed prefill
         try:
             # materialization = device completion; an async runtime
             # failure surfaces here and fails the residents
@@ -2073,18 +2230,24 @@ class GenerateEngine(object):
         """decode_step_seconds, one observation a decode step. A step
         that was in flight together with the one before it is timed from
         that one's fetch to its own: from its own dispatch it would read
-        two periods, round a fetch that is already there nothing. Of an
-        admission in between, what it waited for its prefill ALONE — the
-        steps in flight seen complete (`_prefill_call`) — is taken out:
-        that is prefill_seconds, and the time the steps computed behind
-        the admission stays in. A step with no predecessor in flight is
-        timed from its dispatch. In a device-bound loop the mean is the
-        device's step, in a host-bound one the loop's period."""
+        two periods, round a fetch that is already there nothing. Where
+        first tokens were picked up in between (`_pickup`, just before
+        this fetch), it is timed from the last of them: the device ran
+        the step before, the prefills, then this step, so the stretch
+        from that step's fetch to the first tokens on the host was the
+        prefills' and what follows is this step's. (The loop's wait for
+        them alone, `prefill.fetch`, is shorter by the host's own work
+        since the last fetch: taken out in its place it left that work
+        in the step — + 4 to 9 % on the mean at 64 rows. PERF.md, PR
+        38.) A step with no predecessor in flight is timed from its
+        dispatch. In a device-bound loop the mean is the device's step;
+        in a host-bound one the loop's period, cut short wherever a pass
+        picked a first token up."""
         now = time.perf_counter()
-        since = self._fetched_t if flight.overlapped else flight.t0
-        monitor.observe('decode_step_seconds',
-                        max(0.0, now - since - self._prefill_alone_s))
-        self._fetched_t, self._prefill_alone_s = now, 0.0
+        since = max(self._fetched_t if flight.overlapped else flight.t0,
+                    self._picked_t)
+        monitor.observe('decode_step_seconds', max(0.0, now - since))
+        self._fetched_t = now
         if flight.overlapped:
             # booked with the observation it is a share of, so that a
             # window's two deltas count the same steps
@@ -2238,6 +2401,7 @@ class GenerateEngine(object):
             'sampled_steps': self._sampled_steps,
             'overlapped_steps': self._overlapped_steps,
             'discarded_rows': self._discarded_rows,
+            'first_tokens_carried': self._first_carried,
             'decode_tokens': self._decode_tokens,
             'peak_slot_occupancy': round(self._occ_peak, 4),
             'mean_slot_occupancy': round(self._occ_sum / steps, 4)

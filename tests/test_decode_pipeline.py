@@ -8,9 +8,17 @@ through every other finish; a failed step; a speculative engine's
 fallback steps; and that the device-fed step is the executable
 `warmup()` bound, not a second compile.
 
+Since PR 38 a prefill's first token stays on the device as well (part
+(e)): an admission dispatches its prefill and returns, the row joins the
+next step on the token as the prefill left it, and the loop picks the
+token up before that step's own fetch — over a dense pool, one with
+prefix sharing, and LFM2's three pools with the convolution tails.
+
 Engines share test_paged_generate.py's tiny-LM shape family, so the
 process-wide compile cache keeps warmups at milliseconds.
 """
+import json
+import os
 import threading
 import time
 
@@ -23,6 +31,8 @@ from paddle_tpu.models.transformer import LMConfig
 from paddle_tpu.serving import GenerateConfig, GenerateEngine
 from paddle_tpu.serving import generate as generate_mod
 from paddle_tpu.serving.batcher import DeadlineExceededError
+
+from benchmark.models import lfm2
 
 MAX_LEN = 48
 BS = 8
@@ -39,6 +49,23 @@ def _cfg(**kw):
     kw.setdefault('seed', 0)
     kw.setdefault('block_size', BS)
     return GenerateConfig(**kw)
+
+
+POOLS = ['dense', 'prefix-sharing', 'lfm2-tails']
+with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       'benchmark_tests', 'configs', 'toy-lfm2.json')) as _f:
+    TOY_LFM2 = json.load(_f)
+
+
+def _pool_cfg(pool, **kw):
+    """The engine's config over one of the three kinds of pool: K/V
+    alone, K/V with the prefix index on, and LFM2's — K/V of the
+    attention layers, the convolution layers' tails under the same block
+    ids, prefix sharing on (tests/test_lfm2_serving.py's toy)."""
+    if pool == 'lfm2-tails':
+        kw.setdefault('model', lfm2.lm_config(TOY_LFM2, MAX_LEN, False))
+    kw.setdefault('prefix_sharing', pool != 'dense')
+    return _cfg(**kw)
 
 
 def _prompt(n, seed=0):
@@ -85,9 +112,11 @@ def test_next_step_goes_out_before_the_last_is_fetched_on_device_tokens():
     assert kinds == ['dispatch'] * 2 + ['deliver', 'dispatch'] * (n - 3) \
         + ['deliver'] * 2
     fed = [t for k, t in log if k == 'dispatch']
-    assert fed[0] is np.ndarray                 # the prefill's token
+    # the prefill's token: admitted with nothing in flight, the serial pass
+    assert fed[0] is np.ndarray
     assert all(issubclass(t, jax.Array) for t in fed[1:])   # never numpy
     delta = monitor.counter_delta(before)
+    assert 'generate_first_token_carried_total' not in delta
     assert delta['generate_overlapped_steps_total'] == n - 2
     assert 'generate_discarded_rows_total' not in delta
     st = eng.stats()
@@ -95,11 +124,15 @@ def test_next_step_goes_out_before_the_last_is_fetched_on_device_tokens():
     assert st['decode_steps'] == n - 1
 
 
-def test_the_device_fed_step_is_the_executable_warmup_bound():
+@pytest.mark.parametrize('pool', POOLS)
+def test_the_device_fed_step_is_the_executable_warmup_bound(pool):
     """jax's own compiles, counted: none after warmup(), although the
     loop feeds the step a device int32 where warmup's numpy feed is
-    int64; and the paddle-level compile cache stays quiet too."""
-    eng = GenerateEngine(_cfg())
+    int64; and the paddle-level compile cache stays quiet too. Six
+    admissions through four slots, the last two behind steps in flight:
+    the select's third source, the buffer of first tokens, and the
+    update that fills it are compiled too."""
+    eng = GenerateEngine(_pool_cfg(pool))
     eng.warmup()
     compiles = []
 
@@ -121,22 +154,26 @@ def test_the_device_fed_step_is_the_executable_warmup_bound():
     delta = monitor.counter_delta(before)
     assert not any(k.startswith('compile_cache_miss') for k in delta), delta
     assert delta['generate_overlapped_steps_total'] > 0
+    assert delta['generate_first_token_carried_total'] >= 2
 
 
-@pytest.mark.parametrize('step_done,order', [
-    (True, ['dispatch', 'admit', 'deliver']),
-    (False, ['dispatch', 'deliver', 'admit'])],
-    ids=['host-paced', 'device-paced'])
-def test_where_a_pass_admits_is_what_the_loop_observes(monkeypatch,
-                                                       step_done, order):
-    """The one fork in a pass, pinned on each side: step k done when the
-    dispatch of k + 1 returns (the host paces the loop) — admit, then
-    fetch and deliver k; still computing (the device paces it) — fetch
-    and deliver k, then admit. A prefill so goes out with one unfinished
-    step ahead of it either way. The tokens are generate_once's on both
-    sides: the fork moves when the host looks, not what is computed."""
-    monkeypatch.setattr(generate_mod._Flight, 'ready',
-                        lambda self: step_done)
+@pytest.mark.parametrize('fetch_s', [0.0, 0.01],
+                         ids=['host-paced', 'device-paced'])
+def test_a_pass_admits_in_one_fixed_order(monkeypatch, fetch_s):
+    """No fork in a pass: step k + 1 dispatched, step k fetched and
+    delivered, the consumers' turn, THEN the admission — whether step k
+    is done when the loop looks (the host paces it) or the fetch waits
+    for the device (a slow fetch stands for it). A prefill so queues
+    behind the one step just dispatched either way, and costs the pass
+    its bound call. Nothing probes the step: `_Flight` has no `ready`.
+    The tokens are generate_once's."""
+    assert not hasattr(generate_mod._Flight, 'ready')
+    fetch = generate_mod._Flight.fetch
+
+    def slow(self):
+        time.sleep(fetch_s)
+        return fetch(self)
+    monkeypatch.setattr(generate_mod._Flight, 'fetch', slow)
     eng = GenerateEngine(_cfg())
     work = [(_prompt(6, seed=71), 12), (_prompt(9, seed=72), 8),
             (_prompt(4, seed=73), 10)]
@@ -157,7 +194,8 @@ def test_where_a_pass_admits_is_what_the_loop_observes(monkeypatch,
     # a pass with a step in flight that dispatched another: from its
     # dispatch to the next pass's
     full = [kinds[a:b] for a, b in zip(at, at[1:]) if b - a > 1]
-    assert len(full) >= 8 and all(p == order for p in full), full
+    assert len(full) >= 8
+    assert all(p == ['dispatch', 'deliver', 'admit'] for p in full), full
 
 
 # ---------------------------------------------------------------------------
@@ -394,4 +432,285 @@ def test_stop_lands_the_step_in_flight():
     # ended: nothing on the device outlives the engine's loop
     kinds = [k for k, _ in log]
     assert kinds.count('dispatch') == kinds.count('deliver')
+    assert not any(t.name == 'paddle-generate' for t in threading.enumerate())
+
+
+# ---------------------------------------------------------------------------
+# (e) a prefill's first token stays on the device
+
+
+def _watch(eng, log):
+    """`log` gets, in the loop's order: ('prefill', bucket) and ('step',
+    rows whose token came from the first tokens' buffer) at the two
+    kinds of dispatch, ('fetch', n) where a fetched vector crosses to the
+    host — n is 1 for a prefill's output, the slots for a step's."""
+    step, split, carry = eng._step_bound, eng._split_load, eng._carry_tokens
+    src = []
+
+    def carried(prev, s, toks):
+        src.append(int((s == 2).sum()))
+        return carry(prev, s, toks)
+
+    def bound(feed, **kw):
+        log.append(('step', src.pop() if src else 0))
+        return step(feed, **kw)
+
+    def fetched(out, n):
+        log.append(('fetch', n))
+        return split(out, n)
+    eng._carry_tokens, eng._step_bound, eng._split_load = \
+        carried, bound, fetched
+    for b, f in list(eng._prefill_bound.items()):
+        eng._prefill_bound[b] = (
+            lambda feed, _b=b, _f=f, **kw:
+            log.append(('prefill', _b)) or _f(feed, **kw))
+
+
+@pytest.mark.parametrize('pool', POOLS)
+def test_a_first_token_reaches_the_next_step_without_a_host_round_trip(pool):
+    """A is resident, step k in flight; B and C are admitted in ONE pass.
+    Each prefill is a dispatch and nothing else: step k + 1 takes A's
+    token from step k and both new rows' from the buffer, on the device,
+    and goes out BEFORE any fetch of a prefill's output; step k lands
+    without them; the two first tokens are picked up before step k + 1's
+    own fetch, so every stream sees its first token before its second;
+    all three streams are generate_once's, and the counter counts the
+    two admissions that found a step in flight."""
+    eng = GenerateEngine(_pool_cfg(pool))
+    work = [(_prompt(6, seed=81), 9), (_prompt(11, seed=82), 7),
+            (_prompt(4, seed=83), 6)]
+    ref = [eng.generate_once(p, max_new_tokens=n) for p, n in work]
+    log = []
+    _watch(eng, log)
+    before = monitor.counters()
+    a = eng.submit(work[0][0], max_new_tokens=work[0][1])
+    eng._admit()                        # nothing in flight: the serial pass
+    assert a.tokens == ref[0][:1] and not eng._firsts
+    k = eng._step_dispatch()
+    del log[:]
+    b, c = [eng.submit(p, max_new_tokens=n) for p, n in work[1:]]
+    eng._admit()
+    assert [e for e, _ in log] == ['prefill', 'prefill']
+    assert b.tokens == c.tokens == [] and len(eng._firsts) == 2
+    nxt = eng._step_dispatch(prev=k)
+    assert log[2] == ('step', 2) and len(log) == 3     # no fetch yet
+    assert eng._firsts == [] and len(nxt.firsts) == 2 and not k.firsts
+    eng._step_complete(k, nxt)
+    assert log[3:] == [('fetch', eng.config.slots)]
+    assert b.tokens == c.tokens == [] and a.tokens == ref[0][:2]
+    eng._step_complete(nxt)
+    assert log[4:] == [('fetch', 1), ('fetch', 1), ('fetch', eng.config.slots)]
+    assert [len(r.tokens) for r in (a, b, c)] == [3, 2, 2]
+    reqs = (a, b, c)
+    while any(r.finish_reason is None for r in reqs):
+        eng._step()
+    assert [list(r.result(5)) for r in reqs] == ref
+    delta = monitor.counter_delta(before)
+    assert delta['generate_admit_total'] == 3
+    assert delta['generate_first_token_carried_total'] == 2 \
+        == eng.stats()['first_tokens_carried']
+    assert eng.stats()['discarded_rows'] == 0
+
+
+def _resident(eng, prompt, n=40):
+    """A request under the loop, two tokens streamed: from here on a step
+    is in flight whenever the loop admits."""
+    req = eng.submit(prompt, max_new_tokens=n, deadline_s=60.0)
+    stream = req.stream(timeout=30.0)
+    return req, stream, [next(stream), next(stream)]
+
+
+@pytest.mark.parametrize('pool', POOLS)
+def test_eos_as_a_first_token_and_the_slots_next_tenant(pool):
+    """The prefill's own token is the `eos`, and a neighbour's steps are
+    in flight: by the pick-up the row is in one or two of them already.
+    Its stream is that one token, the row's results are dropped, the
+    neighbour's stream and the slot's next tenant's are
+    generate_once's."""
+    kw = dict(slots=2)
+    pa, pb = _prompt(10, seed=7), _prompt(13, seed=9)
+    probe = GenerateEngine(_pool_cfg(pool, **kw))
+    eos = probe.generate_once(pa, max_new_tokens=1)[0]
+
+    def cut(p, n):
+        ref = probe.generate_once(p, max_new_tokens=n)
+        return ref[:ref.index(eos) + 1] if eos in ref else ref
+    for seed in range(100, 140):    # a neighbour that outlives the admission
+        pn = _prompt(6, seed=seed)
+        cut_n = cut(pn, 40)
+        if len(cut_n) > 24:
+            break
+    cut_b = cut(pb, 15)
+    assert len(cut_n) > 24
+    eng = GenerateEngine(_pool_cfg(pool, eos_id=eos, **kw))
+    eng.warmup()
+    before = monitor.counters()
+    with eng:
+        n, stream, got_n = _resident(eng, pn)
+        a = eng.submit(pa, max_new_tokens=24)
+        got_a = list(a.result(60))
+        b = eng.submit(pb, max_new_tokens=15)
+        got_b = list(b.result(60))
+        got_n += list(stream)
+    assert got_a == [eos] and a.finish_reason == 'eos'
+    assert got_b == cut_b and got_n == cut_n
+    delta = monitor.counter_delta(before)
+    assert delta['generate_first_token_carried_total'] >= 1
+    assert delta['generate_discarded_rows_total'] >= 1
+    assert eng.stats()['active'] == 0
+    assert eng.stats()['blocks']['in_use'] == 0
+
+
+@pytest.mark.parametrize('pool', POOLS)
+def test_a_request_of_one_token_is_never_put_into_a_step(pool):
+    """`max_new_tokens == 1` is foreseen at the admission: behind a step
+    in flight the row is left out of every step, its token picked up by
+    the step a neighbour is in, or by the next dispatch that finds
+    nothing to step."""
+    eng = GenerateEngine(_pool_cfg(pool))
+    pa, pb = _prompt(7, seed=91), _prompt(5, seed=92)
+    ref_a = eng.generate_once(pa, max_new_tokens=1)
+    ref_b = eng.generate_once(pb, max_new_tokens=5)
+    log = []
+    _watch(eng, log)
+    before = monitor.counters()
+    b = eng.submit(pb, max_new_tokens=3)
+    eng._admit()
+    eng._step()                         # b has two of its three tokens
+    k = eng._step_dispatch()            # ... and its last in flight
+    a = eng.submit(pa, max_new_tokens=1)
+    eng._admit()
+    assert a.tokens == [] and len(eng._firsts) == 1
+    del log[:]
+    assert eng._step_dispatch(prev=k) is None   # b ends, a is one token
+    assert log == [('fetch', 1)]        # nothing to step: picked up now
+    assert list(a.result(5)) == ref_a and a.finish_reason == 'length'
+    eng._step_complete(k)
+    assert list(b.result(5)) == ref_b[:3]
+    b2 = eng.submit(pb, max_new_tokens=5)
+    eng._admit()
+    k = eng._step_dispatch()
+    a2 = eng.submit(pa, max_new_tokens=1)
+    eng._admit()
+    nxt = eng._step_dispatch(prev=k)    # b2's step takes a2's pick-up over
+    assert [st.req for _i, st in nxt.active] == [b2] and len(nxt.firsts) == 1
+    eng._step_complete(k, nxt)
+    assert a2.tokens == []
+    eng._step_complete(nxt)
+    assert list(a2.result(5)) == ref_a and b2.tokens == ref_b[:3]
+    while b2.finish_reason is None:
+        eng._step()
+    assert list(b2.result(5)) == ref_b
+    delta = monitor.counter_delta(before)
+    assert delta['generate_admit_total'] == 4
+    assert 'generate_first_token_carried_total' not in delta
+    assert eng.stats()['discarded_rows'] == 0
+    assert eng.stats()['active'] == 0
+
+
+@pytest.mark.parametrize('pool', POOLS)
+def test_a_prefill_that_fails_at_its_pick_up(pool):
+    """An async failure of a prefill surfaces where its token is picked
+    up: its request gets the error, and so do the residents of the steps
+    dispatched since — the cache is threaded through the failed prefill
+    into them — once each, after the tokens they streamed. The loop
+    lives: the next request is generate_once's."""
+    eng = GenerateEngine(_pool_cfg(pool))
+    eng.warmup()
+    ref = eng.generate_once(_prompt(5, seed=43), max_new_tokens=4)
+    split, armed = eng._split_load, []
+
+    def failing(out, n):
+        if n == 1 and armed:
+            armed.pop()
+            raise RuntimeError('async failure of the prefill')
+        return split(out, n)
+    eng._split_load = failing
+    before = monitor.counters()
+    with eng:
+        residents = [_resident(eng, _prompt(5 + i, seed=40 + i))
+                     for i in range(2)]
+        armed.append(1)
+        late = eng.submit(_prompt(9, seed=49), max_new_tokens=40,
+                          deadline_s=60.0)
+        with pytest.raises(RuntimeError, match='failure of the prefill'):
+            late.result(30)
+        assert late.tokens == []
+        for _req, stream, got in residents:
+            with pytest.raises(RuntimeError, match='failure of the prefill'):
+                for tok in stream:
+                    got.append(tok)
+            assert len(got) < 40
+        out = eng.generate(_prompt(5, seed=43), max_new_tokens=4,
+                           deadline_s=60.0)
+        assert list(out) == ref
+    delta = monitor.counter_delta(before)
+    assert delta['generate_step_error_total'] == 1
+    assert delta['generate_request_total{outcome=error}'] == 3  # once each
+    assert delta['generate_request_total{outcome=ok}'] == 1
+    assert eng.stats()['active'] == 0
+    assert eng.stats()['blocks']['in_use'] == 0
+    assert eng._firsts == [] and eng._flights == []
+
+
+@pytest.mark.parametrize('pool', POOLS)
+def test_a_deadline_and_a_stop_with_a_first_token_pending(pool):
+    """A request whose deadline passes between its prefill's dispatch
+    and the pick-up is evicted like any resident: the token is never
+    fetched, the slot and the blocks go to the next tenant, whose stream
+    is generate_once's. stop() with a first token pending lands what is
+    on the device — the steps, then the prefill no step took over — and
+    leaves nothing behind."""
+    eng = GenerateEngine(_pool_cfg(pool, slots=2))
+    pa, pb, pn = _prompt(9, seed=61), _prompt(6, seed=62), _prompt(5, seed=63)
+    ref_b = eng.generate_once(pb, max_new_tokens=6)
+    ref_n = eng.generate_once(pn, max_new_tokens=12)
+    log = []
+    _watch(eng, log)
+    n = eng.submit(pn, max_new_tokens=12, deadline_s=60.0)
+    eng._admit()
+    k = eng._step_dispatch()            # the neighbour's step, in flight
+    del log[:]
+    doomed = eng.submit(pa, max_new_tokens=8, deadline_s=0.05)
+    eng._admit()
+    assert eng._firsts and doomed.tokens == []
+    time.sleep(0.06)
+    eng._evict_expired()
+    with pytest.raises(DeadlineExceededError):
+        doomed.result(5)
+    b = eng.submit(pb, max_new_tokens=6, deadline_s=60.0)
+    eng._admit()                        # the slot's next tenant
+    assert [st.req for st in eng._slots] == [n, b]
+    eng._step_complete(k)
+    while b.finish_reason is None or n.finish_reason is None:
+        eng._step()
+    assert list(b.result(5)) == ref_b and list(n.result(5)) == ref_n
+    # one fetch of a prefill's output, b's: the evicted row's never came
+    assert doomed.tokens == [] and log.count(('fetch', 1)) == 1
+    assert eng.stats()['active'] == 0 and eng._firsts == []
+
+    split = eng._split_load
+
+    def slow(out, n):
+        if n == 1:
+            time.sleep(0.3)             # the pick-up: stop() comes inside
+        return split(out, n)
+    eng.start()
+    _req, stream, got_n = _resident(eng, pn)
+    eng._split_load = slow
+    req = eng.submit(pa, max_new_tokens=30, deadline_s=60.0)
+    time.sleep(0.1)
+    eng.stop()
+    with pytest.raises(generate_mod.EngineStoppedError):
+        req.result(30)
+    with pytest.raises(generate_mod.EngineStoppedError):
+        for tok in stream:
+            got_n.append(tok)
+    assert len(req.tokens) <= 3
+    assert req.tokens == eng.generate_once(
+        pa, max_new_tokens=8)[:len(req.tokens)]
+    assert got_n == ref_n[:len(got_n)]
+    assert eng._flights == [] and eng._firsts == []
+    assert eng.stats()['active'] == 0
+    assert eng.stats()['blocks']['in_use'] == 0
     assert not any(t.name == 'paddle-generate' for t in threading.enumerate())

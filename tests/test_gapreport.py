@@ -95,6 +95,25 @@ def test_under_a_span_idle_and_busy_by_module():
         == pytest.approx(rep['busy_s'])
 
 
+def test_first_tokens_left_on_the_device_of_the_windows_admissions():
+    """The third table's last line: admission spans that start in the
+    window, and the runs of the module that writes a prefill's first
+    token into the next step's input; nothing where no admission is."""
+    assert gapreport.report(TRACE, min_gap_ns=5)['first_tokens'] == \
+        {'admitted': 0, 'on_device': 0}
+    assert 'first tokens' not in gapreport.render(
+        gapreport.report(TRACE, min_gap_ns=5), 5e-6)
+    served = dict(TRACE, spans=TRACE['spans'] + [
+        ('generate.prefill', 20, 4), ('generate.prefill.dispatch', 21, 2),
+        ('generate.prefill', 50, 4), ('generate.prefill', 120, 4)])
+    served['modules'] = {'/device:TPU:0': TRACE['modules'][
+        '/device:TPU:0'] + [('jit_first_token_put', 41, 1)]}
+    rep = gapreport.report(served, min_gap_ns=5)
+    assert rep['first_tokens'] == {'admitted': 2, 'on_device': 1}
+    assert 'first tokens left on the device: 1 of 2 admissions' \
+        in gapreport.render(rep, 5e-6)
+
+
 def test_timeline_is_the_open_span_that_started_last():
     segs = gapreport.timeline([('outer', 0, 100), ('a', 10, 20),
                                ('b', 40, 5), ('late', 120, 10)])

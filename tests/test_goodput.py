@@ -334,6 +334,28 @@ def test_sentinel_bench_row_drift():
     assert trips[-1]['baseline'] == 1.0
 
 
+def test_stats_returns_while_a_loop_keeps_a_dispatch_in_flight():
+    """A pipelined serving loop always has a step in flight: by the time
+    one record's leaf is done the next record is queued. A blocking
+    drain takes what was queued when it began and returns — stats()
+    must not chase the queue for as long as the loop runs (on the chip:
+    `eng.stats()` held a benchmark's window open for 50 s)."""
+    class _Leaf(object):
+        """Done after 1 ms, by which time the loop dispatched again."""
+
+        def block_until_ready(self):
+            time.sleep(0.001)
+            now = time.perf_counter()
+            goodput.note_dispatch('fp:loop', 'run', now, now, leaf=_Leaf())
+            return self
+    now = time.perf_counter()
+    goodput.note_dispatch('fp:loop', 'run', now, now, leaf=_Leaf())
+    t0 = time.perf_counter()
+    done = goodput.stats()
+    assert time.perf_counter() - t0 < 1.0
+    assert done['dispatches'] >= 1
+
+
 def test_dispatch_hook_overhead_guard():
     """The exact per-dispatch addition (note_dispatch) stays <= 5 us:
     interleaved min-of-per-call, gc disabled — the PR 9 methodology (a

@@ -2,9 +2,12 @@
 Executor.run's phase counters add up to the wall time they split, spans
 and phases land in a profiler trace as `paddle_tpu:<name>`, compiled
 programs carry their program's name, and a phase costs little with no
-profiler session. Since PR 37 also the admission taken apart: the three
-phases nested in `prefill`, and every delivered token gap booked by
-whether another request's admission completed inside it."""
+profiler session. Since PR 37 also the admission taken apart — since PR
+38 `prefill.dispatch` nested in `prefill`, and `prefill.fetch`, the
+loop's wait for the first token where it picks it up, a pass or two
+later; `prefill.drain` is gone with the wait it timed — and every
+delivered token gap booked by whether another request's admission
+completed inside it."""
 import gc
 import glob
 import os
@@ -20,11 +23,10 @@ from paddle_tpu import monitor
 from paddle_tpu.core import lowering
 from paddle_tpu.models.transformer import LMConfig, build_lm_decode_step
 from paddle_tpu.serving import GenerateConfig, GenerateEngine
-from paddle_tpu.serving.generate import _Flight
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOOP = 'generate_loop_seconds_total'
-ADMISSION = ('prefill', 'prefill.dispatch', 'prefill.drain', 'prefill.fetch')
+ADMISSION = ('prefill', 'prefill.dispatch', 'prefill.fetch')
 RUN = 'executor_run_phase_seconds_total'
 
 
@@ -118,20 +120,25 @@ def test_loop_phases_add_up_to_the_loops_wall_time():
     delta = monitor.counter_delta(before)
     phases = _phases(delta, LOOP)
     wall = delta['generate_loop_wall_seconds_total']
-    assert {'admit', 'prefill', 'prefill.dispatch', 'prefill.drain',
-            'prefill.fetch', 'feed', 'dispatch', 'admit_overlapped',
-            'wait', 'deliver', 'yield', 'idle'} <= set(phases)
+    assert {'admit', 'prefill', 'prefill.dispatch', 'prefill.fetch',
+            'feed', 'dispatch', 'admit_overlapped', 'wait', 'deliver',
+            'yield', 'idle'} <= set(phases)
+    assert 'prefill.drain' not in phases    # no admission waits for a step
     # self times: nothing counts twice, and little is left uncovered
     assert sum(phases.values()) <= wall * 1.001
     assert sum(phases.values()) >= wall * 0.95
-    # `admit` holds admissions without their prefills: those are `prefill`
-    # and the three phases nested in it, the stretch prefill_seconds times
+    # `admit` holds admissions without their prefills: those are
+    # `prefill`, the bound call nested in it, and the wait at the
+    # pick-up — the loop's own time for an admission, which is what
+    # prefill_seconds observes. A request's `prefill` stage runs from the
+    # admission's start to the token on the host, ACROSS the passes in
+    # between where a step was in flight: no shorter
     prefill_s = _hist_sum('prefill_seconds') - prefill0
     parts = [phases[p] for p in ADMISSION]
     assert sum(parts) == pytest.approx(prefill_s, rel=0.2)
-    # the bound call, the wait for the steps in flight and the prefill
-    # alone are nearly all of it: `prefill` keeps only the feed's making
-    assert min(parts) > 0 and phases['prefill'] < 0.5 * sum(parts)
+    assert min(parts) > 0
+    assert sum(r.result().timing['prefill_s'] for r in reqs) \
+        >= prefill_s * 0.999
     assert phases['admit'] + phases['admit_overlapped'] < wall - prefill_s
     assert delta['generate_admit_total'] == len(work)
     assert delta['generate_queue_wait_seconds_total'] > 0
@@ -142,60 +149,78 @@ def test_loop_phases_add_up_to_the_loops_wall_time():
     assert loop['phase_s']['feed'] == flat[LOOP + '{phase=feed}']
 
 
-class _StepInFlight(object):
-    """A decode step's first output, done `seconds` after it is asked."""
-
-    def __init__(self, seconds):
-        self.seconds = seconds
-
-    def block_until_ready(self):
-        time.sleep(self.seconds)
-
-
-def test_prefill_drain_is_the_wait_for_the_steps_in_flight():
-    """Nothing in flight: the bound call and the fetch, and the drain's
-    counter does not move. A step in flight: the drain is the wait for
-    it, and what `decode_step_seconds` leaves out is the fetch phase's
-    duration, the prefill alone."""
+def test_an_admission_with_a_step_in_flight_blocks_for_nothing():
+    """A step is in flight when B is admitted: the admission is its
+    prefill's bound call and returns with the step still unfetched —
+    nothing is fetched, `prefill.drain` never opens, and no phase counter
+    of the admission moves yet. The wait is where the token is picked up,
+    before the fetch of the first step that carries B: `prefill.fetch`,
+    and that step's `decode_step_seconds` runs from the pick-up's end,
+    not from the fetch before: what lies between was the prefill's.
+    There the admission is booked whole, counters and histogram at one
+    moment: `prefill_seconds` observes the three phases' seconds."""
     eng = _engine()
     eng.warmup()
-    prompt = _prompt(5, 1)
-    table = eng._slot_table(eng._alloc_blocks(
-        -(-prompt.size // eng.config.block_size)))
+    a = eng.submit(_prompt(5, 1), max_new_tokens=8)
+    eng._admit()
+    eng._step()
+    k = eng._step_dispatch()                    # step k, in flight
     before = monitor.counters()
-    first = eng._run_prefill(prompt, table)
-    alone = _phases(monitor.counter_delta(before), LOOP)
-    assert set(alone) == {'prefill.dispatch', 'prefill.fetch'}
-    assert min(alone.values()) > 0 and eng._prefill_alone_s == 0.0
-    eng._flights = [_Flight([_StepInFlight(0.03)], [], 0.0, False)]
-    before = monitor.counters()
-    assert eng._run_prefill(prompt, table) == first
-    behind = _phases(monitor.counter_delta(before), LOOP)
-    eng._flights = []
-    assert set(behind) == set(ADMISSION) - {'prefill'}
-    assert behind['prefill.drain'] == pytest.approx(0.03, abs=0.01)
-    assert eng._prefill_alone_s == pytest.approx(behind['prefill.fetch'],
-                                                 rel=1e-6)
+    prefill0 = _hist_sum('prefill_seconds')
+    b = eng.submit(_prompt(9, 2), max_new_tokens=4)
+    t0 = time.perf_counter()
+    eng._admit()
+    admit_s = time.perf_counter() - t0
+    assert _phases(monitor.counter_delta(before), LOOP) == {}
+    assert eng._flights == [k] and b.tokens == []
+    nxt = eng._step_dispatch(prev=k)            # carries B on the device
+    eng._step_complete(k, nxt)
+    between = _phases(monitor.counter_delta(before), LOOP)
+    assert not set(between) & set(ADMISSION) and b.tokens == []
+    split, step0 = eng._split_load, _hist_sum('decode_step_seconds')
+    eng._split_load = lambda out, n: (time.sleep(0.05 if n == 1 else 0.0),
+                                      split(out, n))[1]
+    t0 = time.perf_counter()
+    eng._step_complete(nxt)                     # the pick-up, then k + 1
+    whole_s = time.perf_counter() - t0
+    eng._split_load = split
+    got = _phases(monitor.counter_delta(before), LOOP)
+    assert 'prefill.drain' not in got and set(ADMISSION) <= set(got)
+    assert got['prefill.fetch'] >= 0.05 and len(b.tokens) == 2
+    # the step's observation begins where the first token landed
+    assert _hist_sum('decode_step_seconds') - step0 \
+        <= whole_s - got['prefill.fetch']
+    assert 0 < got['prefill'] + got['prefill.dispatch'] <= admit_s
+    assert _hist_sum('prefill_seconds') - prefill0 == pytest.approx(
+        sum(got[p] for p in ADMISSION), rel=1e-6)
+    # with nothing in flight the wait is the prefill's own: no step's
+    picked = eng._picked_t
+    lone = eng.submit(_prompt(4, 3), max_new_tokens=1)
+    _drive(eng, a, b, lone)
+    assert eng._picked_t == picked
 
 
 def test_a_chunked_prefill_books_every_chunk():
     """A prompt wider than the widest bucket prefills in chunks, each
     through `_prefill_call`: the three dispatches of a 40-token prompt
-    (16 + 16 + 8) are all inside the two phases."""
+    (16 + 16 + 8) are all inside the one phase, and only the last one's
+    output is fetched — the pick-up's."""
     eng = _engine()
     eng.warmup()
-    calls = []
-    bound = {b: (lambda feed, return_numpy, _b=b, _f=f:
-                 calls.append(_b) or _f(feed, return_numpy=return_numpy))
-             for b, f in eng._prefill_bound.items()}
-    prompt = _prompt(40, 2)
-    table = eng._slot_table(eng._alloc_blocks(
-        -(-prompt.size // eng.config.block_size)))
+    calls, fetched = [], []
+    for b, f in list(eng._prefill_bound.items()):
+        eng._prefill_bound[b] = (
+            lambda feed, return_numpy, _b=b, _f=f:
+            calls.append(_b) or _f(feed, return_numpy=return_numpy))
+    split = eng._split_load
+    eng._split_load = lambda out, n: (fetched.append(n), split(out, n))[1]
     before = monitor.counters()
-    eng._run_prefill(prompt, table, bound=bound)
+    req = eng.submit(_prompt(40, 2), max_new_tokens=1)
+    eng._admit()
     got = _phases(monitor.counter_delta(before), LOOP)
-    assert calls == [16, 16, 8]
-    assert set(got) == {'prefill.dispatch', 'prefill.fetch'}
+    assert calls == [16, 16, 8] and fetched == [1]
+    assert set(got) == set(ADMISSION) and len(req.result(5)) == 1
+    assert got['prefill.dispatch'] > got['prefill'] > 0
 
 
 def _drive(eng, *reqs):

@@ -19,9 +19,13 @@ over the traced window (benchmark.reduce_trace.traced_window):
 - what the device did UNDER each span, by the innermost span at every
   instant of the window: the seconds it was open, the device's idle
   seconds inside it (every gap, the short ones too) and its busy seconds
-  by module — `generate.prefill.drain` should hold the decode step only,
-  `generate.prefill.fetch` the prefill's bucket and then the idle tail
-  that is the fetch's latency.
+  by module — `generate.prefill.fetch`, the loop's wait where it picks a
+  prefill's first token up, should hold the rest of the prefill's bucket
+  and no idle tail (two decode steps are queued behind it), and no
+  `generate.prefill.drain` opens: an admission waits for no step. One
+  line under the table: of the window's admissions (`generate.prefill`
+  spans), those whose first token was written into the next step's input
+  on the device (runs of `jit_first_token_put`).
 
 docs/observability.md "Reading a device trace".
 """
@@ -40,6 +44,10 @@ from benchmark import reduce_trace as rt                   # noqa: E402
 
 SPAN_PREFIX = 'paddle_tpu:'
 MODULES_LINE = 'XLA Modules'
+# an admission's span, and the module that leaves its first token on the
+# device (serving/generate.py `_admit_one`, `_put_first`)
+ADMISSION_SPAN = 'generate.prefill'
+FIRST_TOKEN_MODULE = 'jit_first_token_put'
 _RUN_ID = re.compile(r'\(\d+\)$')
 
 
@@ -116,8 +124,10 @@ def report(trace, min_gap_ns=1000000):
     min_gap_ns), 'busy' {module: seconds} and 'runs' {module: its runs
     that touch the window, over all devices}; 'under' {span: {'open_s',
     'idle_s', 'busy': {module: seconds}}}, the whole window by the
-    innermost span at every instant. Seconds are per device, averaged
-    over the devices in the trace."""
+    innermost span at every instant; 'first_tokens' {'admitted': the
+    admission spans that start in the window, 'on_device': the runs of
+    the module that leaves a first token on the device}. Seconds are per
+    device, averaged over the devices in the trace."""
     t0, t1 = rt.traced_window(trace)
     segments = timeline(trace['spans'])
     starts = [a for a, _b, _n in segments]
@@ -175,6 +185,10 @@ def report(trace, min_gap_ns=1000000):
                       'busy': {m: b / n / 1e9
                                for m, b in busy_under.get(k, {}).items()}}
                   for k, ns in open_ns.items()},
+        'first_tokens': {
+            'admitted': sum(1 for name, s, _d in trace['spans']
+                            if name == ADMISSION_SPAN and t0 <= s < t1),
+            'on_device': runs.get(FIRST_TOKEN_MODULE, 0)},
     }
 
 
@@ -215,6 +229,12 @@ def render(rep, min_gap_ms):
             name, row['open_s'], row['idle_s'], ', '.join(
                 '%s %.4f' % kv for kv in sorted(row['busy'].items(),
                                                 key=lambda kv: -kv[1]))))
+    first = rep['first_tokens']
+    if first['admitted']:
+        out.append('first tokens left on the device: %d of %d admissions '
+                   '(%s runs / %s spans)'
+                   % (first['on_device'], first['admitted'],
+                      FIRST_TOKEN_MODULE, ADMISSION_SPAN))
     return '\n'.join(out)
 
 
