@@ -42,7 +42,16 @@ class LMConfig(object):
     - ``n_kv_head``: fewer K/V heads than query heads (grouped-query
       attention): query head ``h`` reads K/V head ``h // (n_head //
       n_kv_head)``, and the pools hold ``n_kv_head`` heads;
-    - ``layer_types``: one of ``'attention'`` | ``'conv'`` a layer. A
+    - ``layer_types``: one of ``'attention'`` | ``'conv'`` | ``'window'`` a
+      layer. A ``'window'`` layer is an attention layer whose query at
+      position ``i`` sees key ``j`` iff ``0 <= i - j < sliding_window``
+      (K-EXAONE's, EXAONE 4.0's local layers). It keeps K and V pools of
+      its own, under block ids of its own: a slot's FEW blocks there are a
+      ring (logical block ``b`` lies in column ``b % ring`` of the slot's
+      window table, `window_ring`), so a page behind the window is written
+      over and the pools do not grow with the context. With
+      ``global_rope=False`` the ``'attention'`` (global) layers rotate
+      nothing (NoPE) and only the window layers are rotated. A
       ``'conv'`` layer's mixer is LFM2's gated short convolution
       (``[B | C | u] = z W_in``; ``y = (C * conv(B * u)) W_out``, a
       causal depthwise convolution of ``conv_kernel`` taps, no bias); it
@@ -91,7 +100,8 @@ class LMConfig(object):
                  attention='mha', q_lora_rank=0, kv_lora_rank=0,
                  qk_nope_dim=0, qk_rope_dim=0, v_head_dim=0,
                  rope_interleave=False, layer_types=None, conv_kernel=3,
-                 n_kv_head=None, tie_embeddings=False, router_eps=1e-20):
+                 n_kv_head=None, tie_embeddings=False, router_eps=1e-20,
+                 sliding_window=0, global_rope=True):
         self.vocab_size = vocab_size
         self.seq_len = seq_len
         self.d_model = d_model
@@ -149,18 +159,27 @@ class LMConfig(object):
         self.conv_kernel = conv_kernel
         self.tie_embeddings = tie_embeddings
         self.router_eps = router_eps
+        self.sliding_window = int(sliding_window)
+        self.global_rope = bool(global_rope)
         if len(self.layer_types) != n_layer or \
-                set(self.layer_types) - {'attention', 'conv'}:
+                set(self.layer_types) - {'attention', 'conv', 'window'}:
             raise ValueError("LMConfig.layer_types=%r: expected %d of "
-                             "'attention' | 'conv'"
+                             "'attention' | 'conv' | 'window'"
                              % (self.layer_types, n_layer))
+        if bool(self.n_window_layers) != (self.sliding_window > 0):
+            raise ValueError("LMConfig.layer_types=%r with LMConfig."
+                             "sliding_window=%r: 'window' layers and a "
+                             "window's size come together"
+                             % (self.layer_types, sliding_window))
         if n_head % self.n_kv_head:
             raise ValueError('LMConfig.n_kv_head=%r does not divide '
                              'n_head=%r' % (self.n_kv_head, n_head))
         if attention == 'mla' and (self.n_kv_head != n_head
-                                   or self.n_conv_layers):
+                                   or self.n_conv_layers
+                                   or self.n_window_layers):
             raise ValueError("LMConfig.attention='mla' is built with "
-                             "neither n_kv_head nor 'conv' layer_types")
+                             "neither n_kv_head nor 'conv' or 'window' "
+                             "layer_types")
         if attention == 'mla' and not (
                 position == 'rope' and q_lora_rank and kv_lora_rank
                 and qk_nope_dim and qk_rope_dim and v_head_dim):
@@ -186,8 +205,19 @@ class LMConfig(object):
         return self.layer_types.count('conv')
 
     @property
+    def n_window_layers(self):
+        return self.layer_types.count('window')
+
+    @property
     def n_attn_layers(self):
-        return self.n_layer - self.n_conv_layers
+        """The GLOBAL attention layers: those of the K/V pools that the
+        block allocator's tables address."""
+        return self.n_layer - self.n_conv_layers - self.n_window_layers
+
+    def rotates(self, layer):
+        """Whether `layer`'s q and k are rotated by the positions."""
+        return self.position == 'rope' and (
+            self.global_rope or self.layer_types[layer] == 'window')
 
     def layer_ordinal(self, layer):
         """`layer`'s place among the layers of its kind: the `layer`
@@ -325,12 +355,13 @@ def _bias(cfg, name):
     return ParamAttr(name=name) if cfg.bias else False
 
 
-def _heads_of(cfg, flat, p, which, pos, T):
+def _heads_of(cfg, flat, p, which, pos, T, rotate):
     """One of q / k / v from its flat projection ([S, H*dh] decode rows,
     [1, T, H*dh] in a prefill; H the K/V heads for k and v): the optional
     q/k-norm (over the whole width before the split into heads, or over
-    each head after it), the optional rotation by the fed positions; laid
-    out as the cache ops want it ([S, H, dh]; [1, H, T, dh])."""
+    each head after it), the rotation by the fed positions where the
+    layer `rotate`s; laid out as the cache ops want it ([S, H, dh];
+    [1, H, T, dh])."""
     h = cfg.n_head if which == 'q' else cfg.n_kv_head
     dh = cfg.head_dim
     rows = T is None
@@ -344,17 +375,18 @@ def _heads_of(cfg, flat, p, which, pos, T):
     if normed and cfg.qk_norm == 'head':
         x = layers.rms_norm(x, begin_norm_axis=2 if rows else 3,
                             epsilon=cfg.rms_eps, param_attr=norm_attr)
-    if cfg.position == 'rope' and which != 'v':
+    if rotate and which != 'v':
         x = layers.rotary_embedding(x, pos, theta=cfg.rope_theta)
     return x if rows else layers.transpose(x, perm=[0, 2, 1, 3])
 
 
-def _qkv(cfg, ln1, p, pos, T=None):
+def _qkv(cfg, ln1, p, pos, T=None, layer=0):
     """The block's q, k, v from its normed input: the fused projection,
     then each prepared for the cache ops. ``T`` None: decode rows
     ``[S, d]`` -> three ``[S, H, dh]``; else one prompt ``[1, T, d]`` ->
     three ``[1, H, T, dh]``. K comes back as it is CACHED: after k-norm
-    and rotation, on its ``n_kv_head`` heads (as V is)."""
+    and rotation (`LMConfig.rotates(layer)`), on its ``n_kv_head`` heads
+    (as V is)."""
     if cfg.attention == 'mla':
         return _mla_qkv(cfg, ln1, p, pos, T)
     h, dh = cfg.n_head, cfg.head_dim
@@ -375,7 +407,7 @@ def _qkv(cfg, ln1, p, pos, T=None):
     axis = 1 if T is None else 2
     return [_heads_of(cfg, layers.slice(qkv, axes=[axis], starts=[start],
                                         ends=[end]),
-                      p, which, pos, T)
+                      p, which, pos, T, cfg.rotates(layer))
             for which, start, end in zip('qkv', [0] + ends, ends)]
 
 
@@ -698,6 +730,16 @@ def build_lm(cfg=None, is_test=False):
 # Parameter names match build_lm exactly — a scope trained (or loaded) for
 # the LM serves decode without any renaming.
 #
+# A model with WINDOW layers (`LMConfig.layer_types` 'window') declares a
+# second pair of pools for them, `WINDOW_CACHE_K` / `WINDOW_CACHE_V`
+# ([slots * ring + 1, window layers, block_size, kv_width]: `window_ring`
+# blocks a slot, used as a ring, and the trash block), and both programs
+# take a second table feed, 'gen_wtab' [rows, ring]: the engine gives slot
+# i its ring for as long as it is resident (serving/kv_blocks.py
+# `WindowRings`), so these pools are sized by the slots and no allocator
+# serves them. Each attention layer's ops get its kind's pool, table and
+# bound; only the window layers rotate q and k where `global_rope` is off.
+#
 # The decode step (and the prefill, for the FIRST token) ends in the
 # `sample_next_token` op: per-slot temperature / top-k / top-p feeds plus
 # a host-fed uniform drive sampling; temperature 0 rows return the bitwise
@@ -720,39 +762,86 @@ def build_lm(cfg=None, is_test=False):
 KV_CACHE_K = 'gen_kv_k'
 KV_CACHE_V = 'gen_kv_v'
 CONV_CACHE = 'gen_conv_tail'
+WINDOW_CACHE_K = 'gen_kv_window_k'
+WINDOW_CACHE_V = 'gen_kv_window_v'
+
+
+def window_ring(cfg, block_size):
+    """Blocks a slot owns in the window layers' pools, the columns of its
+    window table: logical block ``b`` lies in column ``b % ring``. The
+    keys a query sees, ``pos - sliding_window + 1 .. pos``, lie in at most
+    ``ceil(sliding_window / block_size) + 1`` blocks (one more than the
+    window fills when it does not start on a block's first row). The
+    ring is one block more than that, ISSUE 41's size (64 x 6 blocks): no
+    case needs the sixth -- a step writes its row before it attends, a
+    chunk attends before it writes, and the block either opens lies a
+    whole ring behind the oldest key still seen -- and ROADMAP R5 queues
+    taking it out."""
+    return -(-cfg.sliding_window // block_size) + 2
+
+
+def window_pool_blocks(cfg, slots, block_size):
+    """Blocks of the window layers' pools: every slot's ring, and block 0,
+    the trash block (an idle slot's table row is all zero)."""
+    return slots * window_ring(cfg, block_size) + 1
 
 
 def kv_cache_names(cfg):
-    """The pools a model's programs declare, all indexed by the same block
-    ids: K and V apart, or with latent attention the ONE pool of latent
-    rows (under K's name); with convolution layers the pool of their
-    tails as well."""
+    """The pools a model's programs declare. Indexed by the block
+    allocator's ids: K and V apart, or with latent attention the ONE pool
+    of latent rows (under K's name); with convolution layers the pool of
+    their tails as well. With window layers, indexed by the slots' rings
+    (`window_ring`): those layers' K and V."""
     names = (KV_CACHE_K,) if cfg.attention == 'mla' \
         else (KV_CACHE_K, KV_CACHE_V)
-    return names + (CONV_CACHE,) if cfg.n_conv_layers else names
+    if cfg.n_conv_layers:
+        names += (CONV_CACHE,)
+    if cfg.n_window_layers:
+        names += (WINDOW_CACHE_K, WINDOW_CACHE_V)
+    return names
 
 
-def kv_cache_shapes(cfg, num_blocks, block_size):
-    """name -> shape of every pool of `kv_cache_names`. K/V: the
-    ATTENTION layers' pages, ``[num_blocks, n_attn_layers, block_size,
+def kv_cache_shapes(cfg, num_blocks, block_size, slots=None):
+    """name -> shape of every pool of `kv_cache_names`. K/V: the GLOBAL
+    attention layers' pages, ``[num_blocks, n_attn_layers, block_size,
     kv_width]``; the tails: a block's ``conv_kernel - 1`` rows a
     convolution layer, ``[num_blocks, n_conv_layers, conv_kernel - 1,
-    d_model]``."""
+    d_model]``; the window layers' K/V: ``[window_pool_blocks,
+    n_window_layers, block_size, kv_width]``, sized by the engine's
+    ``slots`` and nothing else."""
     kv = (num_blocks, cfg.n_attn_layers, block_size, cfg.kv_width)
-    tail = (num_blocks, cfg.n_conv_layers, cfg.conv_kernel - 1, cfg.d_model)
-    return {name: tail if name == CONV_CACHE else kv
-            for name in kv_cache_names(cfg)}
+    shapes = {KV_CACHE_K: kv, KV_CACHE_V: kv,
+              CONV_CACHE: (num_blocks, cfg.n_conv_layers,
+                           cfg.conv_kernel - 1, cfg.d_model)}
+    if cfg.n_window_layers:
+        if slots is None:
+            raise ValueError("LMConfig.layer_types=%r: the window layers' "
+                             "pools are sized by the slots"
+                             % (cfg.layer_types,))
+        shapes[WINDOW_CACHE_K] = shapes[WINDOW_CACHE_V] = (
+            window_pool_blocks(cfg, slots, block_size), cfg.n_window_layers,
+            block_size, cfg.kv_width)
+    return {name: shapes[name] for name in kv_cache_names(cfg)}
 
 
-def _declare_paged_kv_caches(block, cfg, num_blocks, block_size):
-    """(K pool, V pool, tail pool) of `kv_cache_names`; None for a pool
-    the model does not have."""
+def _declare_paged_kv_caches(block, cfg, num_blocks, block_size, slots=None):
+    """(K pool, V pool, tail pool, window K pool, window V pool) of
+    `kv_cache_names`; None for a pool the model does not have."""
     pools = {name: block.create_var(name=name, shape=shape, dtype='float32',
                                     persistable=True, stop_gradient=True)
-             for name, shape in kv_cache_shapes(cfg, num_blocks,
-                                                block_size).items()}
-    return [pools.get(name) for name in (KV_CACHE_K, KV_CACHE_V,
-                                         CONV_CACHE)]
+             for name, shape in kv_cache_shapes(cfg, num_blocks, block_size,
+                                                slots).items()}
+    return [pools.get(name) for name in (KV_CACHE_K, KV_CACHE_V, CONV_CACHE,
+                                         WINDOW_CACHE_K, WINDOW_CACHE_V)]
+
+
+def _window_table(cfg, block_size):
+    """The feed of the slots' window tables, 'gen_wtab' ``[rows,
+    window_ring]``; None for a model without window layers."""
+    if not cfg.n_window_layers:
+        return None
+    return layers.data(name='gen_wtab', shape=[window_ring(cfg, block_size)],
+                       dtype='int64')
 
 
 SAMPLE_FEEDS = ('gen_temp', 'gen_topk', 'gen_topp', 'gen_u')
@@ -812,7 +901,8 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
     takes each layer's `_ffn` routing) serve the blocks that need them;
     ``conv(g, weight_attr, layer)`` is a convolution layer's cache op.
     The cache closures get a layer's ORDINAL among the layers of its
-    kind (`LMConfig.layer_ordinal`): a pool holds one kind."""
+    kind (`LMConfig.layer_ordinal`) and the kind (``'attention'`` |
+    ``'window'``): a pool holds one kind."""
     delta = None             # previous layer's deferred FFN output
     for i in range(cfg.n_layer):
         p = 'layer_%d' % i
@@ -821,13 +911,14 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
         if cfg.layer_types[i] == 'conv':
             attn = _conv_mixer(cfg, ln1, p, nth, conv, 1)
         else:
-            q, k, v = _qkv(cfg, ln1, p, pos)                 # [S, H, dh]
-            cache_write(k, v, nth)
+            kind = cfg.layer_types[i]
+            q, k, v = _qkv(cfg, ln1, p, pos, layer=i)        # [S, H, dh]
+            cache_write(k, v, nth, kind)
             if not head and i == cfg.n_layer - 1:
                 # write-only tower, last layer: nothing consumes x past
                 # this K/V deposit — attention/proj/ffn are dead compute
                 return None
-            ctx = attend(q, nth, p + tag)
+            ctx = attend(q, nth, p + tag, kind)
             attn = layers.fc(
                 layers.reshape(ctx, shape=[-1, cfg.attn_width]),
                 size=cfg.d_model,
@@ -851,7 +942,9 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
     'gen_pos' [slots, 1] int64 (the position each slot writes this step),
     the `SAMPLE_FEEDS` quad [slots, 1] (temperature / top-k / top-p /
     host uniform; all-zero = bitwise greedy), and 'gen_btab'
-    [slots, max_len // block_size] int64 per-slot block tables. Returns
+    [slots, max_len // block_size] int64 per-slot block tables; with
+    window layers also 'gen_wtab' [slots, window_ring], the slots' rings
+    in those layers' pools. Returns
     {'tokens', 'pos', 'logits', 'next_tokens', 'k_cache', 'v_cache'} —
     fetch 'next_tokens' ([slots] int64). With experts
     (`cfg.ffn == 'moe'`) also 'tokens_and_load' — next_tokens and the
@@ -866,8 +959,9 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
     block = tokens.block
     mb = max_len // block_size
     btab = layers.data(name='gen_btab', shape=[mb], dtype='int64')
-    kc, vc, tails = _declare_paged_kv_caches(block, cfg, num_blocks,
-                                             block_size)
+    wtab = _window_table(cfg, block_size)
+    kc, vc, tails, wkc, wvc = _declare_paged_kv_caches(
+        block, cfg, num_blocks, block_size, slots)
 
     x = layers.embedding(
         tokens, size=[cfg.vocab_size, d], dtype='float32',
@@ -881,31 +975,38 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
             g, tails, pos, btab, layer, block_size, cfg.conv_kernel,
             param_attr=weight_attr)
 
-    def cache_write(k, v, layer):
-        for cache, new in ((kc, k), (vc, v)):
+    def cache_write(k, v, layer, kind):
+        # a window layer writes into its slot's ring: the table's column
+        # is the logical block modulo the table's width
+        pools, table, ring = ((wkc, wvc), wtab, {'ring': True}) \
+            if kind == 'window' else ((kc, vc), btab, {})
+        for cache, new in zip(pools, (k, v)):
             if cache is None:       # latent attention: no V pool
                 continue
             block.append_op(
                 type='kv_cache_update_paged',
                 inputs={'Cache': [cache], 'New': [new],
-                        'Positions': [pos], 'BlockTables': [btab]},
+                        'Positions': [pos], 'BlockTables': [table]},
                 outputs={'Out': [cache]},
-                attrs={'layer': int(layer),
-                       'block_size': int(block_size)})
+                attrs=dict(ring, layer=int(layer),
+                           block_size=int(block_size)))
 
-    def attend(q, layer, name):
+    def attend(q, layer, name, kind):
         if cfg.attention == 'mla':
             return _mla_attend(cfg, layers.mla_decode_attention, q, kc,
                                pos, btab, layer)
+        pools, table, bound = ((wkc, wvc), wtab,
+                               {'window': cfg.sliding_window}) \
+            if kind == 'window' else ((kc, vc), btab, {})
         ctx = block.create_var(name=name + '.kv_ctx',
                                shape=(-1, h, dh), dtype='float32')
         block.append_op(
             type='kv_decode_attention_paged',
-            inputs={'Q': [q], 'KCache': [kc], 'VCache': [vc],
-                    'Positions': [pos], 'BlockTables': [btab]},
+            inputs={'Q': [q], 'KCache': [pools[0]], 'VCache': [pools[1]],
+                    'Positions': [pos], 'BlockTables': [table]},
             outputs={'Out': [ctx]},
-            attrs={'layer': layer, 'scale': dh ** -0.5,
-                   'block_size': int(block_size)})
+            attrs=dict(bound, layer=layer, scale=dh ** -0.5,
+                       block_size=int(block_size)))
         return ctx
 
     # an idle slot's table row is all zero and a live slot's first page is
@@ -965,7 +1066,8 @@ def build_lm_drafter(cfg, slots, max_len, spec_k, num_blocks, block_size):
     vmask = layers.data(name='gen_vmask', shape=[spec_k + 1],
                         dtype='int64')
     block = tokens.block
-    kc, vc, _ = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
+    kc, vc = _declare_paged_kv_caches(block, cfg, num_blocks,
+                                      block_size)[:2]
     pe = layers.assign(position_encoding_table(max_len, d))
 
     drafts = []
@@ -986,7 +1088,7 @@ def build_lm_drafter(cfg, slots, max_len, spec_k, num_blocks, block_size):
         # the host never accepts, and its cache write is vmask-trashed
         x = layers.elementwise_add(x, layers.gather(pe, pos_j))
 
-        def cache_write(k, v, layer, _pos=pos_j, _valid=valid_j):
+        def cache_write(k, v, layer, _kind, _pos=pos_j, _valid=valid_j):
             for cache, new in ((kc, k), (vc, v)):
                 block.append_op(
                     type='kv_cache_update_paged',
@@ -997,7 +1099,7 @@ def build_lm_drafter(cfg, slots, max_len, spec_k, num_blocks, block_size):
                     attrs={'layer': int(layer),
                            'block_size': int(block_size)})
 
-        def attend(q, layer, name, _pos=pos_j):
+        def attend(q, layer, name, _kind, _pos=pos_j):
             ctx = block.create_var(name=name + '.kv_ctx',
                                    shape=(-1, h, dh), dtype='float32')
             block.append_op(
@@ -1068,7 +1170,8 @@ def build_lm_verify(cfg, slots, width, max_len, num_blocks, block_size):
     btab = layers.data(name='gen_btab', shape=[mb], dtype='int64')
     vmask = layers.data(name='gen_vmask', shape=[W], dtype='int64')
     block = tokens.block
-    kc, vc, _ = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
+    kc, vc = _declare_paged_kv_caches(block, cfg, num_blocks,
+                                      block_size)[:2]
 
     flat = layers.reshape(tokens, shape=[-1])                # [S*W]
     x = layers.embedding(
@@ -1077,7 +1180,7 @@ def build_lm_verify(cfg, slots, width, max_len, num_blocks, block_size):
     pe = layers.assign(position_encoding_table(max_len, d))
     x = layers.elementwise_add(x, layers.gather(pe, pos))
 
-    def cache_write(k, v, layer):
+    def cache_write(k, v, layer, _kind):
         # tower rows [S*W, H, dh] -> the span op's [S, H, W, dh]
         for cache, new in ((kc, k), (vc, v)):
             rows = layers.transpose(
@@ -1092,7 +1195,7 @@ def build_lm_verify(cfg, slots, width, max_len, num_blocks, block_size):
                 attrs={'layer': int(layer),
                        'block_size': int(block_size)})
 
-    def attend(q, layer, name):
+    def attend(q, layer, name, _kind):
         qw = layers.transpose(layers.reshape(q, shape=[-1, W, h, dh]),
                               perm=[0, 2, 1, 3])             # [S,H,W,dh]
         ctx = block.create_var(name=name + '.verify_attn_out',
@@ -1119,7 +1222,7 @@ def build_lm_verify(cfg, slots, width, max_len, num_blocks, block_size):
 
 
 def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
-                           max_blocks):
+                           max_blocks, slots=None):
     """Prefill one prompt SUFFIX (padded to the `prompt_len` bucket) into
     a paged cache slot and emit the first generated token.
 
@@ -1135,7 +1238,12 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     'gen_pos' [1, prompt_len] int64 (global positions ctx_len + t,
     host-precomputed), 'gen_btab' [1, max_blocks] int64 (the slot's
     block table), 'gen_len' [1, 1] int64 (REAL suffix length; pad rows
-    write to the trash block), and the `SAMPLE_FEEDS` quad [1, 1].
+    write to the trash block), and the `SAMPLE_FEEDS` quad [1, 1]. With
+    window layers also 'gen_wtab' [1, window_ring], the slot's ring in
+    those layers' pools (sized by ``slots``): such a layer attends the
+    suffix's own rows and the ``sliding_window - 1`` rows its ring holds
+    from before them, THEN writes — of the suffix, only the rows a later
+    query can still see.
     Returns {'prompt', 'positions', 'block_table', 'length', 'logits',
     'first_token', 'k_cache', 'v_cache'}, and with experts
     'tokens_and_load' (first_token and the [n_layer * n_experts] expert
@@ -1150,8 +1258,9 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     length = layers.data(name='gen_len', shape=[1], dtype='int64')
     sample_vars = _sampling_inputs()
     block = prompt.block
-    kc, vc, tails = _declare_paged_kv_caches(block, cfg, num_blocks,
-                                             block_size)
+    wtab = _window_table(cfg, block_size)
+    kc, vc, tails, wkc, wvc = _declare_paged_kv_caches(
+        block, cfg, num_blocks, block_size, slots)
 
     x = layers.embedding(
         prompt, size=[cfg.vocab_size, d], dtype='float32',
@@ -1164,13 +1273,13 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
         pe_rows = layers.reshape(layers.gather(pe, pos), shape=[-1, T, d])
         x = layers.elementwise_add(x, pe_rows)
 
-    def cache_write(cache, new, layer):
+    def cache_write(cache, new, layer, table=btab, **bound):
         block.append_op(
             type='kv_cache_prefill_paged',
             inputs={'Cache': [cache], 'New': [new], 'Positions': [pos],
-                    'BlockTable': [btab], 'Length': [length]},
+                    'BlockTable': [table], 'Length': [length]},
             outputs={'Out': [cache]},
-            attrs={'layer': int(layer), 'block_size': int(block_size)})
+            attrs=dict(bound, layer=int(layer), block_size=int(block_size)))
         return cache
 
     def conv(g, weight_attr, layer):
@@ -1178,26 +1287,41 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
             g, tails, pos, btab, length, layer, block_size, cfg.conv_kernel,
             param_attr=weight_attr)
 
-    def attention(ln1, p, nth):
+    def attention(ln1, p, nth, layer):
         """An attention layer's mixer: q, k, v, the cache writes, the
         suffix's attention against the slot's pages, the projection."""
-        nonlocal kc, vc
-        q, k, v = _qkv(cfg, ln1, p, pos, T)                  # [1,H,T,dh]
-        kc = cache_write(kc, k, nth)
+        nonlocal kc, vc, wkc, wvc
+        q, k, v = _qkv(cfg, ln1, p, pos, T, layer=layer)     # [1,H,T,dh]
+        window = cfg.layer_types[layer] == 'window'
+        if not window:
+            kc = cache_write(kc, k, nth)
         if cfg.attention == 'mla':
             ctx = _mla_attend(cfg, layers.mla_prefix_attention, q, kc, pos,
                               btab, nth)                     # [1,T,H,v]
         else:
-            vc = cache_write(vc, v, nth)
+            ins, bound = {'KCache': [kc], 'VCache': [vc],
+                          'BlockTable': [btab]}, {}
+            if window:
+                # the ring cannot hold the suffix: its own K and V go into
+                # the attention as they are, beside the rows the ring holds
+                # from before them, and are written behind it
+                ins = {'KCache': [wkc], 'VCache': [wvc], 'K': [k], 'V': [v],
+                       'BlockTable': [wtab], 'Length': [length]}
+                bound = {'window': cfg.sliding_window}
+            else:
+                vc = cache_write(vc, v, nth)
+                ins['VCache'] = [vc]
             ctx = block.create_var(name=p + '.prefix_attn_out',
                                    shape=(-1, h, T, dh), dtype='float32')
             block.append_op(
                 type='kv_prefix_attention',
-                inputs={'Q': [q], 'KCache': [kc], 'VCache': [vc],
-                        'Positions': [pos], 'BlockTable': [btab]},
+                inputs=dict(ins, Q=[q], Positions=[pos]),
                 outputs={'Out': [ctx]},
-                attrs={'layer': nth, 'scale': dh ** -0.5,
-                       'block_size': int(block_size)})
+                attrs=dict(bound, layer=nth, scale=dh ** -0.5,
+                           block_size=int(block_size)))
+            if window:
+                wkc = cache_write(wkc, k, nth, wtab, **bound)
+                wvc = cache_write(wvc, v, nth, wtab, **bound)
             ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
         ctx = layers.reshape(ctx, shape=[0, T, cfg.attn_width])
         return layers.fc(ctx, size=d, num_flatten_dims=2,
@@ -1213,7 +1337,7 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
         if cfg.layer_types[i] == 'conv':
             attn = _conv_mixer(cfg, ln1, p, nth, conv, 2)
         else:
-            attn = attention(ln1, p, nth)
+            attn = attention(ln1, p, nth, i)
         ln2, x = _norm(cfg, x, attn, 2, p + '.ln2')
         delta, routed = _ffn(cfg, ln2, p, 2, length=length, layer=i)
         if routed is not None:

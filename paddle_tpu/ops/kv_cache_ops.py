@@ -64,6 +64,19 @@ against the block-table cache — prefix K/V are read, never recomputed,
 so shared-prefix traffic buckets by suffix length and skips the shared
 prefill compute entirely.
 
+A WINDOW layer's calls (PR 41: attribute ``window`` on the two attention
+ops and on the prefill's write, ``ring`` on the decode step's) go through
+pools and tables of their own: the table is the slot's RING of
+`models.transformer.window_ring` blocks, logical block ``b`` in column ``b
+% ring``, so a page behind the window is written over and those pools do
+not grow with the context. The decode attention sees keys ``p - window +
+1 .. p`` (the kernel starts at the first one's page; the gather takes the
+whole ring and masks by the position each place holds); the prefix
+attention takes the suffix's own K and V as inputs beside the ``window -
+1`` rows the ring holds from before it, and the suffix is written BEHIND
+it, its last ``window - 1`` real rows only. They lower under the named
+scope ``paddle_tpu:window_attention``.
+
 ``sample_next_token`` is the sampling leg: temperature / top-k / top-p
 over the step logits, driven by a HOST-FED per-slot uniform (the
 engine owns one PRNG stream per request), so the op is deterministic,
@@ -104,6 +117,8 @@ already zeroes them, and the engine returns their tail blocks to the
 allocator (serving/generate.py) — the block table is the rollback
 mechanism, no cache bytes are copied or cleared.
 """
+import contextlib
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -111,19 +126,46 @@ from jax import lax
 from ..core.registry import register_op
 
 _NEG_INF = -1e30
+# kv_prefix_attention: the float32 scores that may stand at once
+_SCORES_BYTES = 512 << 20
 
 
 # ---------------------------------------------------------------------------
 # paged (block-table) variants
 
 
-def _block_of(table, pos, block_size):
+WINDOW_SCOPE = 'paddle_tpu:window_attention'
+
+
+def _block_of(table, pos, block_size, ring=False):
     """(block id, in-block offset) of logical position(s) `pos` through a
     1-D block table. Out-of-range table indices clip to the last entry;
     unallocated entries hold 0 — the trash block — so a wild position can
-    only ever touch trash."""
-    idx = jnp.clip(pos // block_size, 0, table.shape[0] - 1)
+    only ever touch trash. ``ring``: the table is a window layer's ring,
+    logical block ``b`` in column ``b % len(table)``."""
+    idx = (pos // block_size) % table.shape[0] if ring \
+        else jnp.clip(pos // block_size, 0, table.shape[0] - 1)
     return table[idx].astype(jnp.int32), (pos % block_size).astype(jnp.int32)
+
+
+def _window_scope(window):
+    """The named scope a window layer's ops lower under, so that a device
+    trace tells them from the global layers'; nothing for those."""
+    return contextlib.nullcontext() if window is None \
+        else jax.named_scope(WINDOW_SCOPE)
+
+
+def _ring_positions(pos, ring, block_size):
+    """``[S, ring * block_size]``: the position whose row each place of a
+    slot's gathered ring holds, for slots writing at ``pos`` ``[S]`` —
+    column ``c`` holds the newest logical block ``b <= pos // block_size``
+    with ``b % ring == c``. Negative: no block of the slot came there
+    yet."""
+    newest = (pos // block_size)[:, None]
+    col = jnp.arange(ring)[None, :]
+    block = newest - (newest - col) % ring                    # [S, ring]
+    return (block[:, :, None] * block_size
+            + jnp.arange(block_size)).reshape(pos.shape[0], -1)
 
 
 def _gather_pages(cache, layer, tables, n_head):
@@ -145,7 +187,12 @@ def _kv_cache_prefill_paged(ctx, op):
     """Cache[table[(P+t)//bs], layer, :, (P+t)%bs, :] = New[0, :, t, :] for
     suffix rows t < Length; rows at or past the real suffix length are
     REDIRECTED to the trash block (a slot owns no span of its own, so
-    pad garbage must never land in a real block)."""
+    pad garbage must never land in a real block). With ``window`` the
+    table is the slot's ring in a window layer's pool, and of the suffix
+    only the ``window - 1`` last real rows are written: the keys a query
+    behind the suffix can still see, which a ring of
+    `models.transformer.window_ring` blocks holds without one landing on
+    another."""
     cache = ctx.in1(op, 'Cache')                # [NB, Ln, bs, H*dh]
     new = ctx.in1(op, 'New')                    # [1, H, T, dh]
     table = ctx.in1(op, 'BlockTable').reshape(-1).astype(jnp.int32)
@@ -153,10 +200,13 @@ def _kv_cache_prefill_paged(ctx, op):
     length = ctx.in1(op, 'Length').reshape(-1).astype(jnp.int32)
     layer = int(op.attr('layer'))
     bs = int(op.attr('block_size'))
+    window = op.attr('window', None)
     rows = jnp.transpose(new[0], (1, 0, 2)).astype(cache.dtype)  # [T,H,dh]
     rows = rows.reshape(rows.shape[0], -1)                       # [T,H*dh]
-    blk, off = _block_of(table, pos, bs)
+    blk, off = _block_of(table, pos, bs, ring=window is not None)
     real = jnp.arange(rows.shape[0]) < length[0]
+    if window is not None:
+        real &= pos > pos[0] + length[0] - int(window)
     blk = jnp.where(real, blk, 0)
     off = jnp.where(real, off, 0)
     out = cache.at[blk, layer, off, :].set(rows)
@@ -171,7 +221,9 @@ def _kv_cache_update_paged(ctx, op):
     An optional per-slot ``Valid`` input ([S] or [S, 1]; nonzero = keep)
     redirects invalid rows to the trash block explicitly — the drafter's
     unrolled steps use it for positions at or past ``max_len``, where
-    the clipped table lookup would otherwise target a LIVE block."""
+    the clipped table lookup would otherwise target a LIVE block.
+    ``ring``: the tables are the slots' rings in a window layer's pool
+    (`_block_of`), and the row lands on the oldest block's."""
     cache = ctx.in1(op, 'Cache')                # [NB, Ln, bs, H*dh]
     new = ctx.in1(op, 'New')                    # [S, H, dh]
     tables = ctx.in1(op, 'BlockTables').astype(jnp.int32)  # [S, MB]
@@ -179,7 +231,8 @@ def _kv_cache_update_paged(ctx, op):
     valid = ctx.in1(op, 'Valid')                # optional [S]/[S, 1]
     layer = int(op.attr('layer'))
     bs = int(op.attr('block_size'))
-    idx = jnp.clip(pos // bs, 0, tables.shape[1] - 1)
+    idx = (pos // bs) % tables.shape[1] if op.attr('ring', False) \
+        else jnp.clip(pos // bs, 0, tables.shape[1] - 1)
     blk = jnp.take_along_axis(tables, idx[:, None], axis=1)[:, 0]
     off = (pos % bs).astype(jnp.int32)
     if valid is not None:
@@ -271,36 +324,57 @@ def _kv_decode_attention_paged(ctx, op):
     lowered to the kernel), and ``xla`` / ``off`` are ONE gather
     formulation over the ``Hkv`` gathered heads, none repeated. A
     >1-device mesh has no kernel here (the pool is not
-    sharded): it takes ``xla``."""
+    sharded): it takes ``xla``. ``window`` (a window layer's call): the
+    tables are the slots' rings, the keys seen are ``Positions[s] - window
+    + 1 .. Positions[s]``; the kernel starts at the page of the first of
+    them under an operation name of its own, and the gather takes the
+    whole ring and masks by the position each place holds
+    (`_ring_positions`)."""
     from . import kernel_tier, paged_decode_attention as pda
-    from .. import monitor
     from ..parallel.api import get_active_mesh
     q = ctx.in1(op, 'Q')                        # [S, H, dh]
     kc = ctx.in1(op, 'KCache')                  # [NB, Ln, bs, Hkv*dh]
     vc = ctx.in1(op, 'VCache')
     tables = ctx.in1(op, 'BlockTables').astype(jnp.int32)  # [S, MB]
     pos = ctx.in1(op, 'Positions').reshape(-1)  # [S]
-    layer = int(op.attr('layer'))
-    scale = op.attr('scale', 1.0)
     bs = int(op.attr('block_size'))
-    MB = tables.shape[1]
+    window = op.attr('window', None)
     H, dh = q.shape[1], q.shape[2]
-    Hkv = kc.shape[3] // dh
     mesh = get_active_mesh()
     meshed = mesh is not None and mesh.size > 1
     impl = kernel_tier.dispatch(
         'kv_decode_attention_paged',
-        pallas_ok=pda.shapes_ok(H, dh, bs, Hkv) and not meshed, mesh=mesh)
+        pallas_ok=pda.shapes_ok(H, dh, bs, kc.shape[3] // dh) and not meshed,
+        mesh=mesh)
+    with _window_scope(window):
+        ctx.out(op, 'Out', _decode_attention(
+            impl, q, kc, vc, tables, pos, int(op.attr('layer')),
+            op.attr('scale', 1.0), bs, window))
+
+
+def _decode_attention(impl, q, kc, vc, tables, pos, layer, scale, bs,
+                      window):
+    """`kv_decode_attention_paged` under the tier `impl`; ``window`` None
+    for a layer that sees every key."""
+    from . import paged_decode_attention as pda
+    from .. import monitor
+    MB = tables.shape[1]
+    H, dh = q.shape[1], q.shape[2]
+    Hkv = kc.shape[3] // dh
     if impl in ('pallas', 'interpret'):
         # which of the kernel's two bodies this call site lowered to: the
         # head counts decide, at trace time
         monitor.inc('paged_decode_attention_form_total',
                     labels={'form': pda.form(H, Hkv)})
-        ctx.out(op, 'Out', pda.paged_decode_attention(
+        return pda.paged_decode_attention(
             q, kc, vc, tables, pos, jnp.int32(layer), scale=float(scale),
-            interpret=impl == 'interpret'))
-        return
-    m = jnp.arange(MB * bs)[None, None, :] <= pos[:, None, None]
+            interpret=impl == 'interpret', attention_span=window)
+    if window is None:
+        m = jnp.arange(MB * bs)[None, None, :] <= pos[:, None, None]
+    else:
+        at = _ring_positions(pos.astype(jnp.int32), MB, bs)[:, None, :]
+        m = (at >= 0) & (at <= pos[:, None, None]) \
+            & (at > pos[:, None, None] - window)
     if Hkv != H:
         # grouped queries: the H // Hkv query heads of a K/V head against
         # its gathered pages, which are not repeated
@@ -311,9 +385,8 @@ def _kv_decode_attention_paged(ctx, op):
                             preferred_element_type=jnp.float32) * scale
         scores = jnp.where(m[:, None], scores, _NEG_INF)
         w = jnp.where(m[:, None], jax.nn.softmax(scores, axis=-1), 0.0)
-        ctx.out(op, 'Out', jnp.einsum('skgm,smkd->skgd', w.astype(v.dtype),
-                                      v).reshape(q.shape))
-        return
+        return jnp.einsum('skgm,smkd->skgd', w.astype(v.dtype),
+                          v).reshape(q.shape)
     # xla keeps the gathered [S, M, H, dh]; off moves it to
     # [S, H, M, dh]
     gather, qk, wv = \
@@ -326,7 +399,7 @@ def _kv_decode_attention_paged(ctx, op):
     scores = jnp.where(m, scores, _NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     w = jnp.where(m, w, 0.0)
-    ctx.out(op, 'Out', jnp.einsum(wv, w.astype(v.dtype), v))
+    return jnp.einsum(wv, w.astype(v.dtype), v)
 
 
 @register_op('kv_prefix_attention', share_lod=False)
@@ -337,7 +410,14 @@ def _kv_prefix_attention(ctx, op):
     the shared prefix (cached by an earlier request) plus the suffix
     rows the surrounding program just deposited. With no shared prefix
     (Positions starting at 0) this is exactly the causal prefill
-    attention, computed from the cache instead of a local K/V copy."""
+    attention, computed from the cache instead of a local K/V copy.
+
+    ``window`` (a window layer's call; the table is the slot's ring):
+    query row t sees key j iff ``0 <= Positions[t] - j < window``. The
+    ring cannot hold a suffix, so the suffix's own ``K`` and ``V`` ([1,
+    Hkv, T, dh], the rows behind ``Length`` masked) are inputs and the
+    cache gives the ``window - 1`` rows before Positions[0] alone — the
+    program writes the suffix behind this op."""
     q = ctx.in1(op, 'Q')                        # [1, H, T, dh]
     kc = ctx.in1(op, 'KCache')                  # [NB, Ln, bs, Hkv*dh]
     vc = ctx.in1(op, 'VCache')
@@ -346,25 +426,66 @@ def _kv_prefix_attention(ctx, op):
     layer = int(op.attr('layer'))
     scale = op.attr('scale', 1.0)
     bs = int(op.attr('block_size'))
+    window = op.attr('window', None)
     MB = table.shape[0]
     H, T, dh = q.shape[1:]
     Hkv = kc.shape[3] // dh
-    k = _gather_heads(kc, layer, table, Hkv)           # [Hkv, MB*bs, dh]
-    v = _gather_heads(vc, layer, table, Hkv)
-    m = jnp.arange(MB * bs)[None, :] <= pos[:, None]       # [T, M]
-    if Hkv != H:
-        # grouped queries: a K/V head's H // Hkv query heads are rows of
-        # ONE matmul against it; the gathered keys are not repeated
-        qg = q[0].reshape(Hkv, (H // Hkv) * T, dh)
-        mg = jnp.tile(m, (H // Hkv, 1))[None]
+    if window is None:
+        k = _gather_heads(kc, layer, table, Hkv)       # [Hkv, MB*bs, dh]
+        v = _gather_heads(vc, layer, table, Hkv)
+        at = jnp.arange(MB * bs)               # the position a key holds
     else:
-        qg, mg = q[0], m[None]
-    scores = jnp.einsum('htd,hmd->htm', qg, k,
-                        preferred_element_type=jnp.float32) * scale
-    scores = jnp.where(mg, scores, _NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)
-    w = jnp.where(mg, w, 0.0)
-    out = jnp.einsum('htm,hmd->htd', w.astype(v.dtype), v)
+        length = ctx.in1(op, 'Length').reshape(-1)[0]
+        before = pos[0] - (window - 1) + jnp.arange(window - 1)
+        blk, off = _block_of(table, jnp.maximum(before, 0), bs, ring=True)
+
+        def keys(cache, own):
+            held = cache[blk, layer, off].reshape(window - 1, Hkv, dh)
+            return jnp.concatenate([jnp.moveaxis(held, 0, 1),
+                                    own[0].astype(cache.dtype)], axis=1)
+        k = keys(kc, ctx.in1(op, 'K'))                 # [Hkv, W-1+T, dh]
+        v = keys(vc, ctx.in1(op, 'V'))
+        # -1: no key (before position 0, behind the suffix's real rows)
+        at = jnp.concatenate([before, jnp.where(jnp.arange(T) < length,
+                                                pos, -1)])
+
+    def attend(rows):
+        """The queries `rows` = (q [H, t, dh], their positions [t])."""
+        qb, pb = rows
+        t = pb.shape[0]
+        m = at[None, :] <= pb[:, None]                     # [t, M]
+        if window is not None:
+            m &= (at[None, :] >= 0) & (at[None, :] > pb[:, None] - window)
+        if Hkv != H:
+            # grouped queries: a K/V head's H // Hkv query heads are rows
+            # of ONE matmul against it; the gathered keys are not repeated
+            qg = qb.reshape(Hkv, (H // Hkv) * t, dh)
+            mg = jnp.tile(m, (H // Hkv, 1))[None]
+        else:
+            qg, mg = qb, m[None]
+        scores = jnp.einsum('htd,hmd->htm', qg, k,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(mg, scores, _NEG_INF)
+        w = jax.nn.softmax(scores, axis=-1)
+        w = jnp.where(mg, w, 0.0)
+        return jnp.einsum('htm,hmd->htd', w.astype(v.dtype),
+                          v).reshape(H, t, dh)
+
+    # the scores of every head stand whole, [H, t, M] float32 twice over
+    # (K-EXAONE's 64 heads x 512 rows x 5120 keys: 1.34 GB): past
+    # `_SCORES_BYTES` the queries are taken in halves, one after another
+    n = 1
+    while H * (T // n) * at.shape[0] * 4 > _SCORES_BYTES \
+            and T % (2 * n) == 0:
+        n *= 2
+    with _window_scope(window):
+        if n == 1:
+            out = attend((q[0], pos))
+        else:
+            out = lax.map(attend, (
+                jnp.swapaxes(q[0].reshape(H, n, T // n, dh), 0, 1),
+                pos.reshape(n, T // n)))
+            out = jnp.swapaxes(out, 0, 1)
     ctx.out(op, 'Out', out.reshape(1, H, T, dh))           # [1, H, T, dh]
 
 

@@ -57,6 +57,18 @@ before the product, so whatever they hold (an earlier tenant's rows, a
 NaN) adds exactly 0. The two products take their float32 operands at
 ``_PRECISION``; see there.
 
+A bounded call (``span``, a window layer's: the slot's query sees the
+``span`` keys up to its position and no other) walks the same pipeline
+from the page of the FIRST key seen, ``(pos - span + 1) // bs``, so it
+reads at most ``ceil(span / bs) + 1`` pages a slot whatever the context.
+Its tables are rings (logical page ``p`` in column ``p % MB``,
+models/transformer.py `window_ring`), and the keys before the first one
+seen — the head of the first page — get the tail's treatment: score
+``-1e30``, weight exactly 0, V rows zeroed in the grouped body. It
+lowers under an operation name of its own, so a trace tells the window
+layers' calls from the global layers'. (The grouped body's "window" is a
+group of pages in one scores matmul and has nothing to do with it.)
+
 Slot independence is bitwise: the pages a slot visits, and the sequence
 of operations on them, depend on its own position, table row and query
 only. Which ring buffer a page lands in depends on the neighbours; the
@@ -136,10 +148,12 @@ def _segment_sum(x, heads):
 
 
 def _page_ring(tables_ref, pos_ref, layer_ref, k_hbm, v_hbm, k_buf, v_buf,
-               sems, cur, *, block_size, max_blocks, slots, ring, window=1):
-    """The DMA side both bodies share: `n_pages(slot)`, `copies(block,
-    place)` (the K and the V copy of one page into one ring place) and
-    `issue()`; at the first slot the ring is filled. ``cur``: [0]
+               sems, cur, *, block_size, max_blocks, slots, ring, window=1,
+               span=None):
+    """The DMA side both bodies share: `first_page(slot)` (0 unless the
+    call is bounded by ``span``), `n_pages(slot)`, `copies(block, place)`
+    (the K and the V copy of one page into one ring place) and `issue()`;
+    at the first slot the ring is filled. ``cur``: [0]
     windows consumed (pages, at ``window == 1``), [1] ring places handed
     out, [2]/[3] the prefetch cursor's slot and page. With ``window >
     1`` a slot's last page rounds the place up to the next window, so
@@ -150,8 +164,22 @@ def _page_ring(tables_ref, pos_ref, layer_ref, k_hbm, v_hbm, k_buf, v_buf,
     bs = block_size
     layer = layer_ref[0]
 
+    def first_page(slot):
+        if span is None:
+            return 0
+        return jnp.maximum(pos_ref[slot] - (span - 1), 0) // bs
+
     def n_pages(slot):
-        return jnp.clip(pos_ref[slot] // bs, 0, max_blocks - 1) + 1
+        if span is None:
+            return jnp.clip(pos_ref[slot] // bs, 0, max_blocks - 1) + 1
+        return jnp.maximum(pos_ref[slot], 0) // bs - first_page(slot) + 1
+
+    def column(slot, page):
+        """Where the slot's table names its `page`-th page read: a
+        bounded call's table is a ring."""
+        if span is None:
+            return page
+        return (first_page(slot) + page) % max_blocks
 
     def copies(block, place):
         return [pltpu.make_async_copy(
@@ -170,7 +198,7 @@ def _page_ring(tables_ref, pos_ref, layer_ref, k_hbm, v_hbm, k_buf, v_buf,
 
         @pl.when(room)
         def _():
-            for c in copies(tables_ref[ps * max_blocks + pp],
+            for c in copies(tables_ref[ps * max_blocks + column(ps, pp)],
                             cur[1] % ring):
                 c.start()
             last = pp + 1 == n_pages(ps)
@@ -190,21 +218,23 @@ def _page_ring(tables_ref, pos_ref, layer_ref, k_hbm, v_hbm, k_buf, v_buf,
         cur[3] = 0
         lax.fori_loop(0, ring, lambda i, c: (issue(), c)[1], 0)
 
-    return n_pages, copies, issue
+    return first_page, n_pages, copies, issue
 
 
 def _kernel(tables_ref, pos_ref, layer_ref,          # scalar prefetch
             q_ref, k_hbm, v_hbm,                     # inputs
             o_ref,                                   # output
             k_buf, v_buf, sems, qb, m_scr, l_scr, acc_scr, cur,
-            *, scale, head_dim, block_size, max_blocks, slots, ring):
+            *, scale, head_dim, block_size, max_blocks, slots, ring,
+            span=None):
     """One query a K/V head (``G == 1``): the VPU body."""
     import jax.experimental.pallas as pl
     s = pl.program_id(0)
     bs, hd = block_size, q_ref.shape[-1]
-    n_pages, copies, issue = _page_ring(
+    first_page, n_pages, copies, issue = _page_ring(
         tables_ref, pos_ref, layer_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
-        cur, block_size=bs, max_blocks=max_blocks, slots=slots, ring=ring)
+        cur, block_size=bs, max_blocks=max_blocks, slots=slots, ring=ring,
+        span=span)
 
     qb[...] = jnp.broadcast_to(q_ref[0] * scale, (bs, hd))
     m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
@@ -227,8 +257,13 @@ def _kernel(tables_ref, pos_ref, layer_ref,          # scalar prefetch
         # only the slot's last page has rows past its position: every
         # other page compares against a limit no row reaches, so one body
         # serves both (traced and lowered once)
-        live = p * bs + row <= jnp.where(p == n - 1, pos,
-                                         jnp.iinfo(jnp.int32).max)
+        if span is None:
+            live = p * bs + row <= jnp.where(p == n - 1, pos,
+                                             jnp.iinfo(jnp.int32).max)
+        else:
+            # a bounded call: the rows ahead of the first key seen too
+            at = (first_page(s) + p) * bs + row
+            live = (at <= pos) & (at > pos - span)
         for c0 in range(0, hd, _LANES):
             sl = pl.ds(c0, _LANES)
             sc = _segment_sum(k_buf[r, :, sl] * qb[:, sl], heads)
@@ -277,7 +312,7 @@ def _grouped_kernel(tables_ref, pos_ref, layer_ref,  # scalar prefetch
                     o_ref,                           # output
                     k_buf, v_buf, sems, qbd, m_scr, l_scr, acc_scr, cur,
                     *, scale, head_dim, block_size, max_blocks, slots, ring,
-                    group, window):
+                    group, window, span=None):
     """``G`` queries a K/V head (``G > 1``): the MXU body."""
     import jax.experimental.pallas as pl
     s = pl.program_id(0)
@@ -285,10 +320,10 @@ def _grouped_kernel(tables_ref, pos_ref, layer_ref,  # scalar prefetch
     H = qbd.shape[0]
     n_kv = H // group
     keys = window * bs
-    n_pages, copies, issue = _page_ring(
+    first_page, n_pages, copies, issue = _page_ring(
         tables_ref, pos_ref, layer_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
         cur, block_size=bs, max_blocks=max_blocks, slots=slots, ring=ring,
-        window=window)
+        window=window, span=span)
 
     # row (g, j) = g * n_kv + j is query g of K/V head j: `mine[g]` says
     # where a row of the group g sits in its own head's lanes
@@ -309,6 +344,14 @@ def _grouped_kernel(tables_ref, pos_ref, layer_ref,  # scalar prefetch
     n_win = (n + window - 1) // window
     key = lax.broadcasted_iota(jnp.int32, (H, keys), 1)
 
+    def seen(at):
+        """Whether the key at place `at` of the slot's pages read (0 the
+        first row of the first page read) is one the query sees."""
+        if span is None:
+            return at <= pos
+        at = first_page(s) * bs + at
+        return (at <= pos) & (at > pos - span)
+
     def pages(w, carry):
         base = pl.multiple_of((cur[0] * window) % ring, window)
         have = jnp.minimum(window, n - w * window)
@@ -323,15 +366,18 @@ def _grouped_kernel(tables_ref, pos_ref, layer_ref,  # scalar prefetch
         # tail of its last page and the ring places no page came to. Their
         # weight is exactly 0; their V rows are zeroed here, so that 0
         # times whatever they hold (a NaN) adds 0 too
-        @pl.when(w == n_win - 1)
+        # (a bounded call's first window besides: the head of its first
+        # page)
+        @pl.when((w == n_win - 1) if span is None
+                 else (w == n_win - 1) | (w == 0))
         def _():
             v_row = lax.broadcasted_iota(jnp.int32, (keys, hd), 0)
             v = v_buf[here].reshape(keys, hd)
-            v_buf[here] = jnp.where(w * keys + v_row <= pos, v,
+            v_buf[here] = jnp.where(seen(w * keys + v_row), v,
                                     0.0).reshape(window, bs, hd)
 
         sc = _scores(qbd[...], k_buf[here].reshape(keys, hd))
-        live = w * keys + key <= pos
+        live = seen(w * keys + key)
         sc = jnp.where(live, sc, _NEG_INF)
         m_prev = m_scr[...]                                  # [H, 128]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
@@ -356,13 +402,16 @@ def _grouped_kernel(tables_ref, pos_ref, layer_ref,  # scalar prefetch
             keepdims=True).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=('scale', 'interpret'))
+@functools.partial(jax.jit, static_argnames=('scale', 'interpret',
+                                             'attention_span'))
 def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
-                           scale, interpret=False):
+                           scale, interpret=False, attention_span=None):
     """q ``[S, H, dh]``; pools ``[NB, Ln, bs, Hkv*dh]``; tables ``[S,
     MB]`` and pos ``[S]`` int32; layer an int32 scalar. Returns ``[S, H,
     dh]``: softmax(q . K[0..pos]) V[0..pos] per slot and head, query head
-    h against K/V head ``h // (H // Hkv)``.
+    h against K/V head ``h // (H // Hkv)``. ``attention_span`` (a window
+    layer's call): keys ``pos - attention_span + 1 .. pos`` alone, through
+    tables that are rings.
 
     Jitted, with `layer` an operand: the layers of a decode program call
     ONE traced function, so the kernel is traced and lowered to Mosaic
@@ -375,11 +424,14 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
     MB = tables.shape[1]
     Hkv = hd // dh
     G = H // Hkv
+    # said only where there is one: an unbounded call's kernel is built
+    # from the arguments it always had
+    bound = {} if attention_span is None else {'span': int(attention_span)}
     if G == 1:
         ring = ring_depth(Hkv, dh, bs)
         kernel = functools.partial(
             _kernel, scale=scale, head_dim=dh, block_size=bs,
-            max_blocks=MB, slots=S, ring=ring)
+            max_blocks=MB, slots=S, ring=ring, **bound)
         # bs online-softmax streams a head
         state = pltpu.VMEM((bs, hd), jnp.float32)
         scratch = [state, state, state, state]
@@ -388,7 +440,8 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
         ring = ring_depth(Hkv, dh, bs, window)
         kernel = functools.partial(
             _grouped_kernel, scale=scale, head_dim=dh, block_size=bs,
-            max_blocks=MB, slots=S, ring=ring, group=G, window=window)
+            max_blocks=MB, slots=S, ring=ring, group=G, window=window,
+            **bound)
         # one online-softmax stream a query row
         stat = pltpu.VMEM((H, _LANES), jnp.float32)
         scratch = [pltpu.VMEM((H, hd), jnp.float32), stat, stat,
@@ -413,7 +466,8 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name='paged_decode_attention',
+        name='paged_window_decode_attention' if bound
+        else 'paged_decode_attention',
     )(tables.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1),
       jnp.swapaxes(q.reshape(S, Hkv, G, dh), 1, 2).reshape(S, G, hd),

@@ -57,6 +57,18 @@ exactly); temperature 0 stays the bitwise greedy default, and the
 program count is unchanged — ``len(prompt_buckets) + 1`` fixed
 signatures, zero recompiles after warmup under any mixed traffic.
 
+WINDOW LAYERS (PR 41, ``LMConfig.layer_types`` ``'window'``) keep their K
+and V in pools of their own that the allocator does not manage: the
+engine sizes them from its slots — ``slots x window_ring`` blocks and the
+trash block — and every slot OWNS its ring of them while resident
+(serving/kv_blocks.py `WindowRings`; a second table a slot, fed as
+'gen_wtab' beside 'gen_btab'). Logical block ``b`` lies in column ``b %
+ring``: a page behind the window is written over, never handed to an
+allocator, and the window layers' cache does not grow with the context.
+``stats()['blocks']`` stays the GLOBAL layers' pool and gains
+``['window']``; prefix sharing and speculation are refused for such a
+model at construction.
+
 SPECULATIVE DECODING (PR 13, ``GenerateConfig(speculative=True)``)
 breaks the one-token-per-dispatch decode ceiling:
 a DRAFT model (``draft_model``; default = the target config, so a
@@ -87,7 +99,10 @@ chunk attending the cached prefix through ``kv_prefix_attention``
 exactly like a shared-prefix suffix, so admission reaches
 ``max_len - 1`` tokens with ZERO new compiled signatures and the
 continuation is bit-exact vs a single-shot prefill through a wider
-bucket.
+bucket. Behind a decode step in flight the chunks go out ONE A PASS of
+the loop (``_admit_run``): the residents' step runs between two chunks,
+so a token gap holds one chunk and never a whole long prompt, and no
+other admission starts before the last chunk is out.
 
 THE LOOP IS A PIPELINE ONE STEP DEEP (PR 31): with step k dispatched and
 its tokens not fetched, step k + 1 is dispatched on them as they are on
@@ -130,11 +145,13 @@ from .. import trace as trace_mod
 from .. import unique_name
 from ..executor import Executor, Scope, scope_guard
 from ..framework import Program, TPUPlace, program_guard
-from ..models.transformer import (LMConfig, build_lm_decode_step,
+from ..models.transformer import (LMConfig, WINDOW_CACHE_K, WINDOW_CACHE_V,
+                                  build_lm_decode_step,
                                   build_lm_prefill_paged, kv_cache_names,
-                                  kv_cache_shapes)
+                                  kv_cache_shapes, window_ring)
 from ..reader.bucketing import bucketize
-from .kv_blocks import BlockAllocator, PrefixCache, chain_hashes
+from .kv_blocks import (BlockAllocator, PrefixCache, WindowRings,
+                        chain_hashes)
 from .batcher import (DeadlineExceededError, EngineStoppedError,
                       LoadShedError, Request, RequestQueue,
                       resolve_metrics_port, start_metrics_server)
@@ -505,21 +522,42 @@ class _Flight(object):
         return np.asarray(self.out[0])
 
 
-class _First(object):
-    """An admission between its prefill's dispatch and the pick-up of
-    its first token (`GenerateEngine._pickup`)."""
-    __slots__ = ('slot', 'st', 'out', 't0', 'wall0', 'self_s', 'dispatch_s')
+class _Admission(object):
+    """An admission from the pop of its request to the dispatch of its
+    last prefill chunk: one pass of the loop, or — a prompt wider than
+    the widest bucket, behind a step in flight — a chunk a pass
+    (`GenerateEngine._admit_run`)."""
+    __slots__ = ('req', 'slot', 'blocks', 'table', 'hashes', 'off',
+                 'sample', 't0', 'wall0', 'self_s', 'dispatch_s', 'fetch_s')
 
-    def __init__(self, slot, st, out, t0, wall0, self_s, dispatch_s):
+    def __init__(self, req, slot, blocks, table, hashes, off, sample):
+        self.req = req
+        self.slot = slot
+        self.blocks = blocks
+        self.table = table
+        self.hashes = hashes
+        self.off = off          # the prompt positions prefilled so far
+        self.sample = sample
+        self.t0 = time.perf_counter()       # the admission's start
+        self.wall0 = time.time() * 1e6      # ... wall clock, us (the span)
+        # what the admission took the loop: the `prefill` phase's self
+        # time, the bound calls in it and the waits for its earlier
+        # chunks, over all its passes
+        self.self_s = self.dispatch_s = self.fetch_s = 0.0
+
+
+class _First(object):
+    """A prefill dispatch between its bound call and its pick-up
+    (`GenerateEngine._pickup`): an admission's last, whose first token
+    the pick-up fetches, or — `st` None — an earlier chunk of an
+    admission still under way, whose end the pick-up waits for."""
+    __slots__ = ('slot', 'st', 'out', 'adm')
+
+    def __init__(self, slot, st, out, adm):
         self.slot = slot
         self.st = st
         self.out = out          # the prefill's device fetch
-        self.t0 = t0            # the admission's start
-        self.wall0 = wall0      # ... on the wall clock, us (the span)
-        # what the admission took the loop: the `prefill` phase's self
-        # time and the bound calls in it, booked at the pick-up
-        self.self_s = self_s
-        self.dispatch_s = dispatch_s
+        self.adm = adm
 
 
 def block_copy_fn(backend):
@@ -598,6 +636,19 @@ class GenerateEngine(object):
                 "draft rewinds positions, and a convolution layer's tail "
                 "in the block pool cannot be rewound (it holds the last "
                 "rows written, not every row)" % (c.model.layer_types,))
+        for option in ('speculative', 'prefix_sharing'):
+            if getattr(c, option) and c.model.n_window_layers:
+                raise ValueError(
+                    "%s=True with LMConfig.layer_types=%r: a window "
+                    "layer's blocks are a ring that its slot writes over "
+                    "— a rejected draft cannot be unwound from it, and a "
+                    "shared block's window rows are gone once its first "
+                    "tenant has moved on" % (option, c.model.layer_types))
+        # the window layers' pool: a ring of blocks a slot, sized from
+        # the slots alone (None for a model without such layers)
+        self._rings = WindowRings(
+            c.slots, window_ring(c.model, c.block_size), c.block_size) \
+            if c.model.n_window_layers else None
         if c.speculative:
             self._draft_cfg = c.draft_model or c.model
             # +1 over the all-slots-at-max_len footprint (the trash
@@ -647,7 +698,12 @@ class GenerateEngine(object):
         # ... and the last first token picked up behind steps in flight:
         # up to there the device's time was a prefill's, no step's
         self._picked_t = 0.0
-        self._admit_seq = 0     # first tokens picked up (`_Slot.admit_seq`)
+        # prefill dispatches picked up (`_Slot.admit_seq`): first tokens,
+        # and the earlier chunks of a chunked admission
+        self._admit_seq = 0
+        # the chunked admission under way (`_admit_run`), if any: its slot
+        # is neither free nor resident, and no other admission starts
+        self._chunking = None
         self._first_carried = 0
         self._decode_steps = 0
         self._sampled_steps = 0
@@ -696,7 +752,7 @@ class GenerateEngine(object):
                 with unique_name.guard():
                     v = build_lm_prefill_paged(
                         cfg, b, c.num_blocks, c.block_size,
-                        self._max_blocks)
+                        self._max_blocks, slots=c.slots)
             self._prefill[b] = (main, v)
         if c.speculative:
             from ..models.transformer import (build_lm_drafter,
@@ -785,7 +841,8 @@ class GenerateEngine(object):
 
     def _ensure_cache(self):
         """Make the scope's pools (`kv_cache_names`: K, V, the
-        convolution tails) match THIS engine's geometry. A provided
+        convolution tails, the window layers' K and V) match THIS
+        engine's geometry. A provided
         scope may carry another engine's cache
         under the same names with a different pool shape; the cache
         holds no trained state, so re-zeroing is always safe, while
@@ -797,7 +854,7 @@ class GenerateEngine(object):
         import jax.numpy as jnp
         c = self.config
         pools = [(self.scope, kv_cache_shapes(c.model, c.num_blocks,
-                                              c.block_size))]
+                                              c.block_size, c.slots))]
         if c.speculative:
             pools.append((self._draft_scope, kv_cache_shapes(
                 self._draft_cfg, self._draft_nb, c.block_size)))
@@ -827,6 +884,8 @@ class GenerateEngine(object):
         s = np.asarray(src, 'int32')
         d = np.asarray(dst, 'int32')
         for name in kv_cache_names(self.config.model):
+            if name in (WINDOW_CACHE_K, WINDOW_CACHE_V):
+                continue    # not the allocator's: no block of it is shared
             self.scope.set(name, self._cow_jit(
                 self.executor._state_value(self.scope, name,
                                            self._step_prog, cache=False),
@@ -926,6 +985,33 @@ class GenerateEngine(object):
         table[:len(blocks)] = blocks
         return table
 
+    def _tables_feed(self, btab, slots=()):
+        """A program's table feeds: 'gen_btab', and for a model with
+        window layers 'gen_wtab', the rings of `slots` ((row, slot)
+        pairs; the other rows all zero, the trash block, as an idle
+        row's are)."""
+        feed = {'gen_btab': btab}
+        if self._rings is not None:
+            wtab = np.zeros((len(btab), self._rings.ring), 'int64')
+            for row, slot in slots:
+                wtab[row] = self._rings.table(slot)
+            feed['gen_wtab'] = wtab
+        return feed
+
+    def _ring_advance(self, slot, length):
+        """Book that `slot`'s tenant has reached `length` positions in
+        the window layers' pool (nothing without such layers)."""
+        if self._rings is not None:
+            n = self._rings.advance(slot, length)
+            if n:
+                monitor.inc('kv_window_blocks_recycled_total', n)
+
+    def _ring_release(self, slot):
+        if self._rings is not None:
+            n = self._rings.release(slot)
+            if n:
+                monitor.inc('kv_window_blocks_recycled_total', n)
+
     # ------------------------------------------------------------------
     # warmup
     def warmup(self):
@@ -959,9 +1045,9 @@ class GenerateEngine(object):
                 # live request could own
                 pfeed = {'gen_prompt': np.zeros((1, b), 'int64'),
                          'gen_len': np.ones((1, 1), 'int64'),
-                         'gen_pos': np.zeros((1, b), 'int64'),
-                         'gen_btab': np.zeros((1, self._max_blocks),
-                                              'int64')}
+                         'gen_pos': np.zeros((1, b), 'int64')}
+                pfeed.update(self._tables_feed(
+                    np.zeros((1, self._max_blocks), 'int64')))
                 pfeed.update(self._sample_feed(1))
                 fetch = [self._token_fetch(v, 'first_token')]
                 key, already = farm.track(self.executor, prog, pfeed,
@@ -974,8 +1060,9 @@ class GenerateEngine(object):
                 else:
                     farm.commit(key)
             feed = {'gen_tokens': np.zeros((S, 1), 'int64'),
-                    'gen_pos': np.zeros((S, 1), 'int64'),
-                    'gen_btab': np.zeros((S, self._max_blocks), 'int64')}
+                    'gen_pos': np.zeros((S, 1), 'int64')}
+            feed.update(self._tables_feed(
+                np.zeros((S, self._max_blocks), 'int64')))
             feed.update(self._sample_feed(S))
             fetch = [self._token_fetch(self._step_vars, 'next_tokens')]
             key, already = farm.track(
@@ -1270,7 +1357,7 @@ class GenerateEngine(object):
         table = self._slot_table(blocks)
         try:
             first = int(self._split_load(self._run_prefill(
-                prompt, table, sample + (draw_u(),)), 1)[0])
+                prompt, table, sample + (draw_u(),), slot=0), 1)[0])
             tokens, last, pos = [first], first, prompt.size
             while (len(tokens) < max_new_tokens and pos < c.max_len and
                    (c.eos_id is None or last != c.eos_id)):
@@ -1286,8 +1373,9 @@ class GenerateEngine(object):
                 toks[0], posf[0] = last, pos
                 btab = np.zeros((S, self._max_blocks), 'int64')
                 btab[0] = table
-                feed = {'gen_tokens': toks, 'gen_pos': posf,
-                        'gen_btab': btab}
+                feed = {'gen_tokens': toks, 'gen_pos': posf}
+                feed.update(self._tables_feed(btab, [(0, 0)]))
+                self._ring_advance(0, pos + 1)
                 sf = self._sample_feed(S)
                 sf['gen_temp'][0], sf['gen_topk'][0] = sample[0], sample[1]
                 sf['gen_topp'][0], sf['gen_u'][0] = sample[2], draw_u()
@@ -1299,6 +1387,7 @@ class GenerateEngine(object):
             return tokens
         finally:
             self._deref_blocks(blocks)
+            self._ring_release(0)
 
     # ------------------------------------------------------------------
     # decode loop
@@ -1323,7 +1412,10 @@ class GenerateEngine(object):
         row joins step k + 2 — the next pass's dispatch, a moment later
         — on its first token as the prefill leaves it on the device, and
         the loop picks that token up before step k + 2's own fetch
-        (`_pickup`), with the prefill long done or nearly so. Wherever
+        (`_pickup`), with the prefill long done or nearly so. A prompt
+        wider than the widest bucket is one CHUNK a pass (`_admit_run`):
+        the device runs step, chunk, step, chunk, and the step behind a
+        chunk waits for it before its own fetch. Wherever
         the admission sat in the pass the device would run the same
         programs in the same order; placed last it catches the client
         whose request the delivery just ended, in the same pass (placed
@@ -1423,10 +1515,20 @@ class GenerateEngine(object):
                         labels={'outcome': 'stopped'})
             req.fail(EngineStoppedError(
                 "engine stopped while the request waited for KV blocks"))
+        self._drop_chunking(EngineStoppedError(
+            "engine stopped between two chunks of the request's prefill"),
+            'stopped')
         self._set_occupancy()
 
     def _admit(self):
-        while self._free and not self._stop_evt.is_set():
+        if self._chunking is not None:
+            # its next chunk is this pass's admission, and no other
+            # starts before its last
+            adm, self._chunking = self._chunking, None
+            self._admit_run(adm)
+            return
+        while self._free and self._chunking is None \
+                and not self._stop_evt.is_set():
             req = self._pending_admit
             self._pending_admit = None
             if req is None:
@@ -1526,21 +1628,40 @@ class GenerateEngine(object):
             req.trace.add_stage('queue', qs)
             monitor.record_span('request.queue', req.enqueue_wall,
                                 qs * 1e6, tid=req._tid, trace=req.trace)
-        t0 = time.perf_counter()
-        pf_wall = time.time() * 1e6
+        return self._admit_run(_Admission(
+            req, slot, blocks, table, hashes, int(ctx_len),
+            (req.temperature, req.top_k, req.top_p, req._draw_u())))
+
+    def _admit_run(self, adm):
+        """The prefill of admission `adm` from `adm.off` on, and with
+        its last dispatch the request resident. Behind a step in flight
+        a prompt wider than the widest bucket takes ONE chunk a pass
+        (`_chunking` keeps it for the next): the residents' step runs
+        between two chunks, so a token gap holds one chunk and not the
+        whole prompt's — a 4096-token prompt at a bucket of 512 held
+        every other stream for eight chunks in one gap. With nothing to
+        wait behind (the start, after an idle spell, the inline paths)
+        and on a speculative engine every chunk goes now. Returns True:
+        the request was consumed."""
+        c = self.config
+        req, slot, blocks = adm.req, adm.slot, adm.blocks
         dblocks, dtable = None, None
         # behind a step in flight the admission ends with its dispatch;
-        # with nothing to wait behind (the start, after an idle spell,
-        # the inline paths) the pass is the serial one, the token now.
-        # So for a speculative engine: a round reads `last` on the host
+        # with nothing to wait behind the pass is the serial one, the
+        # token now. So for a speculative engine: a round reads `last`
+        # on the host
         defer = bool(self._flights) and not c.speculative
+        done = False
         with _loop_phase('prefill', counted=False) as own:
             try:
-                out = self._run_prefill(
-                    req.prompt, table,
-                    (req.temperature, req.top_k, req.top_p, req._draw_u()),
-                    ctx_len=ctx_len)
-                if c.speculative:
+                while not done:
+                    out, adm.off = self._prefill_dispatch(
+                        req.prompt, adm.off, adm.table, adm.sample,
+                        self._prefill_bound, slot)
+                    done = adm.off >= req.prompt.size
+                    if defer and not done:
+                        break
+                if done and c.speculative:
                     # the draft tracks the request in its OWN pool: full
                     # prompt (no prefix cache — draft K/V are
                     # model-specific throwaways), chunked exactly like the
@@ -1556,7 +1677,7 @@ class GenerateEngine(object):
                     else:
                         self._run_prefill(req.prompt, dtable,
                                           bound=self._draft_prefill_bound)
-                if defer:
+                if done and defer:
                     self._put_first(slot, out)
             except Exception as e:  # noqa: BLE001 — delivered per-request
                 self._free.append(slot)
@@ -1568,20 +1689,28 @@ class GenerateEngine(object):
                 req.fail(e)
                 out = None
         # the bound calls are the phases nested in it
-        self_s, dispatch_s = own.dur_s - own.nested_s, own.nested_s
+        adm.self_s += own.dur_s - own.nested_s
+        adm.dispatch_s += own.nested_s
         if out is None:
-            _book_admission(self_s, dispatch_s)
+            _book_admission(adm.self_s, adm.dispatch_s)
+            return True
+        if not done:
+            # the step dispatched next waits for the chunk before its own
+            # fetch (`_pickup`): its time is then its own, and the gap
+            # the chunk sits in reads as one that held an admission
+            self._chunking = adm
+            self._firsts.append(_First(slot, None, out, adm))
             return True
         if self._prefix is not None:
             # publish this prompt's FULL blocks (immutable once
             # prefilled: decode writes land strictly past the prompt).
             # The prefill may still be running: whatever reads the
             # blocks is a program dispatched after it
-            for i, h in enumerate(hashes):
+            for i, h in enumerate(adm.hashes):
                 self._prefix.register(h, i, blocks[i])
-        st = _Slot(req, pos=req.prompt.size, blocks=blocks, table=table,
+        st = _Slot(req, pos=req.prompt.size, blocks=blocks, table=adm.table,
                    dblocks=dblocks, dtable=dtable)
-        st.first = _First(slot, st, out, t0, pf_wall, self_s, dispatch_s)
+        st.first = _First(slot, st, out, adm)
         self._slots[slot] = st
         if defer:
             # the row joins the next step on its token as it is on the
@@ -1592,6 +1721,18 @@ class GenerateEngine(object):
             self._pickup([st.first])
         self._set_occupancy()
         return True
+
+    def _drop_chunking(self, error, outcome):
+        """The chunked admission under way ends here: its slot and blocks
+        go back, its request fails with `error`."""
+        adm, self._chunking = self._chunking, None
+        if adm is None:
+            return
+        _book_admission(adm.self_s, adm.dispatch_s)
+        self._free.append(adm.slot)
+        self._deref_blocks(adm.blocks)
+        monitor.inc('generate_request_total', labels={'outcome': outcome})
+        adm.req.fail(error)
 
     def _pickup(self, firsts):
         """The first tokens of `firsts` on the host, oldest first, each
@@ -1613,21 +1754,27 @@ class GenerateEngine(object):
         admission dispatched since, once each — the cache is threaded
         through all of them (`_fail_step`)."""
         for n, f in enumerate(firsts):
-            st, r = f.st, f.st.req
-            # the loop's phase counters and prefill_seconds move for an
-            # admission at ONE moment, this one: a window's two deltas
-            # hold the same admissions
-            _book_admission(f.self_s, f.dispatch_s)
-            if self._slots[f.slot] is not st:
-                continue
+            st, adm = f.st, f.adm
+            if st is not None:
+                # the loop's phase counters and prefill_seconds move for
+                # an admission at ONE moment, this one: a window's two
+                # deltas hold the same admissions
+                _book_admission(adm.self_s, adm.dispatch_s)
+                if self._slots[f.slot] is not st:
+                    continue
             try:
                 with _loop_phase('prefill.fetch') as alone:
-                    st.last = int(self._split_load(f.out, 1)[0])
+                    if st is None:
+                        np.asarray(f.out)       # the chunk's end
+                    else:
+                        st.last = int(self._split_load(f.out, 1)[0])
             except Exception as e:  # noqa: BLE001 — delivered per-request
-                later = firsts[n:] + self._firsts + \
-                    [g for fl in self._flights for g in fl.firsts]
-                for g in later[1:]:
-                    _book_admission(g.self_s, g.dispatch_s)
+                later = [g for g in firsts[n:] + self._firsts +
+                         [g for fl in self._flights for g in fl.firsts]
+                         if g.st is not None]
+                # the failed one's own is booked above
+                for g in later[1 if st is not None else 0:]:
+                    _book_admission(g.adm.self_s, g.adm.dispatch_s)
                 self._firsts = []
                 for fl in self._flights:
                     fl.firsts = []
@@ -1637,17 +1784,27 @@ class GenerateEngine(object):
                 self._fail_step([(g.slot, g.st) for g in later], e,
                                 *self._flights)
                 return
+            if st is None:
+                # a chunk of an admission under way: no token, but the
+                # device's time up to here was the prefill's and no
+                # step's, and the gap it sits in held an admission
+                adm.fetch_s += alone.dur_s
+                self._admit_seq += 1
+                if self._flights:
+                    self._picked_t = time.perf_counter()
+                continue
+            r = st.req
             st.first = None
             now = time.perf_counter()
             if self._flights:
                 self._picked_t = now
-            pf_s = now - f.t0
-            monitor.observe('prefill_seconds',
-                            f.self_s + f.dispatch_s + alone.dur_s)
+            pf_s = now - adm.t0
+            monitor.observe('prefill_seconds', adm.self_s + adm.dispatch_s
+                            + adm.fetch_s + alone.dur_s)
             if r.trace is not None:
                 r.trace.add_stage('prefill', pf_s)
-                monitor.record_span('request.prefill', f.wall0, pf_s * 1e6,
-                                    trace=r.trace)
+                monitor.record_span('request.prefill', adm.wall0,
+                                    pf_s * 1e6, trace=r.trace)
             monitor.inc('decode_tokens_total')
             self._decode_tokens += 1
             r._emit(st.last)
@@ -1666,49 +1823,60 @@ class GenerateEngine(object):
         self._set_occupancy()
 
     def _run_prefill(self, prompt, table, sample=(0.0, 0, 0.0, 0.0),
-                     ctx_len=0, bound=None):
-        c = self.config
-        # only the UN-CACHED suffix is computed; it buckets by
-        # suffix length — the prefill-compute saving of a prefix hit.
-        # A suffix wider than the widest bucket runs CHUNKED: each
-        # widest-bucket chunk deposits its K/V and attends the cached
-        # prefix (kv_prefix_attention), exactly like a shared-prefix
-        # suffix — same compiled signatures, any prompt length. Only
-        # the FINAL chunk's first-token output is the model's answer.
+                     ctx_len=0, bound=None, slot=0):
+        """Every dispatch of a prefill from `ctx_len` on, back to back;
+        the last one's output, the model's answer."""
         bound = bound if bound is not None else self._prefill_bound
-        wide = c.prompt_buckets[-1]
         off = int(ctx_len)
+        while True:
+            out, off = self._prefill_dispatch(prompt, off, table, sample,
+                                              bound, slot)
+            if off >= prompt.size:
+                return out
+
+    def _prefill_dispatch(self, prompt, off, table, sample, bound, slot):
+        """One dispatch of a prefill: (its output, the position reached).
+        Only the UN-CACHED suffix is computed; it buckets by
+        suffix length — the prefill-compute saving of a prefix hit.
+        A suffix wider than the widest bucket runs CHUNKED: each
+        widest-bucket chunk deposits its K/V and attends the cached
+        prefix (kv_prefix_attention), exactly like a shared-prefix
+        suffix — same compiled signatures, any prompt length. Only
+        the FINAL chunk's first-token output is the model's answer."""
+        c = self.config
+        wide = c.prompt_buckets[-1]
         suffix = prompt[off:]
-        if c.model.n_conv_layers:
+        # the slot's own ring in the window layers' pool goes with it (the
+        # draft's prefills are of a model that has none)
+        tables = self._tables_feed(table[None], [(0, slot)])
+        if c.model.n_conv_layers and off > 0:
             # every dispatch that starts past position 0 — a hit's suffix,
             # a later chunk — resumes from a tail the pool holds
-            monitor.inc('conv_tail_resumes_total',
-                        (off > 0) + (suffix.size - 1) // wide)
-        while suffix.size > wide:
-            chunk, suffix = suffix[:wide], suffix[wide:]
+            monitor.inc('conv_tail_resumes_total')
+        if suffix.size > wide:
             pos = np.clip(off + np.arange(wide), 0, c.max_len - 1)
-            feed = {'gen_prompt': chunk[None],
+            feed = {'gen_prompt': suffix[:wide][None],
                     'gen_pos': pos[None].astype('int64'),
-                    'gen_btab': table[None],
                     'gen_len': np.array([[wide]], 'int64')}
+            feed.update(tables)
             feed.update(self._sample_feed(1))
-            # K/V deposited; the token output is never fetched
-            self._prefill_call(bound[wide], feed)
-            off += wide
+            # K/V deposited; the token output is never read
+            return self._prefill_call(bound[wide], feed), off + wide
         b = bucketize(suffix.size, c.prompt_buckets)
         padded = np.full((1, b), c.pad_id, 'int64')
         padded[0, :suffix.size] = suffix
         pos = np.clip(off + np.arange(b), 0, c.max_len - 1)
         feed = {'gen_prompt': padded,
                 'gen_pos': pos[None].astype('int64'),
-                'gen_btab': table[None],
                 'gen_len': np.array([[suffix.size]], 'int64')}
+        feed.update(tables)
         feed.update(self._sample_feed(1, *sample))
         out = self._prefill_call(bound[b], feed)
+        self._ring_advance(slot, prompt.size)
         # the copy to the host starts behind the prefill: by the pick-up
         # it is latency behind a busy device
         out.copy_to_host_async()
-        return out
+        return out, prompt.size
 
     def _prefill_call(self, bound, feed):
         """One prefill dispatch, phase `prefill.dispatch` inside
@@ -2087,7 +2255,8 @@ class GenerateEngine(object):
             sample = self._sample_feed(S)
             btab = np.zeros((S, self._max_blocks), 'int64')
             active = []
-            live_pages = live_tokens = carried = 0
+            windowed = self._rings is not None
+            live_pages = live_tokens = window_tokens = carried = 0
             for i, st in enumerate(self._slots):
                 if st is None or i in held:
                     continue
@@ -2110,6 +2279,9 @@ class GenerateEngine(object):
                 btab[i] = st.table
                 live_pages += at // c.block_size + 1
                 live_tokens += at + 1
+                if windowed:
+                    window_tokens += min(at + 1, c.model.sliding_window)
+                    self._ring_advance(i, at + 1)
                 active.append((i, st))
             # the admissions since the last dispatch: this step's to pick
             # up, whether or not their rows are in it
@@ -2135,10 +2307,16 @@ class GenerateEngine(object):
                 monitor.inc('kv_latent_tokens_read_total',
                             live_tokens * c.model.n_layer)
             else:
-                # the per-head K and V rows: the attention layers' alone
+                # the per-head K and V rows: the attention layers' alone,
+                # those that see every key ...
                 monitor.inc('kv_tokens_read_total',
                             live_tokens * c.model.n_attn_layers)
-            feed = {'gen_pos': pos, 'gen_btab': btab}
+            if windowed:
+                # ... and the window layers', a window's worth a slot
+                monitor.inc('kv_window_tokens_read_total',
+                            window_tokens * c.model.n_window_layers)
+            feed = {'gen_pos': pos}
+            feed.update(self._tables_feed(btab, ((i, i) for i, _ in active)))
             feed.update(sample)
         with _loop_phase('dispatch'):
             t0 = time.perf_counter()
@@ -2189,6 +2367,8 @@ class GenerateEngine(object):
             monitor.inc('generate_request_total',
                         labels={'outcome': 'error'})
             st.req.fail(e)
+        # ... and the prefill between two of its chunks
+        self._drop_chunking(e, 'error')
         self._set_occupancy()
 
     def _step_complete(self, flight, nxt=None):
@@ -2373,6 +2553,9 @@ class GenerateEngine(object):
         st = self._slots[i]
         if st is not None:
             self._release_blocks(st)
+            # its ring in the window layers' pool is the slot's: the next
+            # tenant's prefill writes what it reads of it
+            self._ring_release(i)
         self._slots[i] = None
         self._free.append(i)
 
@@ -2389,8 +2572,9 @@ class GenerateEngine(object):
         block-level capacity accounting — physical pool state, the
         peak footprint, and the prefix-cache entry count (the monitor
         mirrors it as kv_blocks_in_use/free), and the share of table
-        pages the decode steps read. 'loop' is where the loop thread's
-        time went, by phase (_loop_sums)."""
+        pages the decode steps read; it is the GLOBAL layers' pool, and
+        'window' in it the window layers' (`WindowRings`). 'loop' is
+        where the loop thread's time went, by phase (_loop_sums)."""
         steps = self._decode_steps
         out = {
             'slots': self.config.slots,
@@ -2417,6 +2601,12 @@ class GenerateEngine(object):
             if self._prefix is not None else 0,
             'decode_live_page_share': _live_page_share(),
         }
+        if self._rings is not None:
+            # the window layers' pool: what the resident slots have
+            # touched of their rings, at most slots x ring
+            out['blocks']['window'] = {'capacity': self._rings.capacity,
+                                       'ring': self._rings.ring,
+                                       'in_use': self._rings.in_use()}
         if self.config.speculative:
             prop = self._spec_proposed
             out['spec'] = {

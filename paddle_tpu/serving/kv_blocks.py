@@ -29,6 +29,18 @@ its own pool (sized ``slots * max_len / block_size`` + trash, so
 per-slot growth can never starve) with no prefix cache — draft K/V are
 model-specific throwaways.
 
+A model with WINDOW layers (``LMConfig.layer_types`` ``'window'``: a query
+sees the last ``sliding_window`` keys and no other) keeps those layers' K
+and V in pools of their own, and those are not the allocator's:
+``WindowRings`` gives every slot ``ring`` blocks of them for as long as it
+is resident, used as a ring — logical block ``b`` lies in column ``b %
+ring`` of the slot's window table — so a page behind the window is handed
+on by being written over, a slot's share never grows with its context and
+no step asks an allocator for anything. A shared block's window rows
+would be gone once its first tenant has moved on and a rejected draft
+cannot be unwound from a ring, so the engine refuses prefix sharing and
+speculation for such a model.
+
 Sharing is at FULL-BLOCK granularity. Because a block's K/V rows depend
 only on tokens at or before them (causal), a block fully covered by
 prompt tokens is immutable once prefilled — the one exception is a
@@ -43,7 +55,7 @@ import hashlib
 import threading
 
 __all__ = ['BlockAllocator', 'PrefixCache', 'QuotaBlockAllocator',
-           'chain_hashes']
+           'WindowRings', 'chain_hashes']
 
 
 def chain_hashes(tokens, block_size):
@@ -136,6 +148,47 @@ class BlockAllocator(object):
                 if self.deref(b):
                     freed += 1
             return freed
+
+
+class WindowRings(object):
+    """The window layers' pool, a ring of `ring` blocks a slot: slot `i`
+    owns blocks ``1 + i * ring .. (i + 1) * ring`` (block 0 is the trash
+    block, an idle slot's table row) whoever its tenant is. What it
+    accounts is how much of a ring its tenant has touched: `in_use`, at
+    most ``slots * ring`` whatever the contexts."""
+
+    def __init__(self, slots, ring, block_size):
+        self.ring = int(ring)
+        self.block_size = int(block_size)
+        self._opened = [0] * int(slots)   # logical blocks a tenant opened
+        self._tables = [list(range(1 + i * self.ring,
+                                   1 + (i + 1) * self.ring))
+                        for i in range(int(slots))]
+
+    @property
+    def capacity(self):
+        return len(self._opened) * self.ring
+
+    def table(self, slot):
+        """The slot's window table: its blocks, in column order."""
+        return self._tables[slot]
+
+    def advance(self, slot, length):
+        """The slot's tenant has written (or skipped: a prefill keeps a
+        chunk's last rows only) positions ``0 .. length - 1``. Returns
+        the blocks that took the place of ones the window left behind."""
+        before = self._opened[slot]
+        self._opened[slot] = max(before, -(-length // self.block_size))
+        return max(self._opened[slot], self.ring) - max(before, self.ring)
+
+    def release(self, slot):
+        """The tenant is gone: the blocks it had touched, handed back."""
+        held = min(self._opened[slot], self.ring)
+        self._opened[slot] = 0
+        return held
+
+    def in_use(self):
+        return sum(min(n, self.ring) for n in self._opened)
 
 
 class QuotaBlockAllocator(object):

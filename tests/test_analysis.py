@@ -10,13 +10,13 @@ import paddle_tpu as fluid
 from paddle_tpu import analysis, monitor, profiler
 
 
-def _build_mlp_train(batch_hint=64):
+def _build_mlp_train(batch_hint=64, hidden=64):
     """mnist-mlp train program in the CURRENT default programs (the
     conftest fixture provides fresh ones per test)."""
     img = fluid.layers.data(name='img', shape=[784], dtype='float32')
     label = fluid.layers.data(name='label', shape=[1], dtype='int64')
-    h = fluid.layers.fc(input=img, size=64, act='relu')
-    h = fluid.layers.fc(input=h, size=64, act='relu')
+    h = fluid.layers.fc(input=img, size=hidden, act='relu')
+    h = fluid.layers.fc(input=h, size=hidden, act='relu')
     pred = fluid.layers.fc(input=h, size=10, act='softmax')
     cost = fluid.layers.cross_entropy(input=pred, label=label)
     avg = fluid.layers.mean(cost)
@@ -61,7 +61,12 @@ class TestExplain(object):
         assert not delta.get('compile_cache_miss'), delta
 
     def test_run_registers_analytics_and_snapshot_flushes_gauges(self):
-        avg, _ = _build_mlp_train()
+        # whatever ran on this worker before: 64 other programs fill the
+        # gauge's series cap, and a program some earlier test compiled is
+        # neither registered nor exported a second time — so an empty
+        # monitor and a program of this test's own
+        monitor.reset()
+        avg, _ = _build_mlp_train(hidden=61)
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(fluid.default_startup_program())
         exe.run(fluid.default_main_program(), feed=_feed(),

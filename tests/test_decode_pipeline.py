@@ -714,3 +714,144 @@ def test_a_deadline_and_a_stop_with_a_first_token_pending(pool):
     assert eng.stats()['active'] == 0
     assert eng.stats()['blocks']['in_use'] == 0
     assert not any(t.name == 'paddle-generate' for t in threading.enumerate())
+
+
+# ---------------------------------------------------------------------------
+# (f) a chunked prefill takes a chunk a pass behind a step in flight
+
+with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       'benchmark_tests', 'configs', 'toy-kexaone.json')) as _f:
+    TOY_KEXAONE = json.load(_f)
+CHUNK_POOLS = ['dense', 'lfm2-tails', 'kexaone-rings']
+
+
+def _chunk_cfg(pool, **kw):
+    """Buckets of 8 and 16 under prompts of 35: two chunks of 16 and a
+    last dispatch of 3 rows in the 8 bucket. `kexaone-rings`: window
+    layers of 12 keys, their K/V in a ring a slot beside the global
+    layer's pool (tests/test_kexaone_serving.py's toy)."""
+    if pool == 'kexaone-rings':
+        from benchmark.models import kexaone
+        kw.setdefault('model', kexaone.lm_config(TOY_KEXAONE, MAX_LEN, False))
+        kw.setdefault('prefix_sharing', False)
+        return _cfg(**kw)
+    return _pool_cfg(pool, **kw)
+
+
+def _pass(eng, k):
+    """One pass of the loop behind step `k`, by hand: the next step out,
+    `k` landed and delivered, then the admissions."""
+    nxt = eng._step_dispatch(prev=k)
+    eng._step_complete(k, nxt)
+    eng._admit()
+    return nxt
+
+
+@pytest.mark.parametrize('pool', CHUNK_POOLS)
+def test_f_a_chunked_prefill_takes_one_chunk_a_pass(pool):
+    """A is resident with a step in flight; B's prompt is three
+    dispatches wide. Each pass dispatches ONE of them behind the step
+    just sent, so the device runs step, chunk, step, chunk: A's token gap
+    holds a chunk and never the whole prompt. B's slot is neither free
+    nor resident meanwhile, C waits in the queue until B's last chunk is
+    out, the step behind a chunk waits for the chunk before its own
+    fetch (and every gap with a chunk in it reads as one that held an
+    admission), and all three streams are generate_once's."""
+    eng = GenerateEngine(_chunk_cfg(pool))
+    work = [(_prompt(6, seed=91), 12), (_prompt(35, seed=92), 5),
+            (_prompt(7, seed=93), 4)]
+    ref = [eng.generate_once(p, max_new_tokens=n) for p, n in work]
+    log = []
+    _watch(eng, log)
+    before = monitor.counters()
+    a = eng.submit(work[0][0], max_new_tokens=work[0][1])
+    eng._admit()
+    k = eng._step_dispatch()
+    b, c = [eng.submit(p, max_new_tokens=n) for p, n in work[1:]]
+    del log[:]
+    eng._admit()
+    # one chunk and no more; B under way, C not popped
+    assert log == [('prefill', 16)]
+    assert eng._chunking is not None and eng._chunking.req is b
+    assert eng.queue.depth() == 1 and eng.stats()['active'] == 1
+    assert len(eng._free) == eng.config.slots - 2
+    assert [f.st for f in eng._firsts] == [None]
+    k = _pass(eng, k)
+    # step, A's tokens, then the second chunk behind the step just sent
+    assert log[1:] == [('step', 0), ('fetch', eng.config.slots),
+                       ('prefill', 16)]
+    assert eng._chunking.off == 32 and b.tokens == []
+    del log[:]
+    k = _pass(eng, k)
+    # the step that went out behind the FIRST chunk landed: no fetch of
+    # the chunk's output, the loop only waited for it; B's last dispatch
+    assert log == [('step', 0), ('fetch', eng.config.slots),
+                   ('prefill', 8)]
+    assert eng._chunking is None and eng.stats()['active'] == 2
+    assert b.tokens == [] and eng.queue.depth() == 1
+    del log[:]
+    k = _pass(eng, k)
+    # B's row joins on its token as it is on the device; C's turn
+    assert log == [('step', 1), ('fetch', eng.config.slots),
+                   ('prefill', 8)]
+    assert b.tokens == [] and eng.queue.depth() == 0
+    k = _pass(eng, k)
+    # the first token picked up before the fetch of the step it fed
+    assert b.tokens == ref[1][:2]
+    eng._step_complete(k)
+    reqs = (a, b, c)
+    while any(r.finish_reason is None for r in reqs):
+        eng._step()
+    assert [list(r.result(5)) for r in reqs] == ref
+    delta = monitor.counter_delta(before)
+    assert delta['generate_admit_total'] == 3
+    # A's gaps behind B's two chunks, B's last dispatch and C's prefill
+    assert a.admissions_waited == 4
+    assert eng._firsts == [] and eng.stats()['active'] == 0
+    if not eng.config.prefix_sharing:   # the index keeps B's full blocks
+        assert eng.stats()['blocks']['in_use'] == 0
+
+
+@pytest.mark.parametrize('pool', CHUNK_POOLS)
+def test_f_chunked_admissions_under_the_loop_and_a_stop_between_chunks(pool):
+    """Under the running loop, long and short prompts mixed: every stream
+    is generate_once's, `prefill_seconds` has one observation an
+    admission. Then stop() between two chunks of a prefill: the request
+    fails by name, its slot and blocks go back."""
+    eng = GenerateEngine(_chunk_cfg(pool))
+    work = [(_prompt(n, seed=100 + n), m)
+            for n, m in ((35, 6), (5, 9), (33, 4), (17, 8), (40, 5), (9, 7))]
+    ref = [eng.generate_once(p, max_new_tokens=n) for p, n in work]
+    before = monitor.snapshot()['histograms'].get(
+        'prefill_seconds', {}).get('count', 0)
+    eng.start()
+    _req, stream, _got = _resident(eng, _prompt(4, seed=99))
+    reqs = [eng.submit(p, max_new_tokens=n, deadline_s=60.0)
+            for p, n in work]
+    assert [list(r.result(60)) for r in reqs] == ref
+    after = monitor.snapshot()['histograms']['prefill_seconds']['count']
+    assert after - before == len(work) + 1
+
+    calls = eng._prefill_call
+    gate = threading.Event()
+
+    def slow(bound, feed):
+        out = calls(bound, feed)
+        if eng._flights and feed['gen_len'][0, 0] == 16:
+            gate.set()
+            time.sleep(0.3)             # stop() comes between two chunks
+        return out
+    eng._prefill_call = slow
+    doomed = eng.submit(_prompt(38, seed=77), max_new_tokens=5,
+                        deadline_s=60.0)
+    assert gate.wait(30.0)
+    eng.stop()
+    with pytest.raises(generate_mod.EngineStoppedError,
+                       match='between two chunks'):
+        doomed.result(30)
+    with pytest.raises(generate_mod.EngineStoppedError):
+        list(stream)
+    assert eng._chunking is None and eng._firsts == []
+    assert eng.stats()['active'] == 0
+    assert sorted(eng._free) == list(range(eng.config.slots))
+    assert eng.stats()['blocks']['in_use'] == 0

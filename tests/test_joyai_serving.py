@@ -97,46 +97,52 @@ SIGMOID = {'top_k': 4, 'norm_topk_prob': True, 'score': 'sigmoid',
            'routed_scale': 2.5}
 
 
-def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_layer():
+@pytest.mark.parametrize('E,held,k', [(16, 4, 4), (32, 2, 8)],
+                         ids=['joyai-4-shares-of-4', 'kexaone-16-shares-of-2'])
+def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_layer(
+        E, held, k):
     """Each share computes its own experts' part for the rows routed to
     them; what every chip computes alike (the shared expert) is counted
-    once; the sum is the uncut reference's expert layer."""
+    once; the sum is the uncut reference's expert layer. JoyAI's group of
+    4, and K-EXAONE's of 16 (top 8; its reference's functions are
+    JoyAI's, key for key)."""
     rng = np.random.RandomState(3)
-    x, router, bias, gate, up, down = _expert_layer(rng)
+    x, router, bias, gate, up, down = _expert_layer(rng, E=E)
     shared = [rng.randn(*s).astype('float32') * 0.2
               for s in ((64, 32), (64, 32), (32, 64))]
     scores = ref._scores(jnp.asarray(x), jnp.asarray(router))
-    chosen = ref.chosen_mask(scores, jnp.asarray(bias), 4)
+    chosen = ref.chosen_mask(scores, jnp.asarray(bias), k)
     w = ref.expert_weights(scores, chosen, bias, True, 2.5)
     want = np.asarray(ref._experts(jnp.asarray(x), w, gate, up, down)
                       + ref._gated(jnp.asarray(x), *shared))
     total = np.asarray(ref._gated(jnp.asarray(x), *shared))
     elsewhere = 0
-    for first in (0, 4, 8, 12):
-        mine = slice(first, first + 4)
-        out = lower('moe_ffn', dict(SIGMOID, first_expert=first), X=x,
-                    RouterW=router, SelectBias=bias, GateW=gate[mine],
+    for first in range(0, E, held):
+        mine = slice(first, first + held)
+        out = lower('moe_ffn', dict(SIGMOID, top_k=k, first_expert=first),
+                    X=x, RouterW=router, SelectBias=bias, GateW=gate[mine],
                     UpW=up[mine], DownW=down[mine])
         total = total + out['Out']
-        # the router scores all 16 and every row chooses 4 of them
+        # the router scores all E and every row chooses k of them
         np.testing.assert_array_equal(
             np.sort(out['TopkIdx'], axis=1),
-            np.sort(np.argsort(-np.asarray(scores + bias), axis=1)[:, :4],
+            np.sort(np.argsort(-np.asarray(scores + bias), axis=1)[:, :k],
                     axis=1))
         load = out['ExpertLoad']
-        assert load.shape == (5,) and load.sum() == 11 * 4
+        assert load.shape == (held + 1,) and load.sum() == 11 * k
         np.testing.assert_array_equal(
-            load[:4], np.asarray(chosen)[:, mine].sum(axis=0))
-        elsewhere += load[4]
+            load[:held], np.asarray(chosen)[:, mine].sum(axis=0))
+        elsewhere += load[held]
         # the share alone is the reference's share
         np.testing.assert_allclose(
             out['Out'], np.asarray(ref._experts(
                 jnp.asarray(x), w[:, mine], gate[mine], up[mine],
                 down[mine])), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(total, want, rtol=2e-5, atol=2e-5)
-    assert elsewhere == 3 * 11 * 4      # each assignment is held once
+    # each assignment is held once
+    assert elsewhere == (E // held - 1) * 11 * k
     # the bias chooses (it decides some choices here) and is in no weight
-    plain = ref.chosen_mask(scores, jnp.zeros(16), 4)
+    plain = ref.chosen_mask(scores, jnp.zeros(E), k)
     assert (np.asarray(plain) != np.asarray(chosen)).any()
 
 

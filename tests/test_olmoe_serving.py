@@ -213,15 +213,16 @@ def tap_logits(eng):
     for b, (prog, v) in eng._prefill.items():
         feed = {'gen_prompt': np.zeros((1, b), 'int64'),
                 'gen_pos': np.zeros((1, b), 'int64'),
-                'gen_btab': np.zeros((1, mb), 'int64'),
                 'gen_len': np.ones((1, 1), 'int64')}
+        # 'gen_btab', and a model with window layers' 'gen_wtab'
+        feed.update(eng._tables_feed(np.zeros((1, mb), 'int64')))
         feed.update(eng._sample_feed(1))
         eng._prefill_bound[b] = tapped(eng.executor.bind(
             prog, feed, scope=eng.scope,
             fetch_list=[v['tokens_and_load'], v['logits']]), 'prefill')
     feed = {'gen_tokens': np.zeros((S, 1), 'int64'),
-            'gen_pos': np.zeros((S, 1), 'int64'),
-            'gen_btab': np.zeros((S, mb), 'int64')}
+            'gen_pos': np.zeros((S, 1), 'int64')}
+    feed.update(eng._tables_feed(np.zeros((S, mb), 'int64')))
     feed.update(eng._sample_feed(S))
     eng._step_bound = tapped(eng.executor.bind(
         eng._step_prog, feed, scope=eng.scope,
