@@ -168,10 +168,27 @@ def _ring_positions(pos, ring, block_size):
             + jnp.arange(block_size)).reshape(pos.shape[0], -1)
 
 
+def pool_pages(cache, layer, tables):
+    """The pages `tables` names ([..., MB] block ids) of one layer of a
+    pool ``[num_blocks, layers, block_size, W]``, as ``[..., MB, bs, W]``.
+    ONE gather indexed by (block, layer), so that it reads the table's
+    ``MB`` pages out of the pool where it lies. The other spelling,
+    ``cache[:, layer][tables]``, gives the same values, but XLA:TPU does
+    not fold the slice into the gather: it first copies the layer's share
+    of the WHOLE pool (``num_blocks`` pages) and gathers from the copy —
+    for K and for V in every layer of every prefill, whatever the prompt's
+    length (chat: 48 copies of 67 MB, 4.2 ms of a 7 ms prefill; PERF.md,
+    PR 42). Every op that reads pages outside a Pallas kernel takes them
+    through here."""
+    return cache[tables, layer]
+
+
 def _gather_pages(cache, layer, tables, n_head):
     """The pages `tables` names ([..., MB] block ids) of one layer, as
-    ``[..., MB*bs, H, dh]`` in logical position order."""
-    g = cache[:, layer][tables]                 # [..., MB, bs, H*dh]
+    ``[..., MB*bs, H, dh]`` in logical position order. The index is (block,
+    layer) in one gather (`pool_pages`): a slice of the layer first costs
+    a copy of its ``num_blocks`` pages to read ``MB`` of them."""
+    g = pool_pages(cache, layer, tables)        # [..., MB, bs, H*dh]
     lead = tables.shape[:-1]
     return g.reshape(lead + (-1, n_head, g.shape[-1] // n_head))
 

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
+from .kv_cache_ops import pool_pages
 
 _NEG_INF = -1e30
 # query rows of a prefill that attend at once (the scores are
@@ -47,7 +48,7 @@ def absorbed_decode_reference(q, pool, tables, pos, layer, scale, v_width):
     """The gather formulation of the absorbed decode: q ``[S, H, W]``,
     pool ``[NB, Ln, bs, W]``, tables ``[S, MB]``, pos ``[S]`` ->
     ``[S, H, v_width]``."""
-    rows = pool[:, layer][tables]                   # [S, MB, bs, W]
+    rows = pool_pages(pool, layer, tables)          # [S, MB, bs, W]
     rows = rows.reshape(rows.shape[0], -1, rows.shape[-1])   # [S, M, W]
     scores = jnp.einsum('shw,smw->shm', q, rows,
                         preferred_element_type=jnp.float32) * scale
@@ -115,7 +116,7 @@ def _mla_prefix_attention(ctx, op):
     layer = int(op.attr('layer'))
     scale = float(op.attr('scale', 1.0))
     nope, rank = w_uk.shape[1], w_uk.shape[2]
-    rows = pool[:, layer][table].reshape(-1, pool.shape[3])   # [M, W]
+    rows = pool_pages(pool, layer, table).reshape(-1, pool.shape[3])  # [M, W]
     # behind the rotary key the row is zeros up to whole lane tiles
     latent = rows[:, :rank]
     k_rope = rows[:, rank:rank + q.shape[-1] - nope]
