@@ -156,6 +156,29 @@ class NumpyArrayInitializer(Initializer):
                    'values': self._value.flatten().tolist()})
 
 
+class RowsInitializer(Initializer):
+    """A matrix whose row ``i`` is ``values[i]`` throughout (Mamba's
+    ``A_log``: one number a state, every channel alike): the column as an
+    `assign_value`, tiled over the columns — the program carries
+    ``len(values)`` numbers, not the matrix."""
+
+    def __init__(self, values):
+        self._values = np.asarray(values).reshape(-1)
+
+    def __call__(self, var, block):
+        column = block.create_var(name=var.name + '.column',
+                                  shape=(len(self._values), 1),
+                                  dtype=var.dtype)
+        block.append_op(
+            type='assign_value', outputs={'Out': [column.name]},
+            attrs={'shape': [len(self._values), 1], 'dtype': var.dtype,
+                   'values': self._values.tolist()})
+        return block.append_op(
+            type='expand', inputs={'X': [column.name]},
+            outputs={'Out': [var.name]},
+            attrs={'expand_times': [1, int(var.shape[1])]})
+
+
 Constant = ConstantInitializer
 Uniform = UniformInitializer
 Normal = NormalInitializer

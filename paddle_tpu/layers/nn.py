@@ -5,7 +5,7 @@ import numpy as np
 
 from ..layer_helper import LayerHelper
 from ..framework import Variable
-from ..initializer import Constant, Normal, Xavier
+from ..initializer import Constant, Normal, RowsInitializer, Xavier
 from ..param_attr import ParamAttr
 from ..core.types import convert_np_dtype_to_dtype_
 
@@ -17,6 +17,7 @@ __all__ = [
     'fused_layer_norm_residual', 'fused_ffn_tail', 'rms_norm',
     'rotary_embedding', 'moe_ffn', 'mla_decode_attention',
     'mla_prefix_attention', 'short_conv_decode', 'short_conv_prefill',
+    'ssm_decode', 'ssm_prefill',
     'group_norm', 'data_norm', 'l2_normalize', 'matmul', 'mul', 'topk',
     'reshape', 'squeeze', 'unsqueeze', 'flatten', 'transpose', 'split',
     'reduce_sum', 'reduce_mean', 'reduce_max', 'reduce_min', 'reduce_prod',
@@ -771,6 +772,72 @@ def short_conv_prefill(g, cache, positions, block_table, length, layer,
     return _short_conv('short_conv_prefill_paged', 'BlockTable', g, cache,
                        positions, block_table, layer, block_size, kernel,
                        param_attr, length=length)
+
+
+def _ssm(op_type, u, z, state, tail, rows, layer, prefix, n_state, kernel,
+         dt_rank, epsilon, positions=None, length=None):
+    """A state-space mixer's op (ops/ssm_ops.py) with the layer's inner
+    parameters under ``prefix``. Their defaults are Mamba's own for the
+    recurrence (state-spaces/mamba `Mamba.__init__`): ``A_log`` = log(1 ..
+    n_state) a channel, laid out ``[n_state, d_inner]`` as the state is;
+    ``D`` = 1; the step's bias the inverse softplus of 0.01, the middle of
+    the published [1e-3, 1e-1] — with a zero bias ``delta`` is 0.69 and the
+    state forgets within a few positions."""
+    helper = LayerHelper(op_type)
+    di = int(u.shape[-1])
+
+    def param(name, shape, init):
+        return helper.create_parameter(
+            attr=ParamAttr(name='%s.%s' % (prefix, name)), shape=shape,
+            dtype=u.dtype, default_initializer=init)
+    a_log = np.log(np.arange(1, n_state + 1, dtype='float32'))
+    weights = {
+        'ConvW': param('conv.w', [di, int(kernel)], Normal(0.0, 0.3)),
+        'ConvB': param('conv.b', [di], Normal(0.0, 0.1)),
+        'XProj': param('x.w', [di, dt_rank + 2 * n_state],
+                       Normal(0.0, 0.02)),
+        'DtNorm': param('dt_norm.w', [dt_rank], Constant(1.0)),
+        'BNorm': param('b_norm.w', [n_state], Constant(1.0)),
+        'CNorm': param('c_norm.w', [n_state], Constant(1.0)),
+        'DtProj': param('dt.w', [dt_rank, di], Normal(0.0, 0.02)),
+        'DtBias': param('dt.b', [di],
+                        Constant(float(np.log(np.expm1(0.01))))),
+        'ALog': param('A_log', [n_state, di], RowsInitializer(a_log)),
+        'D': param('D', [di], Constant(1.0))}
+    out = helper.create_variable_for_type_inference(u.dtype, shape=u.shape)
+    inputs = {'X': [u], 'Z': [z], 'State': [state], 'Tail': [tail],
+              'Rows': [rows]}
+    inputs.update({k: [v] for k, v in weights.items()})
+    if positions is not None:
+        inputs.update({'Positions': [positions], 'Length': [length]})
+    helper.append_op(type=op_type, inputs=inputs,
+                     outputs={'Out': [out], 'StateOut': [state],
+                              'TailOut': [tail]},
+                     attrs={'layer': int(layer), 'epsilon': float(epsilon)})
+    return out
+
+
+def ssm_decode(u, z, state, tail, rows, layer, prefix, n_state, kernel,
+               dt_rank, epsilon=1e-6):
+    """One step of a state-space layer for every slot's one row: ``u`` and
+    ``z`` ``[S, d_inner]`` (the two halves of the mixer's input
+    projection), ``state`` / ``tail`` the two pools, read and written in
+    place at the rows ``rows [S, 1]`` names (0: none), ``layer`` the
+    layer's ordinal in them (ops/ssm_ops.py). Returns ``y * silu(z) [S,
+    d_inner]``, the output projection's input."""
+    return _ssm('ssm_decode', u, z, state, tail, rows, layer, prefix,
+                n_state, kernel, dt_rank, epsilon)
+
+
+def ssm_prefill(u, z, state, tail, rows, positions, length, layer, prefix,
+                n_state, kernel, dt_rank, epsilon=1e-6):
+    """`ssm_decode` for one prompt suffix ``u``, ``z`` ``[1, T, d_inner]``
+    that starts at ``positions[0]``: from zeros there, else from the row
+    as the chunk before left it, over the ``length`` real rows
+    (ops/ssm_ops.py). Returns ``[1, T, d_inner]``."""
+    return _ssm('ssm_prefill', u, z, state, tail, rows, layer, prefix,
+                n_state, kernel, dt_rank, epsilon, positions=positions,
+                length=length)
 
 
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,

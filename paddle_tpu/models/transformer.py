@@ -13,6 +13,7 @@ from .. import layers
 # add_position_encoding op applies during prefill — sharing the builder
 # keeps a token's embedding bit-identical on both paths (re-exported)
 from ..framework import default_main_program
+from ..ops import ssm_ops
 from ..ops.tensor_ops import position_encoding_table  # noqa: F401
 from ..param_attr import ParamAttr
 
@@ -42,8 +43,20 @@ class LMConfig(object):
     - ``n_kv_head``: fewer K/V heads than query heads (grouped-query
       attention): query head ``h`` reads K/V head ``h // (n_head //
       n_kv_head)``, and the pools hold ``n_kv_head`` heads;
-    - ``layer_types``: one of ``'attention'`` | ``'conv'`` | ``'window'`` a
-      layer. A ``'window'`` layer is an attention layer whose query at
+    - ``position='none'``: nothing is added to the embedding and nothing
+      is rotated (Jamba: the recurrence carries the order);
+    - ``ffn='gated'``: every layer's FFN is the dense SiLU-gated
+      ``(silu(x W_g) * (x W_u)) W_d`` of width ``d_ff`` (`_gated_ffn`);
+    - ``layer_types``: one of ``'attention'`` | ``'conv'`` | ``'window'`` |
+      ``'ssm'`` a layer. An ``'ssm'`` layer's mixer is Jamba's Mamba-1
+      block (``[u | z] = h W_in``; a causal depthwise convolution of
+      ``ssm_conv`` taps with a bias and a SiLU; ``dt``, ``B``, ``C`` from
+      ``u`` through a projection and a norm each; the recurrence over a
+      ``[ssm_state, ssm_expand x d_model]`` state; ``(y * silu(z))
+      W_out``: ops/ssm_ops.py). It caches no key and no value but the
+      recurrence's state and the convolution's last ``ssm_conv - 1``
+      inputs, a fixed size whatever the context, in two pools of their
+      own with A ROW A SLOT (`SSM_STATE`, `SSM_TAIL`), fed as 'gen_srow'. A ``'window'`` layer is an attention layer whose query at
       position ``i`` sees key ``j`` iff ``0 <= i - j < sliding_window``
       (K-EXAONE's, EXAONE 4.0's local layers). It keeps K and V pools of
       its own, under block ids of its own: a slot's FEW blocks there are a
@@ -101,7 +114,8 @@ class LMConfig(object):
                  qk_nope_dim=0, qk_rope_dim=0, v_head_dim=0,
                  rope_interleave=False, layer_types=None, conv_kernel=3,
                  n_kv_head=None, tie_embeddings=False, router_eps=1e-20,
-                 sliding_window=0, global_rope=True):
+                 sliding_window=0, global_rope=True, ssm_expand=2,
+                 ssm_state=16, ssm_conv=4, ssm_dt_rank=None):
         self.vocab_size = vocab_size
         self.seq_len = seq_len
         self.d_model = d_model
@@ -118,8 +132,8 @@ class LMConfig(object):
         self.ring_zigzag = False
         for field, value, known in (
                 ('norm', norm, ('layer_norm', 'rms_norm')),
-                ('position', position, ('sinusoid', 'rope')),
-                ('ffn', ffn, ('gelu', 'moe')),
+                ('position', position, ('sinusoid', 'rope', 'none')),
+                ('ffn', ffn, ('gelu', 'moe', 'gated')),
                 ('moe_score', moe_score, ('softmax', 'sigmoid')),
                 ('attention', attention, ('mha', 'mla')),
                 ('qk_norm', qk_norm, (False, True, 'head'))):
@@ -161,11 +175,19 @@ class LMConfig(object):
         self.router_eps = router_eps
         self.sliding_window = int(sliding_window)
         self.global_rope = bool(global_rope)
-        if len(self.layer_types) != n_layer or \
-                set(self.layer_types) - {'attention', 'conv', 'window'}:
+        self.ssm_expand = int(ssm_expand)
+        self.ssm_state = int(ssm_state)
+        self.ssm_conv = int(ssm_conv)
+        self.ssm_dt_rank = int(ssm_dt_rank or -(-d_model // 16))
+        if len(self.layer_types) != n_layer or set(self.layer_types) - {
+                'attention', 'conv', 'window', 'ssm'}:
             raise ValueError("LMConfig.layer_types=%r: expected %d of "
-                             "'attention' | 'conv' | 'window'"
+                             "'attention' | 'conv' | 'window' | 'ssm'"
                              % (self.layer_types, n_layer))
+        if self.n_ssm_layers and self.ssm_conv - 1 > ssm_ops.TAIL_ROWS:
+            raise ValueError("LMConfig.ssm_conv=%r: a state-space layer's "
+                             "tail is at most %d rows a slot"
+                             % (ssm_conv, ssm_ops.TAIL_ROWS))
         if bool(self.n_window_layers) != (self.sliding_window > 0):
             raise ValueError("LMConfig.layer_types=%r with LMConfig."
                              "sliding_window=%r: 'window' layers and a "
@@ -176,10 +198,11 @@ class LMConfig(object):
                              'n_head=%r' % (self.n_kv_head, n_head))
         if attention == 'mla' and (self.n_kv_head != n_head
                                    or self.n_conv_layers
-                                   or self.n_window_layers):
+                                   or self.n_window_layers
+                                   or self.n_ssm_layers):
             raise ValueError("LMConfig.attention='mla' is built with "
-                             "neither n_kv_head nor 'conv' or 'window' "
-                             "layer_types")
+                             "neither n_kv_head nor 'conv', 'window' or "
+                             "'ssm' layer_types")
         if attention == 'mla' and not (
                 position == 'rope' and q_lora_rank and kv_lora_rank
                 and qk_nope_dim and qk_rope_dim and v_head_dim):
@@ -209,10 +232,20 @@ class LMConfig(object):
         return self.layer_types.count('window')
 
     @property
+    def n_ssm_layers(self):
+        return self.layer_types.count('ssm')
+
+    @property
+    def ssm_inner(self):
+        """Channels of a state-space layer's recurrence (``d_inner``)."""
+        return self.ssm_expand * self.d_model
+
+    @property
     def n_attn_layers(self):
         """The GLOBAL attention layers: those of the K/V pools that the
         block allocator's tables address."""
-        return self.n_layer - self.n_conv_layers - self.n_window_layers
+        return self.n_layer - self.n_conv_layers - self.n_window_layers \
+            - self.n_ssm_layers
 
     def rotates(self, layer):
         """Whether `layer`'s q and k are rotated by the positions."""
@@ -494,6 +527,25 @@ def _conv_mixer(cfg, ln1, p, nth, conv, num_flatten_dims):
     return proj(layers.elementwise_mul(c, mixed), d, 'out')
 
 
+def _ssm_mixer(cfg, ln1, p, nth, ssm, num_flatten_dims):
+    """Jamba's Mamba-1 mixer on the normed input: ``[u | z] = h W_in``,
+    the program's cache op on the ``nth`` state-space layer's rows
+    (``ssm(u, z, prefix, nth)``: layers.ssm_decode / ssm_prefill — the
+    convolution, the inner projections and norms, the recurrence, the
+    gate), ``W_out``. No bias on either projection."""
+    di = cfg.ssm_inner
+
+    def proj(x, size, which):
+        return layers.fc(x, size=size, num_flatten_dims=num_flatten_dims,
+                         param_attr=ParamAttr(name='%s.ssm.%s.w'
+                                              % (p, which)),
+                         bias_attr=False)
+    uz = proj(ln1, 2 * di, 'in')
+    u, z = [layers.slice(uz, axes=[num_flatten_dims], starts=[i * di],
+                         ends=[(i + 1) * di]) for i in range(2)]
+    return proj(ssm(u, z, p + '.ssm', nth), cfg.d_model, 'out')
+
+
 def _gated_ffn(x, width, d_model, name, num_flatten_dims):
     """``(silu(x W_g) * (x W_u)) W_d``, no bias: a dense SiLU-gated FFN
     (and an expert that every row goes through)."""
@@ -518,7 +570,7 @@ def _ffn(cfg, ln2, p, num_flatten_dims, length=None, valid=None, layer=0):
         # decode is inference-only: prob 0 / is_test keeps the op on the
         # RNG-free bind fast path (no per-step key derivation)
         return _ffn_tail(ln2, cfg, p, num_flatten_dims), None
-    if layer < cfg.n_dense_layers:
+    if cfg.ffn == 'gated' or layer < cfg.n_dense_layers:
         return _gated_ffn(ln2, cfg.d_ff, cfg.d_model, p + '.ffn',
                           num_flatten_dims), None
     shape = ln2.shape
@@ -740,6 +792,12 @@ def build_lm(cfg=None, is_test=False):
 # serves them. Each attention layer's ops get its kind's pool, table and
 # bound; only the window layers rotate q and k where `global_rope` is off.
 #
+# A model with STATE-SPACE layers (`layer_types` 'ssm') declares `SSM_STATE`
+# and `SSM_TAIL`, a row a slot and the trash row, and both programs take
+# the feed 'gen_srow' [rows, 1]: slot i's row is i + 1 while it is
+# resident, and 0 for a row that sits a step out (ops/ssm_ops.py). The K/V
+# pools hold the attention layers only.
+#
 # The decode step (and the prefill, for the FIRST token) ends in the
 # `sample_next_token` op: per-slot temperature / top-k / top-p feeds plus
 # a host-fed uniform drive sampling; temperature 0 rows return the bitwise
@@ -764,6 +822,8 @@ KV_CACHE_V = 'gen_kv_v'
 CONV_CACHE = 'gen_conv_tail'
 WINDOW_CACHE_K = 'gen_kv_window_k'
 WINDOW_CACHE_V = 'gen_kv_window_v'
+SSM_STATE = 'gen_ssm_state'
+SSM_TAIL = 'gen_ssm_tail'
 
 
 def window_ring(cfg, block_size):
@@ -791,13 +851,17 @@ def kv_cache_names(cfg):
     allocator's ids: K and V apart, or with latent attention the ONE pool
     of latent rows (under K's name); with convolution layers the pool of
     their tails as well. With window layers, indexed by the slots' rings
-    (`window_ring`): those layers' K and V."""
+    (`window_ring`): those layers' K and V. With state-space layers,
+    indexed by the slots' rows (slot ``i`` has row ``i + 1``; row 0 is the
+    trash row): the recurrence's state and the convolution's tail."""
     names = (KV_CACHE_K,) if cfg.attention == 'mla' \
         else (KV_CACHE_K, KV_CACHE_V)
     if cfg.n_conv_layers:
         names += (CONV_CACHE,)
     if cfg.n_window_layers:
         names += (WINDOW_CACHE_K, WINDOW_CACHE_V)
+    if cfg.n_ssm_layers:
+        names += (SSM_STATE, SSM_TAIL)
     return names
 
 
@@ -808,16 +872,25 @@ def kv_cache_shapes(cfg, num_blocks, block_size, slots=None):
     convolution layer, ``[num_blocks, n_conv_layers, conv_kernel - 1,
     d_model]``; the window layers' K/V: ``[window_pool_blocks,
     n_window_layers, block_size, kv_width]``, sized by the engine's
-    ``slots`` and nothing else."""
+    ``slots`` and nothing else, as are a state-space model's two: the
+    state ``[slots + 1, n_ssm_layers, ssm_state, ssm_inner]`` (the channels
+    minor: whole vregs of lanes) and the tail ``[slots + 1, n_ssm_layers,
+    8, ssm_inner]`` (the ``ssm_conv - 1`` rows a layer keeps in a sublane
+    tile of their own: ops/ssm_ops.py says what a padded tile cost)."""
     kv = (num_blocks, cfg.n_attn_layers, block_size, cfg.kv_width)
     shapes = {KV_CACHE_K: kv, KV_CACHE_V: kv,
               CONV_CACHE: (num_blocks, cfg.n_conv_layers,
                            cfg.conv_kernel - 1, cfg.d_model)}
+    if (cfg.n_window_layers or cfg.n_ssm_layers) and slots is None:
+        raise ValueError("LMConfig.layer_types=%r: the window and the "
+                         "state-space layers' pools are sized by the slots"
+                         % (cfg.layer_types,))
+    if cfg.n_ssm_layers:
+        shapes[SSM_STATE] = (slots + 1, cfg.n_ssm_layers, cfg.ssm_state,
+                             cfg.ssm_inner)
+        shapes[SSM_TAIL] = (slots + 1, cfg.n_ssm_layers, ssm_ops.TAIL_ROWS,
+                            cfg.ssm_inner)
     if cfg.n_window_layers:
-        if slots is None:
-            raise ValueError("LMConfig.layer_types=%r: the window layers' "
-                             "pools are sized by the slots"
-                             % (cfg.layer_types,))
         shapes[WINDOW_CACHE_K] = shapes[WINDOW_CACHE_V] = (
             window_pool_blocks(cfg, slots, block_size), cfg.n_window_layers,
             block_size, cfg.kv_width)
@@ -825,14 +898,25 @@ def kv_cache_shapes(cfg, num_blocks, block_size, slots=None):
 
 
 def _declare_paged_kv_caches(block, cfg, num_blocks, block_size, slots=None):
-    """(K pool, V pool, tail pool, window K pool, window V pool) of
-    `kv_cache_names`; None for a pool the model does not have."""
+    """(K pool, V pool, tail pool, window K pool, window V pool, state
+    pool, its tail pool) of `kv_cache_names`; None for a pool the model
+    does not have."""
     pools = {name: block.create_var(name=name, shape=shape, dtype='float32',
                                     persistable=True, stop_gradient=True)
              for name, shape in kv_cache_shapes(cfg, num_blocks, block_size,
                                                 slots).items()}
     return [pools.get(name) for name in (KV_CACHE_K, KV_CACHE_V, CONV_CACHE,
-                                         WINDOW_CACHE_K, WINDOW_CACHE_V)]
+                                         WINDOW_CACHE_K, WINDOW_CACHE_V,
+                                         SSM_STATE, SSM_TAIL)]
+
+
+def _state_rows(cfg):
+    """The feed of the slots' rows in the state-space layers' pools,
+    'gen_srow' ``[rows, 1]`` (0: none, the trash row); None for a model
+    without such layers."""
+    if not cfg.n_ssm_layers:
+        return None
+    return layers.data(name='gen_srow', shape=[1], dtype='int64')
 
 
 def _window_table(cfg, block_size):
@@ -882,7 +966,7 @@ def _qkv_split_step(qkv, cfg):
 
 
 def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
-                  pos=None, valid=None, routing=None, conv=None):
+                  pos=None, valid=None, routing=None, conv=None, ssm=None):
     """One decode-position transformer tower over per-slot row state
     ``x`` ([S, d]: token embedding, + position encoding where positions
     are added). The cache write and cached attention are delegated to
@@ -899,7 +983,8 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
     K/V deposited but no logits. ``pos`` ([S, 1], rotary positions),
     ``valid`` ([S, 1], zero = idle slot) and ``routing`` (a list that
     takes each layer's `_ffn` routing) serve the blocks that need them;
-    ``conv(g, weight_attr, layer)`` is a convolution layer's cache op.
+    ``conv(g, weight_attr, layer)`` is a convolution layer's cache op,
+    ``ssm(u, z, prefix, layer)`` a state-space layer's.
     The cache closures get a layer's ORDINAL among the layers of its
     kind (`LMConfig.layer_ordinal`) and the kind (``'attention'`` |
     ``'window'``): a pool holds one kind."""
@@ -910,6 +995,8 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
         ln1, x = _norm(cfg, x, delta, 1, p + '.ln1')
         if cfg.layer_types[i] == 'conv':
             attn = _conv_mixer(cfg, ln1, p, nth, conv, 1)
+        elif cfg.layer_types[i] == 'ssm':
+            attn = _ssm_mixer(cfg, ln1, p, nth, ssm, 1)
         else:
             kind = cfg.layer_types[i]
             q, k, v = _qkv(cfg, ln1, p, pos, layer=i)        # [S, H, dh]
@@ -944,7 +1031,8 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
     host uniform; all-zero = bitwise greedy), and 'gen_btab'
     [slots, max_len // block_size] int64 per-slot block tables; with
     window layers also 'gen_wtab' [slots, window_ring], the slots' rings
-    in those layers' pools. Returns
+    in those layers' pools; with state-space layers 'gen_srow' [slots, 1],
+    the slots' rows in theirs (0: the row sits this step out). Returns
     {'tokens', 'pos', 'logits', 'next_tokens', 'k_cache', 'v_cache'} —
     fetch 'next_tokens' ([slots] int64). With experts
     (`cfg.ffn == 'moe'`) also 'tokens_and_load' — next_tokens and the
@@ -960,7 +1048,8 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
     mb = max_len // block_size
     btab = layers.data(name='gen_btab', shape=[mb], dtype='int64')
     wtab = _window_table(cfg, block_size)
-    kc, vc, tails, wkc, wvc = _declare_paged_kv_caches(
+    srow = _state_rows(cfg)
+    kc, vc, tails, wkc, wvc, states, stails = _declare_paged_kv_caches(
         block, cfg, num_blocks, block_size, slots)
 
     x = layers.embedding(
@@ -974,6 +1063,11 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
         return layers.short_conv_decode(
             g, tails, pos, btab, layer, block_size, cfg.conv_kernel,
             param_attr=weight_attr)
+
+    def ssm(u, z, prefix, layer):
+        return layers.ssm_decode(
+            u, z, states, stails, srow, layer, prefix, cfg.ssm_state,
+            cfg.ssm_conv, cfg.ssm_dt_rank, epsilon=cfg.rms_eps)
 
     def cache_write(k, v, layer, kind):
         # a window layer writes into its slot's ring: the table's column
@@ -1015,8 +1109,8 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
         if cfg.ffn == 'moe' else None
     routing = []
     logits = _decode_tower(cfg, x, cache_write, attend, pos=pos,
-                           valid=valid, routing=routing,
-                           conv=conv)                        # [S, V]
+                           valid=valid, routing=routing, conv=conv,
+                           ssm=ssm)                          # [S, V]
     next_tokens = _append_sample_op(block, logits, sample_vars,
                                     'gen_next_tokens')       # [S]
     return _expert_outputs(
@@ -1243,7 +1337,10 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     those layers' pools (sized by ``slots``): such a layer attends the
     suffix's own rows and the ``sliding_window - 1`` rows its ring holds
     from before them, THEN writes — of the suffix, only the rows a later
-    query can still see.
+    query can still see. With state-space layers also 'gen_srow' [1, 1],
+    the slot's row in their pools: such a layer starts from zeros at
+    position 0 and from the row past it, and leaves the row as of the last
+    real position.
     Returns {'prompt', 'positions', 'block_table', 'length', 'logits',
     'first_token', 'k_cache', 'v_cache'}, and with experts
     'tokens_and_load' (first_token and the [n_layer * n_experts] expert
@@ -1259,7 +1356,8 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     sample_vars = _sampling_inputs()
     block = prompt.block
     wtab = _window_table(cfg, block_size)
-    kc, vc, tails, wkc, wvc = _declare_paged_kv_caches(
+    srow = _state_rows(cfg)
+    kc, vc, tails, wkc, wvc, states, stails = _declare_paged_kv_caches(
         block, cfg, num_blocks, block_size, slots)
 
     x = layers.embedding(
@@ -1286,6 +1384,12 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
         return layers.short_conv_prefill(
             g, tails, pos, btab, length, layer, block_size, cfg.conv_kernel,
             param_attr=weight_attr)
+
+    def ssm(u, z, prefix, layer):
+        return layers.ssm_prefill(
+            u, z, states, stails, srow, pos, length, layer, prefix,
+            cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank,
+            epsilon=cfg.rms_eps)
 
     def attention(ln1, p, nth, layer):
         """An attention layer's mixer: q, k, v, the cache writes, the
@@ -1336,6 +1440,8 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
         ln1, x = _norm(cfg, x, delta, 2, p + '.ln1')
         if cfg.layer_types[i] == 'conv':
             attn = _conv_mixer(cfg, ln1, p, nth, conv, 2)
+        elif cfg.layer_types[i] == 'ssm':
+            attn = _ssm_mixer(cfg, ln1, p, nth, ssm, 2)
         else:
             attn = attention(ln1, p, nth, i)
         ln2, x = _norm(cfg, x, attn, 2, p + '.ln2')

@@ -31,6 +31,7 @@ from . import kv_cache_ops
 from . import moe_ops
 from . import mla_ops
 from . import short_conv_ops
+from . import ssm_ops
 from . import fused_ops
 from . import dist_ops
 from . import pipeline_ops

@@ -102,18 +102,28 @@ _PRECISION = lax.Precision.HIGHEST
 def shapes_ok(n_head, head_dim, block_size, n_kv_head=None):
     """The kernel's tiling rule: pages (of the K/V heads) are whole
     (8, 128) tiles, a head's lanes never straddle a vreg, and the query
-    heads divide evenly over the K/V heads. Grouped queries besides: the
-    query rows fill whole sublanes, whole pages fill a window, and the
-    ring holds two windows."""
+    heads divide evenly over the K/V heads. Grouped queries besides: whole
+    pages fill a window, and the ring holds two windows (the query rows
+    fill whole sublanes by `padded_group`)."""
     n_kv_head = n_kv_head or n_head
     return (n_kv_head * head_dim) % _LANES == 0 and \
         _LANES % head_dim == 0 and block_size % 8 == 0 and \
         n_head % n_kv_head == 0 and \
         (n_head == n_kv_head
-         or (n_head % 8 == 0 and _WINDOW_KEYS % block_size == 0
+         or (_WINDOW_KEYS % block_size == 0
              and ring_depth(n_kv_head, head_dim, block_size,
                             _WINDOW_KEYS // block_size)
              >= 2 * (_WINDOW_KEYS // block_size)))
+
+
+def padded_group(group, n_kv_head):
+    """Queries a K/V head as the MXU body is given them: ``group``, or
+    the next count at which the query rows fill whole sublanes (Jamba's 20
+    queries on one K/V head run as 24; the added rows are zero queries
+    whose output is dropped). LFM2's 4 x 8 and K-EXAONE's 8 x 8 stand."""
+    while (group * n_kv_head) % 8:
+        group += 1
+    return group
 
 
 def form(n_head, n_kv_head=None):
@@ -427,6 +437,10 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
     # said only where there is one: an unbounded call's kernel is built
     # from the arguments it always had
     bound = {} if attention_span is None else {'span': int(attention_span)}
+    # rows of one slot: query g of every K/V head, laid out as a page's
+    # lanes are (K/V head, feature)
+    rows = jnp.swapaxes(q.reshape(S, Hkv, G, dh), 1, 2).reshape(S, G, hd)
+    Gp = G
     if G == 1:
         ring = ring_depth(Hkv, dh, bs)
         kernel = functools.partial(
@@ -436,19 +450,21 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
         state = pltpu.VMEM((bs, hd), jnp.float32)
         scratch = [state, state, state, state]
     else:
+        Gp = padded_group(G, Hkv)
+        if Gp != G:
+            rows = jnp.pad(rows, ((0, 0), (0, Gp - G), (0, 0)))
+        H = Gp * Hkv
         window = _WINDOW_KEYS // bs
         ring = ring_depth(Hkv, dh, bs, window)
         kernel = functools.partial(
             _grouped_kernel, scale=scale, head_dim=dh, block_size=bs,
-            max_blocks=MB, slots=S, ring=ring, group=G, window=window,
+            max_blocks=MB, slots=S, ring=ring, group=Gp, window=window,
             **bound)
         # one online-softmax stream a query row
         stat = pltpu.VMEM((H, _LANES), jnp.float32)
         scratch = [pltpu.VMEM((H, hd), jnp.float32), stat, stat,
                    pltpu.VMEM((H, hd), jnp.float32)]
-    # rows of one slot: query g of every K/V head, laid out as a page's
-    # lanes are (K/V head, feature)
-    row = pl.BlockSpec((1, G, hd), lambda s, *_: (s, 0, 0))
+    row = pl.BlockSpec((1, Gp, hd), lambda s, *_: (s, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         kernel,
@@ -462,14 +478,13 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
                 pltpu.VMEM((ring, bs, hd), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, ring))] + scratch + [
                 pltpu.SMEM((4,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((S, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, Gp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name='paged_window_decode_attention' if bound
         else 'paged_decode_attention',
     )(tables.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.swapaxes(q.reshape(S, Hkv, G, dh), 1, 2).reshape(S, G, hd),
-      k_pool, v_pool)
-    return jnp.swapaxes(out.reshape(S, G, Hkv, dh), 1, 2).reshape(S, H, dh)
+      jnp.asarray(layer, jnp.int32).reshape(1), rows, k_pool, v_pool)
+    return jnp.swapaxes(out[:, :G].reshape(S, G, Hkv, dh), 1,
+                        2).reshape(S, G * Hkv, dh)

@@ -69,6 +69,23 @@ allocator, and the window layers' cache does not grow with the context.
 ``['window']``; prefix sharing and speculation are refused for such a
 model at construction.
 
+STATE-SPACE LAYERS (PR 43, ``layer_types`` ``'ssm'``) keep the
+recurrence's state and the convolution's tail A ROW A SLOT, in two pools
+the engine sizes from its slots (``slots + 1`` rows: row 0 is the trash
+row; models/transformer.py `SSM_STATE`, `SSM_TAIL`). Slot ``i`` owns row
+``i + 1`` from its admission to its release and both programs are fed it
+as 'gen_srow' beside the block table (`_tables_feed`). A state is a fixed
+10 MB a slot in Jamba2-3B whatever the context, 154 times a block's K/V:
+it cannot be an entry a block, as LFM2's tails are. A prefill that starts
+at position 0 starts from zeros and never reads the row (the last
+tenant's state, or what a stale step in flight writes there, is written
+over: `_release`); a later chunk resumes from the row; a decode step
+feeds a row 0 for every slot it leaves out — idle, held back, or between
+two chunks of its prompt — so that slot's state stands still.
+``stats()['state']`` is ``{capacity, in_use}``, rows; prefix sharing (a
+shared block has no state to resume from) and speculation (a rejected
+draft cannot be unwound from a recurrence) are refused at construction.
+
 SPECULATIVE DECODING (PR 13, ``GenerateConfig(speculative=True)``)
 breaks the one-token-per-dispatch decode ceiling:
 a DRAFT model (``draft_model``; default = the target config, so a
@@ -145,7 +162,8 @@ from .. import trace as trace_mod
 from .. import unique_name
 from ..executor import Executor, Scope, scope_guard
 from ..framework import Program, TPUPlace, program_guard
-from ..models.transformer import (LMConfig, WINDOW_CACHE_K, WINDOW_CACHE_V,
+from ..models.transformer import (LMConfig, SSM_STATE, SSM_TAIL,
+                                  WINDOW_CACHE_K, WINDOW_CACHE_V,
                                   build_lm_decode_step,
                                   build_lm_prefill_paged, kv_cache_names,
                                   kv_cache_shapes, window_ring)
@@ -644,11 +662,21 @@ class GenerateEngine(object):
                     "— a rejected draft cannot be unwound from it, and a "
                     "shared block's window rows are gone once its first "
                     "tenant has moved on" % (option, c.model.layer_types))
+            if getattr(c, option) and c.model.n_ssm_layers:
+                raise ValueError(
+                    "%s=True with LMConfig.layer_types=%r: a state-space "
+                    "layer's state is a row a slot, the recurrence up to "
+                    "the slot's last position — a shared block has no "
+                    "state to resume from, and a rejected draft cannot be "
+                    "unwound from it" % (option, c.model.layer_types))
         # the window layers' pool: a ring of blocks a slot, sized from
         # the slots alone (None for a model without such layers)
         self._rings = WindowRings(
             c.slots, window_ring(c.model, c.block_size), c.block_size) \
             if c.model.n_window_layers else None
+        # the state-space layers, whose pools are a row a slot (0: the
+        # model has none, and no program takes 'gen_srow')
+        self._n_ssm = c.model.n_ssm_layers
         if c.speculative:
             self._draft_cfg = c.draft_model or c.model
             # +1 over the all-slots-at-max_len footprint (the trash
@@ -884,7 +912,7 @@ class GenerateEngine(object):
         s = np.asarray(src, 'int32')
         d = np.asarray(dst, 'int32')
         for name in kv_cache_names(self.config.model):
-            if name in (WINDOW_CACHE_K, WINDOW_CACHE_V):
+            if name in (WINDOW_CACHE_K, WINDOW_CACHE_V, SSM_STATE, SSM_TAIL):
                 continue    # not the allocator's: no block of it is shared
             self.scope.set(name, self._cow_jit(
                 self.executor._state_value(self.scope, name,
@@ -989,13 +1017,19 @@ class GenerateEngine(object):
         """A program's table feeds: 'gen_btab', and for a model with
         window layers 'gen_wtab', the rings of `slots` ((row, slot)
         pairs; the other rows all zero, the trash block, as an idle
-        row's are)."""
+        row's are); with state-space layers 'gen_srow', slot + 1 for the
+        same pairs and 0, the trash row, for the others."""
         feed = {'gen_btab': btab}
         if self._rings is not None:
             wtab = np.zeros((len(btab), self._rings.ring), 'int64')
             for row, slot in slots:
                 wtab[row] = self._rings.table(slot)
             feed['gen_wtab'] = wtab
+        if self._n_ssm:
+            srow = np.zeros((len(btab), 1), 'int64')
+            for row, slot in slots:
+                srow[row] = slot + 1
+            feed['gen_srow'] = srow
         return feed
 
     def _ring_advance(self, slot, length):
@@ -1853,6 +1887,14 @@ class GenerateEngine(object):
             # every dispatch that starts past position 0 — a hit's suffix,
             # a later chunk — resumes from a tail the pool holds
             monitor.inc('conv_tail_resumes_total')
+        if self._n_ssm:
+            # the rows the state-space layers' scans walk (a bucket's pad
+            # rows are not among them), and the dispatches that start
+            # from the slot's row instead of from zeros
+            monitor.inc('ssm_prefill_rows_total',
+                        min(suffix.size, wide) * self._n_ssm)
+            if off > 0:
+                monitor.inc('ssm_state_resumes_total')
         if suffix.size > wide:
             pos = np.clip(off + np.arange(wide), 0, c.max_len - 1)
             feed = {'gen_prompt': suffix[:wide][None],
@@ -2315,6 +2357,10 @@ class GenerateEngine(object):
                 # ... and the window layers', a window's worth a slot
                 monitor.inc('kv_window_tokens_read_total',
                             window_tokens * c.model.n_window_layers)
+            if self._n_ssm:
+                # the state rows the step reads, advances and writes back
+                monitor.inc('ssm_state_rows_updated_total',
+                            len(active) * self._n_ssm)
             feed = {'gen_pos': pos}
             feed.update(self._tables_feed(btab, ((i, i) for i, _ in active)))
             feed.update(sample)
@@ -2556,6 +2602,13 @@ class GenerateEngine(object):
             # its ring in the window layers' pool is the slot's: the next
             # tenant's prefill writes what it reads of it
             self._ring_release(i)
+            # So for its row in the state-space layers' pools: the step in
+            # flight advances the departed tenant's state once more, in a
+            # row nothing reads until the next tenant's first chunk, which
+            # starts at position 0 FROM ZEROS (ops/ssm_ops.py never reads
+            # the row there) and writes the whole row, after that step in
+            # dispatch order. A later chunk resumes from what the first
+            # wrote (tests/test_jamba_serving.py holds both).
         self._slots[i] = None
         self._free.append(i)
 
@@ -2573,7 +2626,9 @@ class GenerateEngine(object):
         peak footprint, and the prefix-cache entry count (the monitor
         mirrors it as kv_blocks_in_use/free), and the share of table
         pages the decode steps read; it is the GLOBAL layers' pool, and
-        'window' in it the window layers' (`WindowRings`). 'loop' is
+        'window' in it the window layers' (`WindowRings`). 'state' (a
+        model with state-space layers) is their pools' rows, one a slot
+        that is admitted. 'loop' is
         where the loop thread's time went, by phase (_loop_sums)."""
         steps = self._decode_steps
         out = {
@@ -2607,6 +2662,11 @@ class GenerateEngine(object):
             out['blocks']['window'] = {'capacity': self._rings.capacity,
                                        'ring': self._rings.ring,
                                        'in_use': self._rings.in_use()}
+        if self._n_ssm:
+            # the state-space layers' pools, in rows: a slot owns its row
+            # from its admission (a chunked one too) to its release
+            out['state'] = {'capacity': self.config.slots,
+                            'in_use': self.config.slots - len(self._free)}
         if self.config.speculative:
             prop = self._spec_proposed
             out['spec'] = {

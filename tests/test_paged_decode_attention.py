@@ -121,9 +121,10 @@ def test_interpret_tier_matches_off_tier(monkeypatch, S, H, Hkv, bs, dh, MB):
 def test_shapes_the_kernel_refuses_fall_to_xla(monkeypatch):
     # a head's 24 lanes would straddle vregs; 4-row pages are no tile
     assert not pda.shapes_ok(2, 24, 8) and not pda.shapes_ok(2, 64, 4)
-    # grouped queries: 12 query rows fill no whole sublanes, pages of 24
-    # rows no window, and two windows of 16 K/V heads of 128 no ring
-    assert pda.shapes_ok(32, 64, 32, 8) and not pda.shapes_ok(12, 64, 16, 4)
+    # grouped queries: 12 query rows run as 16 (`padded_group`: whole
+    # sublanes), but pages of 24 rows fill no window, and two windows of 16
+    # K/V heads of 128 no ring
+    assert pda.shapes_ok(32, 64, 32, 8) and pda.shapes_ok(12, 64, 16, 4)
     assert not pda.shapes_ok(32, 64, 24, 8)
     assert pda.shapes_ok(64, 128, 16, 8) and not pda.shapes_ok(64, 128, 16, 16)
     rng = np.random.RandomState(0)
