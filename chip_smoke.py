@@ -73,14 +73,18 @@ class SmokeConfig(object):
         # row tiles), and the f32 serving panels (32 MB at d1024 ff4096)
         # exceed its VMEM predicate (ops/ffn_ops.ffn_shapes_ok).
         # lookup_table has one lowering, XLA's gather of the table where
-        # it lies (PR 33), and counts as `off` at every tier.
+        # it lies (PR 33), and counts as `off` at every tier. A prefill's
+        # attention lands on `xla`: 16 heads x 256 rows x 512 keys of
+        # float32 scores are 8 MB, under what its kernel takes
+        # (ops/prefix_attention.shapes_ok).
         self.train_tiers = {
             'lookup_table': 'off', 'fused_ln_residual': 'pallas',
             'flash_attention': 'pallas', 'fused_ffn_tail': 'xla',
             'softmax_with_cross_entropy': 'pallas', 'fused_adam': 'pallas'}
         self.serve_tiers = {
             'lookup_table': 'off', 'fused_ln_residual': 'pallas',
-            'fused_ffn_tail': 'xla', 'kv_decode_attention_paged': 'pallas'}
+            'fused_ffn_tail': 'xla', 'kv_decode_attention_paged': 'pallas',
+            'kv_prefix_attention': 'xla'}
         # Mosaic kernel names the compiled train step must contain for the
         # units declared pallas (the `name=` of their pallas_call)
         self.mosaic_kernels = {

@@ -419,6 +419,50 @@ def _decode_attention(impl, q, kc, vc, tables, pos, layer, scale, bs,
     return jnp.einsum(wv, w.astype(v.dtype), v)
 
 
+def _prefix_attention_scores(q, k, v, at, pos, scale, window):
+    """`kv_prefix_attention` as plain XLA (the `off` / `xla` tier, and the
+    tests' reference of the kernel): q ``[H, T, dh]`` at positions ``pos
+    [T]`` against k, v ``[Hkv, M, dh]``, key ``i`` at position ``at[i]``.
+    The scores of every head stand whole in HBM, ``[H, t, M]`` float32
+    twice over (K-EXAONE's 64 heads x 512 rows x 5120 keys: 1.34 GB): past
+    `_SCORES_BYTES` the queries are taken in halves, one after another."""
+    H, T, dh = q.shape
+    Hkv = k.shape[0]
+
+    def attend(rows):
+        """The queries `rows` = (q [H, t, dh], their positions [t])."""
+        qb, pb = rows
+        t = pb.shape[0]
+        m = at[None, :] <= pb[:, None]                     # [t, M]
+        if window is not None:
+            m &= (at[None, :] >= 0) & (at[None, :] > pb[:, None] - window)
+        if Hkv != H:
+            # grouped queries: a K/V head's H // Hkv query heads are rows
+            # of ONE matmul against it; the gathered keys are not repeated
+            qg = qb.reshape(Hkv, (H // Hkv) * t, dh)
+            mg = jnp.tile(m, (H // Hkv, 1))[None]
+        else:
+            qg, mg = qb, m[None]
+        scores = jnp.einsum('htd,hmd->htm', qg, k,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(mg, scores, _NEG_INF)
+        w = jax.nn.softmax(scores, axis=-1)
+        w = jnp.where(mg, w, 0.0)
+        return jnp.einsum('htm,hmd->htd', w.astype(v.dtype),
+                          v).reshape(H, t, dh)
+
+    n = 1
+    while H * (T // n) * at.shape[0] * 4 > _SCORES_BYTES \
+            and T % (2 * n) == 0:
+        n *= 2
+    if n == 1:
+        return attend((q, pos))
+    out = lax.map(attend, (
+        jnp.swapaxes(q.reshape(H, n, T // n, dh), 0, 1),
+        pos.reshape(n, T // n)))
+    return jnp.swapaxes(out, 0, 1).reshape(H, T, dh)
+
+
 @register_op('kv_prefix_attention', share_lod=False)
 def _kv_prefix_attention(ctx, op):
     """Multi-query causal attention of one slot's prefill SUFFIX against
@@ -434,7 +478,19 @@ def _kv_prefix_attention(ctx, op):
     ring cannot hold a suffix, so the suffix's own ``K`` and ``V`` ([1,
     Hkv, T, dh], the rows behind ``Length`` masked) are inputs and the
     cache gives the ``window - 1`` rows before Positions[0] alone — the
-    program writes the suffix behind this op."""
+    program writes the suffix behind this op.
+
+    Two lowerings behind ``kernel_tier.dispatch``, for both calls.
+    ``pallas`` / ``interpret``: the blockwise kernel of
+    ops/prefix_attention.py — the scores stay on the chip, and the keys
+    past the last query's position are neither read nor computed. ``off``
+    / ``xla`` (the CPU, a >1-device mesh, shapes the kernel does not tile,
+    calls whose scores are too few bytes to pay for it —
+    `prefix_attention.shapes_ok`; the tests' reference):
+    `_prefix_attention_scores`, whose scores stand in HBM. Either way a
+    masked key has weight exactly 0."""
+    from . import kernel_tier, prefix_attention as pfa
+    from ..parallel.api import get_active_mesh
     q = ctx.in1(op, 'Q')                        # [1, H, T, dh]
     kc = ctx.in1(op, 'KCache')                  # [NB, Ln, bs, Hkv*dh]
     vc = ctx.in1(op, 'VCache')
@@ -466,43 +522,20 @@ def _kv_prefix_attention(ctx, op):
         at = jnp.concatenate([before, jnp.where(jnp.arange(T) < length,
                                                 pos, -1)])
 
-    def attend(rows):
-        """The queries `rows` = (q [H, t, dh], their positions [t])."""
-        qb, pb = rows
-        t = pb.shape[0]
-        m = at[None, :] <= pb[:, None]                     # [t, M]
-        if window is not None:
-            m &= (at[None, :] >= 0) & (at[None, :] > pb[:, None] - window)
-        if Hkv != H:
-            # grouped queries: a K/V head's H // Hkv query heads are rows
-            # of ONE matmul against it; the gathered keys are not repeated
-            qg = qb.reshape(Hkv, (H // Hkv) * t, dh)
-            mg = jnp.tile(m, (H // Hkv, 1))[None]
-        else:
-            qg, mg = qb, m[None]
-        scores = jnp.einsum('htd,hmd->htm', qg, k,
-                            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(mg, scores, _NEG_INF)
-        w = jax.nn.softmax(scores, axis=-1)
-        w = jnp.where(mg, w, 0.0)
-        return jnp.einsum('htm,hmd->htd', w.astype(v.dtype),
-                          v).reshape(H, t, dh)
-
-    # the scores of every head stand whole, [H, t, M] float32 twice over
-    # (K-EXAONE's 64 heads x 512 rows x 5120 keys: 1.34 GB): past
-    # `_SCORES_BYTES` the queries are taken in halves, one after another
-    n = 1
-    while H * (T // n) * at.shape[0] * 4 > _SCORES_BYTES \
-            and T % (2 * n) == 0:
-        n *= 2
+    mesh = get_active_mesh()
+    meshed = mesh is not None and mesh.size > 1
+    impl = kernel_tier.dispatch(
+        'kv_prefix_attention',
+        pallas_ok=pfa.shapes_ok(H, Hkv, T, dh, at.shape[0]) and not meshed,
+        mesh=mesh)
     with _window_scope(window):
-        if n == 1:
-            out = attend((q[0], pos))
+        if impl in ('pallas', 'interpret'):
+            out = pfa.prefix_attention(
+                q[0], k, v, at, pos, scale=float(scale), window=window,
+                interpret=impl == 'interpret')
         else:
-            out = lax.map(attend, (
-                jnp.swapaxes(q[0].reshape(H, n, T // n, dh), 0, 1),
-                pos.reshape(n, T // n)))
-            out = jnp.swapaxes(out, 0, 1)
+            out = _prefix_attention_scores(q[0], k, v, at, pos, scale,
+                                           window)
     ctx.out(op, 'Out', out.reshape(1, H, T, dh))           # [1, H, T, dh]
 
 

@@ -36,7 +36,8 @@ def _toy():
     # AMP stands the FFN kernel down in training; the f32 serving
     # programs run it (these panels fit its VMEM predicate)
     cfg.train_tiers = dict(interp(cfg.train_tiers), fused_ffn_tail='xla')
-    cfg.serve_tiers = interp(cfg.serve_tiers)
+    cfg.serve_tiers = dict(interp(cfg.serve_tiers),
+                           kv_prefix_attention='xla')
     cfg.mosaic_kernels = {}       # no Mosaic in interpret mode
     return cfg
 

@@ -581,3 +581,80 @@ def test_the_block_copy_takes_the_pool_in_place(one_chip):
     nbytes = 1024 * 6 * 16 * 2048 * 4
     assert ma.alias_size_in_bytes == nbytes
     assert ma.temp_size_in_bytes < nbytes // 4
+
+
+# (H, Hkv, T, dh, keys, window): K-EXAONE's global layer at its widest and
+# narrowest bucket and its window layers' call (127 ring rows + the chunk);
+# doc's two buckets against 1 056 keys (no whole number of key tiles); LFM2;
+# OLMoE; Jamba2's 20 queries on one K/V head; chat's narrowest bucket
+PREFIX_SHAPES = {
+    'kexaone-b512': (64, 8, 512, 128, 5120, None),
+    'kexaone-b128': (64, 8, 128, 128, 5120, None),
+    'kexaone-window-b512': (64, 8, 512, 128, 639, 128),
+    'kexaone-window-b128': (64, 8, 128, 128, 255, 128),
+    'doc-b1024': (32, 32, 1024, 64, 1056, None),
+    'doc-b768': (32, 32, 768, 64, 1056, None),
+    'lfm2-b512': (32, 8, 512, 64, 5120, None),
+    'olmoe-b768': (16, 16, 768, 128, 1280, None),
+    'jamba2-b512': (20, 1, 512, 128, 3072, None),
+    'chat-b64': (16, 16, 64, 64, 768, None)}
+
+
+@pytest.mark.parametrize('shape', PREFIX_SHAPES)
+def test_mosaic_accepts_the_prefix_kernel_at_the_cells_shapes(one_chip,
+                                                              shape):
+    """ops/prefix_attention.py at every shape class a serve cell compiles:
+    one custom call under its own name (a window layer's under another),
+    and nothing beside it as long as the scores."""
+    import functools
+    import jax
+    from paddle_tpu.ops import prefix_attention as pfa
+    H, Hkv, T, dh, M, window = PREFIX_SHAPES[shape]
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    c = jax.jit(functools.partial(
+        pfa.prefix_attention, scale=dh ** -0.5, window=window)).lower(
+        sds((H, T, dh)), sds((Hkv, M, dh)), sds((Hkv, M, dh)),
+        sds((M,), jnp.int32), sds((T,), jnp.int32)).compile()
+    text = c.as_text()
+    name = 'kv_prefix_window_attention' if window else 'kv_prefix_attention'
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r'%%?%s[.\d]* = ' % name, text)
+    assert c.memory_analysis().temp_size_in_bytes < H * T * M * 4 // 4
+
+
+@pytest.mark.parametrize('config,traffic,program,keys,gb', [
+    ('k-exaone-236b-a23b-ep16-l5', 'mixed64-closed', 'prefill_b512',
+     (5120, 639), 27.5),
+    ('fairseq-dense-1.3b', 'doc-closed', 'prefill_b1024', (1056,), 34.0)],
+    ids=['kexaone-b512', 'doc-b1024'])
+def test_a_prefill_program_holds_no_attention_scores(one_chip, monkeypatch,
+                                                     config, traffic,
+                                                     program, keys, gb):
+    """The two widest prefill programs of the benchmark, whole, at their
+    real size (`tools/poolscan.py`'s device-less compile). Until PR 44 the
+    scores of every attention layer stood in HBM — ``f32[8,2048,5120]``,
+    336 MB, twice a chunk in K-EXAONE; ``f32[32,1024,1056]``, 138 MB, 48
+    times in doc — now no float32 value is a row of keys long for as many
+    rows as the bucket has. XLA's `bytes accessed` counts such a value once
+    a fusion that writes it (not once a pass the chip makes over it): it
+    read 29.61 and 39.86 GB at the parent."""
+    import os
+    from tools import poolscan
+    monkeypatch.setenv('PADDLE_FUSED_TIER', 'pallas')
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), '..',
+                        'benchmark')
+    (key, e, cfg, compiled), = poolscan.compiled_programs(
+        os.path.join(root, 'configs', config + '.json'),
+        os.path.join(root, 'traffic', traffic + '.json'), only=[program])
+    text = compiled.as_text()
+    rows = int(program.rsplit('b', 1)[1])
+    scores = [(name, dims)
+              for name, _, dtype, dims in poolscan._outputs(text)
+              if dtype == 'f32' and dims and dims[-1] in keys
+              and np.prod(dims[:-1]) >= rows]
+    assert not scores, scores
+    assert re.search(r'%?kv_prefix_attention[.\d]* = ', text)
+    assert compiled.cost_analysis()['bytes accessed'] < gb * 1e9

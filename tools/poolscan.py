@@ -3,7 +3,8 @@
     JAX_PLATFORMS=cpu python3 tools/poolscan.py \
         --config benchmark/configs/fairseq-dense-355m.json \
         --traffic benchmark/traffic/chat-closed.json \
-        [--layers n] [--root <another checkout>] [--dump <prefix>]
+        [--layers n] [--only prefill_b512,...] [--root <another checkout>]
+        [--dump <prefix>]
 
 Compiles the cell's decode step and EVERY prefill bucket at their real size
 for one chip of the device-less `v5e:2x2` topology (the method and the
@@ -16,11 +17,16 @@ temporaries, and the instructions outside fusions' bodies whose output is
   prefill in chat (`slice_bitcast_fusion`, the copy that
   ``cache[:, layer][tables]`` cost; `kv_cache_ops.pool_pages`);
 - `pool_shaped_outputs`: the pool's own shape — the in-place page writes
-  (`scatter` fusions on the donated pool) and nothing else.
+  (`scatter` fusions on the donated pool) and nothing else;
+- `largest_outputs`: the five kinds of value with the most bytes an
+  instruction, pools apart — where a prefill's attention scores showed
+  (``f32[8,2048,5120]``, 336 MB, twice a chunk in K-EXAONE, until PR 44's
+  kernel kept them on the chip; `largest`).
 
-`--root` compiles another checkout's programs (the parent's, unpacked with
-`git archive`) with this file's reading; `--dump` keeps each program's HLO
-text as `<prefix>.<program>.hlo`.
+`--only` names the programs to compile (`decode_step`, `prefill_b<bucket>`;
+all of them without it). `--root` compiles another checkout's programs (the
+parent's, unpacked with `git archive`) with this file's reading; `--dump`
+keeps each program's HLO text as `<prefix>.<program>.hlo`.
 """
 import argparse
 import collections
@@ -36,13 +42,11 @@ _COMPUTATION = re.compile(r'^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$')
 _VIEWS = ('parameter', 'get-tuple-element', 'tuple', 'bitcast', 'constant')
 
 
-def scan(text, pool_shapes, block_size):
-    """(`layer_share_outputs`, `pool_shaped_outputs`) of one compiled
-    program's HLO `text`: ``{'<opcode> <name> <dtype><dims>': count}`` over
-    the instructions outside fusions' bodies, for pools of `pool_shapes`
-    ``(num_blocks, layers, block_size, W)``."""
+def _outputs(text):
+    """(name, kind, dtype, dims) of every value an instruction outside
+    fusions' bodies makes, in one compiled program's HLO `text`; kind =
+    ``'<opcode> <name without its number> <dtype><dims>'``."""
     fused = set(re.findall(r'calls=%?([\w.\-]+)', text))
-    share, whole = collections.Counter(), collections.Counter()
     inside = None
     for line in text.splitlines():
         head = _COMPUTATION.match(line)
@@ -53,45 +57,72 @@ def scan(text, pool_shapes, block_size):
         if inside in fused or not m or m.group(3) in _VIEWS:
             continue
         name, out, opcode = m.groups()
-        kind = re.sub(r'[.\d]+$', '', name)
         for dtype, dims in _SHAPE.findall(out):
             dims = tuple(int(d) for d in dims.split(',') if d)
-            key = '%s %s %s%s' % (opcode, kind, dtype, list(dims))
-            for pool in pool_shapes:
-                if dims == tuple(pool):
-                    whole[key] += 1
-                elif len(dims) >= 3 and dims[0] == pool[0] \
-                        and dims[-2:] == (block_size, pool[3]) \
-                        and all(d == 1 for d in dims[1:-2]):
-                    share[key] += 1
+            yield name, '%s %s %s%s' % (
+                opcode, re.sub(r'[.\d]+$', '', name), dtype,
+                list(dims)), dtype, dims
+
+
+def largest(text, pool_shapes=(), keep=5):
+    """The `keep` kinds of value with the most bytes an instruction that
+    one compiled program's HLO `text` makes outside fusions' bodies, the
+    pools (`pool_shapes`, in whatever shape they are written) apart:
+    ``[[kind, bytes an instruction, instructions]]``, largest first."""
+    def squeezed(dims):
+        return tuple(d for d in dims if d > 1)
+    pools = {squeezed(p) for p in pool_shapes}
+    count, size = collections.Counter(), {}
+    for _, kind, dtype, dims in _outputs(text):
+        if squeezed(dims) in pools:
+            continue
+        # an element's bytes from the type's bits (pred: one byte)
+        size[kind] = max(1, int(re.sub(r'\D', '', dtype) or 8) // 8)
+        for d in dims:
+            size[kind] *= d
+        count[kind] += 1
+    top = sorted(size, key=size.get, reverse=True)[:keep]
+    return [[k, size[k], count[k]] for k in top]
+
+
+def scan(text, pool_shapes, block_size):
+    """(`layer_share_outputs`, `pool_shaped_outputs`) of one compiled
+    program's HLO `text`: ``{'<opcode> <name> <dtype><dims>': count}`` over
+    the instructions outside fusions' bodies, for pools of `pool_shapes`
+    ``(num_blocks, layers, block_size, W)``."""
+    share, whole = collections.Counter(), collections.Counter()
+    for _, key, _, dims in _outputs(text):
+        for pool in pool_shapes:
+            if dims == tuple(pool):
+                whole[key] += 1
+            elif len(dims) >= 3 and dims[0] == pool[0] \
+                    and dims[-2:] == (block_size, pool[3]) \
+                    and all(d == 1 for d in dims[1:-2]):
+                share[key] += 1
     return dict(share), dict(whole)
 
 
-def main(argv=None):
-    ap = argparse.ArgumentParser()
-    ap.add_argument('--config', required=True)
-    ap.add_argument('--traffic', required=True)
-    ap.add_argument('--layers', type=int, help='override the depth')
-    ap.add_argument('--root', default=os.path.dirname(
+def compiled_programs(config, traffic, layers=None, only=None, root=None):
+    """(program's name, the cell's engine settings, its `LMConfig`, the
+    compiled program) for the decode step and every prefill bucket of the
+    cell `config` x `traffic` (two paths), those `only` names if given, at
+    `layers` layers if given, from the checkout `root` (this one)."""
+    root = os.path.abspath(root or os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
-    ap.add_argument('--dump')
-    args = ap.parse_args(argv)
-    root = os.path.abspath(args.root)
-    sys.path.insert(0, root)
-    from benchmark import size_serve, size_serve_pools
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import size_serve_pools
     from benchmark.run import find_file, load_json, load_module
     from jax.experimental import topologies
     from paddle_tpu.models import transformer as T
-    m, e = load_json(args.config), load_json(args.traffic)['engine']
+    m, e = load_json(config), load_json(traffic)['engine']
     manifest = load_json(os.path.join(root, 'BENCHMARK.json'))
     model = load_module(find_file(manifest, 'models', m['builder'] + '.py'))
     cfg = model.lm_config(m, int(e['max_len']), False)
-    if args.layers:
-        cfg.n_layer = args.layers
+    if layers:
+        cfg.n_layer = layers
     device = topologies.get_topology_desc(
         platform='tpu', topology_name='v5e:2x2').devices[0]
-    shapes = T.kv_cache_shapes(cfg, e['num_blocks'], e['block_size'],
-                               e['slots'])
     programs = [('decode_step', lambda: T.build_lm_decode_step(
         cfg, e['slots'], e['max_len'], block_size=e['block_size'],
         num_blocks=e['num_blocks']), 'next_tokens', e['slots'])]
@@ -102,14 +133,34 @@ def main(argv=None):
                 e['max_len'] // e['block_size'], slots=e['slots'])),
             'first_token', 1))
     for key, build, fetch, rows in programs:
-        compiled = size_serve_pools.compiled_program(
+        if only and key not in only:
+            continue
+        yield key, e, cfg, size_serve_pools.compiled_program(
             build, fetch, rows, device, T.kv_cache_names(cfg))
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument('--config', required=True)
+    ap.add_argument('--traffic', required=True)
+    ap.add_argument('--layers', type=int, help='override the depth')
+    ap.add_argument('--only', help='programs to compile, comma-separated')
+    ap.add_argument('--root')
+    ap.add_argument('--dump')
+    args = ap.parse_args(argv)
+    for key, e, cfg, compiled in compiled_programs(
+            args.config, args.traffic, args.layers,
+            args.only and args.only.split(','), args.root):
+        from benchmark import size_serve
+        from paddle_tpu.models import transformer as T
+        shapes = T.kv_cache_shapes(cfg, e['num_blocks'], e['block_size'],
+                                   e['slots'])
+        pools = sorted(set(map(tuple, shapes.values())))
         text = compiled.as_text()
         if args.dump:
             with open('%s.%s.hlo' % (args.dump, key), 'w') as f:
                 f.write(text)
-        share, whole = scan(text, sorted(set(map(tuple, shapes.values()))),
-                            e['block_size'])
+        share, whole = scan(text, pools, e['block_size'])
         size = size_serve.report(compiled)
         print(json.dumps({
             'program': key, 'layers': cfg.n_layer,
@@ -117,7 +168,8 @@ def main(argv=None):
             'bytes_accessed_gb': round(size['bytes_accessed'] / 1e9, 3),
             'temp_gb': size['temp_gb'], 'flops': size['flops'],
             'mosaic_calls': size['mosaic_calls'],
-            'layer_share_outputs': share, 'pool_shaped_outputs': whole}),
+            'layer_share_outputs': share, 'pool_shaped_outputs': whole,
+            'largest_outputs': largest(text, pools)}),
             flush=True)
     return 0
 
