@@ -25,6 +25,7 @@ run:451, global_scope:34) and the C++ serial executor it drives
   docs/executor_performance.md for the full contract.
 """
 import collections
+import operator
 import os
 import threading
 import time
@@ -66,7 +67,7 @@ class _TensorShim(object):
         return list(np.shape(self._scope._vars[self._name]))
 
     def set(self, value, place=None):
-        self._scope._vars[self._name] = np.asarray(value)
+        self._scope.set(self._name, np.asarray(value))
 
     def set_lod(self, lod):
         self._scope._lods[self._name] = lod
@@ -92,6 +93,14 @@ class Scope(object):
     def __init__(self):
         self._vars = {}
         self._lods = {}
+        # the names some BoundProgram keeps staged (its read-only state),
+        # and how many writes have landed on one of them: a handle
+        # compares `_gen` with the value it staged at. Every write goes
+        # through set / update / drop — nothing else touches `_vars` —
+        # and one to a name nobody staged (the KV pools, a training
+        # run's parameters) costs a set lookup.
+        self._staged = set()
+        self._gen = 0
 
     # dict-ish API used internally
     def get(self, name, default=None):
@@ -99,9 +108,13 @@ class Scope(object):
 
     def set(self, name, value):
         self._vars[name] = value
+        if name in self._staged:
+            self._gen += 1
 
     def update(self, d):
         self._vars.update(d)
+        if self._staged and not self._staged.isdisjoint(d):
+            self._gen += 1
 
     def has(self, name):
         return name in self._vars
@@ -111,6 +124,8 @@ class Scope(object):
 
     def drop(self, name):
         self._vars.pop(name, None)
+        if name in self._staged:
+            self._gen += 1
 
     # fluid-style API
     def find_var(self, name):
@@ -507,18 +522,35 @@ class _DeferredFetch(object):
 
 
 class BoundProgram(object):
-    """A fixed-signature dispatch handle from `Executor.bind`: per-call
-    work is state staging from the scope, one fault-site check, the
-    compiled call, and the scope rebind. No cache-key hashing, no feed
-    re-preparation, no span machinery — the per-token host tax of a
-    decode loop. FLAGS_check_nan_inf raises at the program boundary as
-    in run() (the op-level localization replay stays a run() feature).
-    Calls are NOT thread-safe against each other (the decode loop owns
-    its engine's executor thread)."""
+    """A fixed-signature dispatch handle from `Executor.bind`: a call does
+    only what changes from call to call — the read-written state taken
+    from the scope, one fault-site check, the compiled call, and the
+    scope rebind. No cache-key hashing, no feed re-preparation, no span
+    machinery — the per-token host tax of a decode loop.
+
+    The READ-ONLY state (a decode step's weights: several hundred names
+    that never change) is staged at bind, as the tuple the compiled entry
+    takes (`StateCallable.ro_names`' order), and staged again only after a write to one of its names:
+    `Scope` counts those (`Scope._gen`), a call compares one integer.
+    A weight rebound with `scope.set` or a tensor shim's `set` is what
+    the next call uses, exactly as if every call staged;
+    `executor_bound_restage_total` counts the rebuilds. The READ-WRITTEN
+    state (the KV pools) comes from the scope on every call: other
+    programs rebind the same names between two calls, so the handle
+    never keeps its own outputs. Staging is `Executor._state_value`
+    either way: the not-initialised error, the one lossless upload of a
+    host-written value and the freeze of its buffer. A host value that
+    cannot be cached in the scope (a dtype jax narrows, a view) is
+    converted again every call, as `run()` does.
+
+    FLAGS_check_nan_inf raises at the program boundary as in run() (the
+    op-level localization replay stays a run() feature). Calls are NOT
+    thread-safe against each other (the decode loop owns its engine's
+    executor thread)."""
 
     __slots__ = ('_exe', '_entry', '_program', '_scope', '_needs_rng',
-                 '_key0', '_fp', 'first_out', 'fetch_names',
-                 'example_feed')
+                 '_key0', '_fp', '_ro', '_ro_gen', 'restages', 'first_out',
+                 'fetch_names', 'example_feed')
 
     def __init__(self, exe, entry, program, scope, needs_rng, first_out,
                  example_feed=None):
@@ -540,21 +572,41 @@ class BoundProgram(object):
         # bench timing loops — pass it back verbatim instead of
         # re-preparing per call
         self.example_feed = example_feed
+        # how often a call found the scope written and staged again
+        self.restages = 0
+        scope._staged.update(entry.ro_names)
+        self._stage()
+
+    def _stage(self):
+        """Stage the read-only state in the entry's order and note the
+        scope's write count it is good for."""
+        scope, program = self._scope, self._program
+        state_value = self._exe._state_value
+        names = self._entry.fn.ro_names
+        ro = tuple([state_value(scope, n, program) for n in names])
+        # read AFTER staging (an upload cached back into the scope is a
+        # write too) and BEFORE the comparison: a write that lands later
+        # moves the count, one that landed earlier fails the comparison
+        gen = scope._gen
+        # what the scope does not hold is a host value converted for this
+        # call alone: keep nothing, the next call converts it again
+        held = all(map(operator.is_, ro, map(scope.get, names)))
+        self._ro = ro
+        self._ro_gen = gen if held else None
 
     def __call__(self, feed, return_numpy=True):
         entry = self._entry
         scope = self._scope
-        ro_state, rw_state = {}, {}
-        exe = self._exe
-        # _state_value, not a bare scope.get: it raises the clear
-        # not-initialized error, uploads host-written state once with the
-        # lossless-conversion + writeable-freeze guards, and skips
-        # caching for read-written names (new_state rebinds those)
-        for n in entry.ro_names:
-            ro_state[n] = exe._state_value(scope, n, self._program)
-        for n in entry.rw_names:
-            rw_state[n] = exe._state_value(scope, n, self._program,
-                                           cache=False)
+        if scope._gen != self._ro_gen:
+            monitor.inc('executor_bound_restage_total')
+            self.restages += 1
+            self._stage()
+        ro = self._ro
+        state_value = self._exe._state_value
+        # cache=False: new_state rebinds these right after the call
+        rw_names = entry.fn.rw_names
+        rw = tuple([state_value(scope, n, self._program, cache=False)
+                    for n in rw_names])
         if self._needs_rng:
             self._exe._run_counter += 1
             key_arr = _run_key(self._program.random_seed,
@@ -562,16 +614,18 @@ class BoundProgram(object):
                                self._exe._run_counter)
         else:
             key_arr = self._key0
+        flat = entry.fn.flat
 
         def _dispatch():
             resilience.maybe_fault('run')
-            return entry.fn(feed, ro_state, rw_state, key_arr)
+            return flat(feed, ro, rw, key_arr)
         t_disp = time.perf_counter()
         try:
             fetches, new_state = _dispatch()
         except Exception as e:          # noqa: BLE001 — classified inside
             fetches, new_state = resilience.retry_after(
-                e, _dispatch, site='run', state=rw_state)
+                e, _dispatch, site='run',
+                state=dict(zip(rw_names, rw)))
             # failed attempts + backoff sleeps are the retry_backoff
             # loss bucket, not device-busy: restart the window at the
             # successful dispatch so the completer's serial attribution
@@ -1892,10 +1946,12 @@ class Executor(object):
         normal `run()` (compiling and caching as usual), then return a
         `BoundProgram` whose calls skip the per-run key work — feed
         preparation, fingerprint/signature hashing, cache lookup and span
-        bookkeeping — and go straight to state staging + compiled
-        dispatch. Built for token-decode loops (serving/generate.py),
-        where `run()`'s ~200 µs host tax is paid once per generated token
-        engine-wide.
+        bookkeeping — and the staging of the state that does not change:
+        the read-only names are staged here, once, and again only after
+        a scope write to one of them; a call takes the read-written
+        names from the scope and dispatches. Built for token-decode
+        loops (serving/generate.py), where `run()`'s host tax would be
+        paid once per generated token engine-wide.
 
         Contract: every subsequent call must feed the SAME names, shapes
         and dtypes as `feed` (the bound executable is never re-keyed); the

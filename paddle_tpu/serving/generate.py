@@ -498,7 +498,7 @@ class _Slot(object):
         # the prefill's `_First` until the loop picks the first token up
         # (`_pickup`): `last` is on the device only, nothing is emitted
         # yet, and a step takes the row's token from the engine's buffer
-        # of first tokens (`_carry_tokens`)
+        # of first tokens (`_stage_feeds`)
         self.first = None
         # pos, generated and last are as of the last step DELIVERED;
         # `ahead` counts the steps dispatched for this slot since (0 or
@@ -641,7 +641,7 @@ class GenerateEngine(object):
         self._max_blocks = c.max_len // c.block_size
         self._cow_jit = None
         self._dcopy_jit = None
-        self._carry_jit = None
+        self._stage_jit = None
         self._put_jit = None
         # the prefills' first tokens as they are on the device, a row a
         # slot, and a step's output to stand for "no step in flight"
@@ -709,6 +709,7 @@ class GenerateEngine(object):
         self._prefill_bound = {}
         self._draft_prefill_bound = {}
         self._step_bound = None
+        self._handles = []      # every BoundProgram warmup made
         self._drafter_bound = None
         self._verify_bound = None
         self._thread = None
@@ -943,27 +944,42 @@ class GenerateEngine(object):
             self._draft_scope.set(name,
                                   self._dcopy_jit(dst, src, d_ids, s_ids))
 
-    def _carry_tokens(self, prev, src, toks):
-        """The next step's 'gen_tokens' where a row's token is on the
-        device: row i takes the token step `prev` (its device fetch, the
-        tokens leading it; None with no step in flight) made for it where
-        `src[i]` is 1, its prefill's first token (`_put_first`) where 2,
-        the host's `toks[i]` elsewhere — an empty row, or one whose last
-        token was delivered. One tiny jitted select, compiled at warmup:
-        neither token crosses to the host and back."""
+    def _stage_feeds(self, prev, src, toks, feed):
+        """The step's integer feeds (`feed`: positions and tables) and
+        its input tokens as they are on the device, from ONE host array:
+        a host array costs a transfer of its own whatever its size
+        (~0.12 ms on a v5e's host), so `feed`'s columns, `toks` and `src`
+        go up side by side and one tiny jitted program, compiled at
+        warmup, splits them again. The same program is the select of
+        'gen_tokens' where a row's token is on the device: row i takes
+        the token step `prev` (its device fetch, the tokens leading it;
+        None with no step in flight) made for it where `src[i]` is 1, its
+        prefill's first token (`_put_first`) where 2, the host's
+        `toks[i]` elsewhere — an empty row, or one whose last token was
+        delivered. Neither token crosses to the host and back."""
         import jax
-        if self._carry_jit is None:
+        if self._stage_jit is None:
             import jax.numpy as jnp
             S = self.config.slots
+            # a feed's name and columns, in the array's order
+            layout = [(n, feed[n].shape[1]) for n in sorted(feed)]
 
-            def carry_tokens(prev, first, src, toks):
+            def stage_step_feeds(prev, first, ints):
+                out, at = {}, 0
+                for name, width in layout:
+                    out[name] = ints[:, at:at + width]
+                    at += width
+                toks, src = ints[:, at:at + 1], ints[:, at + 1:at + 2]
                 mine = prev.reshape(-1)[:S].reshape(S, 1)
-                return jnp.where(src == 1, mine.astype(toks.dtype),
-                                 jnp.where(src == 2, first.astype(toks.dtype),
-                                           toks))
-            self._carry_jit = jax.jit(carry_tokens)
-        return self._carry_jit(self._no_prev if prev is None else prev,
-                               self._first_buf, src, toks)
+                out['gen_tokens'] = jnp.where(
+                    src == 1, mine.astype(toks.dtype),
+                    jnp.where(src == 2, first.astype(toks.dtype), toks))
+                return out
+            self._stage_jit = jax.jit(stage_step_feeds)
+        ints = np.concatenate(
+            [feed[n] for n in sorted(feed)] + [toks, src], axis=1)
+        return self._stage_jit(self._no_prev if prev is None else prev,
+                               self._first_buf, ints)
 
     def _put_first(self, slot, out):
         """A prefill's first token (its device fetch, the token leading
@@ -1067,6 +1083,7 @@ class GenerateEngine(object):
                 "KV cache and must not race the started engine loop — "
                 "warm up before start() (start() warms up automatically)")
         self._ensure_cache()
+        del self._handles[:]
         from ..warmfarm import farm
         t0 = time.perf_counter()
         before = monitor.counters()
@@ -1087,30 +1104,31 @@ class GenerateEngine(object):
                 key, already = farm.track(self.executor, prog, pfeed,
                                           fetch_list=fetch,
                                           scope=self.scope)
-                self._prefill_bound[b] = self.executor.bind(
+                self._prefill_bound[b] = self._bind(
                     prog, pfeed, fetch_list=fetch, scope=self.scope)
                 if already:
                     reused += 1
                 else:
                     farm.commit(key)
-            feed = {'gen_tokens': np.zeros((S, 1), 'int64'),
-                    'gen_pos': np.zeros((S, 1), 'int64')}
-            feed.update(self._tables_feed(
+            toks = np.zeros((S, 1), 'int64')
+            ints = {'gen_pos': np.zeros((S, 1), 'int64')}
+            ints.update(self._tables_feed(
                 np.zeros((S, self._max_blocks), 'int64')))
-            feed.update(self._sample_feed(S))
+            feed = dict(ints, gen_tokens=toks, **self._sample_feed(S))
             fetch = [self._token_fetch(self._step_vars, 'next_tokens')]
             key, already = farm.track(
                 self.executor, self._step_prog, feed, fetch_list=fetch,
                 scope=self.scope)
-            self._step_bound = self.executor.bind(
+            self._step_bound = self._bind(
                 self._step_prog, feed, fetch_list=fetch, scope=self.scope)
             if already:
                 reused += 1
             else:
                 farm.commit(key)
-            # the pipelined loop feeds the step its predecessor's tokens
-            # as they are on the device (int32 there, with x64 off, where
-            # the numpy feed is int64): compile the select and run the
+            # the loop feeds the step from the device: its predecessor's
+            # tokens as they are there and the host's feeds split there
+            # (`_stage_feeds`; int32 on the device, with x64 off, where
+            # the numpy feed is int64): compile that program and run the
             # step on its output now. It is the executable just bound and
             # no second one (tests/test_decode_pipeline.py counts jax's
             # own compiles); all-zero tables keep the writes in the trash
@@ -1125,9 +1143,10 @@ class GenerateEngine(object):
             self._put_first(0, self._prefill_bound[
                 self.config.prompt_buckets[-1]](pfeed,
                                                 return_numpy=False)[0])
-            self._step_bound(dict(feed, gen_tokens=self._carry_tokens(
-                out[0], np.zeros((S, 1), 'int8'), feed['gen_tokens'])),
-                return_numpy=False)
+            staged = self._stage_feeds(
+                out[0], np.zeros((S, 1), 'int8'), toks, ints)
+            self._step_bound(dict(staged, **self._sample_feed(S)),
+                             return_numpy=False)
             if self.config.speculative:
                 reused += self._warm_spec(farm)
             # compile the copy-on-write block copy now (0 -> 0 is a
@@ -1145,6 +1164,13 @@ class GenerateEngine(object):
         return {'buckets': len(self._prefill_bound),
                 'compiles': int(compiles), 'reused': int(reused),
                 'seconds': round(time.perf_counter() - t0, 3)}
+
+    def _bind(self, program, feed, fetch_list, scope):
+        """`Executor.bind`, the handle kept for stats()['bound_restages']."""
+        bound = self.executor.bind(program, feed, fetch_list=fetch_list,
+                                   scope=scope)
+        self._handles.append(bound)
+        return bound
 
     def _warm_spec(self, farm):
         """Bind + compile the speculative signature set: one DRAFT
@@ -1170,7 +1196,7 @@ class GenerateEngine(object):
             key, already = farm.track(self.executor, prog, feed,
                                       fetch_list=[v['first_token']],
                                       scope=self._draft_scope)
-            self._draft_prefill_bound[b] = self.executor.bind(
+            self._draft_prefill_bound[b] = self._bind(
                 prog, feed, fetch_list=[v['first_token']],
                 scope=self._draft_scope)
             if already:
@@ -1185,7 +1211,7 @@ class GenerateEngine(object):
         key, already = farm.track(self.executor, self._drafter_prog,
                                   feed, fetch_list=fetches,
                                   scope=self._draft_scope)
-        self._drafter_bound = self.executor.bind(
+        self._drafter_bound = self._bind(
             self._drafter_prog, feed, fetch_list=fetches,
             scope=self._draft_scope)
         if already:
@@ -1200,7 +1226,7 @@ class GenerateEngine(object):
             self.executor, self._verify_prog, feed,
             fetch_list=[self._verify_vars['verify_tokens']],
             scope=self.scope)
-        self._verify_bound = self.executor.bind(
+        self._verify_bound = self._bind(
             self._verify_prog, feed,
             fetch_list=[self._verify_vars['verify_tokens']],
             scope=self.scope)
@@ -2280,7 +2306,7 @@ class GenerateEngine(object):
         can do host work while the device computes. With `prev`, the
         step dispatched before this one and not fetched yet, a row of
         `prev` takes its input token from `prev`'s output on the device
-        (`_carry_tokens`) and writes one position further; a row
+        (`_stage_feeds`) and writes one position further; a row
         admitted since takes its prefill's first token, on the device as
         well (`generate_first_token_carried_total`); every other row is
         fed from the host as without. Returns the step's `_Flight`, or
@@ -2363,13 +2389,12 @@ class GenerateEngine(object):
                             len(active) * self._n_ssm)
             feed = {'gen_pos': pos}
             feed.update(self._tables_feed(btab, ((i, i) for i, _ in active)))
-            feed.update(sample)
         with _loop_phase('dispatch'):
             t0 = time.perf_counter()
             try:
-                feed['gen_tokens'] = self._carry_tokens(
-                    None if prev is None else prev.out[0], src,
-                    toks) if src.any() else toks
+                feed = self._stage_feeds(
+                    None if prev is None else prev.out[0], src, toks, feed)
+                feed.update(sample)
                 out = self._step_bound(feed, return_numpy=False)
                 # the device-to-host copy starts now and is latency
                 # behind the next step, not a wait after this one
@@ -2641,6 +2666,10 @@ class GenerateEngine(object):
             'overlapped_steps': self._overlapped_steps,
             'discarded_rows': self._discarded_rows,
             'first_tokens_carried': self._first_carried,
+            # how often one of the engine's bound programs staged its
+            # weights again because the scope was written: 0 in steady
+            # serving, + 1 a handle after a weight is rebound
+            'bound_restages': sum(b.restages for b in self._handles),
             'decode_tokens': self._decode_tokens,
             'peak_slot_occupancy': round(self._occ_peak, 4),
             'mean_slot_occupancy': round(self._occ_sum / steps, 4)

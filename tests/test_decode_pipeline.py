@@ -112,10 +112,12 @@ def test_next_step_goes_out_before_the_last_is_fetched_on_device_tokens():
     assert kinds == ['dispatch'] * 2 + ['deliver', 'dispatch'] * (n - 3) \
         + ['deliver'] * 2
     fed = [t for k, t in log if k == 'dispatch']
-    # the prefill's token: admitted with nothing in flight, the serial pass
-    assert fed[0] is np.ndarray
-    assert all(issubclass(t, jax.Array) for t in fed[1:])   # never numpy
+    # every step's feeds are split on the device (`_stage_feeds`): never
+    # numpy
+    assert all(issubclass(t, jax.Array) for t in fed)
     delta = monitor.counter_delta(before)
+    # the prefill's token: admitted with nothing in flight, the serial
+    # pass, so the first step took it from the host's column
     assert 'generate_first_token_carried_total' not in delta
     assert delta['generate_overlapped_steps_total'] == n - 2
     assert 'generate_discarded_rows_total' not in delta
@@ -444,12 +446,12 @@ def _watch(eng, log):
     rows whose token came from the first tokens' buffer) at the two
     kinds of dispatch, ('fetch', n) where a fetched vector crosses to the
     host — n is 1 for a prefill's output, the slots for a step's."""
-    step, split, carry = eng._step_bound, eng._split_load, eng._carry_tokens
+    step, split, stage = eng._step_bound, eng._split_load, eng._stage_feeds
     src = []
 
-    def carried(prev, s, toks):
+    def carried(prev, s, toks, feed):
         src.append(int((s == 2).sum()))
-        return carry(prev, s, toks)
+        return stage(prev, s, toks, feed)
 
     def bound(feed, **kw):
         log.append(('step', src.pop() if src else 0))
@@ -458,7 +460,7 @@ def _watch(eng, log):
     def fetched(out, n):
         log.append(('fetch', n))
         return split(out, n)
-    eng._carry_tokens, eng._step_bound, eng._split_load = \
+    eng._stage_feeds, eng._step_bound, eng._split_load = \
         carried, bound, fetched
     for b, f in list(eng._prefill_bound.items()):
         eng._prefill_bound[b] = (

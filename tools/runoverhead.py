@@ -13,6 +13,12 @@ executor_performance.md) makes measurable promises about:
   (structurally identical, new `_uid`) program. The process-wide
   fingerprint cache must answer it without retracing, so this should be
   milliseconds against a first_compile_s of seconds.
+- bound_overhead_us: host time of ONE steady `Executor.bind` call on a
+  program that reads N read-only persistables and adds them up, at N = 2
+  and N = 300 (a served model's decode step reads ~300 weights): the
+  handle stages them once, so what is left a variable is the compiled
+  call's own argument handling. bound_overhead_us_per_var is the slope
+  between the two.
 
 Usage: python tools/runoverhead.py [rounds]   (prints one JSON line)
 """
@@ -36,9 +42,40 @@ def _build():
     return main_p, startup
 
 
+def measure_bound_overhead(n_vars, rounds=300):
+    """Host microseconds of one steady bound call on a program that reads
+    `n_vars` read-only persistables of 256 floats and adds them up."""
+    import jax
+    import numpy as np
+    import paddle_tpu as fluid
+    main_p, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main_p, startup):
+        with fluid.unique_name.guard():
+            x = fluid.layers.data(name='x', shape=[256], dtype='float32')
+            total = fluid.layers.sums([x] + [
+                fluid.layers.create_global_var(
+                    [256], value=float(i), dtype='float32',
+                    persistable=True, name='boundoverhead_w%d' % i)
+                for i in range(n_vars)])
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    scope = fluid.Scope()
+    feed = {'x': np.zeros((1, 256), 'float32')}
+    exe.run(startup, scope=scope)
+    bound = exe.bind(main_p, feed, fetch_list=[total], scope=scope)
+    assert len(bound._entry.ro_names) == n_vars
+    out = bound(feed, return_numpy=False)
+    jax.block_until_ready(out)
+    t0 = time.time()
+    for _ in range(rounds):
+        out = bound(feed, return_numpy=False)
+    jax.block_until_ready(out)
+    return (time.time() - t0) / rounds * 1e6
+
+
 def measure_run_overhead(rounds=300):
     """Returns {'run_overhead_us', 'first_compile_s', 'cache_hit_compile_s',
-    'rounds'}; importable."""
+    'bound_overhead_us', 'bound_overhead_us_per_var', 'rounds'};
+    importable."""
     import jax
     import paddle_tpu as fluid
 
@@ -70,9 +107,15 @@ def measure_run_overhead(rounds=300):
         jax.block_until_ready(scope2.get('runoverhead_w'))
         cache_hit_compile_s = time.time() - t0
 
+    few, many = 2, 300
+    bound_us = {n: measure_bound_overhead(n, rounds) for n in (few, many)}
     return {'run_overhead_us': round(overhead_us, 1),
             'first_compile_s': round(first_compile_s, 3),
             'cache_hit_compile_s': round(cache_hit_compile_s, 4),
+            'bound_overhead_us': {str(n): round(us, 1)
+                                  for n, us in bound_us.items()},
+            'bound_overhead_us_per_var': round(
+                (bound_us[many] - bound_us[few]) / (many - few), 3),
             'rounds': rounds}
 
 
