@@ -6,6 +6,8 @@ NMT. All ops are dense batched matmuls -> MXU-friendly; parameters carry
 naming conventions ('*.qkv*', '*.ffn1*', ...) that parallel/api.py's sharding
 rules match for tensor parallelism.
 """
+import collections
+
 import numpy as np
 
 from .. import layers
@@ -782,21 +784,13 @@ def build_lm(cfg=None, is_test=False):
 # Parameter names match build_lm exactly — a scope trained (or loaded) for
 # the LM serves decode without any renaming.
 #
-# A model with WINDOW layers (`LMConfig.layer_types` 'window') declares a
-# second pair of pools for them, `WINDOW_CACHE_K` / `WINDOW_CACHE_V`
-# ([slots * ring + 1, window layers, block_size, kv_width]: `window_ring`
-# blocks a slot, used as a ring, and the trash block), and both programs
-# take a second table feed, 'gen_wtab' [rows, ring]: the engine gives slot
-# i its ring for as long as it is resident (serving/kv_blocks.py
-# `WindowRings`), so these pools are sized by the slots and no allocator
-# serves them. Each attention layer's ops get its kind's pool, table and
+# A model with WINDOW or STATE-SPACE layers (`LMConfig.layer_types`
+# 'window', 'ssm') declares further pools, sized by the engine's slots and
+# served by no allocator, and both programs take the feed that indexes them
+# (`cache_pools`, `_slot_feeds`): slot i's ring of `window_ring` blocks
+# while it is resident, its row i + 1 (0 for a row that sits a step out:
+# ops/ssm_ops.py). Each attention layer's ops get its kind's pool, table and
 # bound; only the window layers rotate q and k where `global_rope` is off.
-#
-# A model with STATE-SPACE layers (`layer_types` 'ssm') declares `SSM_STATE`
-# and `SSM_TAIL`, a row a slot and the trash row, and both programs take
-# the feed 'gen_srow' [rows, 1]: slot i's row is i + 1 while it is
-# resident, and 0 for a row that sits a step out (ops/ssm_ops.py). The K/V
-# pools hold the attention layers only.
 #
 # The decode step (and the prefill, for the FIRST token) ends in the
 # `sample_next_token` op: per-slot temperature / top-k / top-p feeds plus
@@ -846,86 +840,116 @@ def window_pool_blocks(cfg, slots, block_size):
     return slots * window_ring(cfg, block_size) + 1
 
 
-def kv_cache_names(cfg):
-    """The pools a model's programs declare. Indexed by the block
-    allocator's ids: K and V apart, or with latent attention the ONE pool
-    of latent rows (under K's name); with convolution layers the pool of
-    their tails as well. With window layers, indexed by the slots' rings
-    (`window_ring`): those layers' K and V. With state-space layers,
-    indexed by the slots' rows (slot ``i`` has row ``i + 1``; row 0 is the
-    trash row): the recurrence's state and the convolution's tail."""
-    names = (KV_CACHE_K,) if cfg.attention == 'mla' \
-        else (KV_CACHE_K, KV_CACHE_V)
+# A pool of a model's programs, one row of `cache_pools`' table. `index`:
+# what indexes its leading dimension -- 'block', the allocator's block ids;
+# 'ring', a slot's ring of blocks; 'row', a slot's row (`INDEX_FEEDS`: the
+# feed each comes in). `rewinds`: a rejected draft can be rewound from it
+# (rows past the accepted write head are masked, nothing is copied).
+# `copies`: a shared block's entry can be copied and resumed at its last
+# row. `why`: what an engine says when it refuses an option over the pool.
+# `books`: the series the engine books for the pool's ``shape[1]`` layers,
+# once a kind -- 'step': (series, rows a slot a decode step reads at most;
+# None: all up to its position); 'prefill': the REAL rows a prefill walks;
+# 'resume': the dispatches that resume past position 0 from what it holds.
+Pool = collections.namedtuple('Pool',
+                              'name shape index rewinds copies why books')
+INDEX_FEEDS = {'block': 'gen_btab', 'ring': 'gen_wtab', 'row': 'gen_srow'}
+
+
+def cache_pools(cfg, num_blocks=0, block_size=1, slots=None):
+    """The pools a model's programs declare, in the order of their state:
+    the ONE place that maps a layer kind to a pool, and all an engine knows
+    of a kind (serving/generate.py walks it). A pool the slots size has
+    shape None where ``slots`` is not given.
+
+    Indexed by the block allocator's ids: K and V apart, the GLOBAL
+    attention layers' pages, or with latent attention the ONE pool of
+    latent rows (under K's name); with convolution layers their tails too,
+    a block's ``conv_kernel - 1`` rows a layer. With window layers, indexed
+    by the slots' rings (`window_ring`): those layers' K and V. With
+    state-space layers, indexed by the slots' rows (slot ``i`` has row ``i
+    + 1``; row 0 is the trash row): the state (the channels minor: whole
+    vregs of lanes) and the tail (the ``ssm_conv - 1`` rows a layer keeps in
+    a sublane tile of their own: ops/ssm_ops.py has what a padded one cost)."""
+    pools = []
+
+    def kind(shapes, index, rewinds, copies, why=None, **books):
+        sized = index == 'block' or slots is not None
+        for i, (name, shape) in enumerate(shapes):
+            pools.append(Pool(name, shape if sized else None, index, rewinds,
+                              copies, why, {} if i else books))
+
+    latent = cfg.attention == 'mla'
+    n = slots or 0
+    kv = (num_blocks, cfg.n_attn_layers, block_size, cfg.kv_width)
+    kind([(KV_CACHE_K, kv)] + [(KV_CACHE_V, kv)] * (not latent), 'block',
+         True, True, step=('kv_latent_tokens_read_total' if latent
+                           else 'kv_tokens_read_total', None))
     if cfg.n_conv_layers:
-        names += (CONV_CACHE,)
+        kind([(CONV_CACHE, (num_blocks, cfg.n_conv_layers,
+                            cfg.conv_kernel - 1, cfg.d_model))],
+             'block', False, False,
+             "a rejected draft rewinds positions, and a convolution layer's "
+             "tail in the block pool cannot be rewound (it holds the last "
+             "rows written, not every row)",
+             resume='conv_tail_resumes_total')
     if cfg.n_window_layers:
-        names += (WINDOW_CACHE_K, WINDOW_CACHE_V)
+        kv = (window_pool_blocks(cfg, n, block_size), cfg.n_window_layers,
+              block_size, cfg.kv_width)
+        kind([(WINDOW_CACHE_K, kv), (WINDOW_CACHE_V, kv)], 'ring', False,
+             False,
+             "a window layer's blocks are a ring that its slot writes over "
+             "-- a rejected draft cannot be unwound from it, and a shared "
+             "block's window rows are gone once its first tenant has moved "
+             "on", step=('kv_window_tokens_read_total', cfg.sliding_window))
     if cfg.n_ssm_layers:
-        names += (SSM_STATE, SSM_TAIL)
-    return names
+        kind([(SSM_STATE, (n + 1, cfg.n_ssm_layers, cfg.ssm_state,
+                           cfg.ssm_inner)),
+              (SSM_TAIL, (n + 1, cfg.n_ssm_layers, ssm_ops.TAIL_ROWS,
+                          cfg.ssm_inner))], 'row', False, False,
+             "a state-space layer's state is a row a slot, the recurrence "
+             "up to the slot's last position -- a shared block has no state "
+             "to resume from, and a rejected draft cannot be unwound from "
+             "it", step=('ssm_state_rows_updated_total', 1),
+             prefill='ssm_prefill_rows_total',
+             resume='ssm_state_resumes_total')
+    return tuple(pools)
+
+
+def kv_cache_names(cfg):
+    """The names of `cache_pools`' pools, in its order."""
+    return tuple(pool.name for pool in cache_pools(cfg))
 
 
 def kv_cache_shapes(cfg, num_blocks, block_size, slots=None):
-    """name -> shape of every pool of `kv_cache_names`. K/V: the GLOBAL
-    attention layers' pages, ``[num_blocks, n_attn_layers, block_size,
-    kv_width]``; the tails: a block's ``conv_kernel - 1`` rows a
-    convolution layer, ``[num_blocks, n_conv_layers, conv_kernel - 1,
-    d_model]``; the window layers' K/V: ``[window_pool_blocks,
-    n_window_layers, block_size, kv_width]``, sized by the engine's
-    ``slots`` and nothing else, as are a state-space model's two: the
-    state ``[slots + 1, n_ssm_layers, ssm_state, ssm_inner]`` (the channels
-    minor: whole vregs of lanes) and the tail ``[slots + 1, n_ssm_layers,
-    8, ssm_inner]`` (the ``ssm_conv - 1`` rows a layer keeps in a sublane
-    tile of their own: ops/ssm_ops.py says what a padded tile cost)."""
-    kv = (num_blocks, cfg.n_attn_layers, block_size, cfg.kv_width)
-    shapes = {KV_CACHE_K: kv, KV_CACHE_V: kv,
-              CONV_CACHE: (num_blocks, cfg.n_conv_layers,
-                           cfg.conv_kernel - 1, cfg.d_model)}
-    if (cfg.n_window_layers or cfg.n_ssm_layers) and slots is None:
+    """name -> shape of every pool of `cache_pools`; the window and the
+    state-space layers' are sized by the engine's ``slots`` alone."""
+    pools = cache_pools(cfg, num_blocks, block_size, slots)
+    if any(pool.shape is None for pool in pools):
         raise ValueError("LMConfig.layer_types=%r: the window and the "
                          "state-space layers' pools are sized by the slots"
                          % (cfg.layer_types,))
-    if cfg.n_ssm_layers:
-        shapes[SSM_STATE] = (slots + 1, cfg.n_ssm_layers, cfg.ssm_state,
-                             cfg.ssm_inner)
-        shapes[SSM_TAIL] = (slots + 1, cfg.n_ssm_layers, ssm_ops.TAIL_ROWS,
-                            cfg.ssm_inner)
-    if cfg.n_window_layers:
-        shapes[WINDOW_CACHE_K] = shapes[WINDOW_CACHE_V] = (
-            window_pool_blocks(cfg, slots, block_size), cfg.n_window_layers,
-            block_size, cfg.kv_width)
-    return {name: shapes[name] for name in kv_cache_names(cfg)}
+    return {pool.name: pool.shape for pool in pools}
 
 
 def _declare_paged_kv_caches(block, cfg, num_blocks, block_size, slots=None):
-    """(K pool, V pool, tail pool, window K pool, window V pool, state
-    pool, its tail pool) of `kv_cache_names`; None for a pool the model
-    does not have."""
-    pools = {name: block.create_var(name=name, shape=shape, dtype='float32',
-                                    persistable=True, stop_gradient=True)
-             for name, shape in kv_cache_shapes(cfg, num_blocks, block_size,
-                                                slots).items()}
-    return [pools.get(name) for name in (KV_CACHE_K, KV_CACHE_V, CONV_CACHE,
-                                         WINDOW_CACHE_K, WINDOW_CACHE_V,
-                                         SSM_STATE, SSM_TAIL)]
+    """name -> var of every pool of `cache_pools`."""
+    return {name: block.create_var(name=name, shape=shape, dtype='float32',
+                                   persistable=True, stop_gradient=True)
+            for name, shape in kv_cache_shapes(cfg, num_blocks, block_size,
+                                               slots).items()}
 
 
-def _state_rows(cfg):
-    """The feed of the slots' rows in the state-space layers' pools,
-    'gen_srow' ``[rows, 1]`` (0: none, the trash row); None for a model
-    without such layers."""
-    if not cfg.n_ssm_layers:
-        return None
-    return layers.data(name='gen_srow', shape=[1], dtype='int64')
-
-
-def _window_table(cfg, block_size):
-    """The feed of the slots' window tables, 'gen_wtab' ``[rows,
-    window_ring]``; None for a model without window layers."""
-    if not cfg.n_window_layers:
-        return None
-    return layers.data(name='gen_wtab', shape=[window_ring(cfg, block_size)],
-                       dtype='int64')
+def _slot_feeds(cfg, block_size):
+    """index -> the feed of every index a pool of `cache_pools` has beside
+    'block' ('gen_btab' is each builder's own): the slots' window tables
+    ``[rows, window_ring]``, the slots' rows in the state-space layers'
+    pools ``[rows, 1]`` (0: none, the trash row)."""
+    present = {pool.index for pool in cache_pools(cfg)}
+    return {index: layers.data(name=INDEX_FEEDS[index], shape=[width],
+                               dtype='int64')
+            for index, width in (('ring', window_ring(cfg, block_size)),
+                                 ('row', 1)) if index in present}
 
 
 SAMPLE_FEEDS = ('gen_temp', 'gen_topk', 'gen_topp', 'gen_u')
@@ -934,11 +958,9 @@ SAMPLE_FEEDS = ('gen_temp', 'gen_topk', 'gen_topp', 'gen_u')
 def _sampling_inputs():
     """Per-row sampling-control feeds ([rows, 1]; [1, 1] in prefill):
     temperature, top-k, top-p, and the host-drawn uniform."""
-    temp = layers.data(name='gen_temp', shape=[1], dtype='float32')
-    topk = layers.data(name='gen_topk', shape=[1], dtype='int64')
-    topp = layers.data(name='gen_topp', shape=[1], dtype='float32')
-    u = layers.data(name='gen_u', shape=[1], dtype='float32')
-    return temp, topk, topp, u
+    return tuple(layers.data(name=name, shape=[1], dtype=dtype)
+                 for name, dtype in zip(SAMPLE_FEEDS, (
+                     'float32', 'int64', 'float32', 'float32')))
 
 
 def _append_sample_op(block, logits, sample_vars, out_name):
@@ -1029,10 +1051,9 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
     'gen_pos' [slots, 1] int64 (the position each slot writes this step),
     the `SAMPLE_FEEDS` quad [slots, 1] (temperature / top-k / top-p /
     host uniform; all-zero = bitwise greedy), and 'gen_btab'
-    [slots, max_len // block_size] int64 per-slot block tables; with
-    window layers also 'gen_wtab' [slots, window_ring], the slots' rings
-    in those layers' pools; with state-space layers 'gen_srow' [slots, 1],
-    the slots' rows in theirs (0: the row sits this step out). Returns
+    [slots, max_len // block_size] int64 per-slot block tables; with window
+    or state-space layers also their `_slot_feeds`, the slots' rings and
+    rows in those layers' pools (row 0: it sits this step out). Returns
     {'tokens', 'pos', 'logits', 'next_tokens', 'k_cache', 'v_cache'} —
     fetch 'next_tokens' ([slots] int64). With experts
     (`cfg.ffn == 'moe'`) also 'tokens_and_load' — next_tokens and the
@@ -1047,10 +1068,12 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
     block = tokens.block
     mb = max_len // block_size
     btab = layers.data(name='gen_btab', shape=[mb], dtype='int64')
-    wtab = _window_table(cfg, block_size)
-    srow = _state_rows(cfg)
-    kc, vc, tails, wkc, wvc, states, stails = _declare_paged_kv_caches(
-        block, cfg, num_blocks, block_size, slots)
+    feeds = _slot_feeds(cfg, block_size)
+    pools = _declare_paged_kv_caches(block, cfg, num_blocks, block_size,
+                                     slots)
+    kc, vc = pools[KV_CACHE_K], pools.get(KV_CACHE_V)
+    window = (pools.get(WINDOW_CACHE_K), pools.get(WINDOW_CACHE_V)), \
+        feeds.get('ring')
 
     x = layers.embedding(
         tokens, size=[cfg.vocab_size, d], dtype='float32',
@@ -1061,20 +1084,21 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
 
     def conv(g, weight_attr, layer):
         return layers.short_conv_decode(
-            g, tails, pos, btab, layer, block_size, cfg.conv_kernel,
-            param_attr=weight_attr)
+            g, pools[CONV_CACHE], pos, btab, layer, block_size,
+            cfg.conv_kernel, param_attr=weight_attr)
 
     def ssm(u, z, prefix, layer):
         return layers.ssm_decode(
-            u, z, states, stails, srow, layer, prefix, cfg.ssm_state,
-            cfg.ssm_conv, cfg.ssm_dt_rank, epsilon=cfg.rms_eps)
+            u, z, pools[SSM_STATE], pools[SSM_TAIL], feeds['row'], layer,
+            prefix, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank,
+            epsilon=cfg.rms_eps)
 
     def cache_write(k, v, layer, kind):
         # a window layer writes into its slot's ring: the table's column
         # is the logical block modulo the table's width
-        pools, table, ring = ((wkc, wvc), wtab, {'ring': True}) \
+        caches, table, ring = window + ({'ring': True},) \
             if kind == 'window' else ((kc, vc), btab, {})
-        for cache, new in zip(pools, (k, v)):
+        for cache, new in zip(caches, (k, v)):
             if cache is None:       # latent attention: no V pool
                 continue
             block.append_op(
@@ -1089,14 +1113,13 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
         if cfg.attention == 'mla':
             return _mla_attend(cfg, layers.mla_decode_attention, q, kc,
                                pos, btab, layer)
-        pools, table, bound = ((wkc, wvc), wtab,
-                               {'window': cfg.sliding_window}) \
+        caches, table, bound = window + ({'window': cfg.sliding_window},) \
             if kind == 'window' else ((kc, vc), btab, {})
         ctx = block.create_var(name=name + '.kv_ctx',
                                shape=(-1, h, dh), dtype='float32')
         block.append_op(
             type='kv_decode_attention_paged',
-            inputs={'Q': [q], 'KCache': [pools[0]], 'VCache': [pools[1]],
+            inputs={'Q': [q], 'KCache': [caches[0]], 'VCache': [caches[1]],
                     'Positions': [pos], 'BlockTables': [table]},
             outputs={'Out': [ctx]},
             attrs=dict(bound, layer=layer, scale=dh ** -0.5,
@@ -1151,8 +1174,7 @@ def build_lm_drafter(cfg, slots, max_len, spec_k, num_blocks, block_size):
     token, so draft sampling would only lower the accept rate."""
     _name_program('lm_drafter')
     _require_classic_block(cfg, 'build_lm_drafter')
-    d, h = cfg.d_model, cfg.n_head
-    dh = d // h
+    d, h, dh = cfg.d_model, cfg.n_head, cfg.head_dim
     mb = max_len // block_size
     tokens = layers.data(name='gen_tokens', shape=[1], dtype='int64')
     pos = layers.data(name='gen_pos', shape=[1], dtype='int64')
@@ -1160,8 +1182,8 @@ def build_lm_drafter(cfg, slots, max_len, spec_k, num_blocks, block_size):
     vmask = layers.data(name='gen_vmask', shape=[spec_k + 1],
                         dtype='int64')
     block = tokens.block
-    kc, vc = _declare_paged_kv_caches(block, cfg, num_blocks,
-                                      block_size)[:2]
+    pools = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
+    kc, vc = pools[KV_CACHE_K], pools[KV_CACHE_V]
     pe = layers.assign(position_encoding_table(max_len, d))
 
     drafts = []
@@ -1252,8 +1274,7 @@ def build_lm_verify(cfg, slots, width, max_len, num_blocks, block_size):
     ([slots * width] int64, row-major), 'k_cache', 'v_cache'}."""
     _name_program('lm_verify')
     _require_classic_block(cfg, 'build_lm_verify')
-    d, h = cfg.d_model, cfg.n_head
-    dh = d // h
+    d, h, dh = cfg.d_model, cfg.n_head, cfg.head_dim
     W = int(width)
     if W < 2:
         raise ValueError("verify width must be >= 2 (spec_k >= 1), "
@@ -1264,8 +1285,8 @@ def build_lm_verify(cfg, slots, width, max_len, num_blocks, block_size):
     btab = layers.data(name='gen_btab', shape=[mb], dtype='int64')
     vmask = layers.data(name='gen_vmask', shape=[W], dtype='int64')
     block = tokens.block
-    kc, vc = _declare_paged_kv_caches(block, cfg, num_blocks,
-                                      block_size)[:2]
+    pools = _declare_paged_kv_caches(block, cfg, num_blocks, block_size)
+    kc, vc = pools[KV_CACHE_K], pools[KV_CACHE_V]
 
     flat = layers.reshape(tokens, shape=[-1])                # [S*W]
     x = layers.embedding(
@@ -1355,10 +1376,12 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     length = layers.data(name='gen_len', shape=[1], dtype='int64')
     sample_vars = _sampling_inputs()
     block = prompt.block
-    wtab = _window_table(cfg, block_size)
-    srow = _state_rows(cfg)
-    kc, vc, tails, wkc, wvc, states, stails = _declare_paged_kv_caches(
-        block, cfg, num_blocks, block_size, slots)
+    feeds = _slot_feeds(cfg, block_size)
+    wtab = feeds.get('ring')
+    pools = _declare_paged_kv_caches(block, cfg, num_blocks, block_size,
+                                     slots)
+    kc, vc = pools[KV_CACHE_K], pools.get(KV_CACHE_V)
+    wkc, wvc = pools.get(WINDOW_CACHE_K), pools.get(WINDOW_CACHE_V)
 
     x = layers.embedding(
         prompt, size=[cfg.vocab_size, d], dtype='float32',
@@ -1378,27 +1401,25 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
                     'BlockTable': [table], 'Length': [length]},
             outputs={'Out': [cache]},
             attrs=dict(bound, layer=int(layer), block_size=int(block_size)))
-        return cache
 
     def conv(g, weight_attr, layer):
         return layers.short_conv_prefill(
-            g, tails, pos, btab, length, layer, block_size, cfg.conv_kernel,
-            param_attr=weight_attr)
+            g, pools[CONV_CACHE], pos, btab, length, layer, block_size,
+            cfg.conv_kernel, param_attr=weight_attr)
 
     def ssm(u, z, prefix, layer):
         return layers.ssm_prefill(
-            u, z, states, stails, srow, pos, length, layer, prefix,
-            cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank,
-            epsilon=cfg.rms_eps)
+            u, z, pools[SSM_STATE], pools[SSM_TAIL], feeds['row'], pos,
+            length, layer, prefix, cfg.ssm_state, cfg.ssm_conv,
+            cfg.ssm_dt_rank, epsilon=cfg.rms_eps)
 
     def attention(ln1, p, nth, layer):
         """An attention layer's mixer: q, k, v, the cache writes, the
         suffix's attention against the slot's pages, the projection."""
-        nonlocal kc, vc, wkc, wvc
         q, k, v = _qkv(cfg, ln1, p, pos, T, layer=layer)     # [1,H,T,dh]
         window = cfg.layer_types[layer] == 'window'
         if not window:
-            kc = cache_write(kc, k, nth)
+            cache_write(kc, k, nth)
         if cfg.attention == 'mla':
             ctx = _mla_attend(cfg, layers.mla_prefix_attention, q, kc, pos,
                               btab, nth)                     # [1,T,H,v]
@@ -1413,8 +1434,7 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
                        'BlockTable': [wtab], 'Length': [length]}
                 bound = {'window': cfg.sliding_window}
             else:
-                vc = cache_write(vc, v, nth)
-                ins['VCache'] = [vc]
+                cache_write(vc, v, nth)
             ctx = block.create_var(name=p + '.prefix_attn_out',
                                    shape=(-1, h, T, dh), dtype='float32')
             block.append_op(
@@ -1424,8 +1444,8 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
                 attrs=dict(bound, layer=nth, scale=dh ** -0.5,
                            block_size=int(block_size)))
             if window:
-                wkc = cache_write(wkc, k, nth, wtab, **bound)
-                wvc = cache_write(wvc, v, nth, wtab, **bound)
+                cache_write(wkc, k, nth, wtab, **bound)
+                cache_write(wvc, v, nth, wtab, **bound)
             ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
         ctx = layers.reshape(ctx, shape=[0, T, cfg.attn_width])
         return layers.fc(ctx, size=d, num_flatten_dims=2,

@@ -341,8 +341,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, interpret,
 # --------------------------------------------------------------------------
 
 # default tile sizes; the round-3 sweep measured 512x512 optimal at
-# d_head 64 (256/128 tiles 1.5-2.5x slower). Env-overridable so perf
-# sweeps (tools/mfuexp.py) can re-measure without editing source.
+# d_head 64 (256/128 tiles 1.5-2.5x slower). Env-overridable so a sweep
+# can re-measure without editing source; nothing in the repo sets either.
 import os as _os
 _DEF_BQ = int(_os.environ.get('PADDLE_FLASH_BQ', '512'))
 _DEF_BK = int(_os.environ.get('PADDLE_FLASH_BK', '512'))

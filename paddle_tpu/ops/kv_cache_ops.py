@@ -126,8 +126,6 @@ from jax import lax
 from ..core.registry import register_op
 
 _NEG_INF = -1e30
-# kv_prefix_attention: the float32 scores that may stand at once
-_SCORES_BYTES = 512 << 20
 
 
 # ---------------------------------------------------------------------------
@@ -423,44 +421,29 @@ def _prefix_attention_scores(q, k, v, at, pos, scale, window):
     """`kv_prefix_attention` as plain XLA (the `off` / `xla` tier, and the
     tests' reference of the kernel): q ``[H, T, dh]`` at positions ``pos
     [T]`` against k, v ``[Hkv, M, dh]``, key ``i`` at position ``at[i]``.
-    The scores of every head stand whole in HBM, ``[H, t, M]`` float32
-    twice over (K-EXAONE's 64 heads x 512 rows x 5120 keys: 1.34 GB): past
-    `_SCORES_BYTES` the queries are taken in halves, one after another."""
+    The scores of every head stand whole in HBM, ``[H, T, M]`` float32
+    twice over (K-EXAONE's 64 heads x 512 rows x 5120 keys: 1.34 GB, were
+    such a call to come here: on the chip it is the kernel's, whose
+    `shapes_ok` takes every call of 64 MB or more that tiles)."""
     H, T, dh = q.shape
     Hkv = k.shape[0]
-
-    def attend(rows):
-        """The queries `rows` = (q [H, t, dh], their positions [t])."""
-        qb, pb = rows
-        t = pb.shape[0]
-        m = at[None, :] <= pb[:, None]                     # [t, M]
-        if window is not None:
-            m &= (at[None, :] >= 0) & (at[None, :] > pb[:, None] - window)
-        if Hkv != H:
-            # grouped queries: a K/V head's H // Hkv query heads are rows
-            # of ONE matmul against it; the gathered keys are not repeated
-            qg = qb.reshape(Hkv, (H // Hkv) * t, dh)
-            mg = jnp.tile(m, (H // Hkv, 1))[None]
-        else:
-            qg, mg = qb, m[None]
-        scores = jnp.einsum('htd,hmd->htm', qg, k,
-                            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(mg, scores, _NEG_INF)
-        w = jax.nn.softmax(scores, axis=-1)
-        w = jnp.where(mg, w, 0.0)
-        return jnp.einsum('htm,hmd->htd', w.astype(v.dtype),
-                          v).reshape(H, t, dh)
-
-    n = 1
-    while H * (T // n) * at.shape[0] * 4 > _SCORES_BYTES \
-            and T % (2 * n) == 0:
-        n *= 2
-    if n == 1:
-        return attend((q, pos))
-    out = lax.map(attend, (
-        jnp.swapaxes(q.reshape(H, n, T // n, dh), 0, 1),
-        pos.reshape(n, T // n)))
-    return jnp.swapaxes(out, 0, 1).reshape(H, T, dh)
+    m = at[None, :] <= pos[:, None]                        # [T, M]
+    if window is not None:
+        m &= (at[None, :] >= 0) & (at[None, :] > pos[:, None] - window)
+    if Hkv != H:
+        # grouped queries: a K/V head's H // Hkv query heads are rows
+        # of ONE matmul against it; the gathered keys are not repeated
+        qg = q.reshape(Hkv, (H // Hkv) * T, dh)
+        mg = jnp.tile(m, (H // Hkv, 1))[None]
+    else:
+        qg, mg = q, m[None]
+    scores = jnp.einsum('htd,hmd->htm', qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(mg, scores, _NEG_INF)
+    w = jax.nn.softmax(scores, axis=-1)
+    w = jnp.where(mg, w, 0.0)
+    return jnp.einsum('htm,hmd->htd', w.astype(v.dtype),
+                      v).reshape(H, T, dh)
 
 
 @register_op('kv_prefix_attention', share_lod=False)

@@ -10,12 +10,11 @@ This module is the decode-native path:
   ``[num_blocks, layers, block_size, heads * head_dim]`` buffers
   (models/transformer.py ``KV_CACHE_K``/``KV_CACHE_V``; the attention
   layers and the K/V heads only, where a model says so, and beside them
-  the convolution layers' tails under the same block ids:
-  ``kv_cache_shapes``) — a pool of fixed-size blocks — lives in the
-  engine's scope like any other executor
-  state: the decode step reads AND writes them, so the PR 1 donation path
-  aliases each step's update in place — the cache never doubles in HBM
-  and never crosses the host.
+  the convolution layers' tails under the same block ids: ``cache_pools``)
+  — a pool of fixed-size blocks — lives in the engine's scope like any
+  other executor state: the decode step reads AND writes them, so the PR 1
+  donation path aliases each step's update in place — the cache never
+  doubles in HBM and never crosses the host.
 - **Two compiled signatures, fixed forever.** A per-prompt-bucket
   ``prefill`` (prompt lengths pad onto ``prompt_buckets``, the
   reader/bucketing ladder idiom) and ONE single-token ``decode step``
@@ -57,34 +56,21 @@ exactly); temperature 0 stays the bitwise greedy default, and the
 program count is unchanged — ``len(prompt_buckets) + 1`` fixed
 signatures, zero recompiles after warmup under any mixed traffic.
 
-WINDOW LAYERS (PR 41, ``LMConfig.layer_types`` ``'window'``) keep their K
-and V in pools of their own that the allocator does not manage: the
-engine sizes them from its slots — ``slots x window_ring`` blocks and the
-trash block — and every slot OWNS its ring of them while resident
-(serving/kv_blocks.py `WindowRings`; a second table a slot, fed as
-'gen_wtab' beside 'gen_btab'). Logical block ``b`` lies in column ``b %
-ring``: a page behind the window is written over, never handed to an
-allocator, and the window layers' cache does not grow with the context.
-``stats()['blocks']`` stays the GLOBAL layers' pool and gains
-``['window']``; prefix sharing and speculation are refused for such a
-model at construction.
-
-STATE-SPACE LAYERS (PR 43, ``layer_types`` ``'ssm'``) keep the
-recurrence's state and the convolution's tail A ROW A SLOT, in two pools
-the engine sizes from its slots (``slots + 1`` rows: row 0 is the trash
-row; models/transformer.py `SSM_STATE`, `SSM_TAIL`). Slot ``i`` owns row
-``i + 1`` from its admission to its release and both programs are fed it
-as 'gen_srow' beside the block table (`_tables_feed`). A state is a fixed
-10 MB a slot in Jamba2-3B whatever the context, 154 times a block's K/V:
-it cannot be an entry a block, as LFM2's tails are. A prefill that starts
-at position 0 starts from zeros and never reads the row (the last
-tenant's state, or what a stale step in flight writes there, is written
-over: `_release`); a later chunk resumes from the row; a decode step
-feeds a row 0 for every slot it leaves out — idle, held back, or between
-two chunks of its prompt — so that slot's state stands still.
-``stats()['state']`` is ``{capacity, in_use}``, rows; prefix sharing (a
-shared block has no state to resume from) and speculation (a rejected
-draft cannot be unwound from a recurrence) are refused at construction.
+LAYER KINDS. What the engine knows of a model's layers is ONE table,
+models/transformer.py `cache_pools`, read once at construction: each pool's
+name and shape, what indexes its leading dimension, whether a rejected draft
+rewinds from it (``speculative`` needs every pool to), whether a shared
+block's entry of it copies, and the series its reads are booked under. A
+kind of index other than the allocator's block ids gets a bookkeeper
+(serving/kv_blocks.py), its feed beside 'gen_btab' (`_tables_feed`) and its
+entry in ``stats()``; such pools are sized from the slots, and
+``prefix_sharing`` is refused over them. WINDOW LAYERS (PR 41,
+``layer_types`` ``'window'``) see the last ``sliding_window`` keys and keep
+K and V in a RING of blocks a slot owns while resident: a page behind the
+window is written over, never handed to an allocator. STATE-SPACE LAYERS
+(PR 43, ``'ssm'``) keep the recurrence's state and the convolution's tail A
+ROW A SLOT, a fixed size whatever the context (a slot that sits a step out
+is fed row 0, the trash row). docs/serving.md has each kind's contract.
 
 SPECULATIVE DECODING (PR 13, ``GenerateConfig(speculative=True)``)
 breaks the one-token-per-dispatch decode ceiling:
@@ -162,14 +148,12 @@ from .. import trace as trace_mod
 from .. import unique_name
 from ..executor import Executor, Scope, scope_guard
 from ..framework import Program, TPUPlace, program_guard
-from ..models.transformer import (LMConfig, SSM_STATE, SSM_TAIL,
-                                  WINDOW_CACHE_K, WINDOW_CACHE_V,
-                                  build_lm_decode_step,
-                                  build_lm_prefill_paged, kv_cache_names,
-                                  kv_cache_shapes, window_ring)
+from ..models.transformer import (LMConfig, build_lm_decode_step,
+                                  build_lm_prefill_paged, cache_pools,
+                                  kv_cache_names, kv_cache_shapes)
 from ..reader.bucketing import bucketize
-from .kv_blocks import (BlockAllocator, PrefixCache, WindowRings,
-                        chain_hashes)
+from .kv_blocks import (BlockAllocator, PrefixCache, chain_hashes,
+                        slot_bookkeeper)
 from .batcher import (DeadlineExceededError, EngineStoppedError,
                       LoadShedError, Request, RequestQueue,
                       resolve_metrics_port, start_metrics_server)
@@ -639,44 +623,41 @@ class GenerateEngine(object):
         self._prefix = PrefixCache(self._alloc) \
             if c.prefix_sharing else None
         self._max_blocks = c.max_len // c.block_size
-        self._cow_jit = None
-        self._dcopy_jit = None
-        self._stage_jit = None
-        self._put_jit = None
+        self._cow_jit = self._dcopy_jit = None
+        self._stage_jit = self._put_jit = None
         # the prefills' first tokens as they are on the device, a row a
         # slot, and a step's output to stand for "no step in flight"
         # (warmup makes both)
-        self._first_buf = None
-        self._no_prev = None
-        if c.speculative and c.model.n_conv_layers:
-            raise ValueError(
-                "speculative=True with LMConfig.layer_types=%r: a rejected "
-                "draft rewinds positions, and a convolution layer's tail "
-                "in the block pool cannot be rewound (it holds the last "
-                "rows written, not every row)" % (c.model.layer_types,))
-        for option in ('speculative', 'prefix_sharing'):
-            if getattr(c, option) and c.model.n_window_layers:
-                raise ValueError(
-                    "%s=True with LMConfig.layer_types=%r: a window "
-                    "layer's blocks are a ring that its slot writes over "
-                    "— a rejected draft cannot be unwound from it, and a "
-                    "shared block's window rows are gone once its first "
-                    "tenant has moved on" % (option, c.model.layer_types))
-            if getattr(c, option) and c.model.n_ssm_layers:
-                raise ValueError(
-                    "%s=True with LMConfig.layer_types=%r: a state-space "
-                    "layer's state is a row a slot, the recurrence up to "
-                    "the slot's last position — a shared block has no "
-                    "state to resume from, and a rejected draft cannot be "
-                    "unwound from it" % (option, c.model.layer_types))
-        # the window layers' pool: a ring of blocks a slot, sized from
-        # the slots alone (None for a model without such layers)
-        self._rings = WindowRings(
-            c.slots, window_ring(c.model, c.block_size), c.block_size) \
-            if c.model.n_window_layers else None
-        # the state-space layers, whose pools are a row a slot (0: the
-        # model has none, and no program takes 'gen_srow')
-        self._n_ssm = c.model.n_ssm_layers
+        self._first_buf = self._no_prev = None
+        # all the engine knows of the model's layer kinds: its pools,
+        # read here once -- no pass of the loop looks at the model again
+        pools = self._pools = cache_pools(c.model, c.num_blocks,
+                                          c.block_size, c.slots)
+        for option, fits in (('speculative', lambda p: p.rewinds),
+                             ('prefix_sharing', lambda p: p.index == 'block')):
+            unfit = [p for p in pools if not fits(p)]
+            if getattr(c, option) and unfit:
+                raise ValueError("%s=True with LMConfig.layer_types=%r: "
+                                 "pool %r: %s" % (option, c.model.layer_types,
+                                                  unfit[0].name, unfit[0].why))
+        self._free = list(range(c.slots))[::-1]
+        # one bookkeeper a kind of index that is not the allocator's (the
+        # slots' rings, the slots' rows; none for most models)
+        kinds = {p.index: p.shape[0] for p in pools if p.index != 'block'}
+        self._books = tuple(
+            slot_bookkeeper(index, entries, c.slots, c.block_size, self._free)
+            for index, entries in kinds.items())
+        # a wholly shared prompt's last block can be copied and resumed
+        self._cow_ok = all(p.copies for p in pools if p.index == 'block')
+
+        def booked(moment):
+            return tuple((p.books[moment], p.shape[1]) for p in pools
+                         if moment in p.books)
+        # (what is booked, of how many layers): at a decode step (series,
+        # rows a slot at most) for what it reads; at a prefill dispatch the
+        # series of the rows it walks and of what it resumes from
+        self._step_reads, self._prefill_rows, self._resumes = \
+            booked('step'), booked('prefill'), booked('resume')
         if c.speculative:
             self._draft_cfg = c.draft_model or c.model
             # +1 over the all-slots-at-max_len footprint (the trash
@@ -696,15 +677,12 @@ class GenerateEngine(object):
             self._draft_copies_target = draft_scope is None and \
                 c.draft_model is None
         else:
-            self._draft_cfg = None
-            self._draft_alloc = None
-            self._draft_scope = None
+            self._draft_cfg = self._draft_alloc = self._draft_scope = None
             self._draft_copies_target = False
         self._build_programs()
         self._init_state()
         self.queue = RequestQueue(self.config.queue_cap)
         self._slots = [None] * self.config.slots
-        self._free = list(range(self.config.slots))[::-1]
         self._pending_admit = None   # popped but awaiting free blocks
         self._prefill_bound = {}
         self._draft_prefill_bound = {}
@@ -733,21 +711,13 @@ class GenerateEngine(object):
         # the chunked admission under way (`_admit_run`), if any: its slot
         # is neither free nor resident, and no other admission starts
         self._chunking = None
-        self._first_carried = 0
-        self._decode_steps = 0
-        self._sampled_steps = 0
-        self._overlapped_steps = 0
-        self._discarded_rows = 0
-        self._decode_tokens = 0
-        self._occ_sum = 0.0
-        self._occ_peak = 0.0
-        self._active_peak = 0
-        self._blocks_peak = 0
-        self._spec_rounds = 0
-        self._spec_proposed = 0
-        self._spec_accepted = 0
-        self._spec_fallbacks = 0
-        self._spec_stale_rounds = 0
+        # stats()' tallies
+        self._first_carried = self._decode_steps = self._sampled_steps = 0
+        self._overlapped_steps = self._discarded_rows = 0
+        self._decode_tokens = self._active_peak = self._blocks_peak = 0
+        self._occ_sum = self._occ_peak = 0.0
+        self._spec_rounds = self._spec_proposed = self._spec_accepted = 0
+        self._spec_fallbacks = self._spec_stale_rounds = 0
         self._goodput_fps = None
         # resolve + name the goodput fingerprint set NOW: a periodic
         # snapshot exporting counters before the first stats() call
@@ -847,7 +817,6 @@ class GenerateEngine(object):
         return flat[:n_tokens]
 
     def _init_state(self):
-        import jax.numpy as jnp
         cfg, c = self.config.model, self.config
         with scope_guard(self.scope):
             if not self.scope.has('tok_emb.w'):
@@ -869,21 +838,17 @@ class GenerateEngine(object):
         self._ensure_cache()
 
     def _ensure_cache(self):
-        """Make the scope's pools (`kv_cache_names`: K, V, the
-        convolution tails, the window layers' K and V) match THIS
-        engine's geometry. A provided
-        scope may carry another engine's cache
-        under the same names with a different pool shape; the cache
-        holds no trained state, so re-zeroing is always safe, while
-        reusing a mismatched buffer would feed the compiled programs
-        garbage shapes. Re-checked at warmup()/start()/generate_once()
-        so engines sharing one trained scope SEQUENTIALLY each reclaim
-        it (concurrent use of one scope by two live engines stays
-        unsupported)."""
+        """Make the scope's pools (`cache_pools`) match THIS engine's
+        geometry. A provided scope may carry another engine's cache under
+        the same names with a different pool shape; the cache holds no
+        trained state, so re-zeroing is always safe, while reusing a
+        mismatched buffer would feed the compiled programs garbage shapes.
+        Re-checked at warmup()/start()/generate_once() so engines sharing
+        one trained scope SEQUENTIALLY each reclaim it (concurrent use of
+        one scope by two live engines stays unsupported)."""
         import jax.numpy as jnp
         c = self.config
-        pools = [(self.scope, kv_cache_shapes(c.model, c.num_blocks,
-                                              c.block_size, c.slots))]
+        pools = [(self.scope, {p.name: p.shape for p in self._pools})]
         if c.speculative:
             pools.append((self._draft_scope, kv_cache_shapes(
                 self._draft_cfg, self._draft_nb, c.block_size)))
@@ -912,11 +877,11 @@ class GenerateEngine(object):
             self._cow_jit = block_copy_fn(jax.default_backend())
         s = np.asarray(src, 'int32')
         d = np.asarray(dst, 'int32')
-        for name in kv_cache_names(self.config.model):
-            if name in (WINDOW_CACHE_K, WINDOW_CACHE_V, SSM_STATE, SSM_TAIL):
+        for pool in self._pools:
+            if pool.index != 'block':
                 continue    # not the allocator's: no block of it is shared
-            self.scope.set(name, self._cow_jit(
-                self.executor._state_value(self.scope, name,
+            self.scope.set(pool.name, self._cow_jit(
+                self.executor._state_value(self.scope, pool.name,
                                            self._step_prog, cache=False),
                 s, d))
 
@@ -936,7 +901,7 @@ class GenerateEngine(object):
         s_ids = np.zeros((self._max_blocks,), 'int32')
         d_ids[:len(dblocks)] = dblocks
         s_ids[:len(blocks)] = blocks
-        for name in kv_cache_names(self.config.model):
+        for name in (pool.name for pool in self._pools):
             dst = self.executor._state_value(
                 self._draft_scope, name, self._drafter_prog, cache=False)
             src = self.executor._state_value(
@@ -1030,37 +995,27 @@ class GenerateEngine(object):
         return table
 
     def _tables_feed(self, btab, slots=()):
-        """A program's table feeds: 'gen_btab', and for a model with
-        window layers 'gen_wtab', the rings of `slots` ((row, slot)
-        pairs; the other rows all zero, the trash block, as an idle
-        row's are); with state-space layers 'gen_srow', slot + 1 for the
-        same pairs and 0, the trash row, for the others."""
+        """A program's table feeds: 'gen_btab', and beside it what each
+        bookkeeper holds for `slots` ((row, slot) pairs: a slot's ring, its
+        row; the other rows all zero, the trash block or row, as an idle's)."""
         feed = {'gen_btab': btab}
-        if self._rings is not None:
-            wtab = np.zeros((len(btab), self._rings.ring), 'int64')
+        for book in self._books:
+            slots = tuple(slots)
+            table = np.zeros((len(btab), book.width), 'int64')
             for row, slot in slots:
-                wtab[row] = self._rings.table(slot)
-            feed['gen_wtab'] = wtab
-        if self._n_ssm:
-            srow = np.zeros((len(btab), 1), 'int64')
-            for row, slot in slots:
-                srow[row] = slot + 1
-            feed['gen_srow'] = srow
+                table[row] = book.table(slot)
+            feed[book.feed] = table
         return feed
 
-    def _ring_advance(self, slot, length):
-        """Book that `slot`'s tenant has reached `length` positions in
-        the window layers' pool (nothing without such layers)."""
-        if self._rings is not None:
-            n = self._rings.advance(slot, length)
+    def _slot_books(self, slot, length=None):
+        """Book with every bookkeeper that `slot`'s tenant has reached
+        `length` positions, or (None) that it is gone; what a ring hands
+        on meanwhile goes into the bookkeeper's series."""
+        for book in self._books:
+            n = book.release(slot) if length is None \
+                else book.advance(slot, length)
             if n:
-                monitor.inc('kv_window_blocks_recycled_total', n)
-
-    def _ring_release(self, slot):
-        if self._rings is not None:
-            n = self._rings.release(slot)
-            if n:
-                monitor.inc('kv_window_blocks_recycled_total', n)
+                monitor.inc(book.series, n)
 
     # ------------------------------------------------------------------
     # warmup
@@ -1435,7 +1390,7 @@ class GenerateEngine(object):
                 btab[0] = table
                 feed = {'gen_tokens': toks, 'gen_pos': posf}
                 feed.update(self._tables_feed(btab, [(0, 0)]))
-                self._ring_advance(0, pos + 1)
+                self._slot_books(0, pos + 1)
                 sf = self._sample_feed(S)
                 sf['gen_temp'][0], sf['gen_topk'][0] = sample[0], sample[1]
                 sf['gen_topp'][0], sf['gen_u'][0] = sample[2], draw_u()
@@ -1447,7 +1402,7 @@ class GenerateEngine(object):
             return tokens
         finally:
             self._deref_blocks(blocks)
-            self._ring_release(0)
+            self._slot_books(0)
 
     # ------------------------------------------------------------------
     # decode loop
@@ -1608,10 +1563,9 @@ class GenerateEngine(object):
         fresh blocks for the rest, and a copy-on-write duplicate of the
         final shared block when the ENTIRE prompt landed on shared
         blocks (its last position must be recomputed, a divergent
-        write; a model with convolution layers recomputes that whole
-        block into a fresh one instead). Returns None when the pool
-        cannot satisfy the request
-        right now (nothing referenced, nothing allocated)."""
+        write; a model with a pool that does not copy recomputes that
+        whole block into a fresh one instead). Returns None when the pool
+        cannot satisfy the request now (nothing referenced or allocated)."""
         c = self.config
         bs = c.block_size
         L = req.prompt.size
@@ -1627,7 +1581,7 @@ class GenerateEngine(object):
         # into a fresh one instead: a copied entry of the tails' pool
         # holds g of the block's last rows, and recomputing the last
         # position alone would need the rows before them
-        cow = whole and not c.model.n_conv_layers
+        cow = whole and self._cow_ok
         ctx_len = min(n_keep * bs + (bs if cow else 0), L - 1)
         # pin every matched block (incl. the COW source) BEFORE touching
         # the allocator: under pool pressure _alloc_blocks evicts
@@ -1904,47 +1858,40 @@ class GenerateEngine(object):
         suffix — same compiled signatures, any prompt length. Only
         the FINAL chunk's first-token output is the model's answer."""
         c = self.config
-        wide = c.prompt_buckets[-1]
-        suffix = prompt[off:]
-        # the slot's own ring in the window layers' pool goes with it (the
-        # draft's prefills are of a model that has none)
+        # the dispatch's REAL rows: a chunk of the widest bucket while the
+        # suffix is wider, then what is left of it
+        rows = prompt[off:off + c.prompt_buckets[-1]]
+        last = off + rows.size == prompt.size
+        # the slot's own ring or row goes with it (the draft's prefills
+        # are of a model that has none)
         tables = self._tables_feed(table[None], [(0, slot)])
-        if c.model.n_conv_layers and off > 0:
+        for series, n_layers in self._prefill_rows:
+            # the rows the layers' scans walk (a bucket's pad rows are not
+            # among them)
+            monitor.inc(series, rows.size * n_layers)
+        if off > 0:
             # every dispatch that starts past position 0 — a hit's suffix,
-            # a later chunk — resumes from a tail the pool holds
-            monitor.inc('conv_tail_resumes_total')
-        if self._n_ssm:
-            # the rows the state-space layers' scans walk (a bucket's pad
-            # rows are not among them), and the dispatches that start
-            # from the slot's row instead of from zeros
-            monitor.inc('ssm_prefill_rows_total',
-                        min(suffix.size, wide) * self._n_ssm)
-            if off > 0:
-                monitor.inc('ssm_state_resumes_total')
-        if suffix.size > wide:
-            pos = np.clip(off + np.arange(wide), 0, c.max_len - 1)
-            feed = {'gen_prompt': suffix[:wide][None],
-                    'gen_pos': pos[None].astype('int64'),
-                    'gen_len': np.array([[wide]], 'int64')}
-            feed.update(tables)
-            feed.update(self._sample_feed(1))
-            # K/V deposited; the token output is never read
-            return self._prefill_call(bound[wide], feed), off + wide
-        b = bucketize(suffix.size, c.prompt_buckets)
+            # a later chunk — resumes from a tail the pool holds, from the
+            # slot's row instead of from zeros
+            for series, _layers in self._resumes:
+                monitor.inc(series)
+        b = bucketize(rows.size, c.prompt_buckets)
         padded = np.full((1, b), c.pad_id, 'int64')
-        padded[0, :suffix.size] = suffix
+        padded[0, :rows.size] = rows
         pos = np.clip(off + np.arange(b), 0, c.max_len - 1)
         feed = {'gen_prompt': padded,
                 'gen_pos': pos[None].astype('int64'),
-                'gen_len': np.array([[suffix.size]], 'int64')}
+                'gen_len': np.array([[rows.size]], 'int64')}
         feed.update(tables)
-        feed.update(self._sample_feed(1, *sample))
+        feed.update(self._sample_feed(1, *(sample if last else ())))
         out = self._prefill_call(bound[b], feed)
-        self._ring_advance(slot, prompt.size)
-        # the copy to the host starts behind the prefill: by the pick-up
-        # it is latency behind a busy device
-        out.copy_to_host_async()
-        return out, prompt.size
+        if last:
+            self._slot_books(slot, prompt.size)
+            # the copy to the host starts behind the prefill: by the
+            # pick-up it is latency behind a busy device. (An earlier
+            # chunk's K/V is deposited; its token output is never read.)
+            out.copy_to_host_async()
+        return out, off + rows.size
 
     def _prefill_call(self, bound, feed):
         """One prefill dispatch, phase `prefill.dispatch` inside
@@ -2323,8 +2270,7 @@ class GenerateEngine(object):
             sample = self._sample_feed(S)
             btab = np.zeros((S, self._max_blocks), 'int64')
             active = []
-            windowed = self._rings is not None
-            live_pages = live_tokens = window_tokens = carried = 0
+            live_pages = live_tokens = carried = 0
             for i, st in enumerate(self._slots):
                 if st is None or i in held:
                     continue
@@ -2347,9 +2293,6 @@ class GenerateEngine(object):
                 btab[i] = st.table
                 live_pages += at // c.block_size + 1
                 live_tokens += at + 1
-                if windowed:
-                    window_tokens += min(at + 1, c.model.sliding_window)
-                    self._ring_advance(i, at + 1)
                 active.append((i, st))
             # the admissions since the last dispatch: this step's to pick
             # up, whether or not their rows are in it
@@ -2370,23 +2313,20 @@ class GenerateEngine(object):
             monitor.inc('kv_decode_pages_live_total', live_pages)
             monitor.inc('kv_decode_pages_table_total',
                         len(active) * self._max_blocks)
-            if c.model.attention == 'mla':
-                # the latent rows the step's attention has to read
-                monitor.inc('kv_latent_tokens_read_total',
-                            live_tokens * c.model.n_layer)
-            else:
-                # the per-head K and V rows: the attention layers' alone,
-                # those that see every key ...
-                monitor.inc('kv_tokens_read_total',
-                            live_tokens * c.model.n_attn_layers)
-            if windowed:
-                # ... and the window layers', a window's worth a slot
-                monitor.inc('kv_window_tokens_read_total',
-                            window_tokens * c.model.n_window_layers)
-            if self._n_ssm:
-                # the state rows the step reads, advances and writes back
-                monitor.inc('ssm_state_rows_updated_total',
-                            len(active) * self._n_ssm)
+            if self._books:     # pools a slot owns: their books advance
+                lengths = [int(pos[i, 0]) + 1 for i, _ in active]
+                for (i, _), n in zip(active, lengths):
+                    self._slot_books(i, n)
+            for (series, most), n_layers in self._step_reads:
+                # the rows the step has to read of a pool's layers: the
+                # latent rows, or the per-head K and V rows of the layers
+                # that see every key (`most` None: all up to the slot's
+                # position); of a pool a slot owns (so `lengths` is made),
+                # a window's worth at most, or the ONE state row the step
+                # reads, advances and writes back
+                monitor.inc(series, n_layers * (
+                    live_tokens if most is None
+                    else sum(min(n, most) for n in lengths)))
             feed = {'gen_pos': pos}
             feed.update(self._tables_feed(btab, ((i, i) for i, _ in active)))
         with _loop_phase('dispatch'):
@@ -2626,7 +2566,7 @@ class GenerateEngine(object):
             self._release_blocks(st)
             # its ring in the window layers' pool is the slot's: the next
             # tenant's prefill writes what it reads of it
-            self._ring_release(i)
+            self._slot_books(i)
             # So for its row in the state-space layers' pools: the step in
             # flight advances the departed tenant's state once more, in a
             # row nothing reads until the next tenant's first chunk, which
@@ -2653,8 +2593,8 @@ class GenerateEngine(object):
         pages the decode steps read; it is the GLOBAL layers' pool, and
         'window' in it the window layers' (`WindowRings`). 'state' (a
         model with state-space layers) is their pools' rows, one a slot
-        that is admitted. 'loop' is
-        where the loop thread's time went, by phase (_loop_sums)."""
+        that is admitted (both: the bookkeepers' `report`). 'loop' is where
+        the loop thread's time went, by phase (_loop_sums)."""
         steps = self._decode_steps
         out = {
             'slots': self.config.slots,
@@ -2685,17 +2625,8 @@ class GenerateEngine(object):
             if self._prefix is not None else 0,
             'decode_live_page_share': _live_page_share(),
         }
-        if self._rings is not None:
-            # the window layers' pool: what the resident slots have
-            # touched of their rings, at most slots x ring
-            out['blocks']['window'] = {'capacity': self._rings.capacity,
-                                       'ring': self._rings.ring,
-                                       'in_use': self._rings.in_use()}
-        if self._n_ssm:
-            # the state-space layers' pools, in rows: a slot owns its row
-            # from its admission (a chunked one too) to its release
-            out['state'] = {'capacity': self.config.slots,
-                            'in_use': self.config.slots - len(self._free)}
+        for book in self._books:
+            book.report(out)
         if self.config.speculative:
             prop = self._spec_proposed
             out['spec'] = {

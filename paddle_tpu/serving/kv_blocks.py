@@ -29,17 +29,16 @@ its own pool (sized ``slots * max_len / block_size`` + trash, so
 per-slot growth can never starve) with no prefix cache — draft K/V are
 model-specific throwaways.
 
-A model with WINDOW layers (``LMConfig.layer_types`` ``'window'``: a query
-sees the last ``sliding_window`` keys and no other) keeps those layers' K
-and V in pools of their own, and those are not the allocator's:
-``WindowRings`` gives every slot ``ring`` blocks of them for as long as it
-is resident, used as a ring — logical block ``b`` lies in column ``b %
-ring`` of the slot's window table — so a page behind the window is handed
-on by being written over, a slot's share never grows with its context and
-no step asks an allocator for anything. A shared block's window rows
-would be gone once its first tenant has moved on and a rejected draft
-cannot be unwound from a ring, so the engine refuses prefix sharing and
-speculation for such a model.
+Pools that are not the allocator's have a bookkeeper a kind of index
+(`slot_bookkeeper`; models/transformer.py `cache_pools` says which pool has
+which, and why prefix sharing and speculation are refused over them). A
+model with WINDOW layers (a query sees the last ``sliding_window`` keys
+only) keeps those layers' K and V in pools where ``WindowRings`` gives every
+slot ``ring`` blocks for as long as it is resident, used as a ring — logical
+block ``b`` lies in column ``b % ring`` of the slot's window table — so a
+page behind the window is handed on by being written over, a slot's share
+never grows with its context and no step asks an allocator for anything.
+STATE-SPACE layers keep a row a slot (``SlotRows``).
 
 Sharing is at FULL-BLOCK granularity. Because a block's K/V rows depend
 only on tokens at or before them (causal), a block fully covered by
@@ -55,7 +54,7 @@ import hashlib
 import threading
 
 __all__ = ['BlockAllocator', 'PrefixCache', 'QuotaBlockAllocator',
-           'WindowRings', 'chain_hashes']
+           'SlotRows', 'WindowRings', 'chain_hashes', 'slot_bookkeeper']
 
 
 def chain_hashes(tokens, block_size):
@@ -157,12 +156,15 @@ class WindowRings(object):
     accounts is how much of a ring its tenant has touched: `in_use`, at
     most ``slots * ring`` whatever the contexts."""
 
+    # its feed ([rows, width]: a slot's ring, column by column), and the
+    # series that what `advance` and `release` return is booked under
+    feed, series = 'gen_wtab', 'kv_window_blocks_recycled_total'
+
     def __init__(self, slots, ring, block_size):
-        self.ring = int(ring)
+        self.ring = self.width = int(ring)
         self.block_size = int(block_size)
         self._opened = [0] * int(slots)   # logical blocks a tenant opened
-        self._tables = [list(range(1 + i * self.ring,
-                                   1 + (i + 1) * self.ring))
+        self._tables = [list(range(1 + i * self.ring, 1 + (i + 1) * self.ring))
                         for i in range(int(slots))]
 
     @property
@@ -189,6 +191,51 @@ class WindowRings(object):
 
     def in_use(self):
         return sum(min(n, self.ring) for n in self._opened)
+
+    def report(self, stats):
+        """Into an engine's `stats()`: what the resident slots have
+        touched of their rings, at most slots x ring."""
+        stats['blocks']['window'] = {'capacity': self.capacity,
+                                     'ring': self.ring,
+                                     'in_use': self.in_use()}
+
+
+class SlotRows(object):
+    """The state-space layers' pools, a row a slot, behind `WindowRings`'
+    interface: slot `i` owns row ``i + 1`` (row 0 is the trash row, what a
+    slot that sits a step out is fed) from its admission, a chunked one
+    too, to its release -- so a row is in use while its slot is taken
+    (`free`: the engine's own list of free slots), nothing is advanced and
+    nothing handed back."""
+
+    feed, width, series = 'gen_srow', 1, None
+
+    def __init__(self, slots, free):
+        self.capacity, self._free = int(slots), free
+
+    def table(self, slot):
+        return slot + 1
+
+    def advance(self, slot, length=None):
+        return 0
+    release = advance
+
+    def in_use(self):
+        return self.capacity - len(self._free)
+
+    def report(self, stats):
+        stats['state'] = {'capacity': self.capacity, 'in_use': self.in_use()}
+
+
+def slot_bookkeeper(index, entries, slots, block_size, free):
+    """The bookkeeper of the pools whose leading dimension, `entries` long,
+    is indexed by `index` (models/transformer.py `cache_pools`): 'ring', a
+    slot's ring of blocks (and the trash block); 'row', a slot's row."""
+    if index == 'ring':
+        return WindowRings(slots, (entries - 1) // slots, block_size)
+    if index == 'row':
+        return SlotRows(slots, free)
+    raise ValueError("no bookkeeper for a pool indexed by %r" % (index,))
 
 
 class QuotaBlockAllocator(object):

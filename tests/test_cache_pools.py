@@ -1,0 +1,158 @@
+"""The table of a model's pools (ISSUE 46, models/transformer.py
+`cache_pools`): for the toy `LMConfig` of each of the benchmark's seven
+configurations, every pool's name, what indexes it, whether a rejected
+draft rewinds from it and whether a shared block's entry of it copies;
+`kv_cache_names` and `kv_cache_shapes` are views of it; and what an engine
+refuses, which bookkeepers it keeps and what it books follow from the table
+and from nothing else."""
+import json
+import os
+
+import pytest
+
+from paddle_tpu.models import transformer as T
+from paddle_tpu.models.transformer import LMConfig
+from paddle_tpu.serving import GenerateConfig, GenerateEngine
+from paddle_tpu.serving import kv_blocks
+
+from benchmark.models import jamba, joyai, kexaone, lfm2, lm, olmoe
+
+from test_olmoe_serving import LISTED
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def _toy(module, name):
+    with open(os.path.join(HERE, 'benchmark_tests', 'configs',
+                           'toy-%s.json' % name)) as f:
+        return module.lm_config(json.load(f), 64, False)
+
+
+# the toys the serving suites build, under their configurations' names
+CONFIGS = {
+    'fairseq-dense-355m': lambda: LMConfig(**LISTED['fairseq-dense']),
+    'fairseq-dense-1.3b': lambda: _toy(lm, 'lm'),
+    'olmoe-1b-7b-0125-l6': lambda: _toy(olmoe, 'olmoe'),
+    'joyai-llm-flash-ep4': lambda: _toy(joyai, 'joyai'),
+    'lfm2-8b-a1b-l8': lambda: _toy(lfm2, 'lfm2'),
+    'k-exaone-236b-a23b-ep16-l5': lambda: _toy(kexaone, 'kexaone'),
+    'ai21-jamba2-3b': lambda: _toy(jamba, 'jamba'),
+}
+KV = [(T.KV_CACHE_K, 'block', True, True), (T.KV_CACHE_V, 'block', True, True)]
+# (name, index, rewinds, copies) of every pool, in the order of the state
+POOLS = {
+    'fairseq-dense-355m': KV,
+    'fairseq-dense-1.3b': KV,
+    'olmoe-1b-7b-0125-l6': KV,
+    'joyai-llm-flash-ep4': KV[:1],      # latent rows: ONE pool
+    'lfm2-8b-a1b-l8': KV + [(T.CONV_CACHE, 'block', False, False)],
+    'k-exaone-236b-a23b-ep16-l5': KV + [
+        (T.WINDOW_CACHE_K, 'ring', False, False),
+        (T.WINDOW_CACHE_V, 'ring', False, False)],
+    'ai21-jamba2-3b': KV + [(T.SSM_STATE, 'row', False, False),
+                            (T.SSM_TAIL, 'row', False, False)],
+}
+# the series a decode step books its reads under: (series, rows a slot at
+# most, of which field of the config a layer count)
+STEP_READS = {
+    'joyai-llm-flash-ep4': [('kv_latent_tokens_read_total', None, 'n_layer')],
+    'k-exaone-236b-a23b-ep16-l5': [
+        ('kv_tokens_read_total', None, 'n_attn_layers'),
+        ('kv_window_tokens_read_total', 12, 'n_window_layers')],
+    'ai21-jamba2-3b': [('kv_tokens_read_total', None, 'n_attn_layers'),
+                       ('ssm_state_rows_updated_total', 1, 'n_ssm_layers')],
+}
+SLOTS, BLOCKS, BLOCK_SIZE = 4, 19, 8
+
+
+@pytest.mark.parametrize('config', sorted(CONFIGS))
+def test_the_table_holds_every_pool_and_the_views_are_its(config):
+    cfg = CONFIGS[config]()
+    pools = T.cache_pools(cfg, BLOCKS, BLOCK_SIZE, SLOTS)
+    assert [(p.name, p.index, p.rewinds, p.copies) for p in pools] == \
+        POOLS[config]
+    assert T.kv_cache_names(cfg) == tuple(p.name for p in pools)
+    assert T.kv_cache_shapes(cfg, BLOCKS, BLOCK_SIZE, SLOTS) == \
+        {p.name: p.shape for p in pools}
+    # what the allocator indexes is as long as its pool, what the slots
+    # size is as long as they make it, and layers come second everywhere
+    ring = T.window_ring(cfg, BLOCK_SIZE)
+    entries = {'block': BLOCKS, 'ring': SLOTS * ring + 1, 'row': SLOTS + 1}
+    for p in pools:
+        assert p.shape[0] == entries[p.index] and len(p.shape) == 4
+        assert p.shape[1] in (cfg.n_attn_layers, cfg.n_conv_layers,
+                              cfg.n_window_layers, cfg.n_ssm_layers)
+        # a pool an option is refused over says why; the others need not
+        assert (p.why is None) == (p.rewinds and p.index == 'block')
+        assert T.INDEX_FEEDS[p.index].startswith('gen_')
+    # without the slots a pool they size has no shape, and the view says so
+    sized = [p.index != 'block' for p in pools]
+    assert [p.shape is None for p in T.cache_pools(cfg, BLOCKS, BLOCK_SIZE)] \
+        == sized
+    if any(sized):
+        with pytest.raises(ValueError, match='sized by the slots'):
+            T.kv_cache_shapes(cfg, BLOCKS, BLOCK_SIZE)
+    want = STEP_READS.get(
+        config, [('kv_tokens_read_total', None, 'n_attn_layers')])
+    assert [p.books['step'] + (p.shape[1],) for p in pools
+            if 'step' in p.books] == \
+        [(series, most, getattr(cfg, field)) for series, most, field in want]
+
+
+def _engine(monkeypatch, cfg, **options):
+    """An engine of `cfg` with its programs built and no state made: the
+    weights and the pools' arrays are not what is asked here."""
+    monkeypatch.setattr(GenerateEngine, '_init_state', lambda self: None)
+    options.setdefault('prefix_sharing', False)
+    return GenerateEngine(GenerateConfig(
+        model=cfg, slots=SLOTS, max_len=64, prompt_buckets=[16],
+        eos_id=None, seed=0, block_size=BLOCK_SIZE, **options))
+
+
+@pytest.mark.parametrize('option', ['speculative', 'prefix_sharing'])
+@pytest.mark.parametrize('config', sorted(CONFIGS))
+def test_an_engine_refuses_what_the_table_says_and_keeps_its_books(
+        config, option, monkeypatch):
+    cfg = CONFIGS[config]()
+    fits = {'speculative': lambda p: p.rewinds,
+            'prefix_sharing': lambda p: p.index == 'block'}[option]
+    unfit = [p for p in T.cache_pools(cfg, BLOCKS, BLOCK_SIZE, SLOTS)
+             if not fits(p)]
+    assert bool(unfit) == ((option, config) in {
+        ('speculative', 'lfm2-8b-a1b-l8'),
+        ('speculative', 'k-exaone-236b-a23b-ep16-l5'),
+        ('speculative', 'ai21-jamba2-3b'),
+        ('prefix_sharing', 'k-exaone-236b-a23b-ep16-l5'),
+        ('prefix_sharing', 'ai21-jamba2-3b')})
+    if unfit:
+        with pytest.raises(ValueError) as refusal:
+            _engine(monkeypatch, cfg, **{option: True})
+        said = str(refusal.value)
+        assert said.startswith('%s=True with LMConfig.layer_types=%r'
+                               % (option, cfg.layer_types))
+        assert repr(unfit[0].name) in said and unfit[0].why in said
+    elif option == 'prefix_sharing' or config.startswith('fairseq-dense'):
+        assert _engine(monkeypatch, cfg, **{option: True})._books == ()
+    else:
+        # the table lets it pass; the drafter refuses the block by field
+        with pytest.raises(ValueError, match=r'build_lm_drafter .*LMConfig\.'):
+            _engine(monkeypatch, cfg, **{option: True})
+    eng = _engine(monkeypatch, cfg)
+    # one bookkeeper a kind of index that is not the allocator's, in the
+    # table's order, each with the feed the programs declare for its kind
+    kinds = []
+    for p in eng._pools:
+        if p.index != 'block' and p.index not in kinds:
+            kinds.append(p.index)
+    books = {'ring': kv_blocks.WindowRings, 'row': kv_blocks.SlotRows}
+    assert [type(b) for b in eng._books] == [books[k] for k in kinds]
+    assert [b.feed for b in eng._books] == [T.INDEX_FEEDS[k] for k in kinds]
+    assert sorted(eng._tables_feed([[0] * 8])) == \
+        sorted(['gen_btab'] + [b.feed for b in eng._books])
+    # only a tail is recomputed where a wholly shared prompt would copy
+    assert eng._cow_ok == (config != 'lfm2-8b-a1b-l8')
+    stats = {'blocks': {}}
+    for b in eng._books:
+        b.report(stats)
+    assert sorted(stats) == ['blocks'] + ['state'] * ('row' in kinds)
+    assert sorted(stats['blocks']) == ['window'] * ('ring' in kinds)
