@@ -631,6 +631,13 @@ def build_fn(program, fetch_names, read_names, written_names,
     ro_names = [n for n in read_names if n not in written_set]
 
     def fn(feed, ro_state, rw_state, key):
+        # a program that states its matmuls' precision is traced under it
+        # (kernels and ops that state their own keep theirs)
+        with jax.default_matmul_precision(program.matmul_precision) \
+                if program.matmul_precision else contextlib.nullcontext():
+            return body(feed, ro_state, rw_state, key)
+
+    def body(feed, ro_state, rw_state, key):
         env = {}
         env.update(feed)
         env.update(ro_state)

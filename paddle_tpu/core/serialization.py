@@ -151,7 +151,7 @@ def _encode_op(op):
 # -- program <-> dict --------------------------------------------------------
 
 def program_to_dict(program):
-    return {
+    d = {
         'format': FORMAT,
         'version': VERSION,
         'random_seed': program.random_seed,
@@ -163,6 +163,10 @@ def program_to_dict(program):
             for b in program.blocks
         ],
     }
+    if program.matmul_precision:
+        # only where it is set: every other program's dict stays as it was
+        d['matmul_precision'] = program.matmul_precision
+    return d
 
 
 def program_from_dict(d):
@@ -176,6 +180,7 @@ def program_from_dict(d):
             % (d['version'], VERSION))
     p = Program()
     p.random_seed = d.get('random_seed', 0)
+    p.matmul_precision = d.get('matmul_precision')
     p._is_test = d.get('is_test', False)
     # materialize all blocks first so parent links resolve
     for bd in d['blocks'][1:]:

@@ -373,6 +373,12 @@ class Program(object):
         # compiled entry
         self.name = name or self.DEFAULT_NAME
         self.random_seed = 0
+        # the precision of every matmul in the program that states none of
+        # its own (a name jax.default_matmul_precision takes, 'highest':
+        # float32 operands multiplied as float32); None leaves the
+        # backend's default, bfloat16 operands on the TPU. Part of
+        # _fingerprint() where it is set
+        self.matmul_precision = None
         self._version = 0          # bumped on any mutation; keys compile cache
         # process-unique id for compile-cache keys: unlike id(self), never
         # reused after GC; unlike _version alone, never collides across
@@ -428,13 +434,13 @@ class Program(object):
         the executor reuses the compiled entry instead of recompiling per
         `_uid`. Falls back to the uid (no sharing, never wrong) for
         programs whose attrs the durable schema cannot encode (py_func
-        callables etc.). Cached per (_version, random_seed) — structural
-        mutations bump the version, and random_seed sits in the key
-        directly because it is a plain attribute assignment that bumps
-        nothing yet is baked into the trace."""
+        callables etc.). Cached per (_version, random_seed, matmul_precision)
+        — structural mutations bump the version, and the other two sit in
+        the key directly because each is a plain attribute assignment that
+        bumps nothing yet is baked into the trace."""
         cached = getattr(self, '_fp_cache', None)
-        if cached is not None and cached[0] == (self._version,
-                                                self.random_seed):
+        key = (self._version, self.random_seed, self.matmul_precision)
+        if cached is not None and cached[0] == key:
             return cached[1]
         try:
             from .core import serialization as _ser
@@ -445,9 +451,10 @@ class Program(object):
                 _json.dumps(blob, sort_keys=True,
                             separators=(',', ':')).encode()).hexdigest()
         except Exception:
-            fp = 'uid:%d:%d:%s' % (self._uid, self._version,
-                                   self.random_seed)
-        self._fp_cache = ((self._version, self.random_seed), fp)
+            fp = 'uid:%d:%d:%s:%s' % (self._uid, self._version,
+                                      self.random_seed,
+                                      self.matmul_precision)
+        self._fp_cache = (key, fp)
         return fp
 
     # -- cloning / pruning -------------------------------------------------

@@ -5,7 +5,8 @@ import numpy as np
 
 from ..layer_helper import LayerHelper
 from ..framework import Variable
-from ..initializer import Constant, Normal, RowsInitializer, Xavier
+from ..initializer import (Constant, Normal, NumpyArrayInitializer,
+                           RowsInitializer, Xavier)
 from ..param_attr import ParamAttr
 from ..core.types import convert_np_dtype_to_dtype_
 
@@ -17,7 +18,7 @@ __all__ = [
     'fused_layer_norm_residual', 'fused_ffn_tail', 'rms_norm',
     'rotary_embedding', 'moe_ffn', 'mla_decode_attention',
     'mla_prefix_attention', 'short_conv_decode', 'short_conv_prefill',
-    'ssm_decode', 'ssm_prefill',
+    'ssm_decode', 'ssm_prefill', 'ssd_decode', 'ssd_prefill',
     'group_norm', 'data_norm', 'l2_normalize', 'matmul', 'mul', 'topk',
     'reshape', 'squeeze', 'unsqueeze', 'flatten', 'transpose', 'split',
     'reduce_sum', 'reduce_mean', 'reduce_max', 'reduce_min', 'reduce_prod',
@@ -620,7 +621,7 @@ def moe_ffn(input, n_experts, expert_width, top_k, norm_topk_prob=False,
             length=None, valid=None, router_param_attr=None,
             gate_param_attr=None, up_param_attr=None, down_param_attr=None,
             score='softmax', select_bias_attr=None, routed_scale=1.0,
-            experts_held=None, router_eps=None, name=None):
+            experts_held=None, router_eps=None, form='gated', name=None):
     """Dropless top-k mixture-of-experts FFN over the rows of ``input
     [N, d]`` (ops/moe_ops.py): a float32 softmax router over all
     ``n_experts``, the ``top_k`` largest (renormalised only with
@@ -638,7 +639,8 @@ def moe_ffn(input, n_experts, expert_width, top_k, norm_topk_prob=False,
     holds that share of the ``n_experts`` the router scores, ``out`` is
     the part of the sum these experts give and ``expert_load`` is
     ``[count + 1]``, the last entry the assignments that went
-    elsewhere."""
+    elsewhere. ``form='relu2'``: an expert is ``relu(x W_up)^2 W_down``,
+    two matrices and no gate (``gate_param_attr`` names nothing)."""
     helper = LayerHelper('moe_ffn', name=name)
     dtype = input.dtype
     n, d = input.shape[0], input.shape[-1]
@@ -654,7 +656,8 @@ def moe_ffn(input, n_experts, expert_width, top_k, norm_topk_prob=False,
                          'experts' % (experts_held, n_experts))
     share = held < n_experts
     router = param(router_param_attr, [d, n_experts])
-    gate = param(gate_param_attr, [held, d, expert_width])
+    gate = param(gate_param_attr, [held, d, expert_width]) \
+        if form == 'gated' else None
     up = param(up_param_attr, [held, d, expert_width])
     down = param(down_param_attr, [held, expert_width, d])
     out = helper.create_variable_for_type_inference(dtype,
@@ -663,8 +666,10 @@ def moe_ffn(input, n_experts, expert_width, top_k, norm_topk_prob=False,
                                                     shape=(n, top_k))
     load = helper.create_variable_for_type_inference(
         'int32', shape=(held + 1 if share else held,))
-    inputs = {'X': [input], 'RouterW': [router], 'GateW': [gate],
-              'UpW': [up], 'DownW': [down]}
+    inputs = {'X': [input], 'RouterW': [router]}
+    if gate is not None:
+        inputs['GateW'] = [gate]
+    inputs.update({'UpW': [up], 'DownW': [down]})
     if length is not None:
         inputs['Length'] = [length]
     if valid is not None:
@@ -837,6 +842,70 @@ def ssm_prefill(u, z, state, tail, rows, positions, length, layer, prefix,
     (ops/ssm_ops.py). Returns ``[1, T, d_inner]``."""
     return _ssm('ssm_prefill', u, z, state, tail, rows, layer, prefix,
                 n_state, kernel, dt_rank, epsilon, positions=positions,
+                length=length)
+
+
+def _ssd(op_type, xbc, z, dt, state, tail, rows, layer, prefix, groups,
+         kernel, epsilon, chunk=None, positions=None, length=None):
+    """A Mamba-2 mixer's op (ops/ssd_ops.py) with the layer's inner
+    parameters under ``prefix``. Their defaults are Mamba-2's own for the
+    recurrence (state-spaces/mamba `Mamba2.__init__`): ``A_log`` the log of
+    1 .. 16 evenly over the heads (published: a uniform draw there), ``D``
+    = 1, the step's bias the inverse softplus of 0.01, the middle of the
+    published [1e-3, 1e-1]."""
+    helper = LayerHelper(op_type)
+    di, heads = int(z.shape[-1]), int(dt.shape[-1])
+
+    def param(name, shape, init):
+        return helper.create_parameter(
+            attr=ParamAttr(name='%s.%s' % (prefix, name)), shape=shape,
+            dtype=z.dtype, default_initializer=init)
+    weights = {
+        'ConvW': param('conv.w', [int(xbc.shape[-1]), int(kernel)],
+                       Normal(0.0, 0.3)),
+        'ConvB': param('conv.b', [int(xbc.shape[-1])], Normal(0.0, 0.1)),
+        'DtBias': param('dt.b', [heads],
+                        Constant(float(np.log(np.expm1(0.01))))),
+        'ALog': param('A_log', [heads], NumpyArrayInitializer(
+            np.log(np.linspace(1.0, 16.0, heads, dtype='float32')))),
+        'D': param('D', [heads], Constant(1.0)),
+        'NormW': param('norm.w', [di], Constant(1.0))}
+    out = helper.create_variable_for_type_inference(z.dtype, shape=z.shape)
+    inputs = {'X': [xbc], 'Z': [z], 'Dt': [dt], 'State': [state],
+              'Tail': [tail], 'Rows': [rows]}
+    inputs.update({k: [v] for k, v in weights.items()})
+    attrs = {'layer': int(layer), 'epsilon': float(epsilon),
+             'groups': int(groups)}
+    if positions is not None:
+        inputs.update({'Positions': [positions], 'Length': [length]})
+        attrs['chunk'] = int(chunk)
+    helper.append_op(type=op_type, inputs=inputs,
+                     outputs={'Out': [out], 'StateOut': [state],
+                              'TailOut': [tail]}, attrs=attrs)
+    return out
+
+
+def ssd_decode(xbc, z, dt, state, tail, rows, layer, prefix, groups, kernel,
+               epsilon=1e-5):
+    """One step of a Mamba-2 layer for every slot's one row: ``xbc [S,
+    d_inner + 2 G N]``, ``z [S, d_inner]`` and ``dt [S, H]`` (the three
+    parts of the mixer's input projection), ``state`` / ``tail`` the two
+    pools, read and written in place at the rows ``rows [S, 1]`` names (0:
+    none), ``layer`` the layer's ordinal in them (ops/ssd_ops.py). Returns
+    the gated, group-normed ``[S, d_inner]``, the output projection's
+    input."""
+    return _ssd('ssd_decode', xbc, z, dt, state, tail, rows, layer, prefix,
+                groups, kernel, epsilon)
+
+
+def ssd_prefill(xbc, z, dt, state, tail, rows, positions, length, layer,
+                prefix, groups, kernel, chunk, epsilon=1e-5):
+    """`ssd_decode` for one prompt suffix (``[1, T, ...]``) that starts at
+    ``positions[0]``: from zeros there, else from the row as the chunk
+    before left it, over the ``length`` real rows, in blocks of ``chunk``
+    rows (ops/ssd_ops.py). Returns ``[1, T, d_inner]``."""
+    return _ssd('ssd_prefill', xbc, z, dt, state, tail, rows, layer, prefix,
+                groups, kernel, epsilon, chunk=chunk, positions=positions,
                 length=length)
 
 

@@ -48,7 +48,7 @@ class LMConfig(object):
     - ``position='none'``: nothing is added to the embedding and nothing
       is rotated (Jamba: the recurrence carries the order);
     - ``ffn='gated'``: every layer's FFN is the dense SiLU-gated
-      ``(silu(x W_g) * (x W_u)) W_d`` of width ``d_ff`` (`_gated_ffn`);
+      ``(silu(x W_g) * (x W_u)) W_d`` of width ``d_ff`` (`_dense_ffn`);
     - ``layer_types``: one of ``'attention'`` | ``'conv'`` | ``'window'`` |
       ``'ssm'`` a layer. An ``'ssm'`` layer's mixer is Jamba's Mamba-1
       block (``[u | z] = h W_in``; a causal depthwise convolution of
@@ -74,8 +74,27 @@ class LMConfig(object):
       of ``B * u``, in a pool of its own under the K/V pools' block ids
       (ops/short_conv_ops.py). The K/V pools hold the attention layers
       only;
+    - ``layer_types`` ``'ssd'``: a Mamba-2 mixer (Nemotron-H's; ops/
+      ssd_ops.py): ``[z | xBC | dt] = h W_in``; the convolution over all
+      of ``xBC``; ``ssm_heads`` heads of ``ssm_head_dim`` channels, each
+      with a SCALAR decay and a ``[ssm_head_dim, ssm_state]`` state, ``B``
+      and ``C`` shared by the heads of each of ``ssm_groups`` groups; the
+      gate, then an RMSNorm a group; ``W_out``. Prompts are scanned in
+      blocks of ``ssm_chunk`` rows. Its state and tail are two more pools
+      a row a slot (`SSD_STATE`, `SSD_TAIL`);
+    - ``layer_types`` ``'ffn'``: a model that has such layers is made of
+      layers of ONE sublayer, ``x + f(norm(x))`` (Nemotron-H's block): an
+      ``'ffn'`` layer is the FFN alone (norm ``ln2``), a layer of any
+      other kind its mixer alone (``ln1``). Without one, every layer is
+      norm, mixer, norm, FFN. `has_mixer` / `has_ffn` say it a layer, and
+      both serving loops ask nothing else;
     - ``tie_embeddings``: the head contracts against ``tok_emb.w`` where
       it lies; there is no ``lm_head.w``;
+    - ``matmul_precision='highest'``: the two serving programs multiply
+      their float32 operands as float32 (`Program.matmul_precision`: every
+      matmul that states no precision of its own, the kernels' among
+      them); the default None leaves the backend's, bfloat16 operands on
+      the TPU;
     - ``router_eps``: what the sigmoid router adds to the sum it
       divides the chosen weights by;
     - ``bias=False``: no bias on any projection;
@@ -93,7 +112,10 @@ class LMConfig(object):
       what the others would add is left out (ops/moe_ops.py).
       ``n_dense_layers``: that many leading layers take a dense
       SiLU-gated FFN instead, ``(silu(x W_g) * (x W_u)) W_d`` of width
-      ``d_ff``;
+      ``d_ff``. ``expert_form='relu2'``: an expert, routed or shared, is
+      ``relu(x W_u)^2 W_d``, two matrices and no gate.
+      ``shared_expert_width``: the shared experts' width together, where
+      it is not ``n_shared_experts x expert_width``;
     - ``attention='mla'``: latent attention (DeepSeek-V2/V3). q through a
       rank-``q_lora_rank`` bottleneck with its norm, heads of
       ``qk_nope_dim + qk_rope_dim``; K and V through ONE normed latent of
@@ -117,7 +139,10 @@ class LMConfig(object):
                  rope_interleave=False, layer_types=None, conv_kernel=3,
                  n_kv_head=None, tie_embeddings=False, router_eps=1e-20,
                  sliding_window=0, global_rope=True, ssm_expand=2,
-                 ssm_state=16, ssm_conv=4, ssm_dt_rank=None):
+                 ssm_state=16, ssm_conv=4, ssm_dt_rank=None, ssm_heads=0,
+                 ssm_head_dim=0, ssm_groups=1, ssm_chunk=128,
+                 expert_form='gated', shared_expert_width=None,
+                 matmul_precision=None):
         self.vocab_size = vocab_size
         self.seq_len = seq_len
         self.d_model = d_model
@@ -138,7 +163,9 @@ class LMConfig(object):
                 ('ffn', ffn, ('gelu', 'moe', 'gated')),
                 ('moe_score', moe_score, ('softmax', 'sigmoid')),
                 ('attention', attention, ('mha', 'mla')),
-                ('qk_norm', qk_norm, (False, True, 'head'))):
+                ('qk_norm', qk_norm, (False, True, 'head')),
+                ('expert_form', expert_form, ('gated', 'relu2')),
+                ('matmul_precision', matmul_precision, (None, 'highest'))):
             if value not in known:
                 raise ValueError('LMConfig.%s=%r: expected one of %r'
                                  % (field, value, known))
@@ -161,6 +188,9 @@ class LMConfig(object):
         self.moe_score = moe_score
         self.routed_scale = routed_scale
         self.n_shared_experts = n_shared_experts
+        self.expert_form = expert_form
+        self.shared_expert_width = int(
+            shared_expert_width or n_shared_experts * expert_width)
         self.experts_held = tuple(experts_held or (0, n_experts))
         self.n_dense_layers = n_dense_layers if ffn == 'moe' else 0
         self.attention = attention
@@ -181,12 +211,25 @@ class LMConfig(object):
         self.ssm_state = int(ssm_state)
         self.ssm_conv = int(ssm_conv)
         self.ssm_dt_rank = int(ssm_dt_rank or -(-d_model // 16))
+        self.ssm_heads = int(ssm_heads)
+        self.ssm_head_dim = int(ssm_head_dim)
+        self.ssm_groups = int(ssm_groups)
+        self.ssm_chunk = int(ssm_chunk)
+        self.matmul_precision = matmul_precision
         if len(self.layer_types) != n_layer or set(self.layer_types) - {
-                'attention', 'conv', 'window', 'ssm'}:
+                'attention', 'conv', 'window', 'ssm', 'ssd', 'ffn'}:
             raise ValueError("LMConfig.layer_types=%r: expected %d of "
-                             "'attention' | 'conv' | 'window' | 'ssm'"
-                             % (self.layer_types, n_layer))
-        if self.n_ssm_layers and self.ssm_conv - 1 > ssm_ops.TAIL_ROWS:
+                             "'attention' | 'conv' | 'window' | 'ssm' | "
+                             "'ssd' | 'ffn'" % (self.layer_types, n_layer))
+        if self.n_ssd_layers and (
+                ssm_heads < 1 or ssm_head_dim < 1 or ssm_groups < 1
+                or ssm_heads % ssm_groups):
+            raise ValueError("LMConfig.layer_types has 'ssd' layers: they "
+                             "need ssm_heads x ssm_head_dim channels in "
+                             "ssm_groups groups of whole heads, got %r x %r "
+                             "in %r" % (ssm_heads, ssm_head_dim, ssm_groups))
+        if (self.n_ssm_layers or self.n_ssd_layers) \
+                and self.ssm_conv - 1 > ssm_ops.TAIL_ROWS:
             raise ValueError("LMConfig.ssm_conv=%r: a state-space layer's "
                              "tail is at most %d rows a slot"
                              % (ssm_conv, ssm_ops.TAIL_ROWS))
@@ -199,12 +242,10 @@ class LMConfig(object):
             raise ValueError('LMConfig.n_kv_head=%r does not divide '
                              'n_head=%r' % (self.n_kv_head, n_head))
         if attention == 'mla' and (self.n_kv_head != n_head
-                                   or self.n_conv_layers
-                                   or self.n_window_layers
-                                   or self.n_ssm_layers):
+                                   or self.n_attn_layers != n_layer):
             raise ValueError("LMConfig.attention='mla' is built with "
-                             "neither n_kv_head nor 'conv', 'window' or "
-                             "'ssm' layer_types")
+                             "neither n_kv_head nor any layer_types but "
+                             "'attention', got %r" % (self.layer_types,))
         if attention == 'mla' and not (
                 position == 'rope' and q_lora_rank and kv_lora_rank
                 and qk_nope_dim and qk_rope_dim and v_head_dim):
@@ -238,16 +279,40 @@ class LMConfig(object):
         return self.layer_types.count('ssm')
 
     @property
+    def n_ssd_layers(self):
+        return self.layer_types.count('ssd')
+
+    @property
     def ssm_inner(self):
         """Channels of a state-space layer's recurrence (``d_inner``)."""
         return self.ssm_expand * self.d_model
 
     @property
+    def ssd_inner(self):
+        """Channels of a Mamba-2 layer's recurrence: heads x head size
+        (NOT ``ssm_expand x d_model``)."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssd_conv_width(self):
+        """Channels of a Mamba-2 layer's convolution: x, B and C."""
+        return self.ssd_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
     def n_attn_layers(self):
         """The GLOBAL attention layers: those of the K/V pools that the
         block allocator's tables address."""
-        return self.n_layer - self.n_conv_layers - self.n_window_layers \
-            - self.n_ssm_layers
+        return self.n_layer - sum(self.layer_types.count(kind) for kind in (
+            'conv', 'window', 'ssm', 'ssd', 'ffn'))
+
+    def has_mixer(self, layer):
+        """Whether `layer` has the mixer's sublayer (norm ``ln1``)."""
+        return self.layer_types[layer] != 'ffn'
+
+    def has_ffn(self, layer):
+        """Whether `layer` has the FFN's sublayer (norm ``ln2``)."""
+        return 'ffn' not in self.layer_types \
+            or self.layer_types[layer] == 'ffn'
 
     def rotates(self, layer):
         """Whether `layer`'s q and k are rotated by the positions."""
@@ -267,8 +332,8 @@ class LMConfig(object):
 
     @property
     def n_moe_layers(self):
-        return self.n_layer - self.n_dense_layers if self.ffn == 'moe' \
-            else 0
+        return sum(self.has_ffn(i) for i in range(
+            self.n_dense_layers, self.n_layer)) if self.ffn == 'moe' else 0
 
 
 _CLASSIC_BLOCK = (('norm', 'layer_norm'), ('position', 'sinusoid'),
@@ -283,7 +348,7 @@ def _require_classic_block(cfg, who):
         ('head_dim', cfg.d_model // cfg.n_head),
         ('n_kv_head', cfg.n_head),
         ('layer_types', ('attention',) * cfg.n_layer),
-        ('tie_embeddings', False))
+        ('tie_embeddings', False), ('matmul_precision', None))
     for field, value in classic:
         if getattr(cfg, field) != value:
             raise ValueError(
@@ -548,14 +613,43 @@ def _ssm_mixer(cfg, ln1, p, nth, ssm, num_flatten_dims):
     return proj(ssm(u, z, p + '.ssm', nth), cfg.d_model, 'out')
 
 
-def _gated_ffn(x, width, d_model, name, num_flatten_dims):
+def _ssd_mixer(cfg, ln1, p, nth, ssd, num_flatten_dims):
+    """Nemotron-H's Mamba-2 mixer on the normed input: ``[z | xBC | dt] =
+    h W_in``, the program's cache op on the ``nth`` Mamba-2 layer's rows
+    (``ssd(xbc, z, dt, prefix, nth)``: layers.ssd_decode / ssd_prefill --
+    the convolution, the recurrence, the gate and the group norm),
+    ``W_out``. No bias on either projection."""
+    def proj(x, size, which):
+        return layers.fc(x, size=size, num_flatten_dims=num_flatten_dims,
+                         param_attr=ParamAttr(name='%s.ssd.%s.w'
+                                              % (p, which)),
+                         bias_attr=False)
+    ends = [cfg.ssd_inner, cfg.ssd_inner + cfg.ssd_conv_width,
+            cfg.ssd_inner + cfg.ssd_conv_width + cfg.ssm_heads]
+    zxd = proj(ln1, ends[-1], 'in')
+    z, xbc, dt = [layers.slice(zxd, axes=[num_flatten_dims], starts=[start],
+                               ends=[end])
+                  for start, end in zip([0] + ends, ends)]
+    return proj(ssd(xbc, z, dt, p + '.ssd', nth), cfg.d_model, 'out')
+
+
+# the mixers that are no attention, by the layer's kind: each takes (cfg,
+# ln1, prefix, ordinal, the program's cache op of the kind, the rows' axis)
+_MIXERS = {'conv': _conv_mixer, 'ssm': _ssm_mixer, 'ssd': _ssd_mixer}
+
+
+def _dense_ffn(x, width, d_model, name, num_flatten_dims, form='gated'):
     """``(silu(x W_g) * (x W_u)) W_d``, no bias: a dense SiLU-gated FFN
-    (and an expert that every row goes through)."""
+    (and an expert that every row goes through); with ``form='relu2'``
+    the ungated ``relu(x W_u)^2 W_d``."""
     def proj(x, size, which):
         return layers.fc(x, size=size, num_flatten_dims=num_flatten_dims,
                          param_attr=ParamAttr(name='%s.%s.w'
                                               % (name, which)),
                          bias_attr=False)
+    if form == 'relu2':
+        return proj(layers.square(layers.relu(proj(x, width, 'up'))),
+                    d_model, 'down')
     return proj(layers.elementwise_mul(layers.swish(proj(x, width, 'gate')),
                                        proj(x, width, 'up')),
                 d_model, 'down')
@@ -564,7 +658,7 @@ def _gated_ffn(x, width, d_model, name, num_flatten_dims):
 def _ffn(cfg, ln2, p, num_flatten_dims, length=None, valid=None, layer=0):
     """The block's FFN on its normed input: (delta, routing). GELU: the
     fused tail, routing None. The ``n_dense_layers`` leading layers of an
-    expert model: `_gated_ffn`, routing None. Experts: `layers.moe_ffn`
+    expert model: `_dense_ffn`, routing None. Experts: `layers.moe_ffn`
     over the rows, routing = (the ``[rows, experts_per_token]`` experts
     chosen, the int32 rows routed to each expert held here; ``length`` /
     ``valid`` say which rows are a request's and count)."""
@@ -573,7 +667,7 @@ def _ffn(cfg, ln2, p, num_flatten_dims, length=None, valid=None, layer=0):
         # RNG-free bind fast path (no per-step key derivation)
         return _ffn_tail(ln2, cfg, p, num_flatten_dims), None
     if cfg.ffn == 'gated' or layer < cfg.n_dense_layers:
-        return _gated_ffn(ln2, cfg.d_ff, cfg.d_model, p + '.ffn',
+        return _dense_ffn(ln2, cfg.d_ff, cfg.d_model, p + '.ffn',
                           num_flatten_dims), None
     shape = ln2.shape
     rows = ln2 if num_flatten_dims == 1 \
@@ -589,6 +683,8 @@ def _ffn(cfg, ln2, p, num_flatten_dims, length=None, valid=None, layer=0):
         router['experts_held'] = cfg.experts_held
     if cfg.router_eps != 1e-20:
         router['router_eps'] = cfg.router_eps
+    if cfg.expert_form != 'gated':
+        router['form'] = cfg.expert_form
     out, idx, load = layers.moe_ffn(
         rows, cfg.n_experts, cfg.expert_width, cfg.experts_per_token,
         norm_topk_prob=cfg.norm_topk_prob, length=length, valid=valid,
@@ -597,9 +693,9 @@ def _ffn(cfg, ln2, p, num_flatten_dims, length=None, valid=None, layer=0):
         up_param_attr=ParamAttr(name=p + '.moe.up.w'),
         down_param_attr=ParamAttr(name=p + '.moe.down.w'), **router)
     if cfg.n_shared_experts:
-        out = layers.elementwise_add(out, _gated_ffn(
-            rows, cfg.n_shared_experts * cfg.expert_width, cfg.d_model,
-            p + '.moe.shared', 1))
+        out = layers.elementwise_add(out, _dense_ffn(
+            rows, cfg.shared_expert_width, cfg.d_model, p + '.moe.shared',
+            1, cfg.expert_form))
     if num_flatten_dims != 1:
         out = layers.reshape(out, shape=[-1] + list(shape[1:]))
     return out, (idx, load)
@@ -678,12 +774,16 @@ def transformer_block(x, cfg, prefix, mask_var=None, is_test=False,
     return layers.elementwise_add(x, ff2)
 
 
-def _name_program(name):
+def _name_program(name, cfg=None):
     """Name the program being built, unless whoever made it already has:
-    its compiled XLA module is then jit_<name> in a device trace."""
+    its compiled XLA module is then jit_<name> in a device trace. With
+    ``cfg`` (the serving programs) it also takes the model's
+    `LMConfig.matmul_precision`."""
     program = default_main_program()
     if program.name == program.DEFAULT_NAME:
         program.name = name
+    if cfg is not None:
+        program.matmul_precision = cfg.matmul_precision
 
 
 def build_lm(cfg=None, is_test=False):
@@ -785,9 +885,9 @@ def build_lm(cfg=None, is_test=False):
 # the LM serves decode without any renaming.
 #
 # A model with WINDOW or STATE-SPACE layers (`LMConfig.layer_types`
-# 'window', 'ssm') declares further pools, sized by the engine's slots and
-# served by no allocator, and both programs take the feed that indexes them
-# (`cache_pools`, `_slot_feeds`): slot i's ring of `window_ring` blocks
+# 'window', 'ssm', 'ssd') declares further pools, sized by the engine's slots
+# and served by no allocator, and both programs take the feed that indexes
+# them (`cache_pools`, `_slot_feeds`): slot i's ring of `window_ring` blocks
 # while it is resident, its row i + 1 (0 for a row that sits a step out:
 # ops/ssm_ops.py). Each attention layer's ops get its kind's pool, table and
 # bound; only the window layers rotate q and k where `global_rope` is off.
@@ -818,6 +918,8 @@ WINDOW_CACHE_K = 'gen_kv_window_k'
 WINDOW_CACHE_V = 'gen_kv_window_v'
 SSM_STATE = 'gen_ssm_state'
 SSM_TAIL = 'gen_ssm_tail'
+SSD_STATE = 'gen_ssd_state'
+SSD_TAIL = 'gen_ssd_tail'
 
 
 def window_ring(cfg, block_size):
@@ -870,7 +972,10 @@ def cache_pools(cfg, num_blocks=0, block_size=1, slots=None):
     state-space layers, indexed by the slots' rows (slot ``i`` has row ``i
     + 1``; row 0 is the trash row): the state (the channels minor: whole
     vregs of lanes) and the tail (the ``ssm_conv - 1`` rows a layer keeps in
-    a sublane tile of their own: ops/ssm_ops.py has what a padded one cost)."""
+    a sublane tile of their own: ops/ssm_ops.py has what a padded one cost).
+    With Mamba-2 layers, the same of theirs under the same rows: the state
+    ``[N, heads x head size]`` a layer (ops/ssd_ops.py says why that way
+    round) and the tail over all the convolution's channels."""
     pools = []
 
     def kind(shapes, index, rewinds, copies, why=None, **books):
@@ -913,6 +1018,17 @@ def cache_pools(cfg, num_blocks=0, block_size=1, slots=None):
              "it", step=('ssm_state_rows_updated_total', 1),
              prefill='ssm_prefill_rows_total',
              resume='ssm_state_resumes_total')
+    if cfg.n_ssd_layers:
+        kind([(SSD_STATE, (n + 1, cfg.n_ssd_layers, cfg.ssm_state,
+                           cfg.ssd_inner)),
+              (SSD_TAIL, (n + 1, cfg.n_ssd_layers, ssm_ops.TAIL_ROWS,
+                          cfg.ssd_conv_width))], 'row', False, False,
+             "a Mamba-2 layer's state is a row a slot, every head's matrix "
+             "as of the slot's last position -- a shared block has no state "
+             "to resume from, and a rejected draft cannot be unwound from "
+             "it", step=('ssd_state_rows_updated_total', 1),
+             prefill='ssd_prefill_rows_total',
+             resume='ssd_state_resumes_total')
     return tuple(pools)
 
 
@@ -988,7 +1104,7 @@ def _qkv_split_step(qkv, cfg):
 
 
 def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
-                  pos=None, valid=None, routing=None, conv=None, ssm=None):
+                  pos=None, valid=None, routing=None, mixers=None):
     """One decode-position transformer tower over per-slot row state
     ``x`` ([S, d]: token embedding, + position encoding where positions
     are added). The cache write and cached attention are delegated to
@@ -1005,38 +1121,39 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
     K/V deposited but no logits. ``pos`` ([S, 1], rotary positions),
     ``valid`` ([S, 1], zero = idle slot) and ``routing`` (a list that
     takes each layer's `_ffn` routing) serve the blocks that need them;
-    ``conv(g, weight_attr, layer)`` is a convolution layer's cache op,
-    ``ssm(u, z, prefix, layer)`` a state-space layer's.
+    ``mixers`` has the program's cache op of every kind in `_MIXERS`
+    the model has. A layer is the sublayers `cfg.has_mixer` and
+    `cfg.has_ffn` give it, each behind its norm.
     The cache closures get a layer's ORDINAL among the layers of its
     kind (`LMConfig.layer_ordinal`) and the kind (``'attention'`` |
     ``'window'``): a pool holds one kind."""
     delta = None             # previous layer's deferred FFN output
     for i in range(cfg.n_layer):
         p = 'layer_%d' % i
-        nth = cfg.layer_ordinal(i)
-        ln1, x = _norm(cfg, x, delta, 1, p + '.ln1')
-        if cfg.layer_types[i] == 'conv':
-            attn = _conv_mixer(cfg, ln1, p, nth, conv, 1)
-        elif cfg.layer_types[i] == 'ssm':
-            attn = _ssm_mixer(cfg, ln1, p, nth, ssm, 1)
-        else:
-            kind = cfg.layer_types[i]
-            q, k, v = _qkv(cfg, ln1, p, pos, layer=i)        # [S, H, dh]
-            cache_write(k, v, nth, kind)
-            if not head and i == cfg.n_layer - 1:
-                # write-only tower, last layer: nothing consumes x past
-                # this K/V deposit — attention/proj/ffn are dead compute
-                return None
-            ctx = attend(q, nth, p + tag, kind)
-            attn = layers.fc(
-                layers.reshape(ctx, shape=[-1, cfg.attn_width]),
-                size=cfg.d_model,
-                param_attr=ParamAttr(name=p + '.attn.proj.w'),
-                bias_attr=_bias(cfg, p + '.attn.proj.b'))
-        ln2, x = _norm(cfg, x, attn, 1, p + '.ln2')
-        delta, routed = _ffn(cfg, ln2, p, 1, valid=valid, layer=i)
-        if routed is not None:
-            routing.append(routed)
+        nth, kind = cfg.layer_ordinal(i), cfg.layer_types[i]
+        if cfg.has_mixer(i):
+            ln1, x = _norm(cfg, x, delta, 1, p + '.ln1')
+            if kind in _MIXERS:
+                delta = _MIXERS[kind](cfg, ln1, p, nth, mixers[kind], 1)
+            else:
+                q, k, v = _qkv(cfg, ln1, p, pos, layer=i)    # [S, H, dh]
+                cache_write(k, v, nth, kind)
+                if not head and i == cfg.n_layer - 1:
+                    # write-only tower, last layer: nothing consumes x
+                    # past this K/V deposit — attention/proj/ffn are dead
+                    # compute
+                    return None
+                ctx = attend(q, nth, p + tag, kind)
+                delta = layers.fc(
+                    layers.reshape(ctx, shape=[-1, cfg.attn_width]),
+                    size=cfg.d_model,
+                    param_attr=ParamAttr(name=p + '.attn.proj.w'),
+                    bias_attr=_bias(cfg, p + '.attn.proj.b'))
+        if cfg.has_ffn(i):
+            ln2, x = _norm(cfg, x, delta, 1, p + '.ln2')
+            delta, routed = _ffn(cfg, ln2, p, 1, valid=valid, layer=i)
+            if routed is not None:
+                routing.append(routed)
 
     if not head:
         return None
@@ -1060,7 +1177,7 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
     [n_layer * n_experts] expert loads of the live slots' rows in one
     int64 vector: fetch that INSTEAD — and 'topk_idx'
     (`_expert_outputs`)."""
-    _name_program('lm_decode_step')
+    _name_program('lm_decode_step', cfg)
     d, h, dh = cfg.d_model, cfg.n_head, cfg.head_dim
     tokens = layers.data(name='gen_tokens', shape=[1], dtype='int64')
     pos = layers.data(name='gen_pos', shape=[1], dtype='int64')
@@ -1092,6 +1209,11 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
             u, z, pools[SSM_STATE], pools[SSM_TAIL], feeds['row'], layer,
             prefix, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank,
             epsilon=cfg.rms_eps)
+
+    def ssd(xbc, z, dt, prefix, layer):
+        return layers.ssd_decode(
+            xbc, z, dt, pools[SSD_STATE], pools[SSD_TAIL], feeds['row'],
+            layer, prefix, cfg.ssm_groups, cfg.ssm_conv, epsilon=cfg.rms_eps)
 
     def cache_write(k, v, layer, kind):
         # a window layer writes into its slot's ring: the table's column
@@ -1132,8 +1254,8 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
         if cfg.ffn == 'moe' else None
     routing = []
     logits = _decode_tower(cfg, x, cache_write, attend, pos=pos,
-                           valid=valid, routing=routing, conv=conv,
-                           ssm=ssm)                          # [S, V]
+                           valid=valid, routing=routing,
+                           mixers={'conv': conv, 'ssm': ssm, 'ssd': ssd})
     next_tokens = _append_sample_op(block, logits, sample_vars,
                                     'gen_next_tokens')       # [S]
     return _expert_outputs(
@@ -1367,7 +1489,7 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     'tokens_and_load' (first_token and the [n_layer * n_experts] expert
     loads of the REAL suffix rows, one int64 vector: fetch it instead)
     and 'topk_idx' (`_expert_outputs`)."""
-    _name_program('lm_prefill_paged')
+    _name_program('lm_prefill_paged', cfg)
     d, h, dh = cfg.d_model, cfg.n_head, cfg.head_dim
     T = int(prompt_len)
     prompt = layers.data(name='gen_prompt', shape=[-1, T], dtype='int64')
@@ -1413,6 +1535,12 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
             length, layer, prefix, cfg.ssm_state, cfg.ssm_conv,
             cfg.ssm_dt_rank, epsilon=cfg.rms_eps)
 
+    def ssd(xbc, z, dt, prefix, layer):
+        return layers.ssd_prefill(
+            xbc, z, dt, pools[SSD_STATE], pools[SSD_TAIL], feeds['row'],
+            pos, length, layer, prefix, cfg.ssm_groups, cfg.ssm_conv,
+            cfg.ssm_chunk, epsilon=cfg.rms_eps)
+
     def attention(ln1, p, nth, layer):
         """An attention layer's mixer: q, k, v, the cache writes, the
         suffix's attention against the slot's pages, the projection."""
@@ -1454,20 +1582,19 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
 
     delta = None
     routing = []
+    mixers = {'conv': conv, 'ssm': ssm, 'ssd': ssd}
     for i in range(cfg.n_layer):
         p = 'layer_%d' % i
-        nth = cfg.layer_ordinal(i)
-        ln1, x = _norm(cfg, x, delta, 2, p + '.ln1')
-        if cfg.layer_types[i] == 'conv':
-            attn = _conv_mixer(cfg, ln1, p, nth, conv, 2)
-        elif cfg.layer_types[i] == 'ssm':
-            attn = _ssm_mixer(cfg, ln1, p, nth, ssm, 2)
-        else:
-            attn = attention(ln1, p, nth, i)
-        ln2, x = _norm(cfg, x, attn, 2, p + '.ln2')
-        delta, routed = _ffn(cfg, ln2, p, 2, length=length, layer=i)
-        if routed is not None:
-            routing.append(routed)
+        nth, kind = cfg.layer_ordinal(i), cfg.layer_types[i]
+        if cfg.has_mixer(i):
+            ln1, x = _norm(cfg, x, delta, 2, p + '.ln1')
+            delta = _MIXERS[kind](cfg, ln1, p, nth, mixers[kind], 2) \
+                if kind in _MIXERS else attention(ln1, p, nth, i)
+        if cfg.has_ffn(i):
+            ln2, x = _norm(cfg, x, delta, 2, p + '.ln2')
+            delta, routed = _ffn(cfg, ln2, p, 2, length=length, layer=i)
+            if routed is not None:
+                routing.append(routed)
 
     x, _ = _norm(cfg, x, delta, 2, 'final_ln')
     x_flat = layers.reshape(x, shape=[-1, d])                # [T, d]

@@ -32,6 +32,7 @@ from . import moe_ops
 from . import mla_ops
 from . import short_conv_ops
 from . import ssm_ops
+from . import ssd_ops
 from . import fused_ops
 from . import dist_ops
 from . import pipeline_ops

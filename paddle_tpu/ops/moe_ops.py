@@ -21,6 +21,10 @@ to the Program path (OLMoE: models/transformer.py, LMConfig(norm=
       (p_e, e) = the top_k largest p    NOT renormalised unless asked
       out = sum_e p_e * (silu(x @ GateW[e]) * (x @ UpW[e])) @ DownW[e]
 
+  WITHOUT GateW (``layers.moe_ffn(form='relu2')``, Nemotron-H's experts)
+  an expert is ``relu(x @ UpW[e])^2 @ DownW[e]`` -- two grouped matmuls,
+  not three.
+
   The grouped expert matmul: the assignments are sorted by expert, and
   each of the three matmuls is ONE ``jax.lax.ragged_dot`` over the
   sorted rows with the per-expert counts as group sizes; the rows are
@@ -128,11 +132,12 @@ def route(x, router_w, top_k, norm_topk_prob, score='softmax',
 
 def grouped_ffn(x, w, idx, gate_w, up_w, down_w, first=None, routed=None):
     """sum_j w[n, j] * FFN_{idx[n, j]}(x[n]) through three ragged_dots
-    over the assignments sorted by expert. `first` (not None): the
+    over the assignments sorted by expert; with `gate_w` None the
+    UNGATED expert ``relu(x W_up)^2 W_down``, two. `first` (not None): the
     weights are those of experts first .. first + E - 1 of `routed`, and
     the sum runs over the assignments to these alone."""
     n, k = idx.shape
-    n_experts = gate_w.shape[0]
+    n_experts = up_w.shape[0]
     flat = idx.reshape(-1)
     if first is not None:
         # an expert held elsewhere: behind every group, in none
@@ -146,8 +151,9 @@ def grouped_ffn(x, w, idx, gate_w, up_w, down_w, first=None, routed=None):
         """The first `rows` sorted assignments through the experts:
         [rows, d], sorted."""
         xs = x[(order if rows == n * k else order[:rows]) // k]
-        h = jax.nn.silu(lax.ragged_dot(xs, gate_w, sizes)) \
-            * lax.ragged_dot(xs, up_w, sizes)
+        up = lax.ragged_dot(xs, up_w, sizes)
+        h = jnp.square(jax.nn.relu(up)) if gate_w is None \
+            else jax.nn.silu(lax.ragged_dot(xs, gate_w, sizes)) * up
         return lax.ragged_dot(h, down_w, sizes)
 
     if first is None:
@@ -178,7 +184,7 @@ def grouped_ffn(x, w, idx, gate_w, up_w, down_w, first=None, routed=None):
 def _moe_ffn(ctx, op):
     x = ctx.in1(op, 'X')                        # [N, d]
     router_w = ctx.in1(op, 'RouterW')           # [d, E]
-    gate_w = ctx.in1(op, 'GateW')               # [E, d, w]
+    gate_w = ctx.in1(op, 'GateW')               # [E, d, w]; None: ungated
     up_w = ctx.in1(op, 'UpW')                   # [E, d, w]
     down_w = ctx.in1(op, 'DownW')               # [E, w, d]
     length = ctx.in1(op, 'Length')              # optional: real rows
@@ -189,7 +195,7 @@ def _moe_ffn(ctx, op):
                    op.attr('score', 'softmax'), ctx.in1(op, 'SelectBias'),
                    float(op.attr('routed_scale', 1.0)),
                    float(op.attr('router_eps', 1e-20)))
-    held = gate_w.shape[0]
+    held = up_w.shape[0]
     # the experts held here: all of them, or `held` from `first` on
     first = None if held == router_w.shape[1] \
         else int(op.attr('first_expert', 0))

@@ -358,14 +358,19 @@ def _operands(ctx, op):
             int(op.attr('layer')), float(op.attr('epsilon')))
 
 
-def _impl(name, d_inner, n_state, rows=8):
+def tier(name, tiles):
+    """The lowering of the op `name`: the kernels where its shapes tile
+    (`tiles`) and no mesh is active."""
     from . import kernel_tier
     from ..parallel.api import get_active_mesh
     mesh = get_active_mesh()
     meshed = mesh is not None and mesh.size > 1
-    return kernel_tier.dispatch(
-        name, pallas_ok=shapes_ok(d_inner, n_state, rows) and not meshed,
-        mesh=mesh)
+    return kernel_tier.dispatch(name, pallas_ok=tiles and not meshed,
+                                mesh=mesh)
+
+
+def _impl(name, d_inner, n_state, rows=8):
+    return tier(name, shapes_ok(d_inner, n_state, rows))
 
 
 @register_op('ssm_decode', share_lod=False)

@@ -1,6 +1,6 @@
 """The table of a model's pools (ISSUE 46, models/transformer.py
-`cache_pools`): for the toy `LMConfig` of each of the benchmark's seven
-configurations, every pool's name, what indexes it, whether a rejected
+`cache_pools`): for the toy `LMConfig` of each of the benchmark's eight
+configurations (the eighth since PR 48), every pool's name, what indexes it, whether a rejected
 draft rewinds from it and whether a shared block's entry of it copies;
 `kv_cache_names` and `kv_cache_shapes` are views of it; and what an engine
 refuses, which bookkeepers it keeps and what it books follow from the table
@@ -15,7 +15,8 @@ from paddle_tpu.models.transformer import LMConfig
 from paddle_tpu.serving import GenerateConfig, GenerateEngine
 from paddle_tpu.serving import kv_blocks
 
-from benchmark.models import jamba, joyai, kexaone, lfm2, lm, olmoe
+from benchmark.models import (jamba, joyai, kexaone, lfm2, lm, nemotron,
+                              olmoe)
 
 from test_olmoe_serving import LISTED
 
@@ -37,6 +38,7 @@ CONFIGS = {
     'lfm2-8b-a1b-l8': lambda: _toy(lfm2, 'lfm2'),
     'k-exaone-236b-a23b-ep16-l5': lambda: _toy(kexaone, 'kexaone'),
     'ai21-jamba2-3b': lambda: _toy(jamba, 'jamba'),
+    'nemotron-3-nano-30b-a3b-ep8-l20': lambda: _toy(nemotron, 'nemotron'),
 }
 KV = [(T.KV_CACHE_K, 'block', True, True), (T.KV_CACHE_V, 'block', True, True)]
 # (name, index, rewinds, copies) of every pool, in the order of the state
@@ -51,6 +53,8 @@ POOLS = {
         (T.WINDOW_CACHE_V, 'ring', False, False)],
     'ai21-jamba2-3b': KV + [(T.SSM_STATE, 'row', False, False),
                             (T.SSM_TAIL, 'row', False, False)],
+    'nemotron-3-nano-30b-a3b-ep8-l20': KV + [
+        (T.SSD_STATE, 'row', False, False), (T.SSD_TAIL, 'row', False, False)],
 }
 # the series a decode step books its reads under: (series, rows a slot at
 # most, of which field of the config a layer count)
@@ -61,6 +65,9 @@ STEP_READS = {
         ('kv_window_tokens_read_total', 12, 'n_window_layers')],
     'ai21-jamba2-3b': [('kv_tokens_read_total', None, 'n_attn_layers'),
                        ('ssm_state_rows_updated_total', 1, 'n_ssm_layers')],
+    'nemotron-3-nano-30b-a3b-ep8-l20': [
+        ('kv_tokens_read_total', None, 'n_attn_layers'),
+        ('ssd_state_rows_updated_total', 1, 'n_ssd_layers')],
 }
 SLOTS, BLOCKS, BLOCK_SIZE = 4, 19, 8
 
@@ -81,7 +88,8 @@ def test_the_table_holds_every_pool_and_the_views_are_its(config):
     for p in pools:
         assert p.shape[0] == entries[p.index] and len(p.shape) == 4
         assert p.shape[1] in (cfg.n_attn_layers, cfg.n_conv_layers,
-                              cfg.n_window_layers, cfg.n_ssm_layers)
+                              cfg.n_window_layers, cfg.n_ssm_layers,
+                              cfg.n_ssd_layers)
         # a pool an option is refused over says why; the others need not
         assert (p.why is None) == (p.rewinds and p.index == 'block')
         assert T.INDEX_FEEDS[p.index].startswith('gen_')
@@ -122,6 +130,8 @@ def test_an_engine_refuses_what_the_table_says_and_keeps_its_books(
         ('speculative', 'lfm2-8b-a1b-l8'),
         ('speculative', 'k-exaone-236b-a23b-ep16-l5'),
         ('speculative', 'ai21-jamba2-3b'),
+        ('speculative', 'nemotron-3-nano-30b-a3b-ep8-l20'),
+        ('prefix_sharing', 'nemotron-3-nano-30b-a3b-ep8-l20'),
         ('prefix_sharing', 'k-exaone-236b-a23b-ep16-l5'),
         ('prefix_sharing', 'ai21-jamba2-3b')})
     if unfit:

@@ -459,14 +459,16 @@ def _plain(value):
     return value
 
 
-def program_listing(cfg, program):
+def program_listing(cfg, program, slots=None):
     """A decode or prefill program of `cfg` at the listings' toy engine:
     every op with its inputs, outputs and ATTRIBUTES, the parameters, the
-    startup program, in order."""
+    startup program, in order. ``slots``: what the prefill of a model with
+    pools the slots size is told."""
     build = {
         'decode_step': lambda: T.build_lm_decode_step(
             cfg, 4, 32, block_size=8, num_blocks=9),
-        'prefill_paged': lambda: T.build_lm_prefill_paged(cfg, 16, 9, 8, 4),
+        'prefill_paged': lambda: T.build_lm_prefill_paged(
+            cfg, 16, 9, 8, 4, slots=slots),
     }[program]
     main, start = Program(), Program()
     with program_guard(main, start):
