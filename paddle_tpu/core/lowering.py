@@ -681,7 +681,7 @@ class StateCallable(object):
     its parameters as it did when the state went in as dicts (another
     order is another schedule: the train step read 1.6 % slower)."""
 
-    __slots__ = ('flat', 'ro_names', 'rw_names')
+    __slots__ = ('flat', 'ro_names', 'rw_names', '_fn', '_donate')
 
     def __init__(self, fn, ro_names, rw_names, program, donate):
         ro_names, rw_names = tuple(sorted(ro_names)), tuple(sorted(rw_names))
@@ -691,9 +691,31 @@ class StateCallable(object):
                       dict(zip(rw_names, rw_leaves)), key)
 
         name_after(flat, program)
-        self.flat = jax.jit(flat, donate_argnums=(2,) if donate else ())
+        self._fn = flat
+        self._donate = (2,) if donate else ()
+        self.flat = jax.jit(flat, donate_argnums=self._donate)
         self.ro_names = ro_names
         self.rw_names = rw_names
+
+    def lower_bound(self, feed, ro_leaves, rw_leaves, key, ro_formats):
+        """The entry of the BOUND path (Executor.bind), lowered: `flat`
+        with each read-only leaf held as `ro_formats` says, one
+        `jax.experimental.layout.Format` a leaf. `Format(Layout.AUTO,
+        sharding)` leaves a leaf's layout to the compiler, and the
+        compiled object's `input_formats` then say how the program wants
+        it held: weights never change, so whoever stages them can lay them
+        out once the way the one operation that reads them takes them
+        (XLA:TPU puts a transpose of the whole operand in front of a
+        custom call whose parameter lies the other way round, every
+        call). The feeds, the read-written leaves (donated, and rebound
+        between two calls by other programs) and the key keep the default
+        layout. Every argument is a `jax.ShapeDtypeStruct`: JAX lowers an
+        entry that holds an AUTO from shapes alone, and what is called is
+        the COMPILED object. `flat`, the entry of everyone else, is
+        untouched: an entry's parameters are a schedule."""
+        return jax.jit(self._fn, donate_argnums=self._donate,
+                       in_shardings=(None, tuple(ro_formats), None, None)
+                       ).lower(feed, ro_leaves, rw_leaves, key)
 
     def _flat_args(self, feed, ro_state, rw_state, key):
         return (feed, tuple(ro_state[n] for n in self.ro_names),

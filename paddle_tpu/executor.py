@@ -25,10 +25,13 @@ run:451, global_scope:34) and the C++ serial executor it drives
   docs/executor_performance.md for the full contract.
 """
 import collections
+import contextlib
+import functools
 import operator
 import os
 import threading
 import time
+import types
 
 import numpy as np
 import jax
@@ -99,7 +102,10 @@ class Scope(object):
         # through set / update / drop — nothing else touches `_vars` —
         # and one to a name nobody staged (the KV pools, a training
         # run's parameters) costs a set lookup.
-        self._staged = set()
+        # `_staged` also keeps, a name, the layout a handle's compiled
+        # entry chose for it (a `Format`; None where the backend offers
+        # none): a program bound later takes the leaf as it lies.
+        self._staged = {}
         self._gen = 0
 
     # dict-ish API used internally
@@ -113,8 +119,13 @@ class Scope(object):
 
     def update(self, d):
         self._vars.update(d)
-        if self._staged and not self._staged.isdisjoint(d):
+        if self._staged and not self._staged.keys().isdisjoint(d):
             self._gen += 1
+
+    def _relay(self, name, value):
+        """Hold `name` as `value`, the SAME logical array laid out another
+        way on the device (BoundProgram._stage): no write, no count."""
+        self._vars[name] = value
 
     def has(self, name):
         return name in self._vars
@@ -471,7 +482,7 @@ class _CompiledEntry(object):
     # holds a strong ref to the program so id(program) cache keys can never
     # alias a garbage-collected program's address
     __slots__ = ('fn', 'fetch_names', 'ro_names', 'rw_names', 'written',
-                 'program', 'lod_out', 'notify_dirs')
+                 'program', 'lod_out', 'notify_dirs', 'bound')
 
     def __init__(self, fn, fetch_names, ro_names, rw_names, written,
                  program, lod_out=None):
@@ -482,6 +493,9 @@ class _CompiledEntry(object):
         self.written = written
         self.program = program
         self.lod_out = lod_out if lod_out is not None else {}
+        # BoundProgram._compile's executables, by the formats they were
+        # asked for: a second engine on a fresh scope compiles nothing
+        self.bound = {}
         # checkpoint_notify dirs, precomputed once per compile so the hot
         # run path doesn't rescan the op list every call
         self.notify_dirs = [
@@ -521,6 +535,47 @@ class _DeferredFetch(object):
         self.lod = lod
 
 
+def _layouts_offered():
+    """Whether the backend lets a compiled entry say how its parameters
+    lie. The CPU's compiler answers every AUTO with the default: there a
+    bound entry is the jitted `flat`, lowered text byte for byte."""
+    return jax.default_backend() != 'cpu'
+
+
+def _open_format(leaf):
+    """How a read-only leaf that no bound program of its scope has staged
+    yet may lie: the compiler chooses."""
+    from jax.experimental.layout import Format, Layout
+    return Format(Layout.AUTO, leaf.sharding)
+
+
+@contextlib.contextmanager
+def _compiled_here():
+    """JAX's persistent compile cache out of force for the compiles
+    inside: an executable that cache hands back gives its RESULT in the
+    default layout whatever it was compiled for (JAX 0.9.0; the layouts of
+    ARGUMENTS survive, which is all a bound entry asks for), so the small
+    program `jax.device_put` runs to lay a leaf out anew has to be
+    compiled by the process that uses it — or a second process' relaid
+    leaf lies as it did."""
+    from jax.experimental.compilation_cache import compilation_cache
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update('jax_enable_compilation_cache', cached)
+        compilation_cache.reset_cache()
+
+
+def _spec(value):
+    """`value`'s shape, dtype (as jit takes it) and sharding, to lower from."""
+    aval = jax.typeof(value)
+    return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                sharding=getattr(value, 'sharding', None))
+
+
 class BoundProgram(object):
     """A fixed-signature dispatch handle from `Executor.bind`: a call does
     only what changes from call to call — the read-written state taken
@@ -543,17 +598,35 @@ class BoundProgram(object):
     cannot be cached in the scope (a dtype jax narrows, a view) is
     converted again every call, as `run()` does.
 
+    Staged state lies as the compiled entry wants it. Where the backend
+    offers layouts (`_layouts_offered`), the handle's entry is compiled
+    with every read-only leaf's layout left to the compiler
+    (`StateCallable.lower_bound`), and staging puts each leaf in the
+    format the executable asks for: one `jax.device_put` for a leaf that
+    lies otherwise (`executor_bound_relayout_total`), nothing for the
+    others. The relaid array REPLACES the scope's value under its name —
+    the same shape and values, one copy in HBM, no write counted — and
+    `Scope._staged` keeps the format: a program bound later on the scope
+    compiles for the layout it finds and asks nothing again, so a leaf is
+    relaid once a scope and every handle's entry agrees with what the
+    scope holds. `run()`, `precompile` and the runners keep the default
+    entry (jit compiles for the layout of a committed argument, as these
+    are).
+
     FLAGS_check_nan_inf raises at the program boundary as in run() (the
     op-level localization replay stays a run() feature). Calls are NOT
     thread-safe against each other (the decode loop owns its engine's
     executor thread)."""
 
     __slots__ = ('_exe', '_entry', '_program', '_scope', '_needs_rng',
-                 '_key0', '_fp', '_ro', '_ro_gen', 'restages', 'first_out',
-                 'fetch_names', 'example_feed')
+                 '_key0', '_fp', '_ro', '_ro_gen', '_flat', '_formats',
+                 'restages', 'relayouts', 'first_out', 'fetch_names',
+                 'example_feed')
 
-    def __init__(self, exe, entry, program, scope, needs_rng, first_out,
-                 example_feed=None):
+    def __init__(self, exe, entry, program, scope, needs_rng, example_feed,
+                 rw, key):
+        """`rw` and `key`: the read-written leaves and the run key of the
+        call that follows, for their shapes."""
         self._exe = exe
         self._entry = entry
         self._program = program
@@ -565,25 +638,70 @@ class BoundProgram(object):
         # RNG-free programs reuse one key — building a PRNGKey is itself
         # a device dispatch, pure waste for is_test decode steps
         self._key0 = jax.random.PRNGKey(program.random_seed or 0)
-        self.first_out = first_out
+        # what bind's own run fetched (Executor.bind sets it)
+        self.first_out = None
         self.fetch_names = tuple(entry.fetch_names)
         # the PREPARED bind-time feed (LoD tuples flattened, dtypes
         # normalized): callers that dispatch a constant feed every call —
         # bench timing loops — pass it back verbatim instead of
         # re-preparing per call
         self.example_feed = example_feed
-        # how often a call found the scope written and staged again
+        # how often a call found the scope written and staged again, and
+        # how many leaves staging has laid out anew
         self.restages = 0
-        scope._staged.update(entry.ro_names)
+        self.relayouts = 0
+        self._flat, self._formats = entry.fn.flat, None
+        if _layouts_offered():
+            self._compile(rw, key)
+        names = entry.fn.ro_names
+        scope._staged.update(zip(names, self._formats or [None] * len(names)))
         self._stage()
 
+    def _compile(self, rw, key):
+        """The entry compiled for layouts: each read-only leaf as an
+        earlier handle of this scope had it laid (`Scope._staged`), the
+        compiler's choice for the others; `_formats` is what the
+        executable asks for, a leaf."""
+        scope, fn = self._scope, self._entry.fn
+        fixed = tuple([scope._staged.get(n) for n in fn.ro_names])
+        hit = self._entry.bound.get(fixed)
+        if hit is None:
+            ro = [self._exe._state_value(scope, n, self._program)
+                  for n in fn.ro_names]
+            asked = [f or _open_format(v) for f, v in zip(fixed, ro)]
+            lowered = fn.lower_bound(
+                jax.tree_util.tree_map(_spec, self.example_feed),
+                tuple(map(_spec, ro)), tuple(map(_spec, rw)), _spec(key),
+                asked)
+            compiled = lowered.compile()
+            hit = self._entry.bound[fixed] = (
+                compiled, tuple(compiled.input_formats[0][1]))
+            # the analytics mine THIS lowering: registered first, it is
+            # what the run's own registration finds, which would lower
+            # `flat`, that nobody has lowered, a second time (0.2 s a
+            # program of 24 layers, in every process' set-up)
+            analysis.record_compiled(
+                types.SimpleNamespace(lower=lambda *avals: lowered),
+                self._program,
+                (self.example_feed, dict(zip(fn.ro_names, ro)),
+                 dict(zip(fn.rw_names, rw)), key), donate=bool(fn._donate))
+        self._flat, self._formats = hit
+
     def _stage(self):
-        """Stage the read-only state in the entry's order and note the
-        scope's write count it is good for."""
+        """Stage the read-only state in the entry's order, each leaf in
+        the entry's format, and note the scope's write count it is good
+        for."""
         scope, program = self._scope, self._program
         state_value = self._exe._state_value
         names = self._entry.fn.ro_names
-        ro = tuple([state_value(scope, n, program) for n in names])
+        ro = [state_value(scope, n, program) for n in names]
+        todo = [i for i, f in enumerate(self._formats or ())
+                if ro[i].format.layout != f.layout]
+        if todo:
+            with _compiled_here():
+                for i in todo:
+                    self._relay(ro, i)
+        ro = tuple(ro)
         # read AFTER staging (an upload cached back into the scope is a
         # write too) and BEFORE the comparison: a write that lands later
         # moves the count, one that landed earlier fails the comparison
@@ -593,6 +711,22 @@ class BoundProgram(object):
         held = all(map(operator.is_, ro, map(scope.get, names)))
         self._ro = ro
         self._ro_gen = gen if held else None
+
+    def _relay(self, ro, i):
+        """Leaf `i` of `ro` put into the entry's format, in `ro` and —
+        where the scope holds that very array — in the scope. One leaf
+        at a time, the old one let go before the next: a relaid weight
+        beside itself is HBM nobody has."""
+        name, fmt = self._entry.fn.ro_names[i], self._formats[i]
+        held = self._scope.get(name) is ro[i]
+        ro[i] = jax.block_until_ready(jax.device_put(ro[i], fmt))
+        if ro[i].format.layout != fmt.layout:
+            raise RuntimeError('%r put into %s lies as %s'
+                               % (name, fmt.layout, ro[i].format.layout))
+        if held:
+            self._scope._relay(name, ro[i])
+        monitor.inc('executor_bound_relayout_total')
+        self.relayouts += 1
 
     def __call__(self, feed, return_numpy=True):
         entry = self._entry
@@ -614,7 +748,7 @@ class BoundProgram(object):
                                self._exe._run_counter)
         else:
             key_arr = self._key0
-        flat = entry.fn.flat
+        flat = self._flat
 
         def _dispatch():
             resilience.maybe_fault('run')
@@ -1035,13 +1169,19 @@ class Executor(object):
         if hasattr(program, '_executor_run'):
             return program._executor_run(self, feed, fetch_list, scope,
                                          return_numpy, donate=donate)
-        # instrumented from here down: 'run' span + per-run wall-latency
-        # histogram (the delegating paths above recurse into run() and
-        # would double-count). The counter counts ATTEMPTS — a run that
-        # raises (nan check, bad feed) must not vanish from the rate.
-        # step_scope: a bare run with no ambient trace may start its own
-        # head-sampled 'step' trace (PADDLE_TRACE_SAMPLE); the sampled-out
-        # path costs one env read + one thread-local read + one random()
+        return self._run_plain(program, feed, fetch_list, scope,
+                               return_numpy, use_program_cache, donate)
+
+    def _run_plain(self, program, feed, fetch_list, scope, return_numpy,
+                   use_program_cache, donate, _call=None):
+        """run() of a plain Program, instrumented: 'run' span + per-run
+        wall-latency histogram (the delegating paths of run() recurse into
+        it and would double-count). The counter counts ATTEMPTS — a run
+        that raises (nan check, bad feed) must not vanish from the rate.
+        step_scope: a bare run with no ambient trace may start its own
+        head-sampled 'step' trace (PADDLE_TRACE_SAMPLE); the sampled-out
+        path costs one env read + one thread-local read + one random().
+        `_call`: see _run_impl."""
         with trace_mod.step_scope('step'):
             with monitor.timed_span('run', 'executor_run_seconds'):
                 monitor.inc('executor_run_total')
@@ -1053,7 +1193,7 @@ class Executor(object):
                                                  return_numpy)
                 return self._run_impl(program, feed, fetch_list, scope,
                                       return_numpy, use_program_cache,
-                                      donate)
+                                      donate, _call=_call)
 
     # ------------------------------------------------------------------
     def run_async(self, program=None, feed=None, fetch_list=None,
@@ -1227,9 +1367,12 @@ class Executor(object):
             return resilience.retry_after(e, _build, site='compile')
 
     def _run_impl(self, program, feed, fetch_list, scope, return_numpy,
-                  use_program_cache, donate_override=None, _sync_out=None):
+                  use_program_cache, donate_override=None, _sync_out=None,
+                  _call=None):
         """One run, in phases (_run_phase): prepare, then dispatch (or
-        compile, on a signature's first run), commit, fetch."""
+        compile, on a signature's first run), commit, fetch. `_call`
+        (Executor.bind): what is called in place of the entry's function,
+        with the entry before its arguments."""
         if scope is None:
             scope = global_scope()
         with _run_phase('prepare'):
@@ -1282,6 +1425,8 @@ class Executor(object):
                 rw_state[n] = self._state_value(scope, n, program,
                                                 cache=False)
 
+            call = entry.fn if _call is None \
+                else functools.partial(_call, entry)
             self._run_counter += 1
             key_arr = _run_key(program.random_seed,
                                _next_program_run(program),
@@ -1300,7 +1445,7 @@ class Executor(object):
                 # policy.
                 def _first_call():
                     with monitor.span('compile'):
-                        return entry.fn(feed, ro_state, rw_state, key_arr)
+                        return call(feed, ro_state, rw_state, key_arr)
                 try:
                     fetches, new_state = _first_call()
                 except Exception as e:  # noqa: BLE001 — classified inside
@@ -1324,7 +1469,7 @@ class Executor(object):
                 # guards the re-invoke)
                 def _dispatch():
                     resilience.maybe_fault('run')
-                    return entry.fn(feed, ro_state, rw_state, key_arr)
+                    return call(feed, ro_state, rw_state, key_arr)
                 t_disp = time.perf_counter()
                 try:
                     fetches, new_state = _dispatch()
@@ -1943,8 +2088,12 @@ class Executor(object):
     # ------------------------------------------------------------------
     def bind(self, program, feed, fetch_list=None, scope=None, donate=None):
         """Prepare a FIXED-SIGNATURE run for a hot dispatch loop: one
-        normal `run()` (compiling and caching as usual), then return a
-        `BoundProgram` whose calls skip the per-run key work — feed
+        normal `run()` (looking the entry up, building and caching it as
+        usual), its one call made through the `BoundProgram` this
+        returns — on a backend that offers layouts that is the handle's
+        own executable, compiled for weights laid out as it wants them
+        and staged so (see BoundProgram). The handle's calls skip the
+        per-run key work — feed
         preparation, fingerprint/signature hashing, cache lookup and span
         bookkeeping — and the staging of the state that does not change:
         the read-only names are staged here, once, and again only after
@@ -1963,36 +2112,37 @@ class Executor(object):
         run(); `donate` resolves once at bind time."""
         if scope is None:
             scope = global_scope()
-        if donate is None and analysis.nan_localization_enabled():
-            from . import flags as _flags
-            if _flags.get_flags('check_nan_inf'):
-                # mirror _run_impl's localize force-off so the key below
-                # matches the entry the run() actually cached
-                donate = False
-        first_out = self.run(program, feed=feed, fetch_list=fetch_list,
-                             scope=scope, donate=donate)
-        feed2, fetch_names, static_feed, static_lods = \
-            self._prepare_run_inputs(program, feed, scope, fetch_list,
-                                     count=False)
-        donate_flag = _donation_enabled(override=donate, record=False)
-        key = (program._fingerprint(),
-               self._feed_signature(feed2, static_lods, static_feed),
-               tuple(fetch_names), donate_flag)
-        entry = self._cache_get(key)
-        if entry is None:
-            raise RuntimeError(
-                "Executor.bind: no cached compiled entry for this "
-                "(program, feed, fetch) signature — bind() supports "
-                "host-op-free programs outside profile_ops mode only "
-                "(the run above went through a different execution path)")
         # needs_rng may be a static per-op-instance predicate (e.g.
         # fused_ffn_tail: only a train-mode op with live dropout draws a
         # key) — decode programs keep the single-PRNGKey fast path
         needs_rng = any(
             has_op(op.type) and _op_needs_rng(get_op(op.type), op)
             for block in program.blocks for op in block.ops)
-        return BoundProgram(self, entry, program, scope, needs_rng,
-                            first_out, example_feed=feed2)
+        made = []
+
+        def through_handle(entry, feed2, ro_state, rw_state, key_arr):
+            """The run's one call goes through the handle, made here, on
+            the entry the run looked up or built: what is compiled and
+            run first is the entry every later call dispatches."""
+            rw = tuple([rw_state[n] for n in entry.fn.rw_names])
+            if not made:
+                # the handle stages for itself, one leaf at a time: the
+                # run lets go of what it staged, and gets the handle's
+                ro_state.clear()
+                made.append(BoundProgram(self, entry, program, scope,
+                                         needs_rng, feed2, rw, key_arr))
+                ro_state.update(zip(entry.fn.ro_names, made[0]._ro))
+            return made[0]._flat(feed2, made[0]._ro, rw, key_arr)
+        first_out = self._run_plain(program, feed, fetch_list, scope, True,
+                                    True, donate, _call=through_handle)
+        if not made:
+            raise RuntimeError(
+                "Executor.bind: the run compiled no entry for this "
+                "(program, feed, fetch) signature — bind() supports "
+                "host-op-free programs outside profile_ops mode only "
+                "(the run above went through a different execution path)")
+        made[0].first_out = first_out
+        return made[0]
 
     # ------------------------------------------------------------------
     def precompile(self, program=None, feed_spec=None, fetch_list=None,

@@ -1045,6 +1045,25 @@ class GenerateEngine(object):
         S = self.config.slots
         reused = 0
         with monitor.span('generate.warmup'):
+            # the step first: it runs on every token, so where the
+            # backend lets a bound entry choose how its weights lie
+            # (BoundProgram), the step chooses and the prefills, bound
+            # on the same scope after it, take the weights as they lie
+            toks = np.zeros((S, 1), 'int64')
+            ints = {'gen_pos': np.zeros((S, 1), 'int64')}
+            ints.update(self._tables_feed(
+                np.zeros((S, self._max_blocks), 'int64')))
+            feed = dict(ints, gen_tokens=toks, **self._sample_feed(S))
+            fetch = [self._token_fetch(self._step_vars, 'next_tokens')]
+            key, already = farm.track(
+                self.executor, self._step_prog, feed, fetch_list=fetch,
+                scope=self.scope)
+            self._step_bound = self._bind(
+                self._step_prog, feed, fetch_list=fetch, scope=self.scope)
+            if already:
+                reused += 1
+            else:
+                farm.commit(key)
             for b, (prog, v) in sorted(self._prefill.items()):
                 # an all-zero block table points every write at the
                 # reserved trash block — warmup never touches a row a
@@ -1065,21 +1084,6 @@ class GenerateEngine(object):
                     reused += 1
                 else:
                     farm.commit(key)
-            toks = np.zeros((S, 1), 'int64')
-            ints = {'gen_pos': np.zeros((S, 1), 'int64')}
-            ints.update(self._tables_feed(
-                np.zeros((S, self._max_blocks), 'int64')))
-            feed = dict(ints, gen_tokens=toks, **self._sample_feed(S))
-            fetch = [self._token_fetch(self._step_vars, 'next_tokens')]
-            key, already = farm.track(
-                self.executor, self._step_prog, feed, fetch_list=fetch,
-                scope=self.scope)
-            self._step_bound = self._bind(
-                self._step_prog, feed, fetch_list=fetch, scope=self.scope)
-            if already:
-                reused += 1
-            else:
-                farm.commit(key)
             # the loop feeds the step from the device: its predecessor's
             # tokens as they are there and the host's feeds split there
             # (`_stage_feeds`; int32 on the device, with x64 off, where
@@ -1094,7 +1098,11 @@ class GenerateEngine(object):
             import jax.numpy as jnp
             out = self._step_bound(feed, return_numpy=False)
             self._no_prev = out[0]
-            self._first_buf = jnp.zeros((S, 1), out[0].dtype)
+            # zeros as the step's output is held (committed to its device
+            # or not): `_put_first`'s input here is what its own output is
+            # later, one signature
+            self._first_buf = jnp.zeros_like(out[0]).reshape(-1)[:S] \
+                .reshape(S, 1)
             self._put_first(0, self._prefill_bound[
                 self.config.prompt_buckets[-1]](pfeed,
                                                 return_numpy=False)[0])
@@ -1121,7 +1129,8 @@ class GenerateEngine(object):
                 'seconds': round(time.perf_counter() - t0, 3)}
 
     def _bind(self, program, feed, fetch_list, scope):
-        """`Executor.bind`, the handle kept for stats()['bound_restages']."""
+        """`Executor.bind`, the handle kept for stats()['bound_restages']
+        and ['bound_relayouts']."""
         bound = self.executor.bind(program, feed, fetch_list=fetch_list,
                                    scope=scope)
         self._handles.append(bound)
@@ -1142,6 +1151,23 @@ class GenerateEngine(object):
         # programs are never dispatched — don't pay their compiles
         prefills = {} if self._draft_copies_target else \
             self._draft_prefill
+        # the drafter before the draft's prefills, as the step before the
+        # target's (warmup): who runs every round chooses the layouts
+        feed = {'gen_tokens': np.zeros((S, 1), 'int64'),
+                'gen_pos': np.zeros((S, 1), 'int64'),
+                'gen_btab': np.zeros((S, self._max_blocks), 'int64'),
+                'gen_vmask': np.zeros((S, K + 1), 'int64')}
+        fetches = [self._drafter_vars['draft_tokens']]
+        key, already = farm.track(self.executor, self._drafter_prog,
+                                  feed, fetch_list=fetches,
+                                  scope=self._draft_scope)
+        self._drafter_bound = self._bind(
+            self._drafter_prog, feed, fetch_list=fetches,
+            scope=self._draft_scope)
+        if already:
+            reused += 1
+        else:
+            farm.commit(key)
         for b, (prog, v) in sorted(prefills.items()):
             feed = {'gen_prompt': np.zeros((1, b), 'int64'),
                     'gen_len': np.ones((1, 1), 'int64'),
@@ -1158,21 +1184,6 @@ class GenerateEngine(object):
                 reused += 1
             else:
                 farm.commit(key)
-        feed = {'gen_tokens': np.zeros((S, 1), 'int64'),
-                'gen_pos': np.zeros((S, 1), 'int64'),
-                'gen_btab': np.zeros((S, self._max_blocks), 'int64'),
-                'gen_vmask': np.zeros((S, K + 1), 'int64')}
-        fetches = [self._drafter_vars['draft_tokens']]
-        key, already = farm.track(self.executor, self._drafter_prog,
-                                  feed, fetch_list=fetches,
-                                  scope=self._draft_scope)
-        self._drafter_bound = self._bind(
-            self._drafter_prog, feed, fetch_list=fetches,
-            scope=self._draft_scope)
-        if already:
-            reused += 1
-        else:
-            farm.commit(key)
         feed = {'gen_tokens': np.zeros((S, K + 1), 'int64'),
                 'gen_pos': np.zeros((S, K + 1), 'int64'),
                 'gen_btab': np.zeros((S, self._max_blocks), 'int64'),
@@ -2610,6 +2621,10 @@ class GenerateEngine(object):
             # weights again because the scope was written: 0 in steady
             # serving, + 1 a handle after a weight is rebound
             'bound_restages': sum(b.restages for b in self._handles),
+            # leaves a handle's staging laid out anew, as its compiled
+            # entry wants them: at warmup or after a rebind, never in
+            # steady serving
+            'bound_relayouts': sum(b.relayouts for b in self._handles),
             'decode_tokens': self._decode_tokens,
             'peak_slot_occupancy': round(self._occ_peak, 4),
             'mean_slot_occupancy': round(self._occ_sum / steps, 4)

@@ -658,3 +658,49 @@ def test_a_prefill_program_holds_no_attention_scores(one_chip, monkeypatch,
     assert not scores, scores
     assert re.search(r'%?kv_prefix_attention[.\d]* = ', text)
     assert compiled.cost_analysis()['bytes accessed'] < gb * 1e9
+
+
+@pytest.mark.parametrize('width,relaid', [(1856, True), (1920, False),
+                                          (1024, False)],
+                         ids=['nemotron-1856', 'whole-lanes-1920',
+                              'olmoe-1024'])
+def test_a_bound_entry_asks_for_the_up_matrices_the_kernel_takes(
+        one_chip, width, relaid):
+    """`Executor.bind`'s entry (`StateCallable.lower_bound`: every
+    read-only leaf's layout left to the compiler) of one ungated
+    `moe_ffn`, 16 held experts of 128 over 2688, compiled for the
+    described chip (`tools/boundlayouts.py`). At Nemotron's width, 1856 =
+    14.5 lane tiles, the chip's default for ``[16, 2688, 1856]`` puts
+    2688 on the lanes and the grouped matmul's custom call wants 1856
+    there: until PR 49 a transpose of 319 MB stood in front of it in
+    every dispatch of every expert layer. The bound entry asks
+    ``major_to_minor == (0, 1, 2)`` for the up matrix and holds no copy
+    of a parameter; at 1920 and at OLMoE's 1024 every leaf keeps the
+    default."""
+    import paddle_tpu as fluid
+    from tools import boundlayouts
+
+    def build():
+        x = fluid.layers.data(name='gen_x', shape=[128, 2688],
+                              dtype='float32', append_batch_size=False)
+        out, _, _ = fluid.layers.moe_ffn(
+            x, 128, width, 6, experts_held=(0, 16), form='relu2',
+            up_param_attr=fluid.ParamAttr(name='moe.up.w'))
+        return {'out': out}
+    fn, leaves, compiled = boundlayouts.lower_bound(
+        build, 'out', 128, one_chip._device, [])
+    assert 'moe.up.w' in fn.ro_names and len(fn.ro_names) == 3
+    want = boundlayouts.relaid(fn, leaves, compiled)
+    text = compiled.as_text()
+    assert text.count('ragged-dot') >= 2
+    assert boundlayouts.weight_copies(text, leaves) == {}
+    if relaid:
+        assert list(want) == ['moe.up.w']
+        assert want['moe.up.w']['default'] == [0, 2, 1]
+        assert want['moe.up.w']['chosen'] == [0, 1, 2]
+        assert want['moe.up.w']['bytes'] == 16 * 2688 * 1856 * 4
+        at = fn.ro_names.index('moe.up.w')
+        assert compiled.input_formats[0][1][at].layout.major_to_minor \
+            == (0, 1, 2)
+    else:
+        assert want == {}

@@ -60,3 +60,45 @@ def test_scan_tells_two_pools_apart_by_their_widths():
     share, whole = poolscan.scan(SLICE_BODY + WRITE_BODY + PARENT, pools[1:], 16)
     assert (share, whole) == ({}, {})
     assert poolscan.scan(SLICE_BODY + WRITE_BODY + PARENT, pools, 16)[1] == WRITES
+
+
+# tools/boundlayouts.py `weight_copies`: the lines are XLA:TPU's, cut from
+# the device-less compile of one ungated `moe_ffn` at Nemotron's widths
+# with the default entry (PR 49's parent): the up matrices, 2688 on the
+# lanes by default, transposed in front of the grouped matmul in each
+# branch of `grouped_ffn`'s conditional.
+BRANCH = '''\
+%%branch_%d_fun.%d (arg_tuple.%d: (s32[768], f32[128,2688], f32[16,2688,1856], s32[16], f32[16,1856,2688])) -> f32[768,2688] {
+  %%arg_tuple.%d = (s32[768]{0:T(1024)}, f32[128,2688]{1,0:T(8,128)}, f32[16,2688,1856]{1,2,0:T(8,128)}, s32[16]{0:T(128)S(1)}, f32[16,1856,2688]{2,1,0:T(8,128)}) parameter(0)
+  %%get-tuple-element.5%d = f32[16,2688,1856]{1,2,0:T(8,128)} get-tuple-element(%%arg_tuple.%d), index=2
+  %%copy.%d = f32[16,2688,1856]{2,1,0:T(8,128)} copy(%%get-tuple-element.5%d), backend_config={"flag_configs":[]}
+  %%copy.1%d = s32[128,128]{1,0:T(8,128)S(1)} copy(%%get-tuple-element.6%d)
+}
+
+'''
+MOE_ENTRY = '''\
+ENTRY %main.18 (x: f32[128,2688], w_up: f32[16,2688,1856], w_down: f32[16,1856,2688]) -> f32[128,2688] {
+  %w_up = f32[16,2688,1856]{1,2,0:T(8,128)} parameter(1)
+  %w_down = f32[16,1856,2688]{2,1,0:T(8,128)} parameter(2)
+}
+'''
+
+
+class _Leaf(object):
+    def __init__(self, *shape):
+        import numpy as np
+        self.shape, self.dtype = shape, np.dtype('float32')
+
+
+@pytest.mark.parametrize('branches,least,want', [
+    (2, 1 << 20, {'f32[16, 2688, 1856]': 2}),
+    (0, 1 << 20, {}),
+    # the small copies count once nothing is too small to be a weight
+    (1, 0, {'f32[16, 2688, 1856]': 1}),
+], ids=['both-branches', 'bound-entry', 'one-branch'])
+def test_weight_copies_finds_a_weight_laid_out_anew(branches, least, want):
+    from tools import boundlayouts
+    text = ''.join(BRANCH % ((i,) * 10) for i in range(branches)) + MOE_ENTRY
+    leaves = [_Leaf(2688, 128), _Leaf(16, 2688, 1856),
+              _Leaf(16, 1856, 2688)]
+    assert boundlayouts.weight_copies(text, leaves, least) == want

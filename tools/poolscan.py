@@ -102,18 +102,17 @@ def scan(text, pool_shapes, block_size):
     return dict(share), dict(whole)
 
 
-def compiled_programs(config, traffic, layers=None, only=None, root=None):
+def cell_programs(config, traffic, layers=None, only=None, root=None):
     """(program's name, the cell's engine settings, its `LMConfig`, the
-    compiled program) for the decode step and every prefill bucket of the
-    cell `config` x `traffic` (two paths), those `only` names if given, at
-    `layers` layers if given, from the checkout `root` (this one)."""
+    program's builder, its fetch, the rows a feed has) for the decode step
+    and every prefill bucket of the cell `config` x `traffic` (two paths),
+    those `only` names if given, at `layers` layers if given, from the
+    checkout `root` (this one)."""
     root = os.path.abspath(root or os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     if root not in sys.path:
         sys.path.insert(0, root)
-    from benchmark import size_serve_pools
     from benchmark.run import find_file, load_json, load_module
-    from jax.experimental import topologies
     from paddle_tpu.models import transformer as T
     m, e = load_json(config), load_json(traffic)['engine']
     manifest = load_json(os.path.join(root, 'BENCHMARK.json'))
@@ -121,8 +120,6 @@ def compiled_programs(config, traffic, layers=None, only=None, root=None):
     cfg = model.lm_config(m, int(e['max_len']), False)
     if layers:
         cfg.n_layer = layers
-    device = topologies.get_topology_desc(
-        platform='tpu', topology_name='v5e:2x2').devices[0]
     programs = [('decode_step', lambda: T.build_lm_decode_step(
         cfg, e['slots'], e['max_len'], block_size=e['block_size'],
         num_blocks=e['num_blocks']), 'next_tokens', e['slots'])]
@@ -133,8 +130,29 @@ def compiled_programs(config, traffic, layers=None, only=None, root=None):
                 e['max_len'] // e['block_size'], slots=e['slots'])),
             'first_token', 1))
     for key, build, fetch, rows in programs:
-        if only and key not in only:
-            continue
+        if not only or key in only:
+            yield key, e, cfg, build, fetch, rows
+
+
+def one_chip():
+    """One chip of the device-less `v5e:2x2` topology (benchmark.size_serve
+    sets the environment the description needs as it is imported)."""
+    from benchmark import size_serve                        # noqa: F401
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(
+        platform='tpu', topology_name='v5e:2x2').devices[0]
+
+
+def compiled_programs(config, traffic, layers=None, only=None, root=None):
+    """`cell_programs`, each compiled for one chip: (program's name, the
+    engine settings, the `LMConfig`, the compiled program)."""
+    device = None
+    for key, e, cfg, build, fetch, rows in cell_programs(
+            config, traffic, layers, only, root):
+        # importable once `cell_programs` has put `root` on the path
+        from benchmark import size_serve_pools
+        from paddle_tpu.models import transformer as T
+        device = device or one_chip()
         yield key, e, cfg, size_serve_pools.compiled_program(
             build, fetch, rows, device, T.kv_cache_names(cfg))
 
