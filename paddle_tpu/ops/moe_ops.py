@@ -35,6 +35,19 @@ to the Program path (OLMoE: models/transformer.py, LMConfig(norm=
   (read on the chip and in the compiled program's FLOPs: PERF.md, PR 28).
   On the CPU it lowers to masked dense matmuls — the tests' toy widths.
 
+  The kernel is XLA's, the tiles are ours (`grouped_matmul_tiling`, PR
+  50): each `ragged_dot` states `ragged_dot_tiling="tm,tk,tn"` from its
+  own rows, K, N and the precision in force. Left alone XLA takes the
+  largest of 512 / 256 / 128 that DIVIDES each dimension (Nemotron's 2688
+  x 1856: 128 x 128, 64 KB a grid step). The rule leaves XLA its choice
+  where that moves 1 MB a step over no more rows than the MXU hides;
+  else tm = the largest divisor of the rows whose MXU time stays under
+  the matrix's bytes' time (80 rows at `highest`, at most 256), and of
+  the (tk | K or K whole, tn in 128s) blocks inside 10 MB of VMEM the one
+  with the least padding of N, then the fewest steps. The backward's
+  grouped matmuls state nothing. Counted at lowering:
+  `moe_grouped_matmul_tiling_total{tiling="tm,tk,tn"|"xla"}`.
+
   The DeepSeek-V3 router (``score='sigmoid'``): ``s = sigmoid(x @
   RouterW)``, the top_k largest of ``s + SelectBias`` (the bias chooses
   only), weights ``s_e`` of the chosen, divided by ``sum + router_eps``
@@ -57,9 +70,12 @@ to the Program path (OLMoE: models/transformer.py, LMConfig(norm=
   bucket's pad rows by ``Length`` (rows at or past it), a decode step's
   idle slots by ``Valid`` (zero = idle).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from ..core.registry import register_op
 
@@ -130,6 +146,119 @@ def route(x, router_w, top_k, norm_topk_prob, score='softmax',
     return w * routed_scale, idx.astype(jnp.int32)
 
 
+# The tiles of XLA:TPU's grouped matmul (`lax.ragged_dot`'s Mosaic kernel):
+# a grid step multiplies a [tm, tk] block of rows by a [tk, tn] block of ONE
+# group's matrix; a (row tile, group) pair streams the group's whole [K, N]
+# matrix in K/tk x N/tn steps and multiplies ALL tm rows by it. Left alone
+# XLA takes each of tm, tk, tn from `_XLA_TILES`, the largest that DIVIDES
+# its dimension; `frontend_attributes={ragged_dot_tiling="tm,tk,tn"}` on the
+# op states another and XLA takes it (tm | rows; tk | K in multiples of 128,
+# or K whole; tn a multiple of 128, a partial last tile is fine). The
+# constants are the v5e's, from the sweep `tools/kernbench.py --cases
+# grouped_matmul --size bench` (PERF.md, PR 50; ms a call, float32 weights):
+_XLA_TILES = (512, 256, 128)
+# a weight block from which a grid step's fixed cost no longer shows: XLA's
+# own 512 x 512 (1 MB) streams at 687-695 GB/s (OLMoE, K-EXAONE: no stated
+# tiling beat it by over 2.6 %), its 512 x 256 at 547-567 (LFM2 up / down,
+# JoyAI up / down: 0.86 / 0.82 / 0.67 / 0.56 ms -> 0.66 / 0.68 / 0.54 / 0.46
+# at 1.5-3 MB), its 128 x 128 (Nemotron: 2688 = 21 x 128, 1856 = 14.5 x
+# 128; 64 KB, 5 040 steps a matmul) at 125 (2.57 / 2.57 ms -> 0.56 / 0.50)
+_MIN_WEIGHT_BLOCK = 1 << 20
+# the double-buffered row and weight blocks, the output block and its
+# accumulator, of the 16 MB a kernel has (12.3 MB compiled, ~12.5 did not)
+_TILE_VMEM_BYTES = 10 << 20
+# the chip's peaks (benchmark/peaks.json's): a pair's MXU time, `passes`
+# passes a float32 product, stays under the time its matrix's bytes take --
+# 80 rows at `highest`, 481 at the default. Nemotron's up matmul at
+# `highest`, tm 256 / 128 / 64 / 32 over the same tk, tn: 1.53 / 0.76 /
+# 0.57 / 0.57 ms
+_MXU_FLOPS = 197e12
+_HBM_BYTES_PER_S = 819e9
+# and never more rows a tile than leave the weight block its VMEM: JoyAI's
+# widest prefill (6 144 rows, one pass), tm 512 (XLA's) / 384 / 256: 1.07 /
+# 0.75 / 0.71 ms; OLMoE's b128 (1 024 rows) 512 / 256: 1.02 / 0.78
+_MAX_ROW_TILE = 256
+_PASSES = {'highest': 6, 'float32': 6, 'high': 3, 'bfloat16_3x': 3}
+
+
+def matmul_passes():
+    """MXU passes a float32 product takes at the precision in force
+    (`jax.default_matmul_precision`, which `Program.matmul_precision`
+    sets round a program's lowering)."""
+    return _PASSES.get(jax.config.jax_default_matmul_precision, 1)
+
+
+def grouped_matmul_tiling(rows, k, n, passes):
+    """(tm, tk, tn) for a `lax.ragged_dot` of `rows` rows GIVEN against
+    groups of `[k, n]` float32 matrices at `passes` MXU passes a product,
+    or None: nothing stated, XLA's own choice -- where that already moves
+    `_MIN_WEIGHT_BLOCK` a step over no more rows than the MXU hides, and
+    where the shapes leave no whole tile to state."""
+    if rows % 8 or k % 8 or n % 8 or min(k, n) < 128:
+        return None
+    most = 4 * _MXU_FLOPS / (2 * passes * _HBM_BYTES_PER_S)
+    own = [next((t for t in _XLA_TILES if d % t == 0), d)
+           for d in (rows, k, n)]
+    if own[0] <= most and 4 * own[1] * own[2] >= _MIN_WEIGHT_BLOCK:
+        return None
+    tm = max(t for t in range(8, min(rows, _MAX_ROW_TILE) + 1, 8)
+             if rows % t == 0 and (t <= most or t == 8))
+    best = None
+    for tk in [k] + [t for t in range(128, k, 128) if k % t == 0]:
+        for tn in range(128, n + 128, 128):
+            if 4 * (2 * (tm * tk + tk * tn) + 2 * tm * tn) \
+                    > _TILE_VMEM_BYTES:
+                continue
+            # the least of N's padding (a partial tile is multiplied
+            # whole), then the fewest grid steps a pair
+            tiles = -(-n // tn)
+            key = (tiles * tn, (k // tk) * tiles)
+            if best is None or key < best[0]:
+                best = (key, (tm, tk, tn))
+    return best and best[1]
+
+
+def tiling_label(tiling):
+    """'tm,tk,tn' as XLA reads and prints it; 'xla' for None."""
+    return '%d,%d,%d' % tiling if tiling else 'xla'
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tiled_ragged_dot(xs, mat, sizes, tiling):
+    with set_xla_metadata(ragged_dot_tiling=tiling_label(tiling)):
+        return lax.ragged_dot(xs, mat, sizes)
+
+
+def _tiled_fwd(xs, mat, sizes, tiling):
+    return _tiled_ragged_dot(xs, mat, sizes, tiling), (xs, mat, sizes)
+
+
+def _tiled_bwd(tiling, saved, g):
+    # the backward's two grouped matmuls have other shapes: they state
+    # nothing (an equation differentiated under the attribute inherits it)
+    xs, mat, sizes = saved
+    _, vjp = jax.vjp(lambda a, b: lax.ragged_dot(a, b, sizes), xs, mat)
+    return vjp(g) + (None,)
+
+
+_tiled_ragged_dot.defvjp(_tiled_fwd, _tiled_bwd)
+
+
+def grouped_matmul(xs, mat, sizes):
+    """`lax.ragged_dot(xs [rows, K], mat [E, K, N], sizes)` with the tiling
+    `grouped_matmul_tiling` states for these shapes, if it states one."""
+    from .. import monitor
+    tiling = None
+    if xs.dtype == mat.dtype == jnp.float32:    # the rule's bytes, the sweep's
+        tiling = grouped_matmul_tiling(xs.shape[0], mat.shape[1],
+                                       mat.shape[2], matmul_passes())
+    monitor.inc('moe_grouped_matmul_tiling_total',
+                labels={'tiling': tiling_label(tiling)})
+    if tiling is None:
+        return lax.ragged_dot(xs, mat, sizes)
+    return _tiled_ragged_dot(xs, mat, sizes, tiling)
+
+
 def grouped_ffn(x, w, idx, gate_w, up_w, down_w, first=None, routed=None):
     """sum_j w[n, j] * FFN_{idx[n, j]}(x[n]) through three ragged_dots
     over the assignments sorted by expert; with `gate_w` None the
@@ -151,10 +280,10 @@ def grouped_ffn(x, w, idx, gate_w, up_w, down_w, first=None, routed=None):
         """The first `rows` sorted assignments through the experts:
         [rows, d], sorted."""
         xs = x[(order if rows == n * k else order[:rows]) // k]
-        up = lax.ragged_dot(xs, up_w, sizes)
+        up = grouped_matmul(xs, up_w, sizes)
         h = jnp.square(jax.nn.relu(up)) if gate_w is None \
-            else jax.nn.silu(lax.ragged_dot(xs, gate_w, sizes)) * up
-        return lax.ragged_dot(h, down_w, sizes)
+            else jax.nn.silu(grouped_matmul(xs, gate_w, sizes)) * up
+        return grouped_matmul(h, down_w, sizes)
 
     if first is None:
         y = experts(n * k)

@@ -24,10 +24,21 @@ and the in-place DMA kernel it was chosen over (`gather_in_place`, kept
 here only) alone, at the benchmark cells' tables and row counts with
 `--size bench`: ms a call and GB/s, a candidate a column.
 
+The `grouped_matmul` case is none either: `jax.lax.ragged_dot` alone (the
+expert matmul of `ops/moe_ops.py`'s `grouped_ffn`) at the expert cells'
+shapes and group sizes with `--size bench`, a tiling a column — XLA's
+own choice, the rule's (`grouped_matmul_tiling`) and the candidates of
+`--tilings`: ms a call, GB/s of the weights of the groups that hold a row,
+and the largest error against a float64 product. The sweep that set the
+rule's constants (PERF.md, PR 50), to be run again on another chip or
+another libtpu.
+
 Usage: python tools/kernbench.py [--tiers off,xla,interpret]
-       [--cases softmax_ce,fused_adam,embedding_gather,
+       [--cases softmax_ce,fused_adam,embedding_gather,grouped_matmul,
                 layernorm_residual,ffn_tail,ln_sites]
        [--rounds 5] [--size small|bench] [--mesh N]
+       [--tilings 64,896,512:32,896,512]
+       [--shapes 'nemotron up,nemotron down']
        (prints one JSON line)
 
 On CPU the 'pallas' tier runs through the interpreter (pass 'interpret');
@@ -36,6 +47,7 @@ kernels dispatch and to carry the analytics columns. Real pallas timing
 needs the TPU box (tools/tpu_smoke.py environment).
 """
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -205,6 +217,124 @@ def measure_embedding_gather(size, rounds, k, candidates=None):
     return out
 
 
+# the expert cells' grouped matmuls: (rows GIVEN, groups, K, N, the
+# precision the cell's programs multiply at, assignments that fall in a
+# group). Rows given = `grouped_ffn`'s `cap` (or all n * k assignments
+# where every expert is held); a share of the experts gets its share of
+# the assignments, the rest of the rows lie in no group.
+GROUPED_MATMULS = {
+    'small': {'toy up': (16, 4, 128, 256, 'highest', 8),
+              'toy down': (16, 4, 256, 128, None, 16)},
+    'bench': {
+        # nemotron3-serve-reason128: 16 of 128 experts held, 6 a row; the
+        # step and b128 give 768 assignments (cap 256), b256 / b512 cap 384
+        # / 640; 'all' the other branch of the cond, '128' a cap of one tile
+        'nemotron up': (256, 16, 2688, 1856, 'highest', 96),
+        'nemotron down': (256, 16, 1856, 2688, 'highest', 96),
+        'nemotron up b256': (384, 16, 2688, 1856, 'highest', 192),
+        'nemotron up b512': (640, 16, 2688, 1856, 'highest', 384),
+        'nemotron down b512': (640, 16, 1856, 2688, 'highest', 384),
+        'nemotron up all': (768, 16, 2688, 1856, 'highest', 96),
+        'nemotron up 128': (128, 16, 2688, 1856, 'highest', 96),
+        # lfm2-serve-agent64: all 32 experts, 4 a row
+        'lfm2 up': (256, 32, 2048, 1792, None, 256),
+        'lfm2 down': (256, 32, 1792, 2048, None, 256),
+        'lfm2 up b128': (512, 32, 2048, 1792, None, 512),
+        'lfm2 up b512': (2048, 32, 2048, 1792, None, 2048),
+        'lfm2 down b512': (2048, 32, 1792, 2048, None, 2048),
+        # joyai-serve-longchat64: 64 of 256 held, 8 a row
+        'joyai up': (256, 64, 2048, 768, None, 128),
+        'joyai down': (256, 64, 768, 2048, None, 128),
+        'joyai up all': (512, 64, 2048, 768, None, 128),
+        'joyai up b512': (1536, 64, 2048, 768, None, 1024),
+        'joyai up b2048': (6144, 64, 2048, 768, None, 4096),
+        'joyai down b2048': (6144, 64, 768, 2048, None, 4096),
+        # kexaone-serve-mixed64: 8 of 128 held, 8 a row
+        'kexaone up': (128, 8, 6144, 2048, None, 32),
+        'kexaone down': (128, 8, 2048, 6144, None, 32),
+        'kexaone up b512': (384, 8, 6144, 2048, None, 256),
+        'kexaone up all': (512, 8, 6144, 2048, None, 32),
+        # olmoe-serve-chat16: all 64 experts, 8 a row
+        'olmoe up': (128, 64, 2048, 1024, None, 128),
+        'olmoe down': (128, 64, 1024, 2048, None, 128),
+        'olmoe up b128': (1024, 64, 2048, 1024, None, 1024),
+        'olmoe down b128': (1024, 64, 1024, 2048, None, 1024),
+        'olmoe up b768': (6144, 64, 2048, 1024, None, 6144),
+        'olmoe down b768': (6144, 64, 1024, 2048, None, 6144)}}
+
+
+def measure_grouped_matmul(size, rounds, k, tilings=(), shapes=None):
+    """`lax.ragged_dot` alone at each of the cells' shapes, XLA's tiling
+    ('xla'), the rule's ('rule: tm,tk,tn', where it states one) and each
+    of `tilings` ('tm,tk,tn' strings): ms a call (best of `rounds` runs
+    of ONE program that makes `k` dependent calls), GB/s of the weights
+    of the groups that hold a row, and the largest |error| against the
+    float64 product of the same operands over the rows in a group, as a
+    share of the largest |product|. `shapes`: only these labels."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental.xla_metadata import set_xla_metadata
+    from paddle_tpu.ops.moe_ops import (grouped_matmul_tiling, matmul_passes,
+                                        tiling_label)
+    out = {}
+    for label, (rows, groups, kk, n, precision, held) in \
+            GROUPED_MATMULS[size].items():
+        if shapes and label not in shapes:
+            continue
+        rng = np.random.RandomState(len(label))
+        sizes = np.bincount(rng.randint(0, groups, held), minlength=groups)
+        x = jax.random.normal(jax.random.PRNGKey(1), (rows, kk), jnp.float32)
+        w = jax.random.normal(jax.random.PRNGKey(2), (groups, kk, n),
+                              jnp.float32) * kk ** -0.5
+        gs = jnp.asarray(sizes.astype('int32'))
+        xh, ends = np.asarray(x, np.float64), np.cumsum(sizes)
+        want = np.concatenate(
+            [xh[e - c:e] @ np.asarray(w[g], np.float64)
+             for g, (c, e) in enumerate(zip(sizes, ends)) if c])
+        touched = int(np.count_nonzero(sizes)) * kk * n * 4
+        with jax.default_matmul_precision(precision) if precision \
+                else contextlib.nullcontext():
+            rule = grouped_matmul_tiling(rows, kk, n, matmul_passes())
+            named = [('xla', None)]
+            if rule:
+                named.append(('rule: ' + tiling_label(rule),
+                              tiling_label(rule)))
+            named += [(t, t) for t in tilings]
+            row = out.setdefault('%s %d x [%d, %d, %d] %s' % (
+                label, rows, groups, kk, n, precision or 'default'), {})
+            for name, tiling in named:
+                def dot(x, w, gs, tiling=tiling):
+                    with set_xla_metadata(ragged_dot_tiling=tiling) \
+                            if tiling else contextlib.nullcontext():
+                        return lax.ragged_dot(x, w, gs)
+
+                def calls(x, w, gs, dot=dot):
+                    def body(i, acc):
+                        # the next call's rows wait for this call's
+                        return dot(x + (acc[0, 0] != acc[0, 0]), w, gs)
+                    return lax.fori_loop(0, k, body, dot(x, w, gs))
+                try:
+                    got = np.asarray(jax.jit(dot)(x, w, gs))[:int(ends[-1])]
+                    loop = jax.jit(calls)
+                    loop(x, w, gs).block_until_ready()
+                    best = float('inf')
+                    for _ in range(rounds):
+                        t0 = time.perf_counter()
+                        loop(x, w, gs).block_until_ready()
+                        best = min(best, (time.perf_counter() - t0) / (k + 1))
+                    row[name] = {
+                        'ms': round(best * 1e3, 4),
+                        'gb_per_s': round(touched / best / 1e9, 1),
+                        'max_err': float(np.max(np.abs(got - want))
+                                         / np.max(np.abs(want)))}
+                except Exception as e:      # noqa: BLE001 — advisory tool
+                    row[name] = {'error': '%s: %s' % (
+                        type(e).__name__, str(e)[:200])}
+    return out
+
+
 def _build_layernorm_residual(size):
     import numpy as np
     import paddle_tpu as fluid
@@ -277,7 +407,7 @@ _CASES = {
     'ln_sites': _build_ln_sites,
 }
 # every case by name: the tier comparisons and the lookup's candidates
-_CASE_NAMES = list(_CASES) + ['embedding_gather']
+_CASE_NAMES = list(_CASES) + ['embedding_gather', 'grouped_matmul']
 
 
 def _measure(build, tier, rounds, k, size, mesh_n=1):
@@ -344,7 +474,7 @@ def _measure(build, tier, rounds, k, size, mesh_n=1):
 
 
 def measure_kernbench(cases=None, tiers=None, rounds=5, k=10,
-                      size='small', mesh=1):
+                      size='small', mesh=1, tilings=(), shapes=None):
     """Importable entry (the tier-1 smoke test runs one tiny case;
     ``mesh=N`` runs every case through a mesh(data=N) MeshRunner so the
     partitioned fused kernels are what gets timed)."""
@@ -355,6 +485,10 @@ def measure_kernbench(cases=None, tiers=None, rounds=5, k=10,
     for case in cases:
         if case == 'embedding_gather':      # candidates, not tiers
             out[case] = measure_embedding_gather(size, rounds, k)
+            continue
+        if case == 'grouped_matmul':        # tilings, not tiers
+            out[case] = measure_grouped_matmul(size, rounds, k, tilings,
+                                               shapes)
             continue
         out[case] = {}
         for tier in tiers:
@@ -390,6 +524,11 @@ def main():
                     choices=('small', 'bench'))
     ap.add_argument('--mesh', type=int, default=1,
                     help='run each case SPMD over mesh(data=N)')
+    ap.add_argument('--tilings', default='',
+                    help="grouped_matmul: 'tm,tk,tn' candidates, ':' between")
+    ap.add_argument('--shapes', default='',
+                    help='grouped_matmul: only these labels of '
+                         'GROUPED_MATMULS (comma between)')
     args = ap.parse_args()
     if args.mesh > 1 and 'jax' not in sys.modules and \
             '--xla_force_host_platform_device_count' not in \
@@ -404,7 +543,9 @@ def main():
             % max(8, args.mesh)).strip()
     res = measure_kernbench(args.cases.split(','), args.tiers.split(','),
                             args.rounds, args.k, args.size,
-                            mesh=args.mesh)
+                            mesh=args.mesh,
+                            tilings=[t for t in args.tilings.split(':') if t],
+                            shapes=[t for t in args.shapes.split(',') if t])
     print(json.dumps(res))
 
 
