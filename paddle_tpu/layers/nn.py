@@ -1,6 +1,8 @@
 """Neural-net layers (reference python/paddle/fluid/layers/nn.py:36, ~190
 layers). Each builder appends op descs + infers static output shapes; the real
 computation is the registered jax lowering (paddle_tpu/ops/*)."""
+import math
+
 import numpy as np
 
 from ..layer_helper import LayerHelper
@@ -600,17 +602,32 @@ def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
 
 
 def rotary_embedding(input, positions, theta=10000.0, interleave=False,
-                     name=None):
+                     name=None, factor=None, original_max_position=None,
+                     beta_fast=32.0, beta_slow=1.0, attention_factor=None):
     """Rotary position embedding of ``input [..., H, dh]`` by the int64
     ``positions`` (one per leading row of ``input``), ``rotate_half``
     convention, or with ``interleave`` the pairs ``(2i, 2i + 1)``;
-    ``inv_freq = theta^(-2i/dh)`` (ops/moe_ops.py)."""
+    ``inv_freq = theta^(-2i/dh)`` (ops/moe_ops.py). With ``factor`` YaRN's
+    table, made when the program is traced (`moe_ops.yarn_inv_freq`): the
+    frequencies that turn fewer than ``beta_slow`` times in
+    ``original_max_position`` positions divided by ``factor``, those that
+    turn more than ``beta_fast`` times kept, a ramp between; cos and sin
+    times ``attention_factor`` (None: ``0.1 ln(factor) + 1``)."""
     helper = LayerHelper('rotary_embedding', name=name)
     out = helper.create_variable_for_type_inference(input.dtype,
                                                     shape=input.shape)
     attrs = {'theta': float(theta)}
     if interleave:
         attrs['interleave'] = True
+    if factor is not None:
+        # absent where unset: a program without YaRN is what it was
+        attrs.update(
+            yarn_factor=float(factor),
+            yarn_original_max_position=int(original_max_position),
+            yarn_beta_fast=float(beta_fast), yarn_beta_slow=float(beta_slow),
+            yarn_attention_factor=float(
+                0.1 * math.log(factor) + 1.0 if attention_factor is None
+                else attention_factor))
     helper.append_op(type='rotary_embedding',
                      inputs={'X': [input], 'Positions': [positions]},
                      outputs={'Out': [out]}, attrs=attrs)

@@ -63,10 +63,14 @@ class LMConfig(object):
       (K-EXAONE's, EXAONE 4.0's local layers). It keeps K and V pools of
       its own, under block ids of its own: a slot's FEW blocks there are a
       ring (logical block ``b`` lies in column ``b % ring`` of the slot's
-      window table, `window_ring`), so a page behind the window is written
-      over and the pools do not grow with the context. With
+      window table, `window_ring`), so a page behind the window is handed
+      on and the pools do not grow with the context. With
       ``global_rope=False`` the ``'attention'`` (global) layers rotate
-      nothing (NoPE) and only the window layers are rotated. A
+      nothing (NoPE) and only the window layers are rotated; with
+      ``attention_rope`` (keys of `ROPE_KEYS`) they rotate by parameters
+      of their own: another ``theta``, or YaRN's ``factor``,
+      ``original_max_position``, ``beta_fast``, ``beta_slow`` and
+      ``attention_factor`` (`layers.rotary_embedding`). A
       ``'conv'`` layer's mixer is LFM2's gated short convolution
       (``[B | C | u] = z W_in``; ``y = (C * conv(B * u)) W_out``, a
       causal depthwise convolution of ``conv_kernel`` taps, no bias); it
@@ -142,7 +146,7 @@ class LMConfig(object):
                  ssm_state=16, ssm_conv=4, ssm_dt_rank=None, ssm_heads=0,
                  ssm_head_dim=0, ssm_groups=1, ssm_chunk=128,
                  expert_form='gated', shared_expert_width=None,
-                 matmul_precision=None):
+                 matmul_precision=None, attention_rope=None):
         self.vocab_size = vocab_size
         self.seq_len = seq_len
         self.d_model = d_model
@@ -216,6 +220,10 @@ class LMConfig(object):
         self.ssm_groups = int(ssm_groups)
         self.ssm_chunk = int(ssm_chunk)
         self.matmul_precision = matmul_precision
+        self.attention_rope = dict(attention_rope or {})
+        if set(self.attention_rope) - set(ROPE_KEYS):
+            raise ValueError('LMConfig.attention_rope=%r: expected keys of '
+                             '%r' % (attention_rope, ROPE_KEYS))
         if len(self.layer_types) != n_layer or set(self.layer_types) - {
                 'attention', 'conv', 'window', 'ssm', 'ssd', 'ffn'}:
             raise ValueError("LMConfig.layer_types=%r: expected %d of "
@@ -319,6 +327,15 @@ class LMConfig(object):
         return self.position == 'rope' and (
             self.global_rope or self.layer_types[layer] == 'window')
 
+    def rope(self, layer):
+        """The rotation of `layer`'s q and k, as `layers.rotary_embedding`
+        takes it: ``rope_theta`` and nothing else, but for an 'attention'
+        layer of a model that gives those layers a rotation of their own
+        (`attention_rope`)."""
+        own = self.attention_rope \
+            if self.layer_types[layer] == 'attention' else {}
+        return dict({'theta': self.rope_theta}, **own)
+
     def layer_ordinal(self, layer):
         """`layer`'s place among the layers of its kind: the `layer`
         attribute of its cache ops (a pool holds one kind only)."""
@@ -335,6 +352,11 @@ class LMConfig(object):
         return sum(self.has_ffn(i) for i in range(
             self.n_dense_layers, self.n_layer)) if self.ffn == 'moe' else 0
 
+
+# `LMConfig.attention_rope`: the 'attention' layers' own base, and YaRN's
+# parameters (`layers.rotary_embedding`)
+ROPE_KEYS = ('theta', 'factor', 'original_max_position', 'beta_fast',
+             'beta_slow', 'attention_factor')
 
 _CLASSIC_BLOCK = (('norm', 'layer_norm'), ('position', 'sinusoid'),
                   ('qk_norm', False), ('bias', True), ('ffn', 'gelu'),
@@ -460,7 +482,8 @@ def _heads_of(cfg, flat, p, which, pos, T, rotate):
     [1, T, H*dh] in a prefill; H the K/V heads for k and v): the optional
     q/k-norm (over the whole width before the split into heads, or over
     each head after it), the rotation by the fed positions where the
-    layer `rotate`s; laid out as the cache ops want it ([S, H, dh];
+    layer rotates (`rotate`: its rotation's parameters, `LMConfig.rope`);
+    laid out as the cache ops want it ([S, H, dh];
     [1, H, T, dh])."""
     h = cfg.n_head if which == 'q' else cfg.n_kv_head
     dh = cfg.head_dim
@@ -476,7 +499,7 @@ def _heads_of(cfg, flat, p, which, pos, T, rotate):
         x = layers.rms_norm(x, begin_norm_axis=2 if rows else 3,
                             epsilon=cfg.rms_eps, param_attr=norm_attr)
     if rotate and which != 'v':
-        x = layers.rotary_embedding(x, pos, theta=cfg.rope_theta)
+        x = layers.rotary_embedding(x, pos, **rotate)
     return x if rows else layers.transpose(x, perm=[0, 2, 1, 3])
 
 
@@ -507,7 +530,8 @@ def _qkv(cfg, ln1, p, pos, T=None, layer=0):
     axis = 1 if T is None else 2
     return [_heads_of(cfg, layers.slice(qkv, axes=[axis], starts=[start],
                                         ends=[end]),
-                      p, which, pos, T, cfg.rotates(layer))
+                      p, which, pos, T,
+                      cfg.rotates(layer) and cfg.rope(layer))
             for which, start, end in zip('qkv', [0] + ends, ends)]
 
 
@@ -936,10 +960,15 @@ def window_ring(cfg, block_size):
     return -(-cfg.sliding_window // block_size) + 2
 
 
-def window_pool_blocks(cfg, slots, block_size):
+def window_pool_blocks(cfg, slots, block_size, shared=False):
     """Blocks of the window layers' pools: every slot's ring, and block 0,
-    the trash block (an idle slot's table row is all zero)."""
-    return slots * window_ring(cfg, block_size) + 1
+    the trash block (an idle slot's table row is all zero). `shared` (the
+    engine shares prefixes): and half a ring a slot for the blocks that the
+    prefix cache alone holds -- a prefix's resume point needs ``ring - 2``
+    of them (`Pool.reach`), so half the slots can each have come from a
+    prefix of their own before the cache lets one go."""
+    rings = slots * window_ring(cfg, block_size)
+    return rings + 1 + (rings // 2 if shared else 0)
 
 
 # A pool of a model's programs, one row of `cache_pools`' table. `index`:
@@ -952,17 +981,23 @@ def window_pool_blocks(cfg, slots, block_size):
 # `books`: the series the engine books for the pool's ``shape[1]`` layers,
 # once a kind -- 'step': (series, rows a slot a decode step reads at most;
 # None: all up to its position); 'prefill': the REAL rows a prefill walks;
-# 'resume': the dispatches that resume past position 0 from what it holds.
-Pool = collections.namedtuple('Pool',
-                              'name shape index rewinds copies why books')
+# 'resume': the dispatches that resume past position 0 from what it holds;
+# 'hit': the admissions that resumed at a shared prefix's edge over it.
+# `reach`: the rows behind a position that a query there still reads of the
+# pool (None: every row, or a state) -- what a request that resumes at a
+# shared prefix's edge has to find; `shares`: it can (`prefix_sharing`).
+Pool = collections.namedtuple(
+    'Pool', 'name shape index rewinds copies why books reach shares')
 INDEX_FEEDS = {'block': 'gen_btab', 'ring': 'gen_wtab', 'row': 'gen_srow'}
 
 
-def cache_pools(cfg, num_blocks=0, block_size=1, slots=None):
+def cache_pools(cfg, num_blocks=0, block_size=1, slots=None, shared=False):
     """The pools a model's programs declare, in the order of their state:
     the ONE place that maps a layer kind to a pool, and all an engine knows
     of a kind (serving/generate.py walks it). A pool the slots size has
-    shape None where ``slots`` is not given.
+    shape None where ``slots`` is not given. `shared`: the engine shares
+    prefixes, and a pool whose blocks its prefix cache holds beside the
+    slots' has room for them (`window_pool_blocks`).
 
     Indexed by the block allocator's ids: K and V apart, the GLOBAL
     attention layers' pages, or with latent attention the ONE pool of
@@ -978,11 +1013,12 @@ def cache_pools(cfg, num_blocks=0, block_size=1, slots=None):
     round) and the tail over all the convolution's channels."""
     pools = []
 
-    def kind(shapes, index, rewinds, copies, why=None, **books):
+    def kind(shapes, index, rewinds, copies, why=None, reach=None, **books):
         sized = index == 'block' or slots is not None
         for i, (name, shape) in enumerate(shapes):
             pools.append(Pool(name, shape if sized else None, index, rewinds,
-                              copies, why, {} if i else books))
+                              copies, why, {} if i else books, reach,
+                              index != 'row'))
 
     latent = cfg.attention == 'mla'
     n = slots or 0
@@ -999,14 +1035,18 @@ def cache_pools(cfg, num_blocks=0, block_size=1, slots=None):
              "rows written, not every row)",
              resume='conv_tail_resumes_total')
     if cfg.n_window_layers:
-        kv = (window_pool_blocks(cfg, n, block_size), cfg.n_window_layers,
-              block_size, cfg.kv_width)
+        kv = (window_pool_blocks(cfg, n, block_size, shared),
+              cfg.n_window_layers, block_size, cfg.kv_width)
         kind([(WINDOW_CACHE_K, kv), (WINDOW_CACHE_V, kv)], 'ring', False,
              False,
-             "a window layer's blocks are a ring that its slot writes over "
-             "-- a rejected draft cannot be unwound from it, and a shared "
-             "block's window rows are gone once its first tenant has moved "
-             "on", step=('kv_window_tokens_read_total', cfg.sliding_window))
+             "a window layer's blocks are a ring whose columns its slot "
+             "hands on -- a rejected draft cannot be unwound from it (a "
+             "block the window left behind may be another tenant's by "
+             "then); a shared prefix is resumed from a block's edge, where "
+             "the prefix cache still holds the window's blocks before it",
+             reach=cfg.sliding_window - 1,
+             step=('kv_window_tokens_read_total', cfg.sliding_window),
+             hit='kv_window_prefix_resumes_total')
     if cfg.n_ssm_layers:
         kind([(SSM_STATE, (n + 1, cfg.n_ssm_layers, cfg.ssm_state,
                            cfg.ssm_inner)),
@@ -1037,10 +1077,10 @@ def kv_cache_names(cfg):
     return tuple(pool.name for pool in cache_pools(cfg))
 
 
-def kv_cache_shapes(cfg, num_blocks, block_size, slots=None):
+def kv_cache_shapes(cfg, num_blocks, block_size, slots=None, shared=False):
     """name -> shape of every pool of `cache_pools`; the window and the
     state-space layers' are sized by the engine's ``slots`` alone."""
-    pools = cache_pools(cfg, num_blocks, block_size, slots)
+    pools = cache_pools(cfg, num_blocks, block_size, slots, shared)
     if any(pool.shape is None for pool in pools):
         raise ValueError("LMConfig.layer_types=%r: the window and the "
                          "state-space layers' pools are sized by the slots"
@@ -1048,12 +1088,13 @@ def kv_cache_shapes(cfg, num_blocks, block_size, slots=None):
     return {pool.name: pool.shape for pool in pools}
 
 
-def _declare_paged_kv_caches(block, cfg, num_blocks, block_size, slots=None):
+def _declare_paged_kv_caches(block, cfg, num_blocks, block_size, slots=None,
+                             shared=False):
     """name -> var of every pool of `cache_pools`."""
     return {name: block.create_var(name=name, shape=shape, dtype='float32',
                                    persistable=True, stop_gradient=True)
             for name, shape in kv_cache_shapes(cfg, num_blocks, block_size,
-                                               slots).items()}
+                                               slots, shared).items()}
 
 
 def _slot_feeds(cfg, block_size):
@@ -1161,8 +1202,10 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
     return _lm_head(cfg, x)                                  # [S, V]
 
 
-def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
-    """Single-token decode step over ALL cache slots.
+def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks,
+                         shared=False):
+    """Single-token decode step over ALL cache slots. `shared`: the engine
+    shares prefixes (`cache_pools`: the size of the window layers' pools).
 
     Feeds: 'gen_tokens' [slots, 1] int64 (each slot's last token),
     'gen_pos' [slots, 1] int64 (the position each slot writes this step),
@@ -1187,7 +1230,7 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks):
     btab = layers.data(name='gen_btab', shape=[mb], dtype='int64')
     feeds = _slot_feeds(cfg, block_size)
     pools = _declare_paged_kv_caches(block, cfg, num_blocks, block_size,
-                                     slots)
+                                     slots, shared)
     kc, vc = pools[KV_CACHE_K], pools.get(KV_CACHE_V)
     window = (pools.get(WINDOW_CACHE_K), pools.get(WINDOW_CACHE_V)), \
         feeds.get('ring')
@@ -1459,9 +1502,10 @@ def build_lm_verify(cfg, slots, width, max_len, num_blocks, block_size):
 
 
 def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
-                           max_blocks, slots=None):
+                           max_blocks, slots=None, shared=False):
     """Prefill one prompt SUFFIX (padded to the `prompt_len` bucket) into
-    a paged cache slot and emit the first generated token.
+    a paged cache slot and emit the first generated token (`slots`,
+    `shared`: as the decode step's, for the pools the slots size).
 
     The suffix's query row t sits at global position ctx_len + t: with a
     shared prefix of ctx_len tokens already cached in the slot's leading
@@ -1501,7 +1545,7 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     feeds = _slot_feeds(cfg, block_size)
     wtab = feeds.get('ring')
     pools = _declare_paged_kv_caches(block, cfg, num_blocks, block_size,
-                                     slots)
+                                     slots, shared)
     kc, vc = pools[KV_CACHE_K], pools.get(KV_CACHE_V)
     wkc, wvc = pools.get(WINDOW_CACHE_K), pools.get(WINDOW_CACHE_V)
 

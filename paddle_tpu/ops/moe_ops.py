@@ -71,9 +71,11 @@ to the Program path (OLMoE: models/transformer.py, LMConfig(norm=
   idle slots by ``Valid`` (zero = idle).
 """
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental.xla_metadata import set_xla_metadata
 
@@ -94,17 +96,47 @@ def _rms_norm(ctx, op):
     ctx.out(op, 'Out', y.astype(x.dtype))
 
 
-def rotate(x, positions, theta, interleave=False):
+def yarn_inv_freq(dh, theta, factor, original_max_position, beta_fast,
+                  beta_slow):
+    """YaRN's ``dh / 2`` frequencies, as HF `_compute_yarn_parameters` has
+    them (numpy float64: the table is a constant of the program). Pair
+    ``i`` turns ``original_max_position theta^(-2i/dh) / 2 pi`` times over
+    the original context: a pair that turns more than `beta_fast` times
+    keeps ``theta^(-2i/dh)`` (i <= low), one that turns fewer than
+    `beta_slow` times has it divided by `factor` (i >= high), and between
+    the two the share of the divided one rises linearly."""
+    i = np.arange(dh // 2, dtype=np.float64)
+    extra = theta ** (-2.0 * i / dh)
+
+    def pair_that_turns(times):
+        return dh * math.log(original_max_position / (times * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(pair_that_turns(beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(beta_slow)), dh - 1)
+    ramp = np.clip((i - low) / ((high if high != low else high + 0.001)
+                                - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def rotate(x, positions, theta, interleave=False, yarn=None):
     """`x [..., H, dh]` rotated by `positions` (one per leading row): the
     pair of dims (i, i + dh/2), or with `interleave` (2i, 2i + 1), by the
-    angle `position * theta^(-2i/dh)`."""
+    angle `position * theta^(-2i/dh)`. `yarn` (factor, original_max_position,
+    beta_fast, beta_slow, attention_factor): by `yarn_inv_freq`'s
+    frequencies, cos and sin times the attention factor."""
     dh = x.shape[-1]
     half = dh // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dh)
+    if yarn is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dh)
+    else:
+        inv_freq = jnp.asarray(yarn_inv_freq(dh, theta, *yarn[:4]),
+                               jnp.float32)
     angle = positions.reshape(x.shape[:-2]).astype(jnp.float32)[..., None] \
         * inv_freq                                          # [..., dh/2]
     cos = jnp.cos(angle)[..., None, :]
     sin = jnp.sin(angle)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     xf = x.astype(jnp.float32)
     if interleave:
         x1, x2 = xf[..., 0::2], xf[..., 1::2]
@@ -121,8 +153,13 @@ def _rotary_embedding(ctx, op):
     pos = ctx.in1(op, 'Positions')              # one per leading row
     if x.shape[-1] % 2:
         raise ValueError('rotary_embedding: odd head size %d' % x.shape[-1])
+    yarn = None
+    if op.attr('yarn_factor', None) is not None:
+        yarn = tuple(op.attr('yarn_' + key) for key in (
+            'factor', 'original_max_position', 'beta_fast', 'beta_slow',
+            'attention_factor'))
     ctx.out(op, 'Out', rotate(x, pos, float(op.attr('theta', 10000.0)),
-                              bool(op.attr('interleave', False))))
+                              bool(op.attr('interleave', False)), yarn))
 
 
 def route(x, router_w, top_k, norm_topk_prob, score='softmax',

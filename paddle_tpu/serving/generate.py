@@ -148,7 +148,8 @@ from .. import trace as trace_mod
 from .. import unique_name
 from ..executor import Executor, Scope, scope_guard
 from ..framework import Program, TPUPlace, program_guard
-from ..models.transformer import (LMConfig, build_lm_decode_step,
+from ..models.transformer import (INDEX_FEEDS, LMConfig,
+                                  build_lm_decode_step,
                                   build_lm_prefill_paged, cache_pools,
                                   kv_cache_names, kv_cache_shapes)
 from ..reader.bucketing import bucketize
@@ -530,7 +531,8 @@ class _Admission(object):
     the widest bucket, behind a step in flight — a chunk a pass
     (`GenerateEngine._admit_run`)."""
     __slots__ = ('req', 'slot', 'blocks', 'table', 'hashes', 'off',
-                 'sample', 't0', 'wall0', 'self_s', 'dispatch_s', 'fetch_s')
+                 'published', 'sample', 't0', 'wall0', 'self_s',
+                 'dispatch_s', 'fetch_s')
 
     def __init__(self, req, slot, blocks, table, hashes, off, sample):
         self.req = req
@@ -539,6 +541,7 @@ class _Admission(object):
         self.table = table
         self.hashes = hashes
         self.off = off          # the prompt positions prefilled so far
+        self.published = 0      # its full blocks offered to the prefix cache
         self.sample = sample
         self.t0 = time.perf_counter()       # the admission's start
         self.wall0 = time.time() * 1e6      # ... wall clock, us (the span)
@@ -620,10 +623,8 @@ class GenerateEngine(object):
             self._alloc = block_allocator
         else:
             self._alloc = BlockAllocator(c.num_blocks, c.block_size)
-        self._prefix = PrefixCache(self._alloc) \
-            if c.prefix_sharing else None
         self._max_blocks = c.max_len // c.block_size
-        self._cow_jit = self._dcopy_jit = None
+        self._cow_jit = self._dcopy_jit = self._move_jit = None
         self._stage_jit = self._put_jit = None
         # the prefills' first tokens as they are on the device, a row a
         # slot, and a step's output to stand for "no step in flight"
@@ -631,24 +632,19 @@ class GenerateEngine(object):
         self._first_buf = self._no_prev = None
         # all the engine knows of the model's layer kinds: its pools,
         # read here once -- no pass of the loop looks at the model again
-        pools = self._pools = cache_pools(c.model, c.num_blocks,
-                                          c.block_size, c.slots)
+        pools = self._pools = cache_pools(c.model, c.num_blocks, c.block_size,
+                                          c.slots, c.prefix_sharing)
         for option, fits in (('speculative', lambda p: p.rewinds),
-                             ('prefix_sharing', lambda p: p.index == 'block')):
+                             ('prefix_sharing', lambda p: p.shares)):
             unfit = [p for p in pools if not fits(p)]
             if getattr(c, option) and unfit:
                 raise ValueError("%s=True with LMConfig.layer_types=%r: "
                                  "pool %r: %s" % (option, c.model.layer_types,
                                                   unfit[0].name, unfit[0].why))
         self._free = list(range(c.slots))[::-1]
-        # one bookkeeper a kind of index that is not the allocator's (the
-        # slots' rings, the slots' rows; none for most models)
-        kinds = {p.index: p.shape[0] for p in pools if p.index != 'block'}
-        self._books = tuple(
-            slot_bookkeeper(index, entries, c.slots, c.block_size, self._free)
-            for index, entries in kinds.items())
-        # a wholly shared prompt's last block can be copied and resumed
-        self._cow_ok = all(p.copies for p in pools if p.index == 'block')
+        # a wholly shared prompt's last block can be copied and resumed, in
+        # every pool that a prefix is shared over
+        self._cow_ok = all(p.copies for p in pools if p.shares)
 
         def booked(moment):
             return tuple((p.books[moment], p.shape[1]) for p in pools
@@ -658,6 +654,9 @@ class GenerateEngine(object):
         # series of the rows it walks and of what it resumes from
         self._step_reads, self._prefill_rows, self._resumes = \
             booked('step'), booked('prefill'), booked('resume')
+        # ... and at an admission, of what it resumes from at a shared
+        # prefix's edge
+        self._hits = booked('hit')
         if c.speculative:
             self._draft_cfg = c.draft_model or c.model
             # +1 over the all-slots-at-max_len footprint (the trash
@@ -680,6 +679,26 @@ class GenerateEngine(object):
             self._draft_cfg = self._draft_alloc = self._draft_scope = None
             self._draft_copies_target = False
         self._build_programs()
+        # one bookkeeper a kind of index that is not the allocator's (the
+        # slots' rings, the slots' rows; none for most models), its table
+        # as wide as the step's feed of it
+        kinds = {p.index: p for p in pools if p.index != 'block'}
+        self._books = tuple(
+            slot_bookkeeper(
+                pool, self._step_prog.global_block().var(
+                    INDEX_FEEDS[index]).shape[-1],
+                c.slots, c.block_size, self._free)
+            for index, pool in kinds.items())
+        # the bookkeepers whose blocks a prefix's entries hold beside the
+        # allocator's (one at most: the prefix cache has one side)
+        self._sides = tuple(b for b in self._books
+                            if c.prefix_sharing and hasattr(b, 'resume'))
+        self._prefix = None
+        if c.prefix_sharing:
+            self._prefix = PrefixCache(
+                self._alloc, *(b.blocks for b in self._sides))
+            for book in self._sides:
+                book.cache = self._prefix
         self._init_state()
         self.queue = RequestQueue(self.config.queue_cap)
         self._slots = [None] * self.config.slots
@@ -739,7 +758,7 @@ class GenerateEngine(object):
             with unique_name.guard():
                 self._step_vars = build_lm_decode_step(
                     cfg, c.slots, c.max_len, block_size=c.block_size,
-                    num_blocks=c.num_blocks)
+                    num_blocks=c.num_blocks, shared=c.prefix_sharing)
         self._prefill = {}
         for b in c.prompt_buckets:
             # a bucket's prefill is a program of its own: its name says
@@ -751,7 +770,8 @@ class GenerateEngine(object):
                 with unique_name.guard():
                     v = build_lm_prefill_paged(
                         cfg, b, c.num_blocks, c.block_size,
-                        self._max_blocks, slots=c.slots)
+                        self._max_blocks, slots=c.slots,
+                        shared=c.prefix_sharing)
             self._prefill[b] = (main, v)
         if c.speculative:
             from ..models.transformer import (build_lm_drafter,
@@ -972,7 +992,10 @@ class GenerateEngine(object):
         None when the pool genuinely cannot satisfy the request."""
         ids = self._alloc.alloc(n)
         if ids is None and self._prefix is not None:
-            self._prefix.evict_for(n)
+            # a large cache gives up a 64th of its entries beyond the need:
+            # finding the least recently used sorts them all, and a full
+            # pool would have every new block of every slot pay for that
+            self._prefix.evict_for(n + len(self._prefix) // 64)
             ids = self._alloc.alloc(n)
         if ids is not None:
             self._set_block_gauges()
@@ -1007,15 +1030,50 @@ class GenerateEngine(object):
             feed[book.feed] = table
         return feed
 
-    def _slot_books(self, slot, length=None):
-        """Book with every bookkeeper that `slot`'s tenant has reached
-        `length` positions, or (None) that it is gone; what a ring hands
-        on meanwhile goes into the bookkeeper's series."""
+    def _slot_books(self, slot, length=None, start=None):
+        """Book with every bookkeeper that `slot`'s tenant is about to
+        write positions `start` .. `length` - 1 (a step: the last alone), or
+        (None) that it is gone; what a ring hands on meanwhile goes into
+        the bookkeeper's series, and a shared block that the dispatch at
+        hand still reads out of a column it writes is copied first."""
         for book in self._books:
             n = book.release(slot) if length is None \
-                else book.advance(slot, length)
+                else book.advance(slot, length, start)
             if n:
                 monitor.inc(book.series, n)
+            moved = book.moved()
+            if moved:
+                self._move_blocks(book, moved)
+                monitor.inc(book.copied,
+                            len(moved) * self.config.block_size)
+
+    def _move_blocks(self, book, moved):
+        """Copy blocks `moved` ((from, to) ids) in every pool that `book`
+        keeps, on the device, ahead of the dispatch that reads them: one
+        jitted gather and scatter a pool, the ids padded with the trash
+        block to the widest bucket's blocks (one signature, compiled at
+        warmup). Phase `prefill.move` inside `prefill`, as the dispatch
+        is."""
+        import jax
+        width = self.config.prompt_buckets[-1] // self.config.block_size + 1
+        if self._move_jit is None:
+            def _move(cache, src, dst):
+                return cache.at[dst].set(cache[src])
+            self._move_jit = jax.jit(
+                _move, donate_argnums=()
+                if jax.default_backend() == 'cpu' else (0,))
+        with _loop_phase('prefill.move', counted=False):
+            for at in range(0, len(moved), width):
+                ids = np.zeros((2, width), 'int32')
+                part = moved[at:at + width]
+                ids[:, :len(part)] = np.asarray(part, 'int32').T
+                for pool in self._pools:
+                    if INDEX_FEEDS[pool.index] != book.feed:
+                        continue
+                    self.scope.set(pool.name, self._move_jit(
+                        self.executor._state_value(
+                            self.scope, pool.name, self._step_prog,
+                            cache=False), ids[0], ids[1]))
 
     # ------------------------------------------------------------------
     # warmup
@@ -1116,6 +1174,8 @@ class GenerateEngine(object):
             # trash-block no-op) so steady traffic stays at zero
             # compiles even when the first COW lands mid-stream
             self._cow_copy(0, 0)
+            for book in self._sides:    # ... and a shared ring block's
+                self._move_blocks(book, [(0, 0)])
             if self.config.speculative and self._draft_copies_target:
                 # ... and the draft-pool prompt-block copy (same
                 # trash-block no-op) for the draft==target fast path
@@ -1400,8 +1460,10 @@ class GenerateEngine(object):
                 btab = np.zeros((S, self._max_blocks), 'int64')
                 btab[0] = table
                 feed = {'gen_tokens': toks, 'gen_pos': posf}
-                feed.update(self._tables_feed(btab, [(0, 0)]))
+                # the books first: a step that opens a block reads its
+                # slot's table with the block in it
                 self._slot_books(0, pos + 1)
+                feed.update(self._tables_feed(btab, [(0, 0)]))
                 sf = self._sample_feed(S)
                 sf['gen_temp'][0], sf['gen_topk'][0] = sample[0], sample[1]
                 sf['gen_topp'][0], sf['gen_u'][0] = sample[2], draw_u()
@@ -1568,25 +1630,35 @@ class GenerateEngine(object):
             monitor.set_gauge('generate_queue_depth', self.queue.depth())
 
     def _paged_plan(self, req):
-        """Block plan for one admission: (blocks, ctx_len, hashes).
+        """Block plan for one admission: (blocks, ctx_len, hashes, sides).
         `blocks` covers the whole prompt in logical order — prefix-cache
         hits mapped to their existing physical blocks (referenced),
         fresh blocks for the rest, and a copy-on-write duplicate of the
         final shared block when the ENTIRE prompt landed on shared
         blocks (its last position must be recomputed, a divergent
         write; a model with a pool that does not copy recomputes that
-        whole block into a fresh one instead). Returns None when the pool
-        cannot satisfy the request now (nothing referenced or allocated)."""
+        whole block into a fresh one instead). `sides`: the blocks that a
+        bookkeeper's pool still holds of the rows before ``ctx_len``,
+        referenced (`_sides`; none for most models). Returns None when the
+        pool cannot satisfy the request now (nothing referenced or
+        allocated)."""
         c = self.config
         bs = c.block_size
         L = req.prompt.size
         total = -(-L // bs)
-        shared, hashes = [], []
+        shared, hashes, sides = [], [], []
         if self._prefix is not None:
             hashes = chain_hashes(req.prompt, bs)
             shared = self._prefix.match(hashes)
         whole = bool(shared) and len(shared) * bs >= L
         n_keep = len(shared) - (1 if whole else 0)
+        for book in self._sides:
+            # a pool a slot has a ring in resumes where the cache still
+            # holds the rows a query there reads of it: at the chain's end,
+            # else at the deepest depth before it that has them (a shorter
+            # hit still saves its share of the prefill), else not at all
+            n_keep, sides = self._prefix.side_run(hashes, n_keep, book.reach)
+            shared, whole = shared[:n_keep], False
         # a wholly shared prompt: copy its last block and recompute the
         # final row. A model with convolution layers RECOMPUTES that block
         # into a fresh one instead: a copied entry of the tails' pool
@@ -1603,9 +1675,14 @@ class GenerateEngine(object):
         pinned = shared[:n_keep] + (shared[-1:] if cow else [])
         for b in pinned:
             self._alloc.ref(b)
+        for book in self._sides:
+            for b in sides:
+                book.blocks.ref(b)
         new_ids = self._alloc_blocks(total - n_keep)
         if new_ids is None:
             self._deref_blocks(pinned)
+            for book in self._sides:
+                book.blocks.deref_many(sides)
             return None
         if cow:
             self._cow_copy(shared[-1], new_ids[0])
@@ -1617,7 +1694,7 @@ class GenerateEngine(object):
                 'outcome': 'hit' if ctx_len > 0 else 'miss'})
             if ctx_len > 0:
                 monitor.inc('kv_prefix_tokens_saved_total', ctx_len)
-        return shared[:n_keep] + new_ids, ctx_len, hashes
+        return shared[:n_keep] + new_ids, ctx_len, hashes, sides
 
     def _admit_one(self, req):
         """Admit one popped request. Returns False when it must wait for
@@ -1636,9 +1713,16 @@ class GenerateEngine(object):
         if plan is None:
             self._pending_admit = req
             return False
-        blocks, ctx_len, hashes = plan
+        blocks, ctx_len, hashes, sides = plan
         table = self._slot_table(blocks)
         slot = self._free.pop()
+        for book in self._sides:
+            book.resume(slot, ctx_len // c.block_size, sides)
+            if sides:
+                monitor.inc(book.shared, len(sides))
+        if ctx_len > 0:
+            for series, _layers in self._hits:
+                monitor.inc(series)
         qs = max(0.0, time.monotonic() - req.enqueue_t)
         # queue wait as a histogram (the goodput 'queue' loss bucket
         # reads its sum) + the queue-SLO burn sentinel feed
@@ -1680,10 +1764,12 @@ class GenerateEngine(object):
         with _loop_phase('prefill', counted=False) as own:
             try:
                 while not done:
+                    start = adm.off
                     out, adm.off = self._prefill_dispatch(
-                        req.prompt, adm.off, adm.table, adm.sample,
+                        req.prompt, start, adm.table, adm.sample,
                         self._prefill_bound, slot)
                     done = adm.off >= req.prompt.size
+                    self._publish(adm, start)
                     if defer and not done:
                         break
                 if done and c.speculative:
@@ -1707,6 +1793,7 @@ class GenerateEngine(object):
             except Exception as e:  # noqa: BLE001 — delivered per-request
                 self._free.append(slot)
                 self._deref_blocks(blocks)
+                self._slot_books(slot)
                 if dblocks:
                     self._draft_alloc.deref_many(dblocks)
                 monitor.inc('generate_request_total',
@@ -1726,13 +1813,6 @@ class GenerateEngine(object):
             self._chunking = adm
             self._firsts.append(_First(slot, None, out, adm))
             return True
-        if self._prefix is not None:
-            # publish this prompt's FULL blocks (immutable once
-            # prefilled: decode writes land strictly past the prompt).
-            # The prefill may still be running: whatever reads the
-            # blocks is a program dispatched after it
-            for i, h in enumerate(adm.hashes):
-                self._prefix.register(h, i, blocks[i])
         st = _Slot(req, pos=req.prompt.size, blocks=blocks, table=adm.table,
                    dblocks=dblocks, dtable=dtable)
         st.first = _First(slot, st, out, adm)
@@ -1747,6 +1827,32 @@ class GenerateEngine(object):
         self._set_occupancy()
         return True
 
+    def _publish(self, adm, start):
+        """Publish the FULL blocks of `adm`'s prompt that its dispatches
+        have covered so far, the last of them from `start` on (immutable
+        once prefilled: every later write lands strictly past them), each
+        with the block that a bookkeeper's pool holds of the same rows, if
+        it still does -- a ring has handed the earlier ones on by the
+        prompt's end, so they go as the chunks do. The prefill may still be
+        running: whatever reads the blocks is a program dispatched after
+        it."""
+        if self._prefix is None:
+            return
+        bs = self.config.block_size
+        for i in range(adm.published, min(adm.off // bs, len(adm.hashes))):
+            side = None
+            for book in self._sides:
+                held = book.held(adm.slot, i)
+                # a dispatch writes its last `reach` rows: one wider than
+                # that leaves a gap behind the rows its predecessor wrote
+                wrote = adm.off - book.reach
+                first = max(0, (wrote if wrote > start
+                                else start - book.reach) - i * bs)
+                if held is not None and first < bs:
+                    side = (held, first)
+            self._prefix.register(adm.hashes[i], i, adm.blocks[i], side)
+        adm.published = max(adm.published, adm.off // bs)
+
     def _drop_chunking(self, error, outcome):
         """The chunked admission under way ends here: its slot and blocks
         go back, its request fails with `error`."""
@@ -1756,6 +1862,7 @@ class GenerateEngine(object):
         _book_admission(adm.self_s, adm.dispatch_s)
         self._free.append(adm.slot)
         self._deref_blocks(adm.blocks)
+        self._slot_books(adm.slot)
         monitor.inc('generate_request_total', labels={'outcome': outcome})
         adm.req.fail(error)
 
@@ -1875,6 +1982,7 @@ class GenerateEngine(object):
         last = off + rows.size == prompt.size
         # the slot's own ring or row goes with it (the draft's prefills
         # are of a model that has none)
+        self._slot_books(slot, off + rows.size, off)
         tables = self._tables_feed(table[None], [(0, slot)])
         for series, n_layers in self._prefill_rows:
             # the rows the layers' scans walk (a bucket's pad rows are not
@@ -1897,7 +2005,6 @@ class GenerateEngine(object):
         feed.update(self._sample_feed(1, *(sample if last else ())))
         out = self._prefill_call(bound[b], feed)
         if last:
-            self._slot_books(slot, prompt.size)
             # the copy to the host starts behind the prefill: by the
             # pick-up it is latency behind a busy device. (An earlier
             # chunk's K/V is deposited; its token output is never read.)
@@ -2575,8 +2682,9 @@ class GenerateEngine(object):
         st = self._slots[i]
         if st is not None:
             self._release_blocks(st)
-            # its ring in the window layers' pool is the slot's: the next
-            # tenant's prefill writes what it reads of it
+            # its ring's blocks go back too, but for those the prefix cache
+            # or another tenant holds: the stale row lands in the block of
+            # the departed tenant's own last position, which nobody shares
             self._slot_books(i)
             # So for its row in the state-space layers' pools: the step in
             # flight advances the departed tenant's state once more, in a

@@ -53,6 +53,8 @@ copy. Neither sharer ever observes the other's tokens.
 import hashlib
 import threading
 
+from .. import monitor
+
 __all__ = ['BlockAllocator', 'PrefixCache', 'QuotaBlockAllocator',
            'SlotRows', 'WindowRings', 'chain_hashes', 'slot_bookkeeper']
 
@@ -150,54 +152,146 @@ class BlockAllocator(object):
 
 
 class WindowRings(object):
-    """The window layers' pool, a ring of `ring` blocks a slot: slot `i`
-    owns blocks ``1 + i * ring .. (i + 1) * ring`` (block 0 is the trash
-    block, an idle slot's table row) whoever its tenant is. What it
-    accounts is how much of a ring its tenant has touched: `in_use`, at
-    most ``slots * ring`` whatever the contexts."""
+    """The window layers' pools: a slot's table has `ring` columns, logical
+    block ``b`` of its tenant in column ``b % ring``, and the blocks come
+    from an allocator of the pools' own (``blocks``; block 0 is the trash
+    block, an idle slot's table row and an empty column).
+
+    A tenant holds one reference to each block in its columns. When it
+    OPENS logical block ``b`` (`advance`), the column's old block lies a
+    whole ring behind the oldest key still seen: where nobody else holds it
+    the tenant writes over it where it lies, as a ring does; where the
+    prefix cache or another tenant does (a shared prefix's block), the
+    tenant lets go of it and a fresh block takes the column. A prefill
+    chunk reads the ``reach`` rows before its first and writes its own in
+    ONE program through ONE table, so a shared block that it still reads
+    and whose column it opens is first copied into the fresh one (`moved`:
+    the engine makes the copy ahead of the dispatch). A request that
+    resumes at a shared prefix's edge (`resume`) gets the blocks of the
+    ``reach`` rows before it in its columns, referenced, from the cache
+    that kept them (`PrefixCache`'s ``side``).
+
+    The pools have ``slots * ring`` blocks and what `cached` adds for the
+    blocks the cache alone holds: the tenants never hold more than the
+    first, the cache gives its own up under pressure (`evict`), so `advance`
+    always finds a block."""
 
     # its feed ([rows, width]: a slot's ring, column by column), and the
     # series that what `advance` and `release` return is booked under
     feed, series = 'gen_wtab', 'kv_window_blocks_recycled_total'
+    # ... the blocks that `resume` put into a tenant's columns, and the
+    # rows of the blocks that `moved` had copied
+    shared, copied = 'kv_window_blocks_shared_total', \
+        'kv_window_rows_copied_total'
 
-    def __init__(self, slots, ring, block_size):
+    def __init__(self, slots, ring, block_size, reach=None, cached=0):
         self.ring = self.width = int(ring)
         self.block_size = int(block_size)
+        # rows behind a position that a query there still reads
+        self.reach = (self.ring - 2) * self.block_size if reach is None \
+            else int(reach)
+        self.blocks = BlockAllocator(int(slots) * self.ring + 1 + int(cached),
+                                     block_size)
+        self.cache = None       # the PrefixCache that holds blocks of ours
         self._opened = [0] * int(slots)   # logical blocks a tenant opened
-        self._tables = [list(range(1 + i * self.ring, 1 + (i + 1) * self.ring))
-                        for i in range(int(slots))]
+        self._tables = [[0] * self.ring for _ in range(int(slots))]
+        # the logical block each column holds (-1: none)
+        self._holds = [[-1] * self.ring for _ in range(int(slots))]
+        self._moved = []
 
     @property
     def capacity(self):
-        return len(self._opened) * self.ring
+        return self.blocks.capacity
 
     def table(self, slot):
         """The slot's window table: its blocks, in column order."""
         return self._tables[slot]
 
-    def advance(self, slot, length):
-        """The slot's tenant has written (or skipped: a prefill keeps a
-        chunk's last rows only) positions ``0 .. length - 1``. Returns
-        the blocks that took the place of ones the window left behind."""
-        before = self._opened[slot]
-        self._opened[slot] = max(before, -(-length // self.block_size))
-        return max(self._opened[slot], self.ring) - max(before, self.ring)
+    def held(self, slot, block):
+        """The id of logical block `block` in the slot's ring, or None
+        where the ring holds another by now."""
+        col = block % self.ring
+        return self._tables[slot][col] \
+            if self._holds[slot][col] == block else None
+
+    def _fresh(self):
+        ids = self.blocks.alloc(1)
+        if ids is None and self.cache is not None:
+            # a ring's worth at once: the cache sorts its entries to find
+            # the least recently used, once in `ring` blocks and not a block
+            self.cache.evict_side_for(self.ring)
+            ids = self.blocks.alloc(1)
+        if ids is None:
+            raise RuntimeError("the window layers' pool has no block left: "
+                               "%d in use of %d" % (self.blocks.in_use(),
+                                                    self.capacity))
+        return ids[0]
+
+    def advance(self, slot, length, start=None):
+        """The slot's tenant is about to write positions ``start .. length
+        - 1`` (a decode step: the last one alone). Opens the logical blocks
+        up to the last of them; returns how many columns took a new block
+        in place of one the window left behind."""
+        bs, table, holds = self.block_size, self._tables[slot], \
+            self._holds[slot]
+        first = length - 1 if start is None else start
+        recycled = 0
+        for b in range(self._opened[slot], -(-length // bs)):
+            col = b % self.ring
+            old, was = table[col], holds[col]
+            if old and self.blocks.refcount(old) > 1:
+                # somebody else's too: it stays as it is
+                table[col] = self._fresh()
+                if (was + 1) * bs > first - self.reach:
+                    self._moved.append((old, table[col]))
+                self.blocks.deref(old)
+            elif not old:
+                table[col] = self._fresh()
+            recycled += was >= 0
+            holds[col] = b
+        self._opened[slot] = max(self._opened[slot], -(-length // bs))
+        return recycled
+
+    def moved(self):
+        """(from, to) block ids: shared blocks that `advance` took out of a
+        column whose rows the dispatch at hand still reads, and the blocks
+        that took the columns. The caller copies them before it dispatches."""
+        moved, self._moved = self._moved, []
+        return moved
+
+    def resume(self, slot, depth, ids):
+        """A new tenant that resumes at block `depth`: `ids` are the blocks
+        of the logical blocks ``depth - len(ids) .. depth - 1``, referenced
+        for it by the caller."""
+        self._opened[slot] = depth
+        for b, bid in enumerate(ids, depth - len(ids)):
+            self._tables[slot][b % self.ring] = bid
+            self._holds[slot][b % self.ring] = b
 
     def release(self, slot):
-        """The tenant is gone: the blocks it had touched, handed back."""
-        held = min(self._opened[slot], self.ring)
+        """The tenant is gone: the blocks it held, handed back."""
+        held = [b for b in self._tables[slot] if b]
+        self.blocks.deref_many(held)
         self._opened[slot] = 0
-        return held
+        self._tables[slot] = [0] * self.ring
+        self._holds[slot] = [-1] * self.ring
+        return len(held)
 
     def in_use(self):
-        return sum(min(n, self.ring) for n in self._opened)
+        """Blocks in the slots' columns, each once."""
+        return len({b for table in self._tables for b in table if b})
 
     def report(self, stats):
-        """Into an engine's `stats()`: what the resident slots have
-        touched of their rings, at most slots x ring."""
+        """Into an engine's `stats()`: the blocks in the resident slots'
+        rings (at most slots x ring), and those the prefix cache alone
+        holds; with the free ones they are the capacity."""
+        in_use = self.in_use()
         stats['blocks']['window'] = {'capacity': self.capacity,
-                                     'ring': self.ring,
-                                     'in_use': self.in_use()}
+                                     'ring': self.ring, 'in_use': in_use}
+        if self.cache is not None:
+            cached = self.blocks.in_use() - in_use
+            stats['blocks']['window']['cached'] = cached
+            monitor.set_gauge('kv_window_blocks_cached', float(cached))
 
 
 class SlotRows(object):
@@ -216,9 +310,12 @@ class SlotRows(object):
     def table(self, slot):
         return slot + 1
 
-    def advance(self, slot, length=None):
+    def advance(self, slot, length=None, start=None):
         return 0
     release = advance
+
+    def moved(self):
+        return ()
 
     def in_use(self):
         return self.capacity - len(self._free)
@@ -227,15 +324,17 @@ class SlotRows(object):
         stats['state'] = {'capacity': self.capacity, 'in_use': self.in_use()}
 
 
-def slot_bookkeeper(index, entries, slots, block_size, free):
-    """The bookkeeper of the pools whose leading dimension, `entries` long,
-    is indexed by `index` (models/transformer.py `cache_pools`): 'ring', a
-    slot's ring of blocks (and the trash block); 'row', a slot's row."""
-    if index == 'ring':
-        return WindowRings(slots, (entries - 1) // slots, block_size)
-    if index == 'row':
+def slot_bookkeeper(pool, width, slots, block_size, free):
+    """The bookkeeper of the pools indexed as `pool` is (a row of models/
+    transformer.py `cache_pools`; `width`: the columns of a slot's row of
+    their feed): 'ring', a slot's ring of blocks, the trash block, and what
+    is left for the prefix cache's own; 'row', a slot's row."""
+    if pool.index == 'ring':
+        return WindowRings(slots, width, block_size, pool.reach,
+                           pool.shape[0] - 1 - slots * width)
+    if pool.index == 'row':
         return SlotRows(slots, free)
-    raise ValueError("no bookkeeper for a pool indexed by %r" % (index,))
+    raise ValueError("no bookkeeper for a pool indexed by %r" % (pool.index,))
 
 
 class QuotaBlockAllocator(object):
@@ -338,11 +437,25 @@ class PrefixCache(object):
     — least-recently-used first, deepest entry first within a tie, so a
     chain never loses a shallow link before its deeper ones — until the
     allocator can satisfy a request, and is only called under
-    allocation pressure."""
+    allocation pressure.
 
-    def __init__(self, alloc):
+    `side`: the allocator of a second pool (the window layers':
+    `WindowRings.blocks`) whose block of the same logical block an entry
+    may hold beside its own, one reference each, with the first row of it
+    that was written. A request resumes at depth ``d`` only where the
+    entries before it still hold the side blocks of the ``reach`` rows
+    before row ``d * block_size`` (`side_run`). The side's blocks go under
+    the side's own pressure (`evict_side_for`): least recently used first
+    as well, but the SHALLOWEST first within a tie -- a resume needs the
+    blocks just before its depth and none of those further up -- and the
+    entry stays, for the chain and for a later tenant's block
+    (`register`)."""
+
+    def __init__(self, alloc, side=None):
         self._alloc = alloc
-        self._entries = {}      # hash -> [block_id, depth, last_used]
+        self._side = side
+        # hash -> [block_id, depth, last_used, side id or None, its first row]
+        self._entries = {}
         self._clock = 0
 
     def __len__(self):
@@ -362,16 +475,52 @@ class PrefixCache(object):
             out.append(e[0])
         return out
 
-    def register(self, h, depth, block_id):
+    def side_run(self, hashes, depth, reach):
+        """(d, ids): the deepest ``d <= depth`` at which a request can
+        resume over the side pool, and the side's blocks of the `reach`
+        rows before row ``d * block_size`` (logical blocks ``d - len(ids)
+        .. d - 1``), NOT yet referenced. A shallower depth than the chain's
+        still saves its share of the prefill; (0, []) is a miss. `hashes`
+        ``[:depth]`` are entries (`match` returned that many)."""
+        bs = self._alloc.block_size
+        for d in range(depth, 0, -1):
+            row = max(0, d * bs - reach)
+            ids = []
+            for b in range(row // bs, d):
+                e = self._entries[hashes[b]]
+                if e[3] is None or e[4] > max(0, row - b * bs):
+                    break
+                ids.append(e[3])
+            else:
+                return d, ids
+        return 0, []
+
+    def register(self, h, depth, block_id, side=None):
         """Publish `block_id` as the home of chain hash `h` (depth =
         its block index within the prompt). First writer wins — an
-        already-registered hash keeps its existing block."""
-        if h in self._entries:
-            return False
-        self._clock += 1
-        self._alloc.ref(block_id)
-        self._entries[h] = [block_id, int(depth), self._clock]
-        return True
+        already-registered hash keeps its existing block. `side`: (the
+        side pool's block of the same rows, the first row of it that is
+        written); an entry that holds none, or one written from a later
+        row on, takes it."""
+        e, new = self._entries.get(h), False
+        if e is None:
+            self._clock += 1
+            self._alloc.ref(block_id)
+            e = self._entries[h] = [block_id, int(depth), self._clock,
+                                    None, 0]
+            new = True
+        if side is not None and (e[3] is None or side[1] < e[4]):
+            self._side.ref(side[0])
+            if e[3] is not None:
+                self._side.deref(e[3])
+            e[3], e[4] = side
+        return new
+
+    def _drop(self, h):
+        e = self._entries.pop(h)
+        self._alloc.deref(e[0])
+        if e[3] is not None:
+            self._side.deref(e[3])
 
     def evict_for(self, n_needed):
         """Drop cache-only entries (block refcount 1 — no live slot)
@@ -382,17 +531,32 @@ class PrefixCache(object):
         victims = sorted(self._entries.items(),
                          key=lambda kv: (kv[1][2], -kv[1][1]))
         evicted = 0
-        for h, (bid, _depth, _used) in victims:
+        for h, e in victims:
             if self._alloc.available() >= n_needed:
                 break
-            if self._alloc.refcount(bid) == 1:   # only the cache holds it
-                del self._entries[h]
-                self._alloc.deref(bid)
+            if self._alloc.refcount(e[0]) == 1:   # only the cache holds it
+                self._drop(h)
+                evicted += 1
+        return evicted
+
+    def evict_side_for(self, n_needed):
+        """Let go of side blocks that the cache alone holds until the
+        side's allocator has `n_needed` free. Returns how many went."""
+        evicted = 0
+        if self._side.available() >= n_needed:
+            return evicted
+        for e in sorted((e for e in self._entries.values()
+                         if e[3] is not None),
+                        key=lambda e: (e[2], e[1])):
+            if self._side.available() >= n_needed:
+                break
+            if self._side.refcount(e[3]) == 1:
+                self._side.deref(e[3])
+                e[3] = None
                 evicted += 1
         return evicted
 
     def drop_all(self):
         """Release every cached entry (engine shutdown)."""
-        for h, (bid, _d, _u) in list(self._entries.items()):
-            del self._entries[h]
-            self._alloc.deref(bid)
+        for h in list(self._entries):
+            self._drop(h)

@@ -123,7 +123,7 @@ def test_an_engine_refuses_what_the_table_says_and_keeps_its_books(
         config, option, monkeypatch):
     cfg = CONFIGS[config]()
     fits = {'speculative': lambda p: p.rewinds,
-            'prefix_sharing': lambda p: p.index == 'block'}[option]
+            'prefix_sharing': lambda p: p.shares}[option]
     unfit = [p for p in T.cache_pools(cfg, BLOCKS, BLOCK_SIZE, SLOTS)
              if not fits(p)]
     assert bool(unfit) == ((option, config) in {
@@ -132,7 +132,6 @@ def test_an_engine_refuses_what_the_table_says_and_keeps_its_books(
         ('speculative', 'ai21-jamba2-3b'),
         ('speculative', 'nemotron-3-nano-30b-a3b-ep8-l20'),
         ('prefix_sharing', 'nemotron-3-nano-30b-a3b-ep8-l20'),
-        ('prefix_sharing', 'k-exaone-236b-a23b-ep16-l5'),
         ('prefix_sharing', 'ai21-jamba2-3b')})
     if unfit:
         with pytest.raises(ValueError) as refusal:
@@ -142,7 +141,17 @@ def test_an_engine_refuses_what_the_table_says_and_keeps_its_books(
                                % (option, cfg.layer_types))
         assert repr(unfit[0].name) in said and unfit[0].why in said
     elif option == 'prefix_sharing' or config.startswith('fairseq-dense'):
-        assert _engine(monkeypatch, cfg, **{option: True})._books == ()
+        # since PR 51 a prefix is shared over the slots' rings too: their
+        # bookkeeper is the one whose blocks the prefix cache holds beside
+        # the allocator's, and the pools have room for those
+        shared = _engine(monkeypatch, cfg, **{option: True})
+        rings = [b for b in shared._books
+                 if isinstance(b, kv_blocks.WindowRings)]
+        assert list(shared._books) == rings
+        assert list(shared._sides) == rings * (option == 'prefix_sharing')
+        for b in shared._sides:
+            assert b.cache is shared._prefix
+            assert b.capacity == SLOTS * b.ring + SLOTS * b.ring // 2
     else:
         # the table lets it pass; the drafter refuses the block by field
         with pytest.raises(ValueError, match=r'build_lm_drafter .*LMConfig\.'):
@@ -160,7 +169,9 @@ def test_an_engine_refuses_what_the_table_says_and_keeps_its_books(
     assert sorted(eng._tables_feed([[0] * 8])) == \
         sorted(['gen_btab'] + [b.feed for b in eng._books])
     # only a tail is recomputed where a wholly shared prompt would copy
-    assert eng._cow_ok == (config != 'lfm2-8b-a1b-l8')
+    assert eng._cow_ok == (config not in ('lfm2-8b-a1b-l8',
+                                          'k-exaone-236b-a23b-ep16-l5'))
+    assert eng._sides == () and eng._prefix is None
     stats = {'blocks': {}}
     for b in eng._books:
         b.report(stats)

@@ -404,8 +404,10 @@ def test_the_window_pool_does_not_grow_with_the_context():
 def test_window_rings_account_a_slots_blocks():
     rings = WindowRings(slots=3, ring=4, block_size=8)
     assert (rings.capacity, rings.in_use()) == (12, 0)
-    assert rings.table(0) == [1, 2, 3, 4] and rings.table(2) == [9, 10, 11, 12]
+    # a column gets its block when its tenant opens it (0: the trash block)
+    assert rings.table(0) == [0, 0, 0, 0] == rings.table(2)
     assert rings.advance(1, 20) == 0 and rings.in_use() == 3
+    assert rings.table(1) == [1, 2, 3, 0] and rings.moved() == []
     assert rings.advance(1, 33) == 1       # a fifth block: one written over
     assert rings.advance(1, 33) == 0 and rings.in_use() == 4
     assert rings.advance(0, 100) == 13 - 4 and rings.in_use() == 8
@@ -501,6 +503,18 @@ def test_mosaic_accepts_the_kernel_at_kexaones_shapes(one_chip, span):
 
 @pytest.mark.parametrize('option', ['prefix_sharing', 'speculative'])
 def test_sharing_and_speculation_are_refused_for_window_layers(option):
+    """Speculation is; since PR 51 a prefix is shared over window layers
+    (tests/test_mellum2_serving.py has what that takes), and only then do
+    the window pools hold more than the slots' rings."""
+    if option == 'prefix_sharing':
+        plain, shared = _engine(), _engine(prefix_sharing=True)
+        ring = T.window_ring(plain.config.model, plain.config.block_size)
+        rings = plain.config.slots * ring
+        for eng, blocks in ((plain, rings + 1),
+                            (shared, rings + 1 + rings // 2)):
+            assert [p.shape[0] for p in eng._pools
+                    if p.index == 'ring'] == [blocks] * 2
+        return
     with pytest.raises(ValueError, match=r'%s=True with '
                                          r'LMConfig\.layer_types' % option):
         _engine(**{option: True})

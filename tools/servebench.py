@@ -587,7 +587,7 @@ def measure_shared_prefix(clients=8, system_len=48, suffix_len=8,
             pending = list(reqs)
             while pending:
                 if sharing and eng._prefix is not None:
-                    for b, _d, _u in list(eng._prefix._entries.values()):
+                    for b, *_rest in list(eng._prefix._entries.values()):
                         peak_ref[0] = max(peak_ref[0],
                                           eng._alloc.refcount(b))
                     shared_blocks[0] = max(shared_blocks[0],
