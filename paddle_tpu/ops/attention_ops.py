@@ -3,22 +3,35 @@ analog of the reference's operators/jit/ runtime-codegen kernels
 (jit/kernel_base.h:24-52), with the same refer-vs-optimized cross-checking
 discipline of operators/jit/test.cc — see tests/test_attention.py).
 
-Forward: FlashAttention-2 style. Grid (batch*head, q_block, k_block); the
-k dimension is innermost+sequential so f32 scratch (running max, running
-denominator, output accumulator) carries across k blocks — scores for one
-(q_block, k_block) tile live in VMEM only and never round-trip through HBM.
-Matmuls feed the MXU in the input dtype (bf16 under AMP) with f32
-accumulation via preferred_element_type; causal tiles below the diagonal
-are skipped with predication. Alongside O it emits per-row LSE
-(logsumexp), the residual the backward needs.
+Forward: FlashAttention-2 style. Grid (batch*head, q tile, key block): a
+step holds bq queries and the block of K and V that its BlockSpec fetched
+-- the whole key axis where the VMEM budget holds it (L 2048: one block,
+fetched once a (batch*head)) -- and WALKS it bk keys a trip with a trip
+count that ends at the diagonal: a tile above it takes no grid step and no
+DMA, and only the trips ON the diagonal build and apply the causal mask.
+Where the key axis takes several blocks, a step past the diagonal names
+the block the diagonal's step held, so nothing is fetched for it. A trip's
+scores are [bk, bq], queries along the lanes: the running max, the running
+denominator, the rescale and LSE are [1, bq] rows (a [bq, 1] column costs a
+vreg every 8 rows, an exp of it as much as an exp of 8 x 128 scores: that
+shape, not the mask or the second select, was what made round 3's forward
+tile cost 1.7-2.1 x a dQ tile), both reductions run down the sublanes, and
+the f32 accumulator is [dh, bq], turned once a q tile. Scores live in VMEM
+only. Matmuls feed the MXU in the input dtype (bf16 under AMP) with f32
+accumulation via preferred_element_type; a power-of-two scale (head size
+64: 2^-3) multiplies the [bq, dh] operand once, exactly, any other the f32
+scores. Alongside O it emits per-row LSE (logsumexp), the residual the
+backward needs, in the [1, L] layout both backward kernels read.
 
-Backward: two pallas kernels (the FlashAttention-2 split):
-  - dQ:    grid (bh, q_block, k_block), accumulates dQ across k blocks;
-  - dK/dV: grid (bh, k_block, q_block), accumulates dK and dV across
-           q blocks.
+Backward: two pallas kernels (the FlashAttention-2 split), the same walk:
+  - dQ:    a step holds a q tile and walks the keys up to the diagonal;
+  - dK/dV: a step holds a k tile and walks the queries FROM the diagonal.
 Both recompute the probability tile from (Q, K, LSE) instead of storing it
 — O(L) memory, O(L^2) recompute, the standard trade on HBM-bound hardware.
 delta = rowsum(dO * O) is precomputed outside the kernels (XLA fuses it).
+The tile sizes are `flash_attention_tiling`'s, a function of (L, head size,
+dtype, kernel) with the sweep that set its constants beside it; a program's
+`flash_attention_tiling_total{kernel,bq,bk}` says which schedule it got.
 
 Under SPMD (an active MeshRunner mesh) the op no longer falls back to
 einsum: it wraps the kernel in shard_map over the (data, model) axes —
@@ -28,6 +41,7 @@ itself is sharded it dispatches to the ring-attention path
 of this same op rather than a parallel universe.
 """
 import functools
+import math
 
 import numpy as np
 import jax
@@ -62,10 +76,124 @@ def _pick_block(ln, pref):
 
 
 # --------------------------------------------------------------------------
+# the tile schedule
+# --------------------------------------------------------------------------
+
+# The tile, from `tools/kernbench.py --cases flash_attention --size bench
+# --tilings ...` on one v5e (2026-10-02, jax 0.9.0, libtpu 0.0.34; PERF.md,
+# PR 52), ms a call forward / dQ / dKV:
+#   bh 64, L 2048, dh 64, bfloat16 (fd355m-train-2k):
+#     512 x 512 0.79 / 0.91 / 1.20   1024 x 1024 0.83 / 0.88 / 1.31
+#     1024 x 512 0.87 / 0.91 / 1.34  512 x 256   0.95 / 1.07 / 1.32
+#     256 x 512 1.19 / 1.14 / 1.54   256 x 256   1.43 / 1.33 / 1.84
+#     512 x 128 1.23 / 1.37 / 1.63   128 x 128   3.30 / 3.02 / 3.08
+#   (round 3's kernels at 512 x 512: 1.89 / 1.14 / 1.52)
+#   dh 128 bfloat16 (bh 32): 512 x 512 0.54 / 0.58 / 0.66, 256 x 256 0.93 /
+#   0.77 / 0.96, 1024 x 1024 0.58 / 0.57 / 0.73; float32 dh 64 (bh 64):
+#   512 x 512 0.91 / 1.05 / 1.56, 256 x 256 1.54 / 1.46 / 2.12, 1024 x
+#   1024 0.97 / 1.03 / dKV out of VMEM.
+# The three kernels, both head sizes and both dtypes want the same tile: a
+# trip's fixed cost (the state read and written, the matmuls' fill) is
+# large beside 256 x 256 scores, and 1024 x 1024 wastes more of the four
+# tiles on the diagonal than it saves in trips.
+_TILE = 512
+# the K and V (dKV: Q and dO) blocks a grid step holds, double-buffered,
+# of the 16 MB a kernel has on v5e; a trip's float32 scores, their exp and
+# the backward's dP and dS (4 x 1 MB at 512 x 512) take most of the rest.
+# At L 2048 x dh 64 the whole of K and V is 1 MB of it in bfloat16 (a row
+# of 64 lies in a 128-lane tile), 2 MB in float32: one block.
+_WALK_VMEM_BYTES = 4 << 20
+
+
+def flash_attention_tiling(ln, dh, dtype, kernel):
+    """(bq, bk, block) of `kernel` ('fwd', 'bwd_dq', 'bwd_dkv') at sequence
+    length `ln`, head size `dh`, operands of `dtype`: a grid step holds bq
+    query rows (dKV: bk keys) and WALKS the other axis bk keys (dKV: bq
+    queries) a trip, inside the block of `block` rows of it that the step's
+    BlockSpec fetched -- the whole axis where `_WALK_VMEM_BYTES` holds it.
+    Short or odd L (BERT's 128 / 512, `flash_shapes_ok`) is one tile. A
+    function of its arguments alone: no process sees another schedule."""
+    bq = bk = _pick_block(ln, _TILE)
+    walk = bq if kernel == 'bwd_dkv' else bk
+    # two operands, two buffers each, a row padded to the 128 lanes
+    row = 4 * -(-dh // 128) * 128 * jnp.dtype(dtype).itemsize
+    n = ln // walk
+    fit = [d for d in range(1, n + 1)
+           if n % d == 0 and d * walk * row <= _WALK_VMEM_BYTES]
+    return bq, bk, walk * max(fit or [1])
+
+
+def _count_tiling(kernel, bq, bk):
+    """`flash_attention_tiling_total{kernel,bq,bk}`: + 1 a kernel a lowering
+    (trace time), so a program's counters say which schedule it got."""
+    from .. import monitor
+    monitor.inc('flash_attention_tiling_total',
+                labels={'kernel': kernel, 'bq': str(bq), 'bk': str(bk)})
+
+
+def _folds(scale):
+    """A power of two times a float is that float scaled: the scale then
+    multiplies the [tile, dh] operand once, not every score."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _trips(lo, hi, tile):
+    """tile(t) for t in [lo, hi): straight code where the bounds are known
+    and one trip, else a loop whose trip count the grid step computes."""
+    if isinstance(lo, int) and isinstance(hi, int) and hi - lo <= 1:
+        if hi > lo:
+            tile(lo)
+        return
+    lax.fori_loop(lo, hi, lambda t, c: tile(t) or c, 0)
+
+
+def _at(t, size):
+    """pl.ds of sub-tile `t` of `size` rows."""
+    import jax.experimental.pallas as pl
+    if isinstance(t, int):
+        return pl.ds(t * size, size)
+    return pl.ds(pl.multiple_of(t * size, size), size)
+
+
+def _visible(q0, k0, bq, bk):
+    """q0 + (column of the [bk, bq] tile) >= k0 + (row): the causal mask
+    of the tile whose first query is q0 and first key k0."""
+    qi = lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+    ki = lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+    return qi - ki >= k0 - q0
+
+
+def _key_walk(causal, i, j, bq, bk, spm):
+    """The trips of query tile i over the sub-tiles of key block j, as
+    (first masked, end): [0, masked) lie wholly under the diagonal,
+    [masked, end) on it; what lies above it takes no trip."""
+    if not causal:
+        return spm, spm
+    full = jnp.clip((i * bq) // bk - j * spm, 0, spm)
+    end = jnp.clip(((i + 1) * bq + bk - 1) // bk - j * spm, 0, spm)
+    return full, end
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+# --------------------------------------------------------------------------
 # forward kernel
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(scale, causal, nk, has_bias, *refs):
+def _fwd_kernel(scale, causal, bk, has_bias, *refs):
+    """A grid step holds bq queries and walks the keys of its K / V block
+    bk a trip, up to the diagonal. The scores of a trip are [bk, bq]: a
+    row's max, sum, `alpha` and `lse` are [1, bq] rows along the lanes (4
+    vregs at 512 where a [bq, 1] column is 64), the two reductions run
+    down the sublanes, and the accumulator is [dh, bq], turned once at
+    the end."""
     import jax.experimental.pallas as pl
     if has_bias:
         (q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
@@ -75,7 +203,9 @@ def _fwd_kernel(scale, causal, nk, has_bias, *refs):
          m_scr, l_scr, acc_scr) = refs
         bias_ref = None
     i, j = pl.program_id(1), pl.program_id(2)
-    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    bq = q_ref.shape[1]
+    spm = k_ref.shape[1] // bk
+    fold = _folds(scale)
 
     @pl.when(j == 0)
     def _init():
@@ -83,84 +213,107 @@ def _fwd_kernel(scale, causal, nk, has_bias, *refs):
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    q = q_ref[0]
+    if fold:
+        q = q * scale
+
+    def tile(t, masked):
+        k = k_ref[0, _at(t, bk), :]
+        v = v_ref[0, _at(t, bk), :]
+        s = _dot(k, q, _NT)                                 # [bk, bq]
+        if not fold:
+            s = s * scale
         if bias_ref is not None:
             # per-key additive bias (padding masks: 0 keep / -1e9 drop)
-            s = s + bias_ref[0, 0][None, :]
-        if causal:
-            rows = i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            mask = rows >= cols
-            s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            bias = bias_ref[0, t].reshape(bk, 1)
+            s = s + bias
+        if masked:
+            # keys are walked from column 0, so a row's m is finite after
+            # its first tile and exp(-1e30 - m) is an exact 0.0
+            s = jnp.where(_visible(i * bq, (j * spm + t) * bk, bq, bk),
+                          s, _NEG_INF)
+        m_prev = m_scr[...]                                 # [1, bq]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        if causal:
-            # rows whose tile slice is fully masked have m_new == _NEG_INF
-            # and exp(_NEG_INF - _NEG_INF) == 1; force masked entries to 0
-            p = jnp.where(mask, p, 0.0)
         if bias_ref is not None:
-            # exact zero for dropped keys (-1e8 or lower — covers the
+            # exact zero for dropped keys (-1e8 or lower -- covers the
             # documented -1e9 pad convention), independent of underflow
-            p = jnp.where(bias_ref[0, 0][None, :] > -1e8, p, 0.0)
-        l_scr[...] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_scr.shape)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        pv = lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha + pv
+            p = jnp.where(bias > -1e8, p, 0.0)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=0, keepdims=True)
+        m_scr[...] = m_new
+        acc_scr[...] = acc_scr[...] * alpha + _dot(
+            v, p.astype(v.dtype), _TN)                      # [dh, bq]
 
-    if causal:
-        # tile visible iff its first key column <= last query row
-        pl.when(j * bk <= i * bq + bq - 1)(_compute)
-    else:
-        _compute()
+    masked, end = _key_walk(causal, i, j, bq, bk, spm)
+    _trips(0, masked, lambda t: tile(t, False))
+    _trips(masked, end, lambda t: tile(t, True))
 
-    @pl.when(j == nk - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        l = l_scr[:, :1]
-        o_ref[0] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:, 0] + jnp.log(
-            jnp.maximum(l_scr[:, 0], 1e-30))
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / l).T.astype(o_ref.dtype)
+        lse_ref[0] = m_scr[...] + jnp.log(l)
 
 
-def _flash_fwd_pallas(q, k, v, scale, causal, interpret, block_q, block_k,
-                      bias=None, n_heads=1):
+def _key_specs(causal, bq, bk, block, dh, n_heads):
+    """BlockSpecs of a step's K / V block ([L, dh], `block` rows) and of its
+    padding bias ([B, L] as a sub-tile a row: batch*head row b is batch
+    b // n_heads), for a step that holds q tile i: under the causal mask
+    a step past the diagonal names the block the diagonal's step held, so
+    nothing is fetched for it."""
+    import jax.experimental.pallas as pl
+    if causal:
+        def held(i, j):
+            return jnp.minimum(j, ((i + 1) * bq - 1) // block)
+    else:
+        def held(i, j):
+            return j
+    return (pl.BlockSpec((1, block, dh),
+                         lambda b, i, j: (b, held(i, j), 0)),
+            pl.BlockSpec((1, block // bk, 1, bk),
+                         lambda b, i, j: (b // n_heads, held(i, j), 0, 0)))
+
+
+def _flash_fwd_pallas(q, k, v, scale, causal, interpret, bias=None,
+                      n_heads=1, tiling=None):
+    """(O, LSE) of [BH, L, dh] operands by the forward kernel under the
+    rule's schedule (or `tiling`, the sweep's). The call goes through
+    `jax.jit`: the 24 layers of a program trace and lower the kernel ONCE
+    (set-up, not the step: XLA inlines the calls)."""
+    ln, dh = q.shape[1:]
+    tiling = tiling or flash_attention_tiling(ln, dh, q.dtype, 'fwd')
+    _count_tiling('fwd', *tiling[:2])
+    return _fwd_call(q, k, v, bias, scale, causal, interpret, n_heads,
+                     tiling)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _fwd_call(q, k, v, bias, scale, causal, interpret, n_heads, tiling):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     bh, ln, dh = q.shape
-    bq = _pick_block(ln, block_q)
-    bk = _pick_block(ln, block_k)
-    nq, nk = ln // bq, ln // bk
+    bq, bk, block = tiling
     has_bias = bias is not None
-    kernel = functools.partial(_fwd_kernel, scale, causal, nk, has_bias)
+    kernel = functools.partial(_fwd_kernel, scale, causal, bk, has_bias)
     qspec = pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0))
+    kspec, bias_spec = _key_specs(causal, bq, bk, block, dh, n_heads)
     ins = [q, k, v]
     in_specs = [qspec, kspec, kspec]
     if has_bias:
-        # bias [B, L]: each (batch*head) row b maps to batch b // n_heads
-        ins.append(bias.astype(jnp.float32)[:, None, :])
-        in_specs.append(pl.BlockSpec(
-            (1, 1, bk), lambda b, i, j: (b // n_heads, 0, j)))
+        ins.append(bias.astype(jnp.float32).reshape(-1, ln // bk, 1, bk))
+        in_specs.append(bias_spec)
     o, lse = pl.pallas_call(
         kernel,
-        grid=(bh, nq, nk),
+        grid=(bh, ln // bq, ln // block),
         in_specs=in_specs,
         out_specs=[qspec,
                    pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((bh, ln, dh), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, ln), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
-                        pltpu.VMEM((bq, 128), jnp.float32),
-                        pltpu.VMEM((bq, dh), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, bq), jnp.float32),
+                        pltpu.VMEM((1, bq), jnp.float32),
+                        pltpu.VMEM((dh, bq), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -173,7 +326,8 @@ def _flash_fwd_pallas(q, k, v, scale, causal, interpret, block_q, block_k,
 # backward kernels
 # --------------------------------------------------------------------------
 
-def _bwd_dq_kernel(scale, causal, nk, has_bias, *refs):
+def _bwd_dq_kernel(scale, causal, bk, has_bias, *refs):
+    """The forward's walk and its [bk, bq] scores; dQ sums as [dh, bq]."""
     import jax.experimental.pallas as pl
     if has_bias:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
@@ -183,45 +337,54 @@ def _bwd_dq_kernel(scale, causal, nk, has_bias, *refs):
          dq_ref, dq_scr) = refs
         bias_ref = None
     i, j = pl.program_id(1), pl.program_id(2)
-    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    bq = q_ref.shape[1]
+    spm = k_ref.shape[1] // bk
+    fold = _folds(scale)
 
     @pl.when(j == 0)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    def _compute():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    q, do = q_ref[0], do_ref[0]
+    if fold:
+        q = q * scale
+    lse, delta = lse_ref[0], delta_ref[0]                   # [1, bq]
+
+    def tile(t, masked):
+        k = k_ref[0, _at(t, bk), :]
+        v = v_ref[0, _at(t, bk), :]
+        s = _dot(k, q, _NT)                                 # [bk, bq]
+        if not fold:
+            s = s * scale
         if bias_ref is not None:
-            s = s + bias_ref[0, 0][None, :]
-        if causal:
-            rows = i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])   # masked entries underflow
+            bias = bias_ref[0, t].reshape(bk, 1)
+            s = s + bias
+        if masked:
+            s = jnp.where(_visible(i * bq, (j * spm + t) * bk, bq, bk),
+                          s, _NEG_INF)
+        p = jnp.exp(s - lse)                  # masked entries underflow
         if bias_ref is not None:
             # all-padded rows have lse = log(1e-30); without the forward's
             # exact zeroing p explodes to ~e^69 and poisons dQ
-            p = jnp.where(bias_ref[0, 0][None, :] > -1e8, p, 0.0)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None]) * scale
-        dq_scr[...] += lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            p = jnp.where(bias > -1e8, p, 0.0)
+        ds = p * (_dot(v, do, _NT) - delta)
+        # the scale of dS multiplies the float32 sum once, at the end
+        dq_scr[...] += _dot(k, ds.astype(k.dtype), _TN)     # [dh, bq]
 
-    if causal:
-        pl.when(j * bk <= i * bq + bq - 1)(_compute)
-    else:
-        _compute()
+    masked, end = _key_walk(causal, i, j, bq, bk, spm)
+    _trips(0, masked, lambda t: tile(t, False))
+    _trips(masked, end, lambda t: tile(t, True))
 
-    @pl.when(j == nk - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[...] * scale).T.astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(scale, causal, nq, has_bias, *refs):
+def _bwd_dkv_kernel(scale, causal, bq, has_bias, *refs):
+    """A grid step holds bk keys and walks the queries of its Q / dO block
+    bq a trip, FROM the diagonal. Scores as [bk, bq] here too: the walked
+    queries lie along the lanes, as lse and delta are stored, and dV = P^T
+    dO, dK = dS^T Q are plain products."""
     import jax.experimental.pallas as pl
     if has_bias:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
@@ -230,109 +393,139 @@ def _bwd_dkv_kernel(scale, causal, nq, has_bias, *refs):
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_scr, dv_scr) = refs
         bias_ref = None
-    i, j = pl.program_id(1), pl.program_id(2)      # i: k block, j: q block
-    bk, bq = k_ref.shape[1], q_ref.shape[1]
+    i, j = pl.program_id(1), pl.program_id(2)      # i: k tile, j: q block
+    bk, dh = k_ref.shape[1:]
+    spm = q_ref.shape[1] // bq
+    fold = _folds(scale)
 
     @pl.when(j == 0)
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    def _compute():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0][None, :]
-        if causal:
-            rows = j * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = i * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])            # [bq, bk]
-        if bias_ref is not None:
-            p = jnp.where(bias_ref[0, 0][None, :] > -1e8, p, 0.0)
-        dv_scr[...] += lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None]) * scale
-        dk_scr[...] += lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    k, v = k_ref[0], v_ref[0]
+    if fold:
+        k = k * scale
+    bias = bias_ref[0].reshape(bk, 1) if has_bias else None
 
+    def tile(t, masked):
+        q = q_ref[0, _at(t, bq), :]
+        do = do_ref[0, _at(t, bq), :]
+        s = _dot(k, q, _NT)                                 # [bk, bq]
+        if not fold:
+            s = s * scale
+        if bias is not None:
+            s = s + bias
+        if masked:
+            s = jnp.where(_visible((j * spm + t) * bq, i * bk, bq, bk),
+                          s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[0, t])
+        if bias is not None:
+            p = jnp.where(bias > -1e8, p, 0.0)
+        dv_scr[...] += _dot(p.astype(do.dtype), do, _NN)
+        dp = _dot(v, do, _NT)
+        ds = p * (dp - delta_ref[0, t])
+        dk_scr[...] += _dot(ds.astype(q.dtype), q, _NN)
+
+    # the walk over queries STARTS at the diagonal: [first, full) on it,
+    # [full, spm) wholly under it
     if causal:
-        # tile visible iff its last query row >= first key column
-        pl.when(j * bq + bq - 1 >= i * bk)(_compute)
+        first = jnp.clip((i * bk) // bq - j * spm, 0, spm)
+        full = jnp.clip(((i + 1) * bk + bq - 2) // bq - j * spm, 0, spm)
     else:
-        _compute()
+        first = full = 0
+    _trips(first, full, lambda t: tile(t, True))
+    _trips(full, spm, lambda t: tile(t, False))
 
-    @pl.when(j == nq - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, interpret,
-                      block_q, block_k, bias=None, n_heads=1):
+                      bias=None, n_heads=1, tiling_dq=None,
+                      tiling_dkv=None):
+    """(dQ, dK, dV) by the two backward kernels, each under the rule's
+    schedule (or the sweep's); through `jax.jit` as the forward is."""
+    ln, dh = q.shape[1:]
+    tiling_dq = tiling_dq or flash_attention_tiling(ln, dh, q.dtype,
+                                                    'bwd_dq')
+    tiling_dkv = tiling_dkv or flash_attention_tiling(ln, dh, q.dtype,
+                                                      'bwd_dkv')
+    _count_tiling('bwd_dq', *tiling_dq[:2])
+    _count_tiling('bwd_dkv', *tiling_dkv[:2])
+    return _bwd_call(q, k, v, o, lse, do, bias, scale, causal, interpret,
+                     n_heads, tiling_dq, tiling_dkv)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
+def _bwd_call(q, k, v, o, lse, do, bias, scale, causal, interpret, n_heads,
+              tiling_dq, tiling_dkv):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     bh, ln, dh = q.shape
-    bq = _pick_block(ln, block_q)
-    bk = _pick_block(ln, block_k)
-    nq, nk = ln // bq, ln // bk
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    lse3 = lse[:, None, :]
-    delta3 = delta[:, None, :]
     has_bias = bias is not None
-    bias3 = bias.astype(jnp.float32)[:, None, :] if has_bias else None
+    bias32 = bias.astype(jnp.float32) if has_bias else None
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
+    bq, bk, block = tiling_dq
     qspec = pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0))
-    kspec_j = pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0))
+    kspec, bias_spec = _key_specs(causal, bq, bk, block, dh, n_heads)
     rowspec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
-    ins = [q, k, v, do, lse3, delta3]
-    in_specs = [qspec, kspec_j, kspec_j, qspec, rowspec, rowspec]
+    ins = [q, k, v, do, lse[:, None, :], delta[:, None, :]]
+    in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
     if has_bias:
-        ins.append(bias3)
-        in_specs.append(pl.BlockSpec(
-            (1, 1, bk), lambda b, i, j: (b // n_heads, 0, j)))
+        ins.append(bias32.reshape(-1, ln // bk, 1, bk))
+        in_specs.append(bias_spec)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale, causal, nk, has_bias),
-        grid=(bh, nq, nk),
+        functools.partial(_bwd_dq_kernel, scale, causal, bk, has_bias),
+        grid=(bh, ln // bq, ln // block),
         in_specs=in_specs,
         out_specs=[qspec],
         out_shape=[jax.ShapeDtypeStruct((bh, ln, dh), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((dh, bq), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
         name='flash_attention_bwd_dq',
     )(*ins)[0]
 
-    # k-major grid: q blocks stream innermost
-    qspec_j = pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, j, 0))
-    kspec_i = pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, i, 0))
-    rowspec_j = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, j))
-    ins2 = [q, k, v, do, lse3, delta3]
-    in_specs2 = [qspec_j, kspec_i, kspec_i, qspec_j, rowspec_j, rowspec_j]
+    # k-major grid: a step holds a K / V tile and walks the queries
+    bq, bk, block = tiling_dkv
+    if causal:
+        # a step before the diagonal names the block the diagonal's holds
+        def held(i, j):
+            return jnp.maximum(j, (i * bk) // block)
+    else:
+        def held(i, j):
+            return j
+    qspec = pl.BlockSpec((1, block, dh), lambda b, i, j: (b, held(i, j), 0))
+    kspec = pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, i, 0))
+    # lse and delta a sub-tile a row, so a trip reads its row by index
+    rowspec = pl.BlockSpec((1, block // bq, 1, bq),
+                           lambda b, i, j: (b, held(i, j), 0, 0))
+    ins = [q, k, v, do, lse.reshape(bh, ln // bq, 1, bq),
+           delta.reshape(bh, ln // bq, 1, bq)]
+    in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
     if has_bias:
-        ins2.append(bias3)
-        in_specs2.append(pl.BlockSpec(
+        ins.append(bias32[:, None, :])
+        in_specs.append(pl.BlockSpec(
             (1, 1, bk), lambda b, i, j: (b // n_heads, 0, i)))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale, causal, nq, has_bias),
-        grid=(bh, nk, nq),
-        in_specs=in_specs2,
-        out_specs=[kspec_i, kspec_i],
+        functools.partial(_bwd_dkv_kernel, scale, causal, bq, has_bias),
+        grid=(bh, ln // bk, ln // block),
+        in_specs=in_specs,
+        out_specs=[kspec, kspec],
         out_shape=[jax.ShapeDtypeStruct((bh, ln, dh), k.dtype),
                    jax.ShapeDtypeStruct((bh, ln, dh), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, dh), jnp.float32),
                         pltpu.VMEM((bk, dh), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=params,
         interpret=interpret,
         name='flash_attention_bwd_dkv',
-    )(*ins2)
+    )(*ins)
     return dq, dk, dv
 
 
@@ -340,18 +533,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, interpret,
 # custom_vjp wrapper ([BH, L, dh] level)
 # --------------------------------------------------------------------------
 
-# default tile sizes; the round-3 sweep measured 512x512 optimal at
-# d_head 64 (256/128 tiles 1.5-2.5x slower). Env-overridable so a sweep
-# can re-measure without editing source; nothing in the repo sets either.
-import os as _os
-_DEF_BQ = int(_os.environ.get('PADDLE_FLASH_BQ', '512'))
-_DEF_BK = int(_os.environ.get('PADDLE_FLASH_BK', '512'))
-
 
 def _fwd_impl(q, k, v, scale, causal, impl):
     if impl in ('pallas', 'interpret'):
         return _flash_fwd_pallas(q, k, v, scale, causal,
-                                 impl == 'interpret', _DEF_BQ, _DEF_BK)
+                                 impl == 'interpret')
     return _attention_ref(q, k, v, scale, causal), None
 
 
@@ -369,7 +555,7 @@ def _flash_bwd(scale, causal, impl, res, ct):
     q, k, v, o, lse = res
     if impl in ('pallas', 'interpret'):
         return _flash_bwd_pallas(q, k, v, o, lse, ct, scale, causal,
-                                 impl == 'interpret', _DEF_BQ, _DEF_BK)
+                                 impl == 'interpret')
     _, vjp = jax.vjp(lambda a, b, c: _attention_ref(a, b, c, scale, causal),
                      q, k, v)
     return vjp(ct)
@@ -401,8 +587,7 @@ def _flash_biased(q, k, v, bias, scale, causal, impl, n_heads):
 def _fwd_impl_biased(q, k, v, bias, scale, causal, impl, n_heads):
     if impl in ('pallas', 'interpret'):
         return _flash_fwd_pallas(q, k, v, scale, causal,
-                                 impl == 'interpret', _DEF_BQ, _DEF_BK,
-                                 bias=bias, n_heads=n_heads)
+                                 impl == 'interpret', bias=bias, n_heads=n_heads)
     return _attention_ref_biased(q, k, v, bias, scale, causal,
                                  n_heads), None
 
@@ -418,7 +603,7 @@ def _flash_biased_bwd(scale, causal, impl, n_heads, res, ct):
     if impl in ('pallas', 'interpret'):
         dq, dk, dv = _flash_bwd_pallas(
             q, k, v, o, lse, ct, scale, causal, impl == 'interpret',
-            _DEF_BQ, _DEF_BK, bias=bias, n_heads=n_heads)
+            bias=bias, n_heads=n_heads)
         return dq, dk, dv, jnp.zeros_like(bias)
     _, vjp = jax.vjp(
         lambda a, b, c: _attention_ref_biased(a, b, c, bias, scale,

@@ -367,3 +367,261 @@ def test_unfused_fallback_honors_padding_bias():
     c = run(True, 0.5)       # unfused path w/ key_padding_bias branch
     np.testing.assert_allclose(a, b, rtol=1e-4)
     np.testing.assert_allclose(a, c, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 52: the tile schedule. A grid step walks sub-tiles inside its block
+# with a trip count that ends (dKV: starts) at the diagonal; the tile sizes
+# are `flash_attention_tiling`'s.
+
+def _walk_operands(ln, dh, dtype, bias_kind, seed=7):
+    """Two batches of one head: q, k, v, dO, and a [2, L] padding bias
+    ('tail': the last keys dropped; 'row': batch 0 dropped whole)."""
+    rng = np.random.RandomState(seed)
+    q, k, v, do = (jnp.asarray(rng.randn(2, ln, dh), dtype)
+                   for _ in range(4))
+    bias = None
+    if bias_kind:
+        b = np.zeros((2, ln), 'float32')
+        b[1, -37:] = -1e9
+        b[0, -5:] = -1e9
+        if bias_kind == 'row':
+            b[0, :] = -1e9
+        bias = jnp.asarray(b)
+    return q, k, v, do, bias
+
+
+# (L, dh, dtype, causal, bias, (bq, bk, block) forward and dQ, the same dKV)
+_WALKS = [
+    # two and three sub-tiles of 128 in one block, the last on the diagonal
+    (256, 64, 'float32', True, None, (128, 128, 256), (128, 128, 256)),
+    (384, 128, 'float32', True, None, (128, 128, 384), (128, 128, 384)),
+    (256, 64, 'bfloat16', True, None, (128, 128, 256), (128, 128, 256)),
+    (384, 128, 'bfloat16', True, None, (128, 128, 384), (128, 128, 384)),
+    # a block a sub-tile: the grid's third axis walks, the index map clamps
+    (384, 64, 'float32', True, None, (128, 128, 128), (128, 128, 128)),
+    (512, 64, 'float32', True, None, (128, 128, 256), (128, 128, 256)),
+    # bq != bk: the diagonal crosses two sub-tiles of a step
+    (512, 64, 'float32', True, None, (256, 128, 512), (256, 128, 512)),
+    (512, 64, 'float32', True, None, (128, 256, 512), (128, 256, 512)),
+    (512, 128, 'bfloat16', True, None, (256, 128, 256), (128, 256, 256)),
+    # no mask: every sub-tile takes the unmasked trip
+    (256, 64, 'float32', False, None, (128, 128, 256), (128, 128, 256)),
+    (384, 64, 'bfloat16', False, None, (128, 128, 128), (128, 128, 128)),
+    # key_padding_bias, a sub-tile a row of the bias block
+    (384, 64, 'float32', False, 'tail', (128, 128, 384), (128, 128, 384)),
+    (256, 64, 'float32', True, 'tail', (128, 128, 128), (128, 128, 128)),
+    (256, 128, 'bfloat16', False, 'tail', (128, 128, 256), (128, 128, 256)),
+    (512, 64, 'float32', True, 'tail', (256, 128, 512), (128, 256, 512)),
+    # the rule's own answer: one tile
+    (256, 64, 'float32', True, None, None, None),
+]
+
+
+@pytest.mark.parametrize("ln,dh,dtype,causal,bias_kind,tiling,tiling_dkv",
+                         _WALKS)
+def test_walked_kernels_match_reference(ln, dh, dtype, causal, bias_kind,
+                                        tiling, tiling_dkv):
+    """Forward and both gradients through the interpreter against the
+    jnp reference, at the tolerances of the tests above (float32); the
+    reference of a bfloat16 case is the float32 one of the same values."""
+    from paddle_tpu.ops import attention_ops as A
+    q, k, v, do, bias = _walk_operands(ln, dh, dtype, bias_kind)
+    scale = dh ** -0.5
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    if bias is None:
+        ref = lambda a, b, c: A._attention_ref(a, b, c, scale, causal)
+    else:
+        ref = lambda a, b, c: A._attention_ref_biased(a, b, c, bias, scale,
+                                                      causal, 1)
+    o_ref, vjp = jax.vjp(ref, *f32)
+    g_ref = vjp(do.astype(jnp.float32))
+    o, lse = A._flash_fwd_pallas(q, k, v, scale, causal, True, bias=bias,
+                                 tiling=tiling)
+    grads = A._flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, True,
+                                bias=bias, tiling_dq=tiling,
+                                tiling_dkv=tiling_dkv)
+    fwd_tol, bwd_tol = (dict(rtol=2e-4, atol=2e-5), dict(rtol=3e-3,
+                                                         atol=3e-4)) \
+        if dtype == 'float32' else (dict(rtol=2e-2, atol=2e-2),) * 2
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(o_ref), **fwd_tol)
+    for got, want in zip(grads, g_ref):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), **bwd_tol)
+
+
+@pytest.mark.parametrize("causal,tiling", [
+    (False, (128, 128, 256)), (True, (128, 128, 128))])
+def test_walked_kernels_all_padded_row(causal, tiling):
+    """A batch whose keys are ALL padded: the walked backward gives exact
+    zeros there and the other batch its reference gradients."""
+    from paddle_tpu.ops import attention_ops as A
+    q, k, v, do, bias = _walk_operands(256, 64, 'float32', 'row')
+    scale = 64 ** -0.5
+    o, lse = A._flash_fwd_pallas(q, k, v, scale, causal, True, bias=bias,
+                                 tiling=tiling)
+    grads = A._flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, True,
+                                bias=bias, tiling_dq=tiling,
+                                tiling_dkv=tiling)
+    _, vjp = jax.vjp(lambda a, b, c: A._attention_ref_biased(
+        a[1:], b[1:], c[1:], bias[1:], scale, causal, 1), q, k, v)
+    for got, want in zip(grads, vjp(do[1:])):
+        got = np.asarray(got)
+        assert np.isfinite(got).all()
+        assert np.abs(got[0]).max() == 0.0
+        np.testing.assert_allclose(got[1], np.asarray(want)[1], rtol=3e-3,
+                                   atol=3e-4)
+
+
+@pytest.mark.parametrize("kernel", ['fwd', 'bwd_dq', 'bwd_dkv'])
+@pytest.mark.parametrize("dtype", ['bfloat16', 'float32'])
+@pytest.mark.parametrize("ln,dh", [(2048, 64), (2048, 128), (8192, 128),
+                                   (512, 64), (128, 64), (100, 64),
+                                   (16, 8)])
+def test_flash_tiling_rule(ln, dh, dtype, kernel, monkeypatch):
+    """The tile function alone, at the cells' shapes and the short ones:
+    the sizes divide L, the walked blocks fit the stated VMEM budget, one
+    tile where L is short or odd, and the environment changes nothing."""
+    from paddle_tpu.ops import attention_ops as A
+    bq, bk, block = A.flash_attention_tiling(ln, dh, dtype, kernel)
+    walk = bq if kernel == 'bwd_dkv' else bk
+    assert ln % bq == 0 and ln % bk == 0 and ln % block == 0
+    assert block % walk == 0
+    lanes = -(-dh // 128) * 128
+    held = 2 * 2 * block * lanes * jnp.dtype(dtype).itemsize
+    assert held <= A._WALK_VMEM_BYTES or block == walk
+    if ln <= 512 or ln % 128:
+        assert (bq, bk, block) == (ln, ln, ln)
+    if ln == 2048:
+        assert (bq, bk, block) == (512, 512, 2048)    # the sweep's, PR 52
+    if ln == 8192 and dtype == 'float32':
+        assert block < ln                   # 16 MB of K and V: not one block
+    monkeypatch.setenv('PADDLE_FLASH_BQ', '128')
+    monkeypatch.setenv('PADDLE_FLASH_BK', '256')
+    assert A.flash_attention_tiling(ln, dh, dtype, kernel) == (bq, bk, block)
+    assert A.flash_shapes_ok(ln)
+
+
+def test_flash_tile_knobs_are_gone():
+    """ROADMAP D6: no file of the package reads PADDLE_FLASH_BQ / _BK."""
+    import pathlib
+    import paddle_tpu
+    root = pathlib.Path(paddle_tpu.__file__).parent
+    hits = [str(p) for p in root.rglob('*.py')
+            if 'PADDLE_FLASH' in p.read_text()]
+    assert hits == []
+
+
+def test_flash_tiling_counter():
+    """`flash_attention_tiling_total{kernel,bq,bk}`: + 1 a kernel a
+    lowering -- the forward alone lowers one kernel, its gradient three
+    (the forward again, dQ, dKV) -- and nothing at run time."""
+    from paddle_tpu import monitor
+    q, k, v, _, _ = _walk_operands(256, 16, 'float32', None)
+
+    def series(before):
+        return {key: val for key, val in
+                monitor.counter_delta(before).items()
+                if key.startswith('flash_attention_tiling_total')}
+
+    def key(kernel):
+        return 'flash_attention_tiling_total{bk=256,bq=256,kernel=%s}' \
+            % kernel
+    fwd = jax.jit(lambda a: flash_attention(a, k, v, use_pallas='interpret'))
+    before = monitor.counters()
+    fwd.lower(q)
+    assert series(before) == {key('fwd'): 1.0}
+    grad = jax.jit(jax.grad(lambda a: jnp.sum(flash_attention(
+        a, k, v, use_pallas='interpret'))))
+    before = monitor.counters()
+    compiled = grad.lower(q).compile()
+    assert series(before) == {key('fwd'): 1.0, key('bwd_dq'): 1.0,
+                              key('bwd_dkv'): 1.0}
+    before = monitor.counters()
+    compiled(q)
+    assert series(before) == {}
+
+
+# ---------------------------------------------------------------------------
+# Mosaic, without a chip: the three kernels at the cells' widths against a
+# described v5e (block shapes, the dynamic trip counts, the [dh, bq]
+# accumulator's transpose, scoped VMEM). The topology is described inside
+# a fixture, never at import (one process at a time may load libtpu: under
+# xdist only this file's worker does).
+
+@pytest.fixture(scope='module')
+def one_chip():
+    import os
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    for key, val in (('TPU_ACCELERATOR_TYPE', 'v5litepod-4'),
+                     ('TPU_WORKER_HOSTNAMES', 'localhost'),
+                     ('TPU_SKIP_MDS_QUERY', '1')):
+        os.environ.setdefault(key, val)
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to ask
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("bh,ln,dh,dtype,causal,heads", [
+    (64, 2048, 64, 'bfloat16', True, 0),     # fd355m-train-2k
+    (32, 2048, 64, 'bfloat16', True, 0),     # fd1.3b-train-4chip, a chip
+    (64, 2048, 64, 'float32', True, 0),      # the train driver's eval forward
+    (32, 2048, 128, 'bfloat16', True, 0),    # the newer configurations' heads
+    (8, 8192, 128, 'float32', True, 0),      # K and V in four blocks
+    (48, 512, 64, 'bfloat16', False, 12),    # BERT: one tile, padding bias
+    (48, 128, 64, 'float32', False, 12),
+])
+def test_mosaic_accepts_the_kernels_at_the_cells_shapes(one_chip, bh, ln, dh,
+                                                        dtype, causal,
+                                                        heads):
+    from paddle_tpu.ops import attention_ops as A
+
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+    scale = dh ** -0.5
+    x, row = sds((bh, ln, dh)), sds((bh, ln), 'float32')
+    bias = [sds((bh // heads, ln), 'float32')] if heads else []
+
+    def fwd(q, k, v, *b):
+        return A._flash_fwd_pallas(q, k, v, scale, causal, False,
+                                   bias=b[0] if b else None,
+                                   n_heads=heads or 1)
+
+    def bwd(q, k, v, o, lse, do, *b):
+        return A._flash_bwd_pallas(q, k, v, o, lse, do, scale, causal,
+                                   False, bias=b[0] if b else None,
+                                   n_heads=heads or 1)
+    text = jax.jit(fwd).lower(x, x, x, *bias).compile().as_text()
+    assert text.count('tpu_custom_call') >= 1
+    text = jax.jit(bwd).lower(x, x, x, x, row, x, *bias).compile().as_text()
+    assert text.count('tpu_custom_call') >= 2
+
+
+def test_kernbench_flash_attention_case(capsys):
+    """tools/kernbench.py's `flash_attention` case at its toy shape through
+    the interpreter: the rule's column and a stated tiling, the three
+    kernels and the whole, and the JSON line the CLI prints."""
+    import json
+    import sys
+    from tools import kernbench
+    argv = sys.argv
+    sys.argv = ['kernbench.py', '--cases', 'flash_attention', '--size',
+                'small', '--rounds', '1', '--k', '1', '--tilings', '128,128']
+    try:
+        kernbench.main()
+    finally:
+        sys.argv = argv
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    (shape, row), = res['flash_attention'].items()
+    assert shape == 'toy [2, 256, 64] float32'
+    assert set(row) == {'rule: 256,256 / 256,256 / 256,256', '128,128'}
+    for col in row.values():
+        assert set(col) == {'fwd', 'bwd_dq', 'bwd_dkv', 'vjp'}
+        for kern in col.values():
+            assert kern['ms'] > 0 and 'error' not in kern

@@ -33,11 +33,22 @@ and the largest error against a float64 product. The sweep that set the
 rule's constants (PERF.md, PR 50), to be run again on another chip or
 another libtpu.
 
+The `flash_attention` case is the third of that kind: the three kernels
+of `ops/attention_ops.py` each alone (forward, dQ, dKV) and the
+`custom_vjp` whole, at the train cells' shapes with `--size bench` (`bh`
+64 / 32, L 2048, `dh` 64, bfloat16, causal; `dh` 128 beside them), a
+tiling a column -- the rule's (`flash_attention_tiling`) and the
+`bq,bk` candidates of `--tilings`: ms a call and TFLOP/s over the causal
+FLOPs (2 matmuls forward, 5 backward, half of L x L). The installed
+JAX's `pallas.ops.tpu.flash_attention` is a yardstick column, never a
+dependency of the package. The sweep that set the rule's constants
+(PERF.md, PR 52).
+
 Usage: python tools/kernbench.py [--tiers off,xla,interpret]
        [--cases softmax_ce,fused_adam,embedding_gather,grouped_matmul,
-                layernorm_residual,ffn_tail,ln_sites]
+                flash_attention,layernorm_residual,ffn_tail,ln_sites]
        [--rounds 5] [--size small|bench] [--mesh N]
-       [--tilings 64,896,512:32,896,512]
+       [--tilings 64,896,512:32,896,512]   (flash_attention: 512,512:256,256)
        [--shapes 'nemotron up,nemotron down']
        (prints one JSON line)
 
@@ -335,6 +346,126 @@ def measure_grouped_matmul(size, rounds, k, tilings=(), shapes=None):
     return out
 
 
+# the train cells' attention: (batch x heads a chip, L, head size, dtype)
+FLASH_SHAPES = {
+    'small': {'toy': (2, 256, 64, 'float32')},
+    'bench': {'fd355m-train-2k': (64, 2048, 64, 'bfloat16'),
+              'fd1.3b-train-4chip, a chip': (32, 2048, 64, 'bfloat16'),
+              'head size 128': (32, 2048, 128, 'bfloat16'),
+              'fd355m eval forward': (64, 2048, 64, 'float32')}}
+
+
+def measure_flash_attention(size, rounds, k, tilings=(), shapes=None):
+    """The three flash kernels each alone and the `custom_vjp` whole
+    (forward + both backward kernels + the delta row sum), causal, at each
+    of the cells' shapes: the rule's tiling ('rule: bq,bk / bq,bk / bq,bk'
+    for forward / dQ / dKV) and each 'bq,bk' of `tilings` given to all
+    three, and `jax.experimental.pallas.ops.tpu.flash_attention` ('jax')
+    as a yardstick. ms a call (best of `rounds` runs of ONE program that
+    makes `k` dependent calls) and TFLOP/s over the causal FLOPs: 2 x 2 x
+    L x L x dh / 2 a (batch x head) forward, 5 / 2 of it backward (dQ 3
+    matmuls of it, dKV 4: the scores are recomputed in both; each of the
+    two also pays the delta row sum, an XLA fusion over dO and O). Off the
+    chip the kernels run through the interpreter: the times mean nothing
+    there, and the yardstick has no column."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from paddle_tpu.ops import attention_ops as A
+    on_chip = jax.default_backend() == 'tpu'
+    interpret = not on_chip
+    out = {}
+    for label, (bh, ln, dh, dtype) in FLASH_SHAPES[size].items():
+        if shapes and label not in shapes:
+            continue
+        scale = dh ** -0.5
+        q, kk, v, do = (jax.random.normal(jax.random.PRNGKey(i),
+                                          (bh, ln, dh), jnp.dtype(dtype))
+                        for i in range(4))
+        matmul = 2 * bh * ln * ln * dh / 2          # one causal matmul
+        row = out.setdefault('%s [%d, %d, %d] %s' % (label, bh, ln, dh,
+                                                     dtype), {})
+        rule = {kern: A.flash_attention_tiling(ln, dh, q.dtype, kern)
+                for kern in ('fwd', 'bwd_dq', 'bwd_dkv')}
+        named = [('rule: ' + ' / '.join('%d,%d' % t[:2]
+                                        for t in rule.values()), rule)]
+        for t in tilings:
+            bq, bk = (int(x) for x in t.split(','))
+            named.append((t, {kern: (bq, bk) + r[2:]
+                              for kern, r in rule.items()}))
+        for name, til in named:
+            def fwd(q, kk, v, til=til):
+                return A._flash_fwd_pallas(q, kk, v, scale, True, interpret,
+                                           tiling=til['fwd'])
+
+            def bwd(q, kk, v, o, lse, do, til=til):
+                return A._flash_bwd_pallas(
+                    q, kk, v, o, lse, do, scale, True, interpret,
+                    tiling_dq=til['bwd_dq'], tiling_dkv=til['bwd_dkv'])
+            try:
+                o, lse = jax.jit(fwd)(q, kk, v)
+            except Exception as e:      # noqa: BLE001 -- advisory tool
+                row[name] = {'error': '%s: %s' % (type(e).__name__,
+                                                  str(e)[:200])}
+                continue
+            # XLA drops the kernel whose results a program does not return
+            row[name] = _time_flash({
+                'fwd': (2, lambda q, kk, v, *_: fwd(q, kk, v)[0]),
+                'bwd_dq': (3, lambda *a: bwd(*a)[0]),
+                'bwd_dkv': (4, lambda *a: bwd(*a)[1:]),
+                'vjp': (7, lambda q, kk, v, o, lse, do: bwd(
+                    q, kk, v, *fwd(q, kk, v), do))},
+                (q, kk, v, o, lse, do), k, rounds, matmul)
+        if on_chip:
+            from jax.experimental.pallas.ops.tpu import flash_attention as U
+            blocks = U.BlockSizes(
+                block_q=512, block_k_major=512, block_k=512, block_b=1,
+                block_q_major_dkv=512, block_k_major_dkv=512,
+                block_k_dkv=512, block_q_dkv=512, block_k_major_dq=512,
+                block_k_dq=512, block_q_dq=512)
+
+            def up(q, kk, v):           # [1, bh, L, dh]
+                return U.flash_attention(q[None], kk[None], v[None],
+                                         causal=True, sm_scale=scale,
+                                         block_sizes=blocks)[0]
+            row['jax 512 x 512'] = _time_flash({
+                'fwd': (2, lambda q, kk, v, *_: up(q, kk, v)),
+                'vjp': (7, lambda q, kk, v, o, lse, do: jax.vjp(
+                    up, q, kk, v)[1](do))},
+                (q, kk, v, o, lse, do), k, rounds, matmul)
+    return out
+
+
+def _time_flash(kernels, operands, k, rounds, matmul):
+    """{kernel: {ms, tflops}} of `kernels` {name: (causal matmuls, fn(q, k,
+    v, o, lse, do))}: each the best of `rounds` runs of one program of `k`
+    dependent calls (the next call's q waits for a bit of this call's
+    result)."""
+    import jax
+    from jax import lax
+    out = {}
+    for kern, (n_mm, fn) in kernels.items():
+        def calls(q, *rest, fn=fn):
+            def body(i, acc):
+                bit = jax.tree_util.tree_leaves(acc)[0][0, 0, 0]
+                return fn(q + (bit != bit).astype(q.dtype), *rest)
+            return lax.fori_loop(0, k, body, fn(q, *rest))
+        try:
+            loop = jax.jit(calls)
+            jax.block_until_ready(loop(*operands))
+            best = float('inf')
+            for _ in range(rounds):
+                t0 = time.perf_counter()
+                jax.block_until_ready(loop(*operands))
+                best = min(best, (time.perf_counter() - t0) / (k + 1))
+            out[kern] = {'ms': round(best * 1e3, 4),
+                         'tflops': round(n_mm * matmul / best / 1e12, 2)}
+        except Exception as e:      # noqa: BLE001 -- advisory tool
+            out[kern] = {'error': '%s: %s' % (type(e).__name__,
+                                              str(e)[:200])}
+    return out
+
+
 def _build_layernorm_residual(size):
     import numpy as np
     import paddle_tpu as fluid
@@ -407,7 +538,8 @@ _CASES = {
     'ln_sites': _build_ln_sites,
 }
 # every case by name: the tier comparisons and the lookup's candidates
-_CASE_NAMES = list(_CASES) + ['embedding_gather', 'grouped_matmul']
+_CASE_NAMES = list(_CASES) + ['embedding_gather', 'grouped_matmul',
+                                 'flash_attention']
 
 
 def _measure(build, tier, rounds, k, size, mesh_n=1):
@@ -490,6 +622,10 @@ def measure_kernbench(cases=None, tiers=None, rounds=5, k=10,
             out[case] = measure_grouped_matmul(size, rounds, k, tilings,
                                                shapes)
             continue
+        if case == 'flash_attention':       # tilings, not tiers
+            out[case] = measure_flash_attention(size, rounds, k, tilings,
+                                                shapes)
+            continue
         out[case] = {}
         for tier in tiers:
             before = monitor.counters()
@@ -525,10 +661,12 @@ def main():
     ap.add_argument('--mesh', type=int, default=1,
                     help='run each case SPMD over mesh(data=N)')
     ap.add_argument('--tilings', default='',
-                    help="grouped_matmul: 'tm,tk,tn' candidates, ':' between")
+                    help="grouped_matmul: 'tm,tk,tn' candidates, ':' between; "
+                         "flash_attention: 'bq,bk'")
     ap.add_argument('--shapes', default='',
-                    help='grouped_matmul: only these labels of '
-                         'GROUPED_MATMULS (comma between)')
+                    help='grouped_matmul, flash_attention: only these '
+                         'labels of GROUPED_MATMULS / FLASH_SHAPES (comma '
+                         'between)')
     args = ap.parse_args()
     if args.mesh > 1 and 'jax' not in sys.modules and \
             '--xla_force_host_platform_device_count' not in \
