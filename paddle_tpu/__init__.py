@@ -11,6 +11,9 @@ reference at /root/reference) designed TPU-first:
 - ragged sequences via static LoD + segment ops (core/lod.py, ops/sequence_ops.py);
 - host-side input pipeline (reader/) instead of reader ops.
 """
+import time as _time
+_import_t0 = _time.perf_counter()
+
 from . import core
 from . import ops  # registers all op lowerings
 from . import framework
@@ -47,6 +50,7 @@ from . import transpiler
 from . import ps
 from . import parallel
 from . import monitor
+from . import coldstart
 from . import trace
 from . import analysis
 from . import goodput
@@ -81,3 +85,7 @@ from . import serving
 from .serving import ServingConfig, ServingEngine
 
 __version__ = '0.1.0'
+
+# set-up's `import` stage (coldstart.py): this package's own import, and
+# jax's where the process had not imported it yet
+coldstart.book('import', _time.perf_counter() - _import_t0)

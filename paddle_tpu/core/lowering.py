@@ -18,8 +18,8 @@ op's index, so replaying a segment inside the vjp closure sees identical
 randomness (dropout masks match between forward env and grad closure).
 """
 import contextlib
-import re
 import threading
+import time
 
 import numpy as np
 import jax
@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from .registry import get_op
+from .. import coldstart
+from .. import monitor
 
 
 # ---------------------------------------------------------------------------
@@ -244,6 +246,47 @@ class LowerContext(object):
         return c
 
 
+_lowering_open = {}      # thread id -> the innermost open _OpClock
+# a label set an op type: a train step lowers ~80
+monitor.set_series_cap('program_lowering_seconds_total', 512)
+
+
+class _OpClock(object):
+    """The SELF time in Python of one IR op's lowering, into
+    program_lowering_seconds_total{op_type}: what an op type's lowering
+    costs every process that traces it — its own tracing, and JAX's of
+    what it calls. An op lowered inside it (a `*_grad` op's forward
+    through ctx.child, a sub-block's ops) books its own and is taken out.
+    Two clock reads an IR op, at trace time only. A `with` block and no
+    wrapper: the lowering's Python stack — which JAX writes into every
+    traced equation's location, and a Mosaic kernel's body carries into
+    the compile cache's key — stays what it is without the clock."""
+
+    __slots__ = ('op_type', 'outer', 'nested_s', 't0')
+
+    def __init__(self, op_type):
+        self.op_type = op_type
+
+    def __enter__(self):
+        tid = threading.get_ident()
+        self.outer = _lowering_open.get(tid)
+        _lowering_open[tid] = self
+        self.nested_s = 0.0
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        whole = time.perf_counter() - self.t0
+        outer = self.outer
+        if outer is None:
+            del _lowering_open[threading.get_ident()]
+        else:
+            _lowering_open[threading.get_ident()] = outer
+            outer.nested_s += whole
+        monitor.inc('program_lowering_seconds_total', whole - self.nested_s,
+                    {'op_type': self.op_type})
+        return False
+
+
 def lower_ops(ctx, ops, lo, hi):
     hook = _active_op_hook()
     for i in range(lo, hi):
@@ -252,7 +295,8 @@ def lower_ops(ctx, ops, lo, hi):
         ctx._static_written = set()
         ctx._twin_written = set()
         if hook is None:
-            get_op(op.type).lower(ctx, op)
+            with _OpClock(op.type):
+                get_op(op.type).lower(ctx, op)
         else:
             hook(ctx, op, lambda op=op: get_op(op.type).lower(ctx, op))
         for n in op.output_arg_names:
@@ -313,7 +357,11 @@ def lower_block(ctx, lo=0):
     ctx.op_index = b
     hook = _active_op_hook()
     if hook is None:
-        _lower_backward(ctx, ops, lo, b, bop)
+        # the pullback — JAX's transposition, every kernel's backward
+        # rule — is `backward`'s own; the forward ops under the vjp book
+        # theirs
+        with _OpClock('backward'):
+            _lower_backward(ctx, ops, lo, b, bop)
     else:
         # the whole differentiated span (forward-under-vjp + pullback +
         # grad binding) attributes to the `backward` op: its interior ops
@@ -665,8 +713,7 @@ def name_after(fn, program, suffix=''):
     """Name `fn` after the program it runs: jax.jit calls the XLA module
     jit_<fn.__name__>, so a device trace's 'XLA Modules' line and every
     op path read jit_lm_train, jit_lm_decode_step ... and not jit_fn."""
-    fn.__name__ = fn.__qualname__ = re.sub(
-        r'[^0-9A-Za-z_.-]', '_', program.name) + suffix
+    fn.__name__ = fn.__qualname__ = coldstart.label_of(program) + suffix
 
 
 class StateCallable(object):
@@ -681,7 +728,8 @@ class StateCallable(object):
     its parameters as it did when the state went in as dicts (another
     order is another schedule: the train step read 1.6 % slower)."""
 
-    __slots__ = ('flat', 'ro_names', 'rw_names', '_fn', '_donate')
+    __slots__ = ('flat', 'ro_names', 'rw_names', '_fn', '_donate',
+                 '_program')
 
     def __init__(self, fn, ro_names, rw_names, program, donate):
         ro_names, rw_names = tuple(sorted(ro_names)), tuple(sorted(rw_names))
@@ -692,6 +740,7 @@ class StateCallable(object):
 
         name_after(flat, program)
         self._fn = flat
+        self._program = coldstart.label_of(program)
         self._donate = (2,) if donate else ()
         self.flat = jax.jit(flat, donate_argnums=self._donate)
         self.ro_names = ro_names
@@ -713,9 +762,11 @@ class StateCallable(object):
         entry that holds an AUTO from shapes alone, and what is called is
         the COMPILED object. `flat`, the entry of everyone else, is
         untouched: an entry's parameters are a schedule."""
-        return jax.jit(self._fn, donate_argnums=self._donate,
-                       in_shardings=(None, tuple(ro_formats), None, None)
-                       ).lower(feed, ro_leaves, rw_leaves, key)
+        with coldstart.stage('trace', self._program):
+            return jax.jit(
+                self._fn, donate_argnums=self._donate,
+                in_shardings=(None, tuple(ro_formats), None, None)
+            ).lower(feed, ro_leaves, rw_leaves, key)
 
     def _flat_args(self, feed, ro_state, rw_state, key):
         return (feed, tuple(ro_state[n] for n in self.ro_names),
@@ -725,8 +776,9 @@ class StateCallable(object):
         return self.flat(*self._flat_args(feed, ro_state, rw_state, key))
 
     def lower(self, feed, ro_state, rw_state, key):
-        return self.flat.lower(
-            *self._flat_args(feed, ro_state, rw_state, key))
+        with coldstart.stage('trace', self._program):
+            return self.flat.lower(
+                *self._flat_args(feed, ro_state, rw_state, key))
 
 
 def build_callable(program, fetch_names, read_names, written_names,

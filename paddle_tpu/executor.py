@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from . import analysis
 from . import blackbox
+from . import coldstart
 from . import goodput
 from . import monitor
 from . import resilience
@@ -346,6 +347,9 @@ def _wire_persistent_cache():
     force ('' for none) and mirrors it in the
     compile_persistent_cache_wired gauge."""
     if _persistent_cache_dir[0] is None:
+        # ahead of the first compile: from here on JAX's own durations
+        # split each set-up frame into its stages
+        coldstart.listen()
         path = jax.config.jax_compilation_cache_dir or ''
         if not path and jax.default_backend() != 'cpu':
             try:
@@ -472,10 +476,35 @@ def _run_phase(name):
     gather, the run key, the flight recorder's step note; dispatch: the
     compiled call; commit: the goodput hook, scope.update, LoD
     propagation; fetch: materialising the fetches, the wait for the
-    device; compile: lowering and the first call of a new signature;
-    segmented: a PADDLE_SEGMENT_HOST_OPS run."""
+    device; compile: lowering and the first call of a new signature (the
+    self time of set-up's frames there, `_compile_frame`); segmented: a
+    PADDLE_SEGMENT_HOST_OPS run."""
     return monitor.phase('run.' + name, 'executor_run_phase_seconds_total',
                          {'phase': name})
+
+
+# a set-up frame inside Executor.run is the run's `compile` phase as well
+_RUN_COMPILE = ('executor_run_phase_seconds_total', {'phase': 'compile'})
+
+
+def _compile_frame(program, stage='first_run', since=None):
+    return coldstart.compile_frame(program, stage, since, *_RUN_COMPILE)
+
+
+def _first_call(frame, state, call, *args):
+    """The first call, `call(*args)`, of a newly made entry inside
+    `frame` (coldstart.compile_frame): jax.jit is lazy, the XLA compile
+    happens inside it, so honest compile wall time spans lowering + that
+    call — its dispatch: the execution runs on behind what the caller
+    does next, as it did before there was a frame (waited for, it cost
+    the train cell 1.2 s of every start). A transient XLA failure
+    (RESOURCE_EXHAUSTED) retries under the 'compile' site's policy."""
+    with frame:
+        try:
+            return call(*args)
+        except Exception as e:          # noqa: BLE001 — classified inside
+            return resilience.retry_after(e, lambda: call(*args),
+                                          site='compile', state=state)
 
 
 class _CompiledEntry(object):
@@ -673,7 +702,8 @@ class BoundProgram(object):
                 jax.tree_util.tree_map(_spec, self.example_feed),
                 tuple(map(_spec, ro)), tuple(map(_spec, rw)), _spec(key),
                 asked)
-            compiled = lowered.compile()
+            with coldstart.stage('compile', self._program):
+                compiled = lowered.compile()
             hit = self._entry.bound[fixed] = (
                 compiled, tuple(compiled.input_formats[0][1]))
             # the analytics mine THIS lowering: registered first, it is
@@ -698,7 +728,8 @@ class BoundProgram(object):
         todo = [i for i, f in enumerate(self._formats or ())
                 if ro[i].format.layout != f.layout]
         if todo:
-            with _compiled_here():
+            # set-up's `place` stage: the host waits for each copy
+            with coldstart.stage('place', program), _compiled_here():
                 for i in todo:
                     self._relay(ro, i)
         ro = tuple(ro)
@@ -1409,7 +1440,7 @@ class Executor(object):
                 # initialization (io-only executors, launcher parents
                 # that must not claim the chip)
                 _wire_persistent_cache()
-                with _run_phase('compile'):
+                with coldstart.stage('trace', program, *_RUN_COMPILE):
                     entry = self._build_entry(program, feed, fetch_names,
                                               static_lods, static_feed,
                                               donate)
@@ -1437,29 +1468,15 @@ class Executor(object):
             program._last_run_key = key_arr
             blackbox.note_step(program)
         if fresh_compile:
-            with _run_phase('compile'):
-                # jax.jit is lazy: the XLA compile happens inside the
-                # FIRST call, so honest compile wall time spans lowering +
-                # that call. A transient XLA failure here
-                # (RESOURCE_EXHAUSTED) retries under the 'compile' site
-                # policy.
-                def _first_call():
-                    with monitor.span('compile'):
-                        return call(feed, ro_state, rw_state, key_arr)
-                try:
-                    fetches, new_state = _first_call()
-                except Exception as e:  # noqa: BLE001 — classified inside
-                    fetches, new_state = resilience.retry_after(
-                        e, _first_call, site='compile', state=rw_state)
-                monitor.observe('compile_seconds',
-                                time.perf_counter() - t_compile)
-                goodput.note_compile(key[0],
-                                     time.perf_counter() - t_compile)
-                # register the executable for XLA cost/memory analytics
-                # (lazy: mined when snapshot/explain/costreport first looks)
-                analysis.record_compiled(entry.fn, program,
-                                         (feed, ro_state, rw_state, key_arr),
-                                         kind='run', donate=donate)
+            frame = _compile_frame(program, since=t_compile)
+            fetches, new_state = _first_call(
+                frame, rw_state, call, feed, ro_state, rw_state, key_arr)
+            goodput.note_compile(key[0], frame.seconds)
+            # register the executable for XLA cost/memory analytics
+            # (lazy: mined when snapshot/explain/costreport first looks)
+            analysis.record_compiled(entry.fn, program,
+                                     (feed, ro_state, rw_state, key_arr),
+                                     kind='run', donate=donate)
         else:
             with _run_phase('dispatch'):
                 # steady-state dispatch: the success path pays one
@@ -1680,8 +1697,8 @@ class Executor(object):
             seg_feed = {n: v for n, v in val_env.items() if n in seg['ins']}
             seg_fetch = list(seg['crossing'])
             entry = seg.get('entry')
-            if entry is None:
-                t_compile = time.perf_counter()
+            fresh = entry is None
+            if fresh:
                 _wire_persistent_cache()
 
                 def _build_segment():
@@ -1711,19 +1728,18 @@ class Executor(object):
                                           'op_offset': seg['lo']})
                     return _CompiledEntry(fn, seg_fetch, ro_names,
                                           rw_names, written, sub, lod_out)
-                try:
-                    entry = _build_segment()
-                except Exception as e:  # noqa: BLE001 — classified inside
-                    entry = resilience.retry_after(e, _build_segment,
-                                                   site='compile')
-                seg['entry'] = entry
                 # segment build cost (the jit compile itself is lazy and
-                # lands in this segment's first call below; device-segment
-                # granularity is close enough for the rare hostseg path)
-                monitor.observe('compile_seconds',
-                                time.perf_counter() - t_compile)
-                goodput.note_compile(key[1],
-                                     time.perf_counter() - t_compile)
+                # lands in this segment's first call below, a set-up
+                # frame of its own; device-segment granularity is close
+                # enough for the rare hostseg path)
+                with _compile_frame(sub, 'trace') as frame:
+                    try:
+                        entry = _build_segment()
+                    except Exception as e:  # noqa: BLE001 — classified inside
+                        entry = resilience.retry_after(e, _build_segment,
+                                                       site='compile')
+                seg['entry'] = entry
+                goodput.note_compile(key[1], frame.seconds)
             # cache=False also for names a LATER segment writes: caching
             # would freeze the caller's init buffer writeable=False even
             # though the scope is rebound right after that later segment —
@@ -1741,7 +1757,6 @@ class Executor(object):
                 # JAX_PLATFORMS left 'cpu' unregistered, plain eager on the
                 # default device (the callback itself runs on host either
                 # way)
-                import contextlib
                 seg_feed = {n: np.asarray(v) for n, v in seg_feed.items()}
                 ro = {n: np.asarray(v) for n, v in ro.items()}
                 rw = {n: np.asarray(v) for n, v in rw.items()}
@@ -1776,12 +1791,14 @@ class Executor(object):
                     resilience.maybe_fault('run')
                     return entry.fn(seg_feed, ro, rw, key_arr)
                 t_disp = time.perf_counter()
-                try:
-                    fetches, new_state = _seg_dispatch()
-                except Exception as e:  # noqa: BLE001 — classified inside
-                    fetches, new_state = resilience.retry_after(
-                        e, _seg_dispatch, site='run', state=rw)
-                    t_disp = time.perf_counter()  # exclude retry backoff
+                with coldstart.stage('first_run', sub, *_RUN_COMPILE) \
+                        if fresh else contextlib.nullcontext():
+                    try:
+                        fetches, new_state = _seg_dispatch()
+                    except Exception as e:  # noqa: BLE001 — classified inside
+                        fetches, new_state = resilience.retry_after(
+                            e, _seg_dispatch, site='run', state=rw)
+                        t_disp = time.perf_counter()  # exclude retry backoff
                 # device segments contribute busy time (no flops: the
                 # per-segment clones don't register analytics); host
                 # segments are host work, not device-productive
@@ -1975,11 +1992,13 @@ class Executor(object):
                     program, fetch_names, needed, written,
                     static_lods=static_lods)
                 return fn, ro_names, rw_names, written
-            try:
-                fn, ro_names, rw_names, written = _build_fused()
-            except Exception as e:      # noqa: BLE001 — classified inside
-                fn, ro_names, rw_names, written = resilience.retry_after(
-                    e, _build_fused, site='compile')
+            with coldstart.stage('trace', program):
+                try:
+                    fn, ro_names, rw_names, written = _build_fused()
+                except Exception as e:  # noqa: BLE001 — classified inside
+                    fn, ro_names, rw_names, written = \
+                        resilience.retry_after(e, _build_fused,
+                                               site='compile')
 
             def fused(stacked_feed, ro, rw, base_key):
                 # carry: ONE merged state dict (all written persistables,
@@ -2038,20 +2057,11 @@ class Executor(object):
         program._last_run_key = key_arr
         blackbox.note_step(program)
         if fresh_compile:
-            # as in run(): jax.jit compiles inside the first call;
-            # transient XLA failures retry under the 'compile' site
-            def _first_call():
-                with monitor.span('compile'):
-                    return entry.fn(stacked, ro_state, rw_state, key_arr)
-            try:
-                fetches, new_state = _first_call()
-            except Exception as e:      # noqa: BLE001 — classified inside
-                fetches, new_state = resilience.retry_after(
-                    e, _first_call, site='compile', state=rw_state)
-            monitor.observe('compile_seconds',
-                            time.perf_counter() - t_compile)
-            goodput.note_compile(cache_key[3],
-                                 time.perf_counter() - t_compile)
+            frame = coldstart.compile_frame(program, since=t_compile)
+            fetches, new_state = _first_call(
+                frame, rw_state, entry.fn, stacked, ro_state, rw_state,
+                key_arr)
+            goodput.note_compile(cache_key[3], frame.seconds)
             # fused analytics register the scan; XLA cost analysis counts
             # the while BODY once (measured: flops identical for 4- and
             # 8-step scans), so the registered flops are per-step and
@@ -2215,10 +2225,11 @@ class Executor(object):
                 lod_out=lod_out, donate=donate_flag)
             return _CompiledEntry(fn, fetch_names, ro_names, rw_names,
                                   written, program, lod_out)
-        try:
-            entry = _build()
-        except Exception as e:          # noqa: BLE001 — classified inside
-            entry = resilience.retry_after(e, _build, site='compile')
+        with coldstart.stage('trace', program):
+            try:
+                entry = _build()
+            except Exception as e:      # noqa: BLE001 — classified inside
+                entry = resilience.retry_after(e, _build, site='compile')
         self._cache_put(key, entry)
         ro_state = {n: self._state_value(scope, n, program)
                     for n in entry.ro_names}
@@ -2229,19 +2240,12 @@ class Executor(object):
             for n in entry.rw_names}
         key_arr = _run_key(program.random_seed, 0, 0)
 
-        def _first_call():
-            with monitor.span('compile'):
-                return entry.fn(feed, ro_state, rw_state, key_arr)
-        try:
-            fetches, new_state = _first_call()
-        except Exception as e:          # noqa: BLE001 — classified inside
-            fetches, new_state = resilience.retry_after(
-                e, _first_call, site='compile', state=rw_state)
-        del fetches, new_state          # scope stays untouched
-        seconds = time.perf_counter() - t0
-        monitor.observe('compile_seconds', seconds)
+        frame = coldstart.compile_frame(program, since=t0)
+        # the outputs are let go: the scope stays untouched
+        _first_call(frame, rw_state, entry.fn, feed, ro_state, rw_state,
+                    key_arr)
         return {'compiled': True, 'cached': False,
-                'seconds': round(seconds, 4)}
+                'seconds': round(frame.seconds, 4)}
 
     # ------------------------------------------------------------------
     def explain(self, program=None, feed=None, fetch_list=None, scope=None,
@@ -2291,7 +2295,19 @@ class Executor(object):
             # narrows int64/float64, and that narrowed dtype must not
             # leak back into the scope (save_persistables would then
             # checkpoint the narrowed array).
-            dv = jnp.asarray(v)
+            # set-up's `place` stage: the upload's dispatch (the copy
+            # itself is not waited for), where it is a once-only move —
+            # the scope keeps the device copy below, or the run rebinds
+            # the name (cache=False). A value that cannot be cached is
+            # uploaded every call, a cost of the steady path and no
+            # set-up: it opens no frame
+            if not cache or (
+                    isinstance(v, np.ndarray) and v.base is None and
+                    jax.dtypes.canonicalize_dtype(v.dtype) == v.dtype):
+                with coldstart.stage('place', program):
+                    dv = jnp.asarray(v)
+            else:
+                dv = jnp.asarray(v)
             if cache and isinstance(v, np.ndarray) and dv.dtype == v.dtype \
                     and dv.shape == v.shape:
                 # The scope now answers reads from the device copy, so a

@@ -17,6 +17,7 @@ import contextlib
 import copy
 import numpy as np
 
+from . import coldstart
 from . import unique_name
 from .core.types import VarType, convert_np_dtype_to_dtype_, dtype_str
 
@@ -595,9 +596,13 @@ def program_guard(main_program, startup_program=None):
     prev_start = None
     if startup_program is not None:
         prev_start = switch_startup_program(startup_program)
-    try:
-        yield
-    finally:
-        switch_main_program(prev_main)
-        if prev_start is not None:
-            switch_startup_program(prev_start)
+    # the Python front end — layer calls, append_backward, minimize, AMP's
+    # rewrite — is set-up's `build` stage (coldstart.py): self time, so a
+    # run or a compile made inside the guard books its own
+    with coldstart.stage('build', main_program):
+        try:
+            yield
+        finally:
+            switch_main_program(prev_main)
+            if prev_start is not None:
+                switch_startup_program(prev_start)

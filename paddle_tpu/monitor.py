@@ -89,8 +89,20 @@ def _env_int(name, default):
         return default
 
 
-def _max_series():
-    return _env_int('PADDLE_MONITOR_MAX_SERIES', 64)
+# metric name -> the cap its owning module stated for it (set_series_cap)
+_series_caps = {}
+
+
+def set_series_cap(name, n):
+    """The module that books `name` states how many label sets it may
+    hold, where the code bounds them and not the traffic — a program's
+    name, an op's type — and one honest process outnumbers the default
+    (PADDLE_MONITOR_MAX_SERIES). Instrumentation-internal, like phase."""
+    _series_caps[name] = int(n)
+
+
+def _max_series(name=None):
+    return _series_caps.get(name) or _env_int('PADDLE_MONITOR_MAX_SERIES', 64)
 
 
 # exact-quantile sample ring per histogram series: while a series has
@@ -196,10 +208,10 @@ def _labels_key(labels):
     return tuple(sorted((str(k), str(v)) for k, v in labels.items()))
 
 
-def _capped_key(series, key):
+def _capped_key(series, key, name=None):
     """Resolve `key` inside one metric's series dict, honoring the
     cardinality cap. Callers hold _lock."""
-    if key in series or len(series) < _max_series():
+    if key in series or len(series) < _max_series(name):
         return key
     d = _counters.setdefault(_DROPPED, {})
     d[()] = d.get((), 0.0) + 1
@@ -212,7 +224,7 @@ def _inc_key(name, key, value):
         if series is None:
             series = _counters[name] = {}
         if key not in series:
-            key = _capped_key(series, key)
+            key = _capped_key(series, key, name)
         series[key] = series.get(key, 0.0) + value
 
 
@@ -523,6 +535,12 @@ def phase(name, counter, labels=None):
     'paddle_tpu:<name>' TraceAnnotation while a profiler session is live.
     Like timed_span, an instrumentation helper and not part of
     __all__."""
+    return _Phase(phase_series(name, counter, labels))
+
+
+def phase_series(name, counter, labels=None):
+    """What a `_Phase` of these arguments is made from (coldstart.py
+    makes its frames, a subclass, from the same)."""
     # a phase opens thousands of times a second with the same arguments:
     # its series key and annotation name are made once per name
     known = _phase_series.get(name)
@@ -530,7 +548,7 @@ def phase(name, counter, labels=None):
         known = _phase_series[name] = (
             counter, dict(labels) if labels else None,
             _labels_key(labels), ANNOTATION_PREFIX + name)
-    return _Phase(known)
+    return known
 
 
 def spans():

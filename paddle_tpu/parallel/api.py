@@ -21,6 +21,7 @@ import numpy as np
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import coldstart
 from .. import monitor
 from ..core import lowering
 from ..framework import Variable
@@ -128,9 +129,78 @@ class MeshRunner(object):
         return jitted, ro_names, rw_names, lod_out
 
     def run(self, feed, fetch_list, scope, return_numpy=True):
-        from ..executor import global_scope, Executor
+        """One step, in Executor.run's phases
+        (executor_run_phase_seconds_total{phase=prepare|dispatch|commit|
+        fetch}; a signature's first call is set-up's frame and the
+        `compile` phase), counted in executor_run_total."""
+        from ..executor import (global_scope, _run_phase, _compile_frame,
+                                _fetched, _goodput_leaf)
+        from .. import analysis
+        from .. import goodput
         if scope is None:
             scope = global_scope()
+        monitor.inc('executor_run_total')
+        with _run_phase('prepare'):
+            entry, feed, ro, rw, key_arr, fetch_names, since = \
+                self._prepare(feed, fetch_list, scope)
+        program, fn = self._program, entry.fn
+        global _ACTIVE_MESH, _ACTIVE_PARAM_SPEC
+        prev, _ACTIVE_MESH = _ACTIVE_MESH, self._mesh
+        prev_spec, _ACTIVE_PARAM_SPEC = (_ACTIVE_PARAM_SPEC,
+                                         self._rules.spec_for)
+        try:
+            with self._mesh:
+                if since is not None:
+                    # the jit compile lands inside this first call: its
+                    # wall is compile cost (the goodput 'compile' loss
+                    # bucket)
+                    with _compile_frame(program, since=since) as frame:
+                        fetches, new_state = fn(feed, ro, rw, key_arr)
+                else:
+                    with _run_phase('dispatch'):
+                        t_disp = time.perf_counter()
+                        fetches, new_state = fn(feed, ro, rw, key_arr)
+                        t_staged = time.perf_counter()
+        finally:
+            _ACTIVE_MESH = prev
+            _ACTIVE_PARAM_SPEC = prev_spec
+        with _run_phase('commit'):
+            fp = program._fingerprint()
+            if since is not None:
+                # the executable registers for XLA flops/bytes analytics
+                # so mesh dispatches carry MFU like every other kind
+                goodput.note_compile(fp, frame.seconds)
+                analysis.record_compiled(fn, program,
+                                         (feed, ro, rw, key_arr),
+                                         kind='mesh')
+            else:
+                goodput.note_dispatch(fp, 'mesh', t_disp, t_staged,
+                                      leaf=_goodput_leaf(new_state,
+                                                         list(fetches)))
+            scope.update(new_state)
+            # propagate produced LoDs of written persistables into the
+            # scope
+            for n in new_state:
+                lod = entry.lod_out.get(n)
+                if lod:
+                    scope._lods[n] = lod
+                else:
+                    scope._lods.pop(n, None)
+        if not return_numpy:
+            return list(fetches)
+        from .spmd import DataParallelRunner
+        host = DataParallelRunner._fetch_to_host
+        with _run_phase('fetch'):
+            return [
+                _fetched(host(f), entry.lod_out[n])
+                if entry.lod_out.get(n) else host(f)
+                for n, f in zip(fetch_names, fetches)]
+
+    def _prepare(self, feed, fetch_list, scope):
+        """Everything of a run ahead of the sharded call: (entry, feed,
+        ro, rw, key, fetch names, and — for a signature's first run,
+        whose entry was made here — when its making began)."""
+        from ..executor import Executor
         program = self._program
         exe = Executor()
         feed, feed_lods = exe._prepare_feed(program, feed or {})
@@ -146,26 +216,27 @@ class MeshRunner(object):
         key = (program._version, exe._feed_signature(feed, static_lods),
                tuple(fetch_names))
         entry = self._cache.get(key)
-        fresh_compile = entry is None
-        t_compile = time.perf_counter()
-        if fresh_compile:
-            from ..executor import _wire_persistent_cache
+        since = None
+        if entry is None:
+            since = time.perf_counter()
+            from ..executor import _wire_persistent_cache, _RUN_COMPILE
             _wire_persistent_cache()
-            fn_, ro_, rw_, lod_out_ = self.compile(
-                {k: (v.shape, v.dtype) for k, v in feed.items()},
-                fetch_names, scope, feed_lods=static_lods)
+            with coldstart.stage('trace', program, *_RUN_COMPILE):
+                fn_, ro_, rw_, lod_out_ = self.compile(
+                    {k: (v.shape, v.dtype) for k, v in feed.items()},
+                    fetch_names, scope, feed_lods=static_lods)
             entry = _MeshEntry(
                 fn_, ro_, rw_, lod_out_,
                 {n: self._sharding(self._rules.spec_for(n))
                  for n in list(ro_) + list(rw_)})
             self._cache[key] = entry
-        fn, ro_names, rw_names = entry.fn, entry.ro_names, entry.rw_names
+        ro_names, rw_names = entry.ro_names, entry.rw_names
         ro = {n: exe._state_value(scope, n, program) for n in ro_names}
         rw = {n: exe._state_value(scope, n, program) for n in rw_names}
         if jax.process_count() == 1:
             from .spmd import place_state
-            ro = place_state(scope, ro, entry.state_shardings)
-            rw = place_state(scope, rw, entry.state_shardings)
+            ro = place_state(scope, ro, entry.state_shardings, program)
+            rw = place_state(scope, rw, entry.state_shardings, program)
         self._run_counter += 1
         from ..executor import _run_key, _next_program_run
         key_arr = _run_key(program.random_seed, _next_program_run(program),
@@ -197,51 +268,4 @@ class MeshRunner(object):
             karr = np.asarray(key_arr)
             key_arr = jax.make_array_from_callback(
                 karr.shape, self._sharding(P()), lambda idx: karr[idx])
-        global _ACTIVE_MESH, _ACTIVE_PARAM_SPEC
-        prev, _ACTIVE_MESH = _ACTIVE_MESH, self._mesh
-        prev_spec, _ACTIVE_PARAM_SPEC = (_ACTIVE_PARAM_SPEC,
-                                         self._rules.spec_for)
-        t_disp = time.perf_counter()
-        try:
-            with self._mesh:
-                fetches, new_state = fn(feed, ro, rw, key_arr)
-        finally:
-            _ACTIVE_MESH = prev
-            _ACTIVE_PARAM_SPEC = prev_spec
-        from .. import analysis
-        from .. import goodput
-        from ..executor import _goodput_leaf
-        fp = program._fingerprint()
-        if fresh_compile:
-            # the jit compile landed inside this first call: its wall is
-            # compile cost (the goodput 'compile' loss bucket), and the
-            # executable registers for XLA flops/bytes analytics so mesh
-            # dispatches carry MFU like every other kind
-            compile_s = time.perf_counter() - t_compile
-            monitor.observe('compile_seconds', compile_s)
-            goodput.note_compile(fp, compile_s)
-            analysis.record_compiled(fn, program,
-                                     (feed, ro, rw, key_arr),
-                                     kind='mesh')
-        else:
-            goodput.note_dispatch(fp, 'mesh', t_disp,
-                                  time.perf_counter(),
-                                  leaf=_goodput_leaf(new_state,
-                                                     list(fetches)))
-        scope.update(new_state)
-        # propagate produced LoDs of written persistables into the scope
-        for n in new_state:
-            lod = entry.lod_out.get(n)
-            if lod:
-                scope._lods[n] = lod
-            else:
-                scope._lods.pop(n, None)
-        from ..executor import _fetched
-        if return_numpy:
-            from .spmd import DataParallelRunner
-            host = DataParallelRunner._fetch_to_host
-            return [
-                _fetched(host(f), entry.lod_out[n])
-                if entry.lod_out.get(n) else host(f)
-                for n, f in zip(fetch_names, fetches)]
-        return list(fetches)
+        return entry, feed, ro, rw, key_arr, fetch_names, since

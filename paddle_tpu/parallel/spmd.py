@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import coldstart
 from .. import monitor
 from ..core import lowering
 from ..framework import Variable
@@ -40,7 +41,7 @@ class _Entry(object):
         self.lod_out = lod_out if lod_out is not None else {}
 
 
-def place_state(scope, state, shardings):
+def place_state(scope, state, shardings, program='program'):
     """Lay single-process `state` ({name: array}) out as `shardings`
     ({name: NamedSharding}) before it is handed to the sharded jit, ONCE:
     every moved array is rebound into the scope, so later runs (and
@@ -53,7 +54,9 @@ def place_state(scope, state, shardings):
     (counted in spmd_state_migrated_total). jit could move the first kind
     itself, but a step-1 input on one device and a step-2 input on the
     mesh are two different lowerings to it: the same step compiled twice,
-    unseen by compile_cache_miss."""
+    unseen by compile_cache_miss. A move is set-up's `place` stage for
+    `program` (coldstart.py: the host's time in the call, the copy is not
+    waited for); a leaf found in place opens nothing."""
     out = {}
     for n, v in state.items():
         target = shardings[n]
@@ -61,7 +64,8 @@ def place_state(scope, state, shardings):
                 and not v.sharding.is_equivalent_to(target, v.ndim):
             if getattr(v, '_committed', False):
                 monitor.inc('spmd_state_migrated_total')
-            v = jax.device_put(v, target)
+            with coldstart.stage('place', program):
+                v = jax.device_put(v, target)
             scope.set(n, v)
         out[n] = v
     return out
@@ -184,9 +188,80 @@ class DataParallelRunner(object):
                       state_shard, lod_out)
 
     def run(self, executor, feed, fetch_list, scope, return_numpy):
-        from ..executor import global_scope
+        """One step, in the phases Executor.run has
+        (executor_run_phase_seconds_total{phase}): prepare — feed
+        preparation, the signature, every state leaf looked up and found
+        in place, the run key; dispatch — the sharded call (a signature's
+        first is set-up's frame, and the `compile` phase); commit — the
+        scope rebind, LoDs; fetch — the wait for the device. Runs are
+        counted where the CompiledProgram delegates (executor_run_total,
+        compiler.py)."""
+        from ..executor import _run_phase, _compile_frame, global_scope
         if scope is None:
             scope = global_scope()
+        with _run_phase('prepare'):
+            entry, feed, ro_state, rw_state, key_arr, fetch_names, since = \
+                self._prepare(executor, feed, fetch_list, scope)
+        program = self._program
+        from . import api as _papi
+        prev, _papi._ACTIVE_MESH = _papi._ACTIVE_MESH, self._mesh
+        _, reduce_mode = self._strategy_knobs()
+        prev_spec = _papi._ACTIVE_PARAM_SPEC
+        # fused units partition state by its actual placement: replicated
+        # in plain DP, the ZeRO-style reduce-mode spec otherwise
+        _papi._ACTIVE_PARAM_SPEC = (
+            lambda n: self._state_sharding(program, n, reduce_mode,
+                                           self._mesh).spec)
+        try:
+            with self._mesh:
+                if since is not None:
+                    # like the serial executor: jax.jit is lazy, the XLA
+                    # compile happens inside the FIRST call — compile wall
+                    # time must cover it, not just the jit construction
+                    with _compile_frame(program, since=since):
+                        fetches, new_state = entry.fn(feed, ro_state,
+                                                      rw_state, key_arr)
+                else:
+                    with _run_phase('dispatch'):
+                        fetches, new_state = entry.fn(feed, ro_state,
+                                                      rw_state, key_arr)
+        finally:
+            _papi._ACTIVE_MESH = prev
+            _papi._ACTIVE_PARAM_SPEC = prev_spec
+        with _run_phase('commit'):
+            from .. import flags as _flags
+            if _flags.get_flags('check_nan_inf'):
+                from ..executor import _check_nan_inf
+                _check_nan_inf(
+                    {n: self._fetch_to_host(v)
+                     for n, v in new_state.items()},
+                    dict(zip(fetch_names,
+                             [self._fetch_to_host(f) for f in fetches])))
+            if _flags.get_flags('benchmark'):
+                with _run_phase('fetch'):
+                    jax.block_until_ready(fetches)
+            scope.update(new_state)
+            for n in new_state:
+                lod = entry.lod_out.get(n)
+                if lod:
+                    scope._lods[n] = lod
+                else:
+                    scope._lods.pop(n, None)
+        if not return_numpy:
+            return list(fetches)
+        from ..executor import _fetched
+        with _run_phase('fetch'):
+            out = []
+            for n, f in zip(fetch_names, fetches):
+                host = self._fetch_to_host(f)
+                lod = entry.lod_out.get(n)
+                out.append(_fetched(host, lod) if lod else host)
+            return out
+
+    def _prepare(self, executor, feed, fetch_list, scope):
+        """Everything of a run ahead of the sharded call: (entry, feed,
+        ro_state, rw_state, key, fetch names, and — for a signature's
+        first run, whose entry was made here — when its making began)."""
         program = self._program
         feed, feed_lods = executor._prepare_feed(program, feed or {})
         # LoD-carrying scope state binds statically, like the serial
@@ -214,14 +289,15 @@ class DataParallelRunner(object):
                executor._feed_signature(feed, static_lods),
                tuple(fetch_names))
         entry = self._cache.get(key)
-        fresh_compile = entry is None
-        if fresh_compile:
+        since = None
+        if entry is None:
             monitor.inc('compile_cache_miss')
-            t_compile = time.perf_counter()
-            from ..executor import _wire_persistent_cache
+            since = time.perf_counter()
+            from ..executor import _wire_persistent_cache, _RUN_COMPILE
             _wire_persistent_cache()
-            entry = self._compile(feed, fetch_names,
-                                  feed_lods=static_lods)
+            with coldstart.stage('trace', program, *_RUN_COMPILE):
+                entry = self._compile(feed, fetch_names,
+                                      feed_lods=static_lods)
             self._cache[key] = entry
         else:
             monitor.inc('compile_cache_hit')
@@ -231,8 +307,10 @@ class DataParallelRunner(object):
         rw_state = {n: executor._state_value(scope, n, program)
                     for n in entry.rw_names}
         if nproc == 1:
-            ro_state = place_state(scope, ro_state, entry.state_shardings)
-            rw_state = place_state(scope, rw_state, entry.state_shardings)
+            ro_state = place_state(scope, ro_state, entry.state_shardings,
+                                   program)
+            rw_state = place_state(scope, rw_state, entry.state_shardings,
+                                   program)
         if nproc > 1:
             # assemble global arrays from per-process host-local data
             # (feeds: local batch shard; state: every process holds the
@@ -268,57 +346,7 @@ class DataParallelRunner(object):
             key_arr = jax.make_array_from_callback(
                 karr.shape, NamedSharding(self._mesh, P()),
                 lambda idx: karr[idx])
-        from . import api as _papi
-        prev, _papi._ACTIVE_MESH = _papi._ACTIVE_MESH, self._mesh
-        _, reduce_mode = self._strategy_knobs()
-        prev_spec = _papi._ACTIVE_PARAM_SPEC
-        # fused units partition state by its actual placement: replicated
-        # in plain DP, the ZeRO-style reduce-mode spec otherwise
-        _papi._ACTIVE_PARAM_SPEC = (
-            lambda n: self._state_sharding(program, n, reduce_mode,
-                                           self._mesh).spec)
-        try:
-            with self._mesh:
-                if fresh_compile:
-                    # like the serial executor: jax.jit is lazy, the XLA
-                    # compile happens inside the FIRST call — compile wall
-                    # time must cover it, not just the jit construction
-                    with monitor.span('compile'):
-                        fetches, new_state = entry.fn(feed, ro_state,
-                                                      rw_state, key_arr)
-                    monitor.observe('compile_seconds',
-                                    time.perf_counter() - t_compile)
-                else:
-                    fetches, new_state = entry.fn(feed, ro_state, rw_state,
-                                                  key_arr)
-        finally:
-            _papi._ACTIVE_MESH = prev
-            _papi._ACTIVE_PARAM_SPEC = prev_spec
-        from .. import flags as _flags
-        if _flags.get_flags('check_nan_inf'):
-            from ..executor import _check_nan_inf
-            _check_nan_inf(
-                {n: self._fetch_to_host(v) for n, v in new_state.items()},
-                dict(zip(fetch_names,
-                         [self._fetch_to_host(f) for f in fetches])))
-        if _flags.get_flags('benchmark'):
-            jax.block_until_ready(fetches)
-        scope.update(new_state)
-        for n in new_state:
-            lod = entry.lod_out.get(n)
-            if lod:
-                scope._lods[n] = lod
-            else:
-                scope._lods.pop(n, None)
-        from ..executor import _fetched
-        if return_numpy:
-            out = []
-            for n, f in zip(fetch_names, fetches):
-                host = self._fetch_to_host(f)
-                lod = entry.lod_out.get(n)
-                out.append(_fetched(host, lod) if lod else host)
-            return out
-        return list(fetches)
+        return entry, feed, ro_state, rw_state, key_arr, fetch_names, since
 
     @staticmethod
     def _fetch_to_host(f):

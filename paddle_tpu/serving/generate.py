@@ -142,6 +142,7 @@ import time
 import numpy as np
 
 from .. import blackbox
+from .. import coldstart
 from .. import goodput
 from .. import monitor
 from .. import trace as trace_mod
@@ -1102,7 +1103,7 @@ class GenerateEngine(object):
         before = monitor.counters()
         S = self.config.slots
         reused = 0
-        with monitor.span('generate.warmup'):
+        with coldstart.stage('first_run', 'generate.warmup'):
             # the step first: it runs on every token, so where the
             # backend lets a bound entry choose how its weights lie
             # (BoundProgram), the step chooses and the prefills, bound
