@@ -10,8 +10,10 @@ the rest densify via _dense_grad like reference ops without a SelectedRows
 kernel.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 
+from .. import monitor
 from ..core.registry import register_op
 from ..core.selected_rows import SelectedRows
 
@@ -97,7 +99,19 @@ def _lars_momentum(ctx, op):
 def _adam(ctx, op):
     """reference operators/optimizers/adam_op.h: dense + SparseAdamFunctor
     over merged grad rows (lazy semantics: only touched rows advance their
-    moments; BetaPow still advances globally)."""
+    moments; BetaPow still advances globally).
+
+    A dense MATRIX's update is a pass of its own over the finished
+    gradient (`optimization_barrier`): left to itself XLA:TPU takes the
+    update — three float32 streams in, three out — into the fusion of the
+    weight-gradient GEMM that produces the gradient, and tiles the GEMM
+    round the epilogue's footprint (1.5-3.9 x its forward twin's cycles
+    at d_model 1024; PERF.md, PR 54). Behind the barrier the GEMM is tiled
+    for itself and the update is a plain loop fusion over the donated
+    buffers, as a vector's always was; the values are the same bit for
+    bit. `adam_update_form_total{form=own_pass|inline}` counts the choice,
+    once a call site at trace time; what the op sees of the gradient
+    decides (`_own_pass`)."""
     p = ctx.in1(op, 'Param')
     g = ctx.in1(op, 'Grad')
     m1 = ctx.in1(op, 'Moment1')
@@ -109,19 +123,48 @@ def _adam(ctx, op):
     b2 = op.attr('beta2', 0.999)
     eps = op.attr('epsilon', 1e-8)
     lr_t = lr * jnp.sqrt(1 - b2p) / (1 - b1p)
+    own_pass = _own_pass(g)
+    monitor.inc('adam_update_form_total',
+                labels={'form': 'own_pass' if own_pass else 'inline'})
     if isinstance(g, SelectedRows):
         po, m1o, m2o = _adam_sparse(p, g, m1, m2, lr_t, b1, b2, eps)
-        ctx.out(op, 'ParamOut', po)
-        ctx.out(op, 'Moment1Out', m1o)
-        ctx.out(op, 'Moment2Out', m2o)
     else:
-        m1o = b1 * m1 + (1 - b1) * g
-        m2o = b2 * m2 + (1 - b2) * g * g
-        ctx.out(op, 'ParamOut', p - lr_t * m1o / (jnp.sqrt(m2o) + eps))
-        ctx.out(op, 'Moment1Out', m1o)
-        ctx.out(op, 'Moment2Out', m2o)
+        if own_pass:
+            g = jax.lax.optimization_barrier(g)
+        po, m1o, m2o = _adam_dense(p, g, m1, m2, lr_t, b1, b2, eps)
+    ctx.out(op, 'ParamOut', po)
+    ctx.out(op, 'Moment1Out', m1o)
+    ctx.out(op, 'Moment2Out', m2o)
     ctx.out(op, 'Beta1PowOut', (b1p * b1).reshape(1))
     ctx.out(op, 'Beta2PowOut', (b2p * b2).reshape(1))
+
+
+# The fewest elements of a gradient whose update `adam` cuts off the GEMM.
+# fd355m-train-2k on the v5e, us a layer a step (my chip runs, PR 54):
+# attn.proj.w, 1 048 576 elements: 92.4 inline (tiled like its forward twin,
+# 96.7), 92.7 + 41.8 cut; attn.qkv.w, 3 145 728: 564.5 inline, 279.5 + 25.4
+# cut. The whole step with proj inline: 27 622 | 27 638 tokens/s, cut:
+# 27 426 | 27 541.
+_OWN_PASS_MIN_ELEMENTS = 2 * 1024 * 1024
+
+
+def _own_pass(g):
+    """Whether the `adam` op cuts gradient `g` off its producer: a dense
+    gradient of rank >= 2 and `_OWN_PASS_MIN_ELEMENTS` on one device —
+    there its producer is the weight-gradient GEMM, and an epilogue of
+    that size is what XLA:TPU tiles the GEMM round. Under a mesh of
+    several devices the gradient leaves a collective (the reduce-scatter
+    or all-reduce of the data axis) and the update already is a pass of
+    its own; a cut there only splits the gradient's widening convert off
+    the update (48 `convert_convert_fusion`s more in
+    `fd1.3b-train-4chip`'s compiled step, 8 B a parameter of a chip's
+    shard)."""
+    if isinstance(g, SelectedRows) or g.ndim < 2 \
+            or g.size < _OWN_PASS_MIN_ELEMENTS:
+        return False
+    from ..parallel.api import get_active_mesh
+    mesh = get_active_mesh()
+    return mesh is None or mesh.size == 1
 
 
 def _adam_dense(p, g, m1, m2, lr_t, b1, b2, eps):
@@ -221,7 +264,6 @@ def _fused_adam_flat(p, g, m1, m2, lr_t, b1, b2, eps, interpret):
     """One elementwise Pallas pass over the flattened-and-concatenated
     parameter set ([L] padded to (R, 128) tiles)."""
     import functools
-    import jax
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     L = p.shape[0]

@@ -352,3 +352,71 @@ def test_xla_left_alone_tiles_nemotrons_experts_by_128(one_chip,
                              ((256, 2688), '256,128,128'),
                              ((768, 1856), '256,128,128'),
                              ((768, 2688), '256,128,128')], calls
+
+
+# ---------------------------------------------------------------------------
+# The other GEMM whose tiles were not its own (ISSUE 54): a weight-gradient
+# GEMM with Adam's update as its epilogue. It lives in this file for the
+# fixture: one file's worker loads libtpu.
+
+def _lm(d_model, heads, d_ff, vocab, seq_len):
+    return T.LMConfig(vocab_size=vocab, seq_len=seq_len, d_model=d_model,
+                      n_head=heads, n_layer=1, d_ff=d_ff, dropout=0.1,
+                      attn_dropout=0.0, use_flash_attention=True)
+
+
+def test_no_weight_gradient_gemm_is_tiled_round_adams_update(one_chip,
+                                                            monkeypatch):
+    """The finding, not the implementation: a 1-layer LM train step at
+    `fd355m-train-2k`'s widths (d_model 1024, d_ff 4096, 4 x 2048 tokens;
+    a small vocabulary) under `mp.decorate(Adam(fuse=False))`, compiled
+    for the described chip. With the update as their epilogue XLA:TPU
+    tiled the QKV, FFN and head dW GEMMs round six float32 streams, at
+    1.5-3.9 x their forward twins' cycles (PERF.md, PR 54). Now NO fusion
+    holds a `convolution` and writes more than one float32 array of a
+    parameter's shape (the update's parameter and two moments) — but the
+    one whose epilogue is small enough to cost the GEMM nothing,
+    `attn.proj.w`'s 1 M elements, which the compiler itself puts at its
+    forward twin's cycles."""
+    from tools.fusioncost import conv_fusions, lm_train_step_hlo
+    monkeypatch.setenv('PADDLE_FUSED_TIER', 'pallas')   # the chip's tier
+    d, f, v = 1024, 4096, 2048
+    rows = conv_fusions(lm_train_step_hlo(one_chip, _lm(d, 16, f, v, 2048),
+                                          4))
+    matrices = {'f32[%d,%d]' % s for s in
+                ((d, 3 * d), (d, d), (d, f), (f, d), (d, v), (v, d))}
+
+    def held(r):
+        return [o for o in r['outputs'] if o in matrices]
+    # qkv, ffn1, ffn2 and the head: each dW GEMM a fusion of its own, and
+    # the compiler says what each costs
+    alone = [r for r in rows if len(held(r)) == 1
+             and r['dim_labels'][0].startswith('fb_')]
+    assert {held(r)[0] for r in alone} >= {
+        'f32[%d,%d]' % s for s in ((d, 3 * d), (d, f), (f, d), (d, v))}, rows
+    assert all(r['estimated_cycles'] and r['windows'] for r in alone), alone
+    with_update = [r for r in rows if len(held(r)) > 1]
+    assert [set(held(r)) for r in with_update] == [{'f32[1024,1024]'}], \
+        with_update
+    twin, = [r for r in rows if r['outputs'] == ['f32[4,2048,1024]']
+             and r['name'].startswith('convolution_add_fusion')]
+    assert with_update[0]['estimated_cycles'] \
+        <= 1.1 * twin['estimated_cycles'], (with_update, twin)
+
+
+def test_fusioncost_prints_the_table_at_toy_width(one_chip, monkeypatch,
+                                                  capsys):
+    """tools/fusioncost.py end to end: one JSON line a fusion that holds a
+    convolution, with XLA's own cycles and window bounds."""
+    from tools import fusioncost
+    monkeypatch.setenv('PADDLE_FUSED_TIER', 'pallas')
+    assert fusioncost.main(['--layers', '1', '--d-model', '128', '--heads',
+                            '2', '--d-ff', '256', '--vocab', '512',
+                            '--sequences', '2', '--seq-len', '128']) == 0
+    rows = [json.loads(line) for line in capsys.readouterr().out.splitlines()
+            if line.startswith('{')]
+    assert rows and all(set(r) == {'name', 'kind', 'outputs', 'dim_labels',
+                                   'estimated_cycles', 'windows'}
+                        for r in rows), rows
+    assert any(r['estimated_cycles'] for r in rows), rows
+    assert any('f32[128,256]' in r['outputs'] for r in rows), rows

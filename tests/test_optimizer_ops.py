@@ -203,3 +203,91 @@ def test_decayed_adagrad_and_adamax():
                                          ).astype('float32'),
                             'MomentOut': mo, 'InfNormOut': info}
     A().check_output(atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# adam: a dense matrix's update is a pass of its own over the finished
+# gradient (an optimization barrier), everything else is left to XLA's
+# fusion — and the values are the ones the plain expressions give
+
+class _AdamOp(object):
+    """The `adam` lowering's view of an op: slots name themselves."""
+
+    def input(self, slot):
+        return [slot]
+
+    output = input
+
+    def attr(self, name, default=None):
+        return {'beta1': 0.9, 'beta2': 0.999, 'epsilon': 1e-8}.get(name,
+                                                                   default)
+
+
+class _Ctx(object):
+    def __init__(self, env):
+        self.env = env
+
+    def in1(self, op, slot, default=None):
+        return self.env.get(slot, default)
+
+    def out(self, op, slot, value, idx=0):
+        self.env[slot] = value
+
+
+def _adam_case(kind):
+    import jax.numpy as jnp
+    from paddle_tpu.core.selected_rows import SelectedRows
+    shape = {'matrix': (2048, 1024), 'cube': (2, 1024, 1024),
+             'under the floor': (2047, 1024), 'vector': (7,),
+             'rows': (6, 5)}[kind]
+    p, m1 = jnp.asarray(_rand(shape, 10)), jnp.asarray(_rand(shape, 11))
+    m2 = jnp.asarray(_rand(shape, 12, 0, 1))
+    if kind == 'rows':
+        g = SelectedRows(jnp.asarray([4, 1, 4], jnp.int32),
+                         jnp.asarray(_rand((3, 5), 13)), 6)
+    else:
+        g = jnp.asarray(_rand(shape, 13))
+    return p, g, m1, m2
+
+
+@pytest.mark.parametrize('kind,form', [
+    ('matrix', 'own_pass'), ('cube', 'own_pass'),
+    ('under the floor', 'inline'), ('vector', 'inline'), ('rows', 'inline')])
+def test_adam_update_form_and_values(kind, form):
+    """The op's three outputs are bit for bit `_adam_dense`'s (a
+    SelectedRows gradient: `_adam_sparse`'s) under one jit each, and
+    `adam_update_form_total{form}` counts the call site once, at trace
+    time: `own_pass` a dense gradient of rank >= 2 and 2 M elements,
+    `inline` what the rule leaves to XLA (a smaller matrix, vectors,
+    sparse rows)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import monitor
+    from paddle_tpu.core.registry import _registry
+    from paddle_tpu.ops import optimizer_ops as O
+    p, g, m1, m2 = _adam_case(kind)
+    lr = jnp.asarray([0.001], jnp.float32)
+    b1p = jnp.asarray([0.9 ** 3], jnp.float32)
+    b2p = jnp.asarray([0.999 ** 3], jnp.float32)
+
+    def through_op(p, g, m1, m2):
+        env = {'Param': p, 'Grad': g, 'Moment1': m1, 'Moment2': m2,
+               'LearningRate': lr, 'Beta1Pow': b1p, 'Beta2Pow': b2p}
+        _registry.get('adam').lower(_Ctx(env), _AdamOp())
+        return env['ParamOut'], env['Moment1Out'], env['Moment2Out']
+
+    def plain(p, g, m1, m2):
+        lr_t = lr.reshape(()) * jnp.sqrt(1 - b2p.reshape(())) \
+            / (1 - b1p.reshape(()))
+        fn = O._adam_sparse if kind == 'rows' else O._adam_dense
+        return fn(p, g, m1, m2, lr_t, 0.9, 0.999, 1e-8)
+
+    before = monitor.counters()
+    step = jax.jit(through_op)      # a SelectedRows is a pytree
+    got = step(p, g, m1, m2)
+    step(p, g, m1, m2)              # compiled: the second call counts nothing
+    moved = {k: v for k, v in monitor.counter_delta(before).items()
+             if k.startswith('adam_update_form_total')}
+    assert moved == {'adam_update_form_total{form=%s}' % form: 1}, moved
+    for a, b in zip(got, jax.jit(plain)(p, g, m1, m2)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -7,7 +7,7 @@ import pytest
 import paddle_tpu as fluid
 
 
-def _build(seed=11, lr=0.1):
+def _build(seed=11, lr=0.1, optimizer='sgd'):
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = seed
     with fluid.program_guard(main, startup):
@@ -16,7 +16,10 @@ def _build(seed=11, lr=0.1):
         h = fluid.layers.fc(x, size=32, act='relu')
         p = fluid.layers.fc(h, size=4, act='softmax')
         loss = fluid.layers.mean(fluid.layers.cross_entropy(p, y))
-        fluid.optimizer.SGD(lr).minimize(loss)
+        if optimizer == 'adam':
+            fluid.optimizer.Adam(lr * 0.1, fuse=False).minimize(loss)
+        else:
+            fluid.optimizer.SGD(lr).minimize(loss)
     return main, startup, loss
 
 
@@ -27,29 +30,58 @@ def _data():
     return X, Y
 
 
-def _run(build_strategy, seed=11, lr=0.1, steps=4):
+def _run(build_strategy, seed=11, lr=0.1, steps=4, optimizer='sgd',
+         places=None):
     X, Y = _data()
-    main, startup, loss = _build(seed=seed, lr=lr)
+    main, startup, loss = _build(seed=seed, lr=lr, optimizer=optimizer)
     exe = fluid.Executor()
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe.run(startup, scope=scope)
-        compiled = fluid.CompiledProgram(main).with_data_parallel(
-            loss_name=loss.name, build_strategy=build_strategy)
+        compiled = main if build_strategy is None else \
+            fluid.CompiledProgram(main).with_data_parallel(
+                loss_name=loss.name, build_strategy=build_strategy,
+                places=places)
         return [float(np.asarray(exe.run(
             compiled, feed={'x': X, 'y': Y}, fetch_list=[loss],
             scope=scope)[0]).reshape(())) for _ in range(steps)]
 
 
-def test_reduce_matches_allreduce():
+def _adam_forms(before):
+    from paddle_tpu import monitor
+    return {k.split('=')[1].rstrip('}'): v
+            for k, v in monitor.counter_delta(before).items()
+            if k.startswith('adam_update_form_total')}
+
+
+@pytest.mark.parametrize('optimizer,ndev', [('sgd', None), ('adam', 4)])
+def test_reduce_matches_allreduce(optimizer, ndev, monkeypatch):
     """Reduce mode (params sharded over 'data', reference
-    ReduceSSAGraphBuilder) must be numerically identical to AllReduce."""
+    ReduceSSAGraphBuilder) must be numerically identical to AllReduce —
+    under SGD on every device, and under Adam on a 4-device mesh. There a
+    gradient leaves a collective, not a GEMM, and the `adam` op leaves
+    every update to XLA (`form=inline`; on one device the two matrices'
+    are passes of their own once they are large enough,
+    ops/optimizer_ops.py `_own_pass`): the sharded step gives the losses
+    the one-device step gives."""
+    from paddle_tpu import monitor
+    from paddle_tpu.ops import optimizer_ops
+    monkeypatch.setattr(optimizer_ops, '_OWN_PASS_MIN_ELEMENTS', 0)
+    places = ndev and [fluid.TPUPlace(i) for i in range(ndev)]
     bs_all = fluid.BuildStrategy()
     bs_red = fluid.BuildStrategy()
     bs_red.reduce_strategy = fluid.BuildStrategy.ReduceStrategy.Reduce
-    ref = _run(bs_all)
-    red = _run(bs_red)
+    ref = _run(bs_all, optimizer=optimizer, places=places)
+    before = monitor.counters()
+    red = _run(bs_red, optimizer=optimizer, places=places)
     np.testing.assert_allclose(red, ref, rtol=1e-5, atol=1e-6)
+    if optimizer == 'adam':
+        assert _adam_forms(before) == {'inline': 4}     # 2 weights, 2 biases
+        before = monitor.counters()
+        one = _run(None, optimizer=optimizer)
+        assert _adam_forms(before) == {'own_pass': 2, 'inline': 2}
+        np.testing.assert_allclose(red, one, rtol=1e-5, atol=1e-6)
+        assert red[-1] < red[0]
 
 
 def test_gradient_scale_one_equals_lr_times_ndev():
