@@ -21,6 +21,7 @@ __all__ = [
     'rotary_embedding', 'moe_ffn', 'mla_decode_attention',
     'mla_prefix_attention', 'short_conv_decode', 'short_conv_prefill',
     'ssm_decode', 'ssm_prefill', 'ssd_decode', 'ssd_prefill',
+    'gdn_decode', 'gdn_prefill',
     'group_norm', 'data_norm', 'l2_normalize', 'matmul', 'mul', 'topk',
     'reshape', 'squeeze', 'unsqueeze', 'flatten', 'transpose', 'split',
     'reduce_sum', 'reduce_mean', 'reduce_max', 'reduce_min', 'reduce_prod',
@@ -581,29 +582,34 @@ def fused_ffn_tail(input, inner_size, size, num_flatten_dims=1,
 
 
 def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
-             name=None):
+             name=None, zero_centred=False):
     """RMSNorm over the trailing dimensions from ``begin_norm_axis``:
     ``x * rsqrt(mean(x^2) + epsilon) * w``, computed in float32
-    (ops/moe_ops.py). The weight starts at 1."""
+    (ops/moe_ops.py). The weight starts at 1. ``zero_centred``: ``x *
+    rsqrt(..) * (1 + w)``, the weight starts at 0 (Gemma's and
+    Qwen3-Next's norm)."""
     helper = LayerHelper('rms_norm', param_attr=param_attr, name=name)
     dtype = input.dtype
     w = helper.create_parameter(
         attr=helper.param_attr,
         shape=[_prod(input.shape[begin_norm_axis:])], dtype=dtype,
-        default_initializer=Constant(1.0))
+        default_initializer=Constant(0.0 if zero_centred else 1.0))
     out = helper.create_variable_for_type_inference(dtype,
                                                     shape=input.shape)
+    attrs = {'epsilon': epsilon, 'begin_norm_axis': begin_norm_axis}
+    if zero_centred:
+        # absent where unset: a program without it is what it was
+        attrs['zero_centred'] = True
     helper.append_op(type='rms_norm',
                      inputs={'X': [input], 'Scale': [w]},
-                     outputs={'Out': [out]},
-                     attrs={'epsilon': epsilon,
-                            'begin_norm_axis': begin_norm_axis})
+                     outputs={'Out': [out]}, attrs=attrs)
     return out
 
 
 def rotary_embedding(input, positions, theta=10000.0, interleave=False,
                      name=None, factor=None, original_max_position=None,
-                     beta_fast=32.0, beta_slow=1.0, attention_factor=None):
+                     beta_fast=32.0, beta_slow=1.0, attention_factor=None,
+                     rotary_dim=None):
     """Rotary position embedding of ``input [..., H, dh]`` by the int64
     ``positions`` (one per leading row of ``input``), ``rotate_half``
     convention, or with ``interleave`` the pairs ``(2i, 2i + 1)``;
@@ -612,13 +618,18 @@ def rotary_embedding(input, positions, theta=10000.0, interleave=False,
     frequencies that turn fewer than ``beta_slow`` times in
     ``original_max_position`` positions divided by ``factor``, those that
     turn more than ``beta_fast`` times kept, a ramp between; cos and sin
-    times ``attention_factor`` (None: ``0.1 ln(factor) + 1``)."""
+    times ``attention_factor`` (None: ``0.1 ln(factor) + 1``). With
+    ``rotary_dim`` the FIRST ``rotary_dim`` numbers of each head alone are
+    rotated (as a head of that size: ``inv_freq = theta^(-2i/rotary_dim)``)
+    and the rest pass through (``partial_rotary_factor``)."""
     helper = LayerHelper('rotary_embedding', name=name)
     out = helper.create_variable_for_type_inference(input.dtype,
                                                     shape=input.shape)
     attrs = {'theta': float(theta)}
     if interleave:
         attrs['interleave'] = True
+    if rotary_dim is not None and int(rotary_dim) != input.shape[-1]:
+        attrs['rotary_dim'] = int(rotary_dim)
     if factor is not None:
         # absent where unset: a program without YaRN is what it was
         attrs.update(
@@ -924,6 +935,69 @@ def ssd_prefill(xbc, z, dt, state, tail, rows, positions, length, layer,
     return _ssd('ssd_prefill', xbc, z, dt, state, tail, rows, layer, prefix,
                 groups, kernel, epsilon, chunk=chunk, positions=positions,
                 length=length)
+
+
+def _gdn(op_type, qkv, z, b, a, state, tail, rows, layer, prefix, key_heads,
+         kernel, epsilon, chunk=None, positions=None, length=None):
+    """A Gated DeltaNet mixer's op (ops/gdn_ops.py) with the layer's inner
+    parameters under ``prefix``. Their defaults are the family's own (HF
+    `Qwen3NextGatedDeltaNet.__init__`): ``A_log`` the log of 1 .. 16 evenly
+    over the value heads (published: a uniform draw in (0, 16)), the step's
+    bias 1, the output norm's one weight ``[dv]`` 1, the taps without a
+    bias."""
+    helper = LayerHelper(op_type)
+    heads = int(b.shape[-1])
+
+    def param(name, shape, init):
+        return helper.create_parameter(
+            attr=ParamAttr(name='%s.%s' % (prefix, name)), shape=shape,
+            dtype=z.dtype, default_initializer=init)
+    weights = {
+        'ConvW': param('conv.w', [int(qkv.shape[-1]), int(kernel)],
+                       Normal(0.0, 0.3)),
+        'ALog': param('A_log', [heads], NumpyArrayInitializer(
+            np.log(np.linspace(1.0, 16.0, heads, dtype='float32')))),
+        'DtBias': param('dt.b', [heads], Constant(1.0)),
+        'NormW': param('norm.w', [int(z.shape[-1]) // heads],
+                       Constant(1.0))}
+    out = helper.create_variable_for_type_inference(z.dtype, shape=z.shape)
+    inputs = {'X': [qkv], 'Z': [z], 'B': [b], 'A': [a], 'State': [state],
+              'Tail': [tail], 'Rows': [rows]}
+    inputs.update({k: [v] for k, v in weights.items()})
+    attrs = {'layer': int(layer), 'epsilon': float(epsilon),
+             'key_heads': int(key_heads)}
+    if positions is not None:
+        inputs.update({'Positions': [positions], 'Length': [length]})
+        attrs['chunk'] = int(chunk)
+    helper.append_op(type=op_type, inputs=inputs,
+                     outputs={'Out': [out], 'StateOut': [state],
+                              'TailOut': [tail]}, attrs=attrs)
+    return out
+
+
+def gdn_decode(qkv, z, b, a, state, tail, rows, layer, prefix, key_heads,
+               kernel, epsilon=1e-6):
+    """One step of a Gated DeltaNet layer for every slot's one row: ``qkv
+    [S, 2 Hk dk + Hv dv]`` and ``z [S, Hv dv]`` (the parts of the mixer's
+    wide input projection), ``b`` / ``a`` ``[S, Hv]`` (its narrow one: the
+    write strength and the decay's input), ``state`` / ``tail`` the two
+    pools, read and written in place at the rows ``rows [S, 1]`` names (0:
+    none), ``layer`` the layer's ordinal in them (ops/gdn_ops.py). Returns
+    the normed, gated ``[S, Hv dv]``, the output projection's input."""
+    return _gdn('gdn_decode', qkv, z, b, a, state, tail, rows, layer, prefix,
+                key_heads, kernel, epsilon)
+
+
+def gdn_prefill(qkv, z, b, a, state, tail, rows, positions, length, layer,
+                prefix, key_heads, kernel, chunk, epsilon=1e-6):
+    """`gdn_decode` for one prompt suffix (``[1, T, ...]``) that starts at
+    ``positions[0]``: from zeros there, else from the row as the chunk
+    before left it, over the ``length`` real rows, in blocks of ``chunk``
+    rows (the chunked delta rule, ops/gdn_ops.py). Returns ``[1, T, Hv
+    dv]``."""
+    return _gdn('gdn_prefill', qkv, z, b, a, state, tail, rows, layer,
+                prefix, key_heads, kernel, epsilon, chunk=chunk,
+                positions=positions, length=length)
 
 
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,

@@ -86,6 +86,30 @@ class LMConfig(object):
       gate, then an RMSNorm a group; ``W_out``. Prompts are scanned in
       blocks of ``ssm_chunk`` rows. Its state and tail are two more pools
       a row a slot (`SSD_STATE`, `SSD_TAIL`);
+    - ``layer_types`` ``'gdn'``: a Gated DeltaNet mixer (Qwen3-Next's
+      linear-attention layers; ops/gdn_ops.py): ``[q | k | v | z] = h
+      W_in`` and ``[b | a] = h W_ba``; a causal depthwise convolution of
+      ``ssm_conv`` taps, no bias, and a SiLU over all of ``[q | k | v]``;
+      ``gdn_key_heads`` heads of ``gdn_key_dim`` for q and k, l2-normed,
+      ``gdn_value_heads`` heads of ``gdn_value_dim`` for v (value head ``h``
+      reads key head ``h // (value heads / key heads)``); the delta rule
+      with a scalar decay and a write strength a head over a ``[key dim,
+      value dim]`` state a value head; an RMSNorm over each head's values,
+      then the gate ``silu(z)``; ``W_out``. Prompts run the chunked form in
+      blocks of ``gdn_chunk`` rows. Its state and tail are two more pools a
+      row a slot (`GDN_STATE`, `GDN_TAIL`);
+    - ``attention_gate``: an attention layer's q projection is twice as
+      wide -- head ``h`` owns columns ``2 h head_dim ..``: its q, then its
+      gate -- and the attention's output is multiplied by ``sigmoid(gate)``
+      before ``W_o`` (Qwen3-Next's full-attention layers);
+    - ``rotary_dim``: the first ``rotary_dim`` numbers of each head of q
+      and k alone are rotated, as a head of that size
+      (``partial_rotary_factor`` x ``head_dim``); the rest pass through;
+    - ``norm_zero_centred``: the block's norms, the final norm and the
+      q/k-norm multiply by ``1 + w`` (the weight starts at 0), not by
+      ``w``; a Gated DeltaNet layer's output norm stays ``w``;
+    - ``shared_expert_gate``: the shared expert's output is multiplied by
+      ``sigmoid(x w_sg)``, ``w_sg [d_model, 1]``, a scalar a row;
     - ``layer_types`` ``'ffn'``: a model that has such layers is made of
       layers of ONE sublayer, ``x + f(norm(x))`` (Nemotron-H's block): an
       ``'ffn'`` layer is the FFN alone (norm ``ln2``), a layer of any
@@ -146,7 +170,11 @@ class LMConfig(object):
                  ssm_state=16, ssm_conv=4, ssm_dt_rank=None, ssm_heads=0,
                  ssm_head_dim=0, ssm_groups=1, ssm_chunk=128,
                  expert_form='gated', shared_expert_width=None,
-                 matmul_precision=None, attention_rope=None):
+                 matmul_precision=None, attention_rope=None,
+                 gdn_key_heads=0, gdn_value_heads=0, gdn_key_dim=0,
+                 gdn_value_dim=0, gdn_chunk=64, attention_gate=False,
+                 rotary_dim=None, norm_zero_centred=False,
+                 shared_expert_gate=False):
         self.vocab_size = vocab_size
         self.seq_len = seq_len
         self.d_model = d_model
@@ -221,14 +249,52 @@ class LMConfig(object):
         self.ssm_chunk = int(ssm_chunk)
         self.matmul_precision = matmul_precision
         self.attention_rope = dict(attention_rope or {})
+        self.gdn_key_heads = int(gdn_key_heads)
+        self.gdn_value_heads = int(gdn_value_heads)
+        self.gdn_key_dim = int(gdn_key_dim)
+        self.gdn_value_dim = int(gdn_value_dim)
+        self.gdn_chunk = int(gdn_chunk)
+        self.attention_gate = bool(attention_gate)
+        self.rotary_dim = None if rotary_dim is None else int(rotary_dim)
+        self.norm_zero_centred = bool(norm_zero_centred)
+        self.shared_expert_gate = bool(shared_expert_gate)
         if set(self.attention_rope) - set(ROPE_KEYS):
             raise ValueError('LMConfig.attention_rope=%r: expected keys of '
                              '%r' % (attention_rope, ROPE_KEYS))
         if len(self.layer_types) != n_layer or set(self.layer_types) - {
-                'attention', 'conv', 'window', 'ssm', 'ssd', 'ffn'}:
+                'attention', 'conv', 'window', 'ssm', 'ssd', 'gdn', 'ffn'}:
             raise ValueError("LMConfig.layer_types=%r: expected %d of "
                              "'attention' | 'conv' | 'window' | 'ssm' | "
-                             "'ssd' | 'ffn'" % (self.layer_types, n_layer))
+                             "'ssd' | 'gdn' | 'ffn'"
+                             % (self.layer_types, n_layer))
+        if self.n_gdn_layers and (
+                min(gdn_key_heads, gdn_value_heads, gdn_key_dim,
+                    gdn_value_dim, gdn_chunk) < 1
+                or gdn_value_heads % gdn_key_heads):
+            raise ValueError("LMConfig.layer_types has 'gdn' layers: they "
+                             "need gdn_key_heads x gdn_key_dim keys, whole "
+                             "groups of gdn_value_heads x gdn_value_dim "
+                             "values a key head and a gdn_chunk, got %r x "
+                             "%r, %r x %r, %r"
+                             % (gdn_key_heads, gdn_key_dim, gdn_value_heads,
+                                gdn_value_dim, gdn_chunk))
+        if self.rotary_dim is not None and not (
+                position == 'rope' and attention == 'mha'
+                and 0 < self.rotary_dim <= self.head_dim
+                and self.rotary_dim % 2 == 0):
+            raise ValueError("LMConfig.rotary_dim=%r: an even part of "
+                             "head_dim=%r under position='rope', attention="
+                             "'mha'" % (rotary_dim, self.head_dim))
+        if self.attention_gate and attention != 'mha':
+            raise ValueError("LMConfig.attention_gate is built with "
+                             "attention='mha' only")
+        if self.norm_zero_centred and norm != 'rms_norm':
+            raise ValueError("LMConfig.norm_zero_centred needs norm="
+                             "'rms_norm', got %r" % (norm,))
+        if self.shared_expert_gate and not (ffn == 'moe'
+                                            and n_shared_experts):
+            raise ValueError("LMConfig.shared_expert_gate gates a shared "
+                             "expert: ffn='moe' with n_shared_experts")
         if self.n_ssd_layers and (
                 ssm_heads < 1 or ssm_head_dim < 1 or ssm_groups < 1
                 or ssm_heads % ssm_groups):
@@ -236,7 +302,7 @@ class LMConfig(object):
                              "need ssm_heads x ssm_head_dim channels in "
                              "ssm_groups groups of whole heads, got %r x %r "
                              "in %r" % (ssm_heads, ssm_head_dim, ssm_groups))
-        if (self.n_ssm_layers or self.n_ssd_layers) \
+        if (self.n_ssm_layers or self.n_ssd_layers or self.n_gdn_layers) \
                 and self.ssm_conv - 1 > ssm_ops.TAIL_ROWS:
             raise ValueError("LMConfig.ssm_conv=%r: a state-space layer's "
                              "tail is at most %d rows a slot"
@@ -307,11 +373,26 @@ class LMConfig(object):
         return self.ssd_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
+    def n_gdn_layers(self):
+        return self.layer_types.count('gdn')
+
+    @property
+    def gdn_inner(self):
+        """Channels of a Gated DeltaNet layer's values (and of its gate
+        ``z``): value heads x value size."""
+        return self.gdn_value_heads * self.gdn_value_dim
+
+    @property
+    def gdn_conv_width(self):
+        """Channels of a Gated DeltaNet layer's convolution: q, k and v."""
+        return 2 * self.gdn_key_heads * self.gdn_key_dim + self.gdn_inner
+
+    @property
     def n_attn_layers(self):
         """The GLOBAL attention layers: those of the K/V pools that the
         block allocator's tables address."""
         return self.n_layer - sum(self.layer_types.count(kind) for kind in (
-            'conv', 'window', 'ssm', 'ssd', 'ffn'))
+            'conv', 'window', 'ssm', 'ssd', 'gdn', 'ffn'))
 
     def has_mixer(self, layer):
         """Whether `layer` has the mixer's sublayer (norm ``ln1``)."""
@@ -334,7 +415,9 @@ class LMConfig(object):
         (`attention_rope`)."""
         own = self.attention_rope \
             if self.layer_types[layer] == 'attention' else {}
-        return dict({'theta': self.rope_theta}, **own)
+        part = {} if self.rotary_dim is None \
+            else {'rotary_dim': self.rotary_dim}
+        return dict({'theta': self.rope_theta}, **dict(own, **part))
 
     def layer_ordinal(self, layer):
         """`layer`'s place among the layers of its kind: the `layer`
@@ -370,7 +453,9 @@ def _require_classic_block(cfg, who):
         ('head_dim', cfg.d_model // cfg.n_head),
         ('n_kv_head', cfg.n_head),
         ('layer_types', ('attention',) * cfg.n_layer),
-        ('tie_embeddings', False), ('matmul_precision', None))
+        ('tie_embeddings', False), ('matmul_precision', None),
+        ('attention_gate', False), ('rotary_dim', None),
+        ('norm_zero_centred', False), ('shared_expert_gate', False))
     for field, value in classic:
         if getattr(cfg, field) != value:
             raise ValueError(
@@ -470,7 +555,8 @@ def _norm(cfg, x, residual, bna, name):
     if residual is not None:
         x = layers.elementwise_add(x, residual)
     return layers.rms_norm(x, begin_norm_axis=bna, epsilon=cfg.rms_eps,
-                           param_attr=ParamAttr(name=name + '.w')), x
+                           param_attr=ParamAttr(name=name + '.w'),
+                           zero_centred=cfg.norm_zero_centred), x
 
 
 def _bias(cfg, name):
@@ -493,27 +579,32 @@ def _heads_of(cfg, flat, p, which, pos, T, rotate):
     if normed and cfg.qk_norm != 'head':
         flat = layers.rms_norm(
             flat, begin_norm_axis=1 if rows else 2, epsilon=cfg.rms_eps,
-            param_attr=norm_attr)
+            param_attr=norm_attr, zero_centred=cfg.norm_zero_centred)
     x = layers.reshape(flat, shape=[-1, h, dh] if rows else [0, T, h, dh])
     if normed and cfg.qk_norm == 'head':
         x = layers.rms_norm(x, begin_norm_axis=2 if rows else 3,
-                            epsilon=cfg.rms_eps, param_attr=norm_attr)
+                            epsilon=cfg.rms_eps, param_attr=norm_attr,
+                            zero_centred=cfg.norm_zero_centred)
     if rotate and which != 'v':
         x = layers.rotary_embedding(x, pos, **rotate)
     return x if rows else layers.transpose(x, perm=[0, 2, 1, 3])
 
 
 def _qkv(cfg, ln1, p, pos, T=None, layer=0):
-    """The block's q, k, v from its normed input: the fused projection,
+    """The block's q, k, v from its normed input, and the attention's
+    gate (None without `LMConfig.attention_gate`): the fused projection,
     then each prepared for the cache ops. ``T`` None: decode rows
     ``[S, d]`` -> three ``[S, H, dh]``; else one prompt ``[1, T, d]`` ->
     three ``[1, H, T, dh]``. K comes back as it is CACHED: after k-norm
     and rotation (`LMConfig.rotates(layer)`), on its ``n_kv_head`` heads
-    (as V is)."""
+    (as V is). The gate comes back flat, ``[.., H x dh]``, as the
+    attention's output is when `_gated` multiplies it in."""
     if cfg.attention == 'mla':
-        return _mla_qkv(cfg, ln1, p, pos, T)
+        return _mla_qkv(cfg, ln1, p, pos, T) + (None,)
     h, dh = cfg.n_head, cfg.head_dim
-    ends = [w * dh for w in (h, h + cfg.n_kv_head, h + 2 * cfg.n_kv_head)]
+    wide = 2 if cfg.attention_gate else 1       # a head's q, then its gate
+    ends = [w * dh for w in (wide * h, wide * h + cfg.n_kv_head,
+                             wide * h + 2 * cfg.n_kv_head)]
     qkv = layers.fc(ln1, size=ends[-1],
                     num_flatten_dims=1 if T is None else 2,
                     param_attr=ParamAttr(name=p + '.attn.qkv.w'),
@@ -521,18 +612,33 @@ def _qkv(cfg, ln1, p, pos, T=None, layer=0):
     if not cfg.qk_norm and cfg.position == 'sinusoid' \
             and cfg.n_kv_head == h:
         if T is None:
-            return _qkv_split_step(qkv, cfg)
+            return _qkv_split_step(qkv, cfg) + [None]
         qkv = layers.reshape(qkv, shape=[0, T, 3, h, dh])
         qkv = layers.transpose(qkv, perm=[2, 0, 3, 1, 4])    # (3,1,H,T,dh)
         return [layers.squeeze(layers.slice(qkv, axes=[0], starts=[i],
                                             ends=[i + 1]), axes=[0])
-                for i in range(3)]
+                for i in range(3)] + [None]
     axis = 1 if T is None else 2
-    return [_heads_of(cfg, layers.slice(qkv, axes=[axis], starts=[start],
-                                        ends=[end]),
-                      p, which, pos, T,
-                      cfg.rotates(layer) and cfg.rope(layer))
-            for which, start, end in zip('qkv', [0] + ends, ends)]
+    lead = [-1] if T is None else [0, T]
+    out, gate = [], None
+    for which, start, end in zip('qkv', [0] + ends, ends):
+        flat = layers.slice(qkv, axes=[axis], starts=[start], ends=[end])
+        if which == 'q' and cfg.attention_gate:
+            both = layers.reshape(flat, shape=lead + [h, 2 * dh])
+            flat, gate = [
+                layers.reshape(layers.slice(both, axes=[axis + 1],
+                                            starts=[at], ends=[at + dh]),
+                               shape=lead + [h * dh]) for at in (0, dh)]
+        out.append(_heads_of(cfg, flat, p, which, pos, T,
+                             cfg.rotates(layer) and cfg.rope(layer)))
+    return out + [gate]
+
+
+def _gated(ctx, gate):
+    """The attention's flat output times ``sigmoid(gate)`` (`_qkv`'s; None:
+    as it is)."""
+    return ctx if gate is None \
+        else layers.elementwise_mul(ctx, layers.sigmoid(gate))
 
 
 def _mla_qkv(cfg, ln1, p, pos, T=None):
@@ -657,9 +763,34 @@ def _ssd_mixer(cfg, ln1, p, nth, ssd, num_flatten_dims):
     return proj(ssd(xbc, z, dt, p + '.ssd', nth), cfg.d_model, 'out')
 
 
+def _gdn_mixer(cfg, ln1, p, nth, gdn, num_flatten_dims):
+    """Qwen3-Next's Gated DeltaNet mixer on the normed input: ``[q | k | v
+    | z] = h W_in`` and ``[b | a] = h W_ba``, the program's cache op on the
+    ``nth`` such layer's rows (``gdn(qkv, z, b, a, prefix, nth)``:
+    layers.gdn_decode / gdn_prefill -- the convolution, the norms of q and
+    k, the delta rule, the output norm and the gate), ``W_out``. No bias
+    on any projection."""
+    def proj(x, size, which):
+        return layers.fc(x, size=size, num_flatten_dims=num_flatten_dims,
+                         param_attr=ParamAttr(name='%s.gdn.%s.w'
+                                              % (p, which)),
+                         bias_attr=False)
+
+    def cut(x, start, end):
+        return layers.slice(x, axes=[num_flatten_dims], starts=[start],
+                            ends=[end])
+    cw, hv = cfg.gdn_conv_width, cfg.gdn_value_heads
+    qkvz = proj(ln1, cw + cfg.gdn_inner, 'in')
+    ba = proj(ln1, 2 * hv, 'ba')
+    return proj(gdn(cut(qkvz, 0, cw), cut(qkvz, cw, cw + cfg.gdn_inner),
+                    cut(ba, 0, hv), cut(ba, hv, 2 * hv), p + '.gdn', nth),
+                cfg.d_model, 'out')
+
+
 # the mixers that are no attention, by the layer's kind: each takes (cfg,
 # ln1, prefix, ordinal, the program's cache op of the kind, the rows' axis)
-_MIXERS = {'conv': _conv_mixer, 'ssm': _ssm_mixer, 'ssd': _ssd_mixer}
+_MIXERS = {'conv': _conv_mixer, 'ssm': _ssm_mixer, 'ssd': _ssd_mixer,
+           'gdn': _gdn_mixer}
 
 
 def _dense_ffn(x, width, d_model, name, num_flatten_dims, form='gated'):
@@ -717,9 +848,14 @@ def _ffn(cfg, ln2, p, num_flatten_dims, length=None, valid=None, layer=0):
         up_param_attr=ParamAttr(name=p + '.moe.up.w'),
         down_param_attr=ParamAttr(name=p + '.moe.down.w'), **router)
     if cfg.n_shared_experts:
-        out = layers.elementwise_add(out, _dense_ffn(
-            rows, cfg.shared_expert_width, cfg.d_model, p + '.moe.shared',
-            1, cfg.expert_form))
+        shared = _dense_ffn(rows, cfg.shared_expert_width, cfg.d_model,
+                            p + '.moe.shared', 1, cfg.expert_form)
+        if cfg.shared_expert_gate:
+            # a scalar a row: [N, 1] against [N, d]
+            shared = layers.elementwise_mul(shared, layers.sigmoid(layers.fc(
+                rows, size=1, bias_attr=False,
+                param_attr=ParamAttr(name=p + '.moe.shared_gate.w'))))
+        out = layers.elementwise_add(out, shared)
     if num_flatten_dims != 1:
         out = layers.reshape(out, shape=[-1] + list(shape[1:]))
     return out, (idx, load)
@@ -944,6 +1080,8 @@ SSM_STATE = 'gen_ssm_state'
 SSM_TAIL = 'gen_ssm_tail'
 SSD_STATE = 'gen_ssd_state'
 SSD_TAIL = 'gen_ssd_tail'
+GDN_STATE = 'gen_gdn_state'
+GDN_TAIL = 'gen_gdn_tail'
 
 
 def window_ring(cfg, block_size):
@@ -1010,7 +1148,9 @@ def cache_pools(cfg, num_blocks=0, block_size=1, slots=None, shared=False):
     a sublane tile of their own: ops/ssm_ops.py has what a padded one cost).
     With Mamba-2 layers, the same of theirs under the same rows: the state
     ``[N, heads x head size]`` a layer (ops/ssd_ops.py says why that way
-    round) and the tail over all the convolution's channels."""
+    round) and the tail over all the convolution's channels. With Gated
+    DeltaNet layers, theirs: the state ``[key dim, value heads x value
+    dim]`` a layer (ops/gdn_ops.py) and the tail over q, k and v."""
     pools = []
 
     def kind(shapes, index, rewinds, copies, why=None, reach=None, **books):
@@ -1069,6 +1209,19 @@ def cache_pools(cfg, num_blocks=0, block_size=1, slots=None, shared=False):
              "it", step=('ssd_state_rows_updated_total', 1),
              prefill='ssd_prefill_rows_total',
              resume='ssd_state_resumes_total')
+    if cfg.n_gdn_layers:
+        kind([(GDN_STATE, (n + 1, cfg.n_gdn_layers, cfg.gdn_key_dim,
+                           cfg.gdn_inner)),
+              (GDN_TAIL, (n + 1, cfg.n_gdn_layers, ssm_ops.TAIL_ROWS,
+                          cfg.gdn_conv_width))], 'row', False, False,
+             "a Gated DeltaNet layer's state is a row a slot, every value "
+             "head's keys-by-values matrix as of the slot's last position "
+             "-- a shared block has no state to resume from, and a rejected "
+             "draft cannot be unwound from it (the delta rule's correction "
+             "is not undone by masking rows)",
+             step=('gdn_state_rows_updated_total', 1),
+             prefill='gdn_prefill_rows_total',
+             resume='gdn_state_resumes_total')
     return tuple(pools)
 
 
@@ -1177,7 +1330,7 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
             if kind in _MIXERS:
                 delta = _MIXERS[kind](cfg, ln1, p, nth, mixers[kind], 1)
             else:
-                q, k, v = _qkv(cfg, ln1, p, pos, layer=i)    # [S, H, dh]
+                q, k, v, gate = _qkv(cfg, ln1, p, pos, layer=i)  # [S, H, dh]
                 cache_write(k, v, nth, kind)
                 if not head and i == cfg.n_layer - 1:
                     # write-only tower, last layer: nothing consumes x
@@ -1186,7 +1339,8 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
                     return None
                 ctx = attend(q, nth, p + tag, kind)
                 delta = layers.fc(
-                    layers.reshape(ctx, shape=[-1, cfg.attn_width]),
+                    _gated(layers.reshape(ctx, shape=[-1, cfg.attn_width]),
+                           gate),
                     size=cfg.d_model,
                     param_attr=ParamAttr(name=p + '.attn.proj.w'),
                     bias_attr=_bias(cfg, p + '.attn.proj.b'))
@@ -1258,6 +1412,12 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks,
             xbc, z, dt, pools[SSD_STATE], pools[SSD_TAIL], feeds['row'],
             layer, prefix, cfg.ssm_groups, cfg.ssm_conv, epsilon=cfg.rms_eps)
 
+    def gdn(qkv, z, b, a, prefix, layer):
+        return layers.gdn_decode(
+            qkv, z, b, a, pools[GDN_STATE], pools[GDN_TAIL], feeds['row'],
+            layer, prefix, cfg.gdn_key_heads, cfg.ssm_conv,
+            epsilon=cfg.rms_eps)
+
     def cache_write(k, v, layer, kind):
         # a window layer writes into its slot's ring: the table's column
         # is the logical block modulo the table's width
@@ -1298,7 +1458,8 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks,
     routing = []
     logits = _decode_tower(cfg, x, cache_write, attend, pos=pos,
                            valid=valid, routing=routing,
-                           mixers={'conv': conv, 'ssm': ssm, 'ssd': ssd})
+                           mixers={'conv': conv, 'ssm': ssm, 'ssd': ssd,
+                                   'gdn': gdn})
     next_tokens = _append_sample_op(block, logits, sample_vars,
                                     'gen_next_tokens')       # [S]
     return _expert_outputs(
@@ -1585,10 +1746,16 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
             pos, length, layer, prefix, cfg.ssm_groups, cfg.ssm_conv,
             cfg.ssm_chunk, epsilon=cfg.rms_eps)
 
+    def gdn(qkv, z, b, a, prefix, layer):
+        return layers.gdn_prefill(
+            qkv, z, b, a, pools[GDN_STATE], pools[GDN_TAIL], feeds['row'],
+            pos, length, layer, prefix, cfg.gdn_key_heads, cfg.ssm_conv,
+            cfg.gdn_chunk, epsilon=cfg.rms_eps)
+
     def attention(ln1, p, nth, layer):
         """An attention layer's mixer: q, k, v, the cache writes, the
         suffix's attention against the slot's pages, the projection."""
-        q, k, v = _qkv(cfg, ln1, p, pos, T, layer=layer)     # [1,H,T,dh]
+        q, k, v, gate = _qkv(cfg, ln1, p, pos, T, layer=layer)  # [1,H,T,dh]
         window = cfg.layer_types[layer] == 'window'
         if not window:
             cache_write(kc, k, nth)
@@ -1619,14 +1786,15 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
                 cache_write(wkc, k, nth, wtab, **bound)
                 cache_write(wvc, v, nth, wtab, **bound)
             ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
-        ctx = layers.reshape(ctx, shape=[0, T, cfg.attn_width])
+        ctx = _gated(layers.reshape(ctx, shape=[0, T, cfg.attn_width]),
+                     gate)
         return layers.fc(ctx, size=d, num_flatten_dims=2,
                          param_attr=ParamAttr(name=p + '.attn.proj.w'),
                          bias_attr=_bias(cfg, p + '.attn.proj.b'))
 
     delta = None
     routing = []
-    mixers = {'conv': conv, 'ssm': ssm, 'ssd': ssd}
+    mixers = {'conv': conv, 'ssm': ssm, 'ssd': ssd, 'gdn': gdn}
     for i in range(cfg.n_layer):
         p = 'layer_%d' % i
         nth, kind = cfg.layer_ordinal(i), cfg.layer_types[i]

@@ -33,6 +33,7 @@ from . import mla_ops
 from . import short_conv_ops
 from . import ssm_ops
 from . import ssd_ops
+from . import gdn_ops
 from . import fused_ops
 from . import dist_ops
 from . import pipeline_ops

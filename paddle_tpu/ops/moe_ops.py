@@ -3,7 +3,8 @@ to the Program path (OLMoE: models/transformer.py, LMConfig(norm=
 'rms_norm', position='rope', qk_norm=True, ffn='moe')):
 
 - ``rms_norm``: ``x * rsqrt(mean(x^2) + eps) * w`` over the trailing
-  dimensions from ``begin_norm_axis``, computed in float32.
+  dimensions from ``begin_norm_axis``, computed in float32; with
+  ``zero_centred`` times ``1 + w``.
 - ``rotary_embedding``: rotate every head of ``X [..., H, dh]`` by the
   angle ``Positions * theta^(-2i/dh)``, ``rotate_half`` convention (the
   two halves of a head are dims ``[0, dh/2)`` and ``[dh/2, dh)``), or with
@@ -92,7 +93,8 @@ def _rms_norm(ctx, op):
     axes = tuple(range(bna, x.ndim))
     y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=axes, keepdims=True) + eps)
     if scale is not None:
-        y = y * scale.astype(jnp.float32).reshape(x.shape[bna:])
+        scale = scale.astype(jnp.float32).reshape(x.shape[bna:])
+        y = y * (1.0 + scale if op.attr('zero_centred', False) else scale)
     ctx.out(op, 'Out', y.astype(x.dtype))
 
 
@@ -118,12 +120,19 @@ def yarn_inv_freq(dh, theta, factor, original_max_position, beta_fast,
     return extra / factor * ramp + extra * (1.0 - ramp)
 
 
-def rotate(x, positions, theta, interleave=False, yarn=None):
+def rotate(x, positions, theta, interleave=False, yarn=None,
+           rotary_dim=None):
     """`x [..., H, dh]` rotated by `positions` (one per leading row): the
     pair of dims (i, i + dh/2), or with `interleave` (2i, 2i + 1), by the
     angle `position * theta^(-2i/dh)`. `yarn` (factor, original_max_position,
     beta_fast, beta_slow, attention_factor): by `yarn_inv_freq`'s
-    frequencies, cos and sin times the attention factor."""
+    frequencies, cos and sin times the attention factor. `rotary_dim`: the
+    first that many numbers of a head are rotated as a head of that size,
+    the others pass through."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        return jnp.concatenate(
+            [rotate(x[..., :rotary_dim], positions, theta, interleave, yarn),
+             x[..., rotary_dim:]], axis=-1)
     dh = x.shape[-1]
     half = dh // 2
     if yarn is None:
@@ -159,7 +168,8 @@ def _rotary_embedding(ctx, op):
             'factor', 'original_max_position', 'beta_fast', 'beta_slow',
             'attention_factor'))
     ctx.out(op, 'Out', rotate(x, pos, float(op.attr('theta', 10000.0)),
-                              bool(op.attr('interleave', False)), yarn))
+                              bool(op.attr('interleave', False)), yarn,
+                              op.attr('rotary_dim', None)))
 
 
 def route(x, router_w, top_k, norm_topk_prob, score='softmax',

@@ -101,13 +101,18 @@ _PRECISION = lax.Precision.HIGHEST
 
 def shapes_ok(n_head, head_dim, block_size, n_kv_head=None):
     """The kernel's tiling rule: pages (of the K/V heads) are whole
-    (8, 128) tiles, a head's lanes never straddle a vreg, and the query
+    (8, 128) tiles, a head's lanes never straddle a vreg -- or, under
+    grouped queries alone, fill whole vregs (heads of 256: the MXU body
+    contracts over the page's lanes whatever a head's share of them; the
+    VPU body sums a head's lanes inside one vreg) --, and the query
     heads divide evenly over the K/V heads. Grouped queries besides: whole
     pages fill a window, and the ring holds two windows (the query rows
     fill whole sublanes by `padded_group`)."""
     n_kv_head = n_kv_head or n_head
     return (n_kv_head * head_dim) % _LANES == 0 and \
-        _LANES % head_dim == 0 and block_size % 8 == 0 and \
+        (_LANES % head_dim == 0 or (head_dim % _LANES == 0
+                                    and n_head != n_kv_head)) and \
+        block_size % 8 == 0 and \
         n_head % n_kv_head == 0 and \
         (n_head == n_kv_head
          or (_WINDOW_KEYS % block_size == 0

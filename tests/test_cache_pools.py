@@ -1,6 +1,7 @@
 """The table of a model's pools (ISSUE 46, models/transformer.py
-`cache_pools`): for the toy `LMConfig` of each of the benchmark's eight
-configurations (the eighth since PR 48), every pool's name, what indexes it, whether a rejected
+`cache_pools`): for the toy `LMConfig` of each of the benchmark's
+configurations (Nemotron's since PR 48, Qwen3-Next's since PR 55), every
+pool's name, what indexes it, whether a rejected
 draft rewinds from it and whether a shared block's entry of it copies;
 `kv_cache_names` and `kv_cache_shapes` are views of it; and what an engine
 refuses, which bookkeepers it keeps and what it books follow from the table
@@ -16,7 +17,7 @@ from paddle_tpu.serving import GenerateConfig, GenerateEngine
 from paddle_tpu.serving import kv_blocks
 
 from benchmark.models import (jamba, joyai, kexaone, lfm2, lm, nemotron,
-                              olmoe)
+                              olmoe, qwen3next)
 
 from test_olmoe_serving import LISTED
 
@@ -39,6 +40,7 @@ CONFIGS = {
     'k-exaone-236b-a23b-ep16-l5': lambda: _toy(kexaone, 'kexaone'),
     'ai21-jamba2-3b': lambda: _toy(jamba, 'jamba'),
     'nemotron-3-nano-30b-a3b-ep8-l20': lambda: _toy(nemotron, 'nemotron'),
+    'qwen3-next-80b-a3b-ep8-l8': lambda: _toy(qwen3next, 'qwen3next'),
 }
 KV = [(T.KV_CACHE_K, 'block', True, True), (T.KV_CACHE_V, 'block', True, True)]
 # (name, index, rewinds, copies) of every pool, in the order of the state
@@ -55,6 +57,8 @@ POOLS = {
                             (T.SSM_TAIL, 'row', False, False)],
     'nemotron-3-nano-30b-a3b-ep8-l20': KV + [
         (T.SSD_STATE, 'row', False, False), (T.SSD_TAIL, 'row', False, False)],
+    'qwen3-next-80b-a3b-ep8-l8': KV + [
+        (T.GDN_STATE, 'row', False, False), (T.GDN_TAIL, 'row', False, False)],
 }
 # the series a decode step books its reads under: (series, rows a slot at
 # most, of which field of the config a layer count)
@@ -68,6 +72,9 @@ STEP_READS = {
     'nemotron-3-nano-30b-a3b-ep8-l20': [
         ('kv_tokens_read_total', None, 'n_attn_layers'),
         ('ssd_state_rows_updated_total', 1, 'n_ssd_layers')],
+    'qwen3-next-80b-a3b-ep8-l8': [
+        ('kv_tokens_read_total', None, 'n_attn_layers'),
+        ('gdn_state_rows_updated_total', 1, 'n_gdn_layers')],
 }
 SLOTS, BLOCKS, BLOCK_SIZE = 4, 19, 8
 
@@ -89,7 +96,7 @@ def test_the_table_holds_every_pool_and_the_views_are_its(config):
         assert p.shape[0] == entries[p.index] and len(p.shape) == 4
         assert p.shape[1] in (cfg.n_attn_layers, cfg.n_conv_layers,
                               cfg.n_window_layers, cfg.n_ssm_layers,
-                              cfg.n_ssd_layers)
+                              cfg.n_ssd_layers, cfg.n_gdn_layers)
         # a pool an option is refused over says why; the others need not
         assert (p.why is None) == (p.rewinds and p.index == 'block')
         assert T.INDEX_FEEDS[p.index].startswith('gen_')
@@ -132,7 +139,9 @@ def test_an_engine_refuses_what_the_table_says_and_keeps_its_books(
         ('speculative', 'ai21-jamba2-3b'),
         ('speculative', 'nemotron-3-nano-30b-a3b-ep8-l20'),
         ('prefix_sharing', 'nemotron-3-nano-30b-a3b-ep8-l20'),
-        ('prefix_sharing', 'ai21-jamba2-3b')})
+        ('prefix_sharing', 'ai21-jamba2-3b'),
+        ('speculative', 'qwen3-next-80b-a3b-ep8-l8'),
+        ('prefix_sharing', 'qwen3-next-80b-a3b-ep8-l8')})
     if unfit:
         with pytest.raises(ValueError) as refusal:
             _engine(monkeypatch, cfg, **{option: True})

@@ -2,8 +2,9 @@
 `lax.ragged_dot`s carry `ragged_dot_tiling`, chosen by
 `grouped_matmul_tiling` from the call's rows, K, N and precision.
 
-- the rule's invariants over a grid of shapes, and its answers at the five
-  expert cells' shapes;
+- the rule's invariants over a grid of shapes, and its answers at the
+  expert cells' shapes (Qwen3-Next's since PR 55: 640 to 5 120 assignments
+  of which an eighth are held);
 - on the CPU the attribute is carried and ignored: `grouped_ffn` with and
   without it is the same bit for bit, forward and gradient, and the
   gradient's own grouped matmuls state nothing;
@@ -53,6 +54,16 @@ CELLS = {
                                        'highest'),
     'nemotron3-serve-reason128-b512': (512, 6, 16, 128, 2688, 1856, False,
                                        'highest'),
+    # 64 of 512 experts held, 10 a row: the step's 640 assignments (its cap
+    # 128 rows) and the three prefill buckets' (5 120 at 512 rows, cap 1 024)
+    'qwen3next-serve-longmix64': (64, 10, 64, 512, 2048, 512, True,
+                                  'highest'),
+    'qwen3next-serve-longmix64-b128': (128, 10, 64, 512, 2048, 512, True,
+                                       'highest'),
+    'qwen3next-serve-longmix64-b256': (256, 10, 64, 512, 2048, 512, True,
+                                       'highest'),
+    'qwen3next-serve-longmix64-b512': (512, 10, 64, 512, 2048, 512, True,
+                                       'highest'),
 }
 
 
@@ -72,7 +83,7 @@ def _rows_given(cell):
 
 # ---- 1. the rule ------------------------------------------------------------
 
-_WIDTHS = (768, 1024, 1792, 1856, 2048, 2688, 6144)
+_WIDTHS = (512, 768, 1024, 1792, 1856, 2048, 2688, 6144)
 
 
 @pytest.mark.parametrize('passes', (1, 6))
@@ -123,6 +134,10 @@ RULE_AT_THE_CELLS = {
     'nemotron3-serve-reason128': ('64,2688,384', '64,1856,384'),
     'nemotron3-serve-reason128-b256': ('64,2688,384', '64,1856,384'),
     'nemotron3-serve-reason128-b512': ('80,2688,384', '80,1856,384'),
+    'qwen3next-serve-longmix64': ('64,2048,512', '64,512,2048'),
+    'qwen3next-serve-longmix64-b128': ('64,2048,512', '64,512,2048'),
+    'qwen3next-serve-longmix64-b256': ('64,2048,512', '64,512,2048'),
+    'qwen3next-serve-longmix64-b512': ('64,2048,512', '64,512,2048'),
 }
 
 

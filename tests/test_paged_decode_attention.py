@@ -377,6 +377,82 @@ def test_mosaic_accepts_the_grouped_query_kernel_at_its_cells_shapes(
     assert c.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+def test_mosaic_accepts_the_grouped_query_kernel_at_heads_of_256(one_chip):
+    """qwen3next-serve-longmix64 (PR 55): 64 slots, 16 query heads on 2 K/V
+    heads of 256 -- a head two vregs of lanes --, pages of 32 rows x 512
+    lanes, tables of 288 entries, a pool of 18 432 blocks over the 2
+    attention layers. The MXU body: a block-diagonal [16, 512] query matrix
+    against windows of 16 pages; the row's scatter ahead of it costs no
+    copy of the pool."""
+    import jax
+    S, H, Hkv, dh, MB, NB, ln, bs, layer = 64, 16, 2, 256, 288, 18432, 2, \
+        32, 1
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(kc, vc, q, new, tables, pos):
+        blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
+        kc = kc.at[blk, layer, pos % bs, :].set(new)
+        return kc, pda.paged_decode_attention(q, kc, vc, tables, pos, layer,
+                                              scale=dh ** -0.5)
+
+    assert pda.shapes_ok(H, dh, bs, Hkv) and pda.form(H, Hkv) == 'mxu'
+    # one query a head of 256 is the VPU body's, which sums a head's lanes
+    # inside one vreg: refused, as it was
+    assert not pda.shapes_ok(Hkv, dh, bs, Hkv)
+    pool = sds((NB, ln, bs, Hkv * dh))
+    c = jax.jit(step, donate_argnums=0).lower(
+        pool, pool, sds((S, H, dh)), sds((S, Hkv * dh)),
+        sds((S, MB), jnp.int32), sds((S,), jnp.int32)).compile()
+    text = c.as_text()
+    assert 'tpu_custom_call' in text and 'paged_decode_attention' in text
+    assert 'f32[%d,%d,%d,%d]{3,2,1,0:T(8,128)}' % (NB, ln, bs, Hkv * dh) \
+        in text
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_mosaic_accepts_the_delta_rule_kernels_at_the_cells_shapes(one_chip):
+    """qwen3next-serve-longmix64's Gated DeltaNet layers (ops/gdn_ops.py, PR
+    55): 16 key heads and 32 value heads of 128. The decode update moves a
+    slot's [128, 2048] strip of the state pool in place (no temporary as
+    long as the pool), the tails' kernel serves 8 192 channels, and the
+    chunked prefill compiles at every bucket in blocks of 64 rows."""
+    import jax
+    from paddle_tpu.ops import gdn_ops, ssm_ops
+    S, hk, hv, dk, dv, layers = 64, 16, 32, 128, 128, 6
+    cw, vd = 2 * hk * dk + hv * dv, hv * dv
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(state, tails, rows, x, w, decay, beta, q, k):
+        c, tails = ssm_ops.decode_conv(tails, rows, 3, x, w,
+                                       jnp.zeros((cw,), jnp.float32))
+        o, state = gdn_ops.decode_update(state, rows, 3, decay, beta,
+                                         c[:, 2 * hk * dk:], q, k,
+                                         value_heads=hv)
+        return o, state, tails
+    assert gdn_ops.shapes_ok(dk, dv, hk, hv) and ssm_ops.shapes_ok(cw, 8)
+    c = jax.jit(step, donate_argnums=(0, 1)).lower(
+        sds((S + 1, layers, dk, vd)), sds((S + 1, layers, 8, cw)),
+        sds((S,), jnp.int32), sds((S, cw)), sds((cw, 4)), sds((S, vd)),
+        sds((S, vd)), sds((S, hk, dk)), sds((S, hk, dk))).compile()
+    text = c.as_text()
+    assert 'gdn_decode_update' in text and 'ssm_decode_conv' in text
+    assert c.memory_analysis().temp_size_in_bytes < 8 << 20
+    for rows in (128, 256, 512):
+        assert gdn_ops.shapes_ok(dk, dv, hk, hv, rows, 64)
+
+        def prefill(q, k, v, g, beta, s0):
+            return gdn_ops.prefill_chunks(q, k, v, g, beta, s0, chunk=64)
+        c = jax.jit(prefill).lower(
+            sds((rows, hk, dk)), sds((rows, hk, dk)), sds((rows, hv, dv)),
+            sds((rows, hv)), sds((rows, hv)), sds((dk, vd))).compile()
+        assert 'gdn_prefill_chunk' in c.as_text()
+        assert c.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize('NB,ln,bs,W,MB', [
     (1024, 24, 16, 1024, 48),   # fd355m-serve-chat: the K (or V) pool
     (8192, 7, 16, 640, 176)])   # joyai-serve-longchat64: the latent pool
