@@ -469,20 +469,22 @@ def multi_head_attention(x, cfg, prefix, mask_var=None, is_test=False,
                          seq_parallel=False, causal=False,
                          key_padding_bias=None):
     """Fused-QKV multi-head self-attention: one (D, 3D) matmul for Q,K,V
-    (fewer, larger MXU matmuls than three separate projections)."""
+    (fewer, larger MXU matmuls than three separate projections).
+
+    The flash branch hands the fused product ``[B, L, 3 * H * dh]`` to the
+    `flash_attention` op AS IT IS and gets the context back ``[B, L, H *
+    dh]``, as ``proj``'s `fc` reads it: no reshape, transpose, slice or
+    squeeze op lies between the two `fc`s. Where the op's packed kernels
+    apply (heads of 64 or 128 in whole 128-lane column blocks, no padding
+    bias, no mesh axis over the heads or L: `ops/attention_ops.py`) no
+    transpose runs on the device either; elsewhere the op turns the
+    product head-major inside its lowering. The unfused branch keeps its
+    ``[3, B, H, L, dh]`` transpose."""
     d, h = cfg.d_model, cfg.n_head
     dh = d // h
     qkv = layers.fc(input=x, size=3 * d, num_flatten_dims=2,
                     param_attr=ParamAttr(name=prefix + '.qkv.w'),
                     bias_attr=ParamAttr(name=prefix + '.qkv.b'))
-    qkv = layers.reshape(qkv, shape=[0, cfg.seq_len, 3, h, dh])
-    qkv = layers.transpose(qkv, perm=[2, 0, 3, 1, 4])  # (3, B, H, L, dh)
-    q = layers.squeeze(layers.slice(qkv, axes=[0], starts=[0], ends=[1]),
-                       axes=[0])
-    k = layers.squeeze(layers.slice(qkv, axes=[0], starts=[1], ends=[2]),
-                       axes=[0])
-    v = layers.squeeze(layers.slice(qkv, axes=[0], starts=[2], ends=[3]),
-                       axes=[0])
     attn_drop = getattr(cfg, 'attn_dropout', 0.0)
     # the fused kernel supports causal masking and per-key padding biases
     # (key_padding_bias [B, L]); a full additive mask_var or active
@@ -495,8 +497,8 @@ def multi_head_attention(x, cfg, prefix, mask_var=None, is_test=False,
         helper_block = x.block
         ctx = helper_block.create_var(
             name=prefix + '.flash_out',
-            shape=(-1, h, cfg.seq_len, dh), dtype='float32')
-        flash_inputs = {'Q': [q], 'K': [k], 'V': [v]}
+            shape=(-1, cfg.seq_len, d), dtype='float32')
+        flash_inputs = {'QKV': [qkv]}
         if key_padding_bias is not None:
             flash_inputs['KeyPaddingBias'] = [key_padding_bias]
         helper_block.append_op(
@@ -504,9 +506,15 @@ def multi_head_attention(x, cfg, prefix, mask_var=None, is_test=False,
             inputs=flash_inputs,
             outputs={'Out': [ctx]},
             attrs={'scale': dh ** -0.5, 'causal': bool(causal),
+                   'num_heads': h,
                    'ring_zigzag': bool(getattr(cfg, 'ring_zigzag',
                                                False))})
     else:
+        qkv = layers.reshape(qkv, shape=[0, cfg.seq_len, 3, h, dh])
+        qkv = layers.transpose(qkv, perm=[2, 0, 3, 1, 4])  # (3, B, H, L, dh)
+        q, k, v = (layers.squeeze(layers.slice(qkv, axes=[0], starts=[i],
+                                               ends=[i + 1]), axes=[0])
+                   for i in range(3))
         logits = layers.matmul(q, k, transpose_y=True, alpha=dh ** -0.5)
         if mask_var is not None:
             logits = layers.elementwise_add(logits, mask_var)
@@ -521,8 +529,8 @@ def multi_head_attention(x, cfg, prefix, mask_var=None, is_test=False,
                                      is_test=is_test,
                                      dropout_implementation='upscale_in_train')
         ctx = layers.matmul(weights, v)                # (B, H, L, dh)
-    ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
-    ctx = layers.reshape(ctx, shape=[0, cfg.seq_len, d])
+        ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
+        ctx = layers.reshape(ctx, shape=[0, cfg.seq_len, d])
     out = layers.fc(input=ctx, size=d, num_flatten_dims=2,
                     param_attr=ParamAttr(name=prefix + '.proj.w'),
                     bias_attr=ParamAttr(name=prefix + '.proj.b'))

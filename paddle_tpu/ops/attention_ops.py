@@ -3,39 +3,67 @@ analog of the reference's operators/jit/ runtime-codegen kernels
 (jit/kernel_base.h:24-52), with the same refer-vs-optimized cross-checking
 discipline of operators/jit/test.cc — see tests/test_attention.py).
 
-Forward: FlashAttention-2 style. Grid (batch*head, q tile, key block): a
-step holds bq queries and the block of K and V that its BlockSpec fetched
--- the whole key axis where the VMEM budget holds it (L 2048: one block,
-fetched once a (batch*head)) -- and WALKS it bk keys a trip with a trip
-count that ends at the diagonal: a tile above it takes no grid step and no
-DMA, and only the trips ON the diagonal build and apply the causal mask.
-Where the key axis takes several blocks, a step past the diagonal names
-the block the diagonal's step held, so nothing is fetched for it. A trip's
-scores are [bk, bq], queries along the lanes: the running max, the running
-denominator, the rescale and LSE are [1, bq] rows (a [bq, 1] column costs a
-vreg every 8 rows, an exp of it as much as an exp of 8 x 128 scores: that
-shape, not the mask or the second select, was what made round 3's forward
-tile cost 1.7-2.1 x a dQ tile), both reductions run down the sublanes, and
-the f32 accumulator is [dh, bq], turned once a q tile. Scores live in VMEM
-only. Matmuls feed the MXU in the input dtype (bf16 under AMP) with f32
+Two operand views, one set of three kernel bodies. Head-major: Q, K, V
+``[BH, L, dh]`` arrays, one head a lane block (any head size; a head of 64
+lies in half of a 128-lane tile, so HBM holds and every DMA moves a
+half-empty row). PACKED (PR 57): the fused QKV product ``[B, L, 3 * H *
+dh]`` itself, columns ``[3, H, dh]`` as `layers.fc` leaves it, the context
+out ``[B, L, H * dh]`` as `attn.proj`'s `fc` reads it and the gradient ONE
+array in the product's column order -- no transpose on either side of any
+kernel. A lane block there is 128 columns of the product: two heads of 64
+side by side (heads 2p and 2p + 1 of Q are column block p, K's block H *
+dh / 128 + p, V's twice that + p) or one head of 128; BlockSpecs pick the
+three out of the one array, every DMA and every store is lane-dense, and a
+kernel body runs its heads one after the other on static lane slices of
+the tiles it holds. The packed view applies to heads of 64 or 128 in whole
+lane blocks, without a padding bias, and under a mesh only where nothing
+shards the heads or L (`_flash_attention_op` decides, from shapes and the
+mesh alone); everything else -- `layers.flash_attention`, BERT's biased
+attention, ring attention, odd head sizes -- is head-major.
+
+Forward: FlashAttention-2 style. Grid (batch, lane block, q tile, key
+block) -- head-major: (batch*head, 1, ..) -- : a step holds bq queries and
+the block of K and V that its BlockSpec fetched -- the whole key axis
+where the VMEM budget holds it (L 2048: one block, fetched once a (batch,
+lane block)) -- and WALKS it bk keys a trip with a trip count that ends at
+the diagonal: a tile above it takes no grid step and no DMA, and only the
+trips ON the diagonal build and apply the causal mask. Where the key axis
+takes several blocks, a step past the diagonal names the block the
+diagonal's step held, so nothing is fetched for it. A trip's scores are
+[bk, bq] a head, queries along the lanes: the running max, the running
+denominator, the rescale and LSE are [1, bq] rows (a [bq, 1] column costs
+a vreg every 8 rows, an exp of it as much as an exp of 8 x 128 scores:
+that shape, not the mask or the second select, was what made round 3's
+forward tile cost 1.7-2.1 x a dQ tile), both reductions run down the
+sublanes, and the f32 accumulator is [heads * dh, bq] (a head's rows an
+aligned sublane slice), turned once a q tile. Scores live in VMEM only.
+Matmuls feed the MXU in the input dtype (bf16 under AMP) with f32
 accumulation via preferred_element_type; a power-of-two scale (head size
 64: 2^-3) multiplies the [bq, dh] operand once, exactly, any other the f32
 scores. Alongside O it emits per-row LSE (logsumexp), the residual the
-backward needs, in the [1, L] layout both backward kernels read.
+backward needs, as [.., heads, 1, L] rows, the layout both backward
+kernels read.
 
 Backward: two pallas kernels (the FlashAttention-2 split), the same walk:
   - dQ:    a step holds a q tile and walks the keys up to the diagonal;
   - dK/dV: a step holds a k tile and walks the queries FROM the diagonal.
 Both recompute the probability tile from (Q, K, LSE) instead of storing it
 — O(L) memory, O(L^2) recompute, the standard trade on HBM-bound hardware.
-delta = rowsum(dO * O) is precomputed outside the kernels (XLA fuses it).
-The tile sizes are `flash_attention_tiling`'s, a function of (L, head size,
-dtype, kernel) with the sweep that set its constants beside it; a program's
-`flash_attention_tiling_total{kernel,bq,bk}` says which schedule it got.
+delta = rowsum(dO * O) a head is precomputed outside the kernels (XLA
+fuses it). Packed, dQ, dK and dV come lane-dense, [B, L, H * dh] each, and
+one concatenate makes the product's cotangent (one buffer filled by both
+kernels would need dKV to write two column blocks of one array a step,
+which one BlockSpec cannot name); the residuals are the product, the
+context and LSE. The tile sizes are `flash_attention_tiling`'s, a function
+of (L, head size, dtype, kernel, heads a block) with the sweep that set
+its constants beside it; a program's
+`flash_attention_tiling_total{kernel,bq,bk}` says which schedule it got
+and `flash_attention_layout_total{layout}` which operand view.
 
 Under SPMD (an active MeshRunner mesh) the op no longer falls back to
 einsum: it wraps the kernel in shard_map over the (data, model) axes —
-batch and heads are embarrassingly parallel — and when the sequence axis
+batch and heads are embarrassingly parallel; the packed view over 'data'
+alone, its columns cannot be sharded by head — and when the sequence axis
 itself is sharded it dispatches to the ring-attention path
 (parallel/ring_attention.py), making ring the long-context execution mode
 of this same op rather than a parallel universe.
@@ -96,6 +124,23 @@ def _pick_block(ln, pref):
 # trip's fixed cost (the state read and written, the matmuls' fill) is
 # large beside 256 x 256 scores, and 1024 x 1024 wastes more of the four
 # tiles on the diagonal than it saves in trips.
+# The operand view, same tool and chip (2026-10-03, jax 0.9.0, libtpu
+# 0.0.34; PERF.md, PR 57), at 512 x 512, ms a call forward / dQ / dKV, then
+# 'whole': everything between the fused QKV product and `attn.proj`, both
+# ways (head-major pays its two transposes each way, packed its one
+# concatenate):
+#   bh 64, dh 64, bfloat16:  head-major 0.78 / 0.89 / 1.18, whole 3.38;
+#                            packed     0.74 / 0.83 / 1.15, whole 2.61
+#   bh 32, dh 64, bfloat16:  head-major 0.41 / 0.48 / 0.63, whole 1.63;
+#                            packed     0.40 / 0.46 / 0.62, whole 1.37
+#   bh 32, dh 128, bfloat16: head-major 0.49 / 0.54 / 0.62, whole 2.22;
+#                            packed     0.52 / 0.60 / 0.69, whole 1.70
+#   bh 64, dh 64, float32:   head-major 0.81 / 0.96 / 1.39, whole 4.72;
+#                            packed     0.74 / 0.84 / 1.17, whole 2.79
+# Two heads of 64 a block cost LESS a head than one padded head (the DMAs
+# halve); one head of 128 a block costs 6-11 % more a kernel than the same
+# body on head-major arrays (the same bytes, read as 256-byte row pieces of
+# a 3 x wider array) and the whole is still 23 % less.
 _TILE = 512
 # the K and V (dKV: Q and dO) blocks a grid step holds, double-buffered,
 # of the 16 MB a kernel has on v5e; a trip's float32 scores, their exp and
@@ -105,30 +150,38 @@ _TILE = 512
 _WALK_VMEM_BYTES = 4 << 20
 
 
-def flash_attention_tiling(ln, dh, dtype, kernel):
+def flash_attention_tiling(ln, dh, dtype, kernel, heads=1):
     """(bq, bk, block) of `kernel` ('fwd', 'bwd_dq', 'bwd_dkv') at sequence
-    length `ln`, head size `dh`, operands of `dtype`: a grid step holds bq
-    query rows (dKV: bk keys) and WALKS the other axis bk keys (dKV: bq
-    queries) a trip, inside the block of `block` rows of it that the step's
-    BlockSpec fetched -- the whole axis where `_WALK_VMEM_BYTES` holds it.
-    Short or odd L (BERT's 128 / 512, `flash_shapes_ok`) is one tile. A
-    function of its arguments alone: no process sees another schedule."""
+    length `ln`, head size `dh`, operands of `dtype`, `heads` heads a lane
+    block (2 where the packed layout holds two heads of 64 in 128 lanes):
+    a grid step holds bq query rows (dKV: bk keys) and WALKS the other axis
+    bk keys (dKV: bq queries) a trip, inside the block of `block` rows of
+    it that the step's BlockSpec fetched -- the whole axis where
+    `_WALK_VMEM_BYTES` holds it. Short or odd L (BERT's 128 / 512,
+    `flash_shapes_ok`) is one tile. A function of its arguments alone: no
+    process sees another schedule."""
     bq = bk = _pick_block(ln, _TILE)
     walk = bq if kernel == 'bwd_dkv' else bk
-    # two operands, two buffers each, a row padded to the 128 lanes
-    row = 4 * -(-dh // 128) * 128 * jnp.dtype(dtype).itemsize
+    # two operands, two buffers each; a row of one head-major head is padded
+    # to the 128 lanes, a packed row of two heads of 64 fills them
+    row = 4 * -(-dh * heads // 128) * 128 * jnp.dtype(dtype).itemsize
     n = ln // walk
     fit = [d for d in range(1, n + 1)
            if n % d == 0 and d * walk * row <= _WALK_VMEM_BYTES]
     return bq, bk, walk * max(fit or [1])
 
 
-def _count_tiling(kernel, bq, bk):
-    """`flash_attention_tiling_total{kernel,bq,bk}`: + 1 a kernel a lowering
-    (trace time), so a program's counters say which schedule it got."""
+def _count_tiling(kernel, bq, bk, packed):
+    """`flash_attention_tiling_total{kernel,bq,bk}` and
+    `flash_attention_layout_total{layout}`: + 1 a kernel a lowering (trace
+    time), so a program's counters say which schedule and which operand
+    layout ('packed': the fused QKV product; 'heads': head-major) each of
+    its attention ops got."""
     from .. import monitor
     monitor.inc('flash_attention_tiling_total',
                 labels={'kernel': kernel, 'bq': str(bq), 'bk': str(bk)})
+    monitor.inc('flash_attention_layout_total',
+                labels={'layout': 'packed' if packed else 'heads'})
 
 
 def _folds(scale):
@@ -184,16 +237,55 @@ def _dot(a, b, dims):
 
 
 # --------------------------------------------------------------------------
+# heads a lane block. A kernel's blocks are [rows, heads * dh] wide: one
+# head (the head-major view, and packed heads of 128) or two heads of 64
+# side by side in the 128 lanes of the fused QKV product's column block.
+# A head's operand is a static lane slice of the block (`_head`), its
+# [dh, bq] accumulator an aligned sublane slice of the [heads * dh, bq]
+# scratch, its softmax state a [1, bq] row of its own. The other way --
+# the other head's lanes of the held operand zeroed once a step and every
+# contraction over all 128 lanes -- was swept beside it (kernbench, one
+# v5e, 2026-10-03, PR 57; ms a call forward / dQ / dKV at fd355m-train-2k):
+# slices 0.74 / 0.81 / 1.12, zeroed lanes 0.85 / 0.91 / 1.12 (that call's).
+# --------------------------------------------------------------------------
+
+def _span(h, heads, width):
+    """Head h's share of an axis of `width` (the lanes of a tile, the rows
+    of the turned accumulator): all of it where the block holds one head."""
+    dh = width // heads
+    return slice(h * dh, (h + 1) * dh)
+
+
+def _head(x, h, heads):
+    """Head h's [rows, dh] of the [rows, heads * dh] tile `x`."""
+    return x if heads == 1 else x[:, _span(h, heads, x.shape[1])]
+
+
+def _scores(k, q, scale, bias, visible):
+    """One head's [bk, bq] scores of a trip: k @ q.T, times the scale where
+    it was not folded into an operand, plus the per-key padding bias [bk,
+    1] (0 keep / -1e9 drop), the tile's causal mask applied."""
+    s = _dot(k, q, _NT)
+    if not _folds(scale):
+        s = s * scale
+    if bias is not None:
+        s = s + bias
+    if visible is not None:
+        s = jnp.where(visible, s, _NEG_INF)
+    return s
+
+
+# --------------------------------------------------------------------------
 # forward kernel
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(scale, causal, bk, has_bias, *refs):
-    """A grid step holds bq queries and walks the keys of its K / V block
-    bk a trip, up to the diagonal. The scores of a trip are [bk, bq]: a
-    row's max, sum, `alpha` and `lse` are [1, bq] rows along the lanes (4
-    vregs at 512 where a [bq, 1] column is 64), the two reductions run
-    down the sublanes, and the accumulator is [dh, bq], turned once at
-    the end."""
+def _fwd_kernel(scale, causal, bk, has_bias, heads, *refs):
+    """A grid step holds bq queries of one lane block (`heads` heads) and
+    walks the keys of its K / V block bk a trip, up to the diagonal. The
+    scores of a trip are [bk, bq] a head: a row's max, sum, `alpha` and
+    `lse` are [1, bq] rows along the lanes (4 vregs at 512 where a [bq, 1]
+    column is 64), the two reductions run down the sublanes, and the
+    accumulator is [heads * dh, bq], turned once at the end."""
     import jax.experimental.pallas as pl
     if has_bias:
         (q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
@@ -202,10 +294,9 @@ def _fwd_kernel(scale, causal, bk, has_bias, *refs):
         (q_ref, k_ref, v_ref, o_ref, lse_ref,
          m_scr, l_scr, acc_scr) = refs
         bias_ref = None
-    i, j = pl.program_id(1), pl.program_id(2)
-    bq = q_ref.shape[1]
+    i, j = pl.program_id(2), pl.program_id(3)
+    bq, width = q_ref.shape[1:]
     spm = k_ref.shape[1] // bk
-    fold = _folds(scale)
 
     @pl.when(j == 0)
     def _init():
@@ -214,54 +305,69 @@ def _fwd_kernel(scale, causal, bk, has_bias, *refs):
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     q = q_ref[0]
-    if fold:
+    if _folds(scale):
         q = q * scale
+    qs = [_head(q, h, heads) for h in range(heads)]
 
     def tile(t, masked):
         k = k_ref[0, _at(t, bk), :]
         v = v_ref[0, _at(t, bk), :]
-        s = _dot(k, q, _NT)                                 # [bk, bq]
-        if not fold:
-            s = s * scale
-        if bias_ref is not None:
-            # per-key additive bias (padding masks: 0 keep / -1e9 drop)
-            bias = bias_ref[0, t].reshape(bk, 1)
-            s = s + bias
-        if masked:
-            # keys are walked from column 0, so a row's m is finite after
-            # its first tile and exp(-1e30 - m) is an exact 0.0
-            s = jnp.where(_visible(i * bq, (j * spm + t) * bk, bq, bk),
-                          s, _NEG_INF)
-        m_prev = m_scr[...]                                 # [1, bq]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        if bias_ref is not None:
-            # exact zero for dropped keys (-1e8 or lower -- covers the
-            # documented -1e9 pad convention), independent of underflow
-            p = jnp.where(bias > -1e8, p, 0.0)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=0, keepdims=True)
-        m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * alpha + _dot(
-            v, p.astype(v.dtype), _TN)                      # [dh, bq]
+        bias = None if bias_ref is None else bias_ref[0, t].reshape(bk, 1)
+        # keys are walked from column 0, so a row's m is finite after its
+        # first tile and exp(-1e30 - m) of a masked score is an exact 0.0
+        visible = _visible(i * bq, (j * spm + t) * bk, bq, bk) \
+            if masked else None
+        for h, qh in enumerate(qs):
+            s = _scores(_head(k, h, heads), qh, scale, bias, visible)
+            m_prev = m_scr[h]                                   # [1, bq]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            if bias is not None:
+                # exact zero for dropped keys (-1e8 or lower -- covers the
+                # documented -1e9 pad convention), independent of underflow
+                p = jnp.where(bias > -1e8, p, 0.0)
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=0, keepdims=True)
+            m_scr[h] = m_new
+            rows = _span(h, heads, width)
+            acc_scr[rows] = acc_scr[rows] * alpha + _dot(
+                _head(v, h, heads), p.astype(v.dtype), _TN)     # [dh, bq]
 
     masked, end = _key_walk(causal, i, j, bq, bk, spm)
     _trips(0, masked, lambda t: tile(t, False))
     _trips(masked, end, lambda t: tile(t, True))
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l).T.astype(o_ref.dtype)
-        lse_ref[0] = m_scr[...] + jnp.log(l)
+        for h in range(heads):
+            l = jnp.maximum(l_scr[h], 1e-30)
+            rows = _span(h, heads, width)
+            acc_scr[rows] = acc_scr[rows] / l
+            lse_ref[0, h] = m_scr[h] + jnp.log(l)
+        o_ref[0] = acc_scr[...].T.astype(o_ref.dtype)
 
 
-def _key_specs(causal, bq, bk, block, dh, n_heads):
-    """BlockSpecs of a step's K / V block ([L, dh], `block` rows) and of its
-    padding bias ([B, L] as a sub-tile a row: batch*head row b is batch
-    b // n_heads), for a step that holds q tile i: under the causal mask
-    a step past the diagonal names the block the diagonal's step held, so
-    nothing is fetched for it."""
+def _view(q, heads):
+    """(batches, lane blocks, heads a block, lanes a block, the column
+    blocks at which Q, K and V start) of a kernel's operands: head-major
+    ``[BH, L, dh]`` arrays (`heads` 0: one block of one head, dh lanes,
+    three arrays) or the packed ``[B, L, 3 * heads * dh]`` product, whose
+    columns are ``[3, heads, dh]`` -- Q's lane block p is its column block
+    p, K's is heads * dh / 128 + p, V's twice that + p."""
+    if not heads:
+        return q.shape[0], 1, 1, q.shape[2], (0, 0, 0)
+    dh = q.shape[2] // (3 * heads)
+    blocks = heads * dh // 128
+    return q.shape[0], blocks, 128 // dh, 128, (0, blocks, 2 * blocks)
+
+
+def _key_specs(causal, bq, bk, block, width, n_heads, k_at, v_at):
+    """BlockSpecs of a step's K and V blocks (`block` rows of lane block
+    p, from column block `k_at` / `v_at` on) and of its padding bias
+    ([B, L] as a sub-tile a row: batch*head row b of the head-major view
+    is batch b // n_heads), for a step that holds q tile i: under the
+    causal mask a step past the diagonal names the block the diagonal's
+    step held, so nothing is fetched for it."""
     import jax.experimental.pallas as pl
     if causal:
         def held(i, j):
@@ -269,65 +375,83 @@ def _key_specs(causal, bq, bk, block, dh, n_heads):
     else:
         def held(i, j):
             return j
-    return (pl.BlockSpec((1, block, dh),
-                         lambda b, i, j: (b, held(i, j), 0)),
+    return (pl.BlockSpec((1, block, width),
+                         lambda b, p, i, j: (b, held(i, j), k_at + p)),
+            pl.BlockSpec((1, block, width),
+                         lambda b, p, i, j: (b, held(i, j), v_at + p)),
             pl.BlockSpec((1, block // bk, 1, bk),
-                         lambda b, i, j: (b // n_heads, held(i, j), 0, 0)))
+                         lambda b, p, i, j: (b // n_heads, held(i, j), 0,
+                                             0)))
 
 
 def _flash_fwd_pallas(q, k, v, scale, causal, interpret, bias=None,
-                      n_heads=1, tiling=None):
-    """(O, LSE) of [BH, L, dh] operands by the forward kernel under the
-    rule's schedule (or `tiling`, the sweep's). The call goes through
-    `jax.jit`: the 24 layers of a program trace and lower the kernel ONCE
-    (set-up, not the step: XLA inlines the calls)."""
-    ln, dh = q.shape[1:]
-    tiling = tiling or flash_attention_tiling(ln, dh, q.dtype, 'fwd')
-    _count_tiling('fwd', *tiling[:2])
+                      n_heads=1, tiling=None, heads=0):
+    """(O, LSE) by the forward kernel under the rule's schedule (or
+    `tiling`, the sweep's): of head-major [BH, L, dh] operands ([BH, L,
+    dh], [BH, L]), or with `heads` of the packed [B, L, 3 * heads * dh]
+    product `q` (k and v None: [B, L, heads * dh], [B, heads, L]). The call
+    goes through `jax.jit`: the 24 layers of a program trace and lower the
+    kernel ONCE (set-up, not the step: XLA inlines the calls)."""
+    ln = q.shape[1]
+    _, _, per, width, _ = _view(q, heads)
+    tiling = tiling or flash_attention_tiling(ln, width // per, q.dtype,
+                                              'fwd', per)
+    _count_tiling('fwd', *tiling[:2], packed=bool(heads))
     return _fwd_call(q, k, v, bias, scale, causal, interpret, n_heads,
-                     tiling)
+                     tiling, heads)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
-def _fwd_call(q, k, v, bias, scale, causal, interpret, n_heads, tiling):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
+def _fwd_call(q, k, v, bias, scale, causal, interpret, n_heads, tiling,
+              heads):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    bh, ln, dh = q.shape
+    ln = q.shape[1]
+    nbatch, blocks, per, width, (q_at, k_at, v_at) = _view(q, heads)
     bq, bk, block = tiling
     has_bias = bias is not None
-    kernel = functools.partial(_fwd_kernel, scale, causal, bk, has_bias)
-    qspec = pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0))
-    kspec, bias_spec = _key_specs(causal, bq, bk, block, dh, n_heads)
-    ins = [q, k, v]
-    in_specs = [qspec, kspec, kspec]
+    kernel = functools.partial(_fwd_kernel, scale, causal, bk, has_bias,
+                               per)
+    qspec = pl.BlockSpec((1, bq, width),
+                         lambda b, p, i, j: (b, i, q_at + p))
+    kspec, vspec, bias_spec = _key_specs(causal, bq, bk, block, width,
+                                         n_heads, k_at, v_at)
+    ins = [q, q, q] if heads else [q, k, v]
+    in_specs = [qspec, kspec, vspec]
     if has_bias:
         ins.append(bias.astype(jnp.float32).reshape(-1, ln // bk, 1, bk))
         in_specs.append(bias_spec)
     o, lse = pl.pallas_call(
         kernel,
-        grid=(bh, ln // bq, ln // block),
+        grid=(nbatch, blocks, ln // bq, ln // block),
         in_specs=in_specs,
-        out_specs=[qspec,
-                   pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))],
-        out_shape=[jax.ShapeDtypeStruct((bh, ln, dh), q.dtype),
-                   jax.ShapeDtypeStruct((bh, 1, ln), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((1, bq), jnp.float32),
-                        pltpu.VMEM((1, bq), jnp.float32),
-                        pltpu.VMEM((dh, bq), jnp.float32)],
+        out_specs=[pl.BlockSpec((1, bq, width),
+                                lambda b, p, i, j: (b, i, p)),
+                   pl.BlockSpec((1, per, 1, bq),
+                                lambda b, p, i, j: (b, p, 0, i))],
+        out_shape=[jax.ShapeDtypeStruct((nbatch, ln, blocks * width),
+                                        q.dtype),
+                   jax.ShapeDtypeStruct((nbatch, blocks * per, 1, ln),
+                                        jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((per, 1, bq), jnp.float32),
+                        pltpu.VMEM((per, 1, bq), jnp.float32),
+                        pltpu.VMEM((width, bq), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
         name='flash_attention_fwd',
     )(*ins)
-    return o, lse[:, 0]
+    return o, (lse[:, :, 0] if heads else lse[:, 0, 0])
 
 
 # --------------------------------------------------------------------------
 # backward kernels
 # --------------------------------------------------------------------------
 
-def _bwd_dq_kernel(scale, causal, bk, has_bias, *refs):
-    """The forward's walk and its [bk, bq] scores; dQ sums as [dh, bq]."""
+def _bwd_dq_kernel(scale, causal, bk, has_bias, heads, *refs):
+    """The forward's walk and its [bk, bq] scores a head; dQ sums as
+    [heads * dh, bq]."""
     import jax.experimental.pallas as pl
     if has_bias:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
@@ -336,55 +460,55 @@ def _bwd_dq_kernel(scale, causal, bk, has_bias, *refs):
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dq_scr) = refs
         bias_ref = None
-    i, j = pl.program_id(1), pl.program_id(2)
-    bq = q_ref.shape[1]
+    i, j = pl.program_id(2), pl.program_id(3)
+    bq, width = q_ref.shape[1:]
     spm = k_ref.shape[1] // bk
-    fold = _folds(scale)
 
     @pl.when(j == 0)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    q, do = q_ref[0], do_ref[0]
-    if fold:
+    q = q_ref[0]
+    if _folds(scale):
         q = q * scale
-    lse, delta = lse_ref[0], delta_ref[0]                   # [1, bq]
+    qs = [_head(q, h, heads) for h in range(heads)]
+    dos = [_head(do_ref[0], h, heads) for h in range(heads)]
 
     def tile(t, masked):
         k = k_ref[0, _at(t, bk), :]
         v = v_ref[0, _at(t, bk), :]
-        s = _dot(k, q, _NT)                                 # [bk, bq]
-        if not fold:
-            s = s * scale
-        if bias_ref is not None:
-            bias = bias_ref[0, t].reshape(bk, 1)
-            s = s + bias
-        if masked:
-            s = jnp.where(_visible(i * bq, (j * spm + t) * bk, bq, bk),
-                          s, _NEG_INF)
-        p = jnp.exp(s - lse)                  # masked entries underflow
-        if bias_ref is not None:
-            # all-padded rows have lse = log(1e-30); without the forward's
-            # exact zeroing p explodes to ~e^69 and poisons dQ
-            p = jnp.where(bias > -1e8, p, 0.0)
-        ds = p * (_dot(v, do, _NT) - delta)
-        # the scale of dS multiplies the float32 sum once, at the end
-        dq_scr[...] += _dot(k, ds.astype(k.dtype), _TN)     # [dh, bq]
+        bias = None if bias_ref is None else bias_ref[0, t].reshape(bk, 1)
+        visible = _visible(i * bq, (j * spm + t) * bk, bq, bk) \
+            if masked else None
+        for h in range(heads):
+            kh = _head(k, h, heads)
+            s = _scores(kh, qs[h], scale, bias, visible)
+            p = jnp.exp(s - lse_ref[0, h])    # masked entries underflow
+            if bias is not None:
+                # all-padded rows have lse = log(1e-30); without the
+                # forward's exact zeroing p explodes to ~e^69 and poisons dQ
+                p = jnp.where(bias > -1e8, p, 0.0)
+            ds = p * (_dot(_head(v, h, heads), dos[h], _NT)
+                      - delta_ref[0, h])
+            # the scale of dS multiplies the float32 sum once, at the end
+            dq_scr[_span(h, heads, width)] += _dot(
+                kh, ds.astype(k.dtype), _TN)                    # [dh, bq]
 
     masked, end = _key_walk(causal, i, j, bq, bk, spm)
     _trips(0, masked, lambda t: tile(t, False))
     _trips(masked, end, lambda t: tile(t, True))
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
         dq_ref[0] = (dq_scr[...] * scale).T.astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(scale, causal, bq, has_bias, *refs):
-    """A grid step holds bk keys and walks the queries of its Q / dO block
-    bq a trip, FROM the diagonal. Scores as [bk, bq] here too: the walked
-    queries lie along the lanes, as lse and delta are stored, and dV = P^T
-    dO, dK = dS^T Q are plain products."""
+def _bwd_dkv_kernel(scale, causal, bq, has_bias, heads, *refs):
+    """A grid step holds bk keys of one lane block and walks the queries
+    of its Q / dO block bq a trip, FROM the diagonal. Scores as [bk, bq]
+    here too: the walked queries lie along the lanes, as lse and delta are
+    stored, and dV = P^T dO, dK = dS^T Q are plain products -- [bk, heads
+    * dh], of which a head's lanes are its own."""
     import jax.experimental.pallas as pl
     if has_bias:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
@@ -393,39 +517,37 @@ def _bwd_dkv_kernel(scale, causal, bq, has_bias, *refs):
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_scr, dv_scr) = refs
         bias_ref = None
-    i, j = pl.program_id(1), pl.program_id(2)      # i: k tile, j: q block
-    bk, dh = k_ref.shape[1:]
+    i, j = pl.program_id(2), pl.program_id(3)      # i: k tile, j: q block
+    bk, width = k_ref.shape[1:]
     spm = q_ref.shape[1] // bq
-    fold = _folds(scale)
 
     @pl.when(j == 0)
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    k, v = k_ref[0], v_ref[0]
-    if fold:
+    k = k_ref[0]
+    if _folds(scale):
         k = k * scale
+    ks = [_head(k, h, heads) for h in range(heads)]
+    vs = [_head(v_ref[0], h, heads) for h in range(heads)]
     bias = bias_ref[0].reshape(bk, 1) if has_bias else None
 
     def tile(t, masked):
         q = q_ref[0, _at(t, bq), :]
         do = do_ref[0, _at(t, bq), :]
-        s = _dot(k, q, _NT)                                 # [bk, bq]
-        if not fold:
-            s = s * scale
-        if bias is not None:
-            s = s + bias
-        if masked:
-            s = jnp.where(_visible((j * spm + t) * bq, i * bk, bq, bk),
-                          s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, t])
-        if bias is not None:
-            p = jnp.where(bias > -1e8, p, 0.0)
-        dv_scr[...] += _dot(p.astype(do.dtype), do, _NN)
-        dp = _dot(v, do, _NT)
-        ds = p * (dp - delta_ref[0, t])
-        dk_scr[...] += _dot(ds.astype(q.dtype), q, _NN)
+        visible = _visible((j * spm + t) * bq, i * bk, bq, bk) \
+            if masked else None
+        for h in range(heads):
+            qh, doh = _head(q, h, heads), _head(do, h, heads)
+            s = _scores(ks[h], qh, scale, bias, visible)
+            p = jnp.exp(s - lse_ref[0, h, t])
+            if bias is not None:
+                p = jnp.where(bias > -1e8, p, 0.0)
+            cols = _span(h, heads, width)
+            dv_scr[:, cols] += _dot(p.astype(do.dtype), doh, _NN)
+            ds = p * (_dot(vs[h], doh, _NT) - delta_ref[0, h, t])
+            dk_scr[:, cols] += _dot(ds.astype(q.dtype), qh, _NN)
 
     # the walk over queries STARTS at the diagonal: [first, full) on it,
     # [full, spm) wholly under it
@@ -437,7 +559,7 @@ def _bwd_dkv_kernel(scale, causal, bq, has_bias, *refs):
     _trips(first, full, lambda t: tile(t, True))
     _trips(full, spm, lambda t: tile(t, False))
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
         dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -445,48 +567,74 @@ def _bwd_dkv_kernel(scale, causal, bq, has_bias, *refs):
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, interpret,
                       bias=None, n_heads=1, tiling_dq=None,
-                      tiling_dkv=None):
+                      tiling_dkv=None, heads=0):
     """(dQ, dK, dV) by the two backward kernels, each under the rule's
-    schedule (or the sweep's); through `jax.jit` as the forward is."""
-    ln, dh = q.shape[1:]
+    schedule (or the sweep's); through `jax.jit` as the forward is. With
+    `heads`, `q` is the packed product (k, v None), `o` and `do` are [B,
+    L, heads * dh], `lse` [B, heads, L], and the three gradients come
+    lane-dense, [B, L, heads * dh] each, in the product's column order."""
+    ln = q.shape[1]
+    _, _, per, width, _ = _view(q, heads)
+    dh = width // per
     tiling_dq = tiling_dq or flash_attention_tiling(ln, dh, q.dtype,
-                                                    'bwd_dq')
+                                                    'bwd_dq', per)
     tiling_dkv = tiling_dkv or flash_attention_tiling(ln, dh, q.dtype,
-                                                      'bwd_dkv')
-    _count_tiling('bwd_dq', *tiling_dq[:2])
-    _count_tiling('bwd_dkv', *tiling_dkv[:2])
+                                                      'bwd_dkv', per)
+    _count_tiling('bwd_dq', *tiling_dq[:2], packed=bool(heads))
+    _count_tiling('bwd_dkv', *tiling_dkv[:2], packed=bool(heads))
     return _bwd_call(q, k, v, o, lse, do, bias, scale, causal, interpret,
-                     n_heads, tiling_dq, tiling_dkv)
+                     n_heads, tiling_dq, tiling_dkv, heads)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12, 13))
 def _bwd_call(q, k, v, o, lse, do, bias, scale, causal, interpret, n_heads,
-              tiling_dq, tiling_dkv):
+              tiling_dq, tiling_dkv, heads):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    bh, ln, dh = q.shape
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    ln = q.shape[1]
+    nbatch, blocks, per, width, (q_at, k_at, v_at) = _view(q, heads)
+    n_rows = blocks * per                   # heads a batch row: [.., L] rows
+    # delta = rowsum(dO * O) a head, [batches, heads, L] as LSE is
+    delta = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if heads:
+        # a head's columns summed by a 0 / 1 matrix on the MXU, exactly
+        # (`highest`): XLA reduces a 64-lane piece of a row only behind a
+        # transposing copy of dO and of O (two 33 us copies a layer at
+        # fd355m-train-2k; PERF.md, PR 57)
+        own = jnp.arange(n_rows * width // per)[:, None] // (width // per) \
+            == jnp.arange(n_rows)[None, :]
+        delta = jnp.einsum('blw,wh->bhl', delta, own.astype(jnp.float32),
+                           precision=lax.Precision.HIGHEST)
+    else:
+        delta = jnp.sum(delta, axis=-1)[:, None, :]
+    lse = lse.reshape(nbatch, n_rows, ln)
     has_bias = bias is not None
     bias32 = bias.astype(jnp.float32) if has_bias else None
     params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))
+    qkv = [q, q, q] if heads else [q, k, v]
+    out = jax.ShapeDtypeStruct((nbatch, ln, blocks * width), q.dtype)
 
     bq, bk, block = tiling_dq
-    qspec = pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0))
-    kspec, bias_spec = _key_specs(causal, bq, bk, block, dh, n_heads)
-    rowspec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
-    ins = [q, k, v, do, lse[:, None, :], delta[:, None, :]]
-    in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
+    qspec = pl.BlockSpec((1, bq, width),
+                         lambda b, p, i, j: (b, i, q_at + p))
+    ospec = pl.BlockSpec((1, bq, width), lambda b, p, i, j: (b, i, p))
+    kspec, vspec, bias_spec = _key_specs(causal, bq, bk, block, width,
+                                         n_heads, k_at, v_at)
+    rowspec = pl.BlockSpec((1, per, 1, bq), lambda b, p, i, j: (b, p, 0, i))
+    ins = qkv + [do, lse[:, :, None, :], delta[:, :, None, :]]
+    in_specs = [qspec, kspec, vspec, ospec, rowspec, rowspec]
     if has_bias:
         ins.append(bias32.reshape(-1, ln // bk, 1, bk))
         in_specs.append(bias_spec)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale, causal, bk, has_bias),
-        grid=(bh, ln // bq, ln // block),
+        functools.partial(_bwd_dq_kernel, scale, causal, bk, has_bias, per),
+        grid=(nbatch, blocks, ln // bq, ln // block),
         in_specs=in_specs,
-        out_specs=[qspec],
-        out_shape=[jax.ShapeDtypeStruct((bh, ln, dh), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((dh, bq), jnp.float32)],
+        out_specs=[ospec],
+        out_shape=[out],
+        scratch_shapes=[pltpu.VMEM((width, bq), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
         name='flash_attention_bwd_dq',
@@ -501,27 +649,33 @@ def _bwd_call(q, k, v, o, lse, do, bias, scale, causal, interpret, n_heads,
     else:
         def held(i, j):
             return j
-    qspec = pl.BlockSpec((1, block, dh), lambda b, i, j: (b, held(i, j), 0))
-    kspec = pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, i, 0))
+    qspec = pl.BlockSpec((1, block, width),
+                         lambda b, p, i, j: (b, held(i, j), q_at + p))
+    dospec = pl.BlockSpec((1, block, width),
+                          lambda b, p, i, j: (b, held(i, j), p))
+    kspec = pl.BlockSpec((1, bk, width),
+                         lambda b, p, i, j: (b, i, k_at + p))
+    vspec = pl.BlockSpec((1, bk, width),
+                         lambda b, p, i, j: (b, i, v_at + p))
+    ospec = pl.BlockSpec((1, bk, width), lambda b, p, i, j: (b, i, p))
     # lse and delta a sub-tile a row, so a trip reads its row by index
-    rowspec = pl.BlockSpec((1, block // bq, 1, bq),
-                           lambda b, i, j: (b, held(i, j), 0, 0))
-    ins = [q, k, v, do, lse.reshape(bh, ln // bq, 1, bq),
-           delta.reshape(bh, ln // bq, 1, bq)]
-    in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
+    rowspec = pl.BlockSpec((1, per, block // bq, 1, bq),
+                           lambda b, p, i, j: (b, p, held(i, j), 0, 0))
+    ins = qkv + [do, lse.reshape(nbatch, n_rows, ln // bq, 1, bq),
+                 delta.reshape(nbatch, n_rows, ln // bq, 1, bq)]
+    in_specs = [qspec, kspec, vspec, dospec, rowspec, rowspec]
     if has_bias:
         ins.append(bias32[:, None, :])
         in_specs.append(pl.BlockSpec(
-            (1, 1, bk), lambda b, i, j: (b // n_heads, 0, i)))
+            (1, 1, bk), lambda b, p, i, j: (b // n_heads, 0, i)))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale, causal, bq, has_bias),
-        grid=(bh, ln // bk, ln // block),
+        functools.partial(_bwd_dkv_kernel, scale, causal, bq, has_bias, per),
+        grid=(nbatch, blocks, ln // bk, ln // block),
         in_specs=in_specs,
-        out_specs=[kspec, kspec],
-        out_shape=[jax.ShapeDtypeStruct((bh, ln, dh), k.dtype),
-                   jax.ShapeDtypeStruct((bh, ln, dh), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, dh), jnp.float32),
-                        pltpu.VMEM((bk, dh), jnp.float32)],
+        out_specs=[ospec, ospec],
+        out_shape=[out, out],
+        scratch_shapes=[pltpu.VMEM((bk, width), jnp.float32),
+                        pltpu.VMEM((bk, width), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
         name='flash_attention_bwd_dkv',
@@ -613,6 +767,63 @@ def _flash_biased_bwd(scale, causal, impl, n_heads, res, ct):
 
 
 _flash_biased.defvjp(_flash_biased_fwd, _flash_biased_bwd)
+
+
+# --------------------------------------------------------------------------
+# the packed operand view: the fused QKV product in, the context out
+# --------------------------------------------------------------------------
+
+def packed_shapes_ok(heads, dh):
+    """Whether heads of `dh` lie in aligned 128-lane column blocks of the
+    fused product: one head of 128 a block, or two of 64."""
+    return dh in (64, 128) and (heads * dh) % 128 == 0
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _flash_packed(qkv, heads, scale, causal, interpret):
+    """Attention of the fused product ``qkv`` [B, L, 3 * heads * dh]
+    (columns ``[3, heads, dh]``, as `layers.fc` leaves it): the context
+    [B, L, heads * dh], as `attn.proj`'s `fc` reads it. The kernels pick
+    Q, K and V lane blocks out of the one array and write lane-dense; the
+    cotangent is ONE [B, L, 3 * heads * dh] array in the product's column
+    order. Residuals: the product, the context and LSE."""
+    return _flash_fwd_pallas(qkv, None, None, scale, causal, interpret,
+                             heads=heads)[0]
+
+
+def _flash_packed_fwd(qkv, heads, scale, causal, interpret):
+    o, lse = _flash_fwd_pallas(qkv, None, None, scale, causal, interpret,
+                               heads=heads)
+    return o, (qkv, o, lse)
+
+
+def _flash_packed_bwd(heads, scale, causal, interpret, res, ct):
+    qkv, o, lse = res
+    return (jnp.concatenate(_flash_bwd_pallas(
+        qkv, None, None, o, lse, ct, scale, causal, interpret, heads=heads),
+        axis=-1),)
+
+
+_flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
+
+
+def flash_attention_packed(qkv, heads, mesh=None, scale=None, causal=True,
+                           interpret=False):
+    """`_flash_packed` of ``qkv`` [B, L, 3 * heads * dh]; under `mesh` (a
+    'data' axis over the batch and nothing over the heads or L: the packed
+    columns cannot be sharded by head) the call is shard_mapped over the
+    batch."""
+    from jax.sharding import PartitionSpec as P
+    if scale is None:
+        scale = (qkv.shape[2] // (3 * heads)) ** -0.5
+
+    def inner(x):
+        return _flash_packed(x, int(heads), float(scale), bool(causal),
+                             bool(interpret))
+    if mesh is None or mesh.size == 1:
+        return inner(qkv)
+    spec = P(_mesh_axis(mesh, 'data', qkv.shape[0]), None, None)
+    return _shard_map(inner, mesh, (spec,), spec)(qkv)
 
 
 def flash_shapes_ok(ln):
@@ -738,19 +949,44 @@ def flash_attention_spmd(q, k, v, mesh, scale=None, causal=True,
 
 @register_op('flash_attention')
 def _flash_attention_op(ctx, op):
-    """Program-level op: inputs Q, K, V [B, H, L, dh]; attrs scale (float,
-    default dh^-0.5) and causal (bool). Under bf16 AMP the kernel's matmuls
-    run bf16 on the MXU with f32 accumulation (preferred_element_type) and
-    f32 softmax state. Under an active SPMD mesh the kernel runs per shard
-    via shard_map (ring attention when the sequence axis is sharded)."""
+    """Program-level op, two input forms. Head-major: Q, K, V [B, H, L,
+    dh] in, Out [B, H, L, dh]. Fused: QKV [B, L, 3 * H * dh], the fused
+    projection's product as `layers.fc` leaves it (columns ``[3, H, dh]``;
+    attr `num_heads`), Out the context [B, L, H * dh] as `attn.proj`'s `fc`
+    reads it -- no transpose op on either side. Attrs scale (float, default
+    dh^-0.5) and causal (bool). Under bf16 AMP the kernel's matmuls run
+    bf16 on the MXU with f32 accumulation (preferred_element_type) and f32
+    softmax state. Under an active SPMD mesh the kernel runs per shard via
+    shard_map (ring attention when the sequence axis is sharded).
+
+    The fused form lowers to the PACKED kernels (`_flash_packed`: they read
+    the product's own lane blocks, two heads of 64 or one of 128 a block)
+    where that layout applies, decided from the shapes and the mesh alone:
+    head size 64 or 128 with H * dh a multiple of 128, the kernels' tier
+    and `flash_shapes_ok(L)`, no KeyPaddingBias (the packed form stands
+    down for a bias), and under a mesh no 'seq' axis over L and no 'model'
+    axis over the heads. Anything else turns the product head-major inside
+    the lowering and takes the head-major path below;
+    `flash_attention_layout_total{layout}` says which a program got."""
     from ..core import amp
-    q = ctx.in1(op, 'Q')
-    k = ctx.in1(op, 'K')
-    v = ctx.in1(op, 'V')
-    out_dtype = q.dtype
-    q, k, v = amp.cast_compute(op, q, k, v)
+    from . import kernel_tier
+    from ..parallel.api import get_active_mesh
+    qkv = ctx.in1(op, 'QKV')
+    if qkv is not None:
+        out_dtype = qkv.dtype
+        qkv = amp.cast_compute(op, qkv)
+        heads = int(op.attr('num_heads'))
+        b, ln, dh = qkv.shape[0], qkv.shape[1], qkv.shape[2] // (3 * heads)
+        four = True
+    else:
+        q = ctx.in1(op, 'Q')
+        k = ctx.in1(op, 'K')
+        v = ctx.in1(op, 'V')
+        out_dtype = q.dtype
+        q, k, v = amp.cast_compute(op, q, k, v)
+        ln, four = q.shape[-2], q.ndim == 4
     bias = ctx.in1(op, 'KeyPaddingBias')       # optional [B, L]
-    if bias is not None and q.ndim != 4:
+    if bias is not None and not four:
         raise NotImplementedError(
             "flash_attention KeyPaddingBias needs 4-d [B, H, L, dh] Q "
             "(the bias row maps to batch via the head dim)")
@@ -760,8 +996,6 @@ def _flash_attention_op(ctx, op):
     scale = op.attr('scale', None)
     scale = None if scale is None or scale == 0.0 else float(scale)
     causal = op.attr('causal', True)
-    from . import kernel_tier
-    from ..parallel.api import get_active_mesh
     mesh = get_active_mesh()
     meshed = mesh is not None and mesh.size > 1
     # the same tier knob as every other fused unit picks the lowering
@@ -770,15 +1004,24 @@ def _flash_attention_op(ctx, op):
     # Under a mesh the kernel needs batch/head axes to shard_map over
     # (the XLA partitioner cannot split a pallas custom call), and a
     # sharded sequence axis takes the ring path, which has no kernel.
-    ring = meshed and q.ndim == 4 and \
-        _mesh_axis(mesh, 'seq', q.shape[2]) is not None
-    pallas_ok = flash_shapes_ok(q.shape[-2]) and not ring and \
-        (q.ndim == 4 or not meshed)
+    ring = meshed and four and _mesh_axis(mesh, 'seq', ln) is not None
+    pallas_ok = flash_shapes_ok(ln) and not ring and (four or not meshed)
     impl = kernel_tier.dispatch(
         'flash_attention', pallas_ok=pallas_ok, xla_ok=False, mesh=mesh,
         count=getattr(ctx, 'sparse_mode', None) != 'scout')
     use_pallas = {'pallas': True, 'interpret': 'interpret'}.get(impl, False)
-    if meshed and q.ndim == 4:
+    if qkv is not None:
+        if use_pallas and bias is None and packed_shapes_ok(heads, dh) \
+                and not (meshed
+                         and _mesh_axis(mesh, 'model', heads) is not None):
+            out = flash_attention_packed(
+                qkv, heads, mesh=mesh if meshed else None, scale=scale,
+                causal=causal, interpret=use_pallas == 'interpret')
+            ctx.out(op, 'Out', out.astype(out_dtype))
+            return
+        q, k, v = jnp.transpose(qkv.reshape(b, ln, 3, heads, dh),
+                                (2, 0, 3, 1, 4))
+    if meshed and four:
         out = flash_attention_spmd(
             q, k, v, mesh, scale=scale, causal=causal,
             use_pallas=use_pallas,
@@ -788,4 +1031,6 @@ def _flash_attention_op(ctx, op):
         out = flash_attention(q, k, v, scale=scale, causal=causal,
                               use_pallas=use_pallas,
                               key_padding_bias=bias)
+    if qkv is not None:
+        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, ln, heads * dh)
     ctx.out(op, 'Out', out.astype(out_dtype))

@@ -419,6 +419,40 @@ def test_no_weight_gradient_gemm_is_tiled_round_adams_update(one_chip,
         <= 1.1 * twin['estimated_cycles'], (with_update, twin)
 
 
+def test_no_transpose_round_the_flash_kernels_in_the_train_step(
+        one_chip, monkeypatch):
+    """ISSUE 57: the same 1-layer step at `fd355m-train-2k`'s widths. The
+    three flash kernels read the fused QKV product `bf16[4,2048,3072]` as
+    the projection's fusion leaves it and write `[4,2048,1024]` arrays as
+    `attn.proj` and the dX / dW GEMMs read them: NO array of the compiled
+    step is head-major (`[.., 16, 2048, 64]`, `[64, 2048, 64]`: the parent's
+    step held 289 mentions of one at two layers), no `[4, 2048, ..]`
+    activation is copied or transposed, and at most two fusions write an
+    array of the product's shape -- the bias-and-cast behind the QKV GEMM
+    and the ONE concatenate of dQ, dK and dV."""
+    from tools.fusioncost import lm_train_step_hlo
+    monkeypatch.setenv('PADDLE_FUSED_TIER', 'pallas')   # the chip's tier
+    text = lm_train_step_hlo(one_chip, _lm(1024, 16, 4096, 2048, 2048), 4)
+    assert not re.findall(r'\[(?:\d+,)*16,2048,64\]|\[64,2048,64\]', text)
+    entry = text[text.index('ENTRY '):].splitlines()
+    shape_of = dict(m.groups() for m in (
+        re.match(r'\s*(?:ROOT )?(%[\w.\-]+) = (\(?\w+\[[\d,]*\])', line)
+        for line in entry) if m)
+    kernels = [line for line in entry if 'tpu_custom_call' in line
+               and 'flash_attention' in line]
+    assert len(kernels) == 3, kernels
+    for line in kernels:
+        name, first = re.match(r'\s*(%[\w.\-]+) = .*? custom-call\((%[\w.\-]+)',
+                               line).groups()
+        assert shape_of[first] == 'bf16[4,2048,3072]', line
+        assert shape_of[name].lstrip('(') == 'bf16[4,2048,1024]', line
+    assert not [line for line in entry if re.search(
+        r'= (?:bf16|f32)\[4,2048,\d+\]\S* (?:copy|transpose)\(', line)]
+    wide = [line for line in entry if re.search(
+        r'= bf16\[4,2048,3072\]\S* fusion\(', line)]
+    assert len(wide) <= 2, wide
+
+
 def test_fusioncost_prints_the_table_at_toy_width(one_chip, monkeypatch,
                                                   capsys):
     """tools/fusioncost.py end to end: one JSON line a fusion that holds a

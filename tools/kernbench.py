@@ -34,15 +34,17 @@ rule's constants (PERF.md, PR 50), to be run again on another chip or
 another libtpu.
 
 The `flash_attention` case is the third of that kind: the three kernels
-of `ops/attention_ops.py` each alone (forward, dQ, dKV) and the
-`custom_vjp` whole, at the train cells' shapes with `--size bench` (`bh`
-64 / 32, L 2048, `dh` 64, bfloat16, causal; `dh` 128 beside them), a
-tiling a column -- the rule's (`flash_attention_tiling`) and the
-`bq,bk` candidates of `--tilings`: ms a call and TFLOP/s over the causal
+of `ops/attention_ops.py` each alone (forward, dQ, dKV), the
+`custom_vjp`'s kernels behind one another and the whole between the fused
+QKV product and `attn.proj`, at the train cells' shapes with `--size
+bench` (`bh` 64 / 32, L 2048, `dh` 64, bfloat16, causal; `dh` 128 beside
+them), a tiling a column PAIR -- the rule's (`flash_attention_tiling`) and
+the `bq,bk` candidates of `--tilings`, each with head-major operands and
+with the packed product (PR 57): ms a call and TFLOP/s over the causal
 FLOPs (2 matmuls forward, 5 backward, half of L x L). The installed
 JAX's `pallas.ops.tpu.flash_attention` is a yardstick column, never a
 dependency of the package. The sweep that set the rule's constants
-(PERF.md, PR 52).
+(PERF.md, PR 52) and chose the packed view's head split (PR 57).
 
 Usage: python tools/kernbench.py [--tiers off,xla,interpret]
        [--cases softmax_ce,fused_adam,embedding_gather,grouped_matmul,
@@ -346,42 +348,62 @@ def measure_grouped_matmul(size, rounds, k, tilings=(), shapes=None):
     return out
 
 
-# the train cells' attention: (batch x heads a chip, L, head size, dtype)
+# the train cells' attention: (batch x heads a chip, L, head size, dtype,
+# heads of a batch row -- how the packed column splits batch x heads)
 FLASH_SHAPES = {
-    'small': {'toy': (2, 256, 64, 'float32')},
-    'bench': {'fd355m-train-2k': (64, 2048, 64, 'bfloat16'),
-              'fd1.3b-train-4chip, a chip': (32, 2048, 64, 'bfloat16'),
-              'head size 128': (32, 2048, 128, 'bfloat16'),
-              'fd355m eval forward': (64, 2048, 64, 'float32')}}
+    'small': {'toy': (2, 256, 64, 'float32', 2)},
+    'bench': {'fd355m-train-2k': (64, 2048, 64, 'bfloat16', 16),
+              'fd1.3b-train-4chip, a chip': (32, 2048, 64, 'bfloat16', 32),
+              'head size 128': (32, 2048, 128, 'bfloat16', 8),
+              'fd355m eval forward': (64, 2048, 64, 'float32', 16)}}
 
 
 def measure_flash_attention(size, rounds, k, tilings=(), shapes=None):
-    """The three flash kernels each alone and the `custom_vjp` whole
-    (forward + both backward kernels + the delta row sum), causal, at each
-    of the cells' shapes: the rule's tiling ('rule: bq,bk / bq,bk / bq,bk'
-    for forward / dQ / dKV) and each 'bq,bk' of `tilings` given to all
-    three, and `jax.experimental.pallas.ops.tpu.flash_attention` ('jax')
-    as a yardstick. ms a call (best of `rounds` runs of ONE program that
-    makes `k` dependent calls) and TFLOP/s over the causal FLOPs: 2 x 2 x
-    L x L x dh / 2 a (batch x head) forward, 5 / 2 of it backward (dQ 3
-    matmuls of it, dKV 4: the scores are recomputed in both; each of the
-    two also pays the delta row sum, an XLA fusion over dO and O). Off the
-    chip the kernels run through the interpreter: the times mean nothing
-    there, and the yardstick has no column."""
+    """The three flash kernels each alone, the `custom_vjp`'s kernels
+    behind one another ('vjp': forward + both backward kernels + the delta
+    row sum) and the WHOLE of what a layer runs between the fused QKV
+    product and `attn.proj` ('whole': [B, L, 3 x H x dh] and dO [B, L, H x
+    dh] in, the context and the product's cotangent out), causal, at each
+    of the cells' shapes. A tiling a column pair -- the rule's ('rule: bq,bk
+    / bq,bk / bq,bk' for forward / dQ / dKV) and each 'bq,bk' of `tilings`
+    given to all three -- head-major ([BH, L, dh] operands; its 'whole'
+    pays the two transposes each way that the model paid before PR 57) and
+    'packed ...' (the kernels read the product itself; its 'whole' pays one
+    concatenate of dQ, dK, dV), so both columns time the same work. The
+    operands are ARGUMENTS of every timed program (closed over they are
+    baked into the executable: PR 52). `jax.experimental.pallas.ops.tpu.
+    flash_attention` ('jax') is a yardstick. ms a call (best of `rounds`
+    runs of ONE program that makes `k` dependent calls) and TFLOP/s over
+    the causal FLOPs: 2 x 2 x L x L x dh / 2 a (batch x head) forward, 5 /
+    2 of it backward (dQ 3 matmuls of it, dKV 4: the scores are recomputed
+    in both; each of the two also pays the delta row sum, an XLA fusion
+    over dO and O). Off the chip the kernels run through the interpreter:
+    the times mean nothing there, and the yardstick has no column."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
     from paddle_tpu.ops import attention_ops as A
     on_chip = jax.default_backend() == 'tpu'
     interpret = not on_chip
     out = {}
-    for label, (bh, ln, dh, dtype) in FLASH_SHAPES[size].items():
+    for label, (bh, ln, dh, dtype, heads) in FLASH_SHAPES[size].items():
         if shapes and label not in shapes:
             continue
         scale = dh ** -0.5
-        q, kk, v, do = (jax.random.normal(jax.random.PRNGKey(i),
-                                          (bh, ln, dh), jnp.dtype(dtype))
-                        for i in range(4))
+        b = bh // heads
+        qkv, do_p = (jax.random.normal(jax.random.PRNGKey(i), shape,
+                                       jnp.dtype(dtype))
+                     for i, shape in enumerate([(b, ln, 3 * heads * dh),
+                                                (b, ln, heads * dh)]))
+
+        def unpack(x):      # [B, L, n x H x dh] -> n of [BH, L, dh]
+            x = x.reshape(b, ln, -1, heads, dh).transpose(2, 0, 3, 1, 4)
+            return tuple(x.reshape(-1, bh, ln, dh))
+
+        def pack(*xs):      # n of [BH, L, dh] -> [B, L, n x H x dh]
+            x = jnp.stack(xs).reshape(-1, b, heads, ln, dh)
+            return x.transpose(1, 3, 0, 2, 4).reshape(b, ln, -1)
+        q, kk, v = jax.jit(unpack)(qkv)
+        do, = jax.jit(unpack)(do_p)
         matmul = 2 * bh * ln * ln * dh / 2          # one causal matmul
         row = out.setdefault('%s [%d, %d, %d] %s' % (label, bh, ln, dh,
                                                      dtype), {})
@@ -394,28 +416,55 @@ def measure_flash_attention(size, rounds, k, tilings=(), shapes=None):
             named.append((t, {kern: (bq, bk) + r[2:]
                               for kern, r in rule.items()}))
         for name, til in named:
-            def fwd(q, kk, v, til=til):
+            def fwd(q, kk, v, til=til, **kw):
                 return A._flash_fwd_pallas(q, kk, v, scale, True, interpret,
-                                           tiling=til['fwd'])
+                                           tiling=til['fwd'], **kw)
 
-            def bwd(q, kk, v, o, lse, do, til=til):
+            def bwd(q, kk, v, o, lse, do, til=til, **kw):
                 return A._flash_bwd_pallas(
                     q, kk, v, o, lse, do, scale, True, interpret,
-                    tiling_dq=til['bwd_dq'], tiling_dkv=til['bwd_dkv'])
-            try:
-                o, lse = jax.jit(fwd)(q, kk, v)
-            except Exception as e:      # noqa: BLE001 -- advisory tool
-                row[name] = {'error': '%s: %s' % (type(e).__name__,
-                                                  str(e)[:200])}
-                continue
-            # XLA drops the kernel whose results a program does not return
-            row[name] = _time_flash({
-                'fwd': (2, lambda q, kk, v, *_: fwd(q, kk, v)[0]),
-                'bwd_dq': (3, lambda *a: bwd(*a)[0]),
-                'bwd_dkv': (4, lambda *a: bwd(*a)[1:]),
-                'vjp': (7, lambda q, kk, v, o, lse, do: bwd(
-                    q, kk, v, *fwd(q, kk, v), do))},
-                (q, kk, v, o, lse, do), k, rounds, matmul)
+                    tiling_dq=til['bwd_dq'], tiling_dkv=til['bwd_dkv'], **kw)
+
+            def whole(qkv, do_p):
+                q, kk, v = unpack(qkv)
+                o, lse = fwd(q, kk, v)
+                return pack(o), pack(*bwd(q, kk, v, o, lse,
+                                          *unpack(do_p)))
+
+            def fwd_p(x):
+                return fwd(x, None, None, heads=heads)
+
+            def bwd_p(x, o, lse, do):
+                return bwd(x, None, None, o, lse, do, heads=heads)
+
+            def whole_p(x, do):
+                o, lse = fwd_p(x)
+                return o, jnp.concatenate(bwd_p(x, o, lse, do), axis=-1)
+            # a view a column: (its name, the kernels' operands ahead of
+            # (o, lse, dO), its forward, backward and whole, its dO)
+            views = [(name, (q, kk, v), fwd, bwd, whole, do)]
+            if dh in (64, 128):
+                views.append(('packed ' + name, (qkv,), fwd_p, bwd_p,
+                              whole_p, do_p))
+            for col, x, f, b_, w, d in views:
+                n = len(x)
+                try:
+                    o, lse = jax.jit(f)(*x)
+                except Exception as e:      # noqa: BLE001 -- advisory tool
+                    row[col] = {'error': '%s: %s' % (type(e).__name__,
+                                                     str(e)[:200])}
+                    continue
+                # XLA drops the kernel whose results a program does not
+                # return
+                row[col] = _time_flash({
+                    'fwd': (2, lambda *a, f=f, n=n: f(*a[:n])[0]),
+                    'bwd_dq': (3, lambda *a, b_=b_: b_(*a)[0]),
+                    'bwd_dkv': (4, lambda *a, b_=b_: b_(*a)[1:]),
+                    'vjp': (7, lambda *a, f=f, b_=b_, n=n: b_(
+                        *a[:n], *f(*a[:n]), a[-1]))},
+                    x + (o, lse, d), k, rounds, matmul)
+                row[col].update(_time_flash(
+                    {'whole': (7, w)}, (qkv, do_p), k, rounds, matmul))
         if on_chip:
             from jax.experimental.pallas.ops.tpu import flash_attention as U
             blocks = U.BlockSizes(
@@ -439,17 +488,22 @@ def measure_flash_attention(size, rounds, k, tilings=(), shapes=None):
 def _time_flash(kernels, operands, k, rounds, matmul):
     """{kernel: {ms, tflops}} of `kernels` {name: (causal matmuls, fn(q, k,
     v, o, lse, do))}: each the best of `rounds` runs of one program of `k`
-    dependent calls (the next call's q waits for a bit of this call's
-    result)."""
+    dependent calls (the next call's first operand waits for a bit of this
+    call's result)."""
     import jax
     from jax import lax
     out = {}
     for kern, (n_mm, fn) in kernels.items():
         def calls(q, *rest, fn=fn):
-            def body(i, acc):
+            # q rides the loop so that the bit lands in place: a pass over
+            # the operand would be timed with the kernel (the packed
+            # product is three times q)
+            def body(i, carry):
+                q, acc = carry
                 bit = jax.tree_util.tree_leaves(acc)[0][0, 0, 0]
-                return fn(q + (bit != bit).astype(q.dtype), *rest)
-            return lax.fori_loop(0, k, body, fn(q, *rest))
+                q = q.at[0, 0, 0].add((bit != bit).astype(q.dtype))
+                return q, fn(q, *rest)
+            return lax.fori_loop(0, k, body, (q, fn(q, *rest)))[1]
         try:
             loop = jax.jit(calls)
             jax.block_until_ready(loop(*operands))
