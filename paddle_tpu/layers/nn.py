@@ -938,13 +938,15 @@ def ssd_prefill(xbc, z, dt, state, tail, rows, positions, length, layer,
 
 
 def _gdn(op_type, qkv, z, b, a, state, tail, rows, layer, prefix, key_heads,
-         kernel, epsilon, chunk=None, positions=None, length=None):
+         kernel, epsilon, chunk=None, positions=None, length=None,
+         allow_neg_eigval=False):
     """A Gated DeltaNet mixer's op (ops/gdn_ops.py) with the layer's inner
     parameters under ``prefix``. Their defaults are the family's own (HF
     `Qwen3NextGatedDeltaNet.__init__`): ``A_log`` the log of 1 .. 16 evenly
     over the value heads (published: a uniform draw in (0, 16)), the step's
     bias 1, the output norm's one weight ``[dv]`` 1, the taps without a
-    bias."""
+    bias. ``allow_neg_eigval``: the write strength is ``2 sigmoid(b)``, in
+    (0, 2) (the attribute is absent where unset)."""
     helper = LayerHelper(op_type)
     heads = int(b.shape[-1])
 
@@ -969,6 +971,8 @@ def _gdn(op_type, qkv, z, b, a, state, tail, rows, layer, prefix, key_heads,
     if positions is not None:
         inputs.update({'Positions': [positions], 'Length': [length]})
         attrs['chunk'] = int(chunk)
+    if allow_neg_eigval:
+        attrs['allow_neg_eigval'] = True
     helper.append_op(type=op_type, inputs=inputs,
                      outputs={'Out': [out], 'StateOut': [state],
                               'TailOut': [tail]}, attrs=attrs)
@@ -976,7 +980,7 @@ def _gdn(op_type, qkv, z, b, a, state, tail, rows, layer, prefix, key_heads,
 
 
 def gdn_decode(qkv, z, b, a, state, tail, rows, layer, prefix, key_heads,
-               kernel, epsilon=1e-6):
+               kernel, epsilon=1e-6, allow_neg_eigval=False):
     """One step of a Gated DeltaNet layer for every slot's one row: ``qkv
     [S, 2 Hk dk + Hv dv]`` and ``z [S, Hv dv]`` (the parts of the mixer's
     wide input projection), ``b`` / ``a`` ``[S, Hv]`` (its narrow one: the
@@ -985,11 +989,13 @@ def gdn_decode(qkv, z, b, a, state, tail, rows, layer, prefix, key_heads,
     none), ``layer`` the layer's ordinal in them (ops/gdn_ops.py). Returns
     the normed, gated ``[S, Hv dv]``, the output projection's input."""
     return _gdn('gdn_decode', qkv, z, b, a, state, tail, rows, layer, prefix,
-                key_heads, kernel, epsilon)
+                key_heads, kernel, epsilon,
+                allow_neg_eigval=allow_neg_eigval)
 
 
 def gdn_prefill(qkv, z, b, a, state, tail, rows, positions, length, layer,
-                prefix, key_heads, kernel, chunk, epsilon=1e-6):
+                prefix, key_heads, kernel, chunk, epsilon=1e-6,
+                allow_neg_eigval=False):
     """`gdn_decode` for one prompt suffix (``[1, T, ...]``) that starts at
     ``positions[0]``: from zeros there, else from the row as the chunk
     before left it, over the ``length`` real rows, in blocks of ``chunk``
@@ -997,7 +1003,8 @@ def gdn_prefill(qkv, z, b, a, state, tail, rows, positions, length, layer,
     dv]``."""
     return _gdn('gdn_prefill', qkv, z, b, a, state, tail, rows, layer,
                 prefix, key_heads, kernel, epsilon, chunk=chunk,
-                positions=positions, length=length)
+                positions=positions, length=length,
+                allow_neg_eigval=allow_neg_eigval)
 
 
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,

@@ -86,10 +86,11 @@ class LMConfig(object):
       gate, then an RMSNorm a group; ``W_out``. Prompts are scanned in
       blocks of ``ssm_chunk`` rows. Its state and tail are two more pools
       a row a slot (`SSD_STATE`, `SSD_TAIL`);
-    - ``layer_types`` ``'gdn'``: a Gated DeltaNet mixer (Qwen3-Next's
-      linear-attention layers; ops/gdn_ops.py): ``[q | k | v | z] = h
-      W_in`` and ``[b | a] = h W_ba``; a causal depthwise convolution of
-      ``ssm_conv`` taps, no bias, and a SiLU over all of ``[q | k | v]``;
+    - ``layer_types`` ``'gdn'``: a Gated DeltaNet mixer (Qwen3-Next's and
+      Olmo-Hybrid's linear-attention layers; ops/gdn_ops.py): ``[q | k | v
+      | z] = h W_in`` and ``[b | a] = h W_ba``; a causal depthwise
+      convolution of ``ssm_conv`` taps, no bias, and a SiLU over all of
+      ``[q | k | v]``;
       ``gdn_key_heads`` heads of ``gdn_key_dim`` for q and k, l2-normed,
       ``gdn_value_heads`` heads of ``gdn_value_dim`` for v (value head ``h``
       reads key head ``h // (value heads / key heads)``); the delta rule
@@ -97,7 +98,16 @@ class LMConfig(object):
       value dim]`` state a value head; an RMSNorm over each head's values,
       then the gate ``silu(z)``; ``W_out``. Prompts run the chunked form in
       blocks of ``gdn_chunk`` rows. Its state and tail are two more pools a
-      row a slot (`GDN_STATE`, `GDN_TAIL`);
+      row a slot (`GDN_STATE`, `GDN_TAIL`). ``gdn_allow_neg_eigval``: the
+      write strength is ``2 sigmoid(b)``, in (0, 2), so the state's
+      transition may have an eigenvalue in (-1, 0) (Olmo-Hybrid's
+      ``linear_allow_neg_eigval``);
+    - ``norm_placement='post'``: the block's norms sit on each sublayer's
+      OUTPUT and the sublayer reads the stream un-normed, ``h = x +
+      norm_1(mixer(x))``, ``y = h + norm_2(ffn(h))`` (the Olmo 2 / Olmo 3
+      line's "reordered norm", arXiv:2501.00656; RMSNorm only; the final
+      norm stays on the stream); the default ``'pre'`` is ``x +
+      f(norm(x))``;
     - ``attention_gate``: an attention layer's q projection is twice as
       wide -- head ``h`` owns columns ``2 h head_dim ..``: its q, then its
       gate -- and the attention's output is multiplied by ``sigmoid(gate)``
@@ -174,7 +184,8 @@ class LMConfig(object):
                  gdn_key_heads=0, gdn_value_heads=0, gdn_key_dim=0,
                  gdn_value_dim=0, gdn_chunk=64, attention_gate=False,
                  rotary_dim=None, norm_zero_centred=False,
-                 shared_expert_gate=False):
+                 shared_expert_gate=False, norm_placement='pre',
+                 gdn_allow_neg_eigval=False):
         self.vocab_size = vocab_size
         self.seq_len = seq_len
         self.d_model = d_model
@@ -197,7 +208,8 @@ class LMConfig(object):
                 ('attention', attention, ('mha', 'mla')),
                 ('qk_norm', qk_norm, (False, True, 'head')),
                 ('expert_form', expert_form, ('gated', 'relu2')),
-                ('matmul_precision', matmul_precision, (None, 'highest'))):
+                ('matmul_precision', matmul_precision, (None, 'highest')),
+                ('norm_placement', norm_placement, ('pre', 'post'))):
             if value not in known:
                 raise ValueError('LMConfig.%s=%r: expected one of %r'
                                  % (field, value, known))
@@ -258,6 +270,11 @@ class LMConfig(object):
         self.rotary_dim = None if rotary_dim is None else int(rotary_dim)
         self.norm_zero_centred = bool(norm_zero_centred)
         self.shared_expert_gate = bool(shared_expert_gate)
+        self.norm_placement = norm_placement
+        self.gdn_allow_neg_eigval = bool(gdn_allow_neg_eigval)
+        if norm_placement == 'post' and norm != 'rms_norm':
+            raise ValueError("LMConfig.norm_placement='post' is built with "
+                             "norm='rms_norm' only, got %r" % (norm,))
         if set(self.attention_rope) - set(ROPE_KEYS):
             raise ValueError('LMConfig.attention_rope=%r: expected keys of '
                              '%r' % (attention_rope, ROPE_KEYS))
@@ -455,7 +472,8 @@ def _require_classic_block(cfg, who):
         ('layer_types', ('attention',) * cfg.n_layer),
         ('tie_embeddings', False), ('matmul_precision', None),
         ('attention_gate', False), ('rotary_dim', None),
-        ('norm_zero_centred', False), ('shared_expert_gate', False))
+        ('norm_zero_centred', False), ('shared_expert_gate', False),
+        ('norm_placement', 'pre'), ('gdn_allow_neg_eigval', False))
     for field, value in classic:
         if getattr(cfg, field) != value:
             raise ValueError(
@@ -554,17 +572,33 @@ def _entry_ln(x, residual, bna, name):
         bias_attr=ParamAttr(name=name + '.b'))
 
 
-def _norm(cfg, x, residual, bna, name):
+def _norm(cfg, x, residual, bna, name, final=False):
     """The block's norm at a residual-stream read point, of whichever
     kind `cfg.norm` says: (normed, resolved_stream), the pending
-    ``residual`` (or None) added first. LayerNorm: `_entry_ln`."""
+    ``residual`` (or None) added first. LayerNorm: `_entry_ln`. With
+    ``norm_placement='post'`` a sublayer reads the resolved stream as it
+    is -- its norm is on its output (`_out_norm`) -- and only the ``final``
+    norm is one."""
     if cfg.norm == 'layer_norm':
         return _entry_ln(x, residual, bna, name)
     if residual is not None:
         x = layers.elementwise_add(x, residual)
+    if cfg.norm_placement == 'post' and not final:
+        return x, x
     return layers.rms_norm(x, begin_norm_axis=bna, epsilon=cfg.rms_eps,
                            param_attr=ParamAttr(name=name + '.w'),
                            zero_centred=cfg.norm_zero_centred), x
+
+
+def _out_norm(cfg, delta, bna, name):
+    """A sublayer's output as it joins the stream: normed by the weight
+    that `_norm` left unused with ``norm_placement='post'``, else as it
+    is."""
+    if cfg.norm_placement != 'post':
+        return delta
+    return layers.rms_norm(delta, begin_norm_axis=bna, epsilon=cfg.rms_eps,
+                           param_attr=ParamAttr(name=name + '.w'),
+                           zero_centred=cfg.norm_zero_centred)
 
 
 def _bias(cfg, name):
@@ -772,7 +806,8 @@ def _ssd_mixer(cfg, ln1, p, nth, ssd, num_flatten_dims):
 
 
 def _gdn_mixer(cfg, ln1, p, nth, gdn, num_flatten_dims):
-    """Qwen3-Next's Gated DeltaNet mixer on the normed input: ``[q | k | v
+    """The Gated DeltaNet mixer on the block's input (normed, or with
+    ``norm_placement='post'`` the stream): ``[q | k | v
     | z] = h W_in`` and ``[b | a] = h W_ba``, the program's cache op on the
     ``nth`` such layer's rows (``gdn(qkv, z, b, a, prefix, nth)``:
     layers.gdn_decode / gdn_prefill -- the convolution, the norms of q and
@@ -1117,6 +1152,16 @@ def window_pool_blocks(cfg, slots, block_size, shared=False):
     return rings + 1 + (rings // 2 if shared else 0)
 
 
+def snapshot_rows(slots, shared=False):
+    """Spare rows of the 'row' pools behind the slots' own: the SNAPSHOT
+    rows that an engine which shares prefixes keeps a recurrent state in at
+    a block's edge (serving/kv_blocks.py `SlotRows`), one a slot -- every
+    resident can have come from a document of its own, or a few documents
+    keep a row a prefill chunk each, before the cache gives one up; none
+    where nothing is shared."""
+    return int(slots) if shared else 0
+
+
 # A pool of a model's programs, one row of `cache_pools`' table. `index`:
 # what indexes its leading dimension -- 'block', the allocator's block ids;
 # 'ring', a slot's ring of blocks; 'row', a slot's row (`INDEX_FEEDS`: the
@@ -1131,9 +1176,13 @@ def window_pool_blocks(cfg, slots, block_size, shared=False):
 # 'hit': the admissions that resumed at a shared prefix's edge over it.
 # `reach`: the rows behind a position that a query there still reads of the
 # pool (None: every row, or a state) -- what a request that resumes at a
-# shared prefix's edge has to find; `shares`: it can (`prefix_sharing`).
+# shared prefix's edge has to find. Every pool can be resumed so
+# (`prefix_sharing`): a 'block' pool by the shared blocks themselves, a
+# 'ring' pool by the blocks the prefix cache keeps of it, a 'row' pool by a
+# SNAPSHOT row, a copy of the slot's row of every 'row' pool taken where a
+# prefill dispatch ended on a block's edge (`snapshot_rows`).
 Pool = collections.namedtuple(
-    'Pool', 'name shape index rewinds copies why books reach shares')
+    'Pool', 'name shape index rewinds copies why books reach')
 INDEX_FEEDS = {'block': 'gen_btab', 'ring': 'gen_wtab', 'row': 'gen_srow'}
 
 
@@ -1143,7 +1192,7 @@ def cache_pools(cfg, num_blocks=0, block_size=1, slots=None, shared=False):
     of a kind (serving/generate.py walks it). A pool the slots size has
     shape None where ``slots`` is not given. `shared`: the engine shares
     prefixes, and a pool whose blocks its prefix cache holds beside the
-    slots' has room for them (`window_pool_blocks`).
+    slots' has room for them (`window_pool_blocks`, `snapshot_rows`).
 
     Indexed by the block allocator's ids: K and V apart, the GLOBAL
     attention layers' pages, or with latent attention the ONE pool of
@@ -1165,11 +1214,12 @@ def cache_pools(cfg, num_blocks=0, block_size=1, slots=None, shared=False):
         sized = index == 'block' or slots is not None
         for i, (name, shape) in enumerate(shapes):
             pools.append(Pool(name, shape if sized else None, index, rewinds,
-                              copies, why, {} if i else books, reach,
-                              index != 'row'))
+                              copies, why, {} if i else books, reach))
 
     latent = cfg.attention == 'mla'
     n = slots or 0
+    # a 'row' pool's rows: the trash row, a row a slot, the snapshot rows
+    rows = n + 1 + snapshot_rows(n, shared)
     kv = (num_blocks, cfg.n_attn_layers, block_size, cfg.kv_width)
     kind([(KV_CACHE_K, kv)] + [(KV_CACHE_V, kv)] * (not latent), 'block',
          True, True, step=('kv_latent_tokens_read_total' if latent
@@ -1196,37 +1246,40 @@ def cache_pools(cfg, num_blocks=0, block_size=1, slots=None, shared=False):
              step=('kv_window_tokens_read_total', cfg.sliding_window),
              hit='kv_window_prefix_resumes_total')
     if cfg.n_ssm_layers:
-        kind([(SSM_STATE, (n + 1, cfg.n_ssm_layers, cfg.ssm_state,
+        kind([(SSM_STATE, (rows, cfg.n_ssm_layers, cfg.ssm_state,
                            cfg.ssm_inner)),
-              (SSM_TAIL, (n + 1, cfg.n_ssm_layers, ssm_ops.TAIL_ROWS,
+              (SSM_TAIL, (rows, cfg.n_ssm_layers, ssm_ops.TAIL_ROWS,
                           cfg.ssm_inner))], 'row', False, False,
              "a state-space layer's state is a row a slot, the recurrence "
-             "up to the slot's last position -- a shared block has no state "
-             "to resume from, and a rejected draft cannot be unwound from "
-             "it", step=('ssm_state_rows_updated_total', 1),
+             "up to the slot's last position -- a rejected draft cannot be "
+             "unwound from it (a shared prefix is resumed from a snapshot "
+             "row, taken at a block's edge)", reach=1,
+             step=('ssm_state_rows_updated_total', 1),
              prefill='ssm_prefill_rows_total',
              resume='ssm_state_resumes_total')
     if cfg.n_ssd_layers:
-        kind([(SSD_STATE, (n + 1, cfg.n_ssd_layers, cfg.ssm_state,
+        kind([(SSD_STATE, (rows, cfg.n_ssd_layers, cfg.ssm_state,
                            cfg.ssd_inner)),
-              (SSD_TAIL, (n + 1, cfg.n_ssd_layers, ssm_ops.TAIL_ROWS,
+              (SSD_TAIL, (rows, cfg.n_ssd_layers, ssm_ops.TAIL_ROWS,
                           cfg.ssd_conv_width))], 'row', False, False,
              "a Mamba-2 layer's state is a row a slot, every head's matrix "
-             "as of the slot's last position -- a shared block has no state "
-             "to resume from, and a rejected draft cannot be unwound from "
-             "it", step=('ssd_state_rows_updated_total', 1),
+             "as of the slot's last position -- a rejected draft cannot be "
+             "unwound from it (a shared prefix is resumed from a snapshot "
+             "row, taken at a block's edge)", reach=1,
+             step=('ssd_state_rows_updated_total', 1),
              prefill='ssd_prefill_rows_total',
              resume='ssd_state_resumes_total')
     if cfg.n_gdn_layers:
-        kind([(GDN_STATE, (n + 1, cfg.n_gdn_layers, cfg.gdn_key_dim,
+        kind([(GDN_STATE, (rows, cfg.n_gdn_layers, cfg.gdn_key_dim,
                            cfg.gdn_inner)),
-              (GDN_TAIL, (n + 1, cfg.n_gdn_layers, ssm_ops.TAIL_ROWS,
+              (GDN_TAIL, (rows, cfg.n_gdn_layers, ssm_ops.TAIL_ROWS,
                           cfg.gdn_conv_width))], 'row', False, False,
              "a Gated DeltaNet layer's state is a row a slot, every value "
              "head's keys-by-values matrix as of the slot's last position "
-             "-- a shared block has no state to resume from, and a rejected "
-             "draft cannot be unwound from it (the delta rule's correction "
-             "is not undone by masking rows)",
+             "-- a rejected draft cannot be unwound from it: the delta "
+             "rule's correction is not undone by masking rows (a shared "
+             "prefix is resumed from a snapshot row, taken at a block's "
+             "edge)", reach=1,
              step=('gdn_state_rows_updated_total', 1),
              prefill='gdn_prefill_rows_total',
              resume='gdn_state_resumes_total')
@@ -1352,15 +1405,17 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
                     size=cfg.d_model,
                     param_attr=ParamAttr(name=p + '.attn.proj.w'),
                     bias_attr=_bias(cfg, p + '.attn.proj.b'))
+            delta = _out_norm(cfg, delta, 1, p + '.ln1')
         if cfg.has_ffn(i):
             ln2, x = _norm(cfg, x, delta, 1, p + '.ln2')
             delta, routed = _ffn(cfg, ln2, p, 1, valid=valid, layer=i)
+            delta = _out_norm(cfg, delta, 1, p + '.ln2')
             if routed is not None:
                 routing.append(routed)
 
     if not head:
         return None
-    x, _ = _norm(cfg, x, delta, 1, 'final_ln')
+    x, _ = _norm(cfg, x, delta, 1, 'final_ln', final=True)
     return _lm_head(cfg, x)                                  # [S, V]
 
 
@@ -1424,7 +1479,7 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks,
         return layers.gdn_decode(
             qkv, z, b, a, pools[GDN_STATE], pools[GDN_TAIL], feeds['row'],
             layer, prefix, cfg.gdn_key_heads, cfg.ssm_conv,
-            epsilon=cfg.rms_eps)
+            epsilon=cfg.rms_eps, allow_neg_eigval=cfg.gdn_allow_neg_eigval)
 
     def cache_write(k, v, layer, kind):
         # a window layer writes into its slot's ring: the table's column
@@ -1758,7 +1813,8 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
         return layers.gdn_prefill(
             qkv, z, b, a, pools[GDN_STATE], pools[GDN_TAIL], feeds['row'],
             pos, length, layer, prefix, cfg.gdn_key_heads, cfg.ssm_conv,
-            cfg.gdn_chunk, epsilon=cfg.rms_eps)
+            cfg.gdn_chunk, epsilon=cfg.rms_eps,
+            allow_neg_eigval=cfg.gdn_allow_neg_eigval)
 
     def attention(ln1, p, nth, layer):
         """An attention layer's mixer: q, k, v, the cache writes, the
@@ -1810,13 +1866,15 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
             ln1, x = _norm(cfg, x, delta, 2, p + '.ln1')
             delta = _MIXERS[kind](cfg, ln1, p, nth, mixers[kind], 2) \
                 if kind in _MIXERS else attention(ln1, p, nth, i)
+            delta = _out_norm(cfg, delta, 2, p + '.ln1')
         if cfg.has_ffn(i):
             ln2, x = _norm(cfg, x, delta, 2, p + '.ln2')
             delta, routed = _ffn(cfg, ln2, p, 2, length=length, layer=i)
+            delta = _out_norm(cfg, delta, 2, p + '.ln2')
             if routed is not None:
                 routing.append(routed)
 
-    x, _ = _norm(cfg, x, delta, 2, 'final_ln')
+    x, _ = _norm(cfg, x, delta, 2, 'final_ln', final=True)
     x_flat = layers.reshape(x, shape=[-1, d])                # [T, d]
     one = layers.fill_constant(shape=[1], dtype='int64', value=1)
     last = layers.gather(x_flat, layers.elementwise_sub(length, one))

@@ -1,6 +1,6 @@
 """The Gated DeltaNet mixer's recurrence and its state, a row a slot
-(Qwen3-Next's linear-attention layers: models/transformer.py,
-LMConfig(layer_types=...) ``'gdn'``).
+(the linear-attention layers of Qwen3-Next and of Olmo-Hybrid: models/
+transformer.py, LMConfig(layer_types=...) ``'gdn'``).
 
 Between the mixer's projections (``[q | k | v | z] = h W_in``, ``[b | a] =
 h W_ba`` and ``out = o W_out``, ordinary `fc`s in models/transformer.py) a
@@ -11,7 +11,8 @@ Gated DeltaNet layer is (arXiv:2412.06464; HF `modeling_qwen3_next.py`
     [q | k | v] = silu(conv([q | k | v]))   causal depthwise, K taps, no
                                             bias, over all 2 Hk dk + Hv dv
     q = q / |q| / sqrt(dk)    k = k / |k|   eps 1e-6 inside the root
-    beta = sigmoid(b)   [Hv]
+    beta = sigmoid(b)   [Hv]                (0, 1); with the ops' attribute
+                                            `allow_neg_eigval` 2 sigmoid(b)
     g = -exp(A_log) softplus(a + dt_bias)   [Hv], float32
     S_t[h] = e^g S_{t-1}[h]                 S [dk, dv], keys x values
     u = beta (v - S^T k)                    the delta rule: what k already
@@ -25,7 +26,8 @@ block (ops/ssd_ops.py) has no such term, and its chunked form does not
 carry over.
 
 What a token leaves behind is ``S`` after it, ``Hv x dk x dv`` numbers a
-layer (2 MB in Qwen3-Next), and the convolution's last ``K - 1`` inputs.
+layer (2 MB at 32 heads of 128 x 128, 2.2 MB at 30 of 96 x 192), and the
+convolution's last ``K - 1`` inputs.
 Both live A ROW A SLOT in two pools of their own (models/transformer.py
 `GDN_STATE` ``[slots + 1, gdn layers, dk, Hv dv]`` and `GDN_TAIL` ``[slots
 + 1, gdn layers, 8, 2 Hk dk + Hv dv]``; row 0 is the trash row), addressed
@@ -47,7 +49,14 @@ the two read-outs ``S^T k`` and ``S^T q`` are sums over sublanes.
   read against ``k``, then corrected by ``k u^T`` and read against ``q``.
   ``e^g`` and ``beta`` are scalars a head, computed outside on ``[S, Hv]``
   and handed over as lane vectors. A row fed 0 reads zeros and writes the
-  trash row.
+  trash row. HEADS WHOSE VALUES ARE NO WHOLE VREGS (192: a vreg and a
+  half) are walked a RUN at a time -- the fewest heads whose values side
+  by side are whole vregs, two of 192 = three vregs -- so every load and
+  store of the state stays lane-aligned; what the run's heads do not share,
+  their key heads' ``k`` and ``q`` columns, is put over each head's lanes
+  by one select a further head (the middle vreg of three takes half of
+  each), two selects a vreg on top of the walk's eight operations: the
+  kernel moves its strip HBM -> VMEM -> HBM all the same.
 - ``gdn_prefill``: one prompt suffix or chunk of ``T`` rows from position
   ``off = Positions[0]`` on, THE CHUNKED FORM (the WY representation of
   arXiv:2406.06484 with the decay of arXiv:2412.06464), not the recurrence
@@ -71,7 +80,10 @@ the two read-outs ``S^T k`` and ``S^T q`` are sums over sublanes.
   reaches ``(I + A)^-1 R`` in ``C/16 - 1`` rounds of one matmul. Every sum
   over positions or keys is a matmul on the MXU at `Precision.HIGHEST`.
   History: zeros if ``off == 0`` -- whatever the row's last tenant left is
-  never read -- else the row as an earlier chunk left it. A PAD ROW'S ``g``
+  never read -- else the row as an earlier chunk left it (or as a snapshot
+  row copied into it did: serving/kv_blocks.py `SlotRows`). Heads of whole
+  vregs are read where the projections left them; others are laid a head
+  first around the kernel (`prefill_chunks`). A PAD ROW'S ``g``
   AND ``beta`` ARE SET TO 0: its decay is 1 and its ``u`` 0, the identity
   on the state; the state and the tail are written as of the last real
   row.
@@ -84,6 +96,7 @@ einsums round `solve_triangular`. Everything of both ops lies under the
 named scope ``paddle_tpu:gdn_chunk``.
 """
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -108,26 +121,36 @@ _L2_EPS = 1e-6
 
 def shapes_ok(key_dim, value_dim, key_heads, value_heads, rows=None,
               chunk=None):
-    """The kernels' tiling rule: a head's values are whole vregs of lanes,
-    its keys whole sublane tiles, the value heads whole groups a key head;
-    for the prefill a head's keys whole vregs too (or one key head), and
-    the prompt whole chunks of whole diagonal blocks."""
-    ok = value_dim % _LANES == 0 and key_dim % 8 == 0 \
-        and value_heads % key_heads == 0
+    """The kernels' tiling rule: a head's keys are whole sublane tiles, the
+    value heads whole groups a key head, and the value heads come in whole
+    RUNS whose values side by side are whole vregs of lanes (`_heads_a_run`:
+    one head of 128 or 256 values, two of 192, eight of 48) -- the decode
+    update walks a run at a time; for the prefill the prompt is whole chunks
+    of whole diagonal blocks (its heads may be any width: `prefill_chunks`
+    lays heads that are no whole vregs a head first)."""
+    ok = key_dim % 8 == 0 and value_dim % 8 == 0 \
+        and value_heads % key_heads == 0 \
+        and value_heads % _heads_a_run(value_dim) == 0
     if rows is not None:
-        ok = ok and rows % chunk == 0 and chunk % _DIAG == 0 \
-            and (key_dim % _LANES == 0 or key_heads == 1)
+        ok = ok and rows % chunk == 0 and chunk % _DIAG == 0
     return ok
+
+
+def _heads_a_run(value_dim):
+    """The fewest value heads whose values side by side are whole vregs of
+    lanes."""
+    return math.lcm(value_dim, _LANES) // value_dim
 
 
 def _heads_a_strip(key_dim, value_dim, value_heads, rep):
     """Value heads a step of the decode grid holds: whole groups of `rep`
-    (the heads of one key head), the most that keep its block of the state
-    within `_STRIP_BYTES`."""
+    (the heads of one key head) and whole runs (`_heads_a_run`), the most
+    that keep its block of the state within `_STRIP_BYTES`."""
     head = key_dim * value_dim * 4
-    return max(h for h in range(rep, value_heads + 1, rep)
+    unit = math.lcm(rep, _heads_a_run(value_dim))
+    return max(h for h in range(unit, value_heads + 1, unit)
                if value_heads % h == 0
-               and (h == rep or h * head <= _STRIP_BYTES))
+               and (h == unit or h * head <= _STRIP_BYTES))
 
 
 # ---------------------------------------------------------------------------
@@ -140,11 +163,24 @@ def _decode_update_kernel(rows_ref, layer_ref, decay_ref, beta_ref, v_ref,
     del layer_ref
     per = decay_ref.shape[2] // size            # value heads of the strip
     rep = per // k_ref.shape[3]                 # value heads a key head
+    run = _heads_a_run(size)                    # heads walked together
+    dk = k_ref.shape[2]
     live = rows_ref[pl.program_id(0)] > 0
-    for j in range(per):
-        at = pl.ds(j * size, size)
-        kc = k_ref[0, 0, :, j // rep:j // rep + 1]              # [dk, 1]
-        qc = q_ref[0, 0, :, j // rep:j // rep + 1]
+    lane = lax.broadcasted_iota(jnp.int32, (dk, run * size), 1)
+
+    def columns(ref, j):
+        """The run's key heads' vectors, each over its heads' lanes: ONE
+        column ``[dk, 1]`` where the run has one key head (it broadcasts),
+        else ``[dk, run x size]`` by a select a further key head."""
+        col = ref[0, 0, :, j // rep:j // rep + 1]
+        for i in range(1, run):
+            if (j + i) // rep != (j + i - 1) // rep:
+                col = jnp.where(lane >= i * size, ref[
+                    0, 0, :, (j + i) // rep:(j + i) // rep + 1], col)
+        return col
+    for j in range(0, per, run):
+        at = pl.ds(j * size, run * size)
+        kc, qc = columns(k_ref, j), columns(q_ref, j)   # [dk, 1 | lanes]
         # first pass: the decayed state, read against k
         s = jnp.where(live, s_ref[0, 0, :, at], 0.0) * decay_ref[0, :, at]
         u = beta_ref[0, :, at] * (
@@ -224,7 +260,8 @@ def _decode_update_xla(state, rows, layer, decay, beta, v, q, k, *,
 
 
 def _prefill_chunk_kernel(q_ref, k_ref, kt_ref, v_ref, gx_ref, bx_ref,
-                          rt_ref, s0_ref, o_ref, last_ref, s_scr, xd_scr):
+                          rt_ref, s0_ref, o_ref, last_ref, s_scr, xd_scr, *,
+                          joint=True):
     import jax.experimental.pallas as pl
     C, dv = v_ref.shape
     blocks = C // _DIAG
@@ -264,12 +301,17 @@ def _prefill_chunk_kernel(q_ref, k_ref, kt_ref, v_ref, gx_ref, bx_ref,
     # the blocks under the diagonal: N = Xd A_off is nilpotent over the
     # blocks, so Y = Xd R - N Y is exact after blocks - 1 rounds
     n = _dot(xd, jnp.where(own, 0.0, a))
-    p = _dot(xd, jnp.concatenate([bcol * v, (bcol * jnp.exp(gcol)) * k],
-                                 axis=1))
-    y = p
-    for _ in range(blocks - 1):
-        y = p - _dot(n, y)
-    vp = y[:, :dv] - _dot(y[:, dv:], s)                     # the rows' u
+    # U's and W's right-hand sides: side by side through one solve where
+    # both are whole vregs of lanes (`joint`), else one after the other
+    rhs = [bcol * v, (bcol * jnp.exp(gcol)) * k]
+    ys = []
+    for r in [jnp.concatenate(rhs, axis=1)] if joint else rhs:
+        y = p = _dot(xd, r)
+        for _ in range(blocks - 1):
+            y = p - _dot(n, y)
+        ys.append(y)
+    u, w = (ys[0][:, :dv], ys[0][:, dv:]) if joint else ys
+    vp = u - _dot(w, s)                                     # the rows' u
     o_ref[...] = _dot(q * jnp.exp(gcol), s) + _dot(
         jnp.where(ri >= ci, d_lo * _dot(q, kt), 0.0), vp)
     # e^gamma_C over the state's lanes, and D's last row: e^(gamma_C -
@@ -296,18 +338,43 @@ def prefill_chunks(q, k, v, g, beta, s0, *, chunk, interpret=False):
     dk]`` (normed, q scaled), ``v [T, Hv, dv]``, ``g`` / ``beta`` ``[T,
     Hv]`` (a pad row's both 0). Returns (``o [T, Hv dv]``, the state after
     the last row). The grid is (value heads, blocks of rows): a head's
-    ``[dk, dv]`` state stays in VMEM between its blocks."""
+    ``[dk, dv]`` state stays in VMEM between its blocks. Heads whose keys
+    and values are whole vregs of lanes are read where the projections left
+    them, a head a block of lanes; any other width (96 keys by 192 values)
+    is laid A HEAD FIRST around the call -- ``[heads, T, width]``, a block
+    the head's whole rows -- which costs a transpose of the chunk's q, k, v
+    and o (``T x (2 Hk dk + 2 Hv dv)`` numbers, against the layer's
+    projections of ``T x d_model`` by as many) and of the state."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     T, hk, dk = q.shape
     hv, dv = v.shape[1:]
     rep, n = hv // hk, T // chunk
     _, kc, _, gamma, bc = _chunked(q, k, v, g, beta, chunk)
-    keys = pl.BlockSpec((chunk, dk), lambda h, j: (j, h // rep))
-    vals = pl.BlockSpec((chunk, dv), lambda h, j: (j, h))
-    head = pl.BlockSpec((dk, dv), lambda h, j: (0, h))
-    return pl.pallas_call(
-        _prefill_chunk_kernel,
+    tiled = dv % _LANES == 0 and (dk % _LANES == 0 or hk == 1)
+    if tiled:
+        keys = pl.BlockSpec((chunk, dk), lambda h, j: (j, h // rep))
+        vals = pl.BlockSpec((chunk, dv), lambda h, j: (j, h))
+        head = pl.BlockSpec((dk, dv), lambda h, j: (0, h))
+        shapes = (T, hv * dv), (dk, hv * dv)
+
+        def lay(x):                             # [T, H, w] as it lies
+            return x.reshape(T, -1)
+        gx, bx = [_over_heads(x.reshape(T, hv), hv * dv)
+                  for x in (gamma, beta)]
+    else:
+        keys = pl.BlockSpec((None, chunk, dk), lambda h, j: (h // rep, j, 0))
+        vals = pl.BlockSpec((None, chunk, dv), lambda h, j: (h, j, 0))
+        head = pl.BlockSpec((None, dk, dv), lambda h, j: (h, 0, 0))
+        shapes = (hv, T, dv), (hv, dk, dv)
+
+        def lay(x):                             # [T, H, w] -> [H, T, w]
+            return x.transpose(1, 0, 2)
+        gx, bx = [jnp.broadcast_to(x.reshape(T, hv).T[:, :, None],
+                                   (hv, T, dv)) for x in (gamma, beta)]
+        s0 = lay(s0.reshape(dk, hv, dv))
+    o, last = pl.pallas_call(
+        functools.partial(_prefill_chunk_kernel, joint=tiled),
         grid=(hv, n),
         in_specs=[keys, keys,
                   pl.BlockSpec((1, 1, dk, chunk),
@@ -316,19 +383,21 @@ def prefill_chunks(q, k, v, g, beta, s0, *, chunk, interpret=False):
                   pl.BlockSpec((1, 1, 2, chunk), lambda h, j: (h, j, 0, 0)),
                   head],
         out_specs=[vals, head],
-        out_shape=[jax.ShapeDtypeStruct((T, hv * dv), v.dtype),
-                   jax.ShapeDtypeStruct((dk, hv * dv), s0.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(shapes[0], v.dtype),
+                   jax.ShapeDtypeStruct(shapes[1], s0.dtype)],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32),
                         pltpu.VMEM((chunk, chunk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret, name='gdn_prefill_chunk',
-    )(q.reshape(T, hk * dk), k.reshape(T, hk * dk),
+    )(lay(q), lay(k),
       kc.transpose(2, 0, 3, 1),                             # [Hk, n, dk, C]
-      v.reshape(T, hv * dv),
-      _over_heads(gamma.reshape(T, hv), hv * dv), _over_heads(beta, hv * dv),
+      lay(v), gx, bx,
       # a head's gamma and beta as ROWS, a block apart: [Hv, n, 2, C]
       jnp.stack([gamma, bc], axis=2).transpose(3, 0, 2, 1), s0)
+    if tiled:
+        return o, last
+    return lay(o).reshape(T, hv * dv), lay(last).reshape(dk, hv * dv)
 
 
 def _prefill_chunks_xla(q, k, v, g, beta, s0, chunk):
@@ -401,11 +470,14 @@ def _split(c, hk, hv, dv):
     return q * (kd // hk) ** -0.5, k, c[:, 2 * kd:].reshape(rows, hv, dv)
 
 
-def _gates(b, a, p):
+def _gates(b, a, p, op):
     """(``g = -exp(A_log) softplus(a + dt_bias)``, ``beta = sigmoid(b)``),
-    float32, ``[rows, Hv]``."""
+    float32, ``[rows, Hv]``; with the op's ``allow_neg_eigval`` ``beta = 2
+    sigmoid(b)``: the transition ``e^g (I - beta k k^T)`` then has its one
+    eigenvalue off ``e^g`` in (-1, 1) and not in (0, 1)."""
+    wide = 2.0 if op.attr('allow_neg_eigval', False) else 1.0
     return -jnp.exp(p['ALog']) * jax.nn.softplus(a + p['DtBias']), \
-        jax.nn.sigmoid(b)
+        wide * jax.nn.sigmoid(b)
 
 
 def _gated_norm(o, z, w, eps):
@@ -437,7 +509,7 @@ def _gdn_decode(ctx, op):
         c, tails = conv(tails, rows, layer, x.astype(tails.dtype),
                         p['ConvW'], jnp.zeros(x.shape[1], tails.dtype))
         q, k, v = _split(c, hk, hv, dv)
-        g, beta = _gates(b, a, p)
+        g, beta = _gates(b, a, p, op)
         o, state = update(state, rows, layer, _over_heads(jnp.exp(g), vd),
                           _over_heads(beta, vd), v.reshape(-1, vd), q, k,
                           value_heads=hv)
@@ -471,7 +543,7 @@ def _gdn_prefill(ctx, op):
                          hk, hv, dv)
         # a pad row leaves the state as it is: e^0 = 1, and u = 0 x (..)
         real = (jnp.arange(T) < length)[:, None]
-        g, beta = [jnp.where(real, t, 0.0) for t in _gates(b, a, p)]
+        g, beta = [jnp.where(real, t, 0.0) for t in _gates(b, a, p, op)]
         pad = -T % chunk                        # the xla tier's odd bucket
         if pad:
             q, k, v = [jnp.pad(t, ((0, pad), (0, 0), (0, 0)))

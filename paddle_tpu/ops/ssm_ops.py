@@ -369,6 +369,51 @@ def tier(name, tiles):
                                 mesh=mesh)
 
 
+# ---------------------------------------------------------------------------
+# a snapshot of a slot's row (serving/kv_blocks.py `SlotRows`)
+
+def _copy_row_kernel(ids_ref, pool_ref, out_ref, sem):
+    from jax.experimental.pallas import tpu as pltpu
+    del pool_ref                                # the pool IS the output
+    copy = pltpu.make_async_copy(out_ref.at[ids_ref[0]],
+                                 out_ref.at[ids_ref[1]], sem)
+    copy.start()
+    copy.wait()
+
+
+@functools.partial(jax.jit, static_argnames=('interpret',))
+def copy_row(pool, src, dst, *, interpret=False):
+    """Row ``src`` of ``pool [R, ...]`` copied over row ``dst`` IN PLACE,
+    every layer of it: one DMA HBM -> HBM, no gather, no scatter, no copy
+    of the pool (the device operation ``mosaic:state_snapshot_copy``)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    ids = jnp.stack([jnp.asarray(src, jnp.int32).reshape(()),
+                     jnp.asarray(dst, jnp.int32).reshape(())])
+    return pl.pallas_call(
+        _copy_row_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA]),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={1: 0},
+        interpret=interpret, name='state_snapshot_copy')(ids, pool)
+
+
+def snapshot_copy(pool, src, dst, scope):
+    """A 'row' pool with row ``src[0]`` copied over row ``dst[0]``, under
+    the named scope `scope` (``paddle_tpu:state_snapshot``): the kernel
+    `copy_row` where kernels run, else a gather and a scatter."""
+    impl = tier('state_snapshot_copy', True)
+    with jax.named_scope(scope):
+        if impl in ('pallas', 'interpret'):
+            return copy_row(pool, src[0], dst[0],
+                            interpret=impl == 'interpret')
+        return pool.at[dst].set(pool[src])
+
+
 def _impl(name, d_inner, n_state, rows=8):
     return tier(name, shapes_ok(d_inner, n_state, rows))
 

@@ -63,14 +63,18 @@ rewinds from it (``speculative`` needs every pool to), whether a shared
 block's entry of it copies, and the series its reads are booked under. A
 kind of index other than the allocator's block ids gets a bookkeeper
 (serving/kv_blocks.py), its feed beside 'gen_btab' (`_tables_feed`) and its
-entry in ``stats()``; such pools are sized from the slots, and
-``prefix_sharing`` is refused over them. WINDOW LAYERS (PR 41,
+entry in ``stats()``; such pools are sized from the slots, and a shared
+prefix is resumed over them from what the prefix cache's entries hold beside
+their block (`_sides`). WINDOW LAYERS (PR 41,
 ``layer_types`` ``'window'``) see the last ``sliding_window`` keys and keep
 K and V in a RING of blocks a slot owns while resident: a page behind the
 window is written over, never handed to an allocator. STATE-SPACE LAYERS
 (PR 43, ``'ssm'``) keep the recurrence's state and the convolution's tail A
 ROW A SLOT, a fixed size whatever the context (a slot that sits a step out
-is fed row 0, the trash row). docs/serving.md has each kind's contract.
+is fed row 0, the trash row); with ``prefix_sharing`` a prefill dispatch
+that ends on a block's edge leaves a copy of the row in a SNAPSHOT row
+(PR 58), and a later reader of the same prefix resumes from the deepest
+edge that still has one. docs/serving.md has each kind's contract.
 
 SPECULATIVE DECODING (PR 13, ``GenerateConfig(speculative=True)``)
 breaks the one-token-per-dispatch decode ceiling:
@@ -625,7 +629,8 @@ class GenerateEngine(object):
         else:
             self._alloc = BlockAllocator(c.num_blocks, c.block_size)
         self._max_blocks = c.max_len // c.block_size
-        self._cow_jit = self._dcopy_jit = self._move_jit = None
+        self._cow_jit = self._dcopy_jit = None
+        self._move_jit = {}     # a bookkeeper's feed -> its copy program
         self._stage_jit = self._put_jit = None
         # the prefills' first tokens as they are on the device, a row a
         # slot, and a step's output to stand for "no step in flight"
@@ -635,17 +640,15 @@ class GenerateEngine(object):
         # read here once -- no pass of the loop looks at the model again
         pools = self._pools = cache_pools(c.model, c.num_blocks, c.block_size,
                                           c.slots, c.prefix_sharing)
-        for option, fits in (('speculative', lambda p: p.rewinds),
-                             ('prefix_sharing', lambda p: p.shares)):
-            unfit = [p for p in pools if not fits(p)]
-            if getattr(c, option) and unfit:
-                raise ValueError("%s=True with LMConfig.layer_types=%r: "
-                                 "pool %r: %s" % (option, c.model.layer_types,
-                                                  unfit[0].name, unfit[0].why))
+        unfit = [p for p in pools if not p.rewinds]
+        if c.speculative and unfit:
+            raise ValueError("speculative=True with LMConfig.layer_types=%r: "
+                             "pool %r: %s" % (c.model.layer_types,
+                                              unfit[0].name, unfit[0].why))
         self._free = list(range(c.slots))[::-1]
         # a wholly shared prompt's last block can be copied and resumed, in
-        # every pool that a prefix is shared over
-        self._cow_ok = all(p.copies for p in pools if p.shares)
+        # every pool (a prefix is shared over each)
+        self._cow_ok = all(p.copies for p in pools)
 
         def booked(moment):
             return tuple((p.books[moment], p.shape[1]) for p in pools
@@ -691,9 +694,16 @@ class GenerateEngine(object):
                 c.slots, c.block_size, self._free)
             for index, pool in kinds.items())
         # the bookkeepers whose blocks a prefix's entries hold beside the
-        # allocator's (one at most: the prefix cache has one side)
+        # allocator's: a ring's blocks, or the rows' snapshots (one at
+        # most: the prefix cache has one side)
         self._sides = tuple(b for b in self._books
                             if c.prefix_sharing and hasattr(b, 'resume'))
+        if len(self._sides) > 1:
+            raise ValueError(
+                "prefix_sharing=True with LMConfig.layer_types=%r: the "
+                "prefix cache's entries hold ONE side block, and this model "
+                "has window layers' blocks AND state rows to keep there"
+                % (c.model.layer_types,))
         self._prefix = None
         if c.prefix_sharing:
             self._prefix = PrefixCache(
@@ -1042,27 +1052,40 @@ class GenerateEngine(object):
                 else book.advance(slot, length, start)
             if n:
                 monitor.inc(book.series, n)
-            moved = book.moved()
-            if moved:
-                self._move_blocks(book, moved)
+            self._copy_moved(book)
+
+    def _copy_moved(self, book):
+        """Make the copies that `book` has pending (`moved`), booked under
+        its series of copied rows where it has one."""
+        moved = book.moved()
+        if moved:
+            self._move_blocks(book, moved)
+            if book.copied:
                 monitor.inc(book.copied,
                             len(moved) * self.config.block_size)
 
     def _move_blocks(self, book, moved):
-        """Copy blocks `moved` ((from, to) ids) in every pool that `book`
-        keeps, on the device, ahead of the dispatch that reads them: one
-        jitted gather and scatter a pool, the ids padded with the trash
-        block to the widest bucket's blocks (one signature, compiled at
-        warmup). Phase `prefill.move` inside `prefill`, as the dispatch
-        is."""
+        """Copy blocks or rows `moved` ((from, to) ids) in every pool that
+        `book` keeps, on the device, ahead of the dispatch that reads them:
+        one jitted gather and scatter a pool, the ids padded with the trash
+        block to `book.batch` (a ring: the widest bucket's blocks and one)
+        -- one signature, compiled at warmup; a snapshot row is ONE row, a
+        DMA under the named scope `paddle_tpu:state_snapshot`
+        (ops/ssm_ops.py `snapshot_copy`). Phase `prefill.move` inside
+        `prefill`, as the dispatch is."""
         import jax
-        width = self.config.prompt_buckets[-1] // self.config.block_size + 1
-        if self._move_jit is None:
+        width = book.batch or (
+            self.config.prompt_buckets[-1] // self.config.block_size + 1)
+        if book.feed not in self._move_jit:
             def _move(cache, src, dst):
-                return cache.at[dst].set(cache[src])
-            self._move_jit = jax.jit(
+                if book.scope is None:
+                    return cache.at[dst].set(cache[src])
+                from ..ops import ssm_ops
+                return ssm_ops.snapshot_copy(cache, src, dst, book.scope)
+            self._move_jit[book.feed] = jax.jit(
                 _move, donate_argnums=()
                 if jax.default_backend() == 'cpu' else (0,))
+        move = self._move_jit[book.feed]
         with _loop_phase('prefill.move', counted=False):
             for at in range(0, len(moved), width):
                 ids = np.zeros((2, width), 'int32')
@@ -1071,7 +1094,7 @@ class GenerateEngine(object):
                 for pool in self._pools:
                     if INDEX_FEEDS[pool.index] != book.feed:
                         continue
-                    self.scope.set(pool.name, self._move_jit(
+                    self.scope.set(pool.name, move(
                         self.executor._state_value(
                             self.scope, pool.name, self._step_prog,
                             cache=False), ids[0], ids[1]))
@@ -1721,6 +1744,8 @@ class GenerateEngine(object):
             book.resume(slot, ctx_len // c.block_size, sides)
             if sides:
                 monitor.inc(book.shared, len(sides))
+                if book.resumed:
+                    monitor.inc(book.resumed, ctx_len)
         if ctx_len > 0:
             for series, _layers in self._hits:
                 monitor.inc(series)
@@ -1840,7 +1865,15 @@ class GenerateEngine(object):
         if self._prefix is None:
             return
         bs = self.config.block_size
-        for i in range(adm.published, min(adm.off // bs, len(adm.hashes))):
+        upto = min(adm.off // bs, len(adm.hashes))
+        for book in self._sides:
+            # a dispatch that ends on a block's edge leaves the slot's row
+            # as the state there: a snapshot row takes it, for the entry of
+            # the block that ends at the edge to hold
+            if adm.off % bs == 0 and adm.published < upto \
+                    and not self._prefix.has_side(adm.hashes[upto - 1]):
+                book.snapshot(adm.slot, upto - 1)
+        for i in range(adm.published, upto):
             side = None
             for book in self._sides:
                 held = book.held(adm.slot, i)
@@ -1853,6 +1886,8 @@ class GenerateEngine(object):
                     side = (held, first)
             self._prefix.register(adm.hashes[i], i, adm.blocks[i], side)
         adm.published = max(adm.published, adm.off // bs)
+        for book in self._sides:
+            self._copy_moved(book)
 
     def _drop_chunking(self, error, outcome):
         """The chunked admission under way ends here: its slot and blocks

@@ -31,14 +31,18 @@ model-specific throwaways.
 
 Pools that are not the allocator's have a bookkeeper a kind of index
 (`slot_bookkeeper`; models/transformer.py `cache_pools` says which pool has
-which, and why prefix sharing and speculation are refused over them). A
+which, how a shared prefix is resumed over them and why speculation is
+refused). A
 model with WINDOW layers (a query sees the last ``sliding_window`` keys
 only) keeps those layers' K and V in pools where ``WindowRings`` gives every
 slot ``ring`` blocks for as long as it is resident, used as a ring — logical
 block ``b`` lies in column ``b % ring`` of the slot's window table — so a
 page behind the window is handed on by being written over, a slot's share
 never grows with its context and no step asks an allocator for anything.
-STATE-SPACE layers keep a row a slot (``SlotRows``).
+STATE-SPACE layers keep a row a slot (``SlotRows``), and beside the slots'
+rows SNAPSHOT rows: the state at a block's edge, held by the prefix cache's
+entry of the block that ends there, which a later reader of the same prefix
+resumes from.
 
 Sharing is at FULL-BLOCK granularity. Because a block's K/V rows depend
 only on tokens at or before them (causal), a block fully covered by
@@ -183,6 +187,10 @@ class WindowRings(object):
     # rows of the blocks that `moved` had copied
     shared, copied = 'kv_window_blocks_shared_total', \
         'kv_window_rows_copied_total'
+    resumed = None      # ... no series of the tokens a resume skipped
+    # blocks a copy on the device takes at once (None: the widest bucket's
+    # and one), and the scope it runs under
+    batch, scope = None, None
 
     def __init__(self, slots, ring, block_size, reach=None, cached=0):
         self.ring = self.width = int(ring)
@@ -259,6 +267,11 @@ class WindowRings(object):
         moved, self._moved = self._moved, []
         return moved
 
+    def snapshot(self, slot, block):
+        """The dispatch that ended with logical block `block` is out: a
+        ring's blocks are the cache's to hold as they lie (`held`), nothing
+        is copied."""
+
     def resume(self, slot, depth, ids):
         """A new tenant that resumes at block `depth`: `ids` are the blocks
         of the logical blocks ``depth - len(ids) .. depth - 1``, referenced
@@ -300,40 +313,120 @@ class SlotRows(object):
     slot that sits a step out is fed) from its admission, a chunked one
     too, to its release -- so a row is in use while its slot is taken
     (`free`: the engine's own list of free slots), nothing is advanced and
-    nothing handed back."""
+    nothing handed back.
+
+    SNAPSHOT ROWS (`snapshots` > 0: the engine shares prefixes). Behind the
+    slots' rows the pools have spare ones, ids ``1 .. snapshots`` of an
+    allocator of their own (`blocks`; id ``s`` is row ``slots + s``). Where
+    a prefill dispatch ends on a block's edge the slot's row IS the state
+    at that position, in every 'row' pool and layer, and `snapshot` takes a
+    spare row for a copy of it; the prefix cache's entry of the block that
+    ENDS at the edge holds the row as its `side` (`held` names it to
+    `PrefixCache.register`), one reference, and gives it up under the rows'
+    own pressure (least recently used first; an evicted row only shortens
+    later hits). A request that matches the chain resumes at the deepest
+    edge whose entry still holds a row (`PrefixCache.side_run` with a
+    `reach` of one row): `resume` has that row copied into the new tenant's.
+    The copies are (from, to) rows that `moved` hands to the engine, which
+    makes them on the device in dispatch order -- behind the chunk that left
+    the state, ahead of whatever overwrites either row."""
 
     feed, width, series = 'gen_srow', 1, None
+    # a tenant's resumes from a snapshot row (`resume`), the rows that
+    # `snapshot` wrote and that the rows' own pressure gave up; no series
+    # for what `moved` copies (both ends book their own)
+    shared, copied = 'state_snapshot_resumes_total', None
+    resumed = 'state_snapshot_tokens_resumed_total'
+    written, evicted = 'state_snapshot_rows_written_total', \
+        'state_snapshot_evictions_total'
+    # rows a copy on the device takes at once, and the scope it runs under
+    batch, scope = 1, 'paddle_tpu:state_snapshot'
 
-    def __init__(self, slots, free):
+    def __init__(self, slots, free, snapshots=0, block_size=1, reach=1):
         self.capacity, self._free = int(slots), free
+        self.reach = int(reach)
+        self.blocks = BlockAllocator(int(snapshots) + 1, block_size) \
+            if snapshots else None
+        self.cache = None       # the PrefixCache whose entries hold the rows
+        self._snap = {}         # slot -> (logical block, id) of its last one
+        self._moved, self._lent = [], []
 
     def table(self, slot):
         return slot + 1
 
     def advance(self, slot, length=None, start=None):
         return 0
-    release = advance
+
+    def release(self, slot):
+        self._snap.pop(slot, None)
+        return 0
+
+    def snapshot(self, slot, block):
+        """The dispatch that ended with logical block `block` is out: a
+        spare row takes the slot's row as it leaves it. Without a row to
+        spare -- every one in the hands of an admission -- nothing is
+        taken."""
+        ids = self.blocks.alloc(1)
+        if ids is None:
+            monitor.inc(self.evicted, self.cache.evict_side_for(1))
+            ids = self.blocks.alloc(1)
+        if ids is None:
+            return
+        self._snap[slot] = (block, ids[0])
+        self._moved.append((slot + 1, self.capacity + ids[0]))
+        # the book's own reference, until the copy is handed on
+        self._lent.append(ids[0])
+        monitor.inc(self.written)
+
+    def held(self, slot, block):
+        """The id of the snapshot taken of the slot's row where logical
+        block `block` ended, or None."""
+        at, sid = self._snap.get(slot, (None, None))
+        return sid if at == block else None
+
+    def resume(self, slot, depth, ids):
+        """A new tenant that resumes at block `depth`: `ids` is the snapshot
+        row of that edge, referenced for it by the caller until its copy
+        into the slot's row is handed on -- or nothing, a start from zeros."""
+        self._snap.pop(slot, None)
+        for sid in ids:
+            self._moved.append((self.capacity + sid, slot + 1))
+            self._lent.append(sid)
 
     def moved(self):
-        return ()
+        """(from, to) rows to copy in every 'row' pool ahead of the next
+        dispatch. What `snapshot` and `resume` held of a row for the copy's
+        sake goes back: a program dispatched later cannot overtake it."""
+        moved, self._moved = self._moved, []
+        if self._lent:
+            self.blocks.deref_many(self._lent)
+            self._lent = []
+        return moved
 
     def in_use(self):
         return self.capacity - len(self._free)
 
     def report(self, stats):
         stats['state'] = {'capacity': self.capacity, 'in_use': self.in_use()}
+        if self.blocks is not None:
+            stats['state']['snapshots'] = {
+                'rows': self.blocks.capacity, 'in_use': self.blocks.in_use()}
+            monitor.set_gauge('state_snapshot_rows_in_use',
+                              float(self.blocks.in_use()))
 
 
 def slot_bookkeeper(pool, width, slots, block_size, free):
     """The bookkeeper of the pools indexed as `pool` is (a row of models/
     transformer.py `cache_pools`; `width`: the columns of a slot's row of
     their feed): 'ring', a slot's ring of blocks, the trash block, and what
-    is left for the prefix cache's own; 'row', a slot's row."""
+    is left for the prefix cache's own; 'row', a slot's row, the trash row,
+    and what is left for the snapshot rows."""
     if pool.index == 'ring':
         return WindowRings(slots, width, block_size, pool.reach,
                            pool.shape[0] - 1 - slots * width)
     if pool.index == 'row':
-        return SlotRows(slots, free)
+        return SlotRows(slots, free, pool.shape[0] - 1 - slots, block_size,
+                        pool.reach)
     raise ValueError("no bookkeeper for a pool indexed by %r" % (pool.index,))
 
 
@@ -440,10 +533,12 @@ class PrefixCache(object):
     allocation pressure.
 
     `side`: the allocator of a second pool (the window layers':
-    `WindowRings.blocks`) whose block of the same logical block an entry
+    `WindowRings.blocks`; the state-space layers' snapshot rows:
+    `SlotRows.blocks`) whose block of the same logical block an entry
     may hold beside its own, one reference each, with the first row of it
-    that was written. A request resumes at depth ``d`` only where the
-    entries before it still hold the side blocks of the ``reach`` rows
+    that was written (a snapshot row is the state as of the block's LAST
+    row and holds nothing else). A request resumes at depth ``d`` only where
+    the entries before it still hold the side blocks of the ``reach`` rows
     before row ``d * block_size`` (`side_run`). The side's blocks go under
     the side's own pressure (`evict_side_for`): least recently used first
     as well, but the SHALLOWEST first within a tie -- a resume needs the
@@ -494,6 +589,11 @@ class PrefixCache(object):
             else:
                 return d, ids
         return 0, []
+
+    def has_side(self, h):
+        """Whether hash `h` has an entry that holds a side block."""
+        e = self._entries.get(h)
+        return e is not None and e[3] is not None
 
     def register(self, h, depth, block_id, side=None):
         """Publish `block_id` as the home of chain hash `h` (depth =

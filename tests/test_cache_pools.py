@@ -1,6 +1,7 @@
 """The table of a model's pools (ISSUE 46, models/transformer.py
 `cache_pools`): for the toy `LMConfig` of each of the benchmark's
-configurations (Nemotron's since PR 48, Qwen3-Next's since PR 55), every
+configurations (Nemotron's since PR 48, Qwen3-Next's since PR 55,
+Olmo-Hybrid's since PR 58), every
 pool's name, what indexes it, whether a rejected
 draft rewinds from it and whether a shared block's entry of it copies;
 `kv_cache_names` and `kv_cache_shapes` are views of it; and what an engine
@@ -17,7 +18,7 @@ from paddle_tpu.serving import GenerateConfig, GenerateEngine
 from paddle_tpu.serving import kv_blocks
 
 from benchmark.models import (jamba, joyai, kexaone, lfm2, lm, nemotron,
-                              olmoe, qwen3next)
+                              olmoe, olmohybrid, qwen3next)
 
 from test_olmoe_serving import LISTED
 
@@ -41,6 +42,7 @@ CONFIGS = {
     'ai21-jamba2-3b': lambda: _toy(jamba, 'jamba'),
     'nemotron-3-nano-30b-a3b-ep8-l20': lambda: _toy(nemotron, 'nemotron'),
     'qwen3-next-80b-a3b-ep8-l8': lambda: _toy(qwen3next, 'qwen3next'),
+    'olmo-hybrid-7b-l8': lambda: _toy(olmohybrid, 'olmohybrid'),
 }
 KV = [(T.KV_CACHE_K, 'block', True, True), (T.KV_CACHE_V, 'block', True, True)]
 # (name, index, rewinds, copies) of every pool, in the order of the state
@@ -59,6 +61,8 @@ POOLS = {
         (T.SSD_STATE, 'row', False, False), (T.SSD_TAIL, 'row', False, False)],
     'qwen3-next-80b-a3b-ep8-l8': KV + [
         (T.GDN_STATE, 'row', False, False), (T.GDN_TAIL, 'row', False, False)],
+    'olmo-hybrid-7b-l8': KV + [
+        (T.GDN_STATE, 'row', False, False), (T.GDN_TAIL, 'row', False, False)],
 }
 # the series a decode step books its reads under: (series, rows a slot at
 # most, of which field of the config a layer count)
@@ -73,6 +77,9 @@ STEP_READS = {
         ('kv_tokens_read_total', None, 'n_attn_layers'),
         ('ssd_state_rows_updated_total', 1, 'n_ssd_layers')],
     'qwen3-next-80b-a3b-ep8-l8': [
+        ('kv_tokens_read_total', None, 'n_attn_layers'),
+        ('gdn_state_rows_updated_total', 1, 'n_gdn_layers')],
+    'olmo-hybrid-7b-l8': [
         ('kv_tokens_read_total', None, 'n_attn_layers'),
         ('gdn_state_rows_updated_total', 1, 'n_gdn_layers')],
 }
@@ -129,19 +136,17 @@ def _engine(monkeypatch, cfg, **options):
 def test_an_engine_refuses_what_the_table_says_and_keeps_its_books(
         config, option, monkeypatch):
     cfg = CONFIGS[config]()
-    fits = {'speculative': lambda p: p.rewinds,
-            'prefix_sharing': lambda p: p.shares}[option]
+    # the table refuses speculation over a pool that cannot be rewound;
+    # a prefix is shared over every pool (no field says otherwise)
     unfit = [p for p in T.cache_pools(cfg, BLOCKS, BLOCK_SIZE, SLOTS)
-             if not fits(p)]
+             if option == 'speculative' and not p.rewinds]
     assert bool(unfit) == ((option, config) in {
         ('speculative', 'lfm2-8b-a1b-l8'),
         ('speculative', 'k-exaone-236b-a23b-ep16-l5'),
         ('speculative', 'ai21-jamba2-3b'),
         ('speculative', 'nemotron-3-nano-30b-a3b-ep8-l20'),
-        ('prefix_sharing', 'nemotron-3-nano-30b-a3b-ep8-l20'),
-        ('prefix_sharing', 'ai21-jamba2-3b'),
         ('speculative', 'qwen3-next-80b-a3b-ep8-l8'),
-        ('prefix_sharing', 'qwen3-next-80b-a3b-ep8-l8')})
+        ('speculative', 'olmo-hybrid-7b-l8')})
     if unfit:
         with pytest.raises(ValueError) as refusal:
             _engine(monkeypatch, cfg, **{option: True})
@@ -150,17 +155,21 @@ def test_an_engine_refuses_what_the_table_says_and_keeps_its_books(
                                % (option, cfg.layer_types))
         assert repr(unfit[0].name) in said and unfit[0].why in said
     elif option == 'prefix_sharing' or config.startswith('fairseq-dense'):
-        # since PR 51 a prefix is shared over the slots' rings too: their
-        # bookkeeper is the one whose blocks the prefix cache holds beside
-        # the allocator's, and the pools have room for those
+        # since PR 51 a prefix is shared over the slots' rings too, since
+        # PR 58 over the slots' rows: their bookkeeper is the one whose
+        # blocks (whose snapshot rows) the prefix cache holds beside the
+        # allocator's, and the pools have room for those
         shared = _engine(monkeypatch, cfg, **{option: True})
-        rings = [b for b in shared._books
-                 if isinstance(b, kv_blocks.WindowRings)]
-        assert list(shared._books) == rings
-        assert list(shared._sides) == rings * (option == 'prefix_sharing')
+        assert len(shared._books) <= 1
+        assert list(shared._sides) == \
+            list(shared._books) * (option == 'prefix_sharing')
         for b in shared._sides:
             assert b.cache is shared._prefix
-            assert b.capacity == SLOTS * b.ring + SLOTS * b.ring // 2
+            if isinstance(b, kv_blocks.WindowRings):
+                assert b.capacity == SLOTS * b.ring + SLOTS * b.ring // 2
+            else:
+                assert (b.capacity, b.blocks.capacity, b.reach) == \
+                    (SLOTS, SLOTS, 1)
     else:
         # the table lets it pass; the drafter refuses the block by field
         with pytest.raises(ValueError, match=r'build_lm_drafter .*LMConfig\.'):
@@ -177,9 +186,14 @@ def test_an_engine_refuses_what_the_table_says_and_keeps_its_books(
     assert [b.feed for b in eng._books] == [T.INDEX_FEEDS[k] for k in kinds]
     assert sorted(eng._tables_feed([[0] * 8])) == \
         sorted(['gen_btab'] + [b.feed for b in eng._books])
-    # only a tail is recomputed where a wholly shared prompt would copy
-    assert eng._cow_ok == (config not in ('lfm2-8b-a1b-l8',
-                                          'k-exaone-236b-a23b-ep16-l5'))
+    # only a tail is recomputed where a wholly shared prompt would copy;
+    # since PR 58 the rows share too, and a row is no block to copy: a
+    # wholly shared prompt resumes at the deepest edge before its last
+    # block that has a snapshot row
+    assert eng._cow_ok == (config not in (
+        'lfm2-8b-a1b-l8', 'k-exaone-236b-a23b-ep16-l5', 'ai21-jamba2-3b',
+        'nemotron-3-nano-30b-a3b-ep8-l20', 'qwen3-next-80b-a3b-ep8-l8',
+        'olmo-hybrid-7b-l8'))
     assert eng._sides == () and eng._prefix is None
     stats = {'blocks': {}}
     for b in eng._books:

@@ -553,13 +553,22 @@ def test_the_state_never_passes_the_slots_and_returns_to_zero():
 
 @pytest.mark.parametrize('option', ['prefix_sharing', 'speculative'])
 def test_an_ssm_model_refuses_sharing_and_speculation_by_name(option):
+    """Speculation is refused by name; sharing, refused until PR 58, builds
+    an engine whose state rows have a snapshot row a slot behind them."""
     kw = {'prefix_sharing': False}
     kw[option] = True
-    with pytest.raises(ValueError, match=r"%s=True with LMConfig\."
-                       r"layer_types=.*'ssm'.*state-space" % option):
-        GenerateEngine(GenerateConfig(
+
+    def build():
+        return GenerateEngine(GenerateConfig(
             model=jamba.lm_config(TOY, 64, False), slots=2, max_len=64,
             prompt_buckets=[16], block_size=8, **kw))
+    if option == 'prefix_sharing':
+        assert build().stats()['state']['snapshots'] == {'rows': 2,
+                                                         'in_use': 0}
+        return
+    with pytest.raises(ValueError, match=r"%s=True with LMConfig\."
+                       r"layer_types=.*'ssm'.*state-space" % option):
+        build()
 
 
 def test_the_classic_builders_refuse_the_block_by_name():

@@ -485,8 +485,7 @@ def test_speculation_stays_refused_over_the_rings():
                                          r'LMConfig\.layer_types'):
         _engine(speculative=True)
     ring = [p for p in _engine()._pools if p.index == 'ring'][0]
-    assert (ring.rewinds, ring.copies, ring.shares, ring.reach) == \
-        (False, False, True, 39)
+    assert (ring.rewinds, ring.copies, ring.reach) == (False, False, 39)
     assert ring.books['hit'] == 'kv_window_prefix_resumes_total'
 
 

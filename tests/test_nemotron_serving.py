@@ -708,13 +708,22 @@ def test_a_wrong_forward_is_outside_the_tolerance(control, kw):
 
 @pytest.mark.parametrize('option', ['prefix_sharing', 'speculative'])
 def test_an_ssd_model_refuses_sharing_and_speculation_by_name(option):
+    """Speculation is refused by name; sharing, refused until PR 58, builds
+    an engine whose state rows have a snapshot row a slot behind them."""
     kw = {'prefix_sharing': False}
     kw[option] = True
-    with pytest.raises(ValueError, match=r"%s=True with LMConfig\."
-                       r"layer_types=.*'ssd'.*Mamba-2" % option):
-        GenerateEngine(GenerateConfig(
+
+    def build():
+        return GenerateEngine(GenerateConfig(
             model=nemotron.lm_config(TOY, 64, False), slots=2, max_len=64,
             prompt_buckets=[16], block_size=8, **kw))
+    if option == 'prefix_sharing':
+        assert build().stats()['state']['snapshots'] == {'rows': 2,
+                                                         'in_use': 0}
+        return
+    with pytest.raises(ValueError, match=r"%s=True with LMConfig\."
+                       r"layer_types=.*'ssd'.*Mamba-2" % option):
+        build()
 
 
 def test_the_classic_builders_and_lmconfig_refuse_by_name():

@@ -284,7 +284,12 @@ def test_the_kernels_take_whole_tiles_only():
     assert not gdn_ops.shapes_ok(128, 128, 3, 4)
     assert not gdn_ops.shapes_ok(128, 128, 16, 32, 96, 64)
     assert not gdn_ops.shapes_ok(128, 128, 16, 32, 128, 24)
-    assert not gdn_ops.shapes_ok(64, 128, 2, 4, 128, 64)
+    # since PR 58 heads whose keys are no whole vreg are laid a head first
+    # round the prefill's kernel, and heads of 192 values are walked in
+    # pairs (tests/test_olmohybrid_serving.py)
+    assert gdn_ops.shapes_ok(64, 128, 2, 4, 128, 64)
+    assert gdn_ops.shapes_ok(96, 192, 30, 30, 512, 64)
+    assert not gdn_ops.shapes_ok(96, 192, 15, 15)
 
 
 def test_the_paged_kernel_at_8_queries_on_a_kv_head_of_256(monkeypatch):
@@ -421,7 +426,10 @@ def test_the_pools_are_a_row_a_slot_and_only_a_gdn_model_has_them():
                                'prefill': 'gdn_prefill_rows_total',
                                'resume': 'gdn_state_resumes_total'}
     assert by[TAIL].books == {} and 'Gated DeltaNet' in by[STATE].why
-    assert not by[STATE].shares and not by[TAIL].rewinds
+    # since PR 58 a prefix is shared over the rows: a snapshot row
+    assert by[STATE].reach == 1 and not by[TAIL].rewinds
+    assert T.kv_cache_shapes(cfg, 9, 8, 4, shared=True)[STATE] == \
+        (5 + T.snapshot_rows(4, True), 3, 16, 32)
     plain = LMConfig(vocab_size=50, d_model=32, n_head=2, n_layer=2, d_ff=64)
     assert STATE not in T.kv_cache_names(plain)
 
@@ -662,14 +670,25 @@ def test_the_reference_imports_nothing_of_the_program():
 
 @pytest.mark.parametrize('option', ['prefix_sharing', 'speculative'])
 def test_a_gdn_model_refuses_sharing_and_speculation_by_name(option):
+    """Speculation is refused by name; sharing, refused until PR 58, builds
+    an engine whose state rows have a snapshot row a slot behind them
+    (tests/test_olmohybrid_serving.py holds a hit to a miss's logits)."""
     kw = {'prefix_sharing': False}
     kw[option] = True
+
+    def build():
+        return GenerateEngine(GenerateConfig(
+            model=qwen3next.lm_config(TOY, 64, False), slots=2, max_len=64,
+            prompt_buckets=[16], block_size=8, **kw))
+    if option == 'prefix_sharing':
+        eng = build()
+        assert [type(b).__name__ for b in eng._sides] == ['SlotRows']
+        assert eng.stats()['state']['snapshots'] == {'rows': 2, 'in_use': 0}
+        return
     with pytest.raises(ValueError, match=r"%s=True with LMConfig\."
                        r"layer_types=.*'gdn'.*gen_gdn_state.*Gated DeltaNet"
                        % option):
-        GenerateEngine(GenerateConfig(
-            model=qwen3next.lm_config(TOY, 64, False), slots=2, max_len=64,
-            prompt_buckets=[16], block_size=8, **kw))
+        build()
 
 
 def test_the_classic_builders_and_lmconfig_refuse_by_name():
