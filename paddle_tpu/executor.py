@@ -32,6 +32,7 @@ import os
 import threading
 import time
 import types
+import weakref
 
 import numpy as np
 import jax
@@ -108,6 +109,15 @@ class Scope(object):
         # none): a program bound later takes the leaf as it lies.
         self._staged = {}
         self._gen = 0
+        # every write, whatever its name, and what the runs of this
+        # scope's entries carry from one run to the next (`_carry_state`,
+        # a record a compiled entry, gone with the entry): a record is
+        # good while `_writes` stands where the run's own rebind left it.
+        # A writer lets the records go as it moves the count — each is
+        # stale by then — so that an array it replaced or dropped is held
+        # by nothing.
+        self._writes = 0
+        self._carried = weakref.WeakKeyDictionary()
 
     # dict-ish API used internally
     def get(self, name, default=None):
@@ -115,18 +125,26 @@ class Scope(object):
 
     def set(self, name, value):
         self._vars[name] = value
+        self._writes += 1
+        self._carried.clear()
         if name in self._staged:
             self._gen += 1
 
     def update(self, d):
+        if not d:
+            return
         self._vars.update(d)
+        self._writes += 1
+        self._carried.clear()
         if self._staged and not self._staged.keys().isdisjoint(d):
             self._gen += 1
 
     def _relay(self, name, value):
         """Hold `name` as `value`, the SAME logical array laid out another
-        way on the device (BoundProgram._stage): no write, no count."""
+        way on the device (BoundProgram._stage): no write, no count — but
+        no record may keep the array as it lay."""
         self._vars[name] = value
+        self._carried.clear()
 
     def has(self, name):
         return name in self._vars
@@ -136,6 +154,8 @@ class Scope(object):
 
     def drop(self, name):
         self._vars.pop(name, None)
+        self._writes += 1
+        self._carried.clear()
         if name in self._staged:
             self._gen += 1
 
@@ -228,9 +248,48 @@ def _run_key(random_seed, program_runs, global_counter):
     alternating fetch lists never restart the stream.
     Unseeded: fresh key per run."""
     if random_seed:
-        return jax.random.fold_in(jax.random.PRNGKey(random_seed),
-                                  program_runs)
+        return _seeded_key(random_seed, program_runs)
     return jax.random.PRNGKey(global_counter % (2 ** 31))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _seeded_key(seed, run):
+    """fold_in(PRNGKey(seed), run) as ONE compiled call a run, `run` a
+    host scalar: eagerly it is seven primitive binds, each a dispatch."""
+    return jax.random.fold_in(jax.random.PRNGKey(seed), run)
+
+
+def _carried_state(scope, entry):
+    """What `entry`'s last run on `scope` left for this one — its
+    read-only and its read-written leaves, two tuples in the entry's
+    order, the second the very arrays the scope holds — if nothing has
+    written the scope since (`Scope._writes`); else None, and the run
+    walks the scope. The record is TAKEN: a run that raises leaves none,
+    and the donated leaves are the caller's alone to let go."""
+    rec = scope._carried.pop(entry, None)
+    if rec is None or rec[0] != scope._writes:
+        return None
+    monitor.inc('executor_run_carried_total')
+    return rec[1], rec[2]
+
+
+def _carry_state(scope, entry, ro, new_state):
+    """Keep for `entry`'s next run on `scope` what this one, whose
+    rebind is done, would otherwise look up a name at a time: the
+    read-only leaves it was given and the read-written ones it returned.
+    Nothing is kept if the scope does not hold every read-only leaf (one
+    it does not is a host value converted for this call alone, as in
+    BoundProgram)."""
+    fn = entry.fn
+    if all(map(operator.is_, ro, map(scope.get, fn.ro_names))):
+        scope._carried[entry] = (
+            scope._writes, ro,
+            tuple(map(new_state.__getitem__, fn.rw_names)))
+
+
+def _by_name(fn, ro, rw):
+    """The state a `StateCallable` takes flat, as the two dicts by name."""
+    return dict(zip(fn.ro_names, ro)), dict(zip(fn.rw_names, rw))
 
 
 def _next_program_run(program):
@@ -472,10 +531,12 @@ def _run_phase(name):
     """Phase `name` of Executor.run (monitor.phase): its self time into
     executor_run_phase_seconds_total{phase=name}, and a
     'paddle_tpu:run.<name>' span in a profiler session. prepare: feed
-    preparation, fingerprint and feed signature, cache lookup, state
-    gather, the run key, the flight recorder's step note; dispatch: the
-    compiled call; commit: the goodput hook, scope.update, LoD
-    propagation; fetch: materialising the fetches, the wait for the
+    preparation, fingerprint and feed signature, cache lookup, the state
+    (carried from the entry's last run, or gathered from the scope), the
+    run key, the flight recorder's step note; dispatch: the compiled
+    call; commit: the goodput hook, scope.update, the record for the next
+    run, the release of the donated inputs, LoD propagation; fetch:
+    materialising the fetches, the wait for the
     device; compile: lowering and the first call of a new signature (the
     self time of set-up's frames there, `_compile_frame`); segmented: a
     PADDLE_SEGMENT_HOST_OPS run."""
@@ -511,7 +572,7 @@ class _CompiledEntry(object):
     # holds a strong ref to the program so id(program) cache keys can never
     # alias a garbage-collected program's address
     __slots__ = ('fn', 'fetch_names', 'ro_names', 'rw_names', 'written',
-                 'program', 'lod_out', 'notify_dirs', 'bound')
+                 'program', 'lod_out', 'notify_dirs', 'bound', '__weakref__')
 
     def __init__(self, fn, fetch_names, ro_names, rw_names, written,
                  program, lod_out=None):
@@ -1449,15 +1510,20 @@ class Executor(object):
             else:
                 monitor.inc('compile_cache_hit')
 
-            ro_state, rw_state = {}, {}
-            for n in entry.ro_names:
-                ro_state[n] = self._state_value(scope, n, program)
-            for n in entry.rw_names:
-                rw_state[n] = self._state_value(scope, n, program,
-                                                cache=False)
-
-            call = entry.fn if _call is None \
-                else functools.partial(_call, entry)
+            # the state, flat and in the entry's order: what this entry's
+            # last run on the scope left (nothing wrote since), or every
+            # leaf looked up, uploaded where the host wrote it
+            fn = entry.fn
+            state = None if fresh_compile or _call is not None \
+                else _carried_state(scope, entry)
+            if state is None:
+                state = (tuple([self._state_value(scope, n, program)
+                                for n in fn.ro_names]),
+                         tuple([self._state_value(scope, n, program,
+                                                  cache=False)
+                                for n in fn.rw_names]))
+            ro, rw = state
+            del state
             self._run_counter += 1
             key_arr = _run_key(program.random_seed,
                                _next_program_run(program),
@@ -1467,6 +1533,17 @@ class Executor(object):
             # randomness)
             program._last_run_key = key_arr
             blackbox.note_step(program)
+        ro_state = rw_state = None
+        if fresh_compile or _call is not None:
+            # by name: a signature's first call goes the way it always
+            # went, and so does bind's
+            ro_state, rw_state = _by_name(fn, ro, rw)
+            call = fn if _call is None else functools.partial(_call, entry)
+            if _call is not None:
+                # bind's handle lays the read-only leaves out one at a
+                # time, each let go before the next: `ro_state` is the
+                # run's one hold on them
+                ro = None
         if fresh_compile:
             frame = _compile_frame(program, since=t_compile)
             fetches, new_state = _first_call(
@@ -1486,13 +1563,16 @@ class Executor(object):
                 # guards the re-invoke)
                 def _dispatch():
                     resilience.maybe_fault('run')
+                    if _call is None:
+                        return fn.flat(feed, ro, rw, key_arr)
                     return call(feed, ro_state, rw_state, key_arr)
                 t_disp = time.perf_counter()
                 try:
                     fetches, new_state = _dispatch()
                 except Exception as e:  # noqa: BLE001 — classified inside
                     fetches, new_state = resilience.retry_after(
-                        e, _dispatch, site='run', state=rw_state)
+                        e, _dispatch, site='run',
+                        state=dict(zip(fn.rw_names, rw)))
                     t_disp = time.perf_counter()    # exclude retry backoff
                 t_staged = time.perf_counter()
         with _run_phase('commit'):
@@ -1507,6 +1587,8 @@ class Executor(object):
                 # record executed (program, feed, state, key, CPU fetches)
                 # cases for tools/tpu_optest.py to replay on the real chip
                 from .core.optest_collect import record_case
+                if ro_state is None:
+                    ro_state, rw_state = _by_name(fn, ro, rw)
                 record_case(program, feed, static_lods, ro_state, rw_state,
                             key_arr, fetch_names, fetches)
             # rebind the scope BEFORE the nan-check can raise: with
@@ -1531,6 +1613,8 @@ class Executor(object):
                     # against the still-alive pre-run state and name the
                     # first op that produced a non-finite value (no-op when
                     # disabled)
+                    if ro_state is None:
+                        ro_state, rw_state = _by_name(fn, ro, rw)
                     info = analysis.localize_nonfinite(
                         program, feed, ro_state, rw_state, key_arr,
                         static_lods, static_feed)
@@ -1555,6 +1639,12 @@ class Executor(object):
                     jax.block_until_ready((fetches, new_state))
                 monitor.observe('executor_sync_seconds',
                                 time.perf_counter() - t_sync)
+            if _call is None:
+                _carry_state(scope, entry, ro, new_state)
+            # the donated inputs are let go HERE, behind the dispatch and
+            # while the device is busy, not as the frame exits behind the
+            # fetch's wait: a thousand arrays take their time to go
+            del ro, rw, ro_state, rw_state
             # checkpoint_notify (ops/dist_ops.py): the reference RPCs the
             # checkpoint dir to pservers each execution; here the executor
             # is the checkpoint writer, so save persistables after the run
@@ -1563,13 +1653,15 @@ class Executor(object):
                 with scope_guard(scope):
                     save_persistables(self, cn_dir, main_program=program)
             # propagate LoD of written persistables into the scope, and of
-            # fetches into the returned tensors
-            for n in entry.written:
-                lod = entry.lod_out.get(n)
-                if lod:
-                    scope._lods[n] = lod
-                else:
-                    scope._lods.pop(n, None)
+            # fetches into the returned tensors (nothing to do, a name,
+            # where the entry gives no LoD and the scope holds none)
+            if entry.lod_out or scope._lods:
+                for n in entry.written:
+                    lod = entry.lod_out.get(n)
+                    if lod:
+                        scope._lods[n] = lod
+                    else:
+                        scope._lods.pop(n, None)
             from .core.selected_rows import SelectedRows
             # fetched sparse grads densify, like the reference's fetch of
             # a SelectedRows var materializing a tensor

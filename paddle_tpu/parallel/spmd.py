@@ -28,7 +28,7 @@ __all__ = ['DataParallelRunner', 'place_state']
 
 class _Entry(object):
     __slots__ = ('fn', 'ro_names', 'rw_names', 'written', 'feed_shardings',
-                 'state_shardings', 'lod_out')
+                 'state_shardings', 'lod_out', '__weakref__')
 
     def __init__(self, fn, ro_names, rw_names, written, feed_shardings,
                  state_shardings, lod_out=None):
@@ -174,34 +174,45 @@ class DataParallelRunner(object):
         state_shard = {n: self._state_sharding(program, n, reduce_mode,
                                                mesh)
                        for n in set(ro_names) | set(rw_names) | set(written)}
+        # the state goes in flat, in the SORTED order in which jit
+        # flattens a dict (lowering.StateCallable: the compiled program's
+        # parameter list is what it was when the state went in by name),
+        # so a run hands on the tuples the run before it left; called or
+        # lowered with the state by name, it lines the leaves up itself.
+        # Its `flat` is jitted anew, with the mesh's shardings
+        call = lowering.StateCallable(fn, ro_names, rw_names, program, True)
         in_shardings = (
             feed_shardings,
-            {n: state_shard[n] for n in ro_names},
-            {n: state_shard[n] for n in rw_names},
+            tuple([state_shard[n] for n in call.ro_names]),
+            tuple([state_shard[n] for n in call.rw_names]),
             repl,
         )
         out_shardings = (None, {n: state_shard[n] for n in written})
-        jitted = jax.jit(fn, in_shardings=in_shardings,
-                         out_shardings=out_shardings,
-                         donate_argnums=(2,))
-        return _Entry(jitted, ro_names, rw_names, written, feed_shardings,
-                      state_shard, lod_out)
+        call.flat = jax.jit(call._fn, in_shardings=in_shardings,
+                            out_shardings=out_shardings,
+                            donate_argnums=(2,))
+        return _Entry(call, call.ro_names, call.rw_names, written,
+                      feed_shardings, state_shard, lod_out)
 
     def run(self, executor, feed, fetch_list, scope, return_numpy):
         """One step, in the phases Executor.run has
         (executor_run_phase_seconds_total{phase}): prepare — feed
-        preparation, the signature, every state leaf looked up and found
-        in place, the run key; dispatch — the sharded call (a signature's
-        first is set-up's frame, and the `compile` phase); commit — the
-        scope rebind, LoDs; fetch — the wait for the device. Runs are
-        counted where the CompiledProgram delegates (executor_run_total,
-        compiler.py)."""
-        from ..executor import _run_phase, _compile_frame, global_scope
+        preparation, the signature, the state (what this entry's last run
+        left, `executor._carried_state`, or every leaf looked up and
+        found in place), the run key; dispatch — the sharded call (a
+        signature's first is set-up's frame, and the `compile` phase);
+        commit — the scope rebind, the record for the next run, the
+        donated inputs let go, LoDs; fetch — the wait for the device.
+        Runs are counted where the CompiledProgram delegates
+        (executor_run_total, compiler.py)."""
+        from ..executor import (_run_phase, _compile_frame, _carry_state,
+                                global_scope)
         if scope is None:
             scope = global_scope()
         with _run_phase('prepare'):
-            entry, feed, ro_state, rw_state, key_arr, fetch_names, since = \
+            entry, feed, ro, rw, key_arr, fetch_names, since = \
                 self._prepare(executor, feed, fetch_list, scope)
+        flat = entry.fn.flat
         program = self._program
         from . import api as _papi
         prev, _papi._ACTIVE_MESH = _papi._ACTIVE_MESH, self._mesh
@@ -219,12 +230,10 @@ class DataParallelRunner(object):
                     # compile happens inside the FIRST call — compile wall
                     # time must cover it, not just the jit construction
                     with _compile_frame(program, since=since):
-                        fetches, new_state = entry.fn(feed, ro_state,
-                                                      rw_state, key_arr)
+                        fetches, new_state = flat(feed, ro, rw, key_arr)
                 else:
                     with _run_phase('dispatch'):
-                        fetches, new_state = entry.fn(feed, ro_state,
-                                                      rw_state, key_arr)
+                        fetches, new_state = flat(feed, ro, rw, key_arr)
         finally:
             _papi._ACTIVE_MESH = prev
             _papi._ACTIVE_PARAM_SPEC = prev_spec
@@ -241,12 +250,18 @@ class DataParallelRunner(object):
                 with _run_phase('fetch'):
                     jax.block_until_ready(fetches)
             scope.update(new_state)
-            for n in new_state:
-                lod = entry.lod_out.get(n)
-                if lod:
-                    scope._lods[n] = lod
-                else:
-                    scope._lods.pop(n, None)
+            if jax.process_count() == 1:
+                _carry_state(scope, entry, ro, new_state)
+            # the donated inputs go here, while the device is busy, not
+            # as the frame exits behind the fetch's wait
+            del ro, rw
+            if entry.lod_out or scope._lods:
+                for n in new_state:
+                    lod = entry.lod_out.get(n)
+                    if lod:
+                        scope._lods[n] = lod
+                    else:
+                        scope._lods.pop(n, None)
         if not return_numpy:
             return list(fetches)
         from ..executor import _fetched
@@ -260,8 +275,10 @@ class DataParallelRunner(object):
 
     def _prepare(self, executor, feed, fetch_list, scope):
         """Everything of a run ahead of the sharded call: (entry, feed,
-        ro_state, rw_state, key, fetch names, and — for a signature's
-        first run, whose entry was made here — when its making began)."""
+        the read-only and the read-written leaves in the entry's order,
+        key, fetch names, and — for a
+        signature's first run, whose entry was made here — when its
+        making began)."""
         program = self._program
         feed, feed_lods = executor._prepare_feed(program, feed or {})
         # LoD-carrying scope state binds statically, like the serial
@@ -302,40 +319,49 @@ class DataParallelRunner(object):
         else:
             monitor.inc('compile_cache_hit')
 
-        ro_state = {n: executor._state_value(scope, n, program)
-                    for n in entry.ro_names}
-        rw_state = {n: executor._state_value(scope, n, program)
-                    for n in entry.rw_names}
-        if nproc == 1:
-            ro_state = place_state(scope, ro_state, entry.state_shardings,
-                                   program)
-            rw_state = place_state(scope, rw_state, entry.state_shardings,
-                                   program)
-        if nproc > 1:
-            # assemble global arrays from per-process host-local data
-            # (feeds: local batch shard; state: every process holds the
-            # full value — identical init from the same seed)
-            def _globalize_feed(sharding, v):
-                if isinstance(v, jax.Array) and not v.is_fully_addressable:
-                    return v
-                return jax.make_array_from_process_local_data(
-                    sharding, np.asarray(v))
+        from ..executor import _carried_state, _run_key, _next_program_run
+        # one process: what this entry's last run on the scope left, if
+        # nothing wrote the scope since — in place already, the entry's
+        # own outputs under its `out_shardings`
+        state = _carried_state(scope, entry) if nproc == 1 else None
+        if state is None:
+            ro_state = {n: executor._state_value(scope, n, program)
+                        for n in entry.ro_names}
+            rw_state = {n: executor._state_value(scope, n, program)
+                        for n in entry.rw_names}
+            if nproc == 1:
+                ro_state = place_state(scope, ro_state,
+                                       entry.state_shardings, program)
+                rw_state = place_state(scope, rw_state,
+                                       entry.state_shardings, program)
+            else:
+                # assemble global arrays from per-process host-local data
+                # (feeds: local batch shard; state: every process holds
+                # the full value — identical init from the same seed)
+                def _globalize_feed(sharding, v):
+                    if isinstance(v, jax.Array) \
+                            and not v.is_fully_addressable:
+                        return v
+                    return jax.make_array_from_process_local_data(
+                        sharding, np.asarray(v))
 
-            def _globalize_state(sharding, v):
-                if isinstance(v, jax.Array) and not v.is_fully_addressable:
-                    return v          # already a global array from last step
-                arr = np.asarray(v)
-                return jax.make_array_from_callback(
-                    arr.shape, sharding, lambda idx: arr[idx])
+                def _globalize_state(sharding, v):
+                    if isinstance(v, jax.Array) \
+                            and not v.is_fully_addressable:
+                        return v      # already a global array from last step
+                    arr = np.asarray(v)
+                    return jax.make_array_from_callback(
+                        arr.shape, sharding, lambda idx: arr[idx])
 
-            feed = {k: _globalize_feed(entry.feed_shardings[k], v)
-                    for k, v in feed.items()}
-            ro_state = {n: _globalize_state(entry.state_shardings[n], v)
-                        for n, v in ro_state.items()}
-            rw_state = {n: _globalize_state(entry.state_shardings[n], v)
-                        for n, v in rw_state.items()}
+                feed = {k: _globalize_feed(entry.feed_shardings[k], v)
+                        for k, v in feed.items()}
+                ro_state = {n: _globalize_state(entry.state_shardings[n], v)
+                            for n, v in ro_state.items()}
+                rw_state = {n: _globalize_state(entry.state_shardings[n], v)
+                            for n, v in rw_state.items()}
+            state = tuple(ro_state.values()), tuple(rw_state.values())
+        ro, rw = state
         self._run_counter += 1
-        from ..executor import _run_key, _next_program_run
         key_arr = _run_key(program.random_seed, _next_program_run(program),
                            self._run_counter)
         if nproc > 1:
@@ -346,7 +372,7 @@ class DataParallelRunner(object):
             key_arr = jax.make_array_from_callback(
                 karr.shape, NamedSharding(self._mesh, P()),
                 lambda idx: karr[idx])
-        return entry, feed, ro_state, rw_state, key_arr, fetch_names, since
+        return entry, feed, ro, rw, key_arr, fetch_names, since
 
     @staticmethod
     def _fetch_to_host(f):

@@ -20,6 +20,16 @@ executor_performance.md) makes measurable promises about:
   call's own argument handling. bound_overhead_us_per_var is the slope
   between the two.
 
+- run_overhead_us_rw: host time of ONE steady `Executor.run` on a program
+  that increments N read-written persistables, at N = 2 and N = 1 200 (a
+  24-layer AMP + Adam train step reads and writes ~1 460 leaves), and of
+  its `prepare` phase alone (executor_run_phase_seconds_total). A steady
+  run takes its state from the run before, so `prepare` does not grow
+  with N: run_overhead_us_per_rw_var, the slope between the two, is the
+  compiled call's own argument, donation and output handling a leaf, and
+  run_prepare_us_per_rw_var reads ~0 (a walk of the scope a run would
+  read 2-3 us a leaf there).
+
 Usage: python tools/runoverhead.py [rounds]   (prints one JSON line)
 """
 import json
@@ -72,10 +82,57 @@ def measure_bound_overhead(n_vars, rounds=300):
     return (time.time() - t0) / rounds * 1e6
 
 
+def measure_rw_overhead(n_vars, rounds=100):
+    """(host microseconds of one steady `Executor.run`, of its `prepare`
+    phase alone) on a program that increments `n_vars` read-written
+    persistables of 16 floats."""
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu import monitor
+    main_p, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main_p, startup):
+        with fluid.unique_name.guard():
+            for i in range(n_vars):
+                fluid.layers.increment(fluid.layers.create_global_var(
+                    [16], value=0.0, dtype='float32', persistable=True,
+                    name='rwoverhead_w%d' % i))
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    scope = fluid.Scope()
+    exe.run(startup, scope=scope)
+    for _ in range(3):
+        exe.run(main_p, scope=scope)
+    jax.block_until_ready(scope.get('rwoverhead_w0'))
+    series = 'executor_run_phase_seconds_total{phase=prepare}'
+    before = monitor.counters().get(series, 0.0)
+    t0 = time.time()
+    for _ in range(rounds):
+        exe.run(main_p, scope=scope)
+    jax.block_until_ready(scope.get('rwoverhead_w0'))
+    run_us = (time.time() - t0) / rounds * 1e6
+    prepare_us = (monitor.counters().get(series, 0.0) - before) \
+        / rounds * 1e6
+    return run_us, prepare_us
+
+
+def measure_rw_slope(rounds=100, few=2, many=1200):
+    """{'run_overhead_us_rw', 'run_prepare_us_rw',
+    'run_overhead_us_per_rw_var', 'run_prepare_us_per_rw_var'}: a steady
+    run at `few` and at `many` read-written leaves and the slopes."""
+    us = {n: measure_rw_overhead(n, rounds) for n in (few, many)}
+    slope = [round((us[many][i] - us[few][i]) / (many - few), 3)
+             for i in (0, 1)]
+    return {'run_overhead_us_rw': {str(n): round(v[0], 1)
+                                   for n, v in us.items()},
+            'run_prepare_us_rw': {str(n): round(v[1], 1)
+                                  for n, v in us.items()},
+            'run_overhead_us_per_rw_var': slope[0],
+            'run_prepare_us_per_rw_var': slope[1]}
+
+
 def measure_run_overhead(rounds=300):
     """Returns {'run_overhead_us', 'first_compile_s', 'cache_hit_compile_s',
-    'bound_overhead_us', 'bound_overhead_us_per_var', 'rounds'};
-    importable."""
+    'bound_overhead_us', 'bound_overhead_us_per_var', 'rounds'} and
+    measure_rw_slope's four; importable."""
     import jax
     import paddle_tpu as fluid
 
@@ -109,14 +166,15 @@ def measure_run_overhead(rounds=300):
 
     few, many = 2, 300
     bound_us = {n: measure_bound_overhead(n, rounds) for n in (few, many)}
-    return {'run_overhead_us': round(overhead_us, 1),
-            'first_compile_s': round(first_compile_s, 3),
-            'cache_hit_compile_s': round(cache_hit_compile_s, 4),
-            'bound_overhead_us': {str(n): round(us, 1)
-                                  for n, us in bound_us.items()},
-            'bound_overhead_us_per_var': round(
-                (bound_us[many] - bound_us[few]) / (many - few), 3),
-            'rounds': rounds}
+    return dict(measure_rw_slope(min(rounds, 100)),
+                run_overhead_us=round(overhead_us, 1),
+                first_compile_s=round(first_compile_s, 3),
+                cache_hit_compile_s=round(cache_hit_compile_s, 4),
+                bound_overhead_us={str(n): round(us, 1)
+                                   for n, us in bound_us.items()},
+                bound_overhead_us_per_var=round(
+                    (bound_us[many] - bound_us[few]) / (many - few), 3),
+                rounds=rounds)
 
 
 if __name__ == '__main__':
