@@ -374,7 +374,7 @@ def explain_program(executor, program, feed=None, fetch_list=None,
     nothing runs). See Executor.explain for the public contract."""
     import jax
     from .framework import default_main_program
-    from .executor import _donation_enabled, global_scope, _CompiledEntry
+    from .executor import global_scope
 
     if program is None:
         program = default_main_program()
@@ -384,30 +384,17 @@ def explain_program(executor, program, feed=None, fetch_list=None,
     feed, fetch_names, static_feed, static_lods = \
         executor._prepare_run_inputs(program, feed, scope, fetch_list,
                                      count=False)
-
-    donate = _donation_enabled(record=False)
-    from . import flags as _flags
-    if nan_localization_enabled() and _flags.get_flags('check_nan_inf'):
-        # mirror _run_impl's provenance force-off so explain caches under
-        # the SAME key a later run() will look up (one trace, not two)
-        donate = False
-    key = (program._fingerprint(),
-           executor._feed_signature(feed, static_lods, static_feed),
-           tuple(fetch_names), donate)
+    # the key a later run() will look up (one trace, not two); a policy
+    # QUERY, no run: the donation rates stand
+    key = executor._entry_key(program, feed, static_lods, static_feed,
+                              fetch_names, record=False)
+    donate = key[-1]
     entry = executor._cache_get(key)
-    if entry is None or not hasattr(entry, 'fn') \
-            or not hasattr(entry.fn, 'lower'):
-        read, written = lowering.analyze_state(program, fetch_names)
-        needed = executor._read_before_write(program, read, written,
-                                             set(feed), fetch_names)
-        lod_out = {}
-        fn, ro_names, rw_names = lowering.build_callable(
-            program, fetch_names, needed, written, static_lods=static_lods,
-            static_feed=static_feed, lod_out=lod_out, donate=donate)
-        entry = _CompiledEntry(fn, fetch_names, ro_names, rw_names,
-                               written, program, lod_out)
+    if entry is None:
         # share the compile with a later run() of the same signature —
         # explain-then-train pays for one trace, not two
+        entry = executor._build_entry(program, feed, fetch_names,
+                                      static_lods, static_feed, donate)
         executor._cache_put(key, entry)
 
     feed_avals = {k: _aval_of(v) for k, v in feed.items()}
@@ -576,7 +563,7 @@ def run_profiled(executor, program, feed, fetch_list, scope, return_numpy):
     per-op dispatch instead of one fused XLA call. Nothing is cached —
     every profiled run re-traces, by design."""
     import jax
-    from .executor import global_scope, _run_key, _next_program_run
+    from .executor import global_scope, _check_nan_inf
     from .core.selected_rows import SelectedRows
     from . import flags as _flags
 
@@ -584,26 +571,18 @@ def run_profiled(executor, program, feed, fetch_list, scope, return_numpy):
         scope = global_scope()
     feed, fetch_names, static_feed, static_lods = \
         executor._prepare_run_inputs(program, feed, scope, fetch_list)
-
-    read, written = lowering.analyze_state(program, fetch_names)
-    needed = executor._read_before_write(program, read, written, set(feed),
-                                         fetch_names)
-    lod_out = {}
-    fn, ro_names, rw_names = lowering.build_fn(
-        program, fetch_names, needed, written, static_lods=static_lods,
-        static_feed=static_feed, lod_out=lod_out)
-    ro = {n: executor._state_value(scope, n, program) for n in ro_names}
-    rw = {n: executor._state_value(scope, n, program, cache=False)
-          for n in rw_names}
-    executor._run_counter += 1
-    key_arr = _run_key(program.random_seed, _next_program_run(program),
-                       executor._run_counter)
-    program._last_run_key = key_arr
+    # an entry of its own, cached nowhere, for the step's take — the
+    # state as a run takes it, the run key and its counters — and its
+    # fetch: what is called is the entry's function un-jitted
+    entry = executor._build_entry(program, feed, fetch_names, static_lods,
+                                  static_feed, False)
+    rec = executor._take(scope, entry, program)
+    key_arr = executor._next_key(program)
     monitor.inc('op_profile_run_total')
     t0 = time.perf_counter()
     with monitor.span('profile_ops'):
         with lowering.op_hook(_timing_hook):
-            fetches, new_state = fn(feed, ro, rw, key_arr)
+            fetches, new_state = entry.fn._fn(feed, rec.ro, rec.rw, key_arr)
         jax.block_until_ready([v for v in new_state.values()
                                if not isinstance(v, SelectedRows)])
     wall = time.perf_counter() - t0
@@ -613,26 +592,16 @@ def run_profiled(executor, program, feed, fetch_list, scope, return_numpy):
 
     scope.update(new_state)
     if _flags.get_flags('check_nan_inf'):
-        from .executor import _check_nan_inf
         _check_nan_inf(new_state, dict(zip(fetch_names, fetches)))
-    for n in written:
-        lod = lod_out.get(n)
+    for n in entry.written:
+        lod = entry.lod_out.get(n)
         if lod:
             scope._lods[n] = lod
         else:
             scope._lods.pop(n, None)
-    from .executor import _fetched
-    fetches = [f.to_dense() if isinstance(f, SelectedRows) else f
-               for f in fetches]
-    out = []
-    for n, f in zip(fetch_names, fetches):
-        if lod_out.get(n):
-            out.append(_fetched(f, lod_out[n]))
-        elif return_numpy:
-            out.append(np.asarray(f))
-        else:
-            out.append(f)
-    return out
+    return executor._fetch(
+        entry, [f.to_dense() if isinstance(f, SelectedRows) else f
+                for f in fetches], return_numpy)
 
 
 # ---------------------------------------------------------------------------
@@ -771,14 +740,12 @@ def localize_from_scope(executor, program, feed, scope, key_arr):
         feed, _, static_feed, static_lods = \
             executor._prepare_run_inputs(program, feed, scope, [],
                                          count=False)
-        read, written = lowering.analyze_state(program, [])
-        needed = executor._read_before_write(program, read, written,
-                                             set(feed), [])
-        written_set = set(written)
-        ro = {n: executor._state_value(scope, n, program)
-              for n in needed if n not in written_set}
-        rw = {n: executor._state_value(scope, n, program, cache=False)
-              for n in needed if n in written_set}
+        # the state as the failed step took it (Executor._take)
+        from .executor import _by_name
+        entry = executor._build_entry(program, feed, [], static_lods,
+                                      static_feed, False)
+        rec = executor._take(scope, entry, program)
+        ro, rw = _by_name(entry.fn, rec.ro, rec.rw)
         if key_arr is None:
             import jax
             key_arr = jax.random.PRNGKey(0)

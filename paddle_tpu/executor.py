@@ -26,6 +26,7 @@ run:451, global_scope:34) and the C++ serial executor it drives
 """
 import collections
 import contextlib
+import copy
 import functools
 import operator
 import os
@@ -90,6 +91,23 @@ class _VarShim(object):
         return _TensorShim(self._scope, self._name)
 
 
+class _Held(object):
+    """What an entry's last call on a scope left for its next to take
+    without looking (`Scope._held`, one a compiled entry, gone with the
+    entry): `ro`, the read-only leaves in the entry's order and as it
+    wants them placed, good while `gen` is the scope's count of writes
+    to a staged name; `rw`, the read-written leaves it returned — the
+    very arrays the scope holds — good while `writes` is the scope's
+    count of all writes. `gen` is None where the scope does not hold
+    every read-only leaf (a host value converted for one call alone):
+    such a record is never kept."""
+
+    __slots__ = ('ro', 'gen', 'rw', 'writes')
+
+    def __init__(self, ro, gen, rw):
+        self.ro, self.gen, self.rw, self.writes = ro, gen, rw, None
+
+
 class Scope(object):
     """Flat variable store (reference framework/scope.h:48, minus the parent
     chain — sub-scopes are an interpreter artifact; XLA keeps intermediates
@@ -98,26 +116,23 @@ class Scope(object):
     def __init__(self):
         self._vars = {}
         self._lods = {}
-        # the names some BoundProgram keeps staged (its read-only state),
-        # and how many writes have landed on one of them: a handle
-        # compares `_gen` with the value it staged at. Every write goes
-        # through set / update / drop — nothing else touches `_vars` —
-        # and one to a name nobody staged (the KV pools, a training
-        # run's parameters) costs a set lookup.
-        # `_staged` also keeps, a name, the layout a handle's compiled
-        # entry chose for it (a `Format`; None where the backend offers
-        # none): a program bound later takes the leaf as it lies.
+        # the records (`_Held`), written by a call's commit and read by
+        # the next one's take (Executor._take / _commit), and the two
+        # counts they are good by. `_staged`: the names some record
+        # keeps as read-only state, each with the layout a bound
+        # program's compiled entry chose for it (a `Format`; None where
+        # the backend offers none, or nobody asked): a program bound
+        # later takes the leaf as it lies. Every write goes through
+        # set / update / drop — nothing else touches `_vars` — and one
+        # to a name nobody staged (the KV pools, a training run's
+        # parameters) costs a set lookup.
+        self._held = weakref.WeakKeyDictionary()
         self._staged = {}
         self._gen = 0
-        # every write, whatever its name, and what the runs of this
-        # scope's entries carry from one run to the next (`_carry_state`,
-        # a record a compiled entry, gone with the entry): a record is
-        # good while `_writes` stands where the run's own rebind left it.
-        # A writer lets the records go as it moves the count — each is
-        # stale by then — so that an array it replaced or dropped is held
-        # by nothing.
         self._writes = 0
-        self._carried = weakref.WeakKeyDictionary()
+        # the one record whose read-written leaves stand: an entry's own
+        # rebind is a write, so no two do
+        self._fresh = None
 
     # dict-ish API used internally
     def get(self, name, default=None):
@@ -125,39 +140,50 @@ class Scope(object):
 
     def set(self, name, value):
         self._vars[name] = value
-        self._writes += 1
-        self._carried.clear()
-        if name in self._staged:
-            self._gen += 1
+        self._wrote(name in self._staged)
 
     def update(self, d):
-        if not d:
-            return
-        self._vars.update(d)
+        if d:
+            self._vars.update(d)
+            # two key views: the shorter one is walked
+            self._wrote(not self._staged.keys().isdisjoint(d.keys()))
+
+    def drop(self, name):
+        self._vars.pop(name, None)
+        self._wrote(name in self._staged)
+
+    def _wrote(self, staged):
+        """A write has landed, on a staged name or on none."""
         self._writes += 1
-        self._carried.clear()
-        if self._staged and not self._staged.keys().isdisjoint(d):
-            self._gen += 1
+        self._gen += staged
+        self._let_go(staged)
+
+    def _let_go(self, every):
+        """The writer lets the records go as it moves the counts — each
+        is stale by then — so that an array it replaced or dropped is
+        held by nothing: the read-written leaves of the one record that
+        has any, and after a write to a staged name `every` record."""
+        fresh, self._fresh = self._fresh, None
+        if fresh is not None:
+            fresh.rw = None
+        if every:
+            self._held.clear()
+
+    def _keep(self, entry, rec):
+        self._held[entry] = self._fresh = rec
 
     def _relay(self, name, value):
         """Hold `name` as `value`, the SAME logical array laid out another
-        way on the device (BoundProgram._stage): no write, no count — but
+        way on the device (BoundProgram._place): no write, no count — but
         no record may keep the array as it lay."""
         self._vars[name] = value
-        self._carried.clear()
+        self._let_go(True)
 
     def has(self, name):
         return name in self._vars
 
     def names(self):
         return sorted(self._vars)
-
-    def drop(self, name):
-        self._vars.pop(name, None)
-        self._writes += 1
-        self._carried.clear()
-        if name in self._staged:
-            self._gen += 1
 
     # fluid-style API
     def find_var(self, name):
@@ -173,19 +199,19 @@ class Scope(object):
         return Scope()
 
 
-def _check_nan_inf(new_state, fetches):
+def _check_nan_inf(new_state, fetches, host=np.asarray):
     """FLAGS_check_nan_inf: scan run outputs for NaN/Inf and raise naming
     the variable (reference framework/operator.cc:973 checks every op
     output; whole-program XLA means we check at the program boundary —
-    use FLAGS_debug_nans to trap at the producing op instead)."""
-    import numpy as np
+    use FLAGS_debug_nans to trap at the producing op instead). `host`:
+    how the entry's values come to the host (`_CompiledEntry.to_host`)."""
     from .core.selected_rows import SelectedRows
     bad = []
     for group in (new_state, fetches):
         for name, v in group.items():
             if isinstance(v, SelectedRows):
                 v = v.values
-            arr = np.asarray(v)
+            arr = host(v)
             if arr.dtype.kind == 'f' and not np.isfinite(arr).all():
                 bad.append(name)
     if bad:
@@ -259,34 +285,6 @@ def _seeded_key(seed, run):
     return jax.random.fold_in(jax.random.PRNGKey(seed), run)
 
 
-def _carried_state(scope, entry):
-    """What `entry`'s last run on `scope` left for this one — its
-    read-only and its read-written leaves, two tuples in the entry's
-    order, the second the very arrays the scope holds — if nothing has
-    written the scope since (`Scope._writes`); else None, and the run
-    walks the scope. The record is TAKEN: a run that raises leaves none,
-    and the donated leaves are the caller's alone to let go."""
-    rec = scope._carried.pop(entry, None)
-    if rec is None or rec[0] != scope._writes:
-        return None
-    monitor.inc('executor_run_carried_total')
-    return rec[1], rec[2]
-
-
-def _carry_state(scope, entry, ro, new_state):
-    """Keep for `entry`'s next run on `scope` what this one, whose
-    rebind is done, would otherwise look up a name at a time: the
-    read-only leaves it was given and the read-written ones it returned.
-    Nothing is kept if the scope does not hold every read-only leaf (one
-    it does not is a host value converted for this call alone, as in
-    BoundProgram)."""
-    fn = entry.fn
-    if all(map(operator.is_, ro, map(scope.get, fn.ro_names))):
-        scope._carried[entry] = (
-            scope._writes, ro,
-            tuple(map(new_state.__getitem__, fn.rw_names)))
-
-
 def _by_name(fn, ro, rw):
     """The state a `StateCallable` takes flat, as the two dicts by name."""
     return dict(zip(fn.ro_names, ro)), dict(zip(fn.rw_names, rw))
@@ -319,7 +317,7 @@ _HOST_SEGMENT_OPS = ('py_func', 'print', 'detection_map', 'save',
                      'save_combine')
 
 
-def _donation_enabled(fused=False, override=None, record=True):
+def _donation_enabled(override=None, record=True):
     """Default-ON buffer donation for the rw-state pytree: parameter updates
     alias their input buffers instead of holding old+new state simultaneously
     (2x peak HBM). Escape hatches: a per-call ``donate=`` override on
@@ -330,9 +328,8 @@ def _donation_enabled(fused=False, override=None, record=True):
     paths process-wide — callers that keep reading a stale reference to a
     pre-run scope value need it (the scope itself is always rebound to the
     new state right after the call, so normal callers never observe a
-    donated buffer); PADDLE_FUSED_DONATE overrides for run_fused only (its
-    historical opt-in name). Guard: optest collection records the pre-run
-    rw state after the call, which donation would have deleted.
+    donated buffer). Guard: optest collection records the pre-run rw state
+    after the call, which donation would have deleted.
 
     Every resolution is counted: donation_run_total when ON,
     donation_fallback_total{reason} when OFF — so "did this run donate"
@@ -364,11 +361,7 @@ def _donation_enabled(fused=False, override=None, record=True):
         _count('donation_fallback_total',
                labels={'reason': 'per_call_opt_out'})
         return False
-    env = None
-    if fused:
-        env = os.environ.get('PADDLE_FUSED_DONATE')
-    if env is None:
-        env = os.environ.get('PADDLE_DONATE')
+    env = os.environ.get('PADDLE_DONATE')
     if env is not None:
         if env != '0':
             _count('donation_run_total')
@@ -528,20 +521,20 @@ class scope_guard(object):
 
 
 def _run_phase(name):
-    """Phase `name` of Executor.run (monitor.phase): its self time into
-    executor_run_phase_seconds_total{phase=name}, and a
+    """Phase `name` of a run (monitor.phase; `Executor._step`): its self
+    time into executor_run_phase_seconds_total{phase=name}, and a
     'paddle_tpu:run.<name>' span in a profiler session. prepare: feed
-    preparation, fingerprint and feed signature, cache lookup, the state
-    (carried from the entry's last run, or gathered from the scope), the
-    run key, the flight recorder's step note; dispatch: the compiled
-    call; commit: the goodput hook, scope.update, the record for the next
-    run, the release of the donated inputs, LoD propagation; fetch:
-    materialising the fetches, the wait for the
-    device; compile: lowering and the first call of a new signature (the
-    self time of set-up's frames there, `_compile_frame`); segmented: a
-    PADDLE_SEGMENT_HOST_OPS run."""
+    preparation, the key, the cache lookup, `_take`, the run key, the
+    flight recorder's step note; dispatch: `_call`; commit: `_commit`;
+    fetch: `_fetch`, the wait for the device; compile: lowering and the
+    first call of a new signature (the self time of set-up's frames
+    there, `_compile_frame`); segmented: a PADDLE_SEGMENT_HOST_OPS run."""
     return monitor.phase('run.' + name, 'executor_run_phase_seconds_total',
                          {'phase': name})
+
+
+def _no_phase(name):
+    return contextlib.nullcontext()
 
 
 # a set-up frame inside Executor.run is the run's `compile` phase as well
@@ -552,46 +545,98 @@ def _compile_frame(program, stage='first_run', since=None):
     return coldstart.compile_frame(program, stage, since, *_RUN_COMPILE)
 
 
-def _first_call(frame, state, call, *args):
-    """The first call, `call(*args)`, of a newly made entry inside
-    `frame` (coldstart.compile_frame): jax.jit is lazy, the XLA compile
+def _first_call(frame, entry, feed, rec, key):
+    """The first call of a newly made entry inside `frame`
+    (coldstart.compile_frame): jax.jit is lazy, the XLA compile
     happens inside it, so honest compile wall time spans lowering + that
     call — its dispatch: the execution runs on behind what the caller
     does next, as it did before there was a frame (waited for, it cost
     the train cell 1.2 s of every start). A transient XLA failure
     (RESOURCE_EXHAUSTED) retries under the 'compile' site's policy."""
+    def attempt():
+        return entry.call(feed, rec.ro, rec.rw, key)
     with frame:
         try:
-            return call(*args)
+            return attempt()
         except Exception as e:          # noqa: BLE001 — classified inside
-            return resilience.retry_after(e, lambda: call(*args),
-                                          site='compile', state=state)
+            return entry.retry(e, attempt, site='compile',
+                               state=dict(zip(entry.rw_names, rec.rw)))
+
+
+def _notify_dirs(program):
+    """The directories `program`'s checkpoint_notify ops name."""
+    return [op.attr('dir', '') or 'checkpoint_notify'
+            for op in program.global_block().ops
+            if op.type == 'checkpoint_notify']
+
+
+def _as_tuple(scope, names, leaves, program):
+    """Where a plain entry wants a leaf the walk found: where it is."""
+    return tuple(leaves)
+
+
+def _keep_nothing(scope, entry, rec):
+    pass
+
+
+def _raise(e, *args, **kwargs):
+    raise e
 
 
 class _CompiledEntry(object):
+    """One compiled call on a scope, whoever makes it — `Executor.run`,
+    `bind`'s handle, `run_fused`, a segment of a host-op program, the
+    SPMD runners: `fn`, the `lowering.StateCallable`, and its names; what
+    the program said when it was lowered (`fetch_names`, `written`,
+    `lod_out`, `notify_dirs`); and what differs between the ways of
+    running it, set where the entry is built and never asked about by
+    the step (Executor._take / _call / _commit):
+
+    `kind`, `steps`  what a dispatch is booked to goodput as.
+    `call`     what is called, (feed, ro leaves, rw leaves, key): `fn.flat`;
+               a handle's own executable; a sharded `flat` in its mesh.
+    `place`    how leaves the walk found are put where the entry wants
+               them, (scope, names, leaves, program) -> tuple: as they
+               are; onto the mesh (`spmd.place_state`); a handle's
+               weights in the layouts its executable chose.
+    `retry`    what a failed call is handed to: `resilience.retry_after`
+               with the donated-buffer guard; across processes, nothing.
+    `keep`     where commit leaves the record for the next take
+               (`Scope._keep`); across processes, nowhere.
+    `to_host`  how a fetch comes to the host.
+    `feed_shardings`, `state_shardings`  a sharded entry's, by name."""
+
     # holds a strong ref to the program so id(program) cache keys can never
     # alias a garbage-collected program's address
     __slots__ = ('fn', 'fetch_names', 'ro_names', 'rw_names', 'written',
-                 'program', 'lod_out', 'notify_dirs', 'bound', '__weakref__')
+                 'program', 'lod_out', 'notify_dirs', 'bound', 'fp', 'kind',
+                 'steps', 'call', 'place', 'retry', 'keep', 'to_host',
+                 'feed_shardings', 'state_shardings', '__weakref__')
 
-    def __init__(self, fn, fetch_names, ro_names, rw_names, written,
-                 program, lod_out=None):
+    def __init__(self, fn, fetch_names, written, program, lod_out=None,
+                 kind='run', steps=1, fp=None, call=None, place=_as_tuple,
+                 retry=resilience.retry_after, keep=Scope._keep,
+                 to_host=np.asarray, feed_shardings=None,
+                 state_shardings=None):
         self.fn = fn
         self.fetch_names = fetch_names
-        self.ro_names = ro_names
-        self.rw_names = rw_names
+        self.ro_names, self.rw_names = fn.ro_names, fn.rw_names
         self.written = written
         self.program = program
         self.lod_out = lod_out if lod_out is not None else {}
+        self.fp = fp or program._fingerprint()
+        self.kind, self.steps = kind, steps
+        self.call = call if call is not None else fn.flat
+        self.place, self.retry, self.keep = place, retry, keep
+        self.to_host = to_host
+        self.feed_shardings = feed_shardings
+        self.state_shardings = state_shardings
         # BoundProgram._compile's executables, by the formats they were
         # asked for: a second engine on a fresh scope compiles nothing
         self.bound = {}
-        # checkpoint_notify dirs, precomputed once per compile so the hot
-        # run path doesn't rescan the op list every call
-        self.notify_dirs = [
-            op.attr('dir', '') or 'checkpoint_notify'
-            for op in program.global_block().ops
-            if op.type == 'checkpoint_notify']
+        # precomputed once per compile so the hot run path doesn't rescan
+        # the op list every call
+        self.notify_dirs = _notify_dirs(program)
 
 
 class FetchedTensor(np.ndarray):
@@ -667,41 +712,40 @@ def _spec(value):
 
 
 class BoundProgram(object):
-    """A fixed-signature dispatch handle from `Executor.bind`: a call does
-    only what changes from call to call — the read-written state taken
-    from the scope, one fault-site check, the compiled call, and the
-    scope rebind. No cache-key hashing, no feed re-preparation, no span
-    machinery — the per-token host tax of a decode loop.
+    """A fixed-signature dispatch handle from `Executor.bind`: a call is
+    the executor's step bare (`_take`, `_call`, `_commit`) on the handle's
+    own entry — no cache-key hashing, no feed re-preparation, no span or
+    phase machinery: the per-token host tax of a decode loop.
 
     The READ-ONLY state (a decode step's weights: several hundred names
-    that never change) is staged at bind, as the tuple the compiled entry
-    takes (`StateCallable.ro_names`' order), and staged again only after a write to one of its names:
-    `Scope` counts those (`Scope._gen`), a call compares one integer.
-    A weight rebound with `scope.set` or a tensor shim's `set` is what
-    the next call uses, exactly as if every call staged;
-    `executor_bound_restage_total` counts the rebuilds. The READ-WRITTEN
-    state (the KV pools) comes from the scope on every call: other
-    programs rebind the same names between two calls, so the handle
-    never keeps its own outputs. Staging is `Executor._state_value`
-    either way: the not-initialised error, the one lossless upload of a
-    host-written value and the freeze of its buffer. A host value that
-    cannot be cached in the scope (a dtype jax narrows, a view) is
-    converted again every call, as `run()` does.
+    that never change) is the scope's record's (`_Held.ro`), walked again
+    only after a write to one of its names: `Scope` counts those
+    (`Scope._gen`), a call compares one integer. A weight rebound with
+    `scope.set` or a tensor shim's `set` is what the next call uses,
+    exactly as if every call walked; `executor_bound_restage_total`
+    counts the walks after the first. The READ-WRITTEN state (the KV
+    pools) is the record's too while the handle's own rebind is the
+    scope's last write, and comes from the scope otherwise: other
+    programs rebind the same names between two calls. The walk is
+    `Executor._state_value`: the not-initialised error, the one lossless
+    upload of a host-written value and the freeze of its buffer. A host
+    value that cannot be cached in the scope (a dtype jax narrows, a
+    view) is converted again every call, as `run()` does.
 
     Staged state lies as the compiled entry wants it. Where the backend
     offers layouts (`_layouts_offered`), the handle's entry is compiled
     with every read-only leaf's layout left to the compiler
-    (`StateCallable.lower_bound`), and staging puts each leaf in the
-    format the executable asks for: one `jax.device_put` for a leaf that
-    lies otherwise (`executor_bound_relayout_total`), nothing for the
-    others. The relaid array REPLACES the scope's value under its name —
-    the same shape and values, one copy in HBM, no write counted — and
-    `Scope._staged` keeps the format: a program bound later on the scope
-    compiles for the layout it finds and asks nothing again, so a leaf is
-    relaid once a scope and every handle's entry agrees with what the
-    scope holds. `run()`, `precompile` and the runners keep the default
-    entry (jit compiles for the layout of a committed argument, as these
-    are).
+    (`StateCallable.lower_bound`), and the entry's `place` puts each leaf
+    in the format the executable asks for: one `jax.device_put` for a
+    leaf that lies otherwise (`executor_bound_relayout_total`), nothing
+    for the others. The relaid array REPLACES the scope's value under its
+    name — the same shape and values, one copy in HBM, no write counted —
+    and `Scope._staged` keeps the format: a program bound later on the
+    scope compiles for the layout it finds and asks nothing again, so a
+    leaf is relaid once a scope and every handle's entry agrees with what
+    the scope holds. `run()`, `precompile` and the runners keep the
+    default entry (jit compiles for the layout of a committed argument,
+    as these are).
 
     FLAGS_check_nan_inf raises at the program boundary as in run() (the
     op-level localization replay stays a run() feature). Calls are NOT
@@ -709,27 +753,18 @@ class BoundProgram(object):
     executor thread)."""
 
     __slots__ = ('_exe', '_entry', '_program', '_scope', '_needs_rng',
-                 '_key0', '_fp', '_ro', '_ro_gen', '_flat', '_formats',
-                 'restages', 'relayouts', 'first_out', 'fetch_names',
-                 'example_feed')
+                 '_key0', '_ro', '_flat', '_formats', 'restages',
+                 'relayouts', 'first_out', 'fetch_names', 'example_feed')
 
-    def __init__(self, exe, entry, program, scope, needs_rng, example_feed,
-                 rw, key):
-        """`rw` and `key`: the read-written leaves and the run key of the
-        call that follows, for their shapes."""
-        self._exe = exe
-        self._entry = entry
-        self._program = program
-        self._scope = scope
+    def __init__(self, exe, entry, program, scope, needs_rng, example_feed):
+        self._exe, self._program, self._scope = exe, program, scope
         self._needs_rng = needs_rng
-        # fingerprint cached at bind: goodput keys the per-token decode
-        # dispatches on it without per-call hashing
-        self._fp = program._fingerprint()
         # RNG-free programs reuse one key — building a PRNGKey is itself
         # a device dispatch, pure waste for is_test decode steps
         self._key0 = jax.random.PRNGKey(program.random_seed or 0)
-        # what bind's own run fetched (Executor.bind sets it)
-        self.first_out = None
+        # what bind's own run fetched (Executor.bind sets it), and the
+        # read-only leaves it took: a call that takes others has restaged
+        self.first_out = self._ro = None
         self.fetch_names = tuple(entry.fetch_names)
         # the PREPARED bind-time feed (LoD tuples flattened, dtypes
         # normalized): callers that dispatch a constant feed every call —
@@ -738,34 +773,39 @@ class BoundProgram(object):
         self.example_feed = example_feed
         # how often a call found the scope written and staged again, and
         # how many leaves staging has laid out anew
-        self.restages = 0
-        self.relayouts = 0
+        self.restages = self.relayouts = 0
         self._flat, self._formats = entry.fn.flat, None
         if _layouts_offered():
-            self._compile(rw, key)
-        names = entry.fn.ro_names
+            self._compile(entry)
+        # the handle's own entry: the one `run()` looked up or built,
+        # called the handle's way, with a record of its own (the leaves
+        # as THIS executable wants them)
+        self._entry = own = copy.copy(entry)
+        own.call = self._flat
+        if self._formats is not None:
+            own.place = self._place
+        names = entry.ro_names
         scope._staged.update(zip(names, self._formats or [None] * len(names)))
-        self._stage()
 
-    def _compile(self, rw, key):
+    def _compile(self, entry):
         """The entry compiled for layouts: each read-only leaf as an
         earlier handle of this scope had it laid (`Scope._staged`), the
         compiler's choice for the others; `_formats` is what the
         executable asks for, a leaf."""
-        scope, fn = self._scope, self._entry.fn
+        scope, fn, program = self._scope, entry.fn, self._program
         fixed = tuple([scope._staged.get(n) for n in fn.ro_names])
-        hit = self._entry.bound.get(fixed)
+        hit = entry.bound.get(fixed)
         if hit is None:
-            ro = [self._exe._state_value(scope, n, self._program)
-                  for n in fn.ro_names]
+            ro = self._exe._walk(scope, entry, program, fn.ro_names)
+            rw = self._exe._walk(scope, entry, program, fn.rw_names, False)
             asked = [f or _open_format(v) for f, v in zip(fixed, ro)]
             lowered = fn.lower_bound(
                 jax.tree_util.tree_map(_spec, self.example_feed),
-                tuple(map(_spec, ro)), tuple(map(_spec, rw)), _spec(key),
-                asked)
-            with coldstart.stage('compile', self._program):
+                tuple(map(_spec, ro)), tuple(map(_spec, rw)),
+                _spec(self._key0), asked)
+            with coldstart.stage('compile', program):
                 compiled = lowered.compile()
-            hit = self._entry.bound[fixed] = (
+            hit = entry.bound[fixed] = (
                 compiled, tuple(compiled.input_formats[0][1]))
             # the analytics mine THIS lowering: registered first, it is
             # what the run's own registration finds, which would lower
@@ -773,43 +813,29 @@ class BoundProgram(object):
             # program of 24 layers, in every process' set-up)
             analysis.record_compiled(
                 types.SimpleNamespace(lower=lambda *avals: lowered),
-                self._program,
-                (self.example_feed, dict(zip(fn.ro_names, ro)),
-                 dict(zip(fn.rw_names, rw)), key), donate=bool(fn._donate))
+                program, (self.example_feed,) + _by_name(fn, ro, rw)
+                + (self._key0,), donate=bool(fn._donate))
         self._flat, self._formats = hit
 
-    def _stage(self):
-        """Stage the read-only state in the entry's order, each leaf in
-        the entry's format, and note the scope's write count it is good
-        for."""
-        scope, program = self._scope, self._program
-        state_value = self._exe._state_value
-        names = self._entry.fn.ro_names
-        ro = [state_value(scope, n, program) for n in names]
-        todo = [i for i, f in enumerate(self._formats or ())
-                if ro[i].format.layout != f.layout]
-        if todo:
-            # set-up's `place` stage: the host waits for each copy
-            with coldstart.stage('place', program), _compiled_here():
-                for i in todo:
-                    self._relay(ro, i)
-        ro = tuple(ro)
-        # read AFTER staging (an upload cached back into the scope is a
-        # write too) and BEFORE the comparison: a write that lands later
-        # moves the count, one that landed earlier fails the comparison
-        gen = scope._gen
-        # what the scope does not hold is a host value converted for this
-        # call alone: keep nothing, the next call converts it again
-        held = all(map(operator.is_, ro, map(scope.get, names)))
-        self._ro = ro
-        self._ro_gen = gen if held else None
+    def _place(self, scope, names, leaves, program):
+        """The entry's `place`: each read-only leaf in the executable's
+        format (the read-written ones have none and stay)."""
+        if names is self._entry.ro_names:
+            todo = [i for i, f in enumerate(self._formats)
+                    if leaves[i].format.layout != f.layout]
+            if todo:
+                # set-up's `place` stage: the host waits for each copy
+                with coldstart.stage('place', program), _compiled_here():
+                    for i in todo:
+                        self._relay(leaves, i)
+        return tuple(leaves)
 
     def _relay(self, ro, i):
         """Leaf `i` of `ro` put into the entry's format, in `ro` and —
         where the scope holds that very array — in the scope. One leaf
         at a time, the old one let go before the next: a relaid weight
         beside itself is HBM nobody has."""
-        name, fmt = self._entry.fn.ro_names[i], self._formats[i]
+        name, fmt = self._entry.ro_names[i], self._formats[i]
         held = self._scope.get(name) is ro[i]
         ro[i] = jax.block_until_ready(jax.device_put(ro[i], fmt))
         if ro[i].format.layout != fmt.layout:
@@ -821,55 +847,18 @@ class BoundProgram(object):
         self.relayouts += 1
 
     def __call__(self, feed, return_numpy=True):
-        entry = self._entry
-        scope = self._scope
-        if scope._gen != self._ro_gen:
+        exe, entry, scope = self._exe, self._entry, self._scope
+        rec = exe._take(scope, entry, self._program)
+        if rec.ro is not self._ro:
             monitor.inc('executor_bound_restage_total')
             self.restages += 1
-            self._stage()
-        ro = self._ro
-        state_value = self._exe._state_value
-        # cache=False: new_state rebinds these right after the call
-        rw_names = entry.fn.rw_names
-        rw = tuple([state_value(scope, n, self._program, cache=False)
-                    for n in rw_names])
-        if self._needs_rng:
-            self._exe._run_counter += 1
-            key_arr = _run_key(self._program.random_seed,
-                               _next_program_run(self._program),
-                               self._exe._run_counter)
-        else:
-            key_arr = self._key0
-        flat = self._flat
-
-        def _dispatch():
-            resilience.maybe_fault('run')
-            return flat(feed, ro, rw, key_arr)
-        t_disp = time.perf_counter()
-        try:
-            fetches, new_state = _dispatch()
-        except Exception as e:          # noqa: BLE001 — classified inside
-            fetches, new_state = resilience.retry_after(
-                e, _dispatch, site='run',
-                state=dict(zip(rw_names, rw)))
-            # failed attempts + backoff sleeps are the retry_backoff
-            # loss bucket, not device-busy: restart the window at the
-            # successful dispatch so the completer's serial attribution
-            # only covers real execute
-            t_disp = time.perf_counter()
-        goodput.note_dispatch(self._fp, 'bound', t_disp,
-                              time.perf_counter(),
-                              leaf=_goodput_leaf(new_state, fetches))
-        scope.update(new_state)
-        from . import flags as _flags
-        if _flags.get_flags('check_nan_inf'):
-            # same program-boundary check as run(); the op-level
-            # localization replay is a run() feature — rebind through
-            # run() to localize a poisoned step
-            _check_nan_inf(new_state, dict(zip(self.fetch_names, fetches)))
-        if return_numpy:
-            return [np.asarray(f) for f in fetches]
-        return list(fetches)
+            self._ro = rec.ro
+        key_arr = exe._next_key(self._program) if self._needs_rng \
+            else self._key0
+        fetches, _ = exe._commit(
+            scope, entry, self._program, rec,
+            *exe._call(entry, feed, rec, key_arr))
+        return exe._fetch(entry, fetches, return_numpy)
 
 
 class StepFuture(object):
@@ -1224,10 +1213,18 @@ class Executor(object):
         static_names = self._static_feed_names(program)
         static_feed = {n: np.asarray(feed[n]) for n in static_names
                        if n in feed}
+        return (feed, fetch_names, static_feed,
+                self._static_lods(scope, feed_lods))
+
+    @staticmethod
+    def _static_lods(scope, feed_lods):
+        """Scope-held LoD state binds statically, like a feed's LoD — and
+        is part of the cache key, or a compile baked with a stale scope
+        LoD would be reused after the scope's LoD changes."""
         static_lods = {n: normalize_lod(l)
                        for n, l in getattr(scope, '_lods', {}).items() if l}
         static_lods.update(feed_lods)
-        return feed, fetch_names, static_feed, static_lods
+        return static_lods
 
     @staticmethod
     def _static_feed_names(program):
@@ -1265,15 +1262,14 @@ class Executor(object):
                                return_numpy, use_program_cache, donate)
 
     def _run_plain(self, program, feed, fetch_list, scope, return_numpy,
-                   use_program_cache, donate, _call=None):
+                   use_program_cache, donate):
         """run() of a plain Program, instrumented: 'run' span + per-run
         wall-latency histogram (the delegating paths of run() recurse into
         it and would double-count). The counter counts ATTEMPTS — a run
         that raises (nan check, bad feed) must not vanish from the rate.
         step_scope: a bare run with no ambient trace may start its own
         head-sampled 'step' trace (PADDLE_TRACE_SAMPLE); the sampled-out
-        path costs one env read + one thread-local read + one random().
-        `_call`: see _run_impl."""
+        path costs one env read + one thread-local read + one random()."""
         with trace_mod.step_scope('step'):
             with monitor.timed_span('run', 'executor_run_seconds'):
                 monitor.inc('executor_run_total')
@@ -1283,9 +1279,10 @@ class Executor(object):
                     return analysis.run_profiled(self, program, feed,
                                                  fetch_list, scope,
                                                  return_numpy)
-                return self._run_impl(program, feed, fetch_list, scope,
-                                      return_numpy, use_program_cache,
-                                      donate, _call=_call)
+                entry, fetches, _ = self._run_impl(
+                    program, feed, fetch_list, scope, use_program_cache,
+                    donate)
+                return self._fetch(entry, fetches, return_numpy)
 
     # ------------------------------------------------------------------
     def run_async(self, program=None, feed=None, fetch_list=None,
@@ -1356,7 +1353,7 @@ class Executor(object):
         donate_override = donate
         if _donation_enabled(override=donate, record=False):
             donate_override = 'inflight'
-        sync_out = []
+        sync = None
         try:
             with trace_mod.activate(own):
                 with monitor.span('run_async'):
@@ -1373,11 +1370,21 @@ class Executor(object):
                                                      fetch_list, scope,
                                                      False)
                     else:
-                        outs = self._run_impl(program, feed, fetch_list,
-                                              scope, False,
-                                              use_program_cache,
-                                              donate_override,
-                                              _sync_out=sync_out)
+                        # the fetches stay on the device: a LoD-carrying
+                        # one is wrapped when the future materializes
+                        # (np.asarray here would block the submission on
+                        # the device step). What the future waits on: the
+                        # fetches and commit's token, one state leaf —
+                        # a fetch-less step still gives StepFuture.wait
+                        # something device-side to block on (the single
+                        # device stream orders everything else behind it)
+                        entry, fetches, token = self._run_impl(
+                            program, feed, fetch_list, scope,
+                            use_program_cache, donate_override)
+                        sync = (fetches, token)
+                        outs = [_DeferredFetch(f, entry.lod_out[n])
+                                if entry.lod_out.get(n) else f
+                                for n, f in zip(entry.fetch_names, fetches)]
         except Exception as e:      # noqa: BLE001 — delivered on the future
             with self._async_cv:
                 self._pending_submit -= 1
@@ -1396,8 +1403,7 @@ class Executor(object):
         stage_s = time.perf_counter() - t0
         if own is not None:
             own.add_stage('stage', stage_s)
-        fut = StepFuture(self, outs, sync=(outs, sync_out), trace=own,
-                         stage_s=stage_s)
+        fut = StepFuture(self, outs, sync=sync, trace=own, stage_s=stage_s)
         with self._async_cv:
             self._pending_submit -= 1
             self._inflight.append(fut)
@@ -1436,261 +1442,360 @@ class Executor(object):
             program._host_split_cache = cached
         return cached[1]
 
-    def _build_entry(self, program, feed, fetch_names, static_lods,
-                     static_feed, donate):
-        """Lower the program for this signature (the jitted function
-        compiles inside its first call)."""
-        def _build():
-            resilience.maybe_fault('compile')
-            read, written = lowering.analyze_state(program, fetch_names)
-            # only require state read before being written this run
-            needed = self._read_before_write(program, read, written,
-                                             set(feed), fetch_names)
-            lod_out = {}
-            fn, ro_names, rw_names = lowering.build_callable(
-                program, fetch_names, needed, written,
-                static_lods=static_lods, static_feed=static_feed,
-                lod_out=lod_out, donate=donate)
-            return _CompiledEntry(fn, fetch_names, ro_names, rw_names,
-                                  written, program, lod_out)
-        try:
-            return _build()
-        except Exception as e:      # noqa: BLE001 — classified inside
-            return resilience.retry_after(e, _build, site='compile')
-
-    def _run_impl(self, program, feed, fetch_list, scope, return_numpy,
-                  use_program_cache, donate_override=None, _sync_out=None,
-                  _call=None):
-        """One run, in phases (_run_phase): prepare, then dispatch (or
-        compile, on a signature's first run), commit, fetch. `_call`
-        (Executor.bind): what is called in place of the entry's function,
-        with the entry before its arguments."""
-        if scope is None:
-            scope = global_scope()
-        with _run_phase('prepare'):
-            feed, fetch_names, static_feed, static_lods = \
-                self._prepare_run_inputs(program, feed, scope, fetch_list)
-
-            if os.environ.get('PADDLE_SEGMENT_HOST_OPS') == '1' \
-                    and self._host_splittable(program):
-                with _run_phase('segmented'):
-                    return self._run_segmented(
-                        program, feed, fetch_names, scope, return_numpy,
-                        static_lods, static_feed, donate_override)
-
-            if donate_override is None \
-                    and analysis.nan_localization_enabled():
-                from . import flags as _flags
-                if _flags.get_flags('check_nan_inf'):
-                    # the opt-in provenance replay re-runs this step
-                    # against the PRE-run state, so its buffers must
-                    # survive the call
-                    donate_override = False
-            donate = _donation_enabled(override=donate_override)
-            key = (program._fingerprint(),
-                   self._feed_signature(feed, static_lods, static_feed),
-                   tuple(fetch_names), donate)
-            entry = self._cache_get(key) if use_program_cache else None
-            fresh_compile = entry is None
-            if fresh_compile:
-                monitor.inc('compile_cache_miss' if use_program_cache
-                            else 'compile_cache_bypass')
-                t_compile = time.perf_counter()
-                # wired at first compile, not Executor construction:
-                # building an executor must stay free of backend
-                # initialization (io-only executors, launcher parents
-                # that must not claim the chip)
-                _wire_persistent_cache()
-                with coldstart.stage('trace', program, *_RUN_COMPILE):
-                    entry = self._build_entry(program, feed, fetch_names,
-                                              static_lods, static_feed,
-                                              donate)
-                if use_program_cache:
-                    self._cache_put(key, entry)
-            else:
-                monitor.inc('compile_cache_hit')
-
-            # the state, flat and in the entry's order: what this entry's
-            # last run on the scope left (nothing wrote since), or every
-            # leaf looked up, uploaded where the host wrote it
-            fn = entry.fn
-            state = None if fresh_compile or _call is not None \
-                else _carried_state(scope, entry)
-            if state is None:
-                state = (tuple([self._state_value(scope, n, program)
-                                for n in fn.ro_names]),
-                         tuple([self._state_value(scope, n, program,
-                                                  cache=False)
-                                for n in fn.rw_names]))
-            ro, rw = state
-            del state
-            self._run_counter += 1
-            key_arr = _run_key(program.random_seed,
-                               _next_program_run(program),
-                               self._run_counter)
-            # the step's PRNG key, kept for debug replays (TrainingGuard's
-            # NaN-provenance pass must reproduce the failed step's
-            # randomness)
-            program._last_run_key = key_arr
-            blackbox.note_step(program)
-        ro_state = rw_state = None
-        if fresh_compile or _call is not None:
-            # by name: a signature's first call goes the way it always
-            # went, and so does bind's
-            ro_state, rw_state = _by_name(fn, ro, rw)
-            call = fn if _call is None else functools.partial(_call, entry)
-            if _call is not None:
-                # bind's handle lays the read-only leaves out one at a
-                # time, each let go before the next: `ro_state` is the
-                # run's one hold on them
-                ro = None
-        if fresh_compile:
-            frame = _compile_frame(program, since=t_compile)
-            fetches, new_state = _first_call(
-                frame, rw_state, call, feed, ro_state, rw_state, key_arr)
-            goodput.note_compile(key[0], frame.seconds)
-            # register the executable for XLA cost/memory analytics
-            # (lazy: mined when snapshot/explain/costreport first looks)
-            analysis.record_compiled(entry.fn, program,
-                                     (feed, ro_state, rw_state, key_arr),
-                                     kind='run', donate=donate)
-        else:
-            with _run_phase('dispatch'):
-                # steady-state dispatch: the success path pays one
-                # fault-site check and a try frame; retry machinery engages
-                # only after an exception actually escaped (and never with
-                # consumed donated buffers — resilience._buffers_alive
-                # guards the re-invoke)
-                def _dispatch():
-                    resilience.maybe_fault('run')
-                    if _call is None:
-                        return fn.flat(feed, ro, rw, key_arr)
-                    return call(feed, ro_state, rw_state, key_arr)
-                t_disp = time.perf_counter()
-                try:
-                    fetches, new_state = _dispatch()
-                except Exception as e:  # noqa: BLE001 — classified inside
-                    fetches, new_state = resilience.retry_after(
-                        e, _dispatch, site='run',
-                        state=dict(zip(fn.rw_names, rw)))
-                    t_disp = time.perf_counter()    # exclude retry backoff
-                t_staged = time.perf_counter()
-        with _run_phase('commit'):
-            if not fresh_compile:
-                # goodput accounting: fresh compiles land in the 'compile'
-                # loss bucket instead, keeping execute baselines clean
-                goodput.note_dispatch(key[0], 'run', t_disp, t_staged,
-                                      leaf=_goodput_leaf(new_state, fetches))
-            if os.environ.get('PADDLE_OPTEST_COLLECT_DIR'):
-                # TPU second-place validation (reference op_test.py:304
-                # check_output_with_place / the mkldnn-suite reuse pattern):
-                # record executed (program, feed, state, key, CPU fetches)
-                # cases for tools/tpu_optest.py to replay on the real chip
-                from .core.optest_collect import record_case
-                if ro_state is None:
-                    ro_state, rw_state = _by_name(fn, ro, rw)
-                record_case(program, feed, static_lods, ro_state, rw_state,
-                            key_arr, fetch_names, fetches)
-            # rebind the scope BEFORE the nan-check can raise: with
-            # donation on, the pre-run rw buffers are already consumed, so
-            # bailing out here would leave the scope pointing at deleted
-            # arrays — a NaN state is at least readable/checkpointable for
-            # debugging
-            scope.update(new_state)
-            if _sync_out is not None and new_state:
-                # one state leaf as the async completion token: fetch-less
-                # steps still give StepFuture.wait something device-side to
-                # block on (the single device stream orders everything else
-                # behind it)
-                _sync_out.append(next(iter(new_state.values())))
+    def _entry_key(self, program, feed, static_lods, static_feed,
+                   fetch_names, donate=None, kind=(), record=True):
+        """THE compile-cache key of (program, prepared feed, static LoDs
+        and feeds, fetch names, donation) for an entry of `kind` — () a
+        run's, and so bind's, precompile's, explain's and the warm
+        farm's; ('hostseg',) a segmented run's plan; ('fused', staged,
+        steps) a fused window; ('mesh', ...) a runner's, in the runner's
+        own cache. `donate` is the caller's override: the policy
+        resolves here (`_donation_enabled`; record=False for a QUERY —
+        an AOT pass, a report, a runner whose donation is its jit's —
+        that must not move the donation rates), behind the provenance
+        replay's force-off: PADDLE_NAN_LOCALIZE re-runs a step that trips
+        FLAGS_check_nan_inf against its PRE-run state, so its buffers
+        must survive the call."""
+        if donate is None and analysis.nan_localization_enabled():
             from . import flags as _flags
             if _flags.get_flags('check_nan_inf'):
-                try:
-                    _check_nan_inf(new_state,
-                                   dict(zip(entry.fetch_names, fetches)))
-                except RuntimeError as e:
-                    # PADDLE_NAN_LOCALIZE=1: replay the step op-by-op
-                    # against the still-alive pre-run state and name the
-                    # first op that produced a non-finite value (no-op when
-                    # disabled)
-                    if ro_state is None:
-                        ro_state, rw_state = _by_name(fn, ro, rw)
-                    info = analysis.localize_nonfinite(
-                        program, feed, ro_state, rw_state, key_arr,
-                        static_lods, static_feed)
-                    if info is not None:
-                        err = RuntimeError('%s; %s' % (
-                            e, analysis.format_localization(info)))
-                        # carried for TrainingGuard: the guard must reuse
-                        # this localization, not pay a second replay (and
-                        # double-count nonfinite_localized_total)
-                        err.nonfinite_localization = info
-                        raise err from None
+                donate = False
+        return kind + (program._fingerprint(),
+                       self._feed_signature(feed, static_lods, static_feed),
+                       tuple(fetch_names),
+                       _donation_enabled(override=donate, record=record))
+
+    def _find(self, key, program, build, cache=None,
+              missed='compile_cache_miss', book=_RUN_COMPILE):
+        """The entry under `key` — in this executor's cache and the
+        process-wide one behind it, or in `cache` (a runner's own) —
+        and None; or, made by `build()` now (set-up's `trace` stage,
+        the 'compile' site's faults and retries), the new entry and
+        when its making began: its first call is the compile."""
+        entry = self._cache_get(key) if cache is None else cache.get(key)
+        if entry is not None:
+            monitor.inc('compile_cache_hit')
+            return entry, None
+        monitor.inc(missed)
+        since = time.perf_counter()
+        # wired at first compile, not Executor construction: building an
+        # executor must stay free of backend initialization (io-only
+        # executors, launcher parents that must not claim the chip)
+        _wire_persistent_cache()
+
+        def attempt():
+            resilience.maybe_fault('compile')
+            return build()
+        with coldstart.stage('trace', program, *book):
+            try:
+                entry = attempt()
+            except Exception as e:      # noqa: BLE001 — classified inside
+                entry = resilience.retry_after(e, attempt, site='compile')
+        if cache is None:
+            self._cache_put(key, entry)
+        else:
+            cache[key] = entry
+        return entry, since
+
+    def _build_entry(self, program, feed, fetch_names, static_lods,
+                     static_feed, donate, **how):
+        """Lower the program for this signature (the jitted function
+        compiles inside its first call). `how`: the `lower_params` of a
+        segment, what its `_CompiledEntry` is told."""
+        read, written = lowering.analyze_state(program, fetch_names)
+        # only require state read before being written this run
+        needed = self._read_before_write(program, read, written,
+                                         set(feed), fetch_names)
+        lod_out = {}
+        fn, _, _ = lowering.build_callable(
+            program, fetch_names, needed, written,
+            static_lods=static_lods, static_feed=static_feed,
+            lod_out=lod_out, donate=donate,
+            lower_params=how.pop('lower_params', None))
+        return _CompiledEntry(fn, fetch_names, written, program, lod_out,
+                              **how)
+
+    def _run_impl(self, program, feed, fetch_list, scope, use_program_cache,
+                  donate_override=None):
+        """One run up to its fetches, still on the device: (the entry —
+        its fetch names and LoDs, for `_fetch` —, the fetches, commit's
+        completion token)."""
+        if scope is None:
+            scope = global_scope()
+        if os.environ.get('PADDLE_SEGMENT_HOST_OPS') == '1' \
+                and self._host_splittable(program):
+            with _run_phase('prepare'):
+                feed, fetch_names, static_feed, static_lods = \
+                    self._prepare_run_inputs(program, feed, scope,
+                                             fetch_list)
+                with _run_phase('segmented'):
+                    return self._run_segmented(
+                        program, feed, fetch_names, scope, static_lods,
+                        static_feed, donate_override)
+        cache, missed = (None, 'compile_cache_miss') if use_program_cache \
+            else ({}, 'compile_cache_bypass')
+        fetch_names = static_feed = static_lods = None
+
+        def find():
+            nonlocal fetch_names, static_feed, static_lods
+            feed2, fetch_names, static_feed, static_lods = \
+                self._prepare_run_inputs(program, feed, scope, fetch_list)
+            key = self._entry_key(program, feed2, static_lods, static_feed,
+                                  fetch_names, donate_override)
+            return self._find(
+                key, program, lambda: self._build_entry(
+                    program, feed2, fetch_names, static_lods, static_feed,
+                    key[-1]), cache, missed) + (feed2,)
+
+        def collect(entry, feed2, rec, key_arr, fetches):
+            # TPU second-place validation (reference op_test.py:304
+            # check_output_with_place / the mkldnn-suite reuse pattern):
+            # record executed (program, feed, state, key, CPU fetches)
+            # cases for tools/tpu_optest.py to replay on the real chip
+            from .core.optest_collect import record_case
+            record_case(program, feed2, static_lods,
+                        *_by_name(entry.fn, rec.ro, rec.rw), key_arr,
+                        fetch_names, fetches)
+
+        def localize(feed2, key_arr, e, ro_state, rw_state):
+            # PADDLE_NAN_LOCALIZE=1: replay the step op-by-op against the
+            # still-alive pre-run state and name the first op that
+            # produced a non-finite value (no-op when disabled)
+            info = analysis.localize_nonfinite(
+                program, feed2, ro_state, rw_state, key_arr, static_lods,
+                static_feed)
+            if info is None:
+                return e
+            err = RuntimeError('%s; %s' % (
+                e, analysis.format_localization(info)))
+            # carried for TrainingGuard: the guard must reuse this
+            # localization, not pay a second replay (and double-count
+            # nonfinite_localized_total)
+            err.nonfinite_localization = info
+            return err
+        return self._step(
+            scope, program, find, localize,
+            collect if os.environ.get('PADDLE_OPTEST_COLLECT_DIR') else None)
+
+    # ------------------------------------------------------------------
+    # the step: one compiled call on a scope
+    def _step(self, scope, program, find, localize=None, collect=None,
+              phase=_run_phase, book=_RUN_COMPILE):
+        """One compiled call in Executor.run's phases (_run_phase) —
+        `run`'s, `bind`'s first and the SPMD runners': prepare — `find()`
+        (the caller's feed preparation and checks, the key, the entry:
+        `_find`'s pair and the prepared feed), the state, the run key,
+        the flight recorder's step note; dispatch — the call (or compile,
+        on a signature's first: set-up's frame); commit. Returns (entry,
+        fetches on the device, commit's completion token): `_fetch` is
+        the fetch phase. `phase`, `book`: none for a fused window, which
+        is no run."""
+        with phase('prepare'):
+            entry, since, feed = find()
+            rec = self._take(scope, entry, program)
+            key_arr = self._next_key(program)
+            blackbox.note_step(program)
+        if since is not None:
+            frame = coldstart.compile_frame(program, 'first_run', since,
+                                            *book)
+            fetches, new_state = _first_call(frame, entry, feed, rec,
+                                             key_arr)
+            self._compiled(entry, program, feed, rec, key_arr, frame)
+            # goodput accounting: fresh compiles land in the 'compile'
+            # loss bucket instead, keeping execute baselines clean
+            times = None
+        else:
+            with phase('dispatch'):
+                fetches, new_state, times = self._call(entry, feed, rec,
+                                                       key_arr)
+        with phase('commit'):
+            if collect is not None:
+                collect(entry, feed, rec, key_arr, fetches)
+            return (entry,) + self._commit(
+                scope, entry, program, rec, fetches, new_state, times,
+                localize and functools.partial(localize, feed, key_arr))
+
+    @staticmethod
+    def _compiled(entry, program, feed, rec, key_arr, frame):
+        """A new entry's first call is behind it: the compile counts for
+        the recompile-storm sentinel, and the executable registers for
+        XLA cost/memory analytics (lazy: mined when snapshot / explain /
+        costreport first looks) under the kind its dispatches are booked
+        as — none for a segment's clone."""
+        goodput.note_compile(entry.fp, frame.seconds)
+        kind = goodput._ANALYSIS_KIND.get(entry.kind)
+        if kind is not None:
+            analysis.record_compiled(
+                entry.fn, program,
+                (feed,) + _by_name(entry.fn, rec.ro, rec.rw) + (key_arr,),
+                kind=kind, donate=bool(entry.fn._donate), steps=entry.steps)
+
+    def _walk(self, scope, entry, program, names, cache=True):
+        """`names` of `entry`'s state looked up in the scope
+        (`_state_value`: uploaded where the host wrote it; cache=False
+        for the read-written ones, which the run rebinds) and put where
+        the entry wants them: a tuple in `names`' order."""
+        return entry.place(scope, names,
+                           [self._state_value(scope, n, program, cache=cache)
+                            for n in names], program)
+
+    def _take(self, scope, entry, program):
+        """*take*: the entry's state from the scope, as a `_Held` — the
+        record the entry's last call left (`_commit`) as far as it still
+        holds, else the walk. The record is TAKEN: a call that raises
+        leaves none, and the donated leaves are the step's alone to let
+        go. `executor_run_carried_total` counts the takes that looked
+        nothing up."""
+        rec = scope._held.pop(entry, None)
+        if rec is scope._fresh:
+            scope._fresh = None
+        if rec is None or rec.gen != scope._gen:
+            names = entry.ro_names
+            staged = scope._staged
+            for n in names:
+                staged.setdefault(n)
+            ro = self._walk(scope, entry, program, names)
+            rw = self._walk(scope, entry, program, entry.rw_names, False)
+            # read AFTER the walk (an upload cached back into the scope
+            # is a write too) and BEFORE the comparison: a write that
+            # lands later moves the count, one that landed earlier fails
+            # the comparison. What the scope does not hold is a host
+            # value converted for this call alone: nothing is kept
+            gen = scope._gen
+            held = all(map(operator.is_, ro, map(scope.get, names)))
+            rec = _Held(ro, gen if held else None, rw)
+        elif rec.rw is None or rec.writes != scope._writes:
+            rec.rw = self._walk(scope, entry, program, entry.rw_names, False)
+        else:
+            monitor.inc('executor_run_carried_total')
+        return rec
+
+    def _next_key(self, program):
+        """The run key (`_run_key`), the counters it is made of moved."""
+        self._run_counter += 1
+        key_arr = _run_key(program.random_seed, _next_program_run(program),
+                           self._run_counter)
+        # the step's PRNG key, kept for debug replays (TrainingGuard's
+        # NaN-provenance pass must reproduce the failed step's
+        # randomness)
+        program._last_run_key = key_arr
+        return key_arr
+
+    @staticmethod
+    def _call(entry, feed, rec, key_arr):
+        """*call*: the entry's compiled function on the taken state —
+        (fetches, new state, when the dispatch began and ended). THE
+        'run' fault site. The success path pays one fault-site check and
+        a try frame; retry machinery engages only after an exception
+        actually escaped (and never with consumed donated buffers —
+        resilience._buffers_alive guards the re-invoke)."""
+        ro, rw = rec.ro, rec.rw
+
+        def attempt():
+            resilience.maybe_fault('run')
+            return entry.call(feed, ro, rw, key_arr)
+        t_disp = time.perf_counter()
+        try:
+            fetches, new_state = attempt()
+        except Exception as e:          # noqa: BLE001 — classified inside
+            fetches, new_state = entry.retry(
+                e, attempt, site='run', state=dict(zip(entry.rw_names, rw)))
+            # failed attempts + backoff sleeps are the retry_backoff
+            # loss bucket, not device-busy: restart the window at the
+            # successful dispatch so the completer's serial attribution
+            # only covers real execute
+            t_disp = time.perf_counter()
+        return fetches, new_state, (t_disp, time.perf_counter())
+
+    def _commit(self, scope, entry, program, rec, fetches, new_state,
+                times=None, localize=None):
+        """*commit*: what `_call` returned lands. The dispatch is booked
+        to goodput under the entry's kind (`times`: None for a first
+        call, a compile, and for a host segment); the scope is rebound;
+        FLAGS_check_nan_inf checks, `localize(e, ro_state, rw_state)`
+        giving the error to raise where the caller asked; FLAGS_benchmark
+        waits; the record for the entry's next take is left; LoDs and
+        checkpoint_notify follow. Returns (the fetches, sparse ones made
+        dense; one state leaf as a completion token, or None)."""
+        if times is not None:
+            goodput.note_dispatch(entry.fp, entry.kind, times[0], times[1],
+                                  leaf=_goodput_leaf(new_state, fetches),
+                                  steps=entry.steps)
+        # rebind the scope BEFORE the nan-check can raise: with
+        # donation on, the pre-run rw buffers are already consumed, so
+        # bailing out here would leave the scope pointing at deleted
+        # arrays — a NaN state is at least readable/checkpointable for
+        # debugging
+        gen = scope._gen
+        scope.update(new_state)
+        from . import flags as _flags
+        if _flags.get_flags('check_nan_inf'):
+            try:
+                _check_nan_inf(new_state,
+                               dict(zip(entry.fetch_names, fetches)),
+                               entry.to_host)
+            except RuntimeError as e:
+                if localize is None:
                     raise
-            if _flags.get_flags('benchmark'):
-                # block on the new state too: timing only fetches
-                # under-measures steps whose outputs are all state writes
-                # (pure-train steps fetching just a scalar loss, or nothing
-                # at all). The synced wait lands in the
-                # executor_sync_seconds histogram — the device-completion
-                # tail FLAGS_benchmark exists to expose
-                t_sync = time.perf_counter()
-                with _run_phase('fetch'):
-                    jax.block_until_ready((fetches, new_state))
-                monitor.observe('executor_sync_seconds',
-                                time.perf_counter() - t_sync)
-            if _call is None:
-                _carry_state(scope, entry, ro, new_state)
-            # the donated inputs are let go HERE, behind the dispatch and
-            # while the device is busy, not as the frame exits behind the
-            # fetch's wait: a thousand arrays take their time to go
-            del ro, rw, ro_state, rw_state
-            # checkpoint_notify (ops/dist_ops.py): the reference RPCs the
-            # checkpoint dir to pservers each execution; here the executor
-            # is the checkpoint writer, so save persistables after the run
-            for cn_dir in entry.notify_dirs:
-                from .io import save_persistables
-                with scope_guard(scope):
-                    save_persistables(self, cn_dir, main_program=program)
-            # propagate LoD of written persistables into the scope, and of
-            # fetches into the returned tensors (nothing to do, a name,
-            # where the entry gives no LoD and the scope holds none)
-            if entry.lod_out or scope._lods:
-                for n in entry.written:
-                    lod = entry.lod_out.get(n)
-                    if lod:
-                        scope._lods[n] = lod
-                    else:
-                        scope._lods.pop(n, None)
-            from .core.selected_rows import SelectedRows
-            # fetched sparse grads densify, like the reference's fetch of
-            # a SelectedRows var materializing a tensor
-            fetches = [f.to_dense() if isinstance(f, SelectedRows) else f
-                       for f in fetches]
-        if return_numpy:
+                raise localize(
+                    e, *_by_name(entry.fn, rec.ro, rec.rw)) from None
+        if _flags.get_flags('benchmark'):
+            # block on the new state too: timing only fetches
+            # under-measures steps whose outputs are all state writes
+            # (pure-train steps fetching just a scalar loss, or nothing
+            # at all). The synced wait lands in executor_sync_seconds —
+            # the device-completion tail FLAGS_benchmark exists to expose
+            t_sync = time.perf_counter()
             with _run_phase('fetch'):
-                return [
-                    _fetched(f, entry.lod_out[n])
-                    if entry.lod_out.get(n) else np.asarray(f)
-                    for n, f in zip(entry.fetch_names, fetches)
-                ]
-        # return_numpy=False keeps fetches device-resident (no host sync);
-        # only lod-carrying results are wrapped, since the LoD metadata is
-        # the point of asking for them. Under async dispatch the wrap is
-        # deferred (np.asarray here would block the submission on the
-        # device step); the raw array joins the completion token list so
-        # StepFuture.wait covers it
-        out = []
-        for n, f in zip(entry.fetch_names, fetches):
-            lod = entry.lod_out.get(n)
-            if not lod:
-                out.append(f)
-            elif _sync_out is None:
-                out.append(_fetched(f, lod))
-            else:
-                _sync_out.append(f)
-                out.append(_DeferredFetch(f, lod))
-        return out
+                jax.block_until_ready((fetches, new_state))
+            monitor.observe('executor_sync_seconds',
+                            time.perf_counter() - t_sync)
+        # the record for the next take: the read-only leaves this call
+        # was given — the entry's own rebind, which writes none of them,
+        # is the one write they survive — and the read-written ones it
+        # returned. The donated inputs are let go HERE, behind the
+        # dispatch and while the device is busy, not as the frames exit
+        # behind the fetch's wait: a thousand arrays take their time
+        rec.rw = None
+        if rec.gen is not None and rec.gen == gen:
+            rec.gen, rec.writes = scope._gen, scope._writes
+            rec.rw = tuple(map(new_state.__getitem__, entry.rw_names))
+            entry.keep(scope, entry, rec)
+        # checkpoint_notify (ops/dist_ops.py): the reference RPCs the
+        # checkpoint dir to pservers each execution; here the executor
+        # is the checkpoint writer, so save persistables after the run
+        for cn_dir in entry.notify_dirs:
+            from .io import save_persistables
+            with scope_guard(scope):
+                save_persistables(self, cn_dir, main_program=program)
+        # propagate LoD of written persistables into the scope (nothing
+        # to do where the entry gives no LoD and the scope holds none)
+        if entry.lod_out or scope._lods:
+            for n in entry.written:
+                lod = entry.lod_out.get(n)
+                if lod:
+                    scope._lods[n] = lod
+                else:
+                    scope._lods.pop(n, None)
+        from .core.selected_rows import SelectedRows
+        # fetched sparse grads densify, like the reference's fetch of
+        # a SelectedRows var materializing a tensor
+        return ([f.to_dense() if isinstance(f, SelectedRows) else f
+                 for f in fetches],
+                next(iter(new_state.values()), None))
+
+    @staticmethod
+    def _fetch(entry, fetches, return_numpy):
+        """*fetch*: the fetches as the caller asked for them — on the
+        host (the `fetch` phase: the wait for the device), or left on the
+        device (no host sync); a LoD-carrying one is wrapped either way,
+        since the LoD metadata is the point of asking for it."""
+        host, lods = entry.to_host, entry.lod_out
+        with _run_phase('fetch') if return_numpy \
+                else contextlib.nullcontext():
+            return [_fetched(host(f), lods[n]) if lods.get(n)
+                    else host(f) if return_numpy else f
+                    for n, f in zip(entry.fetch_names, fetches)]
 
     # ------------------------------------------------------------------
     def _segment_plan(self, program, fetch_names):
@@ -1755,18 +1860,18 @@ class Executor(object):
                          'later_written': later_written})
         return plan
 
-    def _run_segmented(self, program, feed, fetch_names, scope,
-                       return_numpy, static_lods, static_feed,
-                       donate_override=None):
+    def _run_segmented(self, program, feed, fetch_names, scope, static_lods,
+                       static_feed, donate_override=None):
         """Heterogeneous execution for backends without host callbacks: see
         _HOST_SEGMENT_OPS. Device segments are compiled and cached like
         normal runs; host ops run eagerly on the CPU backend with only the
-        crossing vars transferred."""
+        crossing vars transferred. A part is the step's `_call` (a device
+        part) and `_commit`, not its `_take`: the parts of one run share
+        its ONE run key, and a part's state is walked by the plan's rule
+        (below) — so no record is kept either (`_Held.gen` None)."""
         monitor.inc('executor_run_segmented_total')
-        donate = _donation_enabled(override=donate_override)
-        key = ('hostseg', program._fingerprint(),
-               self._feed_signature(feed, static_lods, static_feed),
-               tuple(fetch_names), donate)
+        key = self._entry_key(program, feed, static_lods, static_feed,
+                              fetch_names, donate_override, ('hostseg',))
         plan = self._cache_get(key)
         if plan is None:
             monitor.inc('compile_cache_miss')
@@ -1775,19 +1880,15 @@ class Executor(object):
         else:
             monitor.inc('compile_cache_hit')
 
-        self._run_counter += 1
-        key_arr = _run_key(program.random_seed, _next_program_run(program),
-                           self._run_counter)
-        # kept for debug replays, as in _run_impl (TrainingGuard's NaN
+        # kept for debug replays, as in a plain run (TrainingGuard's NaN
         # provenance must not fall back to PRNGKey(0) for host-op programs)
-        program._last_run_key = key_arr
+        key_arr = self._next_key(program)
         blackbox.note_step(program)
         val_env = dict(feed)
         lod_env = dict(static_lods)
         for seg in plan:
-            sub = seg['sub']
+            sub, host = seg['sub'], seg['kind'] == 'host'
             seg_feed = {n: v for n, v in val_env.items() if n in seg['ins']}
-            seg_fetch = list(seg['crossing'])
             entry = seg.get('entry')
             fresh = entry is None
             if fresh:
@@ -1795,31 +1896,19 @@ class Executor(object):
 
                 def _build_segment():
                     resilience.maybe_fault('compile')
-                    read, written = lowering.analyze_state(sub, seg_fetch)
-                    needed = self._read_before_write(
-                        sub, read, written, set(seg_feed), seg_fetch)
-                    lod_out = {}
                     # op_offset = the segment's slice start in the
                     # original block, so every op derives the SAME per-op
                     # PRNG key as the unsegmented program (rng streams
                     # must not depend on where host ops split the
                     # program, and two RNG ops at equal within-segment
                     # indices must not collide)
-                    if seg['kind'] == 'dev':
-                        fn, ro_names, rw_names = lowering.build_callable(
-                            sub, seg_fetch, needed, written,
-                            static_lods=lod_env, static_feed=static_feed,
-                            lod_out=lod_out, donate=donate,
-                            lower_params={'op_offset': seg['lo']})
-                    else:
-                        fn, ro_names, rw_names = lowering.build_fn(
-                            sub, seg_fetch, needed, written,
-                            static_lods=lod_env, static_feed=static_feed,
-                            lod_out=lod_out,
-                            lower_params={'host_eager': True,
-                                          'op_offset': seg['lo']})
-                    return _CompiledEntry(fn, seg_fetch, ro_names,
-                                          rw_names, written, sub, lod_out)
+                    return self._build_entry(
+                        sub, seg_feed, list(seg['crossing']), lod_env,
+                        static_feed, key[-1] and not host, kind='segmented',
+                        fp=key[1],      # booked to the whole program
+                        lower_params=dict({'op_offset': seg['lo']},
+                                          **({'host_eager': True}
+                                             if host else {})))
                 # segment build cost (the jit compile itself is lazy and
                 # lands in this segment's first call below, a set-up
                 # frame of its own; device-segment granularity is close
@@ -1830,6 +1919,10 @@ class Executor(object):
                     except Exception as e:  # noqa: BLE001 — classified inside
                         entry = resilience.retry_after(e, _build_segment,
                                                        site='compile')
+                # the program's checkpoint_notify saves follow the LAST
+                # part's commit
+                entry.notify_dirs = _notify_dirs(program) \
+                    if seg is plan[-1] else []
                 seg['entry'] = entry
                 goodput.note_compile(key[1], frame.seconds)
             # cache=False also for names a LATER segment writes: caching
@@ -1837,12 +1930,13 @@ class Executor(object):
             # though the scope is rebound right after that later segment —
             # the rw-path exemption applies program-wide, not per-segment
             later_w = seg.get('later_written', ())
-            ro = {n: self._state_value(scope, n, program,
-                                       cache=n not in later_w)
-                  for n in entry.ro_names}
-            rw = {n: self._state_value(scope, n, program, cache=False)
-                  for n in entry.rw_names}
-            if seg['kind'] == 'host':
+            rec = _Held(
+                tuple([self._state_value(scope, n, program,
+                                         cache=n not in later_w)
+                       for n in entry.ro_names]), None,
+                tuple([self._state_value(scope, n, program, cache=False)
+                       for n in entry.rw_names]))
+            if host:
                 # transfer only the crossing vars; run the op eagerly —
                 # callbacks execute immediately (host-side) outside of jit.
                 # Pin the tiny surrounding math to the CPU backend; where
@@ -1850,8 +1944,8 @@ class Executor(object):
                 # default device (the callback itself runs on host either
                 # way)
                 seg_feed = {n: np.asarray(v) for n, v in seg_feed.items()}
-                ro = {n: np.asarray(v) for n, v in ro.items()}
-                rw = {n: np.asarray(v) for n, v in rw.items()}
+                rec.ro = tuple(map(np.asarray, rec.ro))
+                rec.rw = tuple(map(np.asarray, rec.rw))
                 try:
                     guard = jax.default_device(
                         jax.local_devices(backend='cpu')[0])
@@ -1861,7 +1955,8 @@ class Executor(object):
                 def _host_dispatch():
                     resilience.maybe_fault('host_relay')
                     with guard:
-                        return entry.fn(seg_feed, ro, rw, key_arr)
+                        return entry.fn._fn(seg_feed, rec.ro, rec.rw,
+                                            key_arr)
 
                 def _boundary_fault(e):
                     # host segments run callbacks with SIDE EFFECTS
@@ -1878,63 +1973,30 @@ class Executor(object):
                     fetches, new_state = resilience.retry_after(
                         e, _host_dispatch, site='host_relay',
                         retryable=_boundary_fault)
+                # host work, not device-productive: booked nowhere
+                times = None
             else:
-                def _seg_dispatch():
-                    resilience.maybe_fault('run')
-                    return entry.fn(seg_feed, ro, rw, key_arr)
-                t_disp = time.perf_counter()
+                # device segments contribute busy time (no flops: the
+                # per-segment clones don't register analytics)
                 with coldstart.stage('first_run', sub, *_RUN_COMPILE) \
                         if fresh else contextlib.nullcontext():
-                    try:
-                        fetches, new_state = _seg_dispatch()
-                    except Exception as e:  # noqa: BLE001 — classified inside
-                        fetches, new_state = resilience.retry_after(
-                            e, _seg_dispatch, site='run', state=rw)
-                        t_disp = time.perf_counter()  # exclude retry backoff
-                # device segments contribute busy time (no flops: the
-                # per-segment clones don't register analytics); host
-                # segments are host work, not device-productive
-                goodput.note_dispatch(
-                    key[1], 'segmented', t_disp, time.perf_counter(),
-                    leaf=_goodput_leaf(new_state, list(fetches)))
-            # scope rebinds before the nan-check for the same donated-buffer
-            # reason as run(): a raise must not strand deleted arrays
-            scope.update(new_state)
-            from . import flags as _flags
-            if _flags.get_flags('check_nan_inf'):
-                _check_nan_inf(new_state,
-                               dict(zip(entry.fetch_names, fetches)))
+                    fetches, new_state, times = self._call(
+                        entry, seg_feed, rec, key_arr)
+            # the crossing values go on as they are (a sparse gradient
+            # stays sparse for the part that applies it)
+            self._commit(scope, entry, program, rec, fetches, new_state,
+                         times)
             val_env.update(zip(entry.fetch_names, fetches))
             lod_env.update(entry.lod_out)
-            # written-persistable LoD lands in the scope exactly as in
-            # run(): set when the segment produced one, cleared otherwise
-            for n in entry.written:
-                lod = entry.lod_out.get(n)
-                if lod:
-                    scope._lods[n] = lod
-                else:
-                    scope._lods.pop(n, None)
-
-        from .io import save_persistables
-        for seg in plan:
-            for cn_dir in seg['entry'].notify_dirs:
-                with scope_guard(scope):
-                    save_persistables(self, cn_dir, main_program=program)
 
         from .core.selected_rows import SelectedRows
-        out = []
-        for n in fetch_names:
-            if n in val_env:
-                v = val_env[n]
-            else:
-                v = self._state_value(scope, n, program)
-            if isinstance(v, SelectedRows):
-                v = v.to_dense()
-            lod = lod_env.get(n)
-            if return_numpy or lod:
-                v = _fetched(v, lod) if lod else np.asarray(v)
-            out.append(v)
-        return out
+        out = [val_env[n] if n in val_env
+               else self._state_value(scope, n, program)
+               for n in fetch_names]
+        return (types.SimpleNamespace(fetch_names=fetch_names,
+                                      lod_out=lod_env, to_host=np.asarray),
+                [v.to_dense() if isinstance(v, SelectedRows) else v
+                 for v in out], None)
 
     # ------------------------------------------------------------------
     def run_fused(self, program=None, feed_list=None, fetch_list=None,
@@ -2055,42 +2117,20 @@ class Executor(object):
         fetch_names = [v.name if isinstance(v, Variable) else v
                        for v in (fetch_list or [])]
 
-        # scope-held LoD state binds statically too, like run() — and like
-        # run() it must be part of the cache key, or a compile baked with
-        # a stale scope LoD would be reused after the scope's LoD changes
-        scope_lods = {n: normalize_lod(l) for n, l in
-                      getattr(scope, '_lods', {}).items() if l}
-        static_lods = dict(scope_lods)
-        static_lods.update(lods0)
-
+        static_lods = self._static_lods(scope, lods0)
         n_steps = int(steps) if steps else k_steps
-        donate = _donation_enabled(fused=True, override=donate_override)
-        cache_key = ('fused', k_steps, n_steps, program._fingerprint(),
-                     self._feed_signature(feed0, static_lods, ()),
-                     tuple(fetch_names), donate)
-        entry = self._cache_get(cache_key)
-        fresh_compile = entry is None
-        if fresh_compile:
-            monitor.inc('compile_cache_miss')
-            t_compile = time.perf_counter()
-            _wire_persistent_cache()
+        cache_key = self._entry_key(program, feed0, static_lods, (),
+                                    fetch_names, donate_override,
+                                    ('fused', k_steps, n_steps))
 
-            def _build_fused():
-                resilience.maybe_fault('compile')
-                read, written = lowering.analyze_state(program, fetch_names)
-                needed = self._read_before_write(program, read, written,
-                                                 set(feed0), fetch_names)
-                fn, ro_names, rw_names = lowering.build_fn(
-                    program, fetch_names, needed, written,
-                    static_lods=static_lods)
-                return fn, ro_names, rw_names, written
-            with coldstart.stage('trace', program):
-                try:
-                    fn, ro_names, rw_names, written = _build_fused()
-                except Exception as e:  # noqa: BLE001 — classified inside
-                    fn, ro_names, rw_names, written = \
-                        resilience.retry_after(e, _build_fused,
-                                               site='compile')
+        def _build_fused():
+            read, written = lowering.analyze_state(program, fetch_names)
+            needed = self._read_before_write(program, read, written,
+                                             set(feed0), fetch_names)
+            lod_out = {}
+            fn, ro_names, rw_names = lowering.build_fn(
+                program, fetch_names, needed, written,
+                static_lods=static_lods, lod_out=lod_out)
 
             def fused(stacked_feed, ro, rw, base_key):
                 # carry: ONE merged state dict (all written persistables,
@@ -2129,80 +2169,35 @@ class Executor(object):
                 return fetches, {kk: st_out[kk] for kk in ns0}
 
             # Donation default ON (see _donation_enabled): parameter updates
-            # alias their input buffers instead of doubling peak HBM;
-            # PADDLE_FUSED_DONATE / PADDLE_DONATE override.
-            lowering.name_after(fused, program, '_fused')
-            jitted = jax.jit(fused, donate_argnums=(2,) if donate else ())
-            entry = _CompiledEntry(jitted, fetch_names, ro_names, rw_names,
-                                   written, program, {})
-            self._cache_put(cache_key, entry)
-        else:
-            monitor.inc('compile_cache_hit')
-
-        ro_state = {n: self._state_value(scope, n, program)
-                    for n in entry.ro_names}
-        rw_state = {n: self._state_value(scope, n, program, cache=False)
-                    for n in entry.rw_names}
-        self._run_counter += 1
-        key_arr = _run_key(program.random_seed, _next_program_run(program),
-                           self._run_counter)
-        program._last_run_key = key_arr
-        blackbox.note_step(program)
-        if fresh_compile:
-            frame = coldstart.compile_frame(program, since=t_compile)
-            fetches, new_state = _first_call(
-                frame, rw_state, entry.fn, stacked, ro_state, rw_state,
-                key_arr)
-            goodput.note_compile(cache_key[3], frame.seconds)
+            # alias their input buffers instead of doubling peak HBM. The
+            # state goes in flat like every entry's, under the window's
+            # own name: `flat` is jitted anew
+            call = lowering.StateCallable(fused, ro_names, rw_names, program,
+                                          cache_key[-1])
+            lowering.name_after(call._fn, program, '_fused')
+            call.flat = jax.jit(call._fn, donate_argnums=call._donate)
             # fused analytics register the scan; XLA cost analysis counts
             # the while BODY once (measured: flops identical for 4- and
             # 8-step scans), so the registered flops are per-step and
             # goodput multiplies by the dispatch's n_steps
-            analysis.record_compiled(entry.fn, program,
-                                     (stacked, ro_state, rw_state, key_arr),
-                                     kind='fused', donate=donate,
-                                     steps=n_steps)
-        else:
-            def _dispatch():
-                resilience.maybe_fault('run')
-                return entry.fn(stacked, ro_state, rw_state, key_arr)
-            t_disp = time.perf_counter()
-            try:
-                fetches, new_state = _dispatch()
-            except Exception as e:      # noqa: BLE001 — classified inside
-                fetches, new_state = resilience.retry_after(
-                    e, _dispatch, site='run', state=rw_state)
-                t_disp = time.perf_counter()    # exclude retry backoff
-            goodput.note_dispatch(cache_key[3], 'fused', t_disp,
-                                  time.perf_counter(),
-                                  leaf=_goodput_leaf(new_state, fetches),
-                                  steps=n_steps)
-        scope.update(new_state)
-        # checkpoint_notify: same host-side save contract as run()
-        for cn_dir in entry.notify_dirs:
-            from .io import save_persistables
-            with scope_guard(scope):
-                save_persistables(self, cn_dir, main_program=program)
-        if return_numpy:
-            return [np.asarray(f) for f in fetches]
-        return list(fetches)
+            return _CompiledEntry(call, fetch_names, written, program,
+                                  lod_out, kind='fused', steps=n_steps)
+        # the step, outside a run's phases: a window is no run
+        entry, fetches, _ = self._step(
+            scope, program, lambda: self._find(
+                cache_key, program, _build_fused, book=()) + (stacked,),
+            phase=_no_phase, book=())
+        return [np.asarray(f) for f in fetches] if return_numpy \
+            else list(fetches)
 
     # ------------------------------------------------------------------
     def bind(self, program, feed, fetch_list=None, scope=None, donate=None):
-        """Prepare a FIXED-SIGNATURE run for a hot dispatch loop: one
-        normal `run()` (looking the entry up, building and caching it as
-        usual), its one call made through the `BoundProgram` this
-        returns — on a backend that offers layouts that is the handle's
-        own executable, compiled for weights laid out as it wants them
-        and staged so (see BoundProgram). The handle's calls skip the
-        per-run key work — feed
-        preparation, fingerprint/signature hashing, cache lookup and span
-        bookkeeping — and the staging of the state that does not change:
-        the read-only names are staged here, once, and again only after
-        a scope write to one of them; a call takes the read-written
-        names from the scope and dispatches. Built for token-decode
-        loops (serving/generate.py), where `run()`'s host tax would be
-        paid once per generated token engine-wide.
+        """Prepare a FIXED-SIGNATURE run for a hot dispatch loop
+        (serving/generate.py's token decode): the entry looked up or built
+        and cached as `run()` does, the `BoundProgram` on it, and one run —
+        a run like any other, its one call the handle's first. The
+        handle's calls skip the per-run key work — feed preparation,
+        fingerprint/signature hashing, cache lookup, spans and phases.
 
         Contract: every subsequent call must feed the SAME names, shapes
         and dtypes as `feed` (the bound executable is never re-keyed); the
@@ -2214,6 +2209,14 @@ class Executor(object):
         run(); `donate` resolves once at bind time."""
         if scope is None:
             scope = global_scope()
+        if analysis.profile_ops_active() or (
+                os.environ.get('PADDLE_SEGMENT_HOST_OPS') == '1'
+                and self._host_splittable(program)):
+            raise RuntimeError(
+                "Executor.bind: no one entry runs this (program, feed, "
+                "fetch) signature — bind() supports host-op-free programs "
+                "outside profile_ops mode only (a run() goes through a "
+                "different execution path)")
         # needs_rng may be a static per-op-instance predicate (e.g.
         # fused_ffn_tail: only a train-mode op with live dropout draws a
         # key) — decode programs keep the single-PRNGKey fast path
@@ -2222,29 +2225,35 @@ class Executor(object):
             for block in program.blocks for op in block.ops)
         made = []
 
-        def through_handle(entry, feed2, ro_state, rw_state, key_arr):
-            """The run's one call goes through the handle, made here, on
-            the entry the run looked up or built: what is compiled and
-            run first is the entry every later call dispatches."""
-            rw = tuple([rw_state[n] for n in entry.fn.rw_names])
-            if not made:
-                # the handle stages for itself, one leaf at a time: the
-                # run lets go of what it staged, and gets the handle's
-                ro_state.clear()
-                made.append(BoundProgram(self, entry, program, scope,
-                                         needs_rng, feed2, rw, key_arr))
-                ro_state.update(zip(entry.fn.ro_names, made[0]._ro))
-            return made[0]._flat(feed2, made[0]._ro, rw, key_arr)
-        first_out = self._run_plain(program, feed, fetch_list, scope, True,
-                                    True, donate, _call=through_handle)
-        if not made:
-            raise RuntimeError(
-                "Executor.bind: the run compiled no entry for this "
-                "(program, feed, fetch) signature — bind() supports "
-                "host-op-free programs outside profile_ops mode only "
-                "(the run above went through a different execution path)")
-        made[0].first_out = first_out
-        return made[0]
+        def find():
+            """The entry `run()` would look up or build, and the handle
+            on it: what is compiled and run first is the entry every
+            later call dispatches."""
+            feed2, fetch_names, static_feed, static_lods = \
+                self._prepare_run_inputs(program, feed, scope, fetch_list)
+            key = self._entry_key(program, feed2, static_lods, static_feed,
+                                  fetch_names, donate)
+            entry, since = self._find(
+                key, program, lambda: self._build_entry(
+                    program, feed2, fetch_names, static_lods, static_feed,
+                    key[-1]))
+            made.append(BoundProgram(self, entry, program, scope, needs_rng,
+                                     feed2))
+            return made[0]._entry, since, feed2
+        # a run like any other (`_run_plain`), its one call the handle's
+        with trace_mod.step_scope('step'):
+            with monitor.timed_span('run', 'executor_run_seconds'):
+                monitor.inc('executor_run_total')
+                entry, fetches, _ = self._step(scope, program, find)
+                bound, = made
+                # the leaves the first call took are the handle's first
+                # staging, no restage: the scope's record holds them too
+                held = scope._held.get(entry)
+                bound._ro = held.ro if held is not None else None
+                # that call was a run's, booked so; the handle's are its own
+                entry.kind = 'bound'
+                bound.first_out = self._fetch(entry, fetches, True)
+        return bound
 
     # ------------------------------------------------------------------
     def precompile(self, program=None, feed_spec=None, fetch_list=None,
@@ -2285,57 +2294,25 @@ class Executor(object):
             # run()) — not a warmup farm's contract
             return {'compiled': False, 'cached': False, 'seconds': 0.0,
                     'skipped': 'host_ops'}
-        if donate is None and analysis.nan_localization_enabled():
-            from . import flags as _flags
-            if _flags.get_flags('check_nan_inf'):
-                # mirror _run_impl's localize force-off so the key below
-                # matches the entry the real run() will look up
-                donate = False
-        # record=False: this is a policy QUERY for the cache key (like
-        # bind's) — an AOT pass must not inflate donation counters
-        donate_flag = _donation_enabled(override=donate, record=False)
-        key = (program._fingerprint(),
-               self._feed_signature(feed, static_lods, static_feed),
-               tuple(fetch_names), donate_flag)
+        key = self._entry_key(program, feed, static_lods, static_feed,
+                              fetch_names, donate, record=False)
         monitor.inc('precompile_total')
-        if self._cache_get(key) is not None:
-            monitor.inc('compile_cache_hit')
+        entry, since = self._find(
+            key, program, lambda: self._build_entry(
+                program, feed, fetch_names, static_lods, static_feed,
+                key[-1]), book=())
+        if since is None:
             return {'compiled': False, 'cached': True, 'seconds': 0.0}
-        monitor.inc('compile_cache_miss')
-        t0 = time.perf_counter()
-        _wire_persistent_cache()
-
-        def _build():
-            resilience.maybe_fault('compile')
-            read, written = lowering.analyze_state(program, fetch_names)
-            needed = self._read_before_write(program, read, written,
-                                             set(feed), fetch_names)
-            lod_out = {}
-            fn, ro_names, rw_names = lowering.build_callable(
-                program, fetch_names, needed, written,
-                static_lods=static_lods, static_feed=static_feed,
-                lod_out=lod_out, donate=donate_flag)
-            return _CompiledEntry(fn, fetch_names, ro_names, rw_names,
-                                  written, program, lod_out)
-        with coldstart.stage('trace', program):
-            try:
-                entry = _build()
-            except Exception as e:      # noqa: BLE001 — classified inside
-                entry = resilience.retry_after(e, _build, site='compile')
-        self._cache_put(key, entry)
-        ro_state = {n: self._state_value(scope, n, program)
-                    for n in entry.ro_names}
         # rw state is DONATED by the compiled fn: hand it throwaway
         # copies so the scope's live buffers survive precompilation
-        rw_state = {n: jnp.array(
-            self._state_value(scope, n, program, cache=False), copy=True)
-            for n in entry.rw_names}
-        key_arr = _run_key(program.random_seed, 0, 0)
-
-        frame = coldstart.compile_frame(program, since=t0)
+        rec = _Held(
+            self._walk(scope, entry, program, entry.ro_names), None,
+            tuple([jnp.array(v, copy=True) for v in self._walk(
+                scope, entry, program, entry.rw_names, False)]))
+        frame = coldstart.compile_frame(program, since=since)
         # the outputs are let go: the scope stays untouched
-        _first_call(frame, rw_state, entry.fn, feed, ro_state, rw_state,
-                    key_arr)
+        _first_call(frame, entry, feed, rec, _run_key(program.random_seed,
+                                                      0, 0))
         return {'compiled': True, 'cached': False,
                 'seconds': round(frame.seconds, 4)}
 
@@ -2371,12 +2348,7 @@ class Executor(object):
         return v
 
     def _state_value(self, scope, name, program, cache=True):
-        v = scope.get(name)
-        if v is None:
-            raise RuntimeError(
-                "persistable variable %r is not initialized in the scope — "
-                "run the startup program first (reference: EnforceNotMet "
-                "'Var is not initialized')" % name)
+        v = self._state_ref(scope, name)
         if isinstance(v, np.ndarray) or np.isscalar(v):
             # cache the device array back into the scope: read-only state
             # (inference predictors, frozen params) is never rewritten by

@@ -10,35 +10,16 @@ inserts psum/reduce-scatter collectives over ICI for the gradient reductions —
 semantically identical to AllReduce mode with CoeffNumDevice scaling (the
 global-batch mean IS the 1/N-scaled allreduce).
 """
-import time
-
 import numpy as np
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import coldstart
 from .. import monitor
 from ..core import lowering
-from ..framework import Variable
 from .mesh import data_mesh
 
 __all__ = ['DataParallelRunner', 'place_state']
-
-
-class _Entry(object):
-    __slots__ = ('fn', 'ro_names', 'rw_names', 'written', 'feed_shardings',
-                 'state_shardings', 'lod_out', '__weakref__')
-
-    def __init__(self, fn, ro_names, rw_names, written, feed_shardings,
-                 state_shardings, lod_out=None):
-        self.fn = fn
-        self.ro_names = ro_names
-        self.rw_names = rw_names
-        self.written = written
-        self.feed_shardings = feed_shardings
-        self.state_shardings = state_shardings
-        self.lod_out = lod_out if lod_out is not None else {}
 
 
 def place_state(scope, state, shardings, program='program'):
@@ -71,6 +52,123 @@ def place_state(scope, state, shardings, program='program'):
     return out
 
 
+def fetch_to_host(f):
+    """Host view of a fetch. Multi-host: replicated fetches (losses,
+    metrics) give the full value; batch-sharded fetches give this
+    process's local rows, like each reference trainer seeing its own
+    split (parallel_executor.cc FeedAndSplitTensorIntoLocalScopes)."""
+    if not isinstance(f, jax.Array) or f.is_fully_addressable:
+        return np.asarray(f)
+    uniq = {}
+    for s in f.addressable_shards:      # dedupe replicas by index
+        uniq.setdefault(s.index, s.data)
+    if len(uniq) == 1:
+        # replicated value, or the single shard this process owns
+        return np.asarray(next(iter(uniq.values())))
+    idxs = list(uniq)
+    varying = [d for d in range(len(f.shape))
+               if len({(ix[d].start, ix[d].stop) for ix in idxs}) > 1]
+    if len(varying) != 1:
+        raise ValueError(
+            "multi-host fetch is sharded over %d axes; fetch a "
+            "replicated value (e.g. the mean loss) or keep outputs "
+            "sharded with return_numpy=False" % len(varying))
+    ax = varying[0]
+    ordered = sorted(uniq.items(),
+                     key=lambda kv: kv[0][ax].start or 0)
+    return np.concatenate([np.asarray(v) for _, v in ordered], ax)
+
+
+def _global(sharding, v):
+    """One process' whole copy of a value as its part of the global
+    array (every process holds the full value — state: identical init
+    from the same seed; the run key: the shared seed and counters)."""
+    if isinstance(v, jax.Array) and not v.is_fully_addressable:
+        return v          # already a global array from last step
+    arr = np.asarray(v)
+    return jax.make_array_from_callback(arr.shape, sharding,
+                                        lambda idx: arr[idx])
+
+
+def global_feed(shardings, feed):
+    """Each process' LOCAL batch shard as its rows of the global feed
+    (reference: each trainer reads its own data slice)."""
+    return {k: v if isinstance(v, jax.Array) and not v.is_fully_addressable
+            else jax.make_array_from_process_local_data(shardings[k],
+                                                        np.asarray(v))
+            for k, v in feed.items()}
+
+
+def sharded_entry(program, mesh, feed_names, fetch_names, static_lods,
+                  feed_sharding, state_sharding, lower_params=None):
+    """The executor's entry (`executor._CompiledEntry`) of `program`
+    jitted over `mesh`, for both runners: a feed as `feed_sharding(name)`
+    says (a ragged one comes replicated), a state name as
+    `state_sharding(name)`. The state goes in flat, in the
+    SORTED order in which jit flattens a dict (lowering.StateCallable:
+    the compiled program's parameter list is what it was when the state
+    went in by name), so a run hands on the tuples the run before it
+    left; its `flat` is jitted anew, with the mesh's shardings, the
+    read-written leaves donated. What a sharded entry is told: its call
+    runs in the mesh, under `api._ACTIVE_MESH` / `_ACTIVE_PARAM_SPEC`
+    (sharding_constraint ops resolve specs, fused units partition state
+    by its actual placement, while it traces); in one process a leaf the
+    walk found elsewhere is
+    moved where the entry wants it, once (`place_state`), and the step
+    keeps its record like any other; across processes the leaves and the
+    key become global arrays, no record is kept, and nothing is retried
+    (one process re-entering a collective alone hangs the others)."""
+    from . import api
+    from ..executor import Executor, _CompiledEntry, _keep_nothing, _raise
+    read, written = lowering.analyze_state(program, fetch_names)
+    needed = Executor._read_before_write(program, read, written,
+                                         set(feed_names), fetch_names)
+    lod_out = {}
+    fn, ro_names, rw_names = lowering.build_fn(
+        program, fetch_names, needed, written, static_lods=static_lods,
+        lod_out=lod_out, lower_params=lower_params)
+    feed_shardings = {k: feed_sharding(k) for k in feed_names}
+    state_shardings = {n: state_sharding(n)
+                       for n in set(ro_names) | set(rw_names) | set(written)}
+
+    def param_spec(name):
+        return (state_shardings.get(name) or state_sharding(name)).spec
+    repl = NamedSharding(mesh, P())
+    call = lowering.StateCallable(fn, ro_names, rw_names, program, True)
+    call.flat = flat = jax.jit(
+        call._fn,
+        in_shardings=(feed_shardings,
+                      tuple([state_shardings[n] for n in call.ro_names]),
+                      tuple([state_shardings[n] for n in call.rw_names]),
+                      repl),
+        out_shardings=(None, {n: state_shardings[n] for n in written}),
+        donate_argnums=(2,))
+    alone = jax.process_count() == 1
+
+    def in_mesh(feed, ro, rw, key):
+        prev = api._ACTIVE_MESH, api._ACTIVE_PARAM_SPEC
+        api._ACTIVE_MESH, api._ACTIVE_PARAM_SPEC = mesh, param_spec
+        try:
+            with mesh:
+                return flat(feed, ro, rw,
+                            key if alone else _global(repl, key))
+        finally:
+            api._ACTIVE_MESH, api._ACTIVE_PARAM_SPEC = prev
+
+    def place(scope, names, leaves, program):
+        if alone:
+            return tuple(place_state(scope, dict(zip(names, leaves)),
+                                     state_shardings, program).values())
+        return tuple([_global(state_shardings[n], v)
+                      for n, v in zip(names, leaves)])
+    how = {} if alone else {'keep': _keep_nothing, 'retry': _raise}
+    return _CompiledEntry(call, fetch_names, written, program, lod_out,
+                          kind='mesh', call=in_mesh, place=place,
+                          to_host=fetch_to_host,
+                          feed_shardings=feed_shardings,
+                          state_shardings=state_shardings, **how)
+
+
 class DataParallelRunner(object):
     def __init__(self, program, loss_name=None, build_strategy=None,
                  places=None, mesh=None):
@@ -80,7 +178,6 @@ class DataParallelRunner(object):
         self._mesh = mesh if mesh is not None else data_mesh(
             len(places) if places else None)
         self._cache = {}
-        self._run_counter = 0
 
     @property
     def num_devices(self):
@@ -146,257 +243,61 @@ class DataParallelRunner(object):
         return NamedSharding(mesh, P())
 
     def _compile(self, feed, fetch_names, feed_lods=None):
-        program = self._program
-        read, written = lowering.analyze_state(program, fetch_names)
-        from ..executor import Executor
-        needed = Executor._read_before_write(program, read, written,
-                                             set(feed), fetch_names)
+        program, mesh = self._program, self._mesh
         lower_params, reduce_mode = self._strategy_knobs()
         bs = self._build_strategy
         if bs is not None and getattr(bs, 'debug_graphviz_path', ''):
             from ..debugger import draw_block_graphviz
             draw_block_graphviz(program, bs.debug_graphviz_path)
         feed_lods = dict(feed_lods or {})
-        lod_out = {}
-        fn, ro_names, rw_names = lowering.build_fn(
-            program, fetch_names, needed, written,
-            static_lods=feed_lods, lod_out=lod_out,
-            lower_params=lower_params)
-        mesh = self._mesh
         repl = NamedSharding(mesh, P())
         batch_sharded = NamedSharding(mesh, P('data'))
         # ragged (LoD) feeds replicate: rows are per-sequence, not evenly
         # splittable over devices (reference SplitLoDTensor splits by
         # instance at feed time; the TPU path is bucket+pad to dense —
         # reader/bucketing.py — when scaling matters)
-        feed_shardings = {k: (repl if k in feed_lods else batch_sharded)
-                          for k in feed}
-        state_shard = {n: self._state_sharding(program, n, reduce_mode,
-                                               mesh)
-                       for n in set(ro_names) | set(rw_names) | set(written)}
-        # the state goes in flat, in the SORTED order in which jit
-        # flattens a dict (lowering.StateCallable: the compiled program's
-        # parameter list is what it was when the state went in by name),
-        # so a run hands on the tuples the run before it left; called or
-        # lowered with the state by name, it lines the leaves up itself.
-        # Its `flat` is jitted anew, with the mesh's shardings
-        call = lowering.StateCallable(fn, ro_names, rw_names, program, True)
-        in_shardings = (
-            feed_shardings,
-            tuple([state_shard[n] for n in call.ro_names]),
-            tuple([state_shard[n] for n in call.rw_names]),
-            repl,
-        )
-        out_shardings = (None, {n: state_shard[n] for n in written})
-        call.flat = jax.jit(call._fn, in_shardings=in_shardings,
-                            out_shardings=out_shardings,
-                            donate_argnums=(2,))
-        return _Entry(call, call.ro_names, call.rw_names, written,
-                      feed_shardings, state_shard, lod_out)
+        return sharded_entry(
+            program, mesh, feed, fetch_names, feed_lods,
+            lambda k: repl if k in feed_lods else batch_sharded,
+            lambda n: self._state_sharding(program, n, reduce_mode, mesh),
+            lower_params)
 
     def run(self, executor, feed, fetch_list, scope, return_numpy):
-        """One step, in the phases Executor.run has
-        (executor_run_phase_seconds_total{phase}): prepare — feed
-        preparation, the signature, the state (what this entry's last run
-        left, `executor._carried_state`, or every leaf looked up and
-        found in place), the run key; dispatch — the sharded call (a
-        signature's first is set-up's frame, and the `compile` phase);
-        commit — the scope rebind, the record for the next run, the
-        donated inputs let go, LoDs; fetch — the wait for the device.
-        Runs are counted where the CompiledProgram delegates
-        (executor_run_total, compiler.py)."""
-        from ..executor import (_run_phase, _compile_frame, _carry_state,
-                                global_scope)
+        """One step, the executor's (`Executor._step`: its phases, its
+        take / call / commit, `executor_run_phase_seconds_total{phase}`),
+        on the entry compiled for the mesh. Runs are counted where the
+        CompiledProgram delegates (executor_run_total, compiler.py)."""
+        from ..executor import global_scope
         if scope is None:
             scope = global_scope()
-        with _run_phase('prepare'):
-            entry, feed, ro, rw, key_arr, fetch_names, since = \
-                self._prepare(executor, feed, fetch_list, scope)
-        flat = entry.fn.flat
         program = self._program
-        from . import api as _papi
-        prev, _papi._ACTIVE_MESH = _papi._ACTIVE_MESH, self._mesh
-        _, reduce_mode = self._strategy_knobs()
-        prev_spec = _papi._ACTIVE_PARAM_SPEC
-        # fused units partition state by its actual placement: replicated
-        # in plain DP, the ZeRO-style reduce-mode spec otherwise
-        _papi._ACTIVE_PARAM_SPEC = (
-            lambda n: self._state_sharding(program, n, reduce_mode,
-                                           self._mesh).spec)
-        try:
-            with self._mesh:
-                if since is not None:
-                    # like the serial executor: jax.jit is lazy, the XLA
-                    # compile happens inside the FIRST call — compile wall
-                    # time must cover it, not just the jit construction
-                    with _compile_frame(program, since=since):
-                        fetches, new_state = flat(feed, ro, rw, key_arr)
-                else:
-                    with _run_phase('dispatch'):
-                        fetches, new_state = flat(feed, ro, rw, key_arr)
-        finally:
-            _papi._ACTIVE_MESH = prev
-            _papi._ACTIVE_PARAM_SPEC = prev_spec
-        with _run_phase('commit'):
-            from .. import flags as _flags
-            if _flags.get_flags('check_nan_inf'):
-                from ..executor import _check_nan_inf
-                _check_nan_inf(
-                    {n: self._fetch_to_host(v)
-                     for n, v in new_state.items()},
-                    dict(zip(fetch_names,
-                             [self._fetch_to_host(f) for f in fetches])))
-            if _flags.get_flags('benchmark'):
-                with _run_phase('fetch'):
-                    jax.block_until_ready(fetches)
-            scope.update(new_state)
-            if jax.process_count() == 1:
-                _carry_state(scope, entry, ro, new_state)
-            # the donated inputs go here, while the device is busy, not
-            # as the frame exits behind the fetch's wait
-            del ro, rw
-            if entry.lod_out or scope._lods:
-                for n in new_state:
-                    lod = entry.lod_out.get(n)
-                    if lod:
-                        scope._lods[n] = lod
-                    else:
-                        scope._lods.pop(n, None)
-        if not return_numpy:
-            return list(fetches)
-        from ..executor import _fetched
-        with _run_phase('fetch'):
-            out = []
-            for n, f in zip(fetch_names, fetches):
-                host = self._fetch_to_host(f)
-                lod = entry.lod_out.get(n)
-                out.append(_fetched(host, lod) if lod else host)
-            return out
 
-    def _prepare(self, executor, feed, fetch_list, scope):
-        """Everything of a run ahead of the sharded call: (entry, feed,
-        the read-only and the read-written leaves in the entry's order,
-        key, fetch names, and — for a
-        signature's first run, whose entry was made here — when its
-        making began)."""
-        program = self._program
-        feed, feed_lods = executor._prepare_feed(program, feed or {})
-        # LoD-carrying scope state binds statically, like the serial
-        # executor (executor.py scope_lods handling)
-        from ..core.lod import normalize_lod as _nl
-        scope_lods = {n: _nl(l) for n, l in
-                      getattr(scope, '_lods', {}).items() if l}
-        static_lods = dict(scope_lods)
-        static_lods.update(feed_lods)
-        fetch_names = [v.name if isinstance(v, Variable) else v
-                       for v in (fetch_list or [])]
-        nproc = jax.process_count()
-        # under multi-host, each process feeds its LOCAL batch shard
-        # (reference: each trainer reads its own data slice); divisibility
-        # is per local device count
-        ndev = self.num_devices // nproc if nproc > 1 else self.num_devices
-        for k, v in feed.items():
-            if k in feed_lods:
-                continue          # ragged feeds replicate (see _compile)
-            if v.shape and v.shape[0] % max(ndev, 1) != 0:
-                raise ValueError(
-                    "feed %r batch %d not divisible by %d mesh devices"
-                    % (k, v.shape[0], ndev))
-        key = (program._uid, program._version,
-               executor._feed_signature(feed, static_lods),
-               tuple(fetch_names))
-        entry = self._cache.get(key)
-        since = None
-        if entry is None:
-            monitor.inc('compile_cache_miss')
-            since = time.perf_counter()
-            from ..executor import _wire_persistent_cache, _RUN_COMPILE
-            _wire_persistent_cache()
-            with coldstart.stage('trace', program, *_RUN_COMPILE):
-                entry = self._compile(feed, fetch_names,
-                                      feed_lods=static_lods)
-            self._cache[key] = entry
-        else:
-            monitor.inc('compile_cache_hit')
-
-        from ..executor import _carried_state, _run_key, _next_program_run
-        # one process: what this entry's last run on the scope left, if
-        # nothing wrote the scope since — in place already, the entry's
-        # own outputs under its `out_shardings`
-        state = _carried_state(scope, entry) if nproc == 1 else None
-        if state is None:
-            ro_state = {n: executor._state_value(scope, n, program)
-                        for n in entry.ro_names}
-            rw_state = {n: executor._state_value(scope, n, program)
-                        for n in entry.rw_names}
-            if nproc == 1:
-                ro_state = place_state(scope, ro_state,
-                                       entry.state_shardings, program)
-                rw_state = place_state(scope, rw_state,
-                                       entry.state_shardings, program)
-            else:
-                # assemble global arrays from per-process host-local data
-                # (feeds: local batch shard; state: every process holds
-                # the full value — identical init from the same seed)
-                def _globalize_feed(sharding, v):
-                    if isinstance(v, jax.Array) \
-                            and not v.is_fully_addressable:
-                        return v
-                    return jax.make_array_from_process_local_data(
-                        sharding, np.asarray(v))
-
-                def _globalize_state(sharding, v):
-                    if isinstance(v, jax.Array) \
-                            and not v.is_fully_addressable:
-                        return v      # already a global array from last step
-                    arr = np.asarray(v)
-                    return jax.make_array_from_callback(
-                        arr.shape, sharding, lambda idx: arr[idx])
-
-                feed = {k: _globalize_feed(entry.feed_shardings[k], v)
-                        for k, v in feed.items()}
-                ro_state = {n: _globalize_state(entry.state_shardings[n], v)
-                            for n, v in ro_state.items()}
-                rw_state = {n: _globalize_state(entry.state_shardings[n], v)
-                            for n, v in rw_state.items()}
-            state = tuple(ro_state.values()), tuple(rw_state.values())
-        ro, rw = state
-        self._run_counter += 1
-        key_arr = _run_key(program.random_seed, _next_program_run(program),
-                           self._run_counter)
-        if nproc > 1:
-            # the PRNG key must be a global replicated array too (every
-            # process derives the identical value from the shared seed /
-            # run counters)
-            karr = np.asarray(key_arr)
-            key_arr = jax.make_array_from_callback(
-                karr.shape, NamedSharding(self._mesh, P()),
-                lambda idx: karr[idx])
-        return entry, feed, ro, rw, key_arr, fetch_names, since
-
-    @staticmethod
-    def _fetch_to_host(f):
-        """Host view of a fetch. Multi-host: replicated fetches (losses,
-        metrics) give the full value; batch-sharded fetches give this
-        process's local rows, like each reference trainer seeing its own
-        split (parallel_executor.cc FeedAndSplitTensorIntoLocalScopes)."""
-        if not isinstance(f, jax.Array) or f.is_fully_addressable:
-            return np.asarray(f)
-        uniq = {}
-        for s in f.addressable_shards:      # dedupe replicas by index
-            uniq.setdefault(s.index, s.data)
-        if len(uniq) == 1:
-            # replicated value, or the single shard this process owns
-            return np.asarray(next(iter(uniq.values())))
-        idxs = list(uniq)
-        varying = [d for d in range(len(f.shape))
-                   if len({(ix[d].start, ix[d].stop) for ix in idxs}) > 1]
-        if len(varying) != 1:
-            raise ValueError(
-                "multi-host fetch is sharded over %d axes; fetch a "
-                "replicated value (e.g. the mean loss) or keep outputs "
-                "sharded with return_numpy=False" % len(varying))
-        ax = varying[0]
-        ordered = sorted(uniq.items(),
-                         key=lambda kv: kv[0][ax].start or 0)
-        return np.concatenate([np.asarray(v) for _, v in ordered], ax)
+        def find():
+            feed2, fetch_names, _, static_lods = \
+                executor._prepare_run_inputs(program, feed, scope,
+                                             fetch_list)
+            nproc = jax.process_count()
+            # under multi-host, each process feeds its LOCAL batch shard
+            # (reference: each trainer reads its own data slice);
+            # divisibility is per local device count
+            ndev = self.num_devices // nproc
+            for k, v in feed2.items():
+                if k in static_lods:
+                    continue      # ragged feeds replicate (see _compile)
+                if v.shape and v.shape[0] % max(ndev, 1) != 0:
+                    raise ValueError(
+                        "feed %r batch %d not divisible by %d mesh devices"
+                        % (k, v.shape[0], ndev))
+            # the sharded jit donates, whatever the policy: no override
+            # applies and no rate moves
+            key = executor._entry_key(program, feed2, static_lods, (),
+                                      fetch_names, True, ('mesh',), False)
+            entry, since = executor._find(
+                key, program,
+                lambda: self._compile(feed2, fetch_names, static_lods),
+                self._cache)
+            if nproc > 1:
+                feed2 = global_feed(entry.feed_shardings, feed2)
+            return entry, since, feed2
+        entry, fetches, _ = executor._step(scope, program, find)
+        return executor._fetch(entry, fetches, return_numpy)

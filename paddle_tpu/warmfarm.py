@@ -48,25 +48,17 @@ class WarmFarm(object):
     def signature(self, executor, program, feed, fetch_list=None,
                   scope=None, donate=None):
         """The executor compile-cache key this (program, feed, fetch,
-        donate) run would use — computed exactly like run()/bind() so the
-        farm's ledger and the cache can never disagree (including the
-        NAN_LOCALIZE donation force-off both apply)."""
-        from . import analysis
-        from .executor import (_donation_enabled, _feed_from_spec,
-                               global_scope)
+        donate) run would use — `Executor._entry_key`, the recipe of
+        run()/bind() itself, so the farm's ledger and the cache can never
+        disagree."""
+        from .executor import _feed_from_spec, global_scope
         if scope is None:
             scope = global_scope()
         feed2, fetch_names, static_feed, static_lods = \
             executor._prepare_run_inputs(program, _feed_from_spec(feed),
                                          scope, fetch_list, count=False)
-        if donate is None and analysis.nan_localization_enabled():
-            from . import flags as _flags
-            if _flags.get_flags('check_nan_inf'):
-                donate = False
-        return (program._fingerprint(),
-                executor._feed_signature(feed2, static_lods, static_feed),
-                tuple(fetch_names),
-                _donation_enabled(override=donate, record=False))
+        return executor._entry_key(program, feed2, static_lods, static_feed,
+                                   fetch_names, donate, record=False)
 
     def is_warm(self, key):
         with self._lock:

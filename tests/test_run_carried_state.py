@@ -1,9 +1,10 @@
 """A steady `Executor.run` takes its state from the run before
-(`executor._carried_state` / `_carry_state`, `Scope._writes`): the
-read-written leaves a run returned are what the next run of the same entry
-on the same scope is called with, until something writes the scope. Held
-here: the carried path and the walk give the same bits, every writer sends
-the next run down the walk, and a record pins nothing a writer let go.
+(`Executor._take` / `_commit`, the scope's one record an entry:
+`Scope._held`, `_writes`, `_gen`): the read-written leaves a run returned
+are what the next run of the same entry on the same scope is called with,
+until something writes the scope. Held here: the carried path and the walk
+give the same bits, every writer sends the next run down the walk, and a
+record pins nothing a writer let go.
 """
 import gc
 import weakref
@@ -56,6 +57,11 @@ def _scalar(fetched):
 
 def _carried():
     return monitor.counters().get('executor_run_carried_total', 0)
+
+
+def _records(scope):
+    """The scope's records that hold a leaf, by entry."""
+    return {e: r for e, r in scope._held.items() if r.ro or r.rw}
 
 
 def _state(scope):
@@ -250,7 +256,7 @@ def test_a_run_that_raises_drops_the_record_and_a_good_step_follows():
             exe.run(main, feed=bad, fetch_list=[loss], scope=scope)
     finally:
         fluid.set_flags({'FLAGS_check_nan_inf': False})
-    assert not scope._carried           # the run that raised left none
+    assert not _records(scope)          # the run that raised left none
     for n, v in good.items():           # the trainer's rollback
         scope.set(n, v)
     n0 = _carried()
@@ -422,21 +428,32 @@ def test_the_runs_own_rebind_is_the_only_write_a_record_survives():
     exe.run(startup, scope=scope)
     feeds = _batches()
     exe.run(main, feed=feeds[0], fetch_list=[loss], scope=scope)
-    (entry, (writes, ro, rw)), = scope._carried.items()
-    assert writes == scope._writes
+    (entry, rec), = _records(scope).items()
+    assert (rec.writes, rec.gen) == (scope._writes, scope._gen)
     # the record's read-written leaves ARE the scope's arrays: no copy
-    assert all(v is scope.get(n) for n, v in zip(entry.fn.rw_names, rw))
-    assert all(v is scope.get(n) for n, v in zip(entry.fn.ro_names, ro))
+    assert all(v is scope.get(n)
+               for n, v in zip(entry.fn.rw_names, rec.rw))
+    assert all(v is scope.get(n)
+               for n, v in zip(entry.fn.ro_names, rec.ro))
     scope.update({})                    # nothing written: nothing moves
-    assert scope._carried and writes == scope._writes
+    assert rec.rw and rec.writes == scope._writes
     for write in (lambda: scope.set('unrelated', 0.0),
                   lambda: scope.update({'unrelated': 1.0}),
                   lambda: scope.drop('unrelated')):
         exe.run(main, feed=feeds[1], fetch_list=[loss], scope=scope)
-        before = scope._writes
-        assert scope._carried
+        before = scope._writes, scope._gen
+        assert scope._held[entry].rw
         write()
-        assert scope._writes == before + 1 and not scope._carried
+        # every read-written leaf is let go; the read-only ones, whose
+        # names nobody wrote, stay
+        assert (scope._writes, scope._gen) == (before[0] + 1, before[1])
+        assert not any(r.rw for r in scope._held.values())
+        assert scope._held[entry].gen == scope._gen
+    # a write to a name some record keeps read-only lets every record go
+    exe.run(main, feed=feeds[1], fetch_list=[loss], scope=scope)
+    assert entry.ro_names and scope._held[entry].rw
+    scope.set(entry.ro_names[0], np.asarray(scope.get(entry.ro_names[0])))
+    assert not scope._held and scope._gen == before[1] + 1
 
 
 def test_a_steady_run_looks_no_leaf_up_and_its_cost_a_leaf_stays_small(
@@ -533,7 +550,8 @@ def test_the_plain_entry_lowers_the_same_flat_and_by_name():
     exe.run(startup, scope=scope)
     feed = _batches(1)[0]
     exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
-    (entry, (_w, ro, rw)), = scope._carried.items()
+    (entry, rec), = _records(scope).items()
+    ro, rw = rec.ro, rec.rw
     feed, _lods = exe._prepare_feed(main, feed)
     key = jax.random.PRNGKey(0)
     flat = entry.fn.flat.lower(feed, ro, rw, key).as_text()
@@ -553,8 +571,8 @@ def test_a_record_goes_with_its_entry():
     exe.run(test, feed=feed, fetch_list=[test_loss], scope=scope)
     # the startup program's (its own rebind is the last write) and the
     # eval program's
-    assert len(scope._carried) == 2
+    assert len(scope._held) == 2
     exe._cache.clear()
     executor_mod._shared_cache.clear()
     gc.collect()
-    assert len(scope._carried) == 0
+    assert len(scope._held) == 0
