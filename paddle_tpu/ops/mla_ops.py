@@ -17,9 +17,15 @@ Two forms of it, one result:
 
 - ``mla_prefix_attention`` — EXPANDED, the prefill: the table's rows are
   gathered, ``k_nope`` and ``v`` rebuilt from their latents for every
-  head, and the suffix's queries attend them causally (queries in chunks,
-  so the scores of a 2048-row bucket against a 2816-row table never stand
-  whole).
+  head, and the suffix's queries attend them causally. ``pallas`` /
+  ``interpret``: the blockwise kernel of ops/prefix_attention.py at this
+  layer's widths — a head's ``nope`` lanes against its own keys, its
+  ``rope`` lanes against the one rotary key all heads share, values of
+  ``v`` lanes — so no score stands in HBM and the key tiles past a query
+  tile's last position are neither read nor computed. ``off`` / ``xla``:
+  the composition below (queries in chunks, so the scores of a 2048-row
+  bucket against a 2816-row table never stand whole), the CPU path and
+  the kernel's parity oracle.
 - ``mla_decode_attention_paged`` — ABSORBED, the decode step: ``q' =
   [q_nope W_uk | q_r]`` (``W`` lanes a head) scores against the cached
   rows as they lie, ``o_latent = sum p c_kv`` (``R`` lanes a head), then
@@ -39,9 +45,16 @@ from ..core.registry import register_op
 from .kv_cache_ops import pool_pages
 
 _NEG_INF = -1e30
-# query rows of a prefill that attend at once (the scores are
-# [H, rows, table positions] float32)
+# query rows of a prefill that attend at once in the plain composition (the
+# scores are [H, rows, table positions] float32)
 _QUERY_CHUNK = 256
+# query rows a tile of the kernel: with one query head a K/V head, tiles of
+# 512 rows skip more of the causal half than the kernel's own 1 024 at twice
+# the K/V reads. Measured on the v5e at JoyAI's shape (32 heads of 128 + 64 |
+# 128, 2 816 keys; tools/kernbench.py --cases mla_prefix_attention; PERF.md,
+# PR 61), ms a call at 1 024 | 512 | 256 rows a tile: 512 rows 0.189 | 0.190
+# | 0.225; 1 024 rows 0.441 | 0.380 | 0.476; 2 048 rows 1.304 | 1.188 | 1.587
+_KERNEL_QUERY_ROWS = 512
 
 
 def absorbed_decode_reference(q, pool, tables, pos, layer, scale, v_width):
@@ -100,29 +113,14 @@ def _mla_decode_attention_paged(ctx, op):
     ctx.out(op, 'Out', jnp.einsum('shr,hrv->shv', latent, w_uv))
 
 
-@register_op('mla_prefix_attention', share_lod=False)
-def _mla_prefix_attention(ctx, op):
-    """Expanded causal latent attention of one slot's prefill SUFFIX
-    against its block-table cache: query row t sits at global position
-    Positions[t] and attends every cached position <= Positions[t] — the
-    shared prefix plus the suffix rows just deposited. Q ``[1, T, H, nope
-    + rope]``, Out ``[1, T, H, v]``."""
-    q = ctx.in1(op, 'Q')[0]                     # [T, H, nope + rope]
-    pool = ctx.in1(op, 'Cache')                 # [NB, Ln, bs, W]
-    w_uk = ctx.in1(op, 'UpK')                   # [H, nope, R]
-    w_uv = ctx.in1(op, 'UpV')                   # [H, R, v]
-    table = ctx.in1(op, 'BlockTable').reshape(-1).astype(jnp.int32)
-    pos = ctx.in1(op, 'Positions').reshape(-1).astype(jnp.int32)   # [T]
-    layer = int(op.attr('layer'))
-    scale = float(op.attr('scale', 1.0))
-    nope, rank = w_uk.shape[1], w_uk.shape[2]
-    rows = pool_pages(pool, layer, table).reshape(-1, pool.shape[3])  # [M, W]
-    # behind the rotary key the row is zeros up to whole lane tiles
-    latent = rows[:, :rank]
-    k_rope = rows[:, rank:rank + q.shape[-1] - nope]
-    k_nope = jnp.einsum('mr,hnr->hmn', latent, w_uk)          # [H, M, nope]
-    value = jnp.einsum('mr,hrv->hmv', latent, w_uv)           # [H, M, v]
-    key_at = jnp.arange(rows.shape[0])
+def _expanded_attention_scores(q, k_nope, k_rope, value, pos, scale):
+    """`mla_prefix_attention` as plain XLA (the `off` / `xla` tier, and the
+    tests' reference of the kernel): q ``[T, H, nope + rope]`` at positions
+    ``pos [T]`` against k_nope ``[H, M, nope]``, k_rope ``[M, rope]`` and
+    value ``[H, M, v]``, key ``i`` at position ``i``. The scores stand in
+    HBM against the table's whole width, `_QUERY_CHUNK` rows at a time."""
+    nope = k_nope.shape[2]
+    key_at = jnp.arange(k_nope.shape[1])
 
     def attend(args):
         qc, pc = args                           # [C, H, nope + rope], [C]
@@ -140,4 +138,57 @@ def _mla_prefix_attention(ctx, op):
     chunk = _QUERY_CHUNK if T % _QUERY_CHUNK == 0 else T
     out = jax.lax.map(attend, (q.reshape((T // chunk, chunk) + q.shape[1:]),
                                pos.reshape(T // chunk, chunk)))
-    ctx.out(op, 'Out', out.reshape((1, T) + out.shape[2:]))
+    return out.reshape((T,) + out.shape[2:])
+
+
+@register_op('mla_prefix_attention', share_lod=False)
+def _mla_prefix_attention(ctx, op):
+    """Expanded causal latent attention of one slot's prefill SUFFIX
+    against its block-table cache: query row t sits at global position
+    Positions[t] and attends every cached position <= Positions[t] — the
+    shared prefix plus the suffix rows just deposited. Q ``[1, T, H, nope
+    + rope]``, Out ``[1, T, H, v]``.
+
+    ``k_nope`` and ``v`` are two einsums of the gathered latent rows either
+    way; the tiers differ from the scores on. ``pallas`` / ``interpret``:
+    the blockwise kernel of ops/prefix_attention.py (Mosaic name
+    `mla_prefix_attention`), one query head a K/V head, the rotary key its
+    shared key part — no score stands in HBM, and the key tiles past a
+    query tile's last position are neither read nor computed. ``off`` /
+    ``xla`` (the CPU, a >1-device mesh, what `prefix_attention.shapes_ok`
+    refuses; the tests' reference): `_expanded_attention_scores`."""
+    from . import kernel_tier, prefix_attention as pfa
+    from ..parallel.api import get_active_mesh
+    q = ctx.in1(op, 'Q')[0]                     # [T, H, nope + rope]
+    pool = ctx.in1(op, 'Cache')                 # [NB, Ln, bs, W]
+    w_uk = ctx.in1(op, 'UpK')                   # [H, nope, R]
+    w_uv = ctx.in1(op, 'UpV')                   # [H, R, v]
+    table = ctx.in1(op, 'BlockTable').reshape(-1).astype(jnp.int32)
+    pos = ctx.in1(op, 'Positions').reshape(-1).astype(jnp.int32)   # [T]
+    layer = int(op.attr('layer'))
+    scale = float(op.attr('scale', 1.0))
+    nope, rank = w_uk.shape[1], w_uk.shape[2]
+    rope = q.shape[-1] - nope
+    rows = pool_pages(pool, layer, table).reshape(-1, pool.shape[3])  # [M, W]
+    # behind the rotary key the row is zeros up to whole lane tiles
+    latent = rows[:, :rank]
+    k_rope = rows[:, rank:rank + rope]
+    k_nope = jnp.einsum('mr,hnr->hmn', latent, w_uk)          # [H, M, nope]
+    value = jnp.einsum('mr,hrv->hmv', latent, w_uv)           # [H, M, v]
+    (T, H), M = q.shape[:2], rows.shape[0]
+    mesh = get_active_mesh()
+    meshed = mesh is not None and mesh.size > 1
+    impl = kernel_tier.dispatch(
+        'mla_prefix_attention', mesh=mesh,
+        pallas_ok=pfa.shapes_ok(H, H, T, nope, M, v_dim=value.shape[2],
+                                shared_dim=rope) and not meshed)
+    if impl in ('pallas', 'interpret'):
+        out = pfa.prefix_attention(
+            jnp.swapaxes(q, 0, 1), k_nope, value, jnp.arange(M), pos,
+            k_rope, scale=scale, interpret=impl == 'interpret',
+            name='mla_prefix_attention', rows=_KERNEL_QUERY_ROWS)
+        out = jnp.swapaxes(out, 0, 1)
+    else:
+        out = _expanded_attention_scores(q, k_nope, k_rope, value, pos,
+                                         scale)
+    ctx.out(op, 'Out', out[None])

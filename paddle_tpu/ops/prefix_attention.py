@@ -23,6 +23,15 @@ accumulator of a query row live in VMEM scratch across the key tiles
 of the plain composition take them on this chip, at the default matmul
 precision.
 
+Keys and values need not be one width, and a part of every key may be
+ONE row that all heads share: `mla_prefix_attention` (ops/mla_ops.py) is
+this kernel with a query head a K/V head, 128 key lanes of a head's own,
+64 rotary lanes behind them against the one rotary key ``[M, 64]`` (a
+second product a key tile, against a block whose index ignores the head)
+and values of 128 lanes, under its own Mosaic name. A call with one width
+and no shared part traces to the kernel it was before
+(tests/fixtures/prefix_attention_kernel_parent_pr61.json).
+
 What is skipped. ``seen[q]``, a scalar prefetched a query tile, counts the
 leading keys that some row of the tile sees (a prompt's first chunk of 512
 rows against a table of 5 120 keys: 512). A key tile wholly past it is not
@@ -62,35 +71,43 @@ _VMEM_BYTES = 64 << 20
 _MIN_SCORES_BYTES = 64 << 20
 
 
-def shapes_ok(n_head, n_kv_head, rows, head_dim, keys):
+def shapes_ok(n_head, n_kv_head, rows, head_dim, keys, v_dim=None,
+              shared_dim=0):
     """Whether a call takes the kernel. The tiling rule: whole sublanes of
-    query rows, heads of whole or half vregs, the query heads divided
-    evenly over the K/V heads. And the call's size: the float32 scores of
-    all its heads, which the plain composition would form, are
-    `_MIN_SCORES_BYTES` at least — under that XLA keeps them on the chip
-    itself and the kernel's fixed cost a call (its grid steps, the K and V
-    copies in the layout it reads) is the larger (see there)."""
-    return rows % 8 == 0 and head_dim in (64, 128, 256) and \
-        n_head % n_kv_head == 0 and \
+    query rows, heads (and values, ``v_dim`` where it differs) of whole or
+    half vregs, the query heads divided evenly over the K/V heads; under a
+    shared key part of ``shared_dim`` lanes, a head's own lanes whole vregs
+    (the shared lanes of q begin where they end) and that part half a vreg
+    or a whole one. And the call's size: the float32 scores of all its
+    heads, which the plain composition would form, are `_MIN_SCORES_BYTES`
+    at least — under that XLA keeps them on the chip itself and the
+    kernel's fixed cost a call (its grid steps, the K and V copies in the
+    layout it reads) is the larger (see there)."""
+    widths = (64, 128, 256)
+    return rows % 8 == 0 and head_dim in widths and \
+        (v_dim or head_dim) in widths and \
+        (not shared_dim or (head_dim % 128 == 0 and shared_dim in (64, 128))) \
+        and n_head % n_kv_head == 0 and \
         n_head * rows * keys * 4 >= _MIN_SCORES_BYTES
 
 
-def query_tile(rows, group):
+def query_tile(rows, group, most=_ROWS):
     """Rows of one query head in a tile: the largest divisor of ``rows``
-    of whole sublanes that keeps the group's rows within `_ROWS`."""
+    of whole sublanes that keeps the group's rows within ``most``."""
     return max(t for t in range(8, rows + 1, 8)
-               if rows % t == 0 and (t * group <= _ROWS or t == 8))
+               if rows % t == 0 and (t * group <= most or t == 8))
 
 
 def _kernel(seen_ref,                               # scalar prefetch
             q_ref, pos_ref, k_ref, v_ref, at_ref,   # inputs
-            o_ref,                                  # output
-            m_scr, l_scr, acc_scr,
-            *, scale, window, n_key_tiles):
+            *rest,          # [the shared key part,] output, three scratch
+            scale, window, n_key_tiles):
     import jax.experimental.pallas as pl
+    *shared, o_ref, m_scr, l_scr, acc_scr = rest
     qi, j = pl.program_id(1), pl.program_id(2)
-    G, tq, dh = q_ref.shape[1:]
-    tk = k_ref.shape[1]
+    G, tq, dq = q_ref.shape[1:]
+    tk, dk = k_ref.shape[1:]
+    dv = v_ref.shape[2]
     seen = seen_ref[qi]
 
     @pl.when(j == 0)
@@ -101,15 +118,24 @@ def _kernel(seen_ref,                               # scalar prefetch
 
     @pl.when(j * tk < seen)
     def _():
-        q = q_ref[0].reshape(G * tq, dh)
+        q = q_ref[0].reshape(G * tq, dq)
         pos = pos_ref[0]                                    # [G * tq, 1]
         at = at_ref[...]                                    # [1, tk]
         at = jnp.where(at >= 0, at, _NO_KEY)
         live = at <= pos
         if window is not None:
             live &= at > pos - window
-        s = lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+        def product(x, y):
+            return lax.dot_general(x, y, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        if shared:
+            # a head's own lanes against its own keys, the lanes behind
+            # them against the key part that every head shares
+            s = product(q[:, :dk], k_ref[0]) + product(q[:, dk:],
+                                                       shared[0][...])
+        else:
+            s = product(q, k_ref[0])
+        s = s * scale
         s = jnp.where(live, s, _NEG_INF)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -117,7 +143,7 @@ def _kernel(seen_ref,                               # scalar prefetch
         # a key the row does not see: weight exactly 0, whatever its
         # score was (a NaN, or -1e30 against a maximum of -1e30)
         p = jnp.where(live, jnp.exp(s - m_new), 0.0)
-        key = j * tk + lax.broadcasted_iota(jnp.int32, (tk, dh), 0)
+        key = j * tk + lax.broadcasted_iota(jnp.int32, (tk, dv), 0)
         v = jnp.where(key < seen, v_ref[0], 0.0)
         l_scr[...] = jnp.broadcast_to(
             alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
@@ -131,34 +157,43 @@ def _kernel(seen_ref,                               # scalar prefetch
     def _():
         l = l_scr[:, :1]
         o_ref[0] = (acc_scr[...] / jnp.where(l > 0, l, 1.0)).reshape(
-            G, tq, dh).astype(o_ref.dtype)
+            G, tq, dv).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=('scale', 'window',
-                                             'interpret'))
-def prefix_attention(q, k, v, at, pos, *, scale, window=None,
-                     interpret=False):
-    """q ``[H, T, dh]`` at positions ``pos [T]``; k and v ``[Hkv, M,
-    dh]``, key ``i`` at position ``at[i]`` (negative: none). Returns ``[H,
-    T, dh]``: row ``t``'s softmax over the keys with ``at <= pos[t]`` (and
-    ``> pos[t] - window``), query head ``h`` against K/V head ``h // (H
-    // Hkv)``.
+                                             'interpret', 'name', 'rows'))
+def prefix_attention(q, k, v, at, pos, k_shared=None, *, scale, window=None,
+                     interpret=False, name=None, rows=_ROWS):
+    """q ``[H, T, dk]`` at positions ``pos [T]``; k ``[Hkv, M, dk]`` and v
+    ``[Hkv, M, dv]``, key ``i`` at position ``at[i]`` (negative: none).
+    Returns ``[H, T, dv]``: row ``t``'s softmax over the keys with ``at <=
+    pos[t]`` (and ``> pos[t] - window``), query head ``h`` against K/V head
+    ``h // (H // Hkv)``.
+
+    ``k_shared [M, dr]`` (latent attention's one rotary key, ops/mla_ops.py):
+    a key part that every head shares, q then ``[H, T, dk + dr]`` — a score
+    is the sum of two products, the second against a block whose index
+    ignores the head, and no key ``dk + dr`` wide is laid out anywhere.
+    ``rows``: the most query rows a tile may hold (`query_tile`).
 
     Jitted: the layers of a prefill program call ONE traced function, so
     the kernel is lowered to Mosaic once a program and not once a layer."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    H, T, dh = q.shape
+    H, T, dq = q.shape
     Hkv, M = k.shape[:2]
+    dv = v.shape[2]
     G = H // Hkv
-    tq = query_tile(T, G)
+    tq = query_tile(T, G, rows)
     nq = T // tq
     pos = pos.astype(jnp.int32)
     at = at.astype(jnp.int32)
+    shared = [] if k_shared is None else [k_shared]
     if M < _KEY_TILE:
         # one tile, of whole vregs of scores: the added keys are none
         tk = -(-M // 128) * 128
         k, v = (jnp.pad(x, ((0, 0), (0, tk - M), (0, 0))) for x in (k, v))
+        shared = [jnp.pad(x, ((0, tk - M), (0, 0))) for x in shared]
     else:
         tk = _KEY_TILE
     nk = -(-M // tk)
@@ -176,10 +211,17 @@ def prefix_attention(q, k, v, at, pos, *, scale, window=None,
         # past the last tile that counts, that tile again: no copy
         return jnp.minimum(j, jnp.maximum(seen[qi] - 1, 0) // tk)
 
-    rows = pl.BlockSpec((1, G, tq, dh), lambda h, qi, j, seen: (h, 0, qi, 0))
-    keys = pl.BlockSpec((1, tk, dh),
-                        lambda h, qi, j, seen: (h, tile(qi, j, seen), 0))
+    def queries(d):
+        return pl.BlockSpec((1, G, tq, d),
+                            lambda h, qi, j, seen: (h, 0, qi, 0))
+
+    def keys(d):
+        return pl.BlockSpec((1, tk, d),
+                            lambda h, qi, j, seen: (h, tile(qi, j, seen), 0))
     stat = pltpu.VMEM((G * tq, 128), jnp.float32)
+    if name is None:
+        name = 'kv_prefix_attention' if window is None \
+            else 'kv_prefix_window_attention'
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, window=window,
                           n_key_tiles=nk),
@@ -187,24 +229,25 @@ def prefix_attention(q, k, v, at, pos, *, scale, window=None,
             num_scalar_prefetch=1,
             grid=(Hkv, nq, nk),
             in_specs=[
-                rows,
+                queries(dq),
                 pl.BlockSpec((1, G * tq, 1),
                              lambda h, qi, j, seen: (qi, 0, 0)),
-                keys, keys,
+                keys(k.shape[2]), keys(dv),
                 pl.BlockSpec((1, tk),
                              lambda h, qi, j, seen: (0, tile(qi, j, seen))),
-            ],
-            out_specs=rows,
+            ] + [pl.BlockSpec((tk, x.shape[1]),
+                              lambda h, qi, j, seen: (tile(qi, j, seen), 0))
+                 for x in shared],
+            out_specs=queries(dv),
             scratch_shapes=[stat, stat,
-                            pltpu.VMEM((G * tq, dh), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((Hkv, G, T, dh), q.dtype),
+                            pltpu.VMEM((G * tq, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((Hkv, G, T, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_BYTES),
         interpret=interpret,
-        name='kv_prefix_attention' if window is None
-        else 'kv_prefix_window_attention',
-    )(seen, q.reshape(Hkv, G, T, dh),
+        name=name,
+    )(seen, q.reshape(Hkv, G, T, dq),
       jnp.tile(tiles[:, None, :], (1, G, 1)).reshape(nq, G * tq, 1),
-      k, v, at[None])
-    return out.reshape(H, T, dh)
+      k, v, at[None], *shared)
+    return out.reshape(H, T, dv)

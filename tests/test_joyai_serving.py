@@ -523,12 +523,19 @@ def test_the_chip_comparison_runs_at_toy_width(served):
 
 
 def test_engine_tokens_do_not_depend_on_the_tier(monkeypatch):
-    """The kernel (interpreted) in the engine's decode step serves the
-    tokens the gather formulation serves."""
+    """The kernels (interpreted) in the engine's decode step AND in its
+    prefill serve the tokens the gather formulation and the plain
+    composition serve."""
+    from paddle_tpu.ops import prefix_attention as pfa
     prompts = [np.arange(2, 2 + n).astype('int64') for n in (5, 19)]
     served_by = {}
-    # the kernel's values are whole lane tiles of a row: a latent of 128
-    wide = dict(TOY, kv_lora_rank=128)
+    # the decode kernel's values are whole lane tiles of a row: a latent
+    # of 128; the prefill kernel's head is 128 lanes of its own beside 64
+    # rotary ones (and takes a call of any size here: a matter of speed)
+    wide = dict(TOY, kv_lora_rank=128, qk_nope_head_dim=128,
+                qk_rope_head_dim=64, qk_head_dim=192, head_dim=64,
+                v_head_dim=128)
+    monkeypatch.setattr(pfa, '_MIN_SCORES_BYTES', 0)
     for tier in ('off', 'interpret'):
         monkeypatch.setenv('PADDLE_FUSED_TIER', tier)
         before = monitor.counters()
@@ -539,8 +546,13 @@ def test_engine_tokens_do_not_depend_on_the_tier(monkeypatch):
         served_by[tier] = [list(eng.generate_once(p, max_new_tokens=9))
                            for p in prompts]
         moved = monitor.counter_delta(before)
-        assert [k for k in moved if 'mla_decode_attention_paged' in k
-                and 'impl=%s' % tier in k]
+        for op in ('mla_decode_attention_paged', 'mla_prefix_attention'):
+            # a dispatch a layer a program, and on no other tier
+            assert {k: n for k, n in moved.items()
+                    if k.startswith('fused_kernel_dispatch_total')
+                    and 'op=%s}' % op in k} == {
+                'fused_kernel_dispatch_total{impl=%s,mesh=1,op=%s}'
+                % (tier, op): TOY['num_hidden_layers']}
     assert served_by['off'] == served_by['interpret']
 
 

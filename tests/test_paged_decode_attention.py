@@ -701,22 +701,60 @@ def test_mosaic_accepts_the_prefix_kernel_at_the_cells_shapes(one_chip,
     assert c.memory_analysis().temp_size_in_bytes < H * T * M * 4 // 4
 
 
-@pytest.mark.parametrize('config,traffic,program,keys,gb', [
+@pytest.mark.parametrize('T', [512, 1024, 2048])
+def test_mosaic_accepts_the_prefix_kernel_at_latent_attentions_widths(
+        one_chip, T):
+    """ops/prefix_attention.py as `mla_prefix_attention` calls it, at
+    joyai-serve-longchat64's three buckets: 32 query heads of 128 + 64
+    lanes, each against its own 128-lane keys and 128-lane values, and ONE
+    rotary key of 64 lanes that the 32 share — a second, 64-deep product a
+    key tile against a block whose index ignores the head, query tiles of
+    the op's 512 rows. One custom call under the op's own name, and
+    nothing beside it as long as the scores."""
+    import functools
+    import jax
+    from paddle_tpu.ops import mla_ops, prefix_attention as pfa
+    H, nope, rope, v, M = 32, 128, 64, 128, 2816
+    assert pfa.shapes_ok(H, H, T, nope, M, v_dim=v, shared_dim=rope)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    c = jax.jit(functools.partial(
+        pfa.prefix_attention, scale=(nope + rope) ** -0.5,
+        name='mla_prefix_attention',
+        rows=mla_ops._KERNEL_QUERY_ROWS)).lower(
+        sds((H, T, nope + rope)), sds((H, M, nope)), sds((H, M, v)),
+        sds((M,), jnp.int32), sds((T,), jnp.int32),
+        sds((M, rope))).compile()
+    text = c.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r'%?mla_prefix_attention[.\d]* = ', text)
+    assert c.memory_analysis().temp_size_in_bytes < H * T * M * 4 // 4
+
+
+@pytest.mark.parametrize('config,traffic,program,keys,kernel,gb', [
     ('k-exaone-236b-a23b-ep16-l5', 'mixed64-closed', 'prefill_b512',
-     (5120, 639), 27.5),
-    ('fairseq-dense-1.3b', 'doc-closed', 'prefill_b1024', (1056,), 34.0)],
-    ids=['kexaone-b512', 'doc-b1024'])
+     (5120, 639), 'kv_prefix_attention', 27.5),
+    ('fairseq-dense-1.3b', 'doc-closed', 'prefill_b1024', (1056,),
+     'kv_prefix_attention', 34.0),
+    ('joyai-llm-flash-ep4', 'longchat64-closed', 'prefill_b2048', (2816,),
+     'mla_prefix_attention', 52.5)],
+    ids=['kexaone-b512', 'doc-b1024', 'joyai-b2048'])
 def test_a_prefill_program_holds_no_attention_scores(one_chip, monkeypatch,
                                                      config, traffic,
-                                                     program, keys, gb):
-    """The two widest prefill programs of the benchmark, whole, at their
-    real size (`tools/poolscan.py`'s device-less compile). Until PR 44 the
+                                                     program, keys, kernel,
+                                                     gb):
+    """The widest prefill programs of the benchmark, whole, at their real
+    size (`tools/poolscan.py`'s device-less compile). Until PR 44 the
     scores of every attention layer stood in HBM — ``f32[8,2048,5120]``,
     336 MB, twice a chunk in K-EXAONE; ``f32[32,1024,1056]``, 138 MB, 48
-    times in doc — now no float32 value is a row of keys long for as many
-    rows as the bucket has. XLA's `bytes accessed` counts such a value once
-    a fusion that writes it (not once a pass the chip makes over it): it
-    read 29.61 and 39.86 GB at the parent."""
+    times in doc; until PR 61 JoyAI's ``f32[32,256,2816]``, 92 MB a chunk
+    of 256 rows, eight chunks a layer — now no float32 value is a row of
+    keys long for as many rows as the bucket has. XLA's `bytes accessed`
+    counts such a value once a fusion that writes it (not once a pass the
+    chip makes over it): it read 29.61, 39.86 and 53.12 GB at the
+    parent."""
     import os
     from tools import poolscan
     monkeypatch.setenv('PADDLE_FUSED_TIER', 'pallas')
@@ -732,7 +770,7 @@ def test_a_prefill_program_holds_no_attention_scores(one_chip, monkeypatch,
               if dtype == 'f32' and dims and dims[-1] in keys
               and np.prod(dims[:-1]) >= rows]
     assert not scores, scores
-    assert re.search(r'%?kv_prefix_attention[.\d]* = ', text)
+    assert re.search(r'%%?%s[.\d]* = ' % kernel, text)
     assert compiled.cost_analysis()['bytes accessed'] < gb * 1e9
 
 

@@ -223,3 +223,48 @@ def test_query_tiles_divide_the_bucket(T, G, tq):
     """A tile holds the rows of all the queries of one K/V head, `_ROWS`
     at most (unless eight rows of each already pass it)."""
     assert pfa.query_tile(T, G) == tq
+
+
+# (H, Hkv, T, dh, keys, window): doc's widest bucket, and K-EXAONE's window
+# layers' call at theirs
+PARENT_KERNELS = {'doc-b1024': (32, 32, 1024, 64, 1056, None),
+                  'kexaone-window-b512': (64, 8, 512, 128, 639, 128)}
+
+
+def traced_kernel(H, Hkv, T, dh, M, window):
+    """`prefix_attention` traced at a shape, as text: the jaxpr of the
+    whole call — what XLA computes before the kernel, the `pallas_call`
+    with its grid, block shapes, compiler parameters, name and BODY —
+    and each operand's index map behind it."""
+    import functools
+    import jax
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    jaxpr = jax.make_jaxpr(functools.partial(
+        pfa.prefix_attention, scale=dh ** -0.5, window=window))(
+        sds((H, T, dh)), sds((Hkv, M, dh)), sds((Hkv, M, dh)),
+        sds((M,), jnp.int32), sds((T,), jnp.int32))
+    inner, = (e for e in jaxpr.eqns if e.primitive.name == 'jit')
+    call, = (e for e in inner.params['jaxpr'].eqns
+             if e.primitive.name == 'pallas_call')
+    maps = [str(b.index_map_jaxpr)
+            for b in call.params['grid_mapping'].block_mappings]
+    return '\n'.join([str(jaxpr)] + maps)
+
+
+@pytest.mark.parametrize('shape', PARENT_KERNELS)
+def test_the_kernel_of_equal_widths_is_the_parents(shape):
+    """A call with keys and values of one width and no shared key part
+    traces to the kernel it was before the latent attention's widths came
+    (fixtures/prefix_attention_kernel_parent_pr61.json, recorded on PR
+    60's tree): the cells that run `kv_prefix_attention` keep their
+    Mosaic programs, and their compile-cache keys."""
+    import json
+    import os
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           'fixtures',
+                           'prefix_attention_kernel_parent_pr61.json')) as f:
+        want = json.load(f)['kernels'][shape]
+    assert traced_kernel(*PARENT_KERNELS[shape]) == want

@@ -46,11 +46,21 @@ JAX's `pallas.ops.tpu.flash_attention` is a yardstick column, never a
 dependency of the package. The sweep that set the rule's constants
 (PERF.md, PR 52) and chose the packed view's head split (PR 57).
 
+The `mla_prefix_attention` case is the fourth: a prefill's expanded latent
+attention from the scores on (`ops/mla_ops.py`) at the buckets of
+`joyai-serve-longchat64` with `--size bench`, a form a column -- the plain
+composition, the op's kernel (two products a key tile), one key of 128 + 64
+lanes laid a call, that key padded to 256 -- and each `rows` of `--tilings`
+a column of the op's kernel at that query tile: ms a call and TFLOP/s over
+the causal half's FLOPs (PERF.md, PR 61).
+
 Usage: python tools/kernbench.py [--tiers off,xla,interpret]
        [--cases softmax_ce,fused_adam,embedding_gather,grouped_matmul,
-                flash_attention,layernorm_residual,ffn_tail,ln_sites]
+                flash_attention,mla_prefix_attention,layernorm_residual,
+                ffn_tail,ln_sites]
        [--rounds 5] [--size small|bench] [--mesh N]
-       [--tilings 64,896,512:32,896,512]   (flash_attention: 512,512:256,256)
+       [--tilings 64,896,512:32,896,512]   (flash_attention: 512,512:256,256;
+                                            mla_prefix_attention: 512:256)
        [--shapes 'nemotron up,nemotron down']
        (prints one JSON line)
 
@@ -61,6 +71,7 @@ needs the TPU box (tools/tpu_smoke.py environment).
 """
 import argparse
 import contextlib
+import functools
 import json
 import os
 import sys
@@ -485,6 +496,100 @@ def measure_flash_attention(size, rounds, k, tilings=(), shapes=None):
     return out
 
 
+# a prefill's expanded latent attention: (heads, bucket rows, nope, rope,
+# value width, the table's keys)
+MLA_PREFIX_SHAPES = {
+    'small': {'toy': (2, 64, 128, 64, 128, 160)},
+    'bench': {'joyai b%d' % t: (32, t, 128, 64, 128, 2816)
+              for t in (512, 1024, 2048)}}
+
+
+def measure_mla_prefix_attention(size, rounds, k, tilings=(), shapes=None):
+    """`mla_prefix_attention` (ops/mla_ops.py) from the scores on, at each
+    bucket of `joyai-serve-longchat64` with `--size bench`: q ``[T, H, nope
+    + rope]`` at positions 0 .. T - 1 (the cell shares no prefix), k_nope
+    and the values ``[H, M, .]`` and the one rotary key ``[M, rope]`` in,
+    the context ``[T, H, v]`` out — what differs between the tiers; the
+    two einsums that rebuild k_nope and v are the same program in both.
+    A form a column, each paying what it re-lays: 'composition' (the
+    `off` / `xla` tier), 'two products' (the op's kernel: a head's own
+    lanes against its keys and the rotary lanes against a key block whose
+    index ignores the head), 'one wide key' (the rotary key repeated a
+    head behind k_nope, ``[H, M, nope + rope]``, laid once a call), 'padded
+    key' (that key and q in whole vregs, zeros behind the rotary lanes);
+    'kernel alone' is 'two products' with q and the context heads-major
+    already. Each `rows` of `tilings` is 'two products' again with query
+    tiles of that many rows at most (the op's own: `mla_ops.
+    _KERNEL_QUERY_ROWS`). ms a call, TFLOP/s over the FLOPs of the causal
+    half (2 x H x T x T / 2 x (nope + rope + v)) and a form's largest
+    distance from the composition, as a share of the largest value."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import mla_ops, prefix_attention as pfa
+    interpret = jax.default_backend() != 'tpu'
+    out = {}
+    for label, (H, T, nope, rope, dv, M) in MLA_PREFIX_SHAPES[size].items():
+        if shapes and label not in shapes:
+            continue
+        scale = (nope + rope) ** -0.5
+        q, k_nope, k_rope, value = (
+            jax.random.normal(jax.random.PRNGKey(i), shape, jnp.float32)
+            for i, shape in enumerate([(T, H, nope + rope), (H, M, nope),
+                                       (M, rope), (H, M, dv)]))
+        pos, at = jnp.arange(T), jnp.arange(M)
+
+        def kernel(q, k, v, *shared, rows=mla_ops._KERNEL_QUERY_ROWS):
+            # heads-major q and context
+            return pfa.prefix_attention(q, k, v, at, pos, *shared,
+                                        scale=scale, interpret=interpret,
+                                        name='mla_prefix_attention',
+                                        rows=rows)
+
+        def composition(q, k_nope, k_rope, value):
+            return mla_ops._expanded_attention_scores(q, k_nope, k_rope,
+                                                      value, pos, scale)
+
+        def two_products(q, k_nope, k_rope, value, **kw):
+            return jnp.swapaxes(kernel(jnp.swapaxes(q, 0, 1), k_nope, value,
+                                       k_rope, **kw), 0, 1)
+
+        def wide_key(q, k_nope, k_rope, value, fill=0):
+            zeros = jnp.zeros(q.shape[:2] + (fill,), q.dtype)
+            key = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (H, M, rope)),
+                 jnp.zeros((H, M, fill), q.dtype)], axis=-1)
+            return jnp.swapaxes(kernel(jnp.swapaxes(
+                jnp.concatenate([q, zeros], axis=-1), 0, 1), key, value),
+                0, 1)
+
+        def alone(q, k_nope, k_rope, value):    # q [H, T, .] already
+            return kernel(q, k_nope, value, k_rope)
+        fill = -(nope + rope) % 128
+        forms = {'composition': composition, 'two products': two_products,
+                 'one wide key': wide_key,
+                 'padded key': lambda *a: wide_key(*a, fill=fill)}
+        operands = (q, k_nope, k_rope, value)
+        causal = 2 * H * T * T / 2 * (nope + rope + dv)
+        row = out.setdefault('%s [%d, %d, %d + %d | %d] x %d keys' % (
+            label, H, T, nope, rope, dv, M), {})
+        want = jax.jit(composition)(*operands)
+        for name, fn in forms.items():
+            row[name] = _time_flash({'call': (1, fn)}, operands, k, rounds,
+                                    causal)['call']
+            if 'error' not in row[name]:
+                row[name]['max_err'] = float(
+                    jnp.max(jnp.abs(jax.jit(fn)(*operands) - want))
+                    / jnp.max(jnp.abs(want)))
+        row['kernel alone'] = _time_flash(
+            {'call': (1, alone)}, (jnp.swapaxes(q, 0, 1),) + operands[1:],
+            k, rounds, causal)['call']
+        for t in tilings:
+            row['two products, rows %s' % t] = _time_flash(
+                {'call': (1, functools.partial(two_products, rows=int(t)))},
+                operands, k, rounds, causal)['call']
+    return out
+
+
 def _time_flash(kernels, operands, k, rounds, matmul):
     """{kernel: {ms, tflops}} of `kernels` {name: (causal matmuls, fn(q, k,
     v, o, lse, do))}: each the best of `rounds` runs of one program of `k`
@@ -593,7 +698,7 @@ _CASES = {
 }
 # every case by name: the tier comparisons and the lookup's candidates
 _CASE_NAMES = list(_CASES) + ['embedding_gather', 'grouped_matmul',
-                                 'flash_attention']
+                                 'flash_attention', 'mla_prefix_attention']
 
 
 def _measure(build, tier, rounds, k, size, mesh_n=1):
@@ -680,6 +785,10 @@ def measure_kernbench(cases=None, tiers=None, rounds=5, k=10,
             out[case] = measure_flash_attention(size, rounds, k, tilings,
                                                 shapes)
             continue
+        if case == 'mla_prefix_attention':  # forms, not tiers
+            out[case] = measure_mla_prefix_attention(size, rounds, k,
+                                                     tilings, shapes)
+            continue
         out[case] = {}
         for tier in tiers:
             before = monitor.counters()
@@ -716,10 +825,12 @@ def main():
                     help='run each case SPMD over mesh(data=N)')
     ap.add_argument('--tilings', default='',
                     help="grouped_matmul: 'tm,tk,tn' candidates, ':' between; "
-                         "flash_attention: 'bq,bk'")
+                         "flash_attention: 'bq,bk'; mla_prefix_attention: "
+                         "'rows' a query tile")
     ap.add_argument('--shapes', default='',
-                    help='grouped_matmul, flash_attention: only these '
-                         'labels of GROUPED_MATMULS / FLASH_SHAPES (comma '
+                    help='grouped_matmul, flash_attention, mla_prefix_attention: '
+                         'only these labels of GROUPED_MATMULS / FLASH_SHAPES '
+                         '/ MLA_PREFIX_SHAPES (comma '
                          'between)')
     args = ap.parse_args()
     if args.mesh > 1 and 'jax' not in sys.modules and \
