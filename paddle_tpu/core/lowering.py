@@ -287,6 +287,16 @@ class _OpClock(object):
         return False
 
 
+_NO_SCOPE = contextlib.nullcontext()
+
+
+def _trace_scope(name):
+    """The named scope an op with a ``trace_scope`` attribute lowers
+    under, prefixed as the package's host annotations are."""
+    return _NO_SCOPE if name is None \
+        else jax.named_scope(monitor.ANNOTATION_PREFIX + name)
+
+
 def lower_ops(ctx, ops, lo, hi):
     hook = _active_op_hook()
     for i in range(lo, hi):
@@ -294,11 +304,15 @@ def lower_ops(ctx, ops, lo, hi):
         op = ops[i]
         ctx._static_written = set()
         ctx._twin_written = set()
-        if hook is None:
-            with _OpClock(op.type):
-                get_op(op.type).lower(ctx, op)
-        else:
-            hook(ctx, op, lambda op=op: get_op(op.type).lower(ctx, op))
+        # an op that says which part of its program it belongs to (a
+        # looped model's pass: models/transformer.py `_loop_pass`) lowers
+        # under that name, which its HLO's metadata then carries
+        with _trace_scope(op.attrs.get('trace_scope')):
+            if hook is None:
+                with _OpClock(op.type):
+                    get_op(op.type).lower(ctx, op)
+            else:
+                hook(ctx, op, lambda op=op: get_op(op.type).lower(ctx, op))
         for n in op.output_arg_names:
             if n not in ctx._static_written:
                 ctx.statics.pop(n, None)

@@ -819,10 +819,13 @@ class BoundProgram(object):
 
     def _place(self, scope, names, leaves, program):
         """The entry's `place`: each read-only leaf in the executable's
-        format (the read-written ones have none and stay)."""
+        format (the read-written ones have none and stay; nor has a leaf
+        the executable never reads -- a looped model's exit gate under a
+        fetch list without its masses --, which stays as it lies)."""
         if names is self._entry.ro_names:
             todo = [i for i, f in enumerate(self._formats)
-                    if leaves[i].format.layout != f.layout]
+                    if f.layout is not None
+                    and leaves[i].format.layout != f.layout]
             if todo:
                 # set-up's `place` stage: the host waits for each copy
                 with coldstart.stage('place', program), _compiled_here():

@@ -7,6 +7,7 @@ naming conventions ('*.qkv*', '*.ffn1*', ...) that parallel/api.py's sharding
 rules match for tensor parallelism.
 """
 import collections
+import contextlib
 
 import numpy as np
 
@@ -107,7 +108,24 @@ class LMConfig(object):
       norm_1(mixer(x))``, ``y = h + norm_2(ffn(h))`` (the Olmo 2 / Olmo 3
       line's "reordered norm", arXiv:2501.00656; RMSNorm only; the final
       norm stays on the stream); the default ``'pre'`` is ``x +
-      f(norm(x))``;
+      f(norm(x))``; ``'sandwich'``: a norm before AND after each sublayer,
+      four weights a layer, ``h = x + norm_1o(mixer(norm_1(x)))``, ``y = h
+      + norm_2o(ffn(norm_2(h)))`` (``ln1`` / ``ln1_out`` / ``ln2`` /
+      ``ln2_out``; RMSNorm only);
+    - ``passes``: the whole stack of ``n_layer`` layers is run that many
+      times a token over ONE set of weights (a looped language model,
+      arXiv:2510.25741): the final norm closes EVERY pass and its output
+      is the next pass's input; after every pass the exit gate ``lambda_t
+      = sigmoid(x^t w + b)`` (``exit_gate.w [d_model, 1]``, ``exit_gate.b
+      [1]``) is read, and the logits come from the last pass's output. A
+      query of pass ``t`` at layer ``l`` attends what pass ``t`` of layer
+      ``l`` cached: the K and V pools hold ``passes x n_attn_layers``
+      cache layers (`cache_ordinal`), the parameters stay ``n_layer``
+      layers'. Every token runs every pass (an exit threshold of 1); the
+      decode step returns, behind its tokens, the exit distribution's
+      mass a pass summed over the live rows (`EXIT_MASS_ONE`). Built with
+      'attention' layers, ``attention='mha'`` and a dense FFN only, by the
+      two serving programs only;
     - ``attention_gate``: an attention layer's q projection is twice as
       wide -- head ``h`` owns columns ``2 h head_dim ..``: its q, then its
       gate -- and the attention's output is multiplied by ``sigmoid(gate)``
@@ -185,7 +203,7 @@ class LMConfig(object):
                  gdn_value_dim=0, gdn_chunk=64, attention_gate=False,
                  rotary_dim=None, norm_zero_centred=False,
                  shared_expert_gate=False, norm_placement='pre',
-                 gdn_allow_neg_eigval=False):
+                 gdn_allow_neg_eigval=False, passes=1):
         self.vocab_size = vocab_size
         self.seq_len = seq_len
         self.d_model = d_model
@@ -209,7 +227,8 @@ class LMConfig(object):
                 ('qk_norm', qk_norm, (False, True, 'head')),
                 ('expert_form', expert_form, ('gated', 'relu2')),
                 ('matmul_precision', matmul_precision, (None, 'highest')),
-                ('norm_placement', norm_placement, ('pre', 'post'))):
+                ('norm_placement', norm_placement,
+                 ('pre', 'post', 'sandwich'))):
             if value not in known:
                 raise ValueError('LMConfig.%s=%r: expected one of %r'
                                  % (field, value, known))
@@ -272,9 +291,19 @@ class LMConfig(object):
         self.shared_expert_gate = bool(shared_expert_gate)
         self.norm_placement = norm_placement
         self.gdn_allow_neg_eigval = bool(gdn_allow_neg_eigval)
-        if norm_placement == 'post' and norm != 'rms_norm':
-            raise ValueError("LMConfig.norm_placement='post' is built with "
-                             "norm='rms_norm' only, got %r" % (norm,))
+        self.passes = int(passes)
+        if norm_placement != 'pre' and norm != 'rms_norm':
+            raise ValueError("LMConfig.norm_placement=%r is built with "
+                             "norm='rms_norm' only, got %r"
+                             % (norm_placement, norm))
+        if self.passes < 1 or self.passes > 1 and (
+                set(self.layer_types) != {'attention'}
+                or attention != 'mha' or ffn == 'moe'):
+            raise ValueError("LMConfig.passes=%r: one pass or more, and "
+                             "more than one is built with 'attention' "
+                             "layers, attention='mha' and a dense FFN only "
+                             "(got layer_types=%r, attention=%r, ffn=%r)"
+                             % (passes, self.layer_types, attention, ffn))
         if set(self.attention_rope) - set(ROPE_KEYS):
             raise ValueError('LMConfig.attention_rope=%r: expected keys of '
                              '%r' % (attention_rope, ROPE_KEYS))
@@ -441,6 +470,13 @@ class LMConfig(object):
         attribute of its cache ops (a pool holds one kind only)."""
         return self.layer_types[:layer].count(self.layer_types[layer])
 
+    def cache_ordinal(self, layer, loop_pass=0):
+        """`layer`'s cache layer in its kind's pools at pass `loop_pass`
+        (the `layer` attribute of its cache ops): the passes behind one
+        another, each the kind's layers in order."""
+        return loop_pass * self.layer_types.count(self.layer_types[layer]) \
+            + self.layer_ordinal(layer)
+
     @property
     def attn_width(self):
         """Lanes of one token's attention output, all heads."""
@@ -473,7 +509,8 @@ def _require_classic_block(cfg, who):
         ('tie_embeddings', False), ('matmul_precision', None),
         ('attention_gate', False), ('rotary_dim', None),
         ('norm_zero_centred', False), ('shared_expert_gate', False),
-        ('norm_placement', 'pre'), ('gdn_allow_neg_eigval', False))
+        ('norm_placement', 'pre'), ('gdn_allow_neg_eigval', False),
+        ('passes', 1))
     for field, value in classic:
         if getattr(cfg, field) != value:
             raise ValueError(
@@ -578,7 +615,8 @@ def _norm(cfg, x, residual, bna, name, final=False):
     ``residual`` (or None) added first. LayerNorm: `_entry_ln`. With
     ``norm_placement='post'`` a sublayer reads the resolved stream as it
     is -- its norm is on its output (`_out_norm`) -- and only the ``final``
-    norm is one."""
+    norm is one; with ``'sandwich'`` it reads the normed stream AND its
+    output is normed."""
     if cfg.norm == 'layer_norm':
         return _entry_ln(x, residual, bna, name)
     if residual is not None:
@@ -592,10 +630,12 @@ def _norm(cfg, x, residual, bna, name, final=False):
 
 def _out_norm(cfg, delta, bna, name):
     """A sublayer's output as it joins the stream: normed by the weight
-    that `_norm` left unused with ``norm_placement='post'``, else as it
-    is."""
-    if cfg.norm_placement != 'post':
+    that `_norm` left unused with ``norm_placement='post'``, by a weight
+    of its own (``<name>_out.w``) with ``'sandwich'``, else as it is."""
+    if cfg.norm_placement == 'pre':
         return delta
+    if cfg.norm_placement == 'sandwich':
+        name += '_out'
     return layers.rms_norm(delta, begin_norm_axis=bna, epsilon=cfg.rms_eps,
                            param_attr=ParamAttr(name=name + '.w'),
                            zero_centred=cfg.norm_zero_centred)
@@ -1195,8 +1235,11 @@ def cache_pools(cfg, num_blocks=0, block_size=1, slots=None, shared=False):
     slots' has room for them (`window_pool_blocks`, `snapshot_rows`).
 
     Indexed by the block allocator's ids: K and V apart, the GLOBAL
-    attention layers' pages, or with latent attention the ONE pool of
-    latent rows (under K's name); with convolution layers their tails too,
+    attention layers' pages -- a cache layer a PASS of each
+    (`LMConfig.passes`: the first pool whose layers are not the weights'
+    layers; an engine reads its layers off the pool's ``shape[1]``) --, or
+    with latent attention the ONE pool of latent rows (under K's name); with
+    convolution layers their tails too,
     a block's ``conv_kernel - 1`` rows a layer. With window layers, indexed
     by the slots' rings (`window_ring`): those layers' K and V. With
     state-space layers, indexed by the slots' rows (slot ``i`` has row ``i
@@ -1220,7 +1263,9 @@ def cache_pools(cfg, num_blocks=0, block_size=1, slots=None, shared=False):
     n = slots or 0
     # a 'row' pool's rows: the trash row, a row a slot, the snapshot rows
     rows = n + 1 + snapshot_rows(n, shared)
-    kv = (num_blocks, cfg.n_attn_layers, block_size, cfg.kv_width)
+    # a cache layer a pass of every layer (`LMConfig.cache_ordinal`)
+    kv = (num_blocks, cfg.passes * cfg.n_attn_layers, block_size,
+          cfg.kv_width)
     kind([(KV_CACHE_K, kv)] + [(KV_CACHE_V, kv)] * (not latent), 'block',
          True, True, step=('kv_latent_tokens_read_total' if latent
                            else 'kv_tokens_read_total', None))
@@ -1358,8 +1403,64 @@ def _qkv_split_step(qkv, cfg):
     return parts
 
 
+# `LMConfig.passes`: what a mass of 1 reads as in the int64 vector a decode
+# step returns its exit masses in, behind its tokens (`_exit_masses`)
+EXIT_MASS_ONE = 1 << 20
+# ... and the scope the ops of pass ``t`` lower under (core/lowering.py
+# `trace_scope`; the decode attention's kernel takes it into its name)
+LOOP_PASS_SCOPE = 'loop_pass_%d'
+
+
+@contextlib.contextmanager
+def _loop_pass(cfg, block, t):
+    """The ops appended inside are pass `t`'s of `LMConfig.passes`: each
+    carries the pass as its ``trace_scope`` (a one-pass model's carry
+    nothing). Yields what its intermediate names take behind them."""
+    start = len(block.ops)
+    yield '.pass%d' % t if cfg.passes > 1 else ''
+    if cfg.passes > 1:
+        for op in block.ops[start:]:
+            op.set_attr('trace_scope', LOOP_PASS_SCOPE % t)
+
+
+def _close_pass(cfg, x, delta, bna, gates):
+    """The end of a pass: the final norm on the stream (the SAME after
+    every pass: its output is the next pass's input, the last one's the
+    head's), and with `LMConfig.passes` the exit gate read on it,
+    ``sigmoid(x w + b)`` a row, appended to `gates` (None: nobody reads
+    it -- a prefill's rows are not a step's, and a program that lists the
+    gate's parameters and never reads them has no layout to stage them
+    in)."""
+    x, _ = _norm(cfg, x, delta, bna, 'final_ln', final=True)
+    if gates is not None and cfg.passes > 1:
+        gates.append(layers.sigmoid(layers.fc(
+            x, size=1, num_flatten_dims=bna,
+            param_attr=ParamAttr(name='exit_gate.w'),
+            bias_attr=ParamAttr(name='exit_gate.b'))))
+    return x
+
+
+def _exit_masses(gates, valid):
+    """``[passes]`` int64: the exit distribution's mass a pass, ``p_t =
+    lambda_t prod_{s<t} (1 - lambda_s)`` and the last pass the rest, summed
+    over the live rows (`valid` ``[S, 1]``, zero = idle slot) in units of
+    1 / `EXIT_MASS_ONE` -- a row's masses sum to 1."""
+    live = layers.cast(layers.cast(valid, 'bool'), 'float32')
+    masses = []
+    for lam in gates[:-1]:
+        masses.append(layers.elementwise_mul(live, lam))
+        live = layers.elementwise_mul(
+            live, layers.scale(lam, scale=-1.0, bias=1.0))
+    masses.append(live)
+    total = layers.concat([layers.reduce_sum(m, dim=[0]) for m in masses],
+                          axis=0)
+    return layers.cast(layers.scale(total, scale=float(EXIT_MASS_ONE),
+                                    bias=0.5), 'int64')
+
+
 def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
-                  pos=None, valid=None, routing=None, mixers=None):
+                  pos=None, valid=None, routing=None, mixers=None,
+                  gates=None):
     """One decode-position transformer tower over per-slot row state
     ``x`` ([S, d]: token embedding, + position encoding where positions
     are added). The cache write and cached attention are delegated to
@@ -1379,43 +1480,52 @@ def _decode_tower(cfg, x, cache_write, attend, tag='', head=True,
     ``mixers`` has the program's cache op of every kind in `_MIXERS`
     the model has. A layer is the sublayers `cfg.has_mixer` and
     `cfg.has_ffn` give it, each behind its norm.
-    The cache closures get a layer's ORDINAL among the layers of its
-    kind (`LMConfig.layer_ordinal`) and the kind (``'attention'`` |
-    ``'window'``): a pool holds one kind."""
+    The cache closures get a layer's cache layer in its kind's pools
+    (`LMConfig.cache_ordinal`: its ordinal among the layers of its kind,
+    behind the earlier passes') and the kind (``'attention'`` |
+    ``'window'``): a pool holds one kind. With `LMConfig.passes` the layer
+    loop runs that many times over the same parameters, `_close_pass`
+    after each (``gates`` takes each pass's exit gate); intermediate names
+    carry the pass, parameter names do not."""
     delta = None             # previous layer's deferred FFN output
-    for i in range(cfg.n_layer):
-        p = 'layer_%d' % i
-        nth, kind = cfg.layer_ordinal(i), cfg.layer_types[i]
-        if cfg.has_mixer(i):
-            ln1, x = _norm(cfg, x, delta, 1, p + '.ln1')
-            if kind in _MIXERS:
-                delta = _MIXERS[kind](cfg, ln1, p, nth, mixers[kind], 1)
-            else:
-                q, k, v, gate = _qkv(cfg, ln1, p, pos, layer=i)  # [S, H, dh]
-                cache_write(k, v, nth, kind)
-                if not head and i == cfg.n_layer - 1:
-                    # write-only tower, last layer: nothing consumes x
-                    # past this K/V deposit — attention/proj/ffn are dead
-                    # compute
-                    return None
-                ctx = attend(q, nth, p + tag, kind)
-                delta = layers.fc(
-                    _gated(layers.reshape(ctx, shape=[-1, cfg.attn_width]),
-                           gate),
-                    size=cfg.d_model,
-                    param_attr=ParamAttr(name=p + '.attn.proj.w'),
-                    bias_attr=_bias(cfg, p + '.attn.proj.b'))
-            delta = _out_norm(cfg, delta, 1, p + '.ln1')
-        if cfg.has_ffn(i):
-            ln2, x = _norm(cfg, x, delta, 1, p + '.ln2')
-            delta, routed = _ffn(cfg, ln2, p, 1, valid=valid, layer=i)
-            delta = _out_norm(cfg, delta, 1, p + '.ln2')
-            if routed is not None:
-                routing.append(routed)
-
-    if not head:
-        return None
-    x, _ = _norm(cfg, x, delta, 1, 'final_ln', final=True)
+    block = x.block
+    for t in range(cfg.passes):
+        with _loop_pass(cfg, block, t) as pass_tag:
+            for i in range(cfg.n_layer):
+                p = 'layer_%d' % i
+                nth, kind = cfg.cache_ordinal(i, t), cfg.layer_types[i]
+                if cfg.has_mixer(i):
+                    ln1, x = _norm(cfg, x, delta, 1, p + '.ln1')
+                    if kind in _MIXERS:
+                        delta = _MIXERS[kind](cfg, ln1, p, nth,
+                                              mixers[kind], 1)
+                    else:
+                        q, k, v, gate = _qkv(cfg, ln1, p, pos,
+                                             layer=i)        # [S, H, dh]
+                        cache_write(k, v, nth, kind)
+                        if not head and i == cfg.n_layer - 1:
+                            # write-only tower, last layer: nothing
+                            # consumes x past this K/V deposit —
+                            # attention/proj/ffn are dead compute
+                            return None
+                        ctx = attend(q, nth, p + tag + pass_tag, kind)
+                        delta = layers.fc(
+                            _gated(layers.reshape(
+                                ctx, shape=[-1, cfg.attn_width]), gate),
+                            size=cfg.d_model,
+                            param_attr=ParamAttr(name=p + '.attn.proj.w'),
+                            bias_attr=_bias(cfg, p + '.attn.proj.b'))
+                    delta = _out_norm(cfg, delta, 1, p + '.ln1')
+                if cfg.has_ffn(i):
+                    ln2, x = _norm(cfg, x, delta, 1, p + '.ln2')
+                    delta, routed = _ffn(cfg, ln2, p, 1, valid=valid,
+                                         layer=i)
+                    delta = _out_norm(cfg, delta, 1, p + '.ln2')
+                    if routed is not None:
+                        routing.append(routed)
+            if not head:
+                return None
+            x, delta = _close_pass(cfg, x, delta, 1, gates), None
     return _lm_head(cfg, x)                                  # [S, V]
 
 
@@ -1436,7 +1546,9 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks,
     (`cfg.ffn == 'moe'`) also 'tokens_and_load' — next_tokens and the
     [n_layer * n_experts] expert loads of the live slots' rows in one
     int64 vector: fetch that INSTEAD — and 'topk_idx'
-    (`_expert_outputs`)."""
+    (`_expert_outputs`). With `LMConfig.passes` 'tokens_and_load' is
+    next_tokens and the [passes] exit masses of the live slots' rows
+    (`_exit_masses`), and 'exit_gates' each pass's gate ``[slots, 1]``."""
     _name_program('lm_decode_step', cfg)
     d, h, dh = cfg.d_model, cfg.n_head, cfg.head_dim
     tokens = layers.data(name='gen_tokens', shape=[1], dtype='int64')
@@ -1517,18 +1629,21 @@ def build_lm_decode_step(cfg, slots, max_len, block_size, num_blocks,
     # an idle slot's table row is all zero and a live slot's first page is
     # never block 0 (the trash block): the expert loads count live rows
     valid = layers.slice(btab, axes=[1], starts=[0], ends=[1]) \
-        if cfg.ffn == 'moe' else None
-    routing = []
+        if cfg.ffn == 'moe' or cfg.passes > 1 else None
+    routing, gates = [], []
     logits = _decode_tower(cfg, x, cache_write, attend, pos=pos,
                            valid=valid, routing=routing,
                            mixers={'conv': conv, 'ssm': ssm, 'ssd': ssd,
-                                   'gdn': gdn})
+                                   'gdn': gdn}, gates=gates)
     next_tokens = _append_sample_op(block, logits, sample_vars,
                                     'gen_next_tokens')       # [S]
-    return _expert_outputs(
-        {'tokens': tokens, 'pos': pos, 'logits': logits,
-         'next_tokens': next_tokens, 'k_cache': kc, 'v_cache': vc},
-        next_tokens, routing)
+    out = {'tokens': tokens, 'pos': pos, 'logits': logits,
+           'next_tokens': next_tokens, 'k_cache': kc, 'v_cache': vc}
+    if gates:
+        out['exit_gates'] = gates
+        out['tokens_and_load'] = layers.concat(
+            [next_tokens, _exit_masses(gates, valid)], axis=0)
+    return _expert_outputs(out, next_tokens, routing)
 
 
 def build_lm_drafter(cfg, slots, max_len, spec_k, num_blocks, block_size):
@@ -1756,7 +1871,9 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     'first_token', 'k_cache', 'v_cache'}, and with experts
     'tokens_and_load' (first_token and the [n_layer * n_experts] expert
     loads of the REAL suffix rows, one int64 vector: fetch it instead)
-    and 'topk_idx' (`_expert_outputs`)."""
+    and 'topk_idx' (`_expert_outputs`); with `LMConfig.passes` the stack
+    runs that many times, `_close_pass` after each (no gate is read: the
+    exit masses are a decode step's)."""
     _name_program('lm_prefill_paged', cfg)
     d, h, dh = cfg.d_model, cfg.n_head, cfg.head_dim
     T = int(prompt_len)
@@ -1816,9 +1933,10 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
             cfg.gdn_chunk, epsilon=cfg.rms_eps,
             allow_neg_eigval=cfg.gdn_allow_neg_eigval)
 
-    def attention(ln1, p, nth, layer):
+    def attention(ln1, p, nth, layer, tag):
         """An attention layer's mixer: q, k, v, the cache writes, the
-        suffix's attention against the slot's pages, the projection."""
+        suffix's attention against the slot's pages, the projection
+        (`tag`: the pass, in the one intermediate that is named)."""
         q, k, v, gate = _qkv(cfg, ln1, p, pos, T, layer=layer)  # [1,H,T,dh]
         window = cfg.layer_types[layer] == 'window'
         if not window:
@@ -1838,7 +1956,7 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
                 bound = {'window': cfg.sliding_window}
             else:
                 cache_write(vc, v, nth)
-            ctx = block.create_var(name=p + '.prefix_attn_out',
+            ctx = block.create_var(name=p + tag + '.prefix_attn_out',
                                    shape=(-1, h, T, dh), dtype='float32')
             block.append_op(
                 type='kv_prefix_attention',
@@ -1859,22 +1977,25 @@ def build_lm_prefill_paged(cfg, prompt_len, num_blocks, block_size,
     delta = None
     routing = []
     mixers = {'conv': conv, 'ssm': ssm, 'ssd': ssd, 'gdn': gdn}
-    for i in range(cfg.n_layer):
-        p = 'layer_%d' % i
-        nth, kind = cfg.layer_ordinal(i), cfg.layer_types[i]
-        if cfg.has_mixer(i):
-            ln1, x = _norm(cfg, x, delta, 2, p + '.ln1')
-            delta = _MIXERS[kind](cfg, ln1, p, nth, mixers[kind], 2) \
-                if kind in _MIXERS else attention(ln1, p, nth, i)
-            delta = _out_norm(cfg, delta, 2, p + '.ln1')
-        if cfg.has_ffn(i):
-            ln2, x = _norm(cfg, x, delta, 2, p + '.ln2')
-            delta, routed = _ffn(cfg, ln2, p, 2, length=length, layer=i)
-            delta = _out_norm(cfg, delta, 2, p + '.ln2')
-            if routed is not None:
-                routing.append(routed)
-
-    x, _ = _norm(cfg, x, delta, 2, 'final_ln', final=True)
+    for t in range(cfg.passes):
+        with _loop_pass(cfg, block, t) as tag:
+            for i in range(cfg.n_layer):
+                p = 'layer_%d' % i
+                nth, kind = cfg.cache_ordinal(i, t), cfg.layer_types[i]
+                if cfg.has_mixer(i):
+                    ln1, x = _norm(cfg, x, delta, 2, p + '.ln1')
+                    delta = _MIXERS[kind](cfg, ln1, p, nth, mixers[kind],
+                                          2) if kind in _MIXERS \
+                        else attention(ln1, p, nth, i, tag)
+                    delta = _out_norm(cfg, delta, 2, p + '.ln1')
+                if cfg.has_ffn(i):
+                    ln2, x = _norm(cfg, x, delta, 2, p + '.ln2')
+                    delta, routed = _ffn(cfg, ln2, p, 2, length=length,
+                                         layer=i)
+                    delta = _out_norm(cfg, delta, 2, p + '.ln2')
+                    if routed is not None:
+                        routing.append(routed)
+            x, delta = _close_pass(cfg, x, delta, 2, None), None
     x_flat = layers.reshape(x, shape=[-1, d])                # [T, d]
     one = layers.fill_constant(shape=[1], dtype='int64', value=1)
     last = layers.gather(x_flat, layers.elementwise_sub(length, one))

@@ -332,10 +332,10 @@ ANNOTATION_PREFIX = 'paddle_tpu:'
 _TraceAnnotation = None
 
 
-def _annotate(name):
-    """An entered TraceAnnotation `name` — a host event on the clock of
-    the profiler's device trace — while a profiler session is live, else
-    None (one is_enabled() read, ~0.1 us). The caller exits it."""
+def tracing():
+    """jax's TraceAnnotation while a profiler session is live, else None
+    (one is_enabled() read, ~0.1 us): what a hot path asks before it books
+    something for the traced span alone."""
     global _TraceAnnotation
     ta = _TraceAnnotation
     if ta is None:
@@ -343,7 +343,15 @@ def _annotate(name):
         if profiler is None:
             return None
         ta = _TraceAnnotation = profiler.TraceAnnotation
-    if not ta.is_enabled():
+    return ta if ta.is_enabled() else None
+
+
+def _annotate(name):
+    """An entered TraceAnnotation `name` — a host event on the clock of
+    the profiler's device trace — while a profiler session is live
+    (`tracing`), else None. The caller exits it."""
+    ta = tracing()
+    if ta is None:
         return None
     a = ta(name)
     a.__enter__()

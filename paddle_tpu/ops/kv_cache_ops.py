@@ -344,7 +344,10 @@ def _kv_decode_attention_paged(ctx, op):
     + 1 .. Positions[s]``; the kernel starts at the page of the first of
     them under an operation name of its own, and the gather takes the
     whole ring and masks by the position each place holds
-    (`_ring_positions`)."""
+    (`_ring_positions`). ``trace_scope`` (a looped model's pass): the
+    kernel's operation name ends in it, ``paged_decode_attention_loop_
+    pass_<t>``, so that a device trace's per-name sums tell the passes
+    apart."""
     from . import kernel_tier, paged_decode_attention as pda
     from ..parallel.api import get_active_mesh
     q = ctx.in1(op, 'Q')                        # [S, H, dh]
@@ -364,13 +367,15 @@ def _kv_decode_attention_paged(ctx, op):
     with _window_scope(window):
         ctx.out(op, 'Out', _decode_attention(
             impl, q, kc, vc, tables, pos, int(op.attr('layer')),
-            op.attr('scale', 1.0), bs, window))
+            op.attr('scale', 1.0), bs, window, op.attr('trace_scope')))
 
 
 def _decode_attention(impl, q, kc, vc, tables, pos, layer, scale, bs,
-                      window):
+                      window, part=None):
     """`kv_decode_attention_paged` under the tier `impl`; ``window`` None
-    for a layer that sees every key."""
+    for a layer that sees every key; ``part`` the op's ``trace_scope`` (a
+    looped model's pass), which the kernel's operation name then ends
+    in."""
     from . import paged_decode_attention as pda
     from .. import monitor
     MB = tables.shape[1]
@@ -383,7 +388,8 @@ def _decode_attention(impl, q, kc, vc, tables, pos, layer, scale, bs,
                     labels={'form': pda.form(H, Hkv)})
         return pda.paged_decode_attention(
             q, kc, vc, tables, pos, jnp.int32(layer), scale=float(scale),
-            interpret=impl == 'interpret', attention_span=window)
+            interpret=impl == 'interpret', attention_span=window,
+            part=part)
     if window is None:
         m = jnp.arange(MB * bs)[None, None, :] <= pos[:, None, None]
     else:

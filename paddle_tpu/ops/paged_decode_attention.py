@@ -418,15 +418,18 @@ def _grouped_kernel(tables_ref, pos_ref, layer_ref,  # scalar prefetch
 
 
 @functools.partial(jax.jit, static_argnames=('scale', 'interpret',
-                                             'attention_span'))
+                                             'attention_span', 'part'))
 def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
-                           scale, interpret=False, attention_span=None):
+                           scale, interpret=False, attention_span=None,
+                           part=None):
     """q ``[S, H, dh]``; pools ``[NB, Ln, bs, Hkv*dh]``; tables ``[S,
     MB]`` and pos ``[S]`` int32; layer an int32 scalar. Returns ``[S, H,
     dh]``: softmax(q . K[0..pos]) V[0..pos] per slot and head, query head
     h against K/V head ``h // (H // Hkv)``. ``attention_span`` (a window
     layer's call): keys ``pos - attention_span + 1 .. pos`` alone, through
-    tables that are rings.
+    tables that are rings. ``part`` (a looped model's pass, ``loop_pass_
+    <t>``): the operation's name ends in it -- one trace of the kernel a
+    pass, not one a program.
 
     Jitted, with `layer` an operand: the layers of a decode program call
     ONE traced function, so the kernel is traced and lowered to Mosaic
@@ -487,8 +490,8 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name='paged_window_decode_attention' if bound
-        else 'paged_decode_attention',
+        name=('paged_window_decode_attention' if bound
+              else 'paged_decode_attention') + ('_' + part if part else ''),
     )(tables.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), rows, k_pool, v_pool)
     return jnp.swapaxes(out[:, :G].reshape(S, G, Hkv, dh), 1,

@@ -153,7 +153,7 @@ from .. import trace as trace_mod
 from .. import unique_name
 from ..executor import Executor, Scope, scope_guard
 from ..framework import Program, TPUPlace, program_guard
-from ..models.transformer import (INDEX_FEEDS, LMConfig,
+from ..models.transformer import (EXIT_MASS_ONE, INDEX_FEEDS, LMConfig,
                                   build_lm_decode_step,
                                   build_lm_prefill_paged, cache_pools,
                                   kv_cache_names, kv_cache_shapes)
@@ -661,6 +661,14 @@ class GenerateEngine(object):
         # ... and at an admission, of what it resumes from at a shared
         # prefix's edge
         self._hits = booked('hit')
+        # a looped model's passes a dispatch (`_book_passes`), those run
+        # so far by phase and the exit mass a pass of the decode steps
+        self._passes = c.model.passes
+        self._pass_runs = {'decode': 0, 'prefill': 0}
+        self._exit_mass = [0.0] * self._passes
+        self._pass_labels = [{'pass': str(t + 1)}
+                             for t in range(self._passes)]
+        self._traced_reads = {}
         if c.speculative:
             self._draft_cfg = c.draft_model or c.model
             # +1 over the all-slots-at-max_len footprint (the trash
@@ -830,9 +838,13 @@ class GenerateEngine(object):
         """The fetched vector as tokens; the expert loads behind them
         (a model with experts) go into the moe_* counters: per layer-step
         the assignments, the experts touched and the busiest expert's
-        rows — four scalar adds, no label (docs/observability.md)."""
+        rows — four scalar adds, no label (docs/observability.md). Behind
+        a looped model's tokens lie its step's exit masses instead
+        (`_book_passes`)."""
         flat = np.asarray(out).reshape(-1)
-        if flat.size > n_tokens:
+        if flat.size > n_tokens and self._passes > 1:
+            self._book_passes('decode', flat[n_tokens:])
+        elif flat.size > n_tokens:
             cfg = self.config.model
             load = flat[n_tokens:].reshape(cfg.n_moe_layers, -1)
             # the experts held here; a layer that holds a share only
@@ -846,6 +858,23 @@ class GenerateEngine(object):
             monitor.inc('moe_max_expert_rows_total',
                         int(held.max(axis=1).sum()))
         return flat[:n_tokens]
+
+    def _book_passes(self, phase, masses=()):
+        """A looped model's dispatch (`LMConfig.passes`): the passes it
+        ran into loop_passes_total{phase} -- every one, until a token may
+        leave early -- and a decode step's `masses`, the exit
+        distribution's mass a pass over its live rows as the program
+        returns them (`EXIT_MASS_ONE`), into loop_exit_mass_total{pass}:
+        the mean pass at which a threshold would let go, read off a
+        running server (docs/observability.md)."""
+        monitor.inc('loop_passes_total', self._passes,
+                    labels={'phase': phase})
+        self._pass_runs[phase] += self._passes
+        for t, mass in enumerate(masses):
+            mass = float(mass) / EXIT_MASS_ONE
+            monitor.inc('loop_exit_mass_total', mass,
+                        labels=self._pass_labels[t])
+            self._exit_mass[t] += mass
 
     def _init_state(self):
         cfg, c = self.config.model, self.config
@@ -2024,6 +2053,8 @@ class GenerateEngine(object):
             # the rows the layers' scans walk (a bucket's pad rows are not
             # among them)
             monitor.inc(series, rows.size * n_layers)
+        if self._passes > 1:
+            self._book_passes('prefill')
         if off > 0:
             # every dispatch that starts past position 0 — a hit's suffix,
             # a later chunk — resumes from a tail the pool holds, from the
@@ -2478,9 +2509,15 @@ class GenerateEngine(object):
                 # position); of a pool a slot owns (so `lengths` is made),
                 # a window's worth at most, or the ONE state row the step
                 # reads, advances and writes back
-                monitor.inc(series, n_layers * (
-                    live_tokens if most is None
-                    else sum(min(n, most) for n in lengths)))
+                rows = n_layers * (live_tokens if most is None
+                                   else sum(min(n, most) for n in lengths))
+                monitor.inc(series, rows)
+                if self._passes > 1 and monitor.tracing() is not None:
+                    # ... and apart while a profiler session is live: what
+                    # the steps of a trace read, beside the trace's own
+                    # kernel seconds (`stats()['passes']['traced']`)
+                    self._traced_reads[series] = \
+                        self._traced_reads.get(series, 0) + rows
             feed = {'gen_pos': pos}
             feed.update(self._tables_feed(btab, ((i, i) for i, _ in active)))
         with _loop_phase('dispatch'):
@@ -2748,7 +2785,13 @@ class GenerateEngine(object):
         pages the decode steps read; it is the GLOBAL layers' pool, and
         'window' in it the window layers' (`WindowRings`). 'state' (a
         model with state-space layers) is their pools' rows, one a slot
-        that is admitted (both: the bookkeepers' `report`). 'loop' is where
+        that is admitted (both: the bookkeepers' `report`). 'passes' (a
+        looped model, `LMConfig.passes`): the passes a token takes, those
+        run by phase, the decode steps' exit mass a pass summed over
+        their live rows (`_book_passes`), and 'traced', by series, the rows
+        the steps dispatched under a live profiler session read of the
+        pools' layers -- the bytes that belong to a trace's own kernel
+        seconds. 'loop' is where
         the loop thread's time went, by phase (_loop_sums)."""
         steps = self._decode_steps
         out = {
@@ -2786,6 +2829,11 @@ class GenerateEngine(object):
         }
         for book in self._books:
             book.report(out)
+        if self._passes > 1:
+            out['passes'] = {'a_token': self._passes,
+                             'run': dict(self._pass_runs),
+                             'exit_mass': list(self._exit_mass),
+                             'traced': dict(self._traced_reads)}
         if self.config.speculative:
             prop = self._spec_proposed
             out['spec'] = {

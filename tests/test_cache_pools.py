@@ -1,12 +1,14 @@
 """The table of a model's pools (ISSUE 46, models/transformer.py
 `cache_pools`): for the toy `LMConfig` of each of the benchmark's
 configurations (Nemotron's since PR 48, Qwen3-Next's since PR 55,
-Olmo-Hybrid's since PR 58), every
+Olmo-Hybrid's since PR 58, Ouro's since PR 63: the K and V row's layers a
+PASS of each layer), every
 pool's name, what indexes it, whether a rejected
 draft rewinds from it and whether a shared block's entry of it copies;
 `kv_cache_names` and `kv_cache_shapes` are views of it; and what an engine
 refuses, which bookkeepers it keeps and what it books follow from the table
 and from nothing else."""
+import copy
 import json
 import os
 
@@ -18,7 +20,7 @@ from paddle_tpu.serving import GenerateConfig, GenerateEngine
 from paddle_tpu.serving import kv_blocks
 
 from benchmark.models import (jamba, joyai, kexaone, lfm2, lm, nemotron,
-                              olmoe, olmohybrid, qwen3next)
+                              olmoe, olmohybrid, ouro, qwen3next)
 
 from test_olmoe_serving import LISTED
 
@@ -43,6 +45,7 @@ CONFIGS = {
     'nemotron-3-nano-30b-a3b-ep8-l20': lambda: _toy(nemotron, 'nemotron'),
     'qwen3-next-80b-a3b-ep8-l8': lambda: _toy(qwen3next, 'qwen3next'),
     'olmo-hybrid-7b-l8': lambda: _toy(olmohybrid, 'olmohybrid'),
+    'ouro-2.6b-l8': lambda: _toy(ouro, 'ouro'),
 }
 KV = [(T.KV_CACHE_K, 'block', True, True), (T.KV_CACHE_V, 'block', True, True)]
 # (name, index, rewinds, copies) of every pool, in the order of the state
@@ -63,9 +66,11 @@ POOLS = {
         (T.GDN_STATE, 'row', False, False), (T.GDN_TAIL, 'row', False, False)],
     'olmo-hybrid-7b-l8': KV + [
         (T.GDN_STATE, 'row', False, False), (T.GDN_TAIL, 'row', False, False)],
+    'ouro-2.6b-l8': KV,
 }
 # the series a decode step books its reads under: (series, rows a slot at
-# most, of which field of the config a layer count)
+# most, of which field of the config a layer count -- the K and V row's
+# once a pass, `_layers`)
 STEP_READS = {
     'joyai-llm-flash-ep4': [('kv_latent_tokens_read_total', None, 'n_layer')],
     'k-exaone-236b-a23b-ep16-l5': [
@@ -86,6 +91,13 @@ STEP_READS = {
 SLOTS, BLOCKS, BLOCK_SIZE = 4, 19, 8
 
 
+def _layers(cfg, field):
+    """The layers of the pool that `field` counts: the global attention
+    layers' K and V hold a cache layer a PASS of each."""
+    return getattr(cfg, field) * (cfg.passes if field == 'n_attn_layers'
+                                  else 1)
+
+
 @pytest.mark.parametrize('config', sorted(CONFIGS))
 def test_the_table_holds_every_pool_and_the_views_are_its(config):
     cfg = CONFIGS[config]()
@@ -101,9 +113,10 @@ def test_the_table_holds_every_pool_and_the_views_are_its(config):
     entries = {'block': BLOCKS, 'ring': SLOTS * ring + 1, 'row': SLOTS + 1}
     for p in pools:
         assert p.shape[0] == entries[p.index] and len(p.shape) == 4
-        assert p.shape[1] in (cfg.n_attn_layers, cfg.n_conv_layers,
-                              cfg.n_window_layers, cfg.n_ssm_layers,
-                              cfg.n_ssd_layers, cfg.n_gdn_layers)
+        assert p.shape[1] in (cfg.passes * cfg.n_attn_layers,
+                              cfg.n_conv_layers, cfg.n_window_layers,
+                              cfg.n_ssm_layers, cfg.n_ssd_layers,
+                              cfg.n_gdn_layers)
         # a pool an option is refused over says why; the others need not
         assert (p.why is None) == (p.rewinds and p.index == 'block')
         assert T.INDEX_FEEDS[p.index].startswith('gen_')
@@ -118,7 +131,32 @@ def test_the_table_holds_every_pool_and_the_views_are_its(config):
         config, [('kv_tokens_read_total', None, 'n_attn_layers')])
     assert [p.books['step'] + (p.shape[1],) for p in pools
             if 'step' in p.books] == \
-        [(series, most, getattr(cfg, field)) for series, most, field in want]
+        [(series, most, _layers(cfg, field)) for series, most, field in want]
+
+
+@pytest.mark.parametrize('config', sorted(CONFIGS))
+def test_the_kv_rows_layers_are_a_pass_of_every_attention_layer(config):
+    """The ONE row of the K and V pools sizes their second dimension
+    ``passes x n_attn_layers``: with one pass today's shapes, with more the
+    K and V pools alone grow (which models may loop is `LMConfig`'s to
+    say: tests/test_ouro_serving.py)."""
+    cfg = CONFIGS[config]()
+    assert cfg.passes == (4 if config == 'ouro-2.6b-l8' else 1)
+    one, three = copy.copy(cfg), copy.copy(cfg)
+    one.passes, three.passes = 1, 3
+    shapes = [T.kv_cache_shapes(c, BLOCKS, BLOCK_SIZE, SLOTS)
+              for c in (one, cfg, three)]
+    width = (BLOCKS, cfg.n_attn_layers, BLOCK_SIZE, cfg.kv_width)
+    for c, got in zip((one, cfg, three), shapes):
+        for name, shape in got.items():
+            if name in (T.KV_CACHE_K, T.KV_CACHE_V):
+                assert shape == width[:1] + (c.passes * width[1],) + width[2:]
+            else:
+                assert shape == shapes[0][name]
+        assert [c.cache_ordinal(i, t) for t in range(c.passes)
+                for i in range(c.n_layer)
+                if c.layer_types[i] == 'attention'] == \
+            list(range(c.passes * c.n_attn_layers))
 
 
 def _engine(monkeypatch, cfg, **options):
